@@ -54,11 +54,9 @@ def main() -> int:
     ps = np.asarray(kp).shape[1]
     P = np.asarray(kp).shape[0]
     maxp = np.asarray(tables).shape[1]
-    W = np.asarray(q).shape[0]
     base = functools.partial(
         ap._ragged_prefill_kernel, page_size=ps,
-        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp,
-        tile=min(128, W), window=None)
+        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp, window=None)
 
     # -- crime 1: OOB page id in the wave's write descriptors ---------
     bad_tables = np.array(np.asarray(tables), copy=True)
@@ -70,8 +68,9 @@ def main() -> int:
 
     # -- crime 2: short write (one live row's finalize skipped) -------
     def short_write(*refs):
-        if (pl.program_id(0) == live_r
-                and pl.program_id(1) == pl.num_programs(1) - 1):
+        # grid (query block, row, step)
+        if (pl.program_id(1) == live_r
+                and pl.program_id(2) == pl.num_programs(2) - 1):
             return
         base(*refs)
 
@@ -84,8 +83,8 @@ def main() -> int:
         base(*refs)
         o_ref = refs[9]
         o_ref[...] = (np.zeros(o_ref.shape, np.float32)
-                      + 1.5 * (pl.program_id(0) + 1)
-                      + 0.25 * pl.program_id(1))
+                      + 1.5 * (pl.program_id(1) + 1)
+                      + 0.25 * pl.program_id(2))
 
     kerncheck.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens,

@@ -1,9 +1,6 @@
 #!/usr/bin/env python
 """Reproduce the swarm100 paged-chunked lowering failure on real TPU."""
-import os
 import sys
-
-os.environ.setdefault("SWARMDB_COMPILE_CACHE", "/root/repo/.jax_cache")
 
 import jax
 import numpy as np

@@ -14,6 +14,7 @@ from __future__ import annotations
 import ctypes
 import json
 import os
+import shutil
 import struct
 import subprocess
 import tempfile
@@ -35,23 +36,31 @@ _REC_HDR = struct.Struct("<qdii")  # offset, ts, key_len, val_len
 
 
 def build_native() -> bool:
-    """Build (or freshen) the shared library; True if it is now present.
+    """Build (or freshen) the shared library from ``broker.cpp``; True if
+    it is now present and current.
 
     Always invokes make when targeting the in-tree library — the Makefile's
     ``broker.cpp`` dependency makes it a no-op when fresh, and it guarantees
     edits to broker.cpp are never shadowed by a stale binary (the .so is
-    gitignored, never committed). A custom SWARMDB_BROKER_LIB (e.g. the TSAN
-    build) is loaded as-is.
+    gitignored, never committed). Without a toolchain the library is NOT
+    used, whatever file lies there: False. With one, a build that fails is
+    an error, not a reason to load a leftover. A custom SWARMDB_BROKER_LIB
+    (e.g. the TSAN build) is loaded as-is.
     """
     if _LIB_PATH != os.path.join(_CPP_DIR, "libswarmbroker.so"):
         return os.path.exists(_LIB_PATH)
+    if not (shutil.which("make")
+            and shutil.which(os.environ.get("CXX", "g++"))):
+        return False
     try:
         subprocess.run(
             ["make", "-s", "libswarmbroker.so"],
             cwd=_CPP_DIR, check=True, capture_output=True, timeout=120,
         )
-    except Exception:
-        pass  # no toolchain: fall back to an existing binary if present
+    except subprocess.CalledProcessError as exc:
+        raise BrokerError(
+            "building libswarmbroker.so failed:\n"
+            + exc.stderr.decode("utf-8", "replace")[-2000:]) from exc
     return os.path.exists(_LIB_PATH)
 
 

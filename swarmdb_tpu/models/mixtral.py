@@ -45,6 +45,7 @@ from ..ops.layers import (
     write_kv_cache,
 )
 from .configs import ModelConfig
+from .llama import random_dense
 
 Params = Dict[str, Any]
 KVCache = Tuple[jnp.ndarray, jnp.ndarray]
@@ -65,7 +66,7 @@ def init_params(
     k_embed, k_layers, k_head = jax.random.split(key, 3)
 
     def dense(key, shape, fan_in):
-        return (jax.random.normal(key, shape, jnp.float32) / jnp.sqrt(fan_in)).astype(dtype)
+        return random_dense(key, shape, fan_in, dtype)
 
     ks = jax.random.split(k_layers, 9)
     params: Params = {

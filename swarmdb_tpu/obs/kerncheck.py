@@ -424,7 +424,7 @@ def _run_grid(kernel: Callable, kernel_name: str,
     the TPU order) against numpy backing stores with bounds-checked
     block selection, recording oob-block / oob-ref / write-race
     violations as it goes. Returns (output backing store, per-element
-    last-writer map: -1 = only ever touched by the init cell)."""
+    last-writer map: -1 = only ever touched by its block's init cell)."""
     reg = registry()
     state: Dict[str, Any] = {"grid": grid, "coords": (0,) * len(grid)}
     scalar_refs = [ShadowRef(arr, name, kernel_name, state)
@@ -434,6 +434,7 @@ def _run_grid(kernel: Callable, kernel_name: str,
                     for i, arr in enumerate(scratch)]
     # element-granular last-changer for the race check: -1 = untouched
     last_writer = np.full(out_buf.shape, -1, np.int64)
+    visited: set = set()  # output blocks some grid cell has mapped
 
     def block_view(name: str, arr: np.ndarray, bs: Tuple[int, ...],
                    idx: Sequence[Any]) -> Tuple[np.ndarray,
@@ -472,12 +473,16 @@ def _run_grid(kernel: Callable, kernel_name: str,
                    ShadowRef(oview, out_name, kernel_name, state),
                    *scratch_refs)
             changed = np.asarray(pre != oview)
-            # the all-zero grid cell writing CONSTANT zeros is the
-            # zero-fill init idiom — exempt from writer tracking so a
-            # later per-row finalize is not a "race" against it and a
-            # row it alone touched still counts as unwritten. An init
-            # cell writing real (non-zero) values is an ordinary writer.
-            is_zero_fill = (all(c == 0 for c in coords) and changed.any()
+            # the FIRST cell to visit an output block writing CONSTANT
+            # zeros is the zero-fill init idiom — exempt from writer
+            # tracking so a later per-row finalize is not a "race"
+            # against it and a row it alone touched still counts as
+            # unwritten. An init cell writing real (non-zero) values is
+            # an ordinary writer.
+            block = tuple(s.start for s in oslices)
+            first_visit = block not in visited
+            visited.add(block)
+            is_zero_fill = (first_visit and changed.any()
                             and not np.asarray(
                                 oview, np.float32)[changed].any())
             if changed.any() and not is_zero_fill:
@@ -513,9 +518,13 @@ def shadow_ragged_prefill(q, sfx_k, sfx_v, k_pages, v_pages, row_tables,
     check against the per-row (start, len) descriptors. ``kernel``
     overrides the kernel body (the drill seeds sabotaged variants).
     Returns the shadow output [W, Hq, D]."""
+    import jax.numpy as jnp
+
     from ..ops import attention_pallas as ap
 
-    q = np.asarray(q)
+    n_tok = np.asarray(q).shape[0]
+    q, sfx_k, sfx_v = (np.asarray(s) for s in ap._pad_stream(
+        tile, *(jnp.asarray(s) for s in (q, sfx_k, sfx_v))))
     W, Hq, D = q.shape
     k_pages = np.asarray(k_pages)
     _, ps, Hkv, _ = k_pages.shape
@@ -524,38 +533,46 @@ def shadow_ragged_prefill(q, sfx_k, sfx_v, k_pages, v_pages, row_tables,
     starts = np.asarray(starts, np.int32)
     lens = np.asarray(lens, np.int32)
     plens = np.asarray(prefix_lens, np.int32)
-    Tk = min(tile, W)
-    n_st = -(-W // Tk)
+    Tq = min(tile, W)
+    n_st = W // Tq
     name = "ragged_paged_prefill_attention"
     if kernel is None:
         kernel = functools.partial(
             ap._ragged_prefill_kernel, page_size=ps, n_kv_heads=Hkv,
-            n_pages=maxp, tile=Tk, window=window)
+            n_pages=maxp, window=window)
 
-    def stream_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        return (0, 0, 0)
+    # the wrapper's grid and index maps, restated (they are closures there)
+    def q_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        return (qb, 0, 0)
 
-    def kv_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        import jax.numpy as jnp
+    def sfx_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        tt, _ = ap._ragged_suffix_tile(j - maxp, qb * Tq, Tq,
+                                       starts_ref[r], lens_ref[r], Tq)
+        return (tt, 0, 0)
 
+    def kv_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        meets = ap._ragged_row_meets_block(qb * Tq, Tq, starts_ref[r],
+                                           lens_ref[r])
         last_live = ap._last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.minimum(j, last_live)], 0, 0, 0)
+        return (table_ref[r, jnp.where(meets, jnp.minimum(j, last_live),
+                                       0)], 0, 0, 0)
 
     out = np.full((W, Hq, D), CANARY, q.dtype)
     G = Hq // Hkv
     out, writers = _run_grid(
-        kernel, name, (R, maxp + n_st),
+        kernel, name, (n_st, R, maxp + n_st),
         [("table", row_tables), ("starts", starts), ("lens", lens),
          ("plens", plens)],
-        [("q", q, (W, Hq, D), stream_map),
-         ("sfx_k", np.asarray(sfx_k), (W, Hkv, D), stream_map),
-         ("sfx_v", np.asarray(sfx_v), (W, Hkv, D), stream_map),
+        [("q", q, (Tq, Hq, D), q_map),
+         ("sfx_k", sfx_k, (Tq, Hkv, D), sfx_map),
+         ("sfx_v", sfx_v, (Tq, Hkv, D), sfx_map),
          ("k_pages", k_pages, (1, ps, Hkv, D), kv_map),
          ("v_pages", np.asarray(v_pages), (1, ps, Hkv, D), kv_map)],
-        ("o", out, (W, Hq, D), stream_map),
-        [np.zeros((Hkv, W * G, D), np.float32),
-         np.full((Hkv, W * G, 128), -1e30, np.float32),
-         np.zeros((Hkv, W * G, 128), np.float32)])
+        ("o", out, (Tq, Hq, D), q_map),
+        [np.zeros((Hkv, Tq * G, D), np.float32),
+         np.full((Hkv, Tq * G, 128), -1e30, np.float32),
+         np.zeros((Hkv, Tq * G, 128), np.float32)])
+    out, writers = out[:n_tok], writers[:n_tok]
     _coverage_rows(name, out, writers, starts, lens)
     return out
 

@@ -120,7 +120,9 @@ def platform_peaks(platform: str, device_kind: str = "") -> Dict[str, float]:
     """{peak_flops, peak_bytes_per_s, ridge_flops_per_byte} for a jax
     platform/device-kind pair. ``SWARMDB_PEAK_FLOPS`` /
     ``SWARMDB_PEAK_BW`` override both columns (heterogeneous fleets,
-    new chips the table predates)."""
+    new chips the table predates). A TPU whose kind is in neither the
+    table nor the overrides is an error: a utilization against the CPU
+    row would be a number about nothing."""
     flops: Optional[float] = None
     bw: Optional[float] = None
     kind = (device_kind or "").lower().replace(" ", "").replace("tpu", "")
@@ -129,10 +131,17 @@ def platform_peaks(platform: str, device_kind: str = "") -> Dict[str, float]:
             if key in kind:
                 flops, bw = f, b
                 break
-    if flops is None:
-        flops, bw = _CPU_PEAK_FLOPS, _CPU_PEAK_BW
     flops = _env_float("SWARMDB_PEAK_FLOPS", flops)
     bw = _env_float("SWARMDB_PEAK_BW", bw)
+    if platform == "tpu" and (flops is None or bw is None):
+        raise ValueError(
+            f"no peak FLOP/s and bytes/s known for TPU device kind "
+            f"{device_kind!r}: add it to _PLATFORM_PEAKS or set "
+            f"SWARMDB_PEAK_FLOPS and SWARMDB_PEAK_BW")
+    if flops is None:
+        flops = _CPU_PEAK_FLOPS
+    if bw is None:
+        bw = _CPU_PEAK_BW
     return {
         "peak_flops": flops,
         "peak_bytes_per_s": bw,

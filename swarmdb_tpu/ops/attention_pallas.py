@@ -30,14 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-try:  # pltpu is importable on CPU builds of jax as well
-    from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas import tpu as pltpu
 
-    _SMEM = pltpu.SMEM
-    _VMEM = pltpu.VMEM
-except Exception:  # pragma: no cover
-    pltpu = None
-    _SMEM = _VMEM = None
+_SMEM = pltpu.SMEM
 
 
 def _decode_attn_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *,
@@ -92,6 +87,20 @@ def decode_gqa_attention(
     CPU for tests (pallas interpreter)."""
     B, Hq, D = q.shape
     S, Hkv = cache_k.shape[1], cache_k.shape[2]
+    # the kernel holds a slot's WHOLE K and V lanes in VMEM: two
+    # double-buffered input blocks plus their f32 copies. Refuse here, at
+    # trace time, what the chip's compiler refuses after seconds of
+    # work (v5e, 8 kv heads x 128: S=512 compiles, S=1024 is 16.32 MB
+    # against the 16 MiB scoped limit).
+    from ..analysis.kernelcheck import vmem_budget
+
+    lanes = S * Hkv * D * (4 * cache_k.dtype.itemsize + 8)
+    if lanes >= vmem_budget():
+        raise ValueError(
+            f"decode_gqa_attention keeps whole KV lanes in VMEM: S={S} x "
+            f"{Hkv} kv heads x {D} needs {lanes} bytes, the limit is "
+            f"{vmem_budget()}; use the chunked or paged kernel "
+            f"(SWARMDB_CHUNKED unset) or unset SWARMDB_PALLAS")
 
     grid = (B,)
     return pl.pallas_call(
@@ -333,113 +342,150 @@ def paged_decode_gqa_attention_chunked(
 # One packed token STREAM per admission wave: the engine concatenates the
 # wave's rows back to back (no per-row bucket padding) and describes them
 # with per-row ``(start, len, prefix_len)`` descriptors that ride as
-# scalar-prefetch operands (SMEM). Grid (R, maxp + n_suffix_tiles): grid
+# scalar-prefetch operands (SMEM). Grid (nQ, R, maxp + n_st): the stream is
+# cut into nQ query blocks of ``tile`` tokens; for query block ``qb``, grid
 # row ``r`` streams row r's PREFIX pages straight out of the page pool via
 # the page table (no ``paged_gather_kv`` densification — the dead-iteration
 # DMA-skip trick from the decode kernels bounds HBM traffic at live pages),
-# then the packed suffix K/V in [tile]-token slices, all folded into one
+# then the packed suffix K/V in [tile]-token blocks, all folded into one
 # online softmax (`_online_update`, the same machinery the decode kernels
 # use). Causality inside the stream is POSITIONAL: rows are contiguous, so
 # "key index <= query index within the same row" is exactly causal order
 # and no per-token position array is needed in the kernel.
 #
-# v1 keeps the whole packed stream (q, suffix K/V, fp32 accumulators)
-# VMEM-resident — right-sized for serving waves up to a few hundred tokens
-# at repro-scale models; production-scale head counts want a query-axis
-# block loop on top (noted in ROADMAP). Per grid row the kernel computes
-# scores for every stream query against that row's KV and discards the
-# foreign rows' results at the masked finalize write — wasted MACs scale
-# with R, but the HBM story (pages read once, in place) is what the gather
-# fallback cannot do.
+# VMEM holds ONE query block (q, out, fp32 accumulators for all heads) and
+# one suffix K/V block at a time, so the footprint is that of a
+# ``tile``-token wave whatever the stream width: at Llama-3-8B heads
+# (32 q / 8 kv, head_dim 128) a 128-token block is what fits v5e's default
+# 16 MiB scoped-VMEM limit (whole-stream residency was refused by the
+# chip's compiler from W=256 up). A (qb, r) pair whose row does not touch
+# the query block skips every step (compute under ``pl.when``, page DMA
+# re-pointed at one page) — what is left of it is grid-step overhead.
+
+
+def _ragged_row_meets_block(q0, n_q, start, ln):
+    """Whether stream row [start, start+ln) has a token in the query
+    block [q0, q0+n_q)."""
+    return (ln > 0) & (start < q0 + n_q) & (start + ln > q0)
+
+
+def _ragged_suffix_tile(t, q0, n_q, start, ln, tile):
+    """(tile index, whether it is live) for suffix step ``t`` of a row
+    seen from the query block at ``q0``: tiles run from the row's first
+    to its last, cut at the block's own (causality — later keys are
+    masked for every query in it). Steps past the last, and the page
+    steps before the first (t < 0), re-point at a live tile, so their
+    DMA is skipped. Truncating ``lax.div`` on non-negative numerators,
+    as in `_last_live_page`."""
+    first = jax.lax.div(start, jnp.int32(tile))
+    last = jax.lax.div(
+        jax.lax.max(jnp.minimum(start + ln, q0 + n_q) - 1, 0),
+        jnp.int32(tile))
+    step = first + jax.lax.max(t, 0)
+    return jnp.minimum(step, last), step <= last
+
+
+def _ragged_fold(q_ref, k, v, valid, n_kv_heads, acc_ref, m_ref, l_ref):
+    """Fold one KV tile (k/v [Tk, Hkv, D] f32, valid [Wq*G, Tk]) into the
+    query block's online-softmax state; score rows are (w, g) pairs,
+    w-major — matching q.reshape(Wq, Hkv, G, D)."""
+    Wq, Hq, D = q_ref.shape
+    G = Hq // n_kv_heads
+    scale = 1.0 / (D ** 0.5)
+    q = q_ref[...].reshape(Wq, n_kv_heads, G, D).astype(jnp.float32)
+    for h in range(n_kv_heads):
+        s = jax.lax.dot_general(
+            q[:, h].reshape(Wq * G, D), k[:, h, :],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        ) * scale                                      # [Wq*G, Tk]
+        _online_update(h, jnp.where(valid, s, -1e30), v[:, h, :],
+                       acc_ref, m_ref, l_ref)
 
 
 def _ragged_prefill_kernel(table_ref, starts_ref, lens_ref, plens_ref,
                            q_ref, sk_ref, sv_ref, kp_ref, vp_ref, o_ref,
                            acc_ref, m_ref, l_ref, *, page_size: int,
-                           n_kv_heads: int, n_pages: int, tile: int,
-                           window):
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-    n_steps = pl.num_programs(1)
-    W, Hq, D = q_ref.shape
+                           n_kv_heads: int, n_pages: int, window):
+    qb = pl.program_id(0)
+    r = pl.program_id(1)
+    j = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    Wq, Hq, D = q_ref.shape
+    tile = sk_ref.shape[0]
     Hkv = n_kv_heads
     G = Hq // Hkv
     ps = page_size
     start = starts_ref[r]
     ln = lens_ref[r]
     plen = plens_ref[r]
-    scale = 1.0 / (D ** 0.5)
+    q0 = qb * Wq
+    meets = _ragged_row_meets_block(q0, Wq, start, ln)
 
-    @pl.when(j == 0)
+    @pl.when((r == 0) & (j == 0))
+    def _zero_out():
+        # the query block's output is revisited by every grid row (index
+        # map constant in r, j) and finalized with a masked write per
+        # row — positions no row owns (stream padding) stay zero
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(meets & (j == 0))
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when((r == 0) & (j == 0))
-    def _zero_out():
-        # the output block is revisited by every grid row (constant index
-        # map) and finalized with a masked write per row — positions no
-        # row owns (none when the stream is packed dense) stay zero
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    # stream index of each score row (score rows are (w, g) pairs,
-    # w-major — matching q.reshape(W, Hkv, G, D))
-    wq = jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (W * G, 1), 0), jnp.int32(G))
+    # stream index of each score row
+    wq = q0 + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (Wq * G, 1), 0), jnp.int32(G))
     q_abs = plen + wq - start    # absolute position of query w IN ROW r
 
-    def fold(k_tile, v_tile, valid):
-        # k_tile/v_tile [Tk, Hkv, D]; valid [W*G, Tk]
-        q = q_ref[...].reshape(W, Hkv, G, D).astype(jnp.float32)
-        k = k_tile.astype(jnp.float32)
-        v = v_tile.astype(jnp.float32)
-        for h in range(Hkv):
-            qh = q[:, h].reshape(W * G, D)
-            s = jax.lax.dot_general(
-                qh, k[:, h, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale                                  # [W*G, Tk]
-            _online_update(h, jnp.where(valid, s, -1e30), v[:, h, :],
-                           acc_ref, m_ref, l_ref)
-
-    @pl.when((j < n_pages) & (j * ps < plen))
+    @pl.when(meets & (j < n_pages) & (j * ps < plen))
     def _prefix():
         kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         valid = kpos < plen
         if window is not None:
             valid &= kpos > (q_abs - window)
-        fold(kp_ref[0], vp_ref[0], jnp.broadcast_to(valid, (W * G, ps)))
+        _ragged_fold(q_ref, kp_ref[0].astype(jnp.float32),
+                     vp_ref[0].astype(jnp.float32),
+                     jnp.broadcast_to(valid, (Wq * G, ps)), Hkv,
+                     acc_ref, m_ref, l_ref)
 
-    @pl.when((j >= n_pages) & (ln > 0))
+    @pl.when(meets & (j >= n_pages))
     def _suffix():
-        t = j - n_pages
-        first = jax.lax.div(start, jnp.int32(tile))
-        last = jax.lax.div(start + ln - 1, jnp.int32(tile))
-        tt = first + t
+        tt, live = _ragged_suffix_tile(j - n_pages, q0, Wq, start, ln, tile)
 
-        @pl.when(tt <= last)
+        @pl.when(live)
         def _live():
-            # dynamic [tile]-slice of the resident packed K/V; the slice
-            # start clamps to W - tile, so the anti-overlap term
-            # (x >= tt*tile) keeps a clamped tail tile from re-folding
-            # keys the previous tile already saw
-            s0 = jnp.minimum(tt * tile, jnp.int32(W - tile))
-            x = s0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-            valid = ((x >= tt * tile) & (x >= start) & (x < start + ln)
-                     & (x <= wq))
+            x = tt * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile), 1)
+            valid = (x >= start) & (x < start + ln) & (x <= wq)
             if window is not None:
                 valid &= x > (wq - window)
-            fold(sk_ref[pl.ds(s0, tile)], sv_ref[pl.ds(s0, tile)], valid)
+            _ragged_fold(q_ref, sk_ref[...].astype(jnp.float32),
+                         sv_ref[...].astype(jnp.float32), valid, Hkv,
+                         acc_ref, m_ref, l_ref)
 
-    @pl.when(j == n_steps - 1)
+    @pl.when(meets & (j == n_steps - 1))
     def _finalize():
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)    # [Hkv, W*G, 1]
-        out = (acc_ref[...] / denom).reshape(Hkv, W, G, D)
-        out = out.transpose(1, 0, 2, 3).reshape(W, Hq, D)
-        w_iota = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
+        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)    # [Hkv, Wq*G, 1]
+        out = (acc_ref[...] / denom).reshape(Hkv, Wq, G, D)
+        out = out.transpose(1, 0, 2, 3).reshape(Wq, Hq, D)
+        w_iota = q0 + jax.lax.broadcasted_iota(jnp.int32, (Wq, 1, 1), 0)
         mine = (w_iota >= start) & (w_iota < start + ln)
         o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
+
+
+def _pad_stream(tile, *streams):
+    """Zero-pad packed [W, H, D] streams along W to whole blocks: a
+    multiple of ``tile``, or of the 8-row sublane quantum for a stream
+    shorter than one tile. Returns the padded streams."""
+    n = streams[0].shape[0]
+    blk = min(tile, -(-n // 8) * 8)
+    pad = (-n) % blk
+    if not pad:
+        return streams
+    return tuple(jnp.pad(s, ((0, pad), (0, 0), (0, 0))) for s in streams)
 
 
 @functools.partial(jax.jit, static_argnames=("window", "tile", "interpret"))
@@ -458,56 +504,69 @@ def ragged_paged_prefill_attention(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Ragged paged prefill attention over a packed wave; returns
-    [W, Hq, D] in q.dtype (positions outside every row are zero)."""
+    [W, Hq, D] in q.dtype (positions outside every row are zero).
+    ``tile`` is both the query block and the suffix K/V block."""
+    n_tok = q.shape[0]
+    q, sfx_k, sfx_v = _pad_stream(tile, q, sfx_k, sfx_v)
     W, Hq, D = q.shape
     _, ps, Hkv, _ = k_pages.shape
     R, maxp = row_tables.shape
     G = Hq // Hkv
-    Tk = min(tile, W)
-    n_st = -(-W // Tk)
+    Tq = min(tile, W)         # W is a whole number of blocks
+    n_st = W // Tq
     table = row_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
     plens = prefix_lens.astype(jnp.int32)
 
-    def stream_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        return (0, 0, 0)
+    def q_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        return (qb, 0, 0)
 
-    def kv_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
+    def sfx_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        tt, _ = _ragged_suffix_tile(j - maxp, qb * Tq, Tq, starts_ref[r],
+                                    lens_ref[r], Tq)
+        return (tt, 0, 0)
+
+    def kv_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
         # dead page iterations AND every suffix-tile iteration re-point at
-        # the last live prefix page, so their DMA is skipped; empty prefix
-        # -> table[r, 0] (trash page 0 for fresh rows)
+        # the last live prefix page, so their DMA is skipped; a row that
+        # misses the query block (and an empty prefix) -> table[r, 0]
+        # (trash page 0 for fresh rows)
+        meets = _ragged_row_meets_block(qb * Tq, Tq, starts_ref[r],
+                                        lens_ref[r])
         last_live = _last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.minimum(j, last_live)], 0, 0, 0)
+        return (table_ref[r, jnp.where(meets, jnp.minimum(j, last_live),
+                                       0)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(R, maxp + n_st),
+        grid=(n_st, R, maxp + n_st),
         in_specs=[
-            pl.BlockSpec((W, Hq, D), stream_map),
-            pl.BlockSpec((W, Hkv, D), stream_map),
-            pl.BlockSpec((W, Hkv, D), stream_map),
+            pl.BlockSpec((Tq, Hq, D), q_map),
+            pl.BlockSpec((Tq, Hkv, D), sfx_map),
+            pl.BlockSpec((Tq, Hkv, D), sfx_map),
             pl.BlockSpec((1, ps, Hkv, D), kv_map),
             pl.BlockSpec((1, ps, Hkv, D), kv_map),
         ],
-        # swarmlint: revisit[r] -- every (r, j) step accumulates into the
-        # one stream-resident output block; the masked finalize under
-        # pl.when(j == n_steps - 1) writes each row's lanes exactly once
-        out_specs=pl.BlockSpec((W, Hq, D), stream_map),
+        # swarmlint: revisit[r] -- every (r, j) step of a query block
+        # accumulates into its one resident output block; the masked
+        # finalize under pl.when(j == n_steps - 1) writes each row's
+        # lanes exactly once
+        out_specs=pl.BlockSpec((Tq, Hq, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, W * G, D), jnp.float32),    # acc
-            pltpu.VMEM((Hkv, W * G, 128), jnp.float32),  # running max
-            pltpu.VMEM((Hkv, W * G, 128), jnp.float32),  # running denom
+            pltpu.VMEM((Hkv, Tq * G, D), jnp.float32),    # acc
+            pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running max
+            pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running denom
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ragged_prefill_kernel, page_size=ps,
-                          n_kv_heads=Hkv, n_pages=maxp, tile=Tk,
-                          window=window),
+                          n_kv_heads=Hkv, n_pages=maxp, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((W, Hq, D), q.dtype),
         interpret=interpret,
     )(table, starts, lens, plens, q, sfx_k, sfx_v, k_pages, v_pages)
+    return out[:n_tok]
 
 
 def _dense_chunk_attn_kernel(start_ref, step_ref, q_ref, k_ref, v_ref,
@@ -910,51 +969,41 @@ def _ragged_prefill_kernel_quant(table_ref, starts_ref, lens_ref,
                                  plens_ref, q_ref, sk_ref, sv_ref, kp_ref,
                                  kps_ref, vp_ref, vps_ref, o_ref, acc_ref,
                                  m_ref, l_ref, *, page_size: int,
-                                 n_kv_heads: int, n_pages: int, tile: int,
-                                 window):
+                                 n_kv_heads: int, n_pages: int, window):
     """Quantized ragged prefill: int8 PREFIX pages dequantize per page
     tile; the packed suffix stream (this wave's own K/V, not yet
-    pool-resident) stays full precision."""
-    r = pl.program_id(0)
-    j = pl.program_id(1)
-    n_steps = pl.num_programs(1)
-    W, Hq, D = q_ref.shape
+    pool-resident) stays full precision. Same grid, blocks and masks as
+    `_ragged_prefill_kernel`."""
+    qb = pl.program_id(0)
+    r = pl.program_id(1)
+    j = pl.program_id(2)
+    n_steps = pl.num_programs(2)
+    Wq, Hq, D = q_ref.shape
+    tile = sk_ref.shape[0]
     Hkv = n_kv_heads
     G = Hq // Hkv
     ps = page_size
     start = starts_ref[r]
     ln = lens_ref[r]
     plen = plens_ref[r]
-    scale = 1.0 / (D ** 0.5)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    q0 = qb * Wq
+    meets = _ragged_row_meets_block(q0, Wq, start, ln)
 
     @pl.when((r == 0) & (j == 0))
     def _zero_out():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    wq = jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (W * G, 1), 0), jnp.int32(G))
+    @pl.when(meets & (j == 0))
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    wq = q0 + jax.lax.div(
+        jax.lax.broadcasted_iota(jnp.int32, (Wq * G, 1), 0), jnp.int32(G))
     q_abs = plen + wq - start
 
-    def fold(k_tile, v_tile, valid):
-        q = q_ref[...].reshape(W, Hkv, G, D).astype(jnp.float32)
-        k = k_tile.astype(jnp.float32)
-        v = v_tile.astype(jnp.float32)
-        for h in range(Hkv):
-            qh = q[:, h].reshape(W * G, D)
-            s = jax.lax.dot_general(
-                qh, k[:, h, :], (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32,
-            ) * scale
-            _online_update(h, jnp.where(valid, s, -1e30), v[:, h, :],
-                           acc_ref, m_ref, l_ref)
-
-    @pl.when((j < n_pages) & (j * ps < plen))
+    @pl.when(meets & (j < n_pages) & (j * ps < plen))
     def _prefix():
         kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
         valid = kpos < plen
@@ -962,31 +1011,30 @@ def _ragged_prefill_kernel_quant(table_ref, starts_ref, lens_ref,
             valid &= kpos > (q_abs - window)
         kd = kp_ref[0].astype(jnp.float32) * kps_ref[...].reshape(1, Hkv, 1)
         vd = vp_ref[0].astype(jnp.float32) * vps_ref[...].reshape(1, Hkv, 1)
-        fold(kd, vd, jnp.broadcast_to(valid, (W * G, ps)))
+        _ragged_fold(q_ref, kd, vd, jnp.broadcast_to(valid, (Wq * G, ps)),
+                     Hkv, acc_ref, m_ref, l_ref)
 
-    @pl.when((j >= n_pages) & (ln > 0))
+    @pl.when(meets & (j >= n_pages))
     def _suffix():
-        t = j - n_pages
-        first = jax.lax.div(start, jnp.int32(tile))
-        last = jax.lax.div(start + ln - 1, jnp.int32(tile))
-        tt = first + t
+        tt, live = _ragged_suffix_tile(j - n_pages, q0, Wq, start, ln, tile)
 
-        @pl.when(tt <= last)
+        @pl.when(live)
         def _live():
-            s0 = jnp.minimum(tt * tile, jnp.int32(W - tile))
-            x = s0 + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-            valid = ((x >= tt * tile) & (x >= start) & (x < start + ln)
-                     & (x <= wq))
+            x = tt * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile), 1)
+            valid = (x >= start) & (x < start + ln) & (x <= wq)
             if window is not None:
                 valid &= x > (wq - window)
-            fold(sk_ref[pl.ds(s0, tile)], sv_ref[pl.ds(s0, tile)], valid)
+            _ragged_fold(q_ref, sk_ref[...].astype(jnp.float32),
+                         sv_ref[...].astype(jnp.float32), valid, Hkv,
+                         acc_ref, m_ref, l_ref)
 
-    @pl.when(j == n_steps - 1)
+    @pl.when(meets & (j == n_steps - 1))
     def _finalize():
         denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        out = (acc_ref[...] / denom).reshape(Hkv, W, G, D)
-        out = out.transpose(1, 0, 2, 3).reshape(W, Hq, D)
-        w_iota = jax.lax.broadcasted_iota(jnp.int32, (W, 1, 1), 0)
+        out = (acc_ref[...] / denom).reshape(Hkv, Wq, G, D)
+        out = out.transpose(1, 0, 2, 3).reshape(Wq, Hq, D)
+        w_iota = q0 + jax.lax.broadcasted_iota(jnp.int32, (Wq, 1, 1), 0)
         mine = (w_iota >= start) & (w_iota < start + ln)
         o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
 
@@ -1009,12 +1057,14 @@ def ragged_paged_prefill_attention_quant(
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Quantized ragged paged prefill attention; returns [W, Hq, D]."""
+    n_tok = q.shape[0]
+    q, sfx_k, sfx_v = _pad_stream(tile, q, sfx_k, sfx_v)
     W, Hq, D = q.shape
     P, ps, Hkv, _ = k_pages.shape
     R, maxp = row_tables.shape
     G = Hq // Hkv
-    Tk = min(tile, W)
-    n_st = -(-W // Tk)
+    Tq = min(tile, W)         # W is a whole number of blocks
+    n_st = W // Tq
     table = row_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
@@ -1022,45 +1072,57 @@ def ragged_paged_prefill_attention_quant(
     ks3 = k_scale.reshape(P, 1, Hkv)
     vs3 = v_scale.reshape(P, 1, Hkv)
 
-    def stream_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        return (0, 0, 0)
+    def q_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        return (qb, 0, 0)
 
-    def kv_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        last_live = _last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.minimum(j, last_live)], 0, 0, 0)
+    def sfx_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        tt, _ = _ragged_suffix_tile(j - maxp, qb * Tq, Tq, starts_ref[r],
+                                    lens_ref[r], Tq)
+        return (tt, 0, 0)
 
-    def sc_map(r, j, table_ref, starts_ref, lens_ref, plens_ref):
+    def page_of(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        meets = _ragged_row_meets_block(qb * Tq, Tq, starts_ref[r],
+                                        lens_ref[r])
         last_live = _last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.minimum(j, last_live)], 0, 0)
+        return table_ref[r, jnp.where(meets, jnp.minimum(j, last_live), 0)]
+
+    def kv_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        return (page_of(qb, r, j, table_ref, starts_ref, lens_ref,
+                        plens_ref), 0, 0, 0)
+
+    def sc_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+        return (page_of(qb, r, j, table_ref, starts_ref, lens_ref,
+                        plens_ref), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(R, maxp + n_st),
+        grid=(n_st, R, maxp + n_st),
         in_specs=[
-            pl.BlockSpec((W, Hq, D), stream_map),
-            pl.BlockSpec((W, Hkv, D), stream_map),
-            pl.BlockSpec((W, Hkv, D), stream_map),
+            pl.BlockSpec((Tq, Hq, D), q_map),
+            pl.BlockSpec((Tq, Hkv, D), sfx_map),
+            pl.BlockSpec((Tq, Hkv, D), sfx_map),
             pl.BlockSpec((1, ps, Hkv, D), kv_map),
             pl.BlockSpec((1, 1, Hkv), sc_map),
             pl.BlockSpec((1, ps, Hkv, D), kv_map),
             pl.BlockSpec((1, 1, Hkv), sc_map),
         ],
-        # swarmlint: revisit[r] -- every (r, j) step accumulates into the
-        # one stream-resident output block; the masked finalize under
-        # pl.when(j == n_steps - 1) writes each row's lanes exactly once
-        out_specs=pl.BlockSpec((W, Hq, D), stream_map),
+        # swarmlint: revisit[r] -- every (r, j) step of a query block
+        # accumulates into its one resident output block; the masked
+        # finalize under pl.when(j == n_steps - 1) writes each row's
+        # lanes exactly once
+        out_specs=pl.BlockSpec((Tq, Hq, D), q_map),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, W * G, D), jnp.float32),    # acc
-            pltpu.VMEM((Hkv, W * G, 128), jnp.float32),  # running max
-            pltpu.VMEM((Hkv, W * G, 128), jnp.float32),  # running denom
+            pltpu.VMEM((Hkv, Tq * G, D), jnp.float32),    # acc
+            pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running max
+            pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running denom
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_ragged_prefill_kernel_quant, page_size=ps,
-                          n_kv_heads=Hkv, n_pages=maxp, tile=Tk,
-                          window=window),
+                          n_kv_heads=Hkv, n_pages=maxp, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((W, Hq, D), q.dtype),
         interpret=interpret,
     )(table, starts, lens, plens, q, sfx_k, sfx_v,
       k_pages, ks3, v_pages, vs3)
+    return out[:n_tok]

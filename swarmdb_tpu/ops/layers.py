@@ -355,36 +355,31 @@ def ragged_prefill_dispatch(
 
         quant = is_quantized(k_pages)
         W = q.shape[0]
-        pad = (-W) % 8                 # TPU sublane quantum for tiny waves
+        # the wrappers pad the stream to whole blocks (8-row sublane
+        # quantum below one tile); tile 128 is their default
         _record_static_vmem(
             "_ragged_prefill_kernel_quant" if quant
             else "_ragged_prefill_kernel",
             f"prefill.ragged[w{W}]",
-            {"W": W + pad, "Hq": q.shape[1], "Hkv": sfx_k.shape[1],
-             "D": q.shape[2], "ps": pool_data(k_pages).shape[1]})
-        if pad:
-            grow = ((0, pad), (0, 0), (0, 0))
-            q = jnp.pad(q, grow)
-            sfx_k = jnp.pad(sfx_k, grow)
-            sfx_v = jnp.pad(sfx_v, grow)
+            {"W": W + (-W) % 8, "tile": 128, "Hq": q.shape[1],
+             "Hkv": sfx_k.shape[1], "D": q.shape[2],
+             "ps": pool_data(k_pages).shape[1]})
         interp = jax.default_backend() != "tpu"
         if quant:
             from .attention_pallas import (
                 ragged_paged_prefill_attention_quant)
 
-            out = ragged_paged_prefill_attention_quant(
+            return ragged_paged_prefill_attention_quant(
                 q, sfx_k, sfx_v, k_pages.data, k_pages.scale,
                 v_pages.data, v_pages.scale, row_tables, starts, lens,
                 prefix_lens, window=window, interpret=interp,
             )
-        else:
-            from .attention_pallas import ragged_paged_prefill_attention
+        from .attention_pallas import ragged_paged_prefill_attention
 
-            out = ragged_paged_prefill_attention(
-                q, sfx_k, sfx_v, k_pages, v_pages, row_tables, starts,
-                lens, prefix_lens, window=window, interpret=interp,
-            )
-        return out[:W] if pad else out
+        return ragged_paged_prefill_attention(
+            q, sfx_k, sfx_v, k_pages, v_pages, row_tables, starts,
+            lens, prefix_lens, window=window, interpret=interp,
+        )
     return ragged_prefill_attention_reference(
         q, sfx_k, sfx_v, k_pages, v_pages, row_tables, starts, lens,
         prefix_lens, tok_row, window=window)

@@ -466,10 +466,10 @@ def build_lane_group(
     """One paged single-device engine per mesh ``data`` device.
 
     Each lane's eager state (params, pools, PRNG keys, fed-token
-    vectors) is built under ``jax.default_device(dev)``, so every jit
-    the lane ever dispatches runs on ITS device — the per-shard
-    admission overlap is then a property of the device streams, not of
-    scheduler luck. Params are replicated across lanes (the definition
+    vectors) is built under ``jax.default_device(dev)`` and then
+    COMMITTED to it (``Engine.pin_to_device``), so every jit the lane
+    ever dispatches runs on ITS device — the per-shard admission overlap
+    is then a property of the device streams, not of scheduler luck. Params are replicated across lanes (the definition
     of data parallelism); pools and prefix caches split N ways, same
     aggregate budget as the sharded pool."""
     from ..backend.service import build_backend_engine
@@ -518,7 +518,7 @@ def build_lane_group(
                 prefill_batch=prefill_batch, metrics=metrics,
                 flight_dir=flight_dir,
             )
-        eng._home_device = dev
+        eng.pin_to_device(dev)
         # page sanitizer (SWARMDB_PAGECHECK=1): label the lane's pool so
         # aliasing reports and the per-lane churn counters name lanes
         pagecheck = getattr(eng.paged.allocator, "pagecheck", None)

@@ -229,16 +229,9 @@ def build_sharded_paged(
     conversation's pages pin it to one shard; the serving layer disables
     rolling when it sees a sharded allocator).
     """
-    try:
-        # jax >= 0.8: check_vma replaces the old check_rep knob (off: the
-        # bodies are intentionally per-shard — nothing is replicated)
-        from jax import shard_map as _smap
-
-        def shard_map(f, mesh, in_specs, out_specs, check_rep=False):
-            return _smap(f, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_vma=check_rep)
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    # check_vma off: the bodies are intentionally per-shard — nothing is
+    # replicated
+    shard_map = partial(jax.shard_map, check_vma=False)
 
     from ..ops.layers import pallas_disabled
     from ..ops.paged_kv import (init_paged_kv_cache, kv_quantized,
@@ -300,7 +293,6 @@ def build_sharded_paged(
         _decode_body, mesh=mesh,
         in_specs=(params_specs, TOKEN_SPEC, TOKEN_SPEC, PAGED_CACHE_SPECS),
         out_specs=(P("data", None, None), PAGED_CACHE_SPECS),
-        check_rep=False,
     )
 
     def _chunk_body(p, t, pos, c, chunk_kv, step):
@@ -315,7 +307,6 @@ def build_sharded_paged(
         in_specs=(params_specs, TOKEN_SPEC, TOKEN_SPEC, PAGED_CACHE_SPECS,
                   (CHUNK_KV_SPEC, CHUNK_KV_SPEC), P()),
         out_specs=(P("data", None, None), (CHUNK_KV_SPEC, CHUNK_KV_SPEC)),
-        check_rep=False,
     )
 
     def _merge_body(c, chunk_kv, starts):
@@ -329,7 +320,6 @@ def build_sharded_paged(
         in_specs=(PAGED_CACHE_SPECS, (CHUNK_KV_SPEC, CHUNK_KV_SPEC),
                   P("data")),
         out_specs=PAGED_CACHE_SPECS,
-        check_rep=False,
     )
 
     chunk_sharding = NamedSharding(mesh, CHUNK_KV_SPEC)
@@ -420,7 +410,6 @@ def build_sharded_paged(
                   PAGED_POOL_SPEC, P("data"), P("data"), P("data", None),
                   P("data"), P("data"), P("data")),
         out_specs=(PAGED_POOL_SPEC, PAGED_POOL_SPEC, P("data"), P("data")),
-        check_rep=False,
     )
 
     from ..backend.engine import PagedKV

@@ -1,26 +1,37 @@
 """Persistent XLA compilation cache wiring.
 
-The big-model jit variants (decode chunk, per-bucket prefills) each cost
-10-30 s of XLA compile on first use. JAX's persistent compilation cache
-stores the compiled executables on disk keyed by HLO hash, so every
-process after the first (API server restarts, each bench mode, the
-driver's scheduled run) deserializes instead of recompiling — measured on
-this image's TPU backend, a cold 11 s compile becomes sub-second.
+The big-model jit variants (decode chunk, per-rung prefills) each cost a
+quarter of a minute to a minute and a half of XLA compile on first use.
+JAX's persistent compilation cache stores the compiled executables on
+disk keyed by HLO hash, so every process after the first (API server
+restarts, each bench mode, a second ``chip_smoke.py``) deserializes
+instead of recompiling.
 
-Opt-in via env (SWARMDB_COMPILE_CACHE=<dir>) or an explicit path; the
-bench enables it by default. The reference has no compile step at all
-(SURVEY §2.4 — no model code), so there is no counterpart knob.
+The directory is placed from OUTSIDE the program: where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX itself took it at import and
+nothing here touches it; otherwise the cache lives in ``.jax_cache``
+beside the package (the checkout root — the path is part of the cache
+key, so a directory that moves never hits). A program turns the cache
+on once, where it starts (``api/server.py`` ``main``, ``chip_smoke.py``,
+``bench.py``); library code never does, so the test suite's engines do
+not all write one directory from several workers.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-from typing import Optional
 
 logger = logging.getLogger("swarmdb_tpu.xla_cache")
 
-_ENABLED_DIR: Optional[str] = None
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache``, derived from the package's location."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    return os.path.join(root, ".jax_cache")
 
 
 def persistent_cache_programs(path: str) -> set:
@@ -39,40 +50,26 @@ def persistent_cache_programs(path: str) -> set:
     return {n.rsplit("-", 1)[0] for n in names}
 
 
-def enable_compile_cache(path: Optional[str] = None) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``path`` (or the
-    SWARMDB_COMPILE_CACHE env var). Returns the directory in effect, or
-    None when unconfigured. Idempotent; safe to call before or after the
-    backend initializes."""
-    global _ENABLED_DIR
-    path = path or os.environ.get("SWARMDB_COMPILE_CACHE")
-    if not path:
-        return _ENABLED_DIR
-    if _ENABLED_DIR == path:
-        return path
+def enable_compile_cache() -> str:
+    """Turn JAX's persistent compilation cache on for this process and
+    return the directory in effect: ``JAX_COMPILATION_CACHE_DIR`` when
+    set (left exactly as JAX read it), else :func:`default_cache_dir`.
+    Idempotent; a directory that cannot be made is an error."""
     import jax
 
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        # cache everything that took meaningful compile time; the tiny
-        # helper jits (health probe, token scatter) stay out of the cache
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        # jax pins the cache object to the dir in effect at FIRST use; a
-        # later config update alone is silently ignored. The dir may have
-        # been pinned by anyone (env var, direct config update, an earlier
-        # call here), so reset unconditionally — a no-op when nothing is
-        # pinned yet
-        try:
-            from jax._src import compilation_cache as _cc
+    path = os.environ.get(_ENV)
+    if not path:
+        path = default_cache_dir()
+        if jax.config.jax_compilation_cache_dir != path:
+            from jax.experimental.compilation_cache import compilation_cache
 
-            _cc.reset_cache()
-        except Exception:  # noqa: BLE001 — private API, best effort
-            logger.warning("could not reset pinned compilation cache; "
-                           "new dir %s may not take effect", path)
-        _ENABLED_DIR = path
-        logger.info("persistent XLA compilation cache at %s", path)
-    except Exception:  # noqa: BLE001 — cache is an optimization, not a dep
-        logger.exception("failed to enable compilation cache at %s", path)
-        return None
-    return _ENABLED_DIR
+            os.makedirs(path, exist_ok=True)
+            jax.config.update("jax_compilation_cache_dir", path)
+            # jax pins the cache object to the dir in effect at FIRST
+            # use; a later config update alone is silently ignored
+            compilation_cache.reset_cache()
+    # cache everything that took meaningful compile time; the tiny helper
+    # jits (health probe, token scatter) stay out of the cache
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    logger.info("persistent XLA compilation cache at %s", path)
+    return path

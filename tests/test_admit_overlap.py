@@ -82,6 +82,33 @@ def test_lanes_generate_identical_greedy_tokens(group):
     assert outs[0] == outs[1] == outs[2], outs
 
 
+def test_lanes_compute_on_their_own_device(group):
+    """A lane's programs run on the lane's device, not the process
+    default: after traffic, what the programs WROTE (pool, fed-token
+    vector) still lives, committed, on the device the weights are on.
+    Arrays merely created under ``jax.default_device`` are uncommitted,
+    and jit then computes every lane on device 0 — which virtual CPU
+    devices tolerate and four real chips refuse (out of memory on the
+    first; PR 22's four-chip run)."""
+    mesh_devices = list(group.info.mesh.devices.flat)
+    for hint, lane in enumerate(group.lanes):
+        done = threading.Event()
+        group.submit(GenRequest(
+            prompt=[1, 5, 9, 13], sampling=SamplingParams(max_new_tokens=6),
+            on_done=lambda *_a, _d=done: _d.set(), shard_hint=hint))
+        assert done.wait(120)
+        for _attempt in range(50):
+            state = (lane.params, lane.cache, lane._last_tokens,
+                     lane._last_lps)
+            try:
+                placed = [(leaf.committed, leaf.devices())
+                          for leaf in jax.tree_util.tree_leaves(state)]
+                break
+            except RuntimeError:    # the lane's loop just donated one
+                time.sleep(0.05)
+        assert placed == [(True, {mesh_devices[hint]})] * len(placed), hint
+
+
 def test_shard_hint_routes_to_lane(group):
     before = [e.total_requests for e in group.lanes]
     done = threading.Event()

@@ -78,7 +78,7 @@ def test_static_vmem_table_covers_in_tree_kernels():
 
 
 def test_estimate_vmem_concrete_and_unbound():
-    dims = {"W": 64, "Hq": 8, "Hkv": 2, "D": 64, "ps": 16}
+    dims = {"W": 64, "tile": 128, "Hq": 8, "Hkv": 2, "D": 64, "ps": 16}
     est = estimate_vmem("_ragged_prefill_kernel", dims)
     assert isinstance(est, int) and est > 0
     # unbound dims -> no estimate, never an error
@@ -179,16 +179,15 @@ def test_canary_fires_on_seeded_short_write(kerncheck_on, tmp_path):
      _tok_row) = kerncheck_on._random_ragged_case(rng)
     ps = np.asarray(kp).shape[1]
     maxp = np.asarray(tables).shape[1]
-    W = np.asarray(q).shape[0]
     live_r = int(np.nonzero(np.asarray(lens) > 0)[0][0])
     base = functools.partial(
         ap._ragged_prefill_kernel, page_size=ps,
-        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp,
-        tile=min(128, W), window=None)
+        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp, window=None)
 
     def sabotaged(*refs):
-        if (pl.program_id(0) == live_r
-                and pl.program_id(1) == pl.num_programs(1) - 1):
+        # grid (query block, row, step)
+        if (pl.program_id(1) == live_r
+                and pl.program_id(2) == pl.num_programs(2) - 1):
             return          # skip the finalize for this row
         base(*refs)
 
@@ -216,15 +215,13 @@ def test_bounds_wrapper_names_grid_cell(kerncheck_on):
     rng = np.random.default_rng(5)
     (q, sk, sv, kp, vp, tables, starts, lens, plens,
      _tok_row) = kerncheck_on._random_ragged_case(rng)
-    W = np.asarray(q).shape[0]
     base = functools.partial(
         ap._ragged_prefill_kernel, page_size=np.asarray(kp).shape[1],
         n_kv_heads=np.asarray(kp).shape[2],
-        n_pages=np.asarray(tables).shape[1], tile=min(128, W),
-        window=None)
+        n_pages=np.asarray(tables).shape[1], window=None)
 
     def overread(*refs):
-        if pl.program_id(0) == 0 and pl.program_id(1) == 0:
+        if pl.program_id(1) == 0 and pl.program_id(2) == 0:
             q_ref = refs[4]          # after the 4 scalar-prefetch refs
             _ = q_ref[pl.ds(0, q_ref.shape[0] + 4), ...]
         base(*refs)
@@ -235,8 +232,8 @@ def test_bounds_wrapper_names_grid_cell(kerncheck_on):
     assert "oob-ref" in kinds
     v = next(v for v in kerncheck_on.registry().violations()
              if v["kind"] == "oob-ref")
-    assert "grid cell (0, 0)" in v["message"]
-    assert v["where"]["grid"] == [0, 0]
+    assert "grid cell (0, 0, 0)" in v["message"]
+    assert v["where"]["grid"] == [0, 0, 0]
     assert v["rule"] == "SWL901"
 
 
@@ -253,12 +250,10 @@ def test_write_race_on_unmasked_finalize(kerncheck_on):
     rng = np.random.default_rng(11)
     (q, sk, sv, kp, vp, tables, starts, lens, plens,
      _tok_row) = kerncheck_on._random_ragged_case(rng)
-    W = np.asarray(q).shape[0]
     base = functools.partial(
         ap._ragged_prefill_kernel, page_size=np.asarray(kp).shape[1],
         n_kv_heads=np.asarray(kp).shape[2],
-        n_pages=np.asarray(tables).shape[1], tile=min(128, W),
-        window=None)
+        n_pages=np.asarray(tables).shape[1], window=None)
 
     def unmasked(*refs):
         base(*refs)
@@ -267,7 +262,7 @@ def test_write_race_on_unmasked_finalize(kerncheck_on):
         # value that varies by grid row, so later rows overwrite bytes
         # the earlier rows just wrote
         o_ref[...] = jnp.zeros_like(o_ref[...]) + 1.5 * (
-            pl.program_id(0) + 1) + 0.25 * pl.program_id(1)
+            pl.program_id(1) + 1) + 0.25 * pl.program_id(2)
 
     kerncheck_on.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens, kernel=unmasked)
@@ -487,7 +482,7 @@ def test_dispatch_records_vmem_estimate_under_profiler(monkeypatch):
 
     prof = KernelProfiler(enabled=True)
     monkeypatch.setattr(profmod, "_PROFILER", prof, raising=False)
-    dims = {"W": 16, "Hq": 4, "Hkv": 2, "D": 8, "ps": 4}
+    dims = {"W": 16, "tile": 128, "Hq": 4, "Hkv": 2, "D": 8, "ps": 4}
     layers._record_static_vmem("_ragged_prefill_kernel",
                                "prefill.ragged[w16]", dims)
     prof.record_variant("prefill.ragged[w16]", 1.0, 1.0)

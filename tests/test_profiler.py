@@ -255,7 +255,16 @@ def test_platform_peaks_table_and_overrides(monkeypatch):
     assert v5e["ridge_flops_per_byte"] > 1
     cpu = platform_peaks("cpu")
     assert cpu["peak_flops"] < v5e["peak_flops"]
+    # the string a v5e chip reports as its device_kind
+    assert platform_peaks("tpu", "TPU v5 lite") == v5e
+    # a TPU the table does not know is an error, never the CPU row ...
+    with pytest.raises(ValueError, match="weird-chip"):
+        platform_peaks("tpu", "weird-chip")
+    # ... unless the overrides give both columns
     monkeypatch.setenv("SWARMDB_PEAK_FLOPS", "1e15")
+    with pytest.raises(ValueError, match="weird-chip"):
+        platform_peaks("tpu", "weird-chip")
+    monkeypatch.setenv("SWARMDB_PEAK_BW", "1e12")
     assert platform_peaks("tpu", "weird-chip")["peak_flops"] == 1e15
 
 

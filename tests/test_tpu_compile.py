@@ -1,0 +1,125 @@
+"""AOT compiles for a described TPU v5e: what interpret mode cannot see.
+
+The TPU's compiler is installed beside the CPU backend and compiles for
+a chip that is described, not attached. Every Pallas kernel the default
+TPU serving path reaches is compiled here at Llama-3-8B geometry
+(32 query heads, 8 KV heads, head_dim 128, page 16, batch 8, table span
+1024) — the shapes at which the chip's compiler refused the ragged
+prefill kernel from W=256 up and the dense decode kernel at S=1024 while
+every interpret-mode test passed. Nothing runs: a compile that passes
+says nothing about results or times.
+
+The topology is described inside a module-scoped fixture (only the
+worker that runs this file loads the TPU library) and the persistent
+compile cache is off around the compiles (an entry written for a
+described chip cannot be read back without one).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from swarmdb_tpu.ops import attention_pallas as ap
+
+HQ, HKV, D, PS, B, SPAN = 32, 8, 128, 16, 8, 1024
+MAXP = SPAN // PS
+PAGES = 1 + B * MAXP + B * SPAN // 2 // PS   # slots + prefix budget + trash
+KC = 8                                       # decode chunk (server default)
+RUNGS = (8, 16, 32, 64, 128, 256, 512, 1024)
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(chip, fn, *shapes, **static):
+    """Compile ``fn`` for the described chip; the kernel must be in the
+    program as a Mosaic custom call, not interpreted away."""
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=chip) for s, dt in shapes]
+    compiled = jax.jit(lambda *a: fn(*a, **static)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+BF, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
+Q = ((B, HQ, D), BF)
+POOL = ((PAGES, PS, HKV, D), BF)
+QPOOL = ((PAGES, PS, HKV, D), I8)
+SCALE = ((PAGES, HKV), F32)
+TABLE = ((B, MAXP), I32)
+ROW = ((B,), I32)
+CHUNK = ((B, KC, HKV, D), BF)
+STEP = ((), I32)
+
+
+def test_paged_decode_compiles(one_chip):
+    _compile(one_chip, ap.paged_decode_gqa_attention,
+             Q, POOL, POOL, TABLE, ROW)
+
+
+def test_paged_chunked_decode_compiles(one_chip):
+    _compile(one_chip, ap.paged_decode_gqa_attention_chunked,
+             Q, POOL, POOL, TABLE, CHUNK, CHUNK, ROW, STEP)
+
+
+@pytest.mark.parametrize("width", RUNGS)
+def test_ragged_prefill_rung_compiles(one_chip, width):
+    """Every rung of the engine's ragged ladder up to max_seq 1024."""
+    qs, kv = ((width, HQ, D), BF), ((width, HKV, D), BF)
+    _compile(one_chip, ap.ragged_paged_prefill_attention,
+             qs, kv, kv, POOL, POOL, TABLE, ROW, ROW, ROW)
+
+
+def test_paged_decode_quant_compiles(one_chip):
+    _compile(one_chip, ap.paged_decode_gqa_attention_quant,
+             Q, QPOOL, SCALE, QPOOL, SCALE, TABLE, ROW)
+
+
+def test_paged_chunked_decode_quant_compiles(one_chip):
+    _compile(one_chip, ap.paged_decode_gqa_attention_chunked_quant,
+             Q, QPOOL, SCALE, QPOOL, SCALE, TABLE, CHUNK, CHUNK, ROW, STEP)
+
+
+@pytest.mark.parametrize("width", (128, 1024))
+def test_ragged_prefill_quant_compiles(one_chip, width):
+    qs, kv = ((width, HQ, D), BF), ((width, HKV, D), BF)
+    _compile(one_chip, ap.ragged_paged_prefill_attention_quant,
+             qs, kv, kv, QPOOL, SCALE, QPOOL, SCALE, TABLE, ROW, ROW, ROW)
+
+
+def test_dense_chunked_decode_compiles(one_chip):
+    lane = ((B, SPAN, HKV, D), BF)
+    _compile(one_chip, ap.decode_gqa_attention_chunked,
+             Q, lane, lane, CHUNK, CHUNK, ROW, STEP, tile=256)
+
+
+def test_dense_decode_compiles_to_its_span_limit(one_chip):
+    """The whole-lane dense kernel (opt-in) compiles at S=512 and refuses
+    S=1024 at trace time with the reason — the compiler's own refusal
+    (16.32 MB against the 16 MiB scoped-VMEM limit) takes seconds and
+    names no remedy."""
+    def lane(s):
+        return ((B, s, HKV, D), BF)
+
+    _compile(one_chip, ap.decode_gqa_attention, Q, lane(512), lane(512), ROW)
+    with pytest.raises(ValueError, match="whole KV lanes in VMEM"):
+        _compile(one_chip, ap.decode_gqa_attention,
+                 Q, lane(SPAN), lane(SPAN), ROW)
