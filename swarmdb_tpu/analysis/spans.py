@@ -11,10 +11,14 @@ contract the type system cannot enforce:
   stamp is discarded can never be ended. ``span_end`` without a local
   ``span_begin`` is fine: closing against an externally carried stamp
   (e.g. the engine's dispatch stamp) is the intended hot-path pattern.
+  ``phase_begin()`` / ``phase_end()`` (the pair that also opens a
+  profiler annotation) is held to the same balance: a ``phase_begin``
+  that no ``phase_end`` in the function consumes drops the span AND
+  leaves its annotation open on the thread.
 - ``span(...)`` is an allocating context manager for warm paths. Inside
   a ``# swarmlint: hot`` function the only sanctioned record forms are
   the allocation-free ring writes (``span_begin``/``span_end``/
-  ``span_at``/``instant``); a ``.span(...)`` context manager there
+  ``phase_begin``/``phase_end``/``span_at``/``instant``); a ``.span(...)`` context manager there
   allocates an object + frame per call on the decode path (SWL502).
 - Histograms (``obs/metrics.py`` and ``utils/metrics.py``) have the
   same discipline: ``observe()`` is allocation-free only when the
@@ -60,11 +64,14 @@ manager protocol balances them across two methods by design.
 from __future__ import annotations
 
 import ast
-from typing import Iterator, List, Optional
+from typing import Dict, Iterator, List, Optional
 
 from .core import Finding, SourceFile, dotted_name, make_finding
 
 _BALANCE_EXEMPT = {"__enter__", "__exit__"}
+
+#: begin/end pairs of the tracer held to the SWL501 balance
+_PAIRS = ("span", "phase")
 
 
 def _own_nodes(fn: ast.AST) -> Iterator[ast.AST]:
@@ -165,20 +172,23 @@ def check(src: SourceFile) -> List[Finding]:
     for fn in ast.walk(src.tree):
         if not isinstance(fn, (ast.FunctionDef, ast.AsyncFunctionDef)):
             continue
-        begins: List[ast.Call] = []
-        ends = 0
+        # per pair ("span", "phase"): the begin calls and the end count
+        begins: Dict[str, List[ast.Call]] = {"span": [], "phase": []}
+        ends = {"span": 0, "phase": 0}
         for node in _own_nodes(fn):
-            if _is_call_to(node, "span_begin"):
-                begins.append(node)  # type: ignore[arg-type]
-            elif _is_call_to(node, "span_end"):
-                ends += 1
-            if (isinstance(node, ast.Expr)
-                    and _is_call_to(node.value, "span_begin")):
-                # stamp discarded on the spot — unendable
-                findings.append(make_finding(
-                    src, "SWL501", node,
-                    "span_begin() stamp discarded — the span can never "
-                    "be recorded (bind it and pass to span_end)"))
+            for pair in _PAIRS:
+                if _is_call_to(node, pair + "_begin"):
+                    begins[pair].append(node)  # type: ignore[arg-type]
+                elif _is_call_to(node, pair + "_end"):
+                    ends[pair] += 1
+                if (isinstance(node, ast.Expr)
+                        and _is_call_to(node.value, pair + "_begin")):
+                    # stamp discarded on the spot — unendable
+                    findings.append(make_finding(
+                        src, "SWL501", node,
+                        f"{pair}_begin() stamp discarded — the span can "
+                        f"never be recorded (bind it and pass to "
+                        f"{pair}_end)"))
             if (src.is_hot(fn) and isinstance(node, ast.Call)
                     and _is_call_to(node, "span")):
                 findings.append(make_finding(
@@ -250,10 +260,12 @@ def check(src: SourceFile) -> List[Finding]:
                         f"memprof record path runs under the allocator/"
                         f"cache lock and must stay int adds and slot "
                         f"writes"))
-        if (begins and ends == 0
-                and fn.name not in _BALANCE_EXEMPT):
-            findings.append(make_finding(
-                src, "SWL501", begins[0],
-                f"`{fn.name}` calls span_begin but never span_end — "
-                f"the span is begun and silently dropped"))
+        for pair in _PAIRS:
+            if (begins[pair] and ends[pair] == 0
+                    and fn.name not in _BALANCE_EXEMPT):
+                findings.append(make_finding(
+                    src, "SWL501", begins[pair][0],
+                    f"`{fn.name}` calls {pair}_begin but never "
+                    f"{pair}_end — the span is begun and silently "
+                    f"dropped"))
     return findings

@@ -96,6 +96,25 @@ def is_retryable_reason(reason: str) -> bool:
 
 PROF_DECODE_KEYS = ("decode.full", "decode.fast", "decode.greedy")
 PROF_RESIDENT_KEYS = ("resident.full", "resident.fast", "resident.greedy")
+# The same variants as the jitted programs are called in a device trace
+# (``jit_<name>``) and as the ``jax.named_scope`` round the model forward
+# inside each: every name holds "decode" and none holds "prefill", which
+# is how the benchmark's readers tell the two families apart.
+DECODE_PROGRAM_NAMES = ("decode_scan_full", "decode_scan_fast",
+                        "decode_scan_greedy")
+RESIDENT_PROGRAM_NAMES = ("decode_resident_full", "decode_resident_fast",
+                          "decode_resident_greedy")
+# (use_filters, assume_greedy) per variant, in the order of the names
+_DECODE_VARIANT_FLAGS = ((True, False), (False, False), (False, True))
+
+
+def _named_partial(fn: Callable, name: str, **kwargs) -> Callable:
+    """``functools.partial(fn, **kwargs)`` with a ``__name__``: jax names
+    a jitted partial's program ``jit__unknown``, which a trace cannot
+    tell from any other."""
+    part = functools.partial(fn, scope=name, **kwargs)
+    part.__name__ = part.__qualname__ = name
+    return part
 
 
 def prof_key(family: str, tok_shape, ppb: Optional[int] = None) -> str:
@@ -190,6 +209,12 @@ class _Slot:
     # (pipelined chunks are issued before the previous block is read);
     # ``position`` stays the host-confirmed value, advanced at processing
     dispatched_position: int = 0
+    # what admission found, for the occupant's spans and the page gauge:
+    # prompt tokens served from cached or kept pages / computed by the
+    # prefill, and the pages its table row references (owned + shared)
+    cached_tokens: int = 0
+    new_tokens: int = 0
+    row_pages: int = 0
 
 
 @dataclass
@@ -288,6 +313,15 @@ class Engine:
         self.flight = FlightRecorder()
         self._flight_dir = flight_dir
         self._flight_last_had_work = False
+        # phase spans (obs/tracer.py phase_begin/phase_end, cat="engine"):
+        # every one carries ``step``, this loop-iteration counter, so a
+        # request's spans and a device trace's gaps name the iteration
+        # that caused them. ``_wave_n`` counts prefill dispatches;
+        # ``_compiled_seen`` is the compiled-program count at the last
+        # flight step (engine.compile instants)
+        self._loop_step = 0
+        self._wave_n = 0
+        self._compiled_seen = 0
         # swarmprof lane handle (obs/profiler.py): per-variant device-
         # time attribution + this lane's duty cycle. SWARMDB_PROFILE=0
         # hands back the shared NullLane — dispatch sites then pay one
@@ -546,7 +580,7 @@ class Engine:
         self._chunked_fns = chunked_fns
 
         def _decode(params, last_tokens, last_lps, positions, cache,
-                    base_keys, temp, topk, topp, *, use_filters,
+                    base_keys, temp, topk, topp, *, scope, use_filters,
                     assume_greedy=False):
             # last_tokens [B] fed tokens, last_lps [B] their raw-model
             # logprobs (computed where they were sampled — prefill or the
@@ -562,10 +596,11 @@ class Engine:
 
                 def body(carry, step):
                     tok, pos, chunk_kv = carry
-                    logits, chunk_kv = chunk_fwd(
-                        params, tok[:, None], pos[:, None], cache, chunk_kv,
-                        step,
-                    )
+                    with jax.named_scope(scope):
+                        logits, chunk_kv = chunk_fwd(
+                            params, tok[:, None], pos[:, None], cache,
+                            chunk_kv, step,
+                        )
                     nxt = sample_tokens(logits[:, -1], base_keys, pos, temp,
                                         topk, topp, use_filters=use_filters,
                                         assume_greedy=assume_greedy)
@@ -585,9 +620,10 @@ class Engine:
 
             def body(carry, _):
                 tok, pos, cache = carry
-                logits, cache = self._decode_forward(
-                    params, tok[:, None], pos[:, None], cache
-                )
+                with jax.named_scope(scope):
+                    logits, cache = self._decode_forward(
+                        params, tok[:, None], pos[:, None], cache
+                    )
                 nxt = sample_tokens(logits[:, -1], base_keys, pos, temp,
                                     topk, topp, use_filters=use_filters,
                                     assume_greedy=assume_greedy)
@@ -605,18 +641,13 @@ class Engine:
             last, last_lp = self._pin_slot_state(last, lps[-1])
             return all_toks, all_lps, last, last_lp, cache
 
-        self._decode = jax.jit(
-            functools.partial(_decode, use_filters=True),
-            donate_argnums=donate)
-        self._decode_fast = jax.jit(
-            functools.partial(_decode, use_filters=False),
-            donate_argnums=donate)
-        self._decode_greedy = jax.jit(
-            functools.partial(_decode, use_filters=False, assume_greedy=True),
-            donate_argnums=donate)
         # ordered by parallel.multihost VARIANT_* codes
-        self._decode_variants = (self._decode, self._decode_fast,
-                                 self._decode_greedy)
+        self._decode_variants = tuple(
+            jax.jit(_named_partial(_decode, name, use_filters=uf,
+                                   assume_greedy=ag),
+                    donate_argnums=donate)
+            for name, (uf, ag) in zip(DECODE_PROGRAM_NAMES,
+                                      _DECODE_VARIANT_FLAGS))
 
         # ---- device-resident decode sessions (emission ring) -------------
         # One jitted ``lax.while_loop`` runs MANY decode chunks per host
@@ -647,7 +678,7 @@ class Engine:
 
             def _decode_resident(params, last_tokens, last_lps, positions,
                                  cache, base_keys, temp, topk, topp,
-                                 stop_pos, live, max_chunks, *,
+                                 stop_pos, live, max_chunks, *, scope,
                                  use_filters, assume_greedy=False):
                 # stop_pos [B]: first position at/after which the slot
                 # needs no more tokens (max_new_tokens bound; the host
@@ -664,7 +695,7 @@ class Engine:
                     n, done, cont, lt, llp, pos, cache = carry
                     all_toks, all_lps, lt, llp, cache = _decode(
                         params, lt, llp, pos, cache, base_keys, temp,
-                        topk, topp, use_filters=use_filters,
+                        topk, topp, scope=scope, use_filters=use_filters,
                         assume_greedy=assume_greedy)
                     pos = pos + K
                     # eos anywhere in the block (row 0 = fed token covers
@@ -685,12 +716,11 @@ class Engine:
                 return n, lt, llp, cache
 
             self._resident_variants = tuple(
-                jax.jit(functools.partial(_decode_resident,
-                                          use_filters=uf,
-                                          assume_greedy=ag),
+                jax.jit(_named_partial(_decode_resident, name,
+                                       use_filters=uf, assume_greedy=ag),
                         donate_argnums=donate)
-                for uf, ag in ((True, False), (False, False),
-                               (False, True)))
+                for name, (uf, ag) in zip(RESIDENT_PROGRAM_NAMES,
+                                          _DECODE_VARIANT_FLAGS))
         # set by ShardLaneGroup: returns True when a SIBLING lane has a
         # decode session in flight while this lane admits — the overlap
         # the per-shard lanes exist to create (flight/SLO counter
@@ -1185,6 +1215,7 @@ class Engine:
     def start(self) -> None:
         if self._thread is not None:
             return
+        self._compiled_seen = self._compiled_count()
         self._thread = threading.Thread(target=self._run, daemon=True,
                                         name="swarmdb-engine")
         self._thread.start()
@@ -1413,6 +1444,11 @@ class Engine:
         wave, never per token)."""
         if self._mh is not None:
             self._mh.publish_call(call_id, args)
+        # every call but the table setter is a prefill dispatch: one
+        # device wave, and the phase a compile would show up in
+        kind = self._WAVE_KINDS.get(call_id)
+        t_wave = (self.tracer.phase_begin("engine.admission.dispatch")
+                  if kind else 0)
         prof = self._prof
         if prof.enabled:
             t0 = time.monotonic_ns()
@@ -1421,6 +1457,24 @@ class Engine:
                           time.monotonic_ns() - t0)
         else:
             self._MH_CALLS[call_id](self, *args)
+        if kind:
+            self.tracer.phase_end(
+                t_wave, "engine.admission.dispatch", cat="engine",
+                args=self._count_wave(kind))
+
+    def _pack_args(self, width: int, filled: int) -> Dict[str, Any]:  # swarmlint: hot
+        """Args of the ``engine.admission.pack`` phase of the wave about
+        to be dispatched: its token grid and the real tokens in it."""
+        return {"step": self._loop_step, "wave": self._wave_n + 1,
+                "width": int(width), "filled": int(filled)}
+
+    def _count_wave(self, kind: str) -> Dict[str, Any]:  # swarmlint: hot
+        """Count one prefill dispatch (a device wave); returns the args
+        of its ``engine.admission.dispatch`` phase."""
+        self._wave_n += 1
+        self.metrics.counters["prefill_device_waves"].inc()
+        return {"step": self._loop_step, "wave": self._wave_n,
+                "kind": kind}
 
     # swarmlint: hot
     def _call_paged_prefill(self, tokens, lengths, target, scatter, keys,
@@ -1509,6 +1563,16 @@ class Engine:
         CALL_DENSE_PREFIX_PREFILL: _call_dense_prefix_prefill,
         CALL_PAGED_PREFILL_PACKED: _call_paged_prefill_packed,
         CALL_PAGED_PREFILL_RAGGED: _call_paged_ragged_prefill,
+    }
+
+    # what ``engine.admission.dispatch`` calls each prefill family
+    _WAVE_KINDS = {
+        CALL_PAGED_PREFILL: "paged",
+        CALL_PAGED_PREFIX_PREFILL: "paged_prefix",
+        CALL_PAGED_RESUME_PREFILL: "resume",
+        CALL_DENSE_PREFIX_PREFILL: "dense_prefix",
+        CALL_PAGED_PREFILL_PACKED: "packed",
+        CALL_PAGED_PREFILL_RAGGED: "ragged",
     }
 
     # swarmprof key per mirrored call (args exclude the call id): the
@@ -2374,9 +2438,12 @@ class Engine:
         # variant) per chunk
         in_flight: List[Tuple[Any, Any, List[Tuple[int, GenRequest, int]],
                               int, int]] = []
+        tracer = self.tracer
         while True:
             self._in_step = False
             self._beat()
+            self._loop_step += 1
+            t_wait = 0
             with self._cv:
                 while (not self._stop and not self._queue
                        and not self._any_active() and not in_flight
@@ -2387,9 +2454,14 @@ class Engine:
                     # threshold. An armed chaos fault exits the wait so
                     # it lands at the seam below (outside the lock) even
                     # on an idle lane.
+                    if not t_wait:
+                        t_wait = tracer.phase_begin("engine.wait")
                     self._beat()
                     self._cv.wait(timeout=0.25)
                 stopping = self._stop
+            if t_wait:
+                tracer.phase_end(t_wait, "engine.wait", cat="engine",
+                                 args={"step": self._loop_step})
             if stopping:
                 # drain dispatched chunks so their requests complete
                 # instead of hanging to their callers' timeouts — OUTSIDE
@@ -2412,7 +2484,7 @@ class Engine:
                 cs(self)
             self._in_step = True
             try:
-                self._admit()
+                self._admission_round()
                 if self._role == "prefill":
                     # fleet prefill lanes retire admission-only requests
                     # straight off the prefill sample — decode never runs
@@ -2538,6 +2610,15 @@ class Engine:
         # then carries the SETTLED counters (a dump taken while idle
         # matches the metrics registry exactly)
         self._flight_last_had_work = has_work
+        compiled = self._compiled_count()
+        if compiled > self._compiled_seen:
+            # a program compiled since the last step: the stall class
+            # warm-up exists to prevent, marked where it landed
+            self.tracer.instant(
+                "engine.compile", cat="engine",
+                args={"step": self._loop_step,
+                      "delta": compiled - self._compiled_seen})
+        self._compiled_seen = compiled
         c = self.metrics.counters
         rec: Dict[str, Any] = {
             "ts": time.time(),
@@ -2554,7 +2635,7 @@ class Engine:
             "prefill_packed_tokens": c["prefill_packed_tokens"].value,
             "host_syncs": c["engine_host_syncs"].value,
             "restarts": c["engine_restarts"].value,
-            "compiled_variants": self._compiled_count(),
+            "compiled_variants": compiled,
         }
         if self._last_wave_kind is not None:
             # which prefill family served the most recent wave (ragged
@@ -2783,6 +2864,25 @@ class Engine:
                 except Exception:
                     logger.exception("on_done callback failed")
 
+    def _admission_round(self) -> None:  # swarmlint: hot
+        """One loop step's admission, as the phase ``engine.admission``
+        (not ``engine.admit``, which is one request's wait in the queue).
+        Its sub-phases ``.reclaim``, ``.plan``, ``.pack`` and ``.dispatch``
+        are opened where that work happens."""
+        tracer = self.tracer
+        t0 = tracer.phase_begin("engine.admission")
+        n0 = self.total_requests
+        try:
+            self._admit()
+        finally:
+            with self._cv:
+                queued = len(self._queue)
+            tracer.phase_end(
+                t0, "engine.admission", cat="engine",
+                args={"step": self._loop_step,
+                      "admitted": self.total_requests - n0,
+                      "queued_after": queued})
+
     def _admit(self) -> None:  # swarmlint: hot
         """Move queued requests into free slots (highest priority first) and
         run their prefill in groups of up to ``prefill_batch``.
@@ -2793,11 +2893,14 @@ class Engine:
         """
         self._age_queue()
         self._expire_deadlines()
+        tracer = self.tracer
         if self.paged:
             # reclaim retired slots' pages first: zero their table rows on
             # device (mirrored to pod workers), THEN return pages to the
             # pool (stale-table/reuse race)
             pending = self.paged.allocator.take_pending_frees()
+            t_reclaim = (tracer.phase_begin("engine.admission.reclaim")
+                         if pending or self.on_tier_drain is not None else 0)
             if pending:
                 freed_pages: List[int] = []
                 if self._pagecheck is not None:
@@ -2831,181 +2934,205 @@ class Engine:
                     self.on_tier_drain()
                 except Exception:
                     logger.exception("tier drain failed")
+            tracer.phase_end(t_reclaim, "engine.admission.reclaim",
+                             cat="engine",
+                             args={"step": self._loop_step,
+                                   "slots": len(pending)})
             if not self._backpressure_gate():
                 return
         pressure_called = False
         while True:
             stale_resumes: List[GenRequest] = []
             pressure_need = 0
-            with self._cv:
-                free = self._free_slot_ids()
-                take = min(len(free), len(self._queue), self.prefill_batch)
-                if take == 0:
-                    return
-                if self.paged:
-                    # admit in priority order while the pool covers each
-                    # request's worst-case page footprint; stop at the first
-                    # that doesn't fit (no skip-ahead: prevents starvation
-                    # of long prompts behind a stream of short ones). With
-                    # the prefix cache, hit pages are pinned and referenced
-                    # in place; only the remainder needs fresh pages, and
-                    # LRU cache pages are evicted into the free list when
-                    # the pool runs short.
-                    popped = []
-                    rows = []
-                    plans: Dict[int, Tuple] = {}
-                    use_pp = self._prefix is not None
-                    resume_rows: Dict[int, np.ndarray] = {}
-                    # candidates = ALL free slots (the wave-size cap
-                    # bounds how many ADMIT, not which slots are
-                    # eligible — free[:take] would pre-pick slots
-                    # positionally and defeat the shard-hint search)
-                    remaining = list(free)
-                    admitted = 0
-                    n_sh = getattr(self.paged.allocator, "n_shards", 1)
-                    while remaining and self._queue and admitted < take:
-                        req = self._queue[0][3]
-                        if (req.resume_pages is not None
-                                and req.resume_epoch is not None
-                                and req.resume_epoch
-                                != self.paged.allocator.generation):
-                            # re-validate the resume epoch at ADMISSION,
-                            # not just submit (ADVICE r4 #2): a pool
-                            # reset while the request sat queued makes
-                            # its page ids dangling aliases. No slot is
-                            # consumed by a stale pop.
-                            heapq.heappop(self._queue)
-                            stale_resumes.append(req)
-                            continue
-                        # slot choice: honor the request's shard hint
-                        # when its shard still has a free slot, so a
-                        # conversation's turns land where its cached
-                        # prefix pages live (same-shard-only reuse).
-                        # Unhinted prefix-eligible requests get a
-                        # CONTENT-affine default — a stable hash of the
-                        # first page of tokens — so identical prefixes
-                        # collide on one shard (cross-request reuse)
-                        # while distinct prompts still spread.
-                        slot_id = None
-                        hint = req.shard_hint
-                        if (hint is None and n_sh > 1 and use_pp
-                                and len(req.prompt) >= self._prefix_ps
-                                and not req.keep_pages):
-                            hint = zlib.crc32(np.asarray(
-                                req.prompt[:self._prefix_ps],
-                                np.int32).tobytes())
-                        if hint is not None and n_sh > 1:
-                            h = hint % n_sh
-                            for j, sid in enumerate(remaining):
-                                if self.paged.allocator.shard_of(sid) == h:
-                                    slot_id = remaining.pop(j)
-                                    break
-                        if slot_id is None:
-                            slot_id = remaining.pop(0)
-                        if req.resume_pages is not None:
-                            # rolling-KV continuation: the kept pages are
-                            # referenced (caller custody); only the part
-                            # past resume_len needs fresh pages
-                            ps_ = self.paged.page_size
-                            worst = min(
-                                self.paged.allocator.max_seq,
-                                req.resume_len + len(req.prompt)
-                                + req.sampling.max_new_tokens
-                                + self.decode_chunk,
+            # the phase opens before the lock is taken: a wait for _cv is
+            # part of what a plan costs
+            popped: List[GenRequest] = []
+            plans: Dict[int, Tuple] = {}
+            t_plan = tracer.phase_begin("engine.admission.plan")
+            try:
+                with self._cv:
+                    free = self._free_slot_ids()
+                    take = min(len(free), len(self._queue), self.prefill_batch)
+                    if take == 0:
+                        return
+                    if self.paged:
+                        # admit in priority order while the pool covers each
+                        # request's worst-case page footprint; stop at the first
+                        # that doesn't fit (no skip-ahead: prevents starvation
+                        # of long prompts behind a stream of short ones). With
+                        # the prefix cache, hit pages are pinned and referenced
+                        # in place; only the remainder needs fresh pages, and
+                        # LRU cache pages are evicted into the free list when
+                        # the pool runs short.
+                        popped = []
+                        rows = []
+                        plans = {}
+                        use_pp = self._prefix is not None
+                        resume_rows: Dict[int, np.ndarray] = {}
+                        # candidates = ALL free slots (the wave-size cap
+                        # bounds how many ADMIT, not which slots are
+                        # eligible — free[:take] would pre-pick slots
+                        # positionally and defeat the shard-hint search)
+                        remaining = list(free)
+                        admitted = 0
+                        n_sh = getattr(self.paged.allocator, "n_shards", 1)
+                        while remaining and self._queue and admitted < take:
+                            req = self._queue[0][3]
+                            if (req.resume_pages is not None
+                                    and req.resume_epoch is not None
+                                    and req.resume_epoch
+                                    != self.paged.allocator.generation):
+                                # re-validate the resume epoch at ADMISSION,
+                                # not just submit (ADVICE r4 #2): a pool
+                                # reset while the request sat queued makes
+                                # its page ids dangling aliases. No slot is
+                                # consumed by a stale pop.
+                                heapq.heappop(self._queue)
+                                stale_resumes.append(req)
+                                continue
+                            # slot choice: honor the request's shard hint
+                            # when its shard still has a free slot, so a
+                            # conversation's turns land where its cached
+                            # prefix pages live (same-shard-only reuse).
+                            # Unhinted prefix-eligible requests get a
+                            # CONTENT-affine default — a stable hash of the
+                            # first page of tokens — so identical prefixes
+                            # collide on one shard (cross-request reuse)
+                            # while distinct prompts still spread.
+                            slot_id = None
+                            hint = req.shard_hint
+                            if (hint is None and n_sh > 1 and use_pp
+                                    and len(req.prompt) >= self._prefix_ps
+                                    and not req.keep_pages):
+                                hint = zlib.crc32(np.asarray(
+                                    req.prompt[:self._prefix_ps],
+                                    np.int32).tobytes())
+                            if hint is not None and n_sh > 1:
+                                h = hint % n_sh
+                                for j, sid in enumerate(remaining):
+                                    if self.paged.allocator.shard_of(sid) == h:
+                                        slot_id = remaining.pop(j)
+                                        break
+                            if slot_id is None:
+                                slot_id = remaining.pop(0)
+                            if req.resume_pages is not None:
+                                # rolling-KV continuation: the kept pages are
+                                # referenced (caller custody); only the part
+                                # past resume_len needs fresh pages
+                                ps_ = self.paged.page_size
+                                worst = min(
+                                    self.paged.allocator.max_seq,
+                                    req.resume_len + len(req.prompt)
+                                    + req.sampling.max_new_tokens
+                                    + self.decode_chunk,
+                                )
+                                total = -(-worst // ps_)
+                                n_fresh = max(0,
+                                              total - len(req.resume_pages))
+                                row = self.paged.allocator.allocate_with_prefix(
+                                    slot_id, req.resume_pages, n_fresh)
+                                if row is None:
+                                    pressure_need = n_fresh
+                                    break  # pool exhausted; retry later
+                                heapq.heappop(self._queue)
+                                self._admitting.add(req.request_id)
+                                popped.append(req)
+                                rows.append((slot_id, row))
+                                resume_rows[slot_id] = row
+                                admitted += 1
+                                continue
+                            need = self.paged.allocator.pages_needed(
+                                len(req.prompt), req.sampling.max_new_tokens,
+                                self.decode_chunk,
                             )
-                            total = -(-worst // ps_)
-                            n_fresh = max(0,
-                                          total - len(req.resume_pages))
-                            row = self.paged.allocator.allocate_with_prefix(
-                                slot_id, req.resume_pages, n_fresh)
+                            row = None
+                            hits: List[int] = []
+                            chains: List[bytes] = []
+                            for attempt in range(2):
+                                hits, chains = [], []
+                                # keep_pages (rolling) requests bypass the
+                                # hash prefix cache both ways: a hit would
+                                # reference cache-custody pages that
+                                # retirement cannot hand to the caller, and
+                                # registration would steal the slot's own
+                                # pages INTO cache custody
+                                if (use_pp and len(req.prompt) >= self._prefix_ps
+                                        and not req.keep_pages):
+                                    hits, chains = self._prefix_plan(
+                                        req.prompt, pin=True)
+                                    # DP-sharded pool: a slot can only
+                                    # reference pages of its own shard (the
+                                    # shard_map'd decode addresses its local
+                                    # sub-pool); truncate foreign-shard hits
+                                    keep = self.paged.allocator.usable_prefix(
+                                        slot_id, hits)
+                                    if keep < len(hits):
+                                        self._prefix.unpin(hits[keep:])
+                                        hits = hits[:keep]
+                                row = self._paged_allocate(
+                                    slot_id, hits, max(0, need - len(hits)))
+                                if row is not None:
+                                    break
+                                if hits:
+                                    self._prefix.unpin(hits)
+                                # the hint is ADVISORY (review r5): a hinted
+                                # shard whose sub-pool cannot cover the
+                                # request must not head-of-line-block the 7
+                                # healthy shards — retry once on the
+                                # freest-pooled other free slot
+                                if (attempt == 0 and hint is not None
+                                        and n_sh > 1 and remaining):
+                                    remaining.append(slot_id)  # still free
+                                    alt = max(remaining,
+                                              key=self.paged.allocator.free_count)
+                                    remaining.remove(alt)
+                                    slot_id = alt
+                                    continue
+                                break
                             if row is None:
-                                pressure_need = n_fresh
-                                break  # pool exhausted; retry later
+                                pressure_need = max(0, need - len(hits))
+                                break  # pool exhausted; retry after retirements
                             heapq.heappop(self._queue)
                             self._admitting.add(req.request_id)
                             popped.append(req)
                             rows.append((slot_id, row))
-                            resume_rows[slot_id] = row
                             admitted += 1
-                            continue
-                        need = self.paged.allocator.pages_needed(
-                            len(req.prompt), req.sampling.max_new_tokens,
-                            self.decode_chunk,
-                        )
-                        row = None
-                        hits: List[int] = []
-                        chains: List[bytes] = []
-                        for attempt in range(2):
-                            hits, chains = [], []
-                            # keep_pages (rolling) requests bypass the
-                            # hash prefix cache both ways: a hit would
-                            # reference cache-custody pages that
-                            # retirement cannot hand to the caller, and
-                            # registration would steal the slot's own
-                            # pages INTO cache custody
                             if (use_pp and len(req.prompt) >= self._prefix_ps
                                     and not req.keep_pages):
-                                hits, chains = self._prefix_plan(
-                                    req.prompt, pin=True)
-                                # DP-sharded pool: a slot can only
-                                # reference pages of its own shard (the
-                                # shard_map'd decode addresses its local
-                                # sub-pool); truncate foreign-shard hits
-                                keep = self.paged.allocator.usable_prefix(
-                                    slot_id, hits)
-                                if keep < len(hits):
-                                    self._prefix.unpin(hits[keep:])
-                                    hits = hits[:keep]
-                            row = self._paged_allocate(
-                                slot_id, hits, max(0, need - len(hits)))
-                            if row is not None:
+                                plans[slot_id] = (hits, chains)
+                    else:
+                        resume_rows = {}
+                        popped = []
+                        for _ in range(take):
+                            if not self._queue:
                                 break
-                            if hits:
-                                self._prefix.unpin(hits)
-                            # the hint is ADVISORY (review r5): a hinted
-                            # shard whose sub-pool cannot cover the
-                            # request must not head-of-line-block the 7
-                            # healthy shards — retry once on the
-                            # freest-pooled other free slot
-                            if (attempt == 0 and hint is not None
-                                    and n_sh > 1 and remaining):
-                                remaining.append(slot_id)  # still free
-                                alt = max(remaining,
-                                          key=self.paged.allocator.free_count)
-                                remaining.remove(alt)
-                                slot_id = alt
+                            req = self._queue[0][3]
+                            if (req.resume_pages is not None
+                                    and req.resume_epoch is not None
+                                    and req.resume_epoch != self.pool_epoch()):
+                                # dense rolling resume planned against a pool
+                                # that has since been rebuilt (same race as
+                                # the paged branch above)
+                                heapq.heappop(self._queue)
+                                stale_resumes.append(req)
                                 continue
-                            break
-                        if row is None:
-                            pressure_need = max(0, need - len(hits))
-                            break  # pool exhausted; retry after retirements
-                        heapq.heappop(self._queue)
-                        self._admitting.add(req.request_id)
-                        popped.append(req)
-                        rows.append((slot_id, row))
-                        admitted += 1
-                        if (use_pp and len(req.prompt) >= self._prefix_ps
-                                and not req.keep_pages):
-                            plans[slot_id] = (hits, chains)
-                else:
-                    resume_rows = {}
-                    popped = []
-                    for _ in range(take):
-                        if not self._queue:
-                            break
-                        req = self._queue[0][3]
-                        if (req.resume_pages is not None
-                                and req.resume_epoch is not None
-                                and req.resume_epoch != self.pool_epoch()):
-                            # dense rolling resume planned against a pool
-                            # that has since been rebuilt (same race as
-                            # the paged branch above)
                             heapq.heappop(self._queue)
-                            stale_resumes.append(req)
-                            continue
-                        heapq.heappop(self._queue)
-                        popped.append(req)
-                    self._admitting.update(r.request_id for r in popped)
+                            popped.append(req)
+                        self._admitting.update(r.request_id for r in popped)
+            finally:
+                # cached: prefix-cache hits (whole pages) and the kept
+                # tokens of rolling continuations, whose prompt is the
+                # new part only
+                hit = (self._prefix_ps * sum(len(plan[0])
+                                             for plan in plans.values())
+                       if plans else 0)
+                tracer.phase_end(
+                    t_plan, "engine.admission.plan", cat="engine",
+                    args={"step": self._loop_step, "rows": len(popped),
+                          "cached_tokens": hit + sum(r.resume_len
+                                                     for r in popped),
+                          "new_tokens": sum(len(r.prompt)
+                                            for r in popped) - hit})
             # outside the lock: fire callbacks / the pressure hook (either
             # may re-enter submit() or take the serving layer's locks)
             for req in stale_resumes:
@@ -3066,6 +3193,12 @@ class Engine:
             slot_ids = ([r[0] for r in rows] if self.paged
                         else free[:len(popped)])
             for slot_id, req in zip(slot_ids, popped):
+                slot = self.slots[slot_id]
+                slot.cached_tokens = req.resume_len
+                slot.new_tokens = len(req.prompt)
+                slot.row_pages = (
+                    int(np.count_nonzero(row_by_slot[slot_id]))
+                    if self.paged else 0)
                 if slot_id in resume_rows:
                     resume_batch.append((slot_id, req, resume_rows[slot_id]))
                     continue
@@ -3078,6 +3211,8 @@ class Engine:
                         hits, chains = plans[slot_id]
                     else:
                         hits, chains = [], None
+                    slot.cached_tokens = len(hits) * self.paged.page_size
+                    slot.new_tokens -= slot.cached_tokens
                     ragged_batch.append((slot_id, req, hits, chains,
                                          row_by_slot[slot_id]))
                     continue
@@ -3104,6 +3239,8 @@ class Engine:
                     else:
                         hits, chains = self._prefix_plan(req.prompt)
                     suffix_len = len(req.prompt) - len(hits) * self._prefix_ps
+                    slot.cached_tokens = len(hits) * self._prefix_ps
+                    slot.new_tokens = suffix_len
                     prefix_batch.append((slot_id, req, hits, chains))
                     max_suffix = max(max_suffix, suffix_len)
                     max_hits = max(max_hits, len(hits))
@@ -3380,6 +3517,7 @@ class Engine:
         moves to the cache with no copy. One fused pool-donating dispatch
         per admission wave (see ``_prefill_paged_prefix_insert``)."""
         t0 = time.time()
+        t_pack = self.tracer.phase_begin("engine.admission.pack")
         ps = self._prefix_ps
         Bp = self._rows_for(len(batch))  # row-bucketed wave (lanes)
         chunks = -(-bucket // ps)
@@ -3418,6 +3556,9 @@ class Engine:
                     (slot_id, chains[page_idx],
                      tuple(prompt[page_idx * ps:(page_idx + 1) * ps]),
                      fresh[f]))
+        self.tracer.phase_end(
+            t_pack, "engine.admission.pack", cat="engine",
+            args=self._pack_args(padded.size, lengths[:len(batch)].sum()))
         self._mirrored(
             self.CALL_PAGED_PREFIX_PREFILL, padded, lengths, plens, table,
             target, scatter, self._base_keys_np[gather],
@@ -3454,6 +3595,7 @@ class Engine:
         (mid-page), sample. No hash registration — custody of the kept
         pages stays with the caller's registry."""
         t0 = time.time()
+        t_pack = self.tracer.phase_begin("engine.admission.pack")
         Bp = self.prefill_batch
         maxp = self.paged.allocator.maxp
         padded = np.full((Bp, bucket), self.pad_id, np.int32)
@@ -3477,6 +3619,9 @@ class Engine:
             self._topk[slot_id] = s.top_k
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
+        self.tracer.phase_end(
+            t_pack, "engine.admission.pack", cat="engine",
+            args=self._pack_args(padded.size, lengths[:len(batch)].sum()))
         self._mirrored(
             self.CALL_PAGED_RESUME_PREFILL, padded, lengths, rlens, table,
             row_tables, scatter, self._base_keys_np[gather],
@@ -3505,6 +3650,7 @@ class Engine:
         ``rows``: (slot_id, req, suffix_tokens, prefix_len, table_pages,
         reg_pairs) per admission; ``reg_pairs`` = [(lane_col, pool_page)]
         to register (empty for resume)."""
+        t_pack = self.tracer.phase_begin("engine.admission.pack")
         ps = self._prefix_ps
         Bp = self.prefill_batch
         lane_pages = min(ppb + -(-bucket // ps), self.max_seq // ps)
@@ -3533,6 +3679,9 @@ class Engine:
             for r, (page_idx, pid) in enumerate(reg_pairs):
                 reg_cols[row, r] = page_idx
                 reg_pages[row, r] = pid
+        self.tracer.phase_end(
+            t_pack, "engine.admission.pack", cat="engine",
+            args=self._pack_args(padded.size, lengths[:len(rows)].sum()))
         self._mirrored(
             self.CALL_DENSE_PREFIX_PREFILL, padded, lengths, plens, table,
             reg_cols, reg_pages, scatter, self._base_keys_np[gather],
@@ -3639,7 +3788,9 @@ class Engine:
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
         packed_n = padding_n = 0
+        tracer = self.tracer
         while pend:
+            t_pack = tracer.phase_begin("engine.admission.pack")
             total = 0
             for it in pend:
                 total += len(it[1]) - it[3]
@@ -3689,6 +3840,8 @@ class Engine:
                 check_wave_descriptors(
                     tok_row, tok_pos, tables,
                     self.paged.allocator.num_pages, ps)
+            tracer.phase_end(t_pack, "engine.admission.pack", cat="engine",
+                             args=self._pack_args(wd, filled))
             self._mirrored(
                 self.CALL_PAGED_PREFILL_RAGGED, tokens, tok_row, tok_pos,
                 starts, lens, plens, tables, scatter,
@@ -3743,6 +3896,7 @@ class Engine:
         vector and surface as row 0 of the next chunk's block.
         """
         t0 = time.time()
+        t_pack = self.tracer.phase_begin("engine.admission.pack")
         n = len(batch)
         # row-bucketed wave (lane engines): pay for the admissions the
         # wave actually has, not prefill_batch unconditionally
@@ -3777,6 +3931,11 @@ class Engine:
         self.metrics.counters["prefill_padding_tokens"].inc(padding_n)
         self.metrics.counters["prefill_packed_tokens"].inc(packed_n)
         self._last_wave_kind = "bucketed"
+        # the paged branches below lay the same rows out once more for
+        # their call: microseconds, which show under engine.admission only
+        self.tracer.phase_end(
+            t_pack, "engine.admission.pack", cat="engine",
+            args=self._pack_args(padded.size, packed_n))
 
         if not self.paged:
             # ONE dispatch: forward + sample + slot insert + token scatter.
@@ -3789,6 +3948,7 @@ class Engine:
                     self._temp[gather], self._topk[gather],
                     self._topp[gather])
             prof = self._prof
+            t_wave = self.tracer.phase_begin("engine.admission.dispatch")
             t0_ns = time.monotonic_ns() if prof.enabled else 0
             self.cache, self._last_tokens, self._last_lps = \
                 self._prefill_fused(
@@ -3804,6 +3964,9 @@ class Engine:
                     self._topk[gather],
                     self._topp[gather],
                 )
+            self.tracer.phase_end(
+                t_wave, "engine.admission.dispatch", cat="engine",
+                args=self._count_wave("dense"))
             if t0_ns:
                 key = prof_key("prefill.dense", padded.shape)
                 prof.dispatch(key, t0_ns, time.monotonic_ns() - t0_ns)
@@ -3915,7 +4078,8 @@ class Engine:
             # retro-span: the wait was over before any tracer call site
             # could run, so it is recorded from its wall-clock endpoints
             self.tracer.span_at("engine.admit", req.submitted_at, t0,
-                                cat="engine", rid=req.request_id)
+                                cat="engine", rid=req.request_id,
+                                args={"step": self._loop_step})
         prefill_dt = time.time() - t0
         self._lat_prefill.observe(prefill_dt)
         self.metrics.counters["engine_admission_waves"].inc()
@@ -3932,11 +4096,17 @@ class Engine:
         self.metrics.counters["phase_us_prefill"].inc(
             max(0, int(prefill_dt * 1e6)))
         for slot_id, req in batch:
+            slot = self.slots[slot_id]
             self.tracer.span_at(
                 "engine.prefill", t0, t0 + prefill_dt, cat="engine",
                 rid=req.request_id,
                 args={"slot": slot_id,
-                      "mid": req.metadata.get("message_id")})
+                      "mid": req.metadata.get("message_id"),
+                      "step": self._loop_step,
+                      "cached_tokens": slot.cached_tokens,
+                      "new_tokens": slot.new_tokens,
+                      # the last prefill dispatch that served this batch
+                      "wave": self._wave_n})
 
     # --------------------------------------------------------------- decode
 
@@ -3976,7 +4146,7 @@ class Engine:
                 self._prof.dispatch(self._prof_resident_key, prev_ns,
                                     now_ns - prev_ns)
             self._process_host_block(np.asarray(block), np.asarray(lps),
-                                     snapshot, self._resident_prev_ns)
+                                     snapshot, self._resident_prev_ns, n)
             self._resident_prev_ns = now_ns
             return np.bool_(self._resident_should_continue())
         except Exception:
@@ -4024,6 +4194,10 @@ class Engine:
         chunk counter — a request admitted and retired within a session
         therefore spans a single sanctioned sync, vs one per chunk on
         the scan path."""
+        t_session = self.tracer.phase_begin("engine.session")
+        n_chunks = 0
+        variant = -1
+        carried = 0  # slots already decoding before this session
         B = self.max_batch
         K = self.decode_chunk
         positions = np.zeros((B,), np.int32)
@@ -4046,15 +4220,41 @@ class Engine:
                    - len(s.generated) + 1)
             stop_pos[i] = min(self.max_seq, pos0 + max(1, rem))
             snap.append((i, s.request, pos0))
+            if not s.pending_first:
+                carried += 1
             max_rem = max(max_rem, int(stop_pos[i]) - pos0)
             if self._topk[i] > 0 or self._topp[i] < 1.0:
                 needs_filters = True
             if self._temp[i] > 0:
                 needs_sampling = True
-        if not snap:
-            return
-        max_chunks = np.int32(-(-max(1, max_rem) // K) + 1)
-        variant = (0 if needs_filters else 1 if needs_sampling else 2)
+        try:
+            if not snap:
+                return
+            max_chunks = np.int32(-(-max(1, max_rem) // K) + 1)
+            variant = (0 if needs_filters else 1 if needs_sampling else 2)
+            n_chunks = self._resident_session(
+                variant, positions, stop_pos, live, max_chunks, snap)
+        finally:
+            # build inputs -> dispatch -> the drain read returned
+            self.tracer.phase_end(
+                t_session, "engine.session", cat="engine",
+                args={"step": self._loop_step,
+                      "variant": (RESIDENT_PROGRAM_NAMES[variant]
+                                  if variant >= 0 else None),
+                      "slots": len(snap), "carried": carried,
+                      "chunks": n_chunks})
+        for i, req, _pos0 in snap:
+            s = self.slots[i]
+            if s.active and s.request is req:
+                # every emitted block advanced s.position; the device's
+                # next fed token corresponds to exactly that extent
+                s.dispatched_position = s.position
+
+    # swarmlint: hot
+    def _resident_session(self, variant: int, positions, stop_pos, live,
+                          max_chunks, snap) -> int:
+        """Dispatch one resident program and wait for its chunk count:
+        the device half of ``_run_resident``. Returns the chunks run."""
         fn = self._resident_variants[variant]
         self._prof_resident_key = PROF_RESIDENT_KEYS[variant]
         self._resident_snap = snap
@@ -4080,15 +4280,10 @@ class Engine:
                 (t_sync1 - t_sync0) // 1000)
             self.metrics.counters["engine_resident_sessions"].inc()
             self.metrics.counters["engine_resident_chunks"].inc(n_chunks)
+            return n_chunks
         finally:
             self._resident_snap = None
             self._lane_busy = False
-        for i, req, _pos0 in snap:
-            s = self.slots[i]
-            if s.active and s.request is req:
-                # every emitted block advanced s.position; the device's
-                # next fed token corresponds to exactly that extent
-                s.dispatched_position = s.position
 
     def _dispatch_decode(self):  # swarmlint: hot
         """Issue one K-step decode chunk (NO host sync) and return
@@ -4208,16 +4403,22 @@ class Engine:
 
     # swarmlint: hot
     def _process_host_block(self, block, lps, snapshot,
-                            t_dispatch_ns: int = 0) -> None:
+                            t_dispatch_ns: int = 0, chunk: int = 0) -> None:
         """Pure host-side half of block processing: emit tokens, retire
         finished slots, close the per-chunk spans. Fed numpy blocks by
         BOTH paths — the scan path after its per-chunk drain, and the
         resident emission ring's ordered callback (where the device is
-        never waited on)."""
+        never waited on). ``chunk`` is the block's index in its resident
+        session (a scan dispatch is one chunk a loop step: 0)."""
         # the engine thread parks in the session drain for a whole
         # resident session, so the emission callback is where a live lane
         # proves progress — beat HERE, not just in the loop
         self._beat()
+        t_emit = self.tracer.phase_begin("engine.emit")
+        # live slots of this chunk, and over them the pages each owns
+        # and how many of those its written extent covers
+        n_live = pages_reserved = pages_written = 0
+        alloc = self.paged.allocator if self.paged else None
         t_done_ns = time.monotonic_ns()
         if t_dispatch_ns:
             # per-chunk latency, dispatch -> processed (pipelined chunks
@@ -4243,6 +4444,16 @@ class Engine:
             s = self.slots[i]
             if not s.active or s.request is not req:
                 continue  # retired mid-flight (possibly re-admitted)
+            n_live += 1
+            if alloc is not None:
+                # pages the row references but the slot does not own
+                # (cache hits, pages registered to the cache) hold whole
+                # prompt pages: written, and nobody's reservation
+                owned = len(alloc.pages_for(i))
+                shared = max(0, s.row_pages - owned)
+                pages_reserved += owned
+                pages_written += min(owned, max(
+                    0, -(-s.position // alloc.page_size) - shared))
             if s.cancelled:
                 self._retire(i, "cancelled")
                 continue
@@ -4263,6 +4474,13 @@ class Engine:
                                  logprob=float(lps[step + 1, i]))
             if s.active:
                 s.position = pos0 + K
+        c = self.metrics.counters
+        c["decode_slot_chunks"].inc(n_live)
+        c["kv_page_chunks_reserved"].inc(pages_reserved)
+        c["kv_page_chunks_written"].inc(pages_written)
+        self.tracer.phase_end(t_emit, "engine.emit", cat="engine",
+                              args={"step": self._loop_step, "chunk": chunk,
+                                    "live": n_live})
 
     # swarmlint: hot
     def _emit_token(self, slot_id: int, token: int,
@@ -4276,6 +4494,15 @@ class Engine:
             slot.first_token_at = now
             self._lat_first_token.observe(now - req.submitted_at)
             HIST_TTFT.observe(now - req.submitted_at, req.request_id)
+            # admission (prefill start) -> first token out: the prefill
+            # waves and the decode chunk the token rode out with
+            self.tracer.span_at(
+                "engine.first_token", slot.admitted_at or now, now,
+                cat="engine", rid=req.request_id,
+                args={"step": self._loop_step,
+                      "mid": req.metadata.get("message_id"),
+                      "cached_tokens": slot.cached_tokens,
+                      "new_tokens": slot.new_tokens})
 
         finished_reason = None
         if token == self.eos_id:

@@ -18,7 +18,9 @@ and the broker send path):
   (export) take benign racy snapshots — a torn read costs at most one
   event, never a crash.
 - **Bounded memory.** Rings are fixed-size (``SWARMDB_TRACE_RING``,
-  default 4096 events/thread); old events are overwritten. Rings of dead
+  default 8192 events/thread: a minute of a saturated engine's chunk
+  and phase spans, which the benchmark's span readers need whole); old
+  events are overwritten. Rings of dead
   threads are pruned at the next registration.
 - **Monotonic time.** Spans are stamped with ``time.monotonic_ns`` so a
   wall-clock step can never produce negative durations; one
@@ -27,14 +29,26 @@ and the broker send path):
   warm paths; hot-path functions (``# swarmlint: hot``) must use the
   allocation-free ``span_begin()`` / ``span_end()`` pair — machine-checked
   by swarmlint SWL501/SWL502 (analysis/spans.py).
+- **Phases land in two sinks.** ``phase_begin(name)`` / ``phase_end(...)``
+  record a span in the thread's ring like ``span_begin``/``span_end`` AND
+  open a ``jax.profiler.TraceAnnotation`` of the same name for its
+  duration, so that inside a profiler session the phase shares the device
+  trace's clock (an idle gap of the device is then named by the phase
+  that was open across it). The annotation class is taken from
+  ``sys.modules`` only if ``jax`` is already loaded: this package stays
+  importable without JAX, and then the ring is the only sink. Phases are
+  for per-step and per-chunk work, never per token: outside a profiler
+  session an annotation costs about a microsecond. Same balance check as
+  the span pair (SWL501).
 
-``SWARMDB_TRACE=0`` disables recording entirely (the record path then
-costs one attribute read and a branch).
+``SWARMDB_TRACE=0`` disables recording entirely, both sinks (the record
+path then costs one attribute read and a branch).
 """
 
 from __future__ import annotations
 
 import os
+import sys
 import threading
 import time
 import weakref
@@ -103,9 +117,9 @@ class SpanTracer:
         if capacity_per_thread is None:
             try:
                 capacity_per_thread = int(
-                    os.environ.get("SWARMDB_TRACE_RING", "4096"))
+                    os.environ.get("SWARMDB_TRACE_RING", "8192"))
             except ValueError:
-                capacity_per_thread = 4096
+                capacity_per_thread = 8192
         if enabled is None:
             enabled = os.environ.get("SWARMDB_TRACE", "1") != "0"
         self.enabled = bool(enabled)
@@ -118,6 +132,8 @@ class SpanTracer:
         # clock anchor: monotonic <-> epoch, captured together once
         self._anchor_mono_ns = time.monotonic_ns()
         self._anchor_epoch = time.time()
+        # jax.profiler.TraceAnnotation once jax is loaded (phase sink b)
+        self._annotation: Any = None
 
     # ------------------------------------------------------------ recording
 
@@ -165,6 +181,47 @@ class SpanTracer:
         """Record the closed span started at ``t0`` (one ring write)."""
         if not self.enabled or t0 == 0:
             return
+        self._ring().put((name, cat, rid, t0, time.monotonic_ns(), args))
+
+    def _annotation_cls(self) -> Any:
+        cls = self._annotation
+        if cls is None:
+            jax = sys.modules.get("jax")
+            prof = getattr(jax, "profiler", None)
+            cls = self._annotation = getattr(prof, "TraceAnnotation", None)
+        return cls
+
+    def phase_begin(self, name: str) -> int:
+        """Start stamp for ``phase_end``, like ``span_begin``; also opens
+        a profiler annotation called ``name`` on this thread when jax is
+        loaded. Phases nest: end them in reverse order of their begins."""
+        if not self.enabled:
+            return 0
+        t0 = time.monotonic_ns()
+        cls = self._annotation_cls()
+        if cls is not None:
+            ann = cls(name)
+            ann.__enter__()
+            stack = getattr(self._local, "phases", None)
+            if stack is None:
+                stack = self._local.phases = []
+            stack.append((t0, ann))
+        return t0
+
+    def phase_end(self, t0: int, name: str, cat: str = "span",
+                  rid: Optional[str] = None,
+                  args: Optional[Dict[str, Any]] = None) -> None:
+        """Close the phase begun at ``t0``: one ring write, and its
+        annotation's exit. An annotation that an exception left open
+        inside this phase is closed with it."""
+        if not self.enabled or t0 == 0:
+            return
+        stack = getattr(self._local, "phases", None)
+        while stack:
+            began, ann = stack.pop()
+            ann.__exit__(None, None, None)
+            if began == t0:
+                break
         self._ring().put((name, cat, rid, t0, time.monotonic_ns(), args))
 
     def span_at(self, name: str, start_epoch: float, end_epoch: float,
@@ -217,6 +274,23 @@ class SpanTracer:
                     "args": args,
                 })
         out.sort(key=lambda e: e["start_s"])
+        return out
+
+    def ring_stats(self) -> List[Dict[str, Any]]:
+        """Per thread ring: events written, capacity, events overwritten
+        (``lost``) and the end of the oldest one still held, so a reader
+        of a time window can tell a whole ring from a lapped one."""
+        with self._reg_lock:
+            rings = [r for r, _ in self._rings]
+        out: List[Dict[str, Any]] = []
+        for ring in rings:
+            held = ring.snapshot()
+            out.append({
+                "tid": ring.tid, "thread": ring.name, "written": ring.idx,
+                "capacity": ring.cap, "lost": max(0, ring.idx - ring.cap),
+                "oldest_end_s": (self.epoch_of_mono(held[0][4])
+                                 if held else None),
+            })
         return out
 
     def events_for(self, rid: str) -> List[Dict[str, Any]]:

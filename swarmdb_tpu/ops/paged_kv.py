@@ -698,9 +698,11 @@ def _paged_write_ragged_quant(
     return out[0], out[1]
 
 
-_set_page_table_rows = jax.jit(
-    lambda pt, rows, values: pt.at[rows].set(values, mode="drop")
-)
+@jax.jit
+def _set_page_table_rows(pt, rows, values):
+    # a named function, not a lambda: the engine loop dispatches this
+    # every admission round, and a device trace names programs by it
+    return pt.at[rows].set(values, mode="drop")
 
 
 def set_page_table_rows(

@@ -42,6 +42,7 @@ def expected_findings(path: Path):
     "lock_bad.py",              # lock-discipline family (SWL301)
     "tracer_leak_bad.py",       # tracer-leak family (SWL401)
     "span_bad.py",              # span-discipline family (SWL501/502)
+    "phase_bad.py",             # the phase pair's balance (SWL501)
     "metrics_bad.py",           # histogram discipline (SWL503)
     "exemplar_bad.py",          # exemplar/sentinel allocation (SWL504)
     "profile_bad.py",           # compile-time introspection in hot code (SWL506)
