@@ -239,11 +239,11 @@ def forward_prefix_pages(
     """
     if cfg.is_moe:
         raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral")
-    from ..ops.paged_kv import _dequantize_pages, is_quantized, pool_data
+    from ..ops.paged_kv import (_dequantize_pages, is_quantized, pool_data,
+                                pools_flat)
 
     Bp, T = tokens.shape
     quant = is_quantized(pool_k)
-    L, P = pool_data(pool_k).shape[0], pool_data(pool_k).shape[1]
     ps = pool_data(pool_k).shape[2]
     PP = prefix_table.shape[1]
     Pt = PP * ps
@@ -256,10 +256,7 @@ def forward_prefix_pages(
     # pool followed by a page gather may or may not fuse; this form always
     # reads only the needed pages). Quantized pools gather payload AND
     # scale rows, dequantizing to f32 right after the gather.
-    from ..ops.paged_kv import pool_flat
-
-    pool_k_flat = pool_flat(pool_k)
-    pool_v_flat = pool_flat(pool_v)
+    pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
 
     def _gather_pages(flat, idx):
         if quant:
@@ -332,14 +329,12 @@ def forward_ragged_prefill(
         raise ValueError(f"{cfg.name!r} is MoE; ragged prefill is "
                          "dense-Llama-only for now")
     from ..ops.layers import ragged_prefill_dispatch
-    from ..ops.paged_kv import pool_data, pool_dtype, pool_flat
+    from ..ops.paged_kv import pool_dtype, pools_flat
 
     W = tokens.shape[0]
-    L, P = pool_data(pool_k).shape[0], pool_data(pool_k).shape[1]
     x = params["embed"][tokens][None]                    # [1, W, D]
     cos, sin = rope_cos_sin(tok_pos[None], cfg.head_dim, cfg.rope_theta)
-    pool_k_flat = pool_flat(pool_k)
-    pool_v_flat = pool_flat(pool_v)
+    pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
     kdt, vdt = pool_dtype(pool_k), pool_dtype(pool_v)
     tables = row_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
@@ -575,20 +570,24 @@ def forward_paged_chunked(
     frozen for the chunk's K steps (one bulk page write per chunk via
     ``merge_paged_chunk``), this step's K/V lands in the chunk buffer,
     and attention spans live pages + chunk buffer under one softmax
-    (ops/layers.paged_attention_dispatch_chunked)."""
+    (ops/layers.paged_attention_dispatch_chunked). Like
+    ``forward_ragged_prefill`` the layer scan reads the pool in place,
+    through its flat view and a per-layer table offset."""
     if cfg.is_moe:
         raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral")
     from ..ops.layers import paged_attention_dispatch_chunked
+    from ..ops.paged_kv import pools_flat
 
     x = params["embed"][tokens]
     table = cache["page_table"]
+    pool_k_flat, pool_v_flat, L, P = pools_flat(cache["k"], cache["v"])
     chunk_k, chunk_v = chunk_kv
     pos0 = cache.get("pos0")  # rolling-KV RoPE offset (see forward_paged)
     rope_pos = positions if pos0 is None else positions + pos0[:, None]
     cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
 
     def layer_step(x, scanned):
-        lp, kp, vp, hk, hv = scanned
+        lp, l, hk, hv = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         B, T = h.shape[0], h.shape[1]
         q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
@@ -598,8 +597,8 @@ def forward_paged_chunked(
         hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
                                           (0, step, 0, 0))
         attn = paged_attention_dispatch_chunked(
-            q, kp, vp, table, hk, hv, positions, step,
-            window=cfg.sliding_window)
+            q, pool_k_flat, pool_v_flat, table + l * P, hk, hv, positions,
+            step, window=cfg.sliding_window)
         x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
         h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
@@ -607,7 +606,7 @@ def forward_paged_chunked(
 
     x, (new_hk, new_hv) = jax.lax.scan(
         layer_step, x,
-        (params["layers"], cache["k"], cache["v"], chunk_k, chunk_v),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32), chunk_k, chunk_v),
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     head = params.get("lm_head")

@@ -305,19 +305,17 @@ def forward_prefix_pages(
     from ..ops.layers import gqa_attention_prefix
 
     from ..ops.paged_kv import (_dequantize_pages, is_quantized, pool_data,
-                                pool_flat)
+                                pools_flat)
 
     Bp, T = tokens.shape
     quant = is_quantized(pool_k)
-    L, P = pool_data(pool_k).shape[0], pool_data(pool_k).shape[1]
     ps = pool_data(pool_k).shape[2]
     Pt = prefix_table.shape[1] * ps
     x = params["embed"][tokens]
     positions = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
-    pool_k_flat = pool_flat(pool_k)
-    pool_v_flat = pool_flat(pool_v)
+    pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
 
     def _gather_pages(flat, idx):
         if quant:
@@ -398,16 +396,18 @@ def forward_paged_chunked(
     if not cfg.is_moe:
         raise ValueError(f"{cfg.name!r} is dense; use models.llama")
     from ..ops.layers import paged_attention_dispatch_chunked
+    from ..ops.paged_kv import pools_flat
 
     x = params["embed"][tokens]
     table = cache["page_table"]
+    pool_k_flat, pool_v_flat, L, P = pools_flat(cache["k"], cache["v"])
     chunk_k, chunk_v = chunk_kv
     pos0 = cache.get("pos0")  # rolling-KV RoPE offset (llama.forward_paged)
     rope_pos = positions if pos0 is None else positions + pos0[:, None]
     cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
 
     def layer_step(x, scanned):
-        lp, kp, vp, hk, hv = scanned
+        lp, l, hk, hv = scanned
         h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
         B, T = h.shape[0], h.shape[1]
         q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
@@ -417,8 +417,8 @@ def forward_paged_chunked(
         hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
                                           (0, step, 0, 0))
         attn = paged_attention_dispatch_chunked(
-            q, kp, vp, table, hk, hv, positions, step,
-            window=cfg.sliding_window)
+            q, pool_k_flat, pool_v_flat, table + l * P, hk, hv, positions,
+            step, window=cfg.sliding_window)
         x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
         h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
         moe_out, _load = moe_block(
@@ -430,7 +430,7 @@ def forward_paged_chunked(
 
     x, (new_hk, new_hv) = jax.lax.scan(
         layer_step, x,
-        (params["layers"], cache["k"], cache["v"], chunk_k, chunk_v),
+        (params["layers"], jnp.arange(L, dtype=jnp.int32), chunk_k, chunk_v),
     )
     x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],

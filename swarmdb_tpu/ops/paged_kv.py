@@ -142,6 +142,18 @@ def pool_flat(pool: Any) -> Any:
     return pool.reshape((-1,) + pool.shape[2:])
 
 
+def pools_flat(pool_k: Any, pool_v: Any) -> Tuple[Any, Any, int, int]:
+    """``(k_flat, v_flat, L, P)`` of a K/V pool pair: layer ``l``'s page
+    ``p`` is page ``l * P + p`` of the flat views, so a layer scan hands
+    the attention dispatchers ``table + l * P`` and reads the pool in
+    place — scanning the pool itself makes XLA copy each layer's slice
+    out before a kernel can take it. ``P`` is the pool's own (under
+    ``shard_map``: the shard's local) page count; page 0 of every layer
+    stays that layer's trash page."""
+    L, P = pool_data(pool_k).shape[:2]
+    return pool_flat(pool_k), pool_flat(pool_v), L, P
+
+
 def pool_page_bytes(pool: Any) -> int:
     """HBM bytes ONE page id of this pool occupies ACROSS layers, scale
     rows included — prices swarmmem's warm-tier H2D model (a page's

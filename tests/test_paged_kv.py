@@ -474,6 +474,74 @@ def test_mixtral_paged_chunked_matches_paged():
                                rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("family,kv,pallas,inactive", [
+    ("llama", "f32", "0", False),
+    ("llama", "f32", "1", False),
+    ("llama", "f32", "0", True),
+    ("llama", "f32", "1", True),
+    ("mixtral", "f32", "0", False),
+    ("mixtral", "f32", "1", True),
+    ("llama", "int8", "0", True),
+    ("llama", "int8", "1", False),
+])
+def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
+                                                    pallas, inactive):
+    """Three layers, a pool whose every page differs, rows with their own
+    tables and prefix lengths: the chunked forward (flat pool view +
+    ``table + l * P``; gather path and interpreted kernel) must give the
+    logits of ``forward_paged`` stepped on the same pool. An inactive row
+    (zeroed table) sends layer ``l`` to its own trash page ``l * P``.
+    int8: the per-step reference runs on the dequantized pool, so the
+    two sides read the same values."""
+    import dataclasses
+
+    from swarmdb_tpu.models import mixtral
+    from swarmdb_tpu.ops.paged_kv import (QuantPool, _dequantize_pages,
+                                          _quantize_pages)
+
+    mod, base = ((llama, TINY_DEBUG) if family == "llama"
+                 else (mixtral, TINY_MOE))
+    cfg = dataclasses.replace(base, n_layers=3)
+    params = mod.init_params(cfg, jax.random.PRNGKey(3), dtype=jnp.float32)
+    rng = np.random.default_rng(11)
+    B, ps, maxp, Kc = 3, 4, 4, 4
+    P = 1 + B * maxp
+    shape = (cfg.n_layers, P, ps, cfg.n_kv_heads, cfg.head_dim)
+    k = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    v = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    chunk_pool = {"k": k, "v": v}
+    if kv == "int8":
+        chunk_pool = {n: QuantPool(*_quantize_pages(a))
+                      for n, a in chunk_pool.items()}
+        k, v = (_dequantize_pages(*chunk_pool[n]) for n in ("k", "v"))
+    table = np.asarray([[3, 1, 12, 7], [9, 10, 2, 0], [5, 6, 4, 8]], np.int32)
+    starts = np.asarray([9, 5, 0], np.int32)   # row 2: empty prefix
+    live = np.asarray([True, True, True])
+    if inactive:
+        table[1] = 0
+        live[1] = False
+    table = jnp.asarray(table)
+    step_pool = {"k": k, "v": v, "page_table": table}
+    chunk_pool["page_table"] = table
+    chunk = (jnp.zeros((cfg.n_layers, B, Kc, cfg.n_kv_heads, cfg.head_dim),
+                       jnp.float32),) * 2
+
+    tok = jnp.asarray([[3], [9], [27]], jnp.int32)
+    for step in range(Kc):
+        pos = jnp.asarray(starts[:, None] + step, jnp.int32)
+        monkeypatch.setenv("SWARMDB_PALLAS", "0")
+        l_ref, step_pool = mod.forward_paged(params, cfg, tok, pos, step_pool)
+        monkeypatch.setenv("SWARMDB_PALLAS", pallas)
+        l_chk, chunk = mod.forward_paged_chunked(
+            params, cfg, tok, pos, chunk_pool, chunk,
+            jnp.asarray(step, jnp.int32))
+        assert np.all(np.isfinite(np.asarray(l_chk)))
+        np.testing.assert_allclose(np.asarray(l_ref)[live],
+                                   np.asarray(l_chk)[live],
+                                   rtol=1e-4, atol=1e-4)
+        tok = jnp.argmax(l_ref[:, -1], axis=-1).astype(jnp.int32)[:, None]
+
+
 def test_paged_pos0_rope_offset():
     """cache["pos0"] offsets RoPE only: zero offset reproduces the
     pre-pos0 behavior bit-for-bit, a nonzero offset changes logits (the

@@ -158,6 +158,54 @@ def test_sharded_paged_engine_matches_dense_sharded():
     assert dense == paged, (dense, paged)
 
 
+def test_sharded_paged_chunked_matches_unsharded_engine():
+    """Chunked decode under ``shard_map`` reads the SHARD's pool through
+    its flat view, so the per-layer table offset is the shard's local
+    page count: three layers over a 4-way data axis, concurrent requests
+    on every shard, greedy tokens equal to the single-device paged
+    engine's (same weights, same prompts)."""
+    import threading
+
+    from swarmdb_tpu.backend.engine import GenRequest
+    from swarmdb_tpu.backend.sampling import SamplingParams
+    from swarmdb_tpu.backend.service import build_backend_engine
+
+    cfg = get_config("tiny-debug", n_layers=3)
+    prompts = [[1, 5, 9, 13, 2], list(range(3, 40)), [7, 7, 7], [4, 4],
+               [9, 8, 7, 6, 5, 4], [11], [2, 3, 5, 7, 11, 13, 17], [6] * 12]
+    kw = dict(max_batch=8, max_seq=64, seed=0, paged=True, page_size=8,
+              decode_chunk=4)
+
+    def run(engine):
+        out, done = {}, threading.Event()
+
+        def on_done(i):
+            def cb(rid, toks, reason):
+                out[i] = list(toks)
+                if len(out) == len(prompts):
+                    done.set()
+            return cb
+
+        engine.start()
+        try:
+            for i, p in enumerate(prompts):
+                engine.submit(GenRequest(
+                    prompt=p, on_done=on_done(i),
+                    sampling=SamplingParams(max_new_tokens=10,
+                                            temperature=0.0)))
+            assert done.wait(600), f"{len(out)} of {len(prompts)} finished"
+        finally:
+            engine.stop()
+        return [out[i] for i in range(len(prompts))]
+
+    sharded, _sm = build_serving_engine(
+        cfg, make_mesh(4, data=4, model=1, expert=1), admit_overlap=False,
+        **kw)
+    assert sharded.paged.allocator.n_shards == 4
+    single, _tok = build_backend_engine(cfg, **kw)
+    assert run(sharded) == run(single)
+
+
 def test_sharded_paged_requires_pure_dp_mesh():
     mesh = make_mesh(8, data=4, model=2, expert=1)
     with pytest.raises(ValueError, match="pure-DP"):

@@ -123,3 +123,42 @@ def test_dense_decode_compiles_to_its_span_limit(one_chip):
     with pytest.raises(ValueError, match="whole KV lanes in VMEM"):
         _compile(one_chip, ap.decode_gqa_attention,
                  Q, lane(SPAN), lane(SPAN), ROW)
+
+
+@pytest.mark.parametrize("kv_dtype", ("bfloat16", "int8"))
+def test_chunked_decode_forward_reads_pool_in_place(one_chip, monkeypatch,
+                                                    kv_dtype):
+    """The whole chunked decode forward over a multi-layer pool plans no
+    temporary as large as one layer's K slice: the layer scan hands the
+    kernel the flat pool and ``table + l * P``. Scanning the pool made
+    XLA copy each layer's K and V slice out in every step (two
+    pool-slice temporaries, 30% of the device's time in the chat cell)."""
+    from swarmdb_tpu.models import llama
+    from swarmdb_tpu.models.configs import ModelConfig
+
+    cfg = ModelConfig(name="aot-4-layers", vocab_size=1024, dim=HQ * D,
+                      n_layers=4, n_heads=HQ, n_kv_heads=HKV, ffn_dim=1024)
+    pool_pages = 4096
+    # layers.py asks the backend to choose kernel or gather fallback
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+    def shapes(fn):
+        return jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+            jax.eval_shape(fn))
+
+    params = shapes(lambda: llama.init_params(cfg, jax.random.PRNGKey(0)))
+    cache = shapes(lambda: llama.init_paged_cache(
+        cfg, B, SPAN, pool_pages, PS, dtype=jnp.dtype(kv_dtype)))
+    chunk = shapes(lambda: llama.init_chunk_kv(cfg, B, KC))
+    tok = jax.ShapeDtypeStruct((B, 1), I32, sharding=one_chip)
+    step = jax.ShapeDtypeStruct((), I32, sharding=one_chip)
+
+    compiled = jax.jit(
+        lambda p, t, pos, c, ck, s: llama.forward_paged_chunked(
+            p, cfg, t, pos, c, ck, s)
+    ).lower(params, tok, tok, cache, chunk, step).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    k = jax.tree.leaves(cache["k"])[0]          # payload [L, P, ps, Hkv, D]
+    layer_slice = k.size // cfg.n_layers * k.dtype.itemsize
+    assert compiled.memory_analysis().temp_size_in_bytes < layer_slice
