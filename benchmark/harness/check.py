@@ -17,7 +17,19 @@ about three times that. Near the maximum, neighbouring logits lie about
 pages, a bf16 accumulator) is meant to fail here: that is another result,
 and a benchmark PR decides its tolerance and says why. A kernel that
 mis-tiles or mis-masks moves the chosen token to a typical logit, 4-5
-below the maximum."""
+below the maximum.
+
+What PR 29 read against this (``PERF.md`` section 6 and 7; nothing here
+changed for it). The largest gap of a sample separates a broken kernel or
+sampler from a sound run, and hardly a lower precision: int8 pages read
+at most 0.048 at the tests' tiny widths, and int8-rounded keys and values
+0.064-0.125 against a sound 0.032-0.054 at hidden 4096, so the sentence
+above on int8 states an intent that the readings do not bear. Under an
+architecture that chooses (experts by a router) the rule is not steady
+either: at a near tie a correct bf16 program chooses otherwise than the
+float32 reference and a logit jumps by 0.1-2 with nothing wrong, so such
+a configuration is first held when the program reports its choices and
+its reference follows them."""
 
 from __future__ import annotations
 

@@ -28,7 +28,21 @@ computed for every token of a block and the unchosen ones are weighted 0
 (plain, and dropless by construction; at E / top_k times the arithmetic);
 logits are computed only at the positions asked for. The attention half is
 a copy of ``decoder.py``'s and not an import, so that each reference reads
-whole and an architecture that changes attention changes its own file."""
+whole and an architecture that changes attention changes its own file.
+
+What this file can hold a program to (``PERF.md`` section 6, PR 29): the
+choice of experts is a step function of the router's logits, so where the
+k-th and the (k+1)-th lie closer than bf16's noise a correct program
+chooses another expert than this file and its logits jump. At the tests'
+tiny widths (top-2 of 4, 2 layers) one position in a hundred does, and
+``tiny-moe.chat`` reads a gap over the tolerance on some seeds with
+nothing wrong; at published router widths 5-59% of positions do. Setting
+aside the positions whose own router margin is small was measured and
+does not carry such widths, because the changed choices of a position's
+context move it as far. A cell of a routed configuration therefore waits
+for a program that reports its chosen experts and a reference that
+follows them (``benchmark/calibrate_routing.py``'s ``followed_*``
+readings)."""
 
 from __future__ import annotations
 

@@ -308,6 +308,10 @@ def measure(stack, cell, args, seconds, devs, tmp, out_dir, facts) -> int:
     result["device"] = device
     if args.platform == "cpu":
         result["rehearsal"] = "cpu: no number here is a device metric"
+    log(f"compared: logit gaps {[round(g, 4) for g in gaps]} each <= "
+        f"{check.LOGIT_TOL}; {len(faults)} reply faults, {compiles} "
+        f"compiles in the window, both == 0; {len(sample)} records >= "
+        f"{min(CHECK_SAMPLE, len(win))}; correct {correct}")
     print(json.dumps(facts), flush=True)
     if out_dir:
         with open(os.path.join(out_dir, "facts.json"), "w") as f:

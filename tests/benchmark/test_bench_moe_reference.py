@@ -12,6 +12,7 @@ import sys
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 ROOT = Path(__file__).resolve().parents[2]
 sys.path.insert(0, str(ROOT))
@@ -116,19 +117,30 @@ def test_reference_and_program_disagree_where_the_program_drops():
     assert gap.min() > LOGIT_TOL, gap.min()
 
 
-def test_a_whole_tiny_moe_run_ends_in_the_contract_line(tmp_path):
+def whole_run(tmp_path, seed, code=None):
+    """``run.py`` of ``tiny-moe.chat`` in a process of its own; ``code``
+    runs there first."""
     env = dict(os.environ, JAX_PLATFORMS="cpu", TMPDIR=str(tmp_path),
                JAX_COMPILATION_CACHE_DIR=str(tmp_path / "xla"))
     env.pop("XLA_FLAGS", None)
-    proc = subprocess.run(
-        [sys.executable, str(ROOT / "benchmark" / "run.py"), "--spec",
-         str(TINY / "spec.json"), "--workload", "tiny-moe.chat",
-         "--platform", "cpu", "--seed", str(2 ** 31 + 11), "--seconds", "3",
-         "--trace", "0"],
-        cwd=tmp_path, env=env, capture_output=True, text=True, timeout=900)
+    run_py = str(ROOT / "benchmark" / "run.py")
+    argv = [run_py, "--spec", str(TINY / "spec.json"), "--workload",
+            "tiny-moe.chat", "--platform", "cpu", "--seed", str(seed),
+            "--seconds", "3", "--trace", "0"]
+    start = ([run_py] if code is None else
+             ["-c", f"import runpy, sys; sys.argv = {argv!r}\n"
+                    f"sys.path.insert(0, {str(ROOT)!r})\n{code}\n"
+                    f"runpy.run_path({run_py!r}, run_name='__main__')"])
+    proc = subprocess.run([sys.executable, *start, *argv[1:]], cwd=tmp_path,
+                          env=env, capture_output=True, text=True,
+                          timeout=900)
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = proc.stdout.strip().splitlines()
-    out, facts = json.loads(lines[-1]), json.loads(lines[-2])
+    return json.loads(lines[-1]), json.loads(lines[-2]), proc.stderr
+
+
+def test_a_whole_tiny_moe_run_ends_in_the_contract_line(tmp_path):
+    out, facts, err = whole_run(tmp_path, 2 ** 31 + 11)
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] > 0
     assert set(out["metrics"]) == {"reply_p90_ms", "ttft_p90_ms",
@@ -138,3 +150,21 @@ def test_a_whole_tiny_moe_run_ends_in_the_contract_line(tmp_path):
     assert facts["reference"] == "benchmark/reference/moe_decoder.py"
     assert facts["logit_gaps"] and max(facts["logit_gaps"]) <= facts[
         "logit_tol"]
+    # every number compared stands beside its limit at the end of stderr
+    last = err.strip().splitlines()[-1]
+    assert "compared: logit gaps" in last and "correct True" in last
+
+
+def test_a_broken_sampler_under_the_engine_is_not_correct(tmp_path):
+    """The timed path broken where a token is produced: every decode
+    program takes the second-best token. The rest of the run is as it is,
+    the replies are whole, and ``correct`` comes out false on the gaps."""
+    code = ("import jax.numpy as jnp\n"
+            "import swarmdb_tpu.backend.engine as engine\n"
+            "engine.sample_tokens = lambda logits, *a, **k: jnp.argsort("
+            "logits, axis=-1)[:, -2].astype(jnp.int32)")
+    out, facts, err = whole_run(tmp_path, 2 ** 31 + 11, code)
+    assert out["correct"] is False and out["failed"] == 0
+    assert facts["reply_faults"] == [] and facts["compiles_in_window"] == 0
+    assert max(facts["logit_gaps"]) > facts["logit_tol"]
+    assert "correct False" in err.strip().splitlines()[-1]
