@@ -134,6 +134,11 @@ Expr = Tuple[Any, ...]
 _COMPOSITE = ("add", "mul", "floordiv", "mod", "min", "max")
 
 
+# ``<array>.dtype.itemsize`` and a scratch buffer declared with
+# ``<array>.dtype``: one symbol, bound by the dispatcher (`estimate_vmem`)
+_ITEMSIZE: Expr = ("sym", "itemsize")
+
+
 def _c(n: int) -> Expr:
     return ("const", int(n))
 
@@ -446,6 +451,15 @@ class _ModuleInfo:
             n.name: n for n in src.tree.body
             if isinstance(n, ast.FunctionDef)
         }
+        # module-level ``NAME = <integer expression>`` (a kernel's tile
+        # and budget constants), so a footprint that uses one is a number
+        self.constants: Dict[str, Expr] = {}
+        for n in src.tree.body:
+            if (isinstance(n, ast.Assign) and len(n.targets) == 1
+                    and isinstance(n.targets[0], ast.Name)):
+                v = _eval(n.value, _Env(), self)
+                if v[0] == "const":
+                    self.constants[n.targets[0].id] = v
 
 
 _INLINE_DEPTH = 6
@@ -468,6 +482,8 @@ def _eval(node: ast.expr, env: _Env, mod: _ModuleInfo,
             return ("data",)
         if node.id in env.vars:
             return env.vars[node.id]
+        if node.id in mod.constants:
+            return mod.constants[node.id]
         return ("sym", node.id)
     if isinstance(node, ast.UnaryOp):
         v = _eval(node.operand, env, mod, depth + 1)
@@ -499,6 +515,8 @@ def _eval(node: ast.expr, env: _Env, mod: _ModuleInfo,
         return _eval_subscript(node, env, mod, depth)
     if isinstance(node, ast.Call):
         return _eval_call(node, env, mod, depth)
+    if isinstance(node, ast.Attribute) and node.attr == "itemsize":
+        return _ITEMSIZE       # of the pool the dispatcher binds
     return ("opaque", _safe_unparse(node))
 
 
@@ -1109,11 +1127,14 @@ def _scratch_bytes(node: ast.expr, env: _Env, mod: _ModuleInfo) -> \
     if name == "SMEM":
         return None, None, False
     shp = _resolve_node(node.args[0] if node.args else None, env)
-    esize = _esize_of(node.args[1] if len(node.args) > 1 else None) or 4
+    dt = node.args[1] if len(node.args) > 1 else None
+    esize = _esize_of(dt) or 4
     if not isinstance(shp, ast.Tuple):
         return None, None, True
     total: Expr = _c(esize)
     conc: Optional[int] = esize
+    if isinstance(dt, ast.Attribute) and dt.attr == "dtype":
+        total, conc = _ITEMSIZE, None    # a buffer of the operand's dtype
     for el in shp.elts:
         d = _eval(el, env, mod)
         total = _mul(total, d)
@@ -1137,7 +1158,9 @@ def _check_vmem(src: SourceFile, site: _Site,
                       site.out_esizes[i] if i < len(site.out_esizes)
                       else None))
     for spec, esize in pairs:
-        if spec.memory_space == "SMEM":
+        if spec.memory_space in ("SMEM", "ANY"):
+            # SMEM is not VMEM; an ANY operand stays where it is (HBM)
+            # and the kernel copies what it needs into its own scratch
             continue
         sym, conc = _block_bytes(spec, esize)
         if sym is None:

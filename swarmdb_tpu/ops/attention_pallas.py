@@ -1,17 +1,24 @@
 """Pallas TPU kernels: decode-step GQA attention (dense slot cache and
-ragged block-paged cache).
+ragged block-paged cache) and ragged paged prefill attention.
 
 The serving hot path (engine decode chunks) issues attention with ONE query
 per slot against that slot's cache lane. The XLA einsum path materializes
-fp32 scores [B, Hq, S] in HBM between ops; this kernel keeps each
-(batch, kv-head) tile's scores in VMEM: one MXU dot for q·K, masked softmax
-in registers, one dot against V — per grid cell the only HBM traffic is the
-cache lane itself, which is the unavoidable read.
+fp32 scores [B, Hq, S] in HBM between ops; these kernels keep the scores
+in VMEM: per-kv-head MXU dots for q·K, masked softmax in registers, one
+dot against V — the only HBM traffic is the cache itself, which is the
+unavoidable read.
 
-Layout (grid = (B, Hkv)):
-- q block   [1, G, D]   — the G = Hq/Hkv query heads sharing this kv head
-- k/v block [1, S, 1, D] — the full cache lane for this (slot, kv head)
-- length    [1] in SMEM  — valid prefix length (= q position + 1)
+The paged engine's default decode kernel is
+`paged_decode_gqa_attention_chunked`: one grid step a row, the pools left
+in HBM, and a loop inside the step that copies the row's LIVE pages, a
+block of pages a trip, into a double buffer — so a call costs what the
+contexts hold, not what the page table could hold. Its per-step-write twin
+and the int8 variants still walk a `(B, maxp)` grid, one page a step.
+
+Dense whole-lane kernel, layout (grid = (B,)):
+- q block   [1, Hq, D]      — all query heads of the slot
+- k/v block [1, S, Hkv, D]  — the slot's full cache lane, all kv heads
+- lengths   [B] in SMEM     — valid prefix length (= q position + 1)
 
 Single-chip path only: under tensor parallelism the cache's head axis is
 sharded and this call would force a gather; the engine enables the kernel
@@ -121,22 +128,38 @@ def decode_gqa_attention(
 # ---------------------------------------------------------------------------
 # Ragged PAGED decode attention (ops/paged_kv.py pool layout).
 #
-# Grid (B, maxp) with the page axis innermost; the page TABLE and the
-# per-slot lengths ride as scalar-prefetch operands so each grid step's
-# BlockSpec index_map can pick the right physical page — the standard TPU
-# paged-attention pattern (PrefetchScalarGridSpec). Each iteration loads ONE
-# page across ALL kv heads ([1, ps, Hkv, D] — the Hkv axis may not be
-# sliced: Mosaic requires the last two block dims be (8, 128)-divisible or
-# whole, and a (…, 1, D) per-head block violates the sublane rule) and a
-# static unroll over the Hkv heads runs the online softmax per head, exactly
-# like the dense kernel above. Two properties give the bandwidth win over
-# the XLA gather path:
-#   1. dead iterations (j beyond the slot's live pages) remap to the SAME
-#      page as the last live step, and Pallas skips the DMA for a block
-#      whose indices didn't change — so HBM traffic is ~live pages, not
-#      maxp pages;
-#   2. scores/softmax state stay in VMEM scratch across the page loop
-#      (online softmax), so nothing but the output tile is written back.
+# The page TABLE and the per-slot lengths ride as scalar-prefetch operands
+# (PrefetchScalarGridSpec, SMEM). A page is read across ALL kv heads
+# ([ps, Hkv, D] — the Hkv axis may not be sliced: Mosaic requires the last
+# two block dims be (8, 128)-divisible or whole, and a (…, 1, D) per-head
+# block violates the sublane rule) and a static unroll over the Hkv heads
+# runs the online softmax per head, exactly like the dense kernel above.
+# Scores and softmax state stay in VMEM scratch across the pages (online
+# softmax), so nothing but the output tile is written back. Two ways of
+# walking a row's pages live here:
+#
+#   * `_paged_chunk_attn_kernel` (the chunked decode path, what the engine
+#     runs): grid (B,), one step a row. The pools are operands in ANY
+#     space (HBM, exactly as `pools_flat` hands them over: [L*P, ps, Hkv,
+#     D] with the table already offset by l * P — no copy, no layout
+#     change) and the step loops over the row's live pages in blocks of
+#     `_pages_per_block` pages: ceil(live pages / block) trips, read from
+#     the prefetched ``starts``. Each trip's pages are copied by the
+#     kernel itself (`make_async_copy`, one contiguous page a DMA, DMA
+#     semaphores) into one half of a double buffer while the other half
+#     is computed on, and folded into the softmax as ONE [block * ps]-
+#     token tile. A row with an empty prefix makes no trip and starts no
+#     DMA; pages past a row's last live one are never fetched. The cost
+#     of a call follows the contexts.
+#   * `_paged_attn_kernel` (per-step-write path, SWARMDB_CHUNKED=0) and
+#     the `_quant` twins: grid (B, maxp) with the page axis innermost, one
+#     page a grid step through a BlockSpec whose index_map picks the
+#     physical page. Dead iterations (j beyond the slot's live pages)
+#     remap to the SAME page as the last live step, and Pallas skips the
+#     DMA for a block whose indices didn't change — HBM traffic is ~live
+#     pages, but every dead step is still a grid step (about 0.18 us on
+#     v5e: 0.7 ms a call at a 256-page table whatever the rows hold,
+#     PERF.md section 6, PR 30).
 
 
 def _online_update(h, s, v, acc_ref, m_ref, l_ref):
@@ -217,52 +240,127 @@ def _paged_attn_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
 
 
-def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, k_ref,
-                             v_ref, ck_ref, cv_ref, o_ref, acc_ref, m_ref,
-                             l_ref, *, page_size: int, n_kv_heads: int,
-                             window):
+# VMEM the chunked decode kernel's page loop may hold for one block of
+# pages: both pools' double buffers and the f32 working copies of the
+# block being computed on. `_pages_per_block` sizes a block under it.
+_PAGE_BLOCK_VMEM_BYTES = 4 * 1024 * 1024
+# tokens a block: one lane width of scores a head. On v5e at 32/8 heads
+# of 128, page 16 (PERF.md, PR 30): 5 live rows of 300-1,000 tokens in
+# 16 take 0.079 ms a call at 128, 0.097 at 256, 0.102 at 64; 16 rows of
+# 4,088 tokens 0.90 / 1.11 / 1.35 ms
+_PAGE_BLOCK_TOKENS = 128
+
+
+def _pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
+                     itemsize: int, maxp: int) -> int:
+    """Pages one trip of the chunked decode kernel's page loop takes: a
+    block of `_PAGE_BLOCK_TOKENS` tokens (one lane width of scores a
+    head), fewer where a block's buffers would pass
+    `_PAGE_BLOCK_VMEM_BYTES`, never less than one page nor more than the
+    table holds. From shapes alone: nothing else chooses it."""
+    per_token = n_kv_heads * head_dim * (4 * itemsize + 8)
+    tokens = min(_PAGE_BLOCK_TOKENS, _PAGE_BLOCK_VMEM_BYTES // per_token)
+    return max(1, min(tokens // page_size, maxp))
+
+
+def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, k_hbm,
+                             v_hbm, ck_ref, cv_ref, o_ref, kbuf_ref,
+                             vbuf_ref, sem_ref, acc_ref, m_ref, l_ref, *,
+                             page_size: int, n_kv_heads: int,
+                             pages_per_block: int, window):
     """Ragged paged attention + in-chunk segment under ONE online softmax.
 
-    Grid (B, maxp+1): iterations j < maxp stream the slot's live pages
-    (the FROZEN prefix, valid strictly below the chunk start); iteration
-    j == maxp processes the [Kc] chunk buffer (entries 0..step) and
-    finalizes. The page loop's DMA skipping (dead iterations re-point at
-    the last live page) is unchanged from `_paged_attn_kernel`.
+    Grid (B,): one step a row. The row's FROZEN prefix (valid strictly
+    below the chunk start) is walked in blocks of ``pages_per_block``
+    pages, ``ceil(live pages / pages_per_block)`` trips read from the
+    scalar-prefetched ``starts``: an empty row makes none. A trip waits
+    for its block's page DMAs (pool -> one half of the double buffer,
+    one contiguous page a copy, ids from the scalar-prefetched table),
+    starts the next block's into the other half, and folds its
+    ``pages_per_block * page_size`` tokens into the online softmax.
+    Pages past the row's last live one are never fetched. Then the [Kc]
+    chunk buffer (entries 0..step) and the finalize.
     """
     b = pl.program_id(0)
-    j = pl.program_id(1)
-    maxp = pl.num_programs(1) - 1
     start = start_ref[b]              # frozen prefix length = chunk start
     step = step_ref[0]
     Hq, D = q_ref.shape[1], q_ref.shape[2]
     Hkv = n_kv_heads
+    ps, ppb = page_size, pages_per_block
+    tile = ppb * ps
+    maxp = table_ref.shape[1]
+    # truncating lax.div on non-negative numerators, as `_last_live_page`
+    live_pages = jnp.minimum(
+        jax.lax.div(jax.lax.max(start, 0) + (ps - 1), jnp.int32(ps)), maxp)
+    n_blocks = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
 
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when((j < maxp) & (j * page_size < start))
-    def _pages():
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
+    def page_copies(blk, slot, i):
+        dst = pl.ds(i * ps, ps)
+        pid = table_ref[b, blk * ppb + i]
+        return (pltpu.make_async_copy(k_hbm.at[pid], kbuf_ref.at[slot, dst],
+                                      sem_ref.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pid], vbuf_ref.at[slot, dst],
+                                      sem_ref.at[1, slot]))
+
+    def fetch(blk, slot):
+        for i in range(ppb):
+            live = blk * ppb + i < live_pages
+
+            @pl.when(live)
+            def _start():
+                for cp in page_copies(blk, slot, i):
+                    cp.start()
+
+            @pl.when(jnp.logical_not(live))
+            def _blank():
+                # a page of the tail block that is not fetched: its keys
+                # are masked, its values must still be finite (0 * NaN)
+                vbuf_ref[slot, pl.ds(i * ps, ps)] = jnp.zeros(
+                    (ps,) + vbuf_ref.shape[2:], vbuf_ref.dtype)
+
+    def wait(blk, slot):
+        for i in range(ppb):
+            @pl.when(blk * ppb + i < live_pages)
+            def _wait():
+                for cp in page_copies(blk, slot, i):
+                    cp.wait()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        fetch(0, 0)
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            fetch(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        pos = blk * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
         valid = pos < start
         if window is not None:
             valid &= pos > (start + step - window)
-        _attend_tile(q_ref, k_ref, v_ref, valid, Hkv, acc_ref, m_ref, l_ref)
+        _attend_tile(q_ref, kbuf_ref.at[pl.ds(slot, 1)],
+                     vbuf_ref.at[pl.ds(slot, 1)], valid, Hkv, acc_ref,
+                     m_ref, l_ref)
+        return carry
 
-    @pl.when(j == maxp)
-    def _chunk():
-        Kc = ck_ref.shape[1]
-        idx = jax.lax.broadcasted_iota(jnp.int32, (1, Kc), 1)
-        valid = idx <= step
-        if window is not None:
-            valid &= (start + idx) > (start + step - window)
-        _attend_tile(q_ref, ck_ref, cv_ref, valid, Hkv, acc_ref, m_ref, l_ref)
+    jax.lax.fori_loop(0, n_blocks, block, 0)
 
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
+    Kc = ck_ref.shape[1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, Kc), 1)
+    valid = idx <= step
+    if window is not None:
+        valid &= (start + idx) > (start + step - window)
+    _attend_tile(q_ref, ck_ref, cv_ref, valid, Hkv, acc_ref, m_ref, l_ref)
+
+    denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
 
 
 def _last_live_page(n, ps):
@@ -275,7 +373,7 @@ def _last_live_page(n, ps):
 @functools.partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_gqa_attention_chunked(
     q: jnp.ndarray,           # [B, Hq, D] one decode query per slot
-    k_pages: jnp.ndarray,     # [P, ps, Hkv, D] FROZEN single-layer pool
+    k_pages: jnp.ndarray,     # [P, ps, Hkv, D] FROZEN pool (or flat [L*P, ..])
     v_pages: jnp.ndarray,
     page_table: jnp.ndarray,  # [B, maxp] int32
     chunk_k: jnp.ndarray,     # [B, Kc, Hkv, D] chunk buffer
@@ -285,42 +383,41 @@ def paged_decode_gqa_attention_chunked(
     window=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Two-segment ragged paged decode attention; returns [B, Hq, D]."""
+    """Two-segment ragged paged decode attention; returns [B, Hq, D].
+    The pools stay in HBM as they are handed over; the kernel copies each
+    row's live pages itself, so its cost follows ``starts``, not the
+    table's width."""
     B, Hq, D = q.shape
     _, ps, Hkv, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = Hq // Hkv
+    Kc = chunk_k.shape[1]
+    ppb = _pages_per_block(ps, Hkv, D, k_pages.dtype.itemsize, maxp)
     table = page_table.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
     step_arr = jnp.reshape(step, (1,)).astype(jnp.int32)
 
-    def q_map(b, j, table_ref, start_ref, step_ref):
+    def q_map(b, table_ref, start_ref, step_ref):
         return (b, 0, 0)
 
-    def kv_map(b, j, table_ref, start_ref, step_ref):
-        # dead/trailing iterations re-point at the last live page so their
-        # DMA is skipped; empty prefix -> table[b, 0]
-        last_live = _last_live_page(start_ref[b], ps)
-        return (table_ref[b, jnp.minimum(j, last_live)], 0, 0, 0)
-
-    def chunk_map(b, j, table_ref, start_ref, step_ref):
+    def chunk_map(b, table_ref, start_ref, step_ref):
         return (b, 0, 0, 0)
-
-    def o_map(b, j, table_ref, start_ref, step_ref):
-        return (b, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, maxp + 1),
+        grid=(B,),
         in_specs=[
             pl.BlockSpec((1, Hq, D), q_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, chunk_k.shape[1], Hkv, D), chunk_map),
-            pl.BlockSpec((1, chunk_k.shape[1], Hkv, D), chunk_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, Kc, Hkv, D), chunk_map),
+            pl.BlockSpec((1, Kc, Hkv, D), chunk_map),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), o_map),
+        out_specs=pl.BlockSpec((1, Hq, D), q_map),
         scratch_shapes=[
+            pltpu.VMEM((2, ppb * ps, Hkv, D), k_pages.dtype),  # K halves
+            pltpu.VMEM((2, ppb * ps, Hkv, D), v_pages.dtype),  # V halves
+            pltpu.SemaphoreType.DMA((2, 2)),         # [pool, half]
             pltpu.VMEM((Hkv, G, D), jnp.float32),    # acc
             pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running max (bcast)
             pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running denom (bcast)
@@ -328,7 +425,8 @@ def paged_decode_gqa_attention_chunked(
     )
     out = pl.pallas_call(
         functools.partial(_paged_chunk_attn_kernel, page_size=ps,
-                          n_kv_heads=Hkv, window=window),
+                          n_kv_heads=Hkv, pages_per_block=ppb,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
@@ -575,11 +673,11 @@ def _dense_chunk_attn_kernel(start_ref, step_ref, q_ref, k_ref, v_ref,
     """Dense two-segment decode attention (the serve-bench hot path):
     stream the FROZEN slot cache in [tile]-token blocks, then fold the
     in-chunk buffer, all under one online softmax. Mirrors
-    `_paged_chunk_attn_kernel` with the page table replaced by the slot's
-    own contiguous lane; dead tiles (>= the slot's chunk start) re-point
-    at the last live tile so their DMA is skipped — HBM traffic scales
-    with each slot's LIVE prefix, which the XLA einsum path (always a
-    full [S] read + materialized fp32 scores) cannot do.
+    `_paged_chunk_attn_kernel_quant`'s grid with the page table replaced
+    by the slot's own contiguous lane; dead tiles (>= the slot's chunk
+    start) re-point at the last live tile so their DMA is skipped — HBM
+    traffic scales with each slot's LIVE prefix, which the XLA einsum
+    path (always a full [S] read + materialized fp32 scores) cannot do.
     """
     b = pl.program_id(0)
     j = pl.program_id(1)
@@ -741,7 +839,9 @@ def paged_decode_gqa_attention(
 # ---------------------------------------------------------------------------
 # Quantized-pool kernel variants (SWARMDB_KV_DTYPE=int8, ISSUE 18).
 #
-# Same grids, same online softmax, same DMA-skip index maps as the three
+# The grid form of the paged kernels (page axis in the grid, DMA-skip
+# index maps: `(B, maxp)`, and `(B, maxp + 1)` for the chunked twin with
+# the chunk segment as its last step), the same online softmax as the
 # kernels above — the ONLY difference is the KV operands: int8 page
 # payloads plus a per-page-per-head f32 scale operand shaped [P, 1, Hkv]
 # (block (1, 1, Hkv), whole in its last two dims — Mosaic-legal — and

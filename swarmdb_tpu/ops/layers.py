@@ -239,6 +239,11 @@ def paged_attention_dispatch_chunked(
             return out[:, None]
         from .attention_pallas import paged_decode_gqa_attention_chunked
 
+        _record_static_vmem(
+            "_paged_chunk_attn_kernel", "kernel:pallas",
+            {"Hq": q.shape[2], "Hkv": kd.shape[2], "D": q.shape[3],
+             "ps": kd.shape[1], "Kc": chunk_k.shape[1],
+             "maxp": page_table.shape[1], "itemsize": kd.dtype.itemsize})
         out = paged_decode_gqa_attention_chunked(
             q[:, 0], k_pages, v_pages, page_table, chunk_k, chunk_v,
             starts, step.astype(jnp.int32),

@@ -72,9 +72,35 @@ def test_static_vmem_table_covers_in_tree_kernels():
     kernels = {r["kernel"] for r in rows}
     assert "_ragged_prefill_kernel" in kernels
     assert "_paged_attn_kernel" in kernels
+    assert "_paged_chunk_attn_kernel" in kernels
     for r in rows:
         assert r["formula"]
         assert r["expr"] is not None
+
+
+def test_static_vmem_of_the_chunked_decode_page_loop():
+    """The chunked paged decode kernel leaves its pools in HBM (ANY
+    space, no block) and copies pages into scratch of the pool's dtype:
+    its row is in the table, and under the dims its dispatcher binds
+    the page buffers are what `_pages_per_block` makes them."""
+    from swarmdb_tpu.ops.attention_pallas import _pages_per_block
+
+    rows = {r["kernel"]: r for r in static_vmem_table()}
+    assert "_paged_chunk_attn_kernel" in rows
+    hq, hkv, d, ps, kc, maxp = 32, 8, 128, 16, 8, 256
+    for itemsize in (2, 4):
+        dims = {"Hq": hq, "Hkv": hkv, "D": d, "ps": ps, "Kc": kc,
+                "maxp": maxp, "itemsize": itemsize}
+        ppb = _pages_per_block(ps, hkv, d, itemsize, maxp)
+        buffers = 2 * 2 * ppb * ps * hkv * d * itemsize   # K, V halves
+        blocks = 2 * 4 * (2 * hq * d + 2 * kc * hkv * d)  # q, out, chunks
+        state = 4 * hq * (d + 2 * 128)                    # acc, max, denom
+        assert estimate_vmem("_paged_chunk_attn_kernel",
+                             dims) == buffers + blocks + state
+    assert _pages_per_block(16, 8, 128, 2, 256) == 8      # the chat cell
+    assert _pages_per_block(16, 8, 128, 2, 4) == 4        # a short table
+    assert _pages_per_block(256, 8, 128, 2, 16) == 1      # a page a block
+    assert estimate_vmem("_paged_chunk_attn_kernel", {"Hq": hq}) is None
 
 
 def test_estimate_vmem_concrete_and_unbound():

@@ -388,6 +388,100 @@ def test_paged_chunked_kernel_matches_fallback(window):
                                rtol=2e-5, atol=2e-5)
 
 
+# the page loop's block at the test shapes below: ps 8, a 40-page table,
+# `_pages_per_block` gives 16 pages = 128 tokens, so the table holds two
+# whole blocks and half a third
+_CPS, _CMAXP, _CBLOCK = 8, 40, 128
+_CFULL = _CPS * _CMAXP
+
+
+def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
+                  dtype=jnp.float32, shuffle=False, layer=None, seed=0):
+    """Kernel (interpret mode) against the gather path on one batch:
+    row b holds ``starts[b]`` frozen tokens in its own pages. ``shuffle``
+    deals the page ids out of order; ``layer`` = (l, L) puts the pool at
+    slice l of a flat [L*P, ...] pool, the table offset by l * P."""
+    from swarmdb_tpu.ops.attention_pallas import (
+        _pages_per_block, paged_decode_gqa_attention_chunked)
+    from swarmdb_tpu.ops.layers import gqa_attention_chunked
+
+    rng = np.random.default_rng(seed)
+    ps, maxp, Kc = _CPS, _CMAXP, 8
+    B = len(starts)
+    assert _pages_per_block(ps, Hkv, D, jnp.dtype(dtype).itemsize,
+                            maxp) * ps == _CBLOCK
+    P = 1 + B * maxp
+    ids = np.arange(1, P)
+    if shuffle:
+        rng.shuffle(ids)
+    table = np.zeros((B, maxp), np.int32)
+    nxt = 0
+    for b, n in enumerate(starts):
+        live = -(-max(int(n), 0) // ps)
+        table[b, :live] = ids[nxt:nxt + live]
+        nxt += live
+    kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
+    vp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
+    q = jnp.asarray(rng.normal(size=(B, 1, Hq, D)), dtype)
+    ck = jnp.asarray(rng.normal(size=(B, Kc, Hkv, D)), dtype)
+    cv = jnp.asarray(rng.normal(size=(B, Kc, Hkv, D)), dtype)
+    starts = jnp.asarray(starts, jnp.int32)
+    step = jnp.asarray(step, jnp.int32)
+    kg, vg = paged_gather_kv(kp, vp, jnp.asarray(table))
+    ref = gqa_attention_chunked(q, kg, vg, ck, cv, (starts + step)[:, None],
+                                step, window=window)[:, 0]
+    pool_k, pool_v, tbl = kp, vp, table
+    if layer is not None:
+        l, L = layer
+
+        def flat(own):   # other layers' pages hold other numbers
+            return jnp.concatenate(
+                [own if i == l else
+                 jnp.asarray(rng.normal(size=own.shape), dtype)
+                 for i in range(L)])
+
+        pool_k, pool_v, tbl = flat(kp), flat(vp), table + l * P
+    out = paged_decode_gqa_attention_chunked(
+        q[:, 0], pool_k, pool_v, jnp.asarray(tbl), ck, cv, starts, step,
+        window=window, interpret=True)
+    assert out.dtype == q.dtype
+    tol = 2e-5 if dtype == jnp.float32 else 3e-2
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kw", [
+    # one prefix length a case, beside an empty row and a mid-block one
+    *[pytest.param(dict(starts=(n, 0, 77)), id=f"prefix-{n}")
+      for n in (0, 1, _CPS, _CBLOCK - 1, _CBLOCK, _CBLOCK + 1,
+                2 * _CBLOCK, _CFULL - 1, _CFULL)],
+    pytest.param(dict(starts=(0, 0, 0)), id="all-empty"),
+    pytest.param(dict(starts=(_CFULL, 0, 1, 0, 0, _CBLOCK + 3, 0, 200)),
+                 id="live-and-empty-rows"),
+    pytest.param(dict(starts=(-2, 9, 0)), id="inactive-row-negative-start"),
+    *[pytest.param(dict(starts=(150, 0, 257), Hq=2 * g, Hkv=2, step=st),
+                   id=f"G{g}-step{st}")
+      for g in (1, 4, 8) for st in (0, 7)],
+    *[pytest.param(dict(starts=(300, 131, 0, 40), step=3, window=w),
+                   id=f"window-{w}")
+      for w in (5, _CBLOCK, 200)],
+    pytest.param(dict(starts=(300, 0, 129), dtype=jnp.bfloat16),
+                 id="bf16-pools"),
+    pytest.param(dict(starts=(300, 17, 0, 129), shuffle=True),
+                 id="page-ids-out-of-order"),
+    *[pytest.param(dict(starts=(300, 0, 129), shuffle=True, layer=(l, 3)),
+                   id=f"flat-pool-layer-{l}")
+      for l in (0, 2)],
+])
+def test_paged_chunked_kernel_walks_live_pages(kw):
+    """The chunked decode kernel's page loop (one grid step a row,
+    blocks of pages copied by the kernel itself) agrees with the gather
+    path wherever a row's prefix ends: before, at and after a block's
+    edge, for an empty row, and at the table's full width."""
+    _chunked_case(**kw)
+
+
 @pytest.fixture(scope="module")
 def chunked_paged_engine():
     """Engine over the paged pool WITH the two-segment chunked decode
