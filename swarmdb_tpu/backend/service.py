@@ -180,22 +180,14 @@ def build_backend_engine(
            else get_config(model_name_or_cfg))
     seq = max_seq or min(cfg.max_seq_len, 1024)
     key = jax.random.PRNGKey(seed)
-    if cfg.is_moe:
-        params = mixtral.init_params(cfg, key)
-        fwd = lambda p, t, pos, c: mixtral.forward(p, cfg, t, pos, c)
-        init_cache = lambda b, s: mixtral.init_kv_cache(cfg, b, s)
-        paged_fwd = lambda p, t, pos, c: mixtral.forward_paged(p, cfg, t,
-                                                               pos, c)
-        init_pool_model = mixtral.init_paged_cache
-        mod = mixtral
-    else:
-        params = llama.init_params(cfg, key)
-        fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
-        init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
-        paged_fwd = lambda p, t, pos, c: llama.forward_paged(p, cfg, t,
-                                                             pos, c)
-        init_pool_model = llama.init_paged_cache
-        mod = llama
+    # one decoder serves both families (models/llama.py); what a family
+    # brings of its own is its parameter tree
+    params = (mixtral if cfg.is_moe else llama).init_params(cfg, key)
+    fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
+    fwd_last = lambda p, t, pos, c, at: llama.forward(
+        p, cfg, t, pos, c, logits_at=at)
+    init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
+    paged_fwd = lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c)
     # two-segment chunked decode — the cache (dense slot buffer OR
     # paged pool) stays frozen per chunk; see Engine._decode /
     # ops.layers. SWARMDB_CHUNKED=0 falls back to per-step cache
@@ -206,27 +198,26 @@ def build_backend_engine(
     # ONE prefix-cache enablement flag shared by paged pool sizing and
     # prefix_fns wiring (review finding: duplicated conditions drift)
     prefix_enabled = (
-        hasattr(mod, "forward_prefix_pages" if paged
-                else "forward_prefix_lane")
-        and os.environ.get("SWARMDB_PREFIX", "1") != "0"
+        os.environ.get("SWARMDB_PREFIX", "1") != "0"
         and seq % page_size == 0
     )
     chunked_fns = None
     if os.environ.get("SWARMDB_CHUNKED", "1") != "0":
-        chunk_fwd = mod.forward_paged_chunked if paged else mod.forward_chunked
+        chunk_fwd = (llama.forward_paged_chunked if paged
+                     else llama.forward_chunked)
         if paged:
-            merge = mod.merge_paged_chunk
+            merge = llama.merge_paged_chunk
         elif os.environ.get("SWARMDB_MERGE", "einsum") == "scatter":
             # scatter-form chunk merge: numerically identical
             # (ops/layers.merge_chunk_kv_scatter); raced against the
             # einsum form on silicon by scripts/profile_merge.py
-            merge = mod.merge_chunk_scatter
+            merge = llama.merge_chunk_scatter
         else:
-            merge = mod.merge_chunk
+            merge = llama.merge_chunk
         chunked_fns = (
             lambda p, t, pos, c, hkv, s: chunk_fwd(p, cfg, t, pos, c,
                                                    hkv, s),
-            lambda b, k: mod.init_chunk_kv(cfg, b, k),
+            lambda b, k: llama.init_chunk_kv(cfg, b, k),
             merge,
         )
 
@@ -247,23 +238,26 @@ def build_backend_engine(
         num_pages = 1 + -(-pool_tokens // page_size)  # +1 trash page
         paged_spec = PagedKV(
             decode_forward=paged_fwd,
-            init_pool=lambda: init_pool_model(
+            init_pool=lambda: llama.init_paged_cache(
                 cfg, max_batch, seq, num_pages, page_size),
             page_size=page_size,
             num_pages=num_pages,
             allocator=make_page_allocator(num_pages, page_size, seq,
                                           max_batch),
         )
-        if hasattr(mod, "forward_ragged_prefill"):
+        if not cfg.is_moe:
             # packed ragged admission waves (ISSUE 11): one no-padding
             # token stream per wave, prefix KV read in place from the
-            # pool. Dense-Llama-family only today (mixtral has no ragged
-            # forward); the engine keeps the row-bucketed path as the
-            # SWARMDB_RAGGED_PREFILL=0 fallback either way.
+            # pool. Dense configurations only: ``moe_block`` reckons its
+            # capacity over the tokens of the call, padding included, so
+            # a packed stream and a row-bucketed wave drop different
+            # tokens. The forward itself takes both families; a routed
+            # one keeps the row-bucketed path until the dropping goes
+            # (ROADMAP Design 13).
             paged_spec.prefill_ragged = (
                 lambda p, toks, trow, tpos, tables, st, ln, pl, pk, pv:
-                    mod.forward_ragged_prefill(p, cfg, toks, trow, tpos,
-                                               tables, st, ln, pl, pk, pv))
+                    llama.forward_ragged_prefill(p, cfg, toks, trow, tpos,
+                                                 tables, st, ln, pl, pk, pv))
 
     # Automatic prefix caching: chat serving re-prefills each
     # conversation's history every turn, so reuse of page-aligned
@@ -282,8 +276,8 @@ def build_backend_engine(
             # suffix-forward core is needed (no side pool, no lane)
             prefix_fns = (
                 lambda p, t, tab, pl, pk, pv, logits_at=None:
-                    mod.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
-                                             logits_at=logits_at),
+                    llama.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
+                                               logits_at=logits_at),
                 None,
             )
         else:
@@ -292,18 +286,12 @@ def build_backend_engine(
             prefix_pages = 1 + -(-prefix_tokens // page_size)  # +1 trash
             prefix_fns = (
                 lambda p, t, tab, pl, pk, pv, lp, logits_at=None:
-                    mod.forward_prefix_lane(p, cfg, t, tab, pl, pk, pv,
-                                            lp, logits_at=logits_at),
-                lambda n, ps: mod.init_prefix_pool(cfg, n, ps),
+                    llama.forward_prefix_lane(p, cfg, t, tab, pl, pk, pv,
+                                              lp, logits_at=logits_at),
+                lambda n, ps: llama.init_prefix_pool(cfg, n, ps),
             )
 
     tokenizer = default_tokenizer(cfg.vocab_size, tokenizer_path)
-    if cfg.is_moe:
-        fwd_last = lambda p, t, pos, c, at: mixtral.forward(
-            p, cfg, t, pos, c, logits_at=at)
-    else:
-        fwd_last = lambda p, t, pos, c, at: llama.forward(
-            p, cfg, t, pos, c, logits_at=at)
     engine = Engine(
         fwd, init_cache, params,
         max_batch=max_batch, max_seq=seq,

@@ -1,6 +1,10 @@
-"""Llama-3 model family — functional JAX implementation.
+"""The decoder, and the Llama-3 family's parameters — functional JAX.
 
 Design (idiomatic TPU, not a torch port):
+- The layer body (``decoder_layer``) and the head (``lm_logits``) are written
+  once. Every forward is its own attention-and-cache step (its ``mixer``)
+  around them, and a model family is its FFN (``_ffn``) and its parameters:
+  a routed configuration's are in models/mixtral.py.
 - Parameters are a plain pytree dict; per-layer weights are STACKED along a
   leading [L, ...] axis and the forward pass is one `lax.scan` over layers —
   one compiled layer body regardless of depth (fast compiles, natural hook
@@ -129,12 +133,6 @@ def init_kv_cache(
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
-def cache_specs(model_axis: str = "model") -> Tuple[P, P]:
-    """KV cache shards its head dim over the model axis, batch over data."""
-    spec = P(None, "data", None, model_axis, None)
-    return spec, spec
-
-
 def init_paged_cache(
     cfg: ModelConfig,
     batch: int,
@@ -155,7 +153,83 @@ def init_paged_cache(
     )
 
 
-# ------------------------------------------------------------------- forward
+# --------------------------------------------------------- the decoder block
+
+
+def _ffn(cfg: ModelConfig, moe_dispatch: Optional[str] = None):
+    """The family's FFN, chosen from the configuration: ``swiglu`` for a
+    dense one, ``mixtral.moe_block`` for one that routes. ``moe_dispatch``
+    pins the routed form's realization (``parallel/serving`` pins
+    ``einsum`` on an expert-sharded mesh); ``None`` keeps the module
+    default (models/mixtral.py docstring)."""
+    if not cfg.is_moe:
+        return lambda h, lp: swiglu(h, lp["w_gate"], lp["w_up"],
+                                    lp["w_down"])
+    from .mixtral import moe_block
+
+    def routed(h, lp):
+        # the router-load aux is for direct moe_block callers (tests,
+        # balance metrics); the serving forwards carry the cache only
+        out, _load = moe_block(
+            h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+            top_k=cfg.experts_per_token, dispatch=moe_dispatch)
+        return out
+
+    return routed
+
+
+def decoder_layer(cfg: ModelConfig, cos, sin, mixer,
+                  moe_dispatch: Optional[str] = None):
+    """THE decoder layer, as the body of a ``lax.scan`` over the stacked
+    layers: attention norm, ``qkv_proj``, the caller's ``mixer``, ``wo``
+    and residual, MLP norm, the family's FFN (``_ffn``), residual. Every
+    forward below scans this one body with a mixer of its own.
+
+    ``mixer(q, k, v, ops)`` is all that differs between the forwards: it
+    takes the layer's RoPE'd projections and the layer's slice ``ops`` of
+    whatever the forward scans beside the weights (cache layers, chunk
+    buffers, a layer index), does its cache write and attention, and
+    returns ``(attn [.., T, Hq, hd], out)``. The body takes
+    ``(x, (layer_params, ops))`` and returns ``(x, out)``, so the scan
+    stacks ``out`` over layers."""
+    ffn = _ffn(cfg, moe_dispatch)
+
+    def layer_step(x, scanned):
+        lp, ops = scanned
+        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
+                           cfg.head_dim, cos, sin)
+        attn, out = mixer(q, k, v, ops)
+        B, T = x.shape[0], x.shape[1]
+        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
+        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+        x = x + ffn(h2, lp)
+        return x, out
+
+    return layer_step
+
+
+def lm_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray,
+              logits_at: Optional[jnp.ndarray] = None,
+              stream_at: Optional[jnp.ndarray] = None) -> jnp.ndarray:
+    """Final norm and LM head, fp32 logits, in the three shapes the
+    forwards use: ``[B, T, V]``; ``[B, V]`` at each row's ``logits_at``
+    position; ``[R, V]`` at the ``stream_at`` offsets of a packed
+    ``[1, W, D]`` stream. A configuration with tied embeddings has no
+    ``lm_head`` and uses the transposed embedding."""
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+    head = params.get("lm_head")
+    if head is None:  # tied embeddings
+        head = params["embed"].T
+    if stream_at is not None:
+        x = x[0, stream_at]                              # [R, D]
+    elif logits_at is not None:
+        x = x[jnp.arange(x.shape[0]), logits_at]         # [B, D]
+    return jnp.einsum("btd,dv->btv" if x.ndim == 3 else "bd,dv->bv",
+                      x, head, preferred_element_type=jnp.float32)
+
+
+# ------------------------------------------------------------------ forwards
 
 
 def forward(
@@ -165,6 +239,7 @@ def forward(
     positions: jnp.ndarray,    # [B, T] int32 absolute positions per row
     cache: KVCache,            # ([L, B, S, Hkv, hd], ...)
     logits_at: Optional[jnp.ndarray] = None,  # [B] int32 row indices into T
+    moe_dispatch: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, KVCache]:
     """One forward pass; returns fp32 logits and updated cache.
 
@@ -178,44 +253,20 @@ def forward(
     materialize (0.5 GB per admission wave at Bp=16, T=255, V=32k, and
     ~7% of prefill FLOPs).
     """
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral.forward")
     x = params["embed"][tokens]  # [B, T, D]; compute dtype = param dtype
-    cache_k, cache_v = cache
     # RoPE terms depend only on positions: compute once, reuse in every
     # scanned layer (XLA can't hoist transcendentals out of the loop body)
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
-    layer_params = params["layers"]
-
-    def layer_step(x, scanned):
-        lp, ck, cv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        ck, cv = write_kv_cache(ck, cv, k, v, positions)
+    def mixer(q, k, v, kv):
+        ck, cv = write_kv_cache(*kv, k, v, positions)
         attn = gqa_attention(q, ck, cv, positions, window=cfg.sliding_window)
-        attn_out = jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        x = x + attn_out
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (ck, cv)
+        return attn, (ck, cv)
 
-    x, (new_k, new_v) = jax.lax.scan(layer_step, x, (layer_params, cache_k, cache_v))
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:  # tied embeddings
-        head = params["embed"].T
-    if logits_at is not None:
-        x = x[jnp.arange(x.shape[0]), logits_at]         # [B, D]
-        logits = jnp.einsum("bd,dv->bv", x, head,
-                            preferred_element_type=jnp.float32)
-        return logits, (new_k, new_v)
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
-    return logits, (new_k, new_v)
+    x, new_cache = jax.lax.scan(
+        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
+        (params["layers"], tuple(cache)))
+    return lm_logits(params, cfg, x, logits_at), new_cache
 
 
 def forward_prefix_pages(
@@ -227,6 +278,7 @@ def forward_prefix_pages(
     pool_k: jnp.ndarray,        # [L, P, ps, Hkv, D] prefix page pool
     pool_v: jnp.ndarray,
     logits_at: Optional[jnp.ndarray] = None,  # [B] int32 row indices into T
+    moe_dispatch: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Prefix-cache suffix prefill CORE: compute ONLY the suffix tokens,
     attending each row's reused prefix pages + the suffix itself
@@ -237,16 +289,13 @@ def forward_prefix_pages(
     Returns (fp32 logits [Bp, T, V] — or [Bp, V] with ``logits_at``, see
     ``forward`` — plus sfx_k, sfx_v [L, Bp, T, Hkv, D]).
     """
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral")
     from ..ops.paged_kv import (_dequantize_pages, is_quantized, pool_data,
                                 pools_flat)
 
     Bp, T = tokens.shape
     quant = is_quantized(pool_k)
     ps = pool_data(pool_k).shape[2]
-    PP = prefix_table.shape[1]
-    Pt = PP * ps
+    Pt = prefix_table.shape[1] * ps
     x = params["embed"][tokens]
     positions = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
@@ -265,37 +314,18 @@ def forward_prefix_pages(
                                                cfg.head_dim)
         return flat[idx].reshape(Bp, Pt, cfg.n_kv_heads, cfg.head_dim)
 
-    def layer_step(x, scanned):
-        lp, l = scanned
+    def mixer(q, k, v, l):
         kp = _gather_pages(pool_k_flat, l * P + prefix_table)
         vp = _gather_pages(pool_v_flat, l * P + prefix_table)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        attn = gqa_attention_prefix(q, kp, vp, k.astype(kp.dtype),
-                                    v.astype(vp.dtype), prefix_lens,
+        k, v = k.astype(kp.dtype), v.astype(vp.dtype)
+        attn = gqa_attention_prefix(q, kp, vp, k, v, prefix_lens,
                                     window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(Bp, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (k.astype(kp.dtype), v.astype(vp.dtype))
+        return attn, (k, v)
 
     x, (sfx_k, sfx_v) = jax.lax.scan(
-        layer_step, x,
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    if logits_at is not None:
-        x = x[jnp.arange(x.shape[0]), logits_at]
-        logits = jnp.einsum("bd,dv->bv", x, head,
-                            preferred_element_type=jnp.float32)
-        return logits, sfx_k, sfx_v
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
-    return logits, sfx_k, sfx_v
+        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    return lm_logits(params, cfg, x, logits_at), sfx_k, sfx_v
 
 
 def forward_ragged_prefill(
@@ -325,13 +355,9 @@ def forward_ragged_prefill(
     LAST live token, sfx_k, sfx_v [L, W, Hkv, D] — packed, stream order,
     for ``ops.paged_kv.paged_write_ragged``).
     """
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; ragged prefill is "
-                         "dense-Llama-only for now")
     from ..ops.layers import ragged_prefill_dispatch
     from ..ops.paged_kv import pool_dtype, pools_flat
 
-    W = tokens.shape[0]
     x = params["embed"][tokens][None]                    # [1, W, D]
     cos, sin = rope_cos_sin(tok_pos[None], cfg.head_dim, cfg.rope_theta)
     pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
@@ -341,11 +367,7 @@ def forward_ragged_prefill(
     lens = lens.astype(jnp.int32)
     plens = prefix_lens.astype(jnp.int32)
 
-    def layer_step(x, scanned):
-        lp, l = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
+    def mixer(q, k, v, l):
         # suffix K/V cast to the pool's LOGICAL dtype BEFORE attention
         # (matching forward_prefix_pages): what this wave attends is
         # bit-identical to what later waves/decodes read back from the
@@ -357,23 +379,13 @@ def forward_ragged_prefill(
         attn = ragged_prefill_dispatch(
             q[0], ks, vs, pool_k_flat, pool_v_flat, tables + l * P,
             starts, lens, plens, tok_row, window=cfg.sliding_window)
-        x = x + jnp.einsum("wh,hd->wd", attn.reshape(W, -1), lp["wo"])[None]
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (ks, vs)
+        return attn, (ks, vs)
 
     x, (sfx_k, sfx_v) = jax.lax.scan(
-        layer_step, x,
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
+        decoder_layer(cfg, cos, sin, mixer), x,
+        (params["layers"], jnp.arange(L, dtype=jnp.int32)))
     last_w = starts + jnp.maximum(lens - 1, 0)           # dead rows -> 0
-    logits = jnp.einsum("rd,dv->rv", x[0, last_w], head,
-                        preferred_element_type=jnp.float32)
-    return logits, sfx_k, sfx_v
+    return lm_logits(params, cfg, x, stream_at=last_w), sfx_k, sfx_v
 
 
 def forward_prefix_lane(
@@ -419,6 +431,13 @@ def init_chunk_kv(
     return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
+def _write_chunk_step(hk, hv, k, v, step):
+    """This decode step's K/V into the chunk buffer at index ``step``."""
+    hk = jax.lax.dynamic_update_slice(hk, k.astype(hk.dtype), (0, step, 0, 0))
+    hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype), (0, step, 0, 0))
+    return hk, hv
+
+
 def forward_chunked(
     params: Params,
     cfg: ModelConfig,
@@ -427,6 +446,7 @@ def forward_chunked(
     cache: KVCache,            # FROZEN during the chunk
     chunk_kv: Tuple[jnp.ndarray, jnp.ndarray],  # [L, B, Kc, Hkv, D] each
     step: jnp.ndarray,         # scalar int32 — index within the chunk
+    moe_dispatch: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
     """Decode step against a frozen cache + in-chunk K/V buffer.
 
@@ -437,40 +457,20 @@ def forward_chunked(
     step's K/V is written at chunk index ``step`` via dynamic_update_slice
     (uniform index across rows, no scatter).
     """
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral")
     x = params["embed"][tokens]  # [B, 1, D]
-    cache_k, cache_v = cache
-    chunk_k, chunk_v = chunk_kv
     cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
-    def layer_step(x, scanned):
-        lp, ck, cv, hk, hv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        hk = jax.lax.dynamic_update_slice(hk, k.astype(hk.dtype),
-                                          (0, step, 0, 0))
-        hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
-                                          (0, step, 0, 0))
+    def mixer(q, k, v, scanned):
+        ck, cv, hk, hv = scanned
+        hk, hv = _write_chunk_step(hk, hv, k, v, step)
         attn = gqa_attention_chunked(q, ck, cv, hk, hv, positions, step,
                                      window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (hk, hv)
+        return attn, (hk, hv)
 
-    x, (new_hk, new_hv) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache_k, cache_v, chunk_k, chunk_v)
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:  # tied embeddings
-        head = params["embed"].T
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
-    return logits, (new_hk, new_hv)
+    x, new_chunk = jax.lax.scan(
+        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
+        (params["layers"], (*cache, *chunk_kv)))
+    return lm_logits(params, cfg, x), new_chunk
 
 
 def merge_chunk(
@@ -498,12 +498,24 @@ def merge_chunk_scatter(
     return merge_chunk_kv_scatter(ck, cv, hk, hv, start_positions)
 
 
+def _paged_rope_terms(cfg: ModelConfig, cache, positions):
+    """RoPE terms for a decode step over the paged pool. Rolling-KV
+    conversations carry a per-row RoPE offset ``pos0``: kept pages' K
+    were rope'd at their original absolute positions, so queries must be
+    too (RoPE scores depend only on position differences). ``positions``
+    itself stays LOGICAL (page writes + masks)."""
+    pos0 = cache.get("pos0")
+    rope_pos = positions if pos0 is None else positions + pos0[:, None]
+    return rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
+
+
 def forward_paged(
     params: Params,
     cfg: ModelConfig,
     tokens: jnp.ndarray,       # [B, 1] int32 — DECODE steps only
     positions: jnp.ndarray,    # [B, 1] int32 absolute positions per row
     cache,                     # {"k": [L,P,ps,Hkv,D], "v": ..., "page_table"}
+    moe_dispatch: Optional[str] = None,
 ):
     """Decode forward over the block-paged KV pool (ops/paged_kv.py).
 
@@ -513,48 +525,26 @@ def forward_paged(
     kernel on TPU (reads only live pages) with an XLA gather fallback.
     Returns fp32 logits [B, 1, V] and the updated cache dict.
     """
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral.forward_paged")
     from ..ops.layers import paged_attention_dispatch
     from ..ops.paged_kv import paged_write_decode
 
     x = params["embed"][tokens]  # [B, 1, D]
     table = cache["page_table"]
-    # rolling-KV conversations carry a per-row RoPE offset: kept pages'
-    # K were rope'd at their original absolute positions, so queries
-    # must be too (RoPE scores depend only on position differences).
-    # ``positions`` stays LOGICAL (page writes + masks)
-    pos0 = cache.get("pos0")
-    rope_pos = positions if pos0 is None else positions + pos0[:, None]
-    cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
+    cos, sin = _paged_rope_terms(cfg, cache, positions)
 
-    def layer_step(x, scanned):
-        lp, kp, vp = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        kp, vp = paged_write_decode(kp, vp, k, v, positions, table)
+    def mixer(q, k, v, pages):
+        kp, vp = paged_write_decode(*pages, k, v, positions, table)
         attn = paged_attention_dispatch(
             q, kp, vp, table, positions, window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (kp, vp)
+        return attn, (kp, vp)
 
     x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
+        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
+        (params["layers"], (cache["k"], cache["v"])))
     out = {"k": new_k, "v": new_v, "page_table": table}
-    if pos0 is not None:
-        out["pos0"] = pos0
-    return logits, out
+    if "pos0" in cache:
+        out["pos0"] = cache["pos0"]
+    return lm_logits(params, cfg, x), out
 
 
 def forward_paged_chunked(
@@ -565,6 +555,7 @@ def forward_paged_chunked(
     cache,                     # {"k","v","page_table"} — FROZEN this chunk
     chunk_kv: Tuple[jnp.ndarray, jnp.ndarray],  # [L, B, Kc, Hkv, D] each
     step: jnp.ndarray,         # scalar int32
+    moe_dispatch: Optional[str] = None,
 ):
     """Two-segment chunked decode over the PAGED pool: the pool stays
     frozen for the chunk's K steps (one bulk page write per chunk via
@@ -573,48 +564,26 @@ def forward_paged_chunked(
     (ops/layers.paged_attention_dispatch_chunked). Like
     ``forward_ragged_prefill`` the layer scan reads the pool in place,
     through its flat view and a per-layer table offset."""
-    if cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is MoE; use models.mixtral")
     from ..ops.layers import paged_attention_dispatch_chunked
     from ..ops.paged_kv import pools_flat
 
     x = params["embed"][tokens]
     table = cache["page_table"]
     pool_k_flat, pool_v_flat, L, P = pools_flat(cache["k"], cache["v"])
-    chunk_k, chunk_v = chunk_kv
-    pos0 = cache.get("pos0")  # rolling-KV RoPE offset (see forward_paged)
-    rope_pos = positions if pos0 is None else positions + pos0[:, None]
-    cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
+    cos, sin = _paged_rope_terms(cfg, cache, positions)
 
-    def layer_step(x, scanned):
-        lp, l, hk, hv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        hk = jax.lax.dynamic_update_slice(hk, k.astype(hk.dtype),
-                                          (0, step, 0, 0))
-        hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
-                                          (0, step, 0, 0))
+    def mixer(q, k, v, scanned):
+        l, hk, hv = scanned
+        hk, hv = _write_chunk_step(hk, hv, k, v, step)
         attn = paged_attention_dispatch_chunked(
             q, pool_k_flat, pool_v_flat, table + l * P, hk, hv, positions,
             step, window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-        return x, (hk, hv)
+        return attn, (hk, hv)
 
-    x, (new_hk, new_hv) = jax.lax.scan(
-        layer_step, x,
-        (params["layers"], jnp.arange(L, dtype=jnp.int32), chunk_k, chunk_v),
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params.get("lm_head")
-    if head is None:
-        head = params["embed"].T
-    logits = jnp.einsum("btd,dv->btv", x, head,
-                        preferred_element_type=jnp.float32)
-    return logits, (new_hk, new_hv)
+    x, new_chunk = jax.lax.scan(
+        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
+        (params["layers"], (jnp.arange(L, dtype=jnp.int32), *chunk_kv)))
+    return lm_logits(params, cfg, x), new_chunk
 
 
 def merge_paged_chunk(cache, chunk_kv, start_positions: jnp.ndarray):
@@ -706,20 +675,12 @@ def forward_pipelined(
         def run_layers(x, pos):
             cos, sin = rope_cos_sin(pos, cfg.head_dim, cfg.rope_theta)
 
-            def layer_step(x, layer):
-                h = rms_norm(x, layer["attn_norm"], cfg.norm_eps)
-                b, t = h.shape[0], h.shape[1]
-                q, k, v = qkv_proj(h, layer, cfg.n_heads, cfg.n_kv_heads,
-                                   cfg.head_dim, cos, sin)
+            def mixer(q, k, v, _):
                 attn = gqa_attention(q, k, v, pos, window=cfg.sliding_window)
-                x = x + jnp.einsum("bth,hd->btd", attn.reshape(b, t, -1),
-                                   layer["wo"])
-                h2 = rms_norm(x, layer["mlp_norm"], cfg.norm_eps)
-                x = x + swiglu(h2, layer["w_gate"], layer["w_up"],
-                               layer["w_down"])
-                return x, (k, v)
+                return attn, (k, v)
 
-            return jax.lax.scan(layer_step, x, lp)
+            return jax.lax.scan(decoder_layer(cfg, cos, sin, mixer), x,
+                                (lp, None))
 
         state = jnp.zeros((Bm, T, cfg.dim), params["embed"].dtype)
         ks_all = jnp.zeros((L_local, M, Bm, T, cfg.n_kv_heads, cfg.head_dim),
@@ -822,26 +783,14 @@ def forward_seq_parallel(
         x = params["embed"][tokens]
         cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
-        def layer_step(x, lp):
-            h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-            B, T = h.shape[0], h.shape[1]
-            q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                               cfg.head_dim, cos, sin)
+        def mixer(q, k, v, _):
             attn = ring_attention(q, k, v, positions, positions, seq_axis,
                                   window=cfg.sliding_window)
-            x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-            h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-            x = x + swiglu(h2, lp["w_gate"], lp["w_up"], lp["w_down"])
-            return x, (k, v)
+            return attn, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(layer_step, x, params["layers"])
-        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-        head = params.get("lm_head")
-        if head is None:
-            head = params["embed"].T
-        logits = jnp.einsum("btd,dv->btv", x, head,
-                            preferred_element_type=jnp.float32)
-        return logits, ks, vs
+        x, (ks, vs) = jax.lax.scan(decoder_layer(cfg, cos, sin, mixer), x,
+                                   (params["layers"], None))
+        return lm_logits(params, cfg, x), ks, vs
 
     sharded = shard_map(
         local_fwd,

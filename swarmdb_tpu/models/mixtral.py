@@ -1,8 +1,8 @@
 """Mixtral-style sparse Mixture-of-Experts family — functional JAX.
 
-Same skeleton as ``models/llama.py`` (stacked layers + lax.scan, slot KV
-cache, GQA attention with per-row positions) with the dense FFN replaced by
-a top-k routed MoE block. Two numerically-equivalent dispatch forms:
+The decoder is ``models/llama.py``'s (one layer body, one head, every
+forward); this family brings its parameters and its FFN, a top-k routed MoE
+block (``llama._ffn`` calls it). Two numerically-equivalent dispatch forms:
 
 - ``einsum``: the classic capacity-based one-hot dispatch (router -> top-k
   -> position-in-expert via cumsum -> [N, E, C] dispatch/combine tensors ->
@@ -36,21 +36,16 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.layers import (
-    gqa_attention,
-    gqa_attention_chunked,
-    qkv_proj,
-    rms_norm,
-    rope_cos_sin,
-    write_kv_cache,
-)
+from . import llama
 from .configs import ModelConfig
-from .llama import random_dense
 
 Params = Dict[str, Any]
-KVCache = Tuple[jnp.ndarray, jnp.ndarray]
 
 DEFAULT_CAPACITY_FACTOR = 2.0
+
+# what callers of this family import beside ``init_params``
+forward = llama.forward
+init_kv_cache = llama.init_kv_cache
 
 
 # ---------------------------------------------------------------------- init
@@ -66,7 +61,9 @@ def init_params(
     k_embed, k_layers, k_head = jax.random.split(key, 3)
 
     def dense(key, shape, fan_in):
-        return random_dense(key, shape, fan_in, dtype)
+        # looked up when called: a caller that plans memory without
+        # drawing weights replaces ``llama.random_dense`` for both families
+        return llama.random_dense(key, shape, fan_in, dtype)
 
     ks = jax.random.split(k_layers, 9)
     params: Params = {
@@ -113,13 +110,6 @@ def param_specs(cfg: ModelConfig, model_axis: str = "model",
         "final_norm": P(None),
         "lm_head": P(None, m),
     }
-
-
-def init_kv_cache(
-    cfg: ModelConfig, batch: int, max_seq: int, dtype: jnp.dtype = jnp.bfloat16
-) -> KVCache:
-    shape = (cfg.n_layers, batch, max_seq, cfg.n_kv_heads, cfg.head_dim)
-    return jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
 
 
 # ---------------------------------------------------------------- MoE block
@@ -216,343 +206,3 @@ def moe_block(
     y = jnp.einsum("ecd,nec->nd", ye, combine.astype(x.dtype))
 
     return y.reshape(B, T, D), load
-
-
-# ------------------------------------------------------------------- forward
-
-
-def forward(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,
-    positions: jnp.ndarray,
-    cache: KVCache,
-    logits_at: Optional[jnp.ndarray] = None,
-    moe_dispatch: Optional[str] = None,
-) -> Tuple[jnp.ndarray, KVCache]:
-    """Forward pass; same contract as ``llama.forward`` (fp32 logits +
-    updated cache, head-at-last-position via ``logits_at``), with
-    per-layer MoE FFN."""
-    if not cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is dense; use models.llama.forward")
-    x = params["embed"][tokens]
-    cache_k, cache_v = cache
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-
-    def layer_step(x, scanned):
-        lp, ck, cv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        ck, cv = write_kv_cache(ck, cv, k, v, positions)
-        attn = gqa_attention(q, ck, cv, positions, window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        # the router-load aux is for direct moe_block callers (tests,
-        # balance metrics); the serving forward keeps the llama cache-only
-        # scan contract and drops it here
-        moe_out, _load = moe_block(
-            h2, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, dispatch=moe_dispatch,
-        )
-        x = x + moe_out
-        return x, (ck, cv)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache_k, cache_v)
-    )
-
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if logits_at is not None:
-        x = x[jnp.arange(x.shape[0]), logits_at]
-        logits = jnp.einsum("bd,dv->bv", x, params["lm_head"],
-                            preferred_element_type=jnp.float32)
-        return logits, (new_k, new_v)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, (new_k, new_v)
-
-
-# chunk-KV / prefix-pool helpers are attention-side and identical across
-# families — shared with the dense stack (one definition, review finding r4)
-from .llama import (  # noqa: E402, F401
-    init_chunk_kv,
-    init_prefix_pool,
-    merge_chunk,
-    merge_chunk_scatter,
-    merge_paged_chunk,
-)
-
-
-def forward_prefix_pages(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,        # [Bp, T] SUFFIX tokens (padded)
-    prefix_table: jnp.ndarray,  # [Bp, PP] int32 prefix-pool page ids
-    prefix_lens: jnp.ndarray,   # [Bp] int32 reused prefix length (tokens)
-    pool_k: jnp.ndarray,        # [L, P, ps, Hkv, D]
-    pool_v: jnp.ndarray,
-    logits_at: Optional[jnp.ndarray] = None,
-    moe_dispatch: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Prefix-cache suffix prefill core (see ``llama.forward_prefix_pages``
-    for the design); MoE FFN unchanged. Returns (fp32 logits, sfx_k,
-    sfx_v [L, Bp, T, Hkv, D])."""
-    if not cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is dense; use models.llama")
-    from ..ops.layers import gqa_attention_prefix
-
-    from ..ops.paged_kv import (_dequantize_pages, is_quantized, pool_data,
-                                pools_flat)
-
-    Bp, T = tokens.shape
-    quant = is_quantized(pool_k)
-    ps = pool_data(pool_k).shape[2]
-    Pt = prefix_table.shape[1] * ps
-    x = params["embed"][tokens]
-    positions = prefix_lens[:, None] + jnp.arange(T, dtype=jnp.int32)[None]
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-
-    pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
-
-    def _gather_pages(flat, idx):
-        if quant:
-            return _dequantize_pages(flat.data[idx], flat.scale[idx]
-                                     ).reshape(Bp, Pt, cfg.n_kv_heads,
-                                               cfg.head_dim)
-        return flat[idx].reshape(Bp, Pt, cfg.n_kv_heads, cfg.head_dim)
-
-    def layer_step(x, scanned):
-        lp, l = scanned
-        kp = _gather_pages(pool_k_flat, l * P + prefix_table)
-        vp = _gather_pages(pool_v_flat, l * P + prefix_table)
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        attn = gqa_attention_prefix(q, kp, vp, k.astype(kp.dtype),
-                                    v.astype(vp.dtype), prefix_lens,
-                                    window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(Bp, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        moe_out, _load = moe_block(
-            h2, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, dispatch=moe_dispatch,
-        )
-        x = x + moe_out
-        return x, (k.astype(kp.dtype), v.astype(vp.dtype))
-
-    x, (sfx_k, sfx_v) = jax.lax.scan(
-        layer_step, x,
-        (params["layers"], jnp.arange(L, dtype=jnp.int32)),
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    if logits_at is not None:
-        x = x[jnp.arange(x.shape[0]), logits_at]
-        logits = jnp.einsum("bd,dv->bv", x, params["lm_head"],
-                            preferred_element_type=jnp.float32)
-        return logits, sfx_k, sfx_v
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, sfx_k, sfx_v
-
-
-def forward_prefix_lane(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,
-    prefix_table: jnp.ndarray,
-    prefix_lens: jnp.ndarray,
-    pool_k: jnp.ndarray,
-    pool_v: jnp.ndarray,
-    lane_pages: int,
-    logits_at: Optional[jnp.ndarray] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Dense-cache prefix prefill: core + shared lane composition (see
-    ``llama.forward_prefix_lane``)."""
-    from ..ops.layers import compose_prefix_lane
-
-    logits, sfx_k, sfx_v = forward_prefix_pages(
-        params, cfg, tokens, prefix_table, prefix_lens, pool_k, pool_v,
-        logits_at=logits_at)
-    lane_k, lane_v = compose_prefix_lane(
-        pool_k, pool_v, prefix_table, prefix_lens, sfx_k, sfx_v, lane_pages)
-    return logits, lane_k, lane_v
-
-
-def forward_paged_chunked(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,       # [B, 1]
-    positions: jnp.ndarray,    # [B, 1]
-    cache,                     # {"k","v","page_table"} — FROZEN this chunk
-    chunk_kv: Tuple[jnp.ndarray, jnp.ndarray],
-    step: jnp.ndarray,
-    moe_dispatch: Optional[str] = None,
-):
-    """Two-segment chunked decode over the paged pool (see
-    ``llama.forward_paged_chunked``); MoE FFN unchanged."""
-    if not cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is dense; use models.llama")
-    from ..ops.layers import paged_attention_dispatch_chunked
-    from ..ops.paged_kv import pools_flat
-
-    x = params["embed"][tokens]
-    table = cache["page_table"]
-    pool_k_flat, pool_v_flat, L, P = pools_flat(cache["k"], cache["v"])
-    chunk_k, chunk_v = chunk_kv
-    pos0 = cache.get("pos0")  # rolling-KV RoPE offset (llama.forward_paged)
-    rope_pos = positions if pos0 is None else positions + pos0[:, None]
-    cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
-
-    def layer_step(x, scanned):
-        lp, l, hk, hv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        hk = jax.lax.dynamic_update_slice(hk, k.astype(hk.dtype),
-                                          (0, step, 0, 0))
-        hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
-                                          (0, step, 0, 0))
-        attn = paged_attention_dispatch_chunked(
-            q, pool_k_flat, pool_v_flat, table + l * P, hk, hv, positions,
-            step, window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        moe_out, _load = moe_block(
-            h2, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, dispatch=moe_dispatch,
-        )
-        x = x + moe_out
-        return x, (hk, hv)
-
-    x, (new_hk, new_hv) = jax.lax.scan(
-        layer_step, x,
-        (params["layers"], jnp.arange(L, dtype=jnp.int32), chunk_k, chunk_v),
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, (new_hk, new_hv)
-
-
-def forward_chunked(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,       # [B, 1]
-    positions: jnp.ndarray,    # [B, 1]
-    cache: KVCache,            # FROZEN during the chunk
-    chunk_kv: Tuple[jnp.ndarray, jnp.ndarray],
-    step: jnp.ndarray,         # scalar int32
-    moe_dispatch: Optional[str] = None,
-) -> Tuple[jnp.ndarray, Tuple[jnp.ndarray, jnp.ndarray]]:
-    """Two-segment chunked decode step (see ``llama.forward_chunked``);
-    MoE FFN unchanged."""
-    if not cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is dense; use models.llama")
-    x = params["embed"][tokens]
-    cache_k, cache_v = cache
-    chunk_k, chunk_v = chunk_kv
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
-
-    def layer_step(x, scanned):
-        lp, ck, cv, hk, hv = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        hk = jax.lax.dynamic_update_slice(hk, k.astype(hk.dtype),
-                                          (0, step, 0, 0))
-        hv = jax.lax.dynamic_update_slice(hv, v.astype(hv.dtype),
-                                          (0, step, 0, 0))
-        attn = gqa_attention_chunked(q, ck, cv, hk, hv, positions, step,
-                                     window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        moe_out, _load = moe_block(
-            h2, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, dispatch=moe_dispatch,
-        )
-        x = x + moe_out
-        return x, (hk, hv)
-
-    x, (new_hk, new_hv) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache_k, cache_v, chunk_k, chunk_v)
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    return logits, (new_hk, new_hv)
-
-
-def init_paged_cache(
-    cfg: ModelConfig,
-    batch: int,
-    max_seq: int,
-    num_pages: int,
-    page_size: int,
-    dtype: Optional[jnp.dtype] = None,
-):
-    """Block-paged KV pool; see ``llama.init_paged_cache``.
-
-    ``dtype=None`` resolves from ``SWARMDB_KV_DTYPE`` (int8 → quantized
-    ``QuantPool``)."""
-    from ..ops.paged_kv import init_paged_kv_cache
-
-    return init_paged_kv_cache(
-        cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim,
-        batch, max_seq, dtype,
-    )
-
-
-def forward_paged(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,     # [B, 1] — DECODE steps only
-    positions: jnp.ndarray,  # [B, 1]
-    cache,                   # {"k", "v", "page_table"}
-    moe_dispatch: Optional[str] = None,
-):
-    """Decode forward over the block-paged KV pool; MoE FFN unchanged.
-    Same contract as ``llama.forward_paged``."""
-    if not cfg.is_moe:
-        raise ValueError(f"{cfg.name!r} is dense; use models.llama.forward_paged")
-    from ..ops.layers import paged_attention_dispatch
-    from ..ops.paged_kv import paged_write_decode
-
-    x = params["embed"][tokens]
-    table = cache["page_table"]
-    pos0 = cache.get("pos0")  # rolling-KV RoPE offset (llama.forward_paged)
-    rope_pos = positions if pos0 is None else positions + pos0[:, None]
-    cos, sin = rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
-
-    def layer_step(x, scanned):
-        lp, kp, vp = scanned
-        h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-        B, T = h.shape[0], h.shape[1]
-        q, k, v = qkv_proj(h, lp, cfg.n_heads, cfg.n_kv_heads,
-                           cfg.head_dim, cos, sin)
-        kp, vp = paged_write_decode(kp, vp, k, v, positions, table)
-        attn = paged_attention_dispatch(
-            q, kp, vp, table, positions, window=cfg.sliding_window)
-        x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
-        h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        moe_out, _load = moe_block(
-            h2, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
-            top_k=cfg.experts_per_token, dispatch=moe_dispatch,
-        )
-        x = x + moe_out
-        return x, (kp, vp)
-
-    x, (new_k, new_v) = jax.lax.scan(
-        layer_step, x, (params["layers"], cache["k"], cache["v"])
-    )
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    logits = jnp.einsum("btd,dv->btv", x, params["lm_head"],
-                        preferred_element_type=jnp.float32)
-    out = {"k": new_k, "v": new_v, "page_table": table}
-    if pos0 is not None:
-        out["pos0"] = pos0
-    return logits, out

@@ -8,8 +8,8 @@ as written by ``SpanTracer.to_chrome_trace`` / ``/admin/trace/export`` /
 ``/admin/cluster/trace``) and flight-recorder dumps, and produces a
 machine-readable diagnosis::
 
-    python -m swarmdb_tpu.obs.analyze bench_logs/dpserve_dp1_trace.json \
-        bench_logs/dpserve_dp8_trace.json
+    python -m swarmdb_tpu.obs.analyze bench_logs/dpserve_dp1_trace_r07.json \
+        bench_logs/dpserve_dp8_trace_r07.json
 
 With TWO traces the report is a comparison (first = base, second =
 test): the per-completion engine cost is decomposed by span category

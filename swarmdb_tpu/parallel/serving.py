@@ -31,7 +31,7 @@ from ..models.configs import ModelConfig, get_config
 from .mesh import make_mesh, tree_shardings
 
 # Activations/tokens shard batch over data; cache shards batch over data and
-# KV heads over model (models/llama.py `cache_specs`).
+# KV heads over model.
 TOKEN_SPEC = P("data", None)
 CACHE_SPEC = P(None, "data", None, "model", None)
 
@@ -72,16 +72,14 @@ class ShardedModel:
 
 
 def _family(cfg: ModelConfig):
+    """Whose parameters: the module that has the configuration's
+    ``init_params`` and ``param_specs``. The decoder itself is one
+    (models/llama.py) and is called by that name below."""
     return mixtral if cfg.is_moe else llama
 
 
 def param_shardings_for(cfg: ModelConfig, mesh: Mesh) -> Any:
-    fam = _family(cfg)
-    if cfg.is_moe:
-        specs = fam.param_specs(cfg, model_axis="model", expert_axis="expert")
-    else:
-        specs = fam.param_specs(cfg, model_axis="model")
-    return tree_shardings(mesh, specs)
+    return tree_shardings(mesh, _family(cfg).param_specs(cfg))
 
 
 def build_sharded_model(
@@ -136,8 +134,8 @@ def build_sharded_model(
                 lambda c: jax.lax.with_sharding_constraint(c, cache_sharding), cache
             )
         with pallas_disabled():
-            logits, cache = fam.forward(p, cfg, tokens, positions, cache,
-                                        **moe_kw)
+            logits, cache = llama.forward(p, cfg, tokens, positions, cache,
+                                          **moe_kw)
         if constrain:
             cache = jax.tree.map(
                 lambda c: jax.lax.with_sharding_constraint(c, cache_sharding), cache
@@ -145,7 +143,7 @@ def build_sharded_model(
         return logits, cache
 
     def init_cache_fn(batch: int, max_seq: int):
-        shape_fn = partial(fam.init_kv_cache, cfg, batch, max_seq)
+        shape_fn = partial(llama.init_kv_cache, cfg, batch, max_seq)
         if batch % mesh.shape["data"] == 0:
             out_sh = jax.tree.map(lambda _: cache_sharding, jax.eval_shape(shape_fn))
             return jax.jit(shape_fn, out_shardings=out_sh)()
@@ -165,15 +163,16 @@ def build_sharded_model(
         cache = _constrain_kv(cache)
         chunk_kv = _constrain_kv(chunk_kv)
         with pallas_disabled():
-            logits, chunk_kv = fam.forward_chunked(
+            logits, chunk_kv = llama.forward_chunked(
                 p, cfg, tokens, positions, cache, chunk_kv, step, **moe_kw)
         return logits, _constrain_kv(chunk_kv)
 
     def init_chunk_fn(batch: int, chunk: int):
-        return _constrain_kv(fam.init_chunk_kv(cfg, batch, chunk))
+        return _constrain_kv(llama.init_chunk_kv(cfg, batch, chunk))
 
     def merge_fn(cache, chunk_kv, start_positions):
-        return _constrain_kv(fam.merge_chunk(cache, chunk_kv, start_positions))
+        return _constrain_kv(
+            llama.merge_chunk(cache, chunk_kv, start_positions))
 
     return ShardedModel(
         cfg=cfg,
@@ -238,7 +237,7 @@ def build_sharded_paged(
                                 make_sharded_page_allocator,
                                 pages_per_slot)
 
-    cfg, mesh, fam = sm.cfg, sm.mesh, _family(sm.cfg)
+    cfg, mesh = sm.cfg, sm.mesh
     if kv_quantized():
         # PAGED_CACHE_SPECS are rank-5 payload PartitionSpecs; the int8
         # QuantPool carries rank-3 scale planes they cannot shard. Fail
@@ -285,7 +284,7 @@ def build_sharded_paged(
     def _decode_body(p, t, pos, c):
         local = dict(c, page_table=_localize(c["page_table"]))
         with pallas_disabled():
-            logits, out = fam.forward_paged(p, cfg, t, pos, local)
+            logits, out = llama.forward_paged(p, cfg, t, pos, local)
         out["page_table"] = c["page_table"]  # keep GLOBAL ids outside
         return logits, out
 
@@ -298,7 +297,7 @@ def build_sharded_paged(
     def _chunk_body(p, t, pos, c, chunk_kv, step):
         local = dict(c, page_table=_localize(c["page_table"]))
         with pallas_disabled():
-            logits, out_ck = fam.forward_paged_chunked(
+            logits, out_ck = llama.forward_paged_chunked(
                 p, cfg, t, pos, local, chunk_kv, step)
         return logits, out_ck
 
@@ -311,7 +310,7 @@ def build_sharded_paged(
 
     def _merge_body(c, chunk_kv, starts):
         local = dict(c, page_table=_localize(c["page_table"]))
-        out = fam.merge_paged_chunk(local, chunk_kv, starts)
+        out = llama.merge_paged_chunk(local, chunk_kv, starts)
         out["page_table"] = c["page_table"]
         return out
 
@@ -325,7 +324,7 @@ def build_sharded_paged(
     chunk_sharding = NamedSharding(mesh, CHUNK_KV_SPEC)
 
     def init_chunk_fn(batch: int, k: int):
-        shape_fn = partial(fam.init_chunk_kv, cfg, batch, k)
+        shape_fn = partial(llama.init_chunk_kv, cfg, batch, k)
         out_sh = jax.tree.map(lambda _: chunk_sharding,
                               jax.eval_shape(shape_fn))
         return jax.jit(shape_fn, out_shardings=out_sh)()
@@ -365,7 +364,7 @@ def build_sharded_paged(
         # range, dropped), k/v_pool [L, per_shard, ...], last_* [slots_per]
         #
         # PARITY CONTRACT: this is the shard-local twin of
-        # backend/engine._prefill_paged_insert — same forward (fam.forward
+        # backend/engine._prefill_paged_insert — same forward (llama.forward
         # with logits_at IS what the engine's _forward_last_of resolves
         # to), same sampling fold, same pad/reshape/page-scatter shapes.
         # A change to either body must land in both;
@@ -375,10 +374,10 @@ def build_sharded_paged(
         d = jax.lax.axis_index("data").astype(jnp.int32)
         positions = jnp.broadcast_to(
             jnp.arange(T, dtype=jnp.int32)[None], (R, T))
-        cacheB = fam.init_kv_cache(cfg, R, T)
+        cacheB = llama.init_kv_cache(cfg, R, T)
         with pallas_disabled():
-            logits, cacheB = fam.forward(p, cfg, tokens, positions, cacheB,
-                                         logits_at=lengths - 1)
+            logits, cacheB = llama.forward(p, cfg, tokens, positions, cacheB,
+                                           logits_at=lengths - 1)
         last = (logits if logits.ndim == 2
                 else logits[jnp.arange(R), lengths - 1])
         next_tok = sample_tokens(last, keys, lengths - 1, temp, topk, topp)
@@ -424,13 +423,13 @@ def build_sharded_paged(
     )
 
     prefix_fns = None
-    if prefix and hasattr(fam, "forward_prefix_pages"):
+    if prefix:
         # prefill path: GSPMD over GLOBAL ids (gathers from the sharded
         # pool; admission-time only, so the collectives amortize)
         def pages_fwd(p, t, tab, pl, pk, pv, logits_at=None):
             with pallas_disabled():
-                return fam.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
-                                                logits_at=logits_at)
+                return llama.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
+                                                  logits_at=logits_at)
 
         prefix_fns = (pages_fwd, None)
 

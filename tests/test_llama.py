@@ -1,6 +1,8 @@
 """Llama model tests: shapes, KV-cache decode equivalence, and numerics
 parity against HF transformers (torch CPU) on a tiny config."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -217,3 +219,118 @@ def test_merge_chunk_scatter_matches_einsum():
     sk, sv = merge_chunk_kv_scatter(ck, cv, hk, hv, starts)
     np.testing.assert_array_equal(np.asarray(ek), np.asarray(sk))
     np.testing.assert_array_equal(np.asarray(ev), np.asarray(sv))
+
+
+# ---------------------------------------------------------------------------
+# one decoder, every forward, both families: each forward is its own
+# attention-and-cache step around the same layer body, so stepped over a
+# short sequence it must give ``forward``'s logits whatever the FFN is
+
+
+@functools.lru_cache(maxsize=None)
+def _family_setup(family):
+    from swarmdb_tpu.models import mixtral
+    from swarmdb_tpu.models.configs import TINY_MOE
+
+    cfg, mod = ((TINY_DEBUG, llama) if family == "dense"
+                else (TINY_MOE, mixtral))
+    # TINY_MOE's capacity is a token an expert for every token of a call
+    # (N * 2 * 2.0 / 4), so no call size drops one and calls of different
+    # sizes can be compared
+    params = mod.init_params(cfg, jax.random.PRNGKey(4), dtype=jnp.float32)
+    B, T, S = 2, 12, 16
+    tokens = jnp.asarray(
+        np.random.default_rng(9).integers(1, cfg.vocab_size, size=(B, T)),
+        jnp.int32)
+    positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
+    want, cache = llama.forward(
+        params, cfg, tokens, positions,
+        llama.init_kv_cache(cfg, B, S, dtype=jnp.float32))
+    return cfg, params, tokens, want, cache
+
+
+def _pages_of(cache, n_tokens, ps):
+    """A page pool holding each row's first ``n_tokens`` of ``cache``
+    (row b's pages are 1 + b * maxp ...; page 0 is the trash page) and the
+    rows' page table."""
+    L, B, S, H, D = cache[0].shape
+    maxp = S // ps
+    table = jnp.arange(1, 1 + B * maxp, dtype=jnp.int32).reshape(B, maxp)
+
+    def pool(c):
+        live = jnp.where(jnp.arange(S)[None, :, None, None] < n_tokens,
+                         c, 0.0)
+        pages = live.reshape(L, B * maxp, ps, H, D)
+        return jnp.concatenate([jnp.zeros_like(pages[:, :1]), pages], axis=1)
+
+    return pool(cache[0]), pool(cache[1]), table
+
+
+@pytest.mark.parametrize("name", [
+    "forward_chunked", "forward_paged", "forward_paged_chunked",
+    "forward_prefix_pages", "forward_prefix_lane"])
+@pytest.mark.parametrize("family", ["dense", "moe"])
+def test_every_forward_gives_forwards_logits(family, name):
+    cfg, params, tokens, want, cache = _family_setup(family)
+    B, T = tokens.shape
+    ps, P0 = 4, 8           # two pages of history, then four more tokens
+    pool_k, pool_v, table = _pages_of(cache, P0, ps)
+
+    if name.startswith("forward_prefix"):
+        args = (params, cfg, tokens[:, P0:], table[:, :P0 // ps],
+                jnp.full((B,), P0, jnp.int32), pool_k, pool_v)
+        if name == "forward_prefix_lane":
+            got, lane_k, _ = llama.forward_prefix_lane(*args, T // ps)
+            np.testing.assert_allclose(np.asarray(lane_k[:, :, :T]),
+                                       np.asarray(cache[0][:, :, :T]),
+                                       rtol=1e-4, atol=1e-4)
+        else:
+            got, _, _ = llama.forward_prefix_pages(*args)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, P0:]),
+                                   rtol=1e-4, atol=1e-4)
+        return
+
+    # decode: the first P0 tokens are history, the rest come a step each
+    history = tuple(jnp.where(
+        jnp.arange(c.shape[2])[None, None, :, None, None] < P0, c, 0.0)
+        for c in cache)
+    paged = {"k": pool_k, "v": pool_v, "page_table": table}
+    chunk = llama.init_chunk_kv(cfg, B, T - P0, dtype=jnp.float32)
+    for step in range(T - P0):
+        tok = tokens[:, P0 + step:P0 + step + 1]
+        pos = jnp.full((B, 1), P0 + step, jnp.int32)
+        at = jnp.asarray(step, jnp.int32)
+        if name == "forward_chunked":
+            got, chunk = llama.forward_chunked(params, cfg, tok, pos,
+                                               history, chunk, at)
+        elif name == "forward_paged":
+            got, paged = llama.forward_paged(params, cfg, tok, pos, paged)
+        else:
+            got, chunk = llama.forward_paged_chunked(params, cfg, tok, pos,
+                                                     paged, chunk, at)
+        np.testing.assert_allclose(np.asarray(got[:, 0]),
+                                   np.asarray(want[:, P0 + step]),
+                                   rtol=1e-4, atol=1e-4)
+
+
+def test_routed_ragged_prefill_gives_forwards_logits():
+    """The ragged prefill of a routed configuration, which the engine
+    does not wire yet (backend/service.py: a packed stream and a
+    row-bucketed wave reckon different capacities): on one row with no
+    padding the two calls hold the same tokens, so the capacities agree
+    and the logits and the suffix K/V must be ``forward``'s."""
+    cfg, params, tokens, want, cache = _family_setup("moe")
+    T, ps = tokens.shape[1], 4
+    pool_k, pool_v, table = _pages_of(cache, 0, ps)
+    got, sfx_k, _ = llama.forward_ragged_prefill(
+        params, cfg, tokens[0], jnp.zeros((T,), jnp.int32),
+        jnp.arange(T, dtype=jnp.int32), table[:1],
+        jnp.asarray([0], jnp.int32), jnp.asarray([T], jnp.int32),
+        jnp.asarray([0], jnp.int32), pool_k, pool_v)
+    alone, (ck, _) = llama.forward(
+        params, cfg, tokens[:1], jnp.arange(T, dtype=jnp.int32)[None],
+        llama.init_kv_cache(cfg, 1, T, dtype=jnp.float32))
+    np.testing.assert_allclose(np.asarray(got[0]), np.asarray(alone[0, -1]),
+                               rtol=1e-4, atol=1e-4)
+    np.testing.assert_allclose(np.asarray(sfx_k), np.asarray(ck[:, 0]),
+                               rtol=1e-4, atol=1e-4)

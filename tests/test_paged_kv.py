@@ -543,8 +543,8 @@ def test_mixtral_paged_chunked_matches_paged():
     B, S, ps = 2, 32, 4
     maxp = pages_per_slot(S, ps)
     num_pages = 1 + B * maxp
-    pool = mixtral.init_paged_cache(cfg, B, S, num_pages, ps,
-                                    dtype=jnp.float32)
+    pool = llama.init_paged_cache(cfg, B, S, num_pages, ps,
+                                  dtype=jnp.float32)
     table = np.arange(1, 1 + B * maxp, dtype=np.int32).reshape(B, maxp)
     pool["page_table"] = jnp.asarray(table)
     pool2 = {k: v for k, v in pool.items()}
@@ -556,13 +556,13 @@ def test_mixtral_paged_chunked_matches_paged():
     tok = jnp.asarray([[3], [9]], jnp.int32)
     for step in range(Kc):
         pos = jnp.full((B, 1), step, jnp.int32)
-        l_ref, pool = mixtral.forward_paged(params, cfg, tok, pos, pool)
-        l_chk, chunk = mixtral.forward_paged_chunked(
+        l_ref, pool = llama.forward_paged(params, cfg, tok, pos, pool)
+        l_chk, chunk = llama.forward_paged_chunked(
             params, cfg, tok, pos, pool2, chunk, jnp.asarray(step, jnp.int32))
         np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_chk),
                                    rtol=1e-4, atol=1e-4)
         tok = jnp.argmax(l_ref[:, -1], axis=-1).astype(jnp.int32)[:, None]
-    pool2 = mixtral.merge_paged_chunk(pool2, chunk, starts)
+    pool2 = llama.merge_paged_chunk(pool2, chunk, starts)
     np.testing.assert_allclose(np.asarray(pool["k"][:, 1:]),
                                np.asarray(pool2["k"][:, 1:]),
                                rtol=1e-5, atol=1e-5)
@@ -624,9 +624,10 @@ def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
     for step in range(Kc):
         pos = jnp.asarray(starts[:, None] + step, jnp.int32)
         monkeypatch.setenv("SWARMDB_PALLAS", "0")
-        l_ref, step_pool = mod.forward_paged(params, cfg, tok, pos, step_pool)
+        l_ref, step_pool = llama.forward_paged(params, cfg, tok, pos,
+                                               step_pool)
         monkeypatch.setenv("SWARMDB_PALLAS", pallas)
-        l_chk, chunk = mod.forward_paged_chunked(
+        l_chk, chunk = llama.forward_paged_chunked(
             params, cfg, tok, pos, chunk_pool, chunk,
             jnp.asarray(step, jnp.int32))
         assert np.all(np.isfinite(np.asarray(l_chk)))

@@ -300,7 +300,7 @@ def test_mixtral_forward_prefix_lane_matches_full():
         params, cfg, jnp.asarray([prompt], jnp.int32),
         jnp.arange(len(prompt), dtype=jnp.int32)[None], cache)
 
-    pool_k, pool_v = mixtral.init_prefix_pool(cfg, 4, ps)
+    pool_k, pool_v = llama.init_prefix_pool(cfg, 4, ps)
     for p in range(PP):
         pool_k = pool_k.at[:, p + 1].set(ck[:, 0, p * ps:(p + 1) * ps])
         pool_v = pool_v.at[:, p + 1].set(cv[:, 0, p * ps:(p + 1) * ps])
@@ -308,7 +308,7 @@ def test_mixtral_forward_prefix_lane_matches_full():
     suffix = prompt[P0:]
     sfx = np.zeros((1, T), np.int32)
     sfx[0, :len(suffix)] = suffix
-    logits_sfx, lane_k, _lane_v = mixtral.forward_prefix_lane(
+    logits_sfx, lane_k, _lane_v = llama.forward_prefix_lane(
         params, cfg, jnp.asarray(sfx), jnp.asarray([[1, 2]], jnp.int32),
         jnp.asarray([P0], jnp.int32), pool_k, pool_v, lane_pages,
     )
