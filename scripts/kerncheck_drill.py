@@ -5,7 +5,7 @@ The CI ``kerncheck`` job runs the kernel/ragged suites under
 ``SWARMDB_KERNCHECK=1`` and fails on any violation; this script is the
 other direction: it deliberately commits every kernel crime the shadow
 interpreter hunts — an out-of-bounds page id in a wave's write
-descriptors (SWL901-class), a sabotaged kernel that skips one row's
+descriptors (SWL901-class), a sabotaged kernel that loses one row's
 finalize so the canary survives (SWL905-class), and an unmasked
 finalize whose grid rows race on the shared output block
 (SWL902-class) — and exits non-zero unless the detector FIRED on each
@@ -16,7 +16,6 @@ Run: SWARMDB_KERNCHECK=1 python scripts/kerncheck_drill.py
 (the script forces the flag itself so a bare invocation also works).
 """
 
-import functools
 import os
 import sys
 import tempfile
@@ -54,9 +53,7 @@ def main() -> int:
     ps = np.asarray(kp).shape[1]
     P = np.asarray(kp).shape[0]
     maxp = np.asarray(tables).shape[1]
-    base = functools.partial(
-        ap._ragged_prefill_kernel, page_size=ps,
-        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp, window=None)
+    base = kerncheck.ragged_prefill_body(kp, tables)
 
     # -- crime 1: OOB page id in the wave's write descriptors ---------
     bad_tables = np.array(np.asarray(tables), copy=True)
@@ -66,13 +63,15 @@ def main() -> int:
         np.array([live_r], np.int32),
         np.array([0], np.int32), bad_tables, P, ps)
 
-    # -- crime 2: short write (one live row's finalize skipped) -------
+    # -- crime 2: short write (one live row's finalize lost) ----------
     def short_write(*refs):
-        # grid (query block, row, step)
-        if (pl.program_id(1) == live_r
-                and pl.program_id(2) == pl.num_programs(2) - 1):
-            return
+        # grid (query block, row): whatever row ``live_r``'s step wrote
+        # into the output block (refs[9]) is taken back
+        o_ref = refs[9]
+        before = o_ref[...]
         base(*refs)
+        if pl.program_id(1) == live_r:
+            o_ref[...] = before
 
     kerncheck.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens,
@@ -83,8 +82,7 @@ def main() -> int:
         base(*refs)
         o_ref = refs[9]
         o_ref[...] = (np.zeros(o_ref.shape, np.float32)
-                      + 1.5 * (pl.program_id(1) + 1)
-                      + 0.25 * pl.program_id(2))
+                      + 1.5 * (pl.program_id(1) + 1))
 
     kerncheck.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens,

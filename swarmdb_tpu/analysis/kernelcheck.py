@@ -1542,9 +1542,13 @@ def estimate_vmem(kernel: str, dims: Dict[str, int],
     kernel or wrapper name contains ``kernel``, evaluated under concrete
     ``dims`` (trace-time shapes). None when no site matches or a dim is
     unbound — callers treat that as 'no estimate', never an error."""
-    for row in static_vmem_table(paths):
-        if kernel in row["kernel"] or kernel in row["wrapper"]:
-            got = eval_with_dims(row["expr"], dims)
-            if got is not None:
-                return got
+    rows = static_vmem_table(paths)
+    # the kernel of that very name before one whose name contains it
+    # (`_ragged_prefill_kernel` is a prefix of its `_quant` twin's)
+    named = [r for r in rows if kernel in (r["kernel"], r["wrapper"])]
+    for row in named or [r for r in rows if kernel in r["kernel"]
+                         or kernel in r["wrapper"]]:
+        got = eval_with_dims(row["expr"], dims)
+        if got is not None:
+            return got
     return None

@@ -20,9 +20,14 @@ over the real kernel function:
   been overwritten — surviving canary is a ``short-write`` violation
   (the runtime face of SWL905),
 - per grid step the interpreter diffs the output block: an element
-  changed by two different outer grid rows (the init cell ``(0, .., 0)``
-  exempt — the zero-fill idiom) is a ``write-race`` violation naming
-  both writers (the runtime face of SWL902),
+  changed by two different outer grid rows (the zeros the first cell to
+  visit a block leaves exempt — the zero-fill idiom) is a ``write-race``
+  violation naming both writers (the runtime face of SWL902),
+- a kernel that walks its own keys inside a grid step (the ragged
+  prefill kernel: pools in ``ANY`` space, ``make_async_copy`` into a
+  double buffer, ``fori_loop`` trips read from SMEM) runs the same way:
+  its loops as Python loops, its copies as bounds-checked numpy copies
+  that land at ``wait()``, its buffers starting as NaN,
 - the shadow result is compared against the dispatched result — a
   free differential check of kernel-vs-dispatch parity on the live
   descriptors; :func:`differential_ragged_prefill` /
@@ -44,9 +49,10 @@ serving path.
 
 The registry's mutex is a *leaf* lock: no user code runs under it.
 The pallas-shim patch lock (``_PATCH_MU``) serializes shadow runs —
-``pl.program_id``/``pl.num_programs``/``pl.when``/``pl.ds`` are
-module attributes the kernels resolve at call time, so the interpreter
-swaps them for concrete evaluators for the duration of a run.
+``pl.program_id``/``pl.num_programs``/``pl.when``/``pl.ds``,
+``jax.lax.fori_loop`` and ``pltpu.make_async_copy`` are module
+attributes the kernels resolve at call time, so the interpreter swaps
+them for concrete evaluators for the duration of a run.
 """
 
 from __future__ import annotations
@@ -69,7 +75,8 @@ logger = logging.getLogger("swarmdb_tpu.obs")
 
 __all__ = ["enabled", "registry", "KernCheckRegistry", "ShadowRef",
            "CANARY", "parity_tol",
-           "shadow_ragged_prefill", "shadow_paged_decode",
+           "ragged_prefill_body", "shadow_ragged_prefill",
+           "shadow_paged_decode",
            "shadow_paged_write_ragged", "check_wave_descriptors",
            "differential_ragged_prefill", "differential_paged_decode",
            "checked_ragged_prefill_dispatch",
@@ -299,17 +306,21 @@ def registry() -> KernCheckRegistry:
 # ------------------------------------------------------ shadow machinery
 
 # serializes shadow runs: the interpreter swaps pl.program_id /
-# pl.num_programs / pl.when / pl.ds for concrete evaluators while a
-# kernel body executes on the host
+# pl.num_programs / pl.when / pl.ds / lax.fori_loop / make_async_copy
+# for concrete evaluators while a kernel body executes on the host
 _PATCH_MU = threading.RLock()
 
 
 @contextlib.contextmanager
 def _patched_pallas(state: Dict[str, Any]):
+    import jax
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     with _PATCH_MU:
-        saved = (pl.program_id, pl.num_programs, pl.when, pl.ds)
+        saved = (pl.program_id, pl.num_programs, pl.when, pl.ds,
+                 jax.lax.fori_loop, pltpu.make_async_copy)
+        real_fori_loop = saved[4]
 
         def _program_id(i: int) -> int:
             return state["coords"][i]
@@ -327,14 +338,46 @@ def _patched_pallas(state: Dict[str, Any]):
         def _ds(start, size):
             return slice(int(start), int(start) + int(size))
 
+        def _fori_loop(lower, upper, body, init, **kw):
+            # a kernel's in-step loops (trip counts read from SMEM) run
+            # as Python loops over the shadow refs; anything traced is
+            # somebody else's loop and keeps the real one
+            try:
+                lo, hi = int(lower), int(upper)
+            except Exception:
+                return real_fori_loop(lower, upper, body, init, **kw)
+            val = init
+            for i in range(lo, hi):
+                val = body(i, val)
+            return val
+
         pl.program_id = _program_id
         pl.num_programs = _num_programs
         pl.when = _when
         pl.ds = _ds
+        jax.lax.fori_loop = _fori_loop
+        pltpu.make_async_copy = _ShadowCopy
         try:
             yield
         finally:
-            (pl.program_id, pl.num_programs, pl.when, pl.ds) = saved
+            (pl.program_id, pl.num_programs, pl.when, pl.ds,
+             jax.lax.fori_loop, pltpu.make_async_copy) = saved
+
+
+class _ShadowCopy:
+    """Stand-in for ``pltpu.make_async_copy(src, dst, sem)`` over shadow
+    refs. The bytes land at ``wait()``, not at ``start()``: a kernel that
+    reads its buffer before waiting reads what was there before, and the
+    parity check sees it."""
+
+    def __init__(self, src: "ShadowRef", dst: "ShadowRef", sem: Any) -> None:
+        self._src, self._dst = src, dst
+
+    def start(self) -> None:
+        pass
+
+    def wait(self) -> None:
+        self._dst[...] = self._src[...]
 
 
 class ShadowRef:
@@ -402,14 +445,31 @@ class ShadowRef:
             {"ref": self._name, "axis": axis, "grid": list(coords),
              "slice": what})
 
+    @property
+    def at(self) -> "_ShadowAt":
+        """``ref.at[idx]``: a bounds-checked sub-ref over the same
+        backing store (what a DMA descriptor is built from)."""
+        return _ShadowAt(self)
+
     def __getitem__(self, idx: Any):
         import jax.numpy as jnp
 
-        return jnp.asarray(np.asarray(self._arr[self._resolve(idx)]))
+        # a copy: a load is a value, and jnp.asarray may alias numpy memory
+        return jnp.asarray(np.array(self._arr[self._resolve(idx)]))
 
     def __setitem__(self, idx: Any, value: Any) -> None:
         s = self._resolve(idx)
         self._arr[s] = np.asarray(value, dtype=self._arr.dtype)
+
+
+class _ShadowAt:
+    def __init__(self, ref: ShadowRef) -> None:
+        self._ref = ref
+
+    def __getitem__(self, idx: Any) -> ShadowRef:
+        r = self._ref
+        return ShadowRef(r._arr[r._resolve(idx)], r._name, r._kernel,
+                         r._state)
 
 
 def _run_grid(kernel: Callable, kernel_name: str,
@@ -418,13 +478,19 @@ def _run_grid(kernel: Callable, kernel_name: str,
               inputs: Sequence[Tuple[str, np.ndarray, Tuple[int, ...],
                                      Callable]],
               out: Tuple[str, np.ndarray, Tuple[int, ...], Callable],
-              scratch: Sequence[np.ndarray]
+              scratch: Sequence[np.ndarray],
+              writer_axes: Optional[int] = None,
               ) -> Tuple[np.ndarray, np.ndarray]:
     """Interpret ``kernel`` over ``grid`` (row-major, last axis minor —
     the TPU order) against numpy backing stores with bounds-checked
     block selection, recording oob-block / oob-ref / write-race
-    violations as it goes. Returns (output backing store, per-element
-    last-writer map: -1 = only ever touched by its block's init cell)."""
+    violations as it goes. ``writer_axes`` leading grid axes name a
+    writer (default: all but the minor one, along which a kernel
+    accumulates into its own block). Returns (output backing store,
+    per-element last-writer map: -1 = only ever touched by its block's
+    init cell)."""
+    if writer_axes is None:
+        writer_axes = len(grid) - 1
     reg = registry()
     state: Dict[str, Any] = {"grid": grid, "coords": (0,) * len(grid)}
     scalar_refs = [ShadowRef(arr, name, kernel_name, state)
@@ -473,22 +539,20 @@ def _run_grid(kernel: Callable, kernel_name: str,
                    ShadowRef(oview, out_name, kernel_name, state),
                    *scratch_refs)
             changed = np.asarray(pre != oview)
-            # the FIRST cell to visit an output block writing CONSTANT
-            # zeros is the zero-fill init idiom — exempt from writer
-            # tracking so a later per-row finalize is not a "race"
-            # against it and a row it alone touched still counts as
-            # unwritten. An init cell writing real (non-zero) values is
-            # an ordinary writer.
+            # the zeros the FIRST cell to visit an output block leaves
+            # are the zero-fill init idiom — exempt from writer tracking
+            # so a later per-row finalize is not a "race" against them
+            # and a row they alone touched still counts as unwritten.
+            # What that cell writes besides (real, non-zero values: its
+            # own row's finalize) is an ordinary write.
             block = tuple(s.start for s in oslices)
-            first_visit = block not in visited
+            if block not in visited:
+                changed &= np.asarray(oview, np.float32) != 0
             visited.add(block)
-            is_zero_fill = (first_visit and changed.any()
-                            and not np.asarray(
-                                oview, np.float32)[changed].any())
-            if changed.any() and not is_zero_fill:
-                writer = (int(np.ravel_multi_index(coords[:-1],
-                                                   grid[:-1]))
-                          if len(grid) > 1 else 0)
+            if changed.any():
+                writer = (int(np.ravel_multi_index(coords[:writer_axes],
+                                                   grid[:writer_axes]))
+                          if writer_axes else 0)
                 lw = last_writer[oslices]
                 prev = lw[changed]
                 clash = (prev >= 0) & (prev != writer)
@@ -508,6 +572,21 @@ def _run_grid(kernel: Callable, kernel_name: str,
 
 
 # --------------------------------------------------- kernel shadow runs
+
+def ragged_prefill_body(k_pages, row_tables, *, window=None) -> Callable:
+    """The in-tree ragged prefill kernel body as its wrapper binds it for
+    these pools and tables: what `shadow_ragged_prefill` runs when handed
+    no other, and what a drill wraps to seed a crime."""
+    from ..ops import attention_pallas as ap
+
+    _, ps, Hkv, D = np.shape(k_pages)
+    return functools.partial(
+        ap._ragged_prefill_kernel, page_size=ps, n_kv_heads=Hkv,
+        pages_per_block=ap._pages_per_block(
+            ps, Hkv, D, np.asarray(k_pages).dtype.itemsize,
+            np.shape(row_tables)[1]),
+        window=window)
+
 
 def shadow_ragged_prefill(q, sfx_k, sfx_v, k_pages, v_pages, row_tables,
                           starts, lens, prefix_lens, *, window=None,
@@ -536,42 +615,38 @@ def shadow_ragged_prefill(q, sfx_k, sfx_v, k_pages, v_pages, row_tables,
     Tq = min(tile, W)
     n_st = W // Tq
     name = "ragged_paged_prefill_attention"
+    ppb = ap._pages_per_block(ps, Hkv, D, k_pages.dtype.itemsize, maxp)
     if kernel is None:
-        kernel = functools.partial(
-            ap._ragged_prefill_kernel, page_size=ps, n_kv_heads=Hkv,
-            n_pages=maxp, window=window)
+        kernel = ragged_prefill_body(k_pages, row_tables, window=window)
 
-    # the wrapper's grid and index maps, restated (they are closures there)
-    def q_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+    # the wrapper's grid and index maps, restated (they are closures
+    # there): one step a (query block, row); the suffix stream and the
+    # pools are whole operands (ANY space) that the kernel copies from
+    def q_map(qb, r, table_ref, starts_ref, lens_ref, plens_ref):
         return (qb, 0, 0)
 
-    def sfx_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        tt, _ = ap._ragged_suffix_tile(j - maxp, qb * Tq, Tq,
-                                       starts_ref[r], lens_ref[r], Tq)
-        return (tt, 0, 0)
-
-    def kv_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        meets = ap._ragged_row_meets_block(qb * Tq, Tq, starts_ref[r],
-                                           lens_ref[r])
-        last_live = ap._last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.where(meets, jnp.minimum(j, last_live),
-                                       0)], 0, 0, 0)
+    def whole(arr):
+        return (arr, arr.shape, lambda *_: (0,) * arr.ndim)
 
     out = np.full((W, Hq, D), CANARY, q.dtype)
     G = Hq // Hkv
+    # the key buffers start as uninitialised VMEM may: not finite
+    halves = np.full((2, max(ppb * ps, Tq), Hkv, D), np.nan, k_pages.dtype)
     out, writers = _run_grid(
-        kernel, name, (n_st, R, maxp + n_st),
+        kernel, name, (n_st, R),
         [("table", row_tables), ("starts", starts), ("lens", lens),
          ("plens", plens)],
         [("q", q, (Tq, Hq, D), q_map),
-         ("sfx_k", sfx_k, (Tq, Hkv, D), sfx_map),
-         ("sfx_v", sfx_v, (Tq, Hkv, D), sfx_map),
-         ("k_pages", k_pages, (1, ps, Hkv, D), kv_map),
-         ("v_pages", np.asarray(v_pages), (1, ps, Hkv, D), kv_map)],
+         ("sfx_k", *whole(sfx_k.astype(k_pages.dtype))),
+         ("sfx_v", *whole(sfx_v.astype(k_pages.dtype))),
+         ("k_pages", *whole(k_pages)),
+         ("v_pages", *whole(np.asarray(v_pages)))],
         ("o", out, (Tq, Hq, D), q_map),
-        [np.zeros((Hkv, Tq * G, D), np.float32),
+        [halves, halves.copy(), np.zeros((2, 2), np.int32),
+         np.zeros((Hkv, Tq * G, D), np.float32),
          np.full((Hkv, Tq * G, 128), -1e30, np.float32),
-         np.zeros((Hkv, Tq * G, 128), np.float32)])
+         np.zeros((Hkv, Tq * G, 128), np.float32)],
+        writer_axes=2)    # a row step is a writer: rows share a block
     out, writers = out[:n_tok], writers[:n_tok]
     _coverage_rows(name, out, writers, starts, lens)
     return out

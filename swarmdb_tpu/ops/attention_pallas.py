@@ -14,6 +14,10 @@ in HBM, and a loop inside the step that copies the row's LIVE pages, a
 block of pages a trip, into a double buffer — so a call costs what the
 contexts hold, not what the page table could hold. Its per-step-write twin
 and the int8 variants still walk a `(B, maxp)` grid, one page a step.
+`ragged_paged_prefill_attention` walks the same way: one grid step a
+(query block, wave row), and inside it the row's live prefix pages and
+its suffix tiles, a 128-token block a trip; its int8 twin keeps the grid
+of the table's width.
 
 Dense whole-lane kernel, layout (grid = (B,)):
 - q block   [1, Hq, D]      — all query heads of the slot
@@ -435,30 +439,48 @@ def paged_decode_gqa_attention_chunked(
 
 
 # ---------------------------------------------------------------------------
-# Ragged paged PREFILL attention (ISSUE 11 tentpole).
+# Ragged paged PREFILL attention (ISSUE 11 tentpole; the walk of ISSUE 32).
 #
 # One packed token STREAM per admission wave: the engine concatenates the
 # wave's rows back to back (no per-row bucket padding) and describes them
 # with per-row ``(start, len, prefix_len)`` descriptors that ride as
-# scalar-prefetch operands (SMEM). Grid (nQ, R, maxp + n_st): the stream is
-# cut into nQ query blocks of ``tile`` tokens; for query block ``qb``, grid
-# row ``r`` streams row r's PREFIX pages straight out of the page pool via
-# the page table (no ``paged_gather_kv`` densification — the dead-iteration
-# DMA-skip trick from the decode kernels bounds HBM traffic at live pages),
-# then the packed suffix K/V in [tile]-token blocks, all folded into one
-# online softmax (`_online_update`, the same machinery the decode kernels
-# use). Causality inside the stream is POSITIONAL: rows are contiguous, so
-# "key index <= query index within the same row" is exactly causal order
-# and no per-token position array is needed in the kernel.
+# scalar-prefetch operands (SMEM). Grid (nQ, R): the stream is cut into nQ
+# query blocks of ``tile`` tokens and grid row ``r`` is wave row r. A row
+# with no token in the query block (a dead row, a row of another block)
+# is one empty grid step: no DMA, no compute. A row that meets the block
+# walks ITS OWN keys inside the step, a 128-token block a trip:
+#
+#   * its PREFIX, ``ceil(prefix_len / (pages_per_block * ps))`` trips read
+#     from the prefetched ``prefix_lens`` (a fresh row makes none). The
+#     pools are operands in ANY space (HBM, exactly as `pools_flat` hands
+#     them over: [L*P, ps, Hkv, D] with the table already offset by
+#     l * P). A trip's live pages are copied by the kernel itself
+#     (`make_async_copy`, one contiguous page a DMA, ids from the
+#     prefetched table) into one half of a double buffer while the other
+#     half is folded; pages past the row's last live one are not fetched;
+#   * then its SUFFIX, the packed K/V stream (ANY space too) in
+#     [tile]-token tiles from the row's first tile to the query block's
+#     own (causality: later keys are masked for every query in it),
+#     through the same double buffer.
+#
+# Every trip folds into one online softmax (`_online_update`, the same
+# machinery the decode kernels use); each (block, row) pair keeps its own
+# state and ends in a masked finalize of the row's lanes. Causality inside
+# the stream is POSITIONAL: rows are contiguous, so "key index <= query
+# index within the same row" is exactly causal order and no per-token
+# position array is needed in the kernel. So a call costs what the wave
+# holds: R grid steps a query block, and a trip for every 128 keys a
+# row's queries can see. (The int8 twin `_ragged_prefill_kernel_quant`
+# still walks a grid (nQ, R, maxp + nQ), one 16-token page a step: 4,112
+# steps a layer for a chat wave's ~20 live ones, 1.2 ms a call whatever
+# it holds; PERF.md section 6, PR 32.)
 #
 # VMEM holds ONE query block (q, out, fp32 accumulators for all heads) and
-# one suffix K/V block at a time, so the footprint is that of a
+# the two halves of one key block, so the footprint is that of a
 # ``tile``-token wave whatever the stream width: at Llama-3-8B heads
 # (32 q / 8 kv, head_dim 128) a 128-token block is what fits v5e's default
 # 16 MiB scoped-VMEM limit (whole-stream residency was refused by the
-# chip's compiler from W=256 up). A (qb, r) pair whose row does not touch
-# the query block skips every step (compute under ``pl.when``, page DMA
-# re-pointed at one page) — what is left of it is grid-step overhead.
+# chip's compiler from W=256 up).
 
 
 def _ragged_row_meets_block(q0, n_q, start, ln):
@@ -484,9 +506,9 @@ def _ragged_suffix_tile(t, q0, n_q, start, ln, tile):
 
 
 def _ragged_fold(q_ref, k, v, valid, n_kv_heads, acc_ref, m_ref, l_ref):
-    """Fold one KV tile (k/v [Tk, Hkv, D] f32, valid [Wq*G, Tk]) into the
-    query block's online-softmax state; score rows are (w, g) pairs,
-    w-major — matching q.reshape(Wq, Hkv, G, D)."""
+    """Fold one KV tile (k/v [Tk, Hkv, D] f32, valid [Wq*G, Tk] or
+    [1, Tk]) into the query block's online-softmax state; score rows are
+    (w, g) pairs, w-major — matching q.reshape(Wq, Hkv, G, D)."""
     Wq, Hq, D = q_ref.shape
     G = Hq // n_kv_heads
     scale = 1.0 / (D ** 0.5)
@@ -502,70 +524,126 @@ def _ragged_fold(q_ref, k, v, valid, n_kv_heads, acc_ref, m_ref, l_ref):
 
 
 def _ragged_prefill_kernel(table_ref, starts_ref, lens_ref, plens_ref,
-                           q_ref, sk_ref, sv_ref, kp_ref, vp_ref, o_ref,
-                           acc_ref, m_ref, l_ref, *, page_size: int,
-                           n_kv_heads: int, n_pages: int, window):
+                           q_ref, sk_hbm, sv_hbm, kp_hbm, vp_hbm, o_ref,
+                           kbuf_ref, vbuf_ref, sem_ref, acc_ref, m_ref,
+                           l_ref, *, page_size: int, n_kv_heads: int,
+                           pages_per_block: int, window):
+    """Grid (nQ, R): query block ``qb`` against wave row ``r``. A row
+    that meets the block walks its live prefix pages in blocks of
+    ``pages_per_block`` and then its suffix tiles up to the block's own,
+    every trip through one double buffer: start the next trip's copies
+    into the other half, wait for this trip's, fold. A row that does not
+    meet the block does nothing."""
     qb = pl.program_id(0)
     r = pl.program_id(1)
-    j = pl.program_id(2)
-    n_steps = pl.num_programs(2)
     Wq, Hq, D = q_ref.shape
-    tile = sk_ref.shape[0]
     Hkv = n_kv_heads
     G = Hq // Hkv
-    ps = page_size
+    ps, ppb = page_size, pages_per_block
+    blk = ppb * ps
+    T = kbuf_ref.shape[1]             # keys a trip: max(blk, Wq)
+    far = 1 << 30
+    maxp = table_ref.shape[1]
     start = starts_ref[r]
     ln = lens_ref[r]
     plen = plens_ref[r]
     q0 = qb * Wq
-    meets = _ragged_row_meets_block(q0, Wq, start, ln)
 
-    @pl.when((r == 0) & (j == 0))
+    @pl.when(r == 0)
     def _zero_out():
         # the query block's output is revisited by every grid row (index
-        # map constant in r, j) and finalized with a masked write per
-        # row — positions no row owns (stream padding) stay zero
+        # map constant in r) and finalized with a masked write per row —
+        # positions no row owns (stream padding) stay zero. The value
+        # buffer starts finite: a key that is not fetched is masked, its
+        # weight is 0, and 0 * NaN is not
         o_ref[...] = jnp.zeros_like(o_ref)
+        vbuf_ref[...] = jnp.zeros_like(vbuf_ref)
 
-    @pl.when(meets & (j == 0))
-    def _init():
+    @pl.when(_ragged_row_meets_block(q0, Wq, start, ln))
+    def _row():
+        # truncating lax.div on non-negative numerators (`_last_live_page`)
+        live_pages = jnp.minimum(
+            jax.lax.div(jax.lax.max(plen, 0) + (ps - 1), jnp.int32(ps)),
+            maxp)
+        n_pref = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
+        # suffix tiles: the row's first to the block's own
+        first = jax.lax.div(start, jnp.int32(Wq))
+        n_trips = n_pref + (qb - first + 1)
+
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, -1e30)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    # stream index of each score row
-    wq = q0 + jax.lax.div(
-        jax.lax.broadcasted_iota(jnp.int32, (Wq * G, 1), 0), jnp.int32(G))
-    q_abs = plen + wq - start    # absolute position of query w IN ROW r
+        def copies(t, slot, act):
+            """Start or wait for (``act``) trip ``t``'s keys into half
+            ``slot``: the live pages of prefix block ``t``, or suffix
+            tile ``t - n_pref`` of the row."""
+            @pl.when(t < n_pref)
+            def _pages():
+                def page(i, carry):
+                    pid = table_ref[r, t * ppb + i]
+                    dst = pl.ds(i * ps, ps)
+                    act(pltpu.make_async_copy(
+                        kp_hbm.at[pid], kbuf_ref.at[slot, dst],
+                        sem_ref.at[0, slot]))
+                    act(pltpu.make_async_copy(
+                        vp_hbm.at[pid], vbuf_ref.at[slot, dst],
+                        sem_ref.at[1, slot]))
+                    return carry
 
-    @pl.when(meets & (j < n_pages) & (j * ps < plen))
-    def _prefix():
-        kpos = j * ps + jax.lax.broadcasted_iota(jnp.int32, (1, ps), 1)
-        valid = kpos < plen
-        if window is not None:
-            valid &= kpos > (q_abs - window)
-        _ragged_fold(q_ref, kp_ref[0].astype(jnp.float32),
-                     vp_ref[0].astype(jnp.float32),
-                     jnp.broadcast_to(valid, (Wq * G, ps)), Hkv,
-                     acc_ref, m_ref, l_ref)
+                jax.lax.fori_loop(
+                    0, jnp.minimum(live_pages - t * ppb, ppb), page, 0)
 
-    @pl.when(meets & (j >= n_pages))
-    def _suffix():
-        tt, live = _ragged_suffix_tile(j - n_pages, q0, Wq, start, ln, tile)
+            @pl.when(t >= n_pref)
+            def _tile():
+                src = pl.ds((first + t - n_pref) * Wq, Wq)
+                dst = pl.ds(0, Wq)
+                act(pltpu.make_async_copy(
+                    sk_hbm.at[src], kbuf_ref.at[slot, dst],
+                    sem_ref.at[0, slot]))
+                act(pltpu.make_async_copy(
+                    sv_hbm.at[src], vbuf_ref.at[slot, dst],
+                    sem_ref.at[1, slot]))
 
-        @pl.when(live)
-        def _live():
-            x = tt * tile + jax.lax.broadcasted_iota(
-                jnp.int32, (1, tile), 1)
-            valid = (x >= start) & (x < start + ln) & (x <= wq)
+        # stream index of each score row, and key index within a trip
+        wq = q0 + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (Wq * G, 1), 0),
+            jnp.int32(G))
+        kidx = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+
+        copies(0, 0, lambda cp: cp.start())
+
+        def trip(t, carry):
+            slot = jax.lax.rem(t, 2)
+
+            @pl.when(t + 1 < n_trips)
+            def _next():
+                copies(t + 1, 1 - slot, lambda cp: cp.start())
+
+            copies(t, slot, lambda cp: cp.wait())
+            # one mask for both kinds of trip, its bounds chosen by
+            # scalar selects. A prefix trip's keys are positions
+            # t*blk + i of the row, valid below ``plen``; a suffix
+            # trip's are stream indices of its tile, valid inside the
+            # row and not after the query (``far`` lifts that bound off
+            # a prefix trip: every cached key precedes every query)
+            pre = t < n_pref
+            kx = kidx + jnp.where(pre, t * blk, (first + t - n_pref) * Wq)
+            valid = ((kx >= jnp.where(pre, 0, start))
+                     & (kx < jnp.where(pre, plen, start + ln))
+                     & (kx <= wq + jnp.where(pre, far, 0)))
             if window is not None:
-                valid &= x > (wq - window)
-            _ragged_fold(q_ref, sk_ref[...].astype(jnp.float32),
-                         sv_ref[...].astype(jnp.float32), valid, Hkv,
+                # query w sits at plen + w - start in row r
+                valid &= kx > wq - window + jnp.where(pre, plen - start, 0)
+            if blk < T or Wq < T:
+                valid &= kidx < jnp.where(pre, blk, Wq)
+            _ragged_fold(q_ref, kbuf_ref[slot].astype(jnp.float32),
+                         vbuf_ref[slot].astype(jnp.float32), valid, Hkv,
                          acc_ref, m_ref, l_ref)
+            return carry
 
-    @pl.when(meets & (j == n_steps - 1))
-    def _finalize():
+        jax.lax.fori_loop(0, n_trips, trip, 0)
+
         denom = jnp.maximum(l_ref[:, :, :1], 1e-30)    # [Hkv, Wq*G, 1]
         out = (acc_ref[...] / denom).reshape(Hkv, Wq, G, D)
         out = out.transpose(1, 0, 2, 3).reshape(Wq, Hq, D)
@@ -592,7 +670,7 @@ def ragged_paged_prefill_attention(
     sfx_k: jnp.ndarray,       # [W, Hkv, D] packed suffix K (this wave's)
     sfx_v: jnp.ndarray,
     k_pages: jnp.ndarray,     # [P, ps, Hkv, D] single-layer page pool
-    v_pages: jnp.ndarray,
+    v_pages: jnp.ndarray,     #   (or the flat [L*P, ..] one)
     row_tables: jnp.ndarray,  # [R, maxp] int32 page ids per wave row
     starts: jnp.ndarray,      # [R] int32 — row r's offset in the stream
     lens: jnp.ndarray,        # [R] int32 — row r's token count (0 = dead)
@@ -603,7 +681,10 @@ def ragged_paged_prefill_attention(
 ) -> jnp.ndarray:
     """Ragged paged prefill attention over a packed wave; returns
     [W, Hq, D] in q.dtype (positions outside every row are zero).
-    ``tile`` is both the query block and the suffix K/V block."""
+    ``tile`` is both the query block and the suffix K/V tile. The pools
+    and the suffix stream stay in HBM as they are handed over; the
+    kernel copies each row's live pages and suffix tiles itself, so its
+    cost follows the descriptors, not the table's width."""
     n_tok = q.shape[0]
     q, sfx_k, sfx_v = _pad_stream(tile, q, sfx_k, sfx_v)
     W, Hq, D = q.shape
@@ -612,46 +693,37 @@ def ragged_paged_prefill_attention(
     G = Hq // Hkv
     Tq = min(tile, W)         # W is a whole number of blocks
     n_st = W // Tq
+    ppb = _pages_per_block(ps, Hkv, D, k_pages.dtype.itemsize, maxp)
+    keys = max(ppb * ps, Tq)  # a trip: a block of pages or a suffix tile
     table = row_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
     lens = lens.astype(jnp.int32)
     plens = prefix_lens.astype(jnp.int32)
+    # the pools' dtype is the suffix's (the model casts before attention)
+    sfx_k = sfx_k.astype(k_pages.dtype)
+    sfx_v = sfx_v.astype(v_pages.dtype)
 
-    def q_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
+    def q_map(qb, r, table_ref, starts_ref, lens_ref, plens_ref):
         return (qb, 0, 0)
-
-    def sfx_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        tt, _ = _ragged_suffix_tile(j - maxp, qb * Tq, Tq, starts_ref[r],
-                                    lens_ref[r], Tq)
-        return (tt, 0, 0)
-
-    def kv_map(qb, r, j, table_ref, starts_ref, lens_ref, plens_ref):
-        # dead page iterations AND every suffix-tile iteration re-point at
-        # the last live prefix page, so their DMA is skipped; a row that
-        # misses the query block (and an empty prefix) -> table[r, 0]
-        # (trash page 0 for fresh rows)
-        meets = _ragged_row_meets_block(qb * Tq, Tq, starts_ref[r],
-                                        lens_ref[r])
-        last_live = _last_live_page(plens_ref[r], ps)
-        return (table_ref[r, jnp.where(meets, jnp.minimum(j, last_live),
-                                       0)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(n_st, R, maxp + n_st),
+        grid=(n_st, R),
         in_specs=[
             pl.BlockSpec((Tq, Hq, D), q_map),
-            pl.BlockSpec((Tq, Hkv, D), sfx_map),
-            pl.BlockSpec((Tq, Hkv, D), sfx_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        # swarmlint: revisit[r] -- every (r, j) step of a query block
-        # accumulates into its one resident output block; the masked
-        # finalize under pl.when(j == n_steps - 1) writes each row's
-        # lanes exactly once
+        # swarmlint: revisit[r] -- every row step of a query block
+        # writes into its one resident output block; the masked finalize
+        # at the end of a row's walk writes each row's lanes exactly once
         out_specs=pl.BlockSpec((Tq, Hq, D), q_map),
         scratch_shapes=[
+            pltpu.VMEM((2, keys, Hkv, D), k_pages.dtype),    # K halves
+            pltpu.VMEM((2, keys, Hkv, D), v_pages.dtype),    # V halves
+            pltpu.SemaphoreType.DMA((2, 2)),              # [pool, half]
             pltpu.VMEM((Hkv, Tq * G, D), jnp.float32),    # acc
             pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running max
             pltpu.VMEM((Hkv, Tq * G, 128), jnp.float32),  # running denom
@@ -659,7 +731,8 @@ def ragged_paged_prefill_attention(
     )
     out = pl.pallas_call(
         functools.partial(_ragged_prefill_kernel, page_size=ps,
-                          n_kv_heads=Hkv, n_pages=maxp, window=window),
+                          n_kv_heads=Hkv, pages_per_block=ppb,
+                          window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((W, Hq, D), q.dtype),
         interpret=interpret,

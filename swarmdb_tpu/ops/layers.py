@@ -368,7 +368,9 @@ def ragged_prefill_dispatch(
             f"prefill.ragged[w{W}]",
             {"W": W + (-W) % 8, "tile": 128, "Hq": q.shape[1],
              "Hkv": sfx_k.shape[1], "D": q.shape[2],
-             "ps": pool_data(k_pages).shape[1]})
+             "ps": pool_data(k_pages).shape[1],
+             "maxp": row_tables.shape[1],
+             "itemsize": pool_data(k_pages).dtype.itemsize})
         interp = jax.default_backend() != "tpu"
         if quant:
             from .attention_pallas import (

@@ -103,8 +103,35 @@ def test_static_vmem_of_the_chunked_decode_page_loop():
     assert estimate_vmem("_paged_chunk_attn_kernel", {"Hq": hq}) is None
 
 
-def test_estimate_vmem_concrete_and_unbound():
+def test_static_vmem_of_the_ragged_prefill_walk():
+    """The ragged prefill kernel leaves the pools and the suffix stream
+    in HBM (ANY space) and holds one query block, its state and the two
+    halves of one key block: its row is in the table under its own name
+    (not its `_quant` twin's), and under the dims its dispatcher binds
+    the buffers are what `_pages_per_block` and the tile make them."""
+    from swarmdb_tpu.ops.attention_pallas import _pages_per_block
+
+    hq, hkv, d, ps, maxp = 32, 8, 128, 16, 256
+    for w, itemsize in ((128, 2), (4096, 2), (16, 2), (128, 4)):
+        dims = {"W": w, "tile": 128, "Hq": hq, "Hkv": hkv, "D": d,
+                "ps": ps, "maxp": maxp, "itemsize": itemsize}
+        tq = min(128, w)
+        keys = max(_pages_per_block(ps, hkv, d, itemsize, maxp) * ps, tq)
+        buffers = 2 * 2 * keys * hkv * d * itemsize       # K, V halves
+        blocks = 2 * 4 * 2 * tq * hq * d                  # q, out
+        state = 4 * tq * hq * (d + 2 * 128)               # acc, max, denom
+        assert estimate_vmem("_ragged_prefill_kernel",
+                             dims) == buffers + blocks + state
+    # without the table's width and the pool's item size: no estimate,
+    # and never the twin's
     dims = {"W": 64, "tile": 128, "Hq": 8, "Hkv": 2, "D": 64, "ps": 16}
+    assert estimate_vmem("_ragged_prefill_kernel", dims) is None
+    assert estimate_vmem("_ragged_prefill_kernel_quant", dims) > 0
+
+
+def test_estimate_vmem_concrete_and_unbound():
+    dims = {"W": 64, "tile": 128, "Hq": 8, "Hkv": 2, "D": 64, "ps": 16,
+            "maxp": 64, "itemsize": 2}
     est = estimate_vmem("_ragged_prefill_kernel", dims)
     assert isinstance(est, int) and est > 0
     # unbound dims -> no estimate, never an error
@@ -191,31 +218,25 @@ def test_shadow_clean_on_in_tree_kernels(kerncheck_on):
 
 
 def test_canary_fires_on_seeded_short_write(kerncheck_on, tmp_path):
-    """A sabotaged kernel that skips one live row's finalize leaves that
+    """A sabotaged kernel that loses one live row's finalize leaves that
     row either canaried or only-zero-filled — a short-write violation
     naming the row, dumped SIGKILL-proof the moment it is recorded."""
-    import functools
-
     from jax.experimental import pallas as pl
-
-    from swarmdb_tpu.ops import attention_pallas as ap
 
     rng = np.random.default_rng(3)
     (q, sk, sv, kp, vp, tables, starts, lens, plens,
      _tok_row) = kerncheck_on._random_ragged_case(rng)
-    ps = np.asarray(kp).shape[1]
-    maxp = np.asarray(tables).shape[1]
     live_r = int(np.nonzero(np.asarray(lens) > 0)[0][0])
-    base = functools.partial(
-        ap._ragged_prefill_kernel, page_size=ps,
-        n_kv_heads=np.asarray(kp).shape[2], n_pages=maxp, window=None)
+    base = kerncheck_on.ragged_prefill_body(kp, tables)
 
     def sabotaged(*refs):
-        # grid (query block, row, step)
-        if (pl.program_id(1) == live_r
-                and pl.program_id(2) == pl.num_programs(2) - 1):
-            return          # skip the finalize for this row
+        # grid (query block, row): whatever row ``live_r``'s step wrote
+        # into the output block (refs[9]) is taken back
+        o_ref = refs[9]
+        before = o_ref[...]
         base(*refs)
+        if pl.program_id(1) == live_r:
+            o_ref[...] = before
 
     kerncheck_on.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens,
@@ -232,22 +253,15 @@ def test_bounds_wrapper_names_grid_cell(kerncheck_on):
     """An in-kernel Ref access past the block records an oob-ref naming
     the ref, the slice, and the grid cell it happened at — then clamps
     so the run finishes and surfaces everything at once."""
-    import functools
-
     from jax.experimental import pallas as pl
-
-    from swarmdb_tpu.ops import attention_pallas as ap
 
     rng = np.random.default_rng(5)
     (q, sk, sv, kp, vp, tables, starts, lens, plens,
      _tok_row) = kerncheck_on._random_ragged_case(rng)
-    base = functools.partial(
-        ap._ragged_prefill_kernel, page_size=np.asarray(kp).shape[1],
-        n_kv_heads=np.asarray(kp).shape[2],
-        n_pages=np.asarray(tables).shape[1], window=None)
+    base = kerncheck_on.ragged_prefill_body(kp, tables)
 
     def overread(*refs):
-        if pl.program_id(1) == 0 and pl.program_id(2) == 0:
+        if pl.program_id(0) == 0 and pl.program_id(1) == 0:
             q_ref = refs[4]          # after the 4 scalar-prefetch refs
             _ = q_ref[pl.ds(0, q_ref.shape[0] + 4), ...]
         base(*refs)
@@ -258,37 +272,30 @@ def test_bounds_wrapper_names_grid_cell(kerncheck_on):
     assert "oob-ref" in kinds
     v = next(v for v in kerncheck_on.registry().violations()
              if v["kind"] == "oob-ref")
-    assert "grid cell (0, 0, 0)" in v["message"]
-    assert v["where"]["grid"] == [0, 0, 0]
+    assert "grid cell (0, 0)" in v["message"]
+    assert v["where"]["grid"] == [0, 0]
     assert v["rule"] == "SWL901"
 
 
 def test_write_race_on_unmasked_finalize(kerncheck_on):
-    """Dropping the last-step mask from the finalize makes every grid
-    step of a row rewrite the row's output — the element-granular
-    last-writer map calls the collision between OUTER grid rows."""
-    import functools
-
+    """Dropping the row mask from the finalize makes every row step
+    rewrite the whole output block — the element-granular last-writer
+    map calls the collision between the grid's ROW steps."""
     from jax.experimental import pallas as pl
-
-    from swarmdb_tpu.ops import attention_pallas as ap
 
     rng = np.random.default_rng(11)
     (q, sk, sv, kp, vp, tables, starts, lens, plens,
      _tok_row) = kerncheck_on._random_ragged_case(rng)
-    base = functools.partial(
-        ap._ragged_prefill_kernel, page_size=np.asarray(kp).shape[1],
-        n_kv_heads=np.asarray(kp).shape[2],
-        n_pages=np.asarray(tables).shape[1], window=None)
+    base = kerncheck_on.ragged_prefill_body(kp, tables)
 
     def unmasked(*refs):
         base(*refs)
         o_ref = refs[9]
-        # rogue: EVERY step rewrites the whole output block with a
+        # rogue: EVERY row step rewrites the whole output block with a
         # value that varies by grid row, so later rows overwrite bytes
         # the earlier rows just wrote
         o_ref[...] = jnp.zeros_like(o_ref[...]) + 1.5 * (
-            pl.program_id(1) + 1) + 0.25 * pl.program_id(2)
+            pl.program_id(1) + 1)
 
     kerncheck_on.shadow_ragged_prefill(
         q, sk, sv, kp, vp, tables, starts, lens, plens, kernel=unmasked)
@@ -508,7 +515,8 @@ def test_dispatch_records_vmem_estimate_under_profiler(monkeypatch):
 
     prof = KernelProfiler(enabled=True)
     monkeypatch.setattr(profmod, "_PROFILER", prof, raising=False)
-    dims = {"W": 16, "tile": 128, "Hq": 4, "Hkv": 2, "D": 8, "ps": 4}
+    dims = {"W": 16, "tile": 128, "Hq": 4, "Hkv": 2, "D": 8, "ps": 4,
+            "maxp": 8, "itemsize": 4}
     layers._record_static_vmem("_ragged_prefill_kernel",
                                "prefill.ragged[w16]", dims)
     prof.record_variant("prefill.ragged[w16]", 1.0, 1.0)

@@ -319,3 +319,166 @@ def test_ragged_dispatch_env(monkeypatch):
     out = layers.ragged_prefill_dispatch(*args, tok_row)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=1e-5, atol=1e-5)
+
+
+# ---- the ragged kernel's walk (ISSUE 32): rows that meet a query block,
+# ---- their live pages a block a trip, their suffix tiles up to the block's
+
+_WPS, _WMAXP, _WTILE = 8, 40, 16
+_WBLOCK = 128            # tokens a prefix trip: `_pages_per_block` * ps
+_WFULL = _WPS * _WMAXP   # the table's full width, 320 tokens
+
+
+def _walk_case(rows, *, Hq=8, Hkv=2, D=16, R=None, pad=0, shuffle=False,
+               layer=None, dtype=np.float32, window=None, seed=0):
+    """Kernel (interpret mode) against the dense reference on one wave.
+    ``rows`` = [(prefix_len, suffix_len)], (0, 0) a dead row wherever it
+    stands; ``R`` pads the descriptors with dead rows and ``pad`` the
+    stream with tokens of no row (their output is zero). ``shuffle`` deals
+    the page ids out of order; ``layer`` = (l, L) puts the pool at slice
+    l of a flat [L*P, ...] pool, the kernel's table offset by l * P."""
+    from swarmdb_tpu.ops.attention_pallas import (
+        _pages_per_block, ragged_paged_prefill_attention)
+    from swarmdb_tpu.ops.layers import ragged_prefill_attention_reference
+
+    ps, maxp = _WPS, _WMAXP
+    assert _pages_per_block(ps, Hkv, D, np.dtype(dtype).itemsize,
+                            maxp) * ps == _WBLOCK
+    rng = np.random.default_rng(seed)
+    rows = list(rows) + [(0, 0)] * ((R or len(rows)) - len(rows))
+    R = len(rows)
+    W = sum(s for _, s in rows) + pad
+    P = 1 + sum(-(-p // ps) for p, _ in rows) + 2
+    ids = np.arange(1, P)
+    if shuffle:
+        rng.shuffle(ids)
+    tables = np.zeros((R, maxp), np.int32)
+    starts, lens, plens = (np.zeros(R, np.int32) for _ in range(3))
+    tok_row = np.full(W, R, np.int32)
+    nxt = off = 0
+    for r, (p, s) in enumerate(rows):
+        n = -(-p // ps)
+        assert n <= maxp
+        tables[r, :n] = ids[nxt:nxt + n]
+        nxt += n
+        if s:
+            starts[r] = off
+        lens[r], plens[r] = s, p
+        tok_row[off:off + s] = r
+        off += s
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape), dtype)
+
+    q, sk, sv = arr(W, Hq, D), arr(W, Hkv, D), arr(W, Hkv, D)
+    kp, vp = arr(P, ps, Hkv, D), arr(P, ps, Hkv, D)
+    desc = tuple(jnp.asarray(a) for a in (starts, lens, plens))
+    ref = ragged_prefill_attention_reference(
+        q, sk, sv, kp, vp, jnp.asarray(tables), *desc,
+        jnp.asarray(tok_row), window=window)
+    pool_k, pool_v, tbl = kp, vp, tables
+    if layer is not None:
+        l, L = layer
+
+        def flat(own):   # other layers' pages hold other numbers
+            return jnp.concatenate(
+                [own if i == l else arr(*own.shape) for i in range(L)])
+
+        pool_k, pool_v, tbl = flat(kp), flat(vp), tables + l * P
+    out = ragged_paged_prefill_attention(
+        q, sk, sv, pool_k, pool_v, jnp.asarray(tbl), *desc,
+        window=window, tile=_WTILE, interpret=True)
+    assert out.dtype == q.dtype
+    out, live = np.asarray(out, np.float32), tok_row < R
+    assert (out[~live] == 0).all()
+    tol = 2e-5 if dtype == np.float32 else 5e-2
+    np.testing.assert_allclose(out[live], np.asarray(ref, np.float32)[live],
+                               rtol=tol, atol=tol)
+
+
+@pytest.mark.parametrize("kw", [
+    # one prefix length a case, beside a fresh row and a mid-block one
+    *[pytest.param(dict(rows=[(n, 5), (0, 9), (77, 3)]), id=f"prefix-{n}")
+      for n in (0, 1, _WPS, _WBLOCK - 1, _WBLOCK, _WBLOCK + 1,
+                2 * _WBLOCK, _WFULL - 1, _WFULL)],
+    # a row over two and three query blocks, its head and tail sharing
+    # blocks with its neighbours
+    pytest.param(dict(rows=[(13, 7), (40, 20), (0, 5)]),
+                 id="row-spans-two-blocks"),
+    pytest.param(dict(rows=[(0, 3), (_WBLOCK + 9, 40), (8, 5)]),
+                 id="row-spans-three-blocks"),
+    pytest.param(dict(rows=[(0, 64)]), id="fresh-row-of-four-blocks"),
+    pytest.param(dict(rows=[(0, 0), (21, 6), (0, 0), (0, 0), (130, 11),
+                            (0, 0)]),
+                 id="dead-rows-before-between-after"),
+    pytest.param(dict(rows=[(150, 30)], R=16, pad=2),
+                 id="R16-one-live-row"),
+    pytest.param(dict(rows=[(0, 0)] * 4, pad=24), id="all-dead"),
+    pytest.param(dict(rows=[(200, 17), (31, 8), (0, 4)], shuffle=True),
+                 id="page-ids-out-of-order"),
+    *[pytest.param(dict(rows=[(200, 17), (0, 4), (129, 20)], shuffle=True,
+                        layer=(l, 3)), id=f"flat-pool-layer-{l}")
+      for l in (0, 2)],
+    *[pytest.param(dict(rows=[(140, 19), (0, 6), (9, 12)], Hq=2 * g,
+                        Hkv=2), id=f"G{g}")
+      for g in (1, 4, 8)],
+    *[pytest.param(dict(rows=[(300, 21), (131, 30), (0, 40)], window=w),
+                   id=f"window-{w}")
+      for w in (5, _WBLOCK, 200)],
+    pytest.param(dict(rows=[(200, 21), (0, 12), (129, 3)],
+                      dtype=jnp.bfloat16), id="bf16"),
+])
+def test_ragged_kernel_walks_live_rows_and_pages(kw):
+    """The ragged prefill kernel (grid (query block, row); prefix pages
+    and suffix tiles copied by the kernel itself, a 128-token block a
+    trip) agrees with the dense reference wherever a row's prefix ends
+    (before, at and after a block's edge, at the table's full width),
+    however a row lies across query blocks, and whichever rows are dead."""
+    _walk_case(**kw)
+
+
+@pytest.mark.parametrize("cut", (5, _WTILE, 37))
+def test_ragged_row_split_over_two_waves_reads_back_its_pages(cut):
+    """A row longer than its wave rides two: the head's K/V land in the
+    row's pages and the tail's wave finds them there as prefix
+    (``prefix_len`` advanced). Both waves together give what one wave
+    gives."""
+    from swarmdb_tpu.ops.attention_pallas import (
+        ragged_paged_prefill_attention)
+
+    ps, maxp, Hq, Hkv, D = _WPS, _WMAXP, 8, 2, 16
+    rng = np.random.default_rng(cut)
+    p0, n = 123, 60          # cached prefix, new tokens of the split row
+    other = (19, 7)          # a neighbour in both waves
+    P = 1 + 2 * maxp
+
+    def arr(*shape):
+        return jnp.asarray(rng.normal(size=shape), jnp.float32)
+
+    kp, vp = np.array(arr(P, ps, Hkv, D)), np.array(arr(P, ps, Hkv, D))
+    tables = np.zeros((2, maxp), np.int32)
+    tables[0] = rng.permutation(np.arange(1, 1 + maxp))
+    tables[1] = rng.permutation(np.arange(1 + maxp, 1 + 2 * maxp))
+    q, sk, sv = arr(n, Hq, D), arr(n, Hkv, D), arr(n, Hkv, D)
+    oq, ok, ov = (arr(other[1], h, D) for h in (Hq, Hkv, Hkv))
+
+    def wave(lo, hi, plen):
+        """Tokens [lo, hi) of the split row, then the neighbour."""
+        args = [jnp.concatenate([a[lo:hi], b])
+                for a, b in ((q, oq), (sk, ok), (sv, ov))]
+        desc = [jnp.asarray(a, jnp.int32) for a in
+                ([0, hi - lo], [hi - lo, other[1]], [plen, other[0]])]
+        return ragged_paged_prefill_attention(
+            *args, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(tables),
+            *desc, tile=_WTILE, interpret=True)[:hi - lo]
+
+    whole = wave(0, n, p0)
+    head = wave(0, cut, p0)
+    for i in range(cut):     # the engine's paged_write_ragged
+        pos = p0 + i
+        kp[tables[0, pos // ps], pos % ps] = np.asarray(sk[i])
+        vp[tables[0, pos // ps], pos % ps] = np.asarray(sv[i])
+    tail = wave(cut, n, p0 + cut)
+    np.testing.assert_allclose(
+        np.concatenate([np.asarray(head), np.asarray(tail)]),
+        np.asarray(whole), rtol=2e-5, atol=2e-5)
