@@ -56,6 +56,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import io_callback
 
+from ..models.mixtral import routing_dropped, routing_experts
 from ..obs import TRACER, FlightRecorder
 from ..obs.metrics import (HIST_DECODE_CHUNK, HIST_QUEUE_WAIT, HIST_TTFT)
 from ..obs.profiler import NullLane, profiler as kernel_profiler
@@ -188,6 +189,93 @@ class GenRequest:
     # RETRYABLE_REASONS) may transparently requeue this request before
     # the failure surfaces. Consumed by the supervisor, not the engine.
     retries_left: int = 0
+    # ---- routing record (a configuration that routes; else None) ------
+    # routing: written by the engine before on_done fires, one row for
+    # every position of prompt + generated whose hidden state went
+    # through the stack and whose output was read (all but the last
+    # generated token; the token that sampled eos has a row too):
+    # [positions, L_routed, k] int16 in models.mixtral.encode_routing's
+    # format — the expert, or ~expert where the choice was dropped.
+    # Cached prefix positions hold the rows their pages were registered
+    # with. routing_complete: every position has its row. False where
+    # the context came by a path that does not carry routing — a rolling
+    # resume (resume_pages: tiering promotions and fleet handoffs too) —
+    # and the rows are then those of the positions this request computed
+    # — or where the record holds fewer rows than positions, whatever the
+    # path (counted: routing_incomplete_requests).
+    routing: Optional[np.ndarray] = None
+    routing_complete: bool = False
+
+
+class WaveRouting:
+    """The routing one prefill dispatch computed ([rows, T, L_routed, k],
+    or [W, L_routed, k] for a packed stream), on its way to the host.
+    Prefill never syncs, so what a slot's record and a registered page
+    keep at dispatch is a ``part`` of the wave; the first ``get`` of any
+    part lands the wave (its copy was started at dispatch, and the decode
+    that sampled a token from it has long run) and hands every part its
+    own rows, so the wave's array is not kept alive by one cached page."""
+
+    def __init__(self, dev) -> None:
+        self._dev = dev
+        self._parts: List["RoutingRows"] = []
+        self._lock = threading.Lock()
+        dev.copy_to_host_async()
+
+    def part(self, index) -> "RoutingRows":
+        rows = RoutingRows(self, index)
+        self._parts.append(rows)
+        return rows
+
+    def land(self) -> None:
+        # retirements land it: on the emission callback's thread, or on
+        # the engine thread (a cancellation, the scan path)
+        with self._lock:
+            if self._dev is None:
+                return
+            host = np.asarray(self._dev)
+            for rows in self._parts:
+                rows.rows = np.array(host[rows.index])
+            self._dev, self._parts = None, []
+
+
+class RoutingRows:
+    """``[n, L_routed, k]`` rows of a ``WaveRouting``: what a slot's
+    record and a registered page (``PrefixLRU`` keeps it as it is given)
+    hold until a retirement reads them (``Engine._finish_routing``)."""
+
+    __slots__ = ("wave", "index", "rows")
+
+    def __init__(self, wave: WaveRouting, index) -> None:
+        self.wave, self.index, self.rows = wave, index, None
+
+    def get(self) -> np.ndarray:
+        wave = self.wave      # read once: another thread may clear it
+        if wave is not None:
+            wave.land()       # idempotent; returns once the rows are set
+            self.wave = None
+        return self.rows
+
+
+class _SuffixRows:
+    """Rows ``[a, b)`` of a suffix that a split prompt spread over the
+    parts of several packed waves (``Engine._prefill_ragged_waves``): what
+    a page registered from such a row keeps."""
+
+    __slots__ = ("parts", "a", "b")
+
+    def __init__(self, parts: List[RoutingRows], a: int, b: int) -> None:
+        self.parts, self.a, self.b = parts, a, b
+
+    def get(self) -> np.ndarray:
+        out, at = [], 0
+        for part in self.parts:      # only the parts [a, b) lies in
+            rows = part.get()
+            lo, hi = max(self.a - at, 0), min(self.b - at, len(rows))
+            if lo < hi:
+                out.append(rows[lo:hi])
+            at += len(rows)
+        return np.concatenate(out)
 
 
 @dataclass
@@ -215,6 +303,15 @@ class _Slot:
     cached_tokens: int = 0
     new_tokens: int = 0
     row_pages: int = 0
+    # routed configurations: the occupant's routing so far, in position
+    # order — its cached pages' rows and its prefill's (arrays or
+    # RoutingRows), then its [K, L_routed, k] of each decode chunk (the
+    # last one cut at retirement to the steps whose output was read).
+    # Set at admission, handed to the request and cleared at
+    # retirement. None on a dense engine.
+    routing: Optional[List[Any]] = None
+    cached_parts: int = 0        # how many of them came with cached pages
+    routing_complete: bool = True
 
 
 @dataclass
@@ -284,7 +381,19 @@ class Engine:
         forward_last_fn: Optional[Callable] = None,
         flight_dir: Optional[str] = None,
         aging_s: Optional[float] = None,
+        routed: Optional[Tuple[int, int, int]] = None,
     ) -> None:
+        # routed = (L_routed, k, E) of a configuration that routes
+        # (models.mixtral.routing_shape; None = dense). Its forwards
+        # return their routing last ([.., T, L_routed, k]), every
+        # compiled program returns it after its other outputs, and the
+        # engine carries the rows to the request that owns each position
+        # (GenRequest.routing). A dense engine's programs, callbacks and
+        # records are as they were.
+        self._routed = routed
+        # what the last prefill dispatch returned after its other outputs:
+        # [] for a dense configuration, [routing] for one that routes
+        self._wave_routing: List[Any] = []
         # forward_last_fn(params, tokens, positions, cache, last_pos) ->
         # ([B, V] logits at each row's last_pos, cache): prefill only ever
         # samples the LAST position, so computing the LM head there alone
@@ -590,6 +699,10 @@ class Engine:
             # extra host block is 8 KB/chunk, while gating it would double
             # the compiled variant count (each a quarter of a minute or
             # more at 8B widths) for a flag most requests leave off.
+            # A routed configuration's forward returns its routing last
+            # ([B, 1, L_routed, k]): the step's row a slot rides the scan
+            # beside the sampled token, and the chunk's [K, B, L_routed,
+            # k] is returned after the cache. Dense: no such output.
             if self._chunked_fns is not None:
                 chunk_fwd, init_chunk, merge_chunk = self._chunked_fns
                 chunk_kv = init_chunk(self.max_batch, K)
@@ -597,7 +710,7 @@ class Engine:
                 def body(carry, step):
                     tok, pos, chunk_kv = carry
                     with jax.named_scope(scope):
-                        logits, chunk_kv = chunk_fwd(
+                        logits, chunk_kv, *routing = chunk_fwd(
                             params, tok[:, None], pos[:, None], cache,
                             chunk_kv, step,
                         )
@@ -605,41 +718,46 @@ class Engine:
                                         topk, topp, use_filters=use_filters,
                                         assume_greedy=assume_greedy)
                     lp = token_logprob(logits[:, -1], nxt)
-                    return (nxt, pos + 1, chunk_kv), (nxt, lp)
+                    return (nxt, pos + 1, chunk_kv), (
+                        nxt, lp, *(r[:, 0] for r in routing))
 
-                (last, _, chunk_kv), (sampled, lps) = jax.lax.scan(
+                (last, _, chunk_kv), (sampled, lps, *routing) = jax.lax.scan(
                     body, (last_tokens, positions, chunk_kv),
                     jnp.arange(K, dtype=jnp.int32),
                 )
                 new_cache = merge_chunk(cache, chunk_kv, positions)
                 all_toks = jnp.concatenate([last_tokens[None], sampled], axis=0)
                 all_lps = jnp.concatenate([last_lps[None], lps], axis=0)
-                all_toks, all_lps = self._replicate_block(all_toks, all_lps)
+                all_toks, all_lps, *routing = self._replicate_block(
+                    all_toks, all_lps, *routing)
                 last, last_lp = self._pin_slot_state(last, lps[-1])
-                return all_toks, all_lps, last, last_lp, new_cache
+                return (all_toks, all_lps, last, last_lp, new_cache,
+                        *routing)
 
             def body(carry, _):
                 tok, pos, cache = carry
                 with jax.named_scope(scope):
-                    logits, cache = self._decode_forward(
+                    logits, cache, *routing = self._decode_forward(
                         params, tok[:, None], pos[:, None], cache
                     )
                 nxt = sample_tokens(logits[:, -1], base_keys, pos, temp,
                                     topk, topp, use_filters=use_filters,
                                     assume_greedy=assume_greedy)
                 lp = token_logprob(logits[:, -1], nxt)
-                return (nxt, pos + 1, cache), (nxt, lp)
+                return (nxt, pos + 1, cache), (
+                    nxt, lp, *(r[:, 0] for r in routing))
 
-            (last, _, cache), (sampled, lps) = jax.lax.scan(
+            (last, _, cache), (sampled, lps, *routing) = jax.lax.scan(
                 body, (last_tokens, positions, cache), None, length=K
             )
             # row 0 = the fed tokens (surfaces prefill samples the host has
             # never seen); rows 1..K = this chunk's samples
             all_toks = jnp.concatenate([last_tokens[None], sampled], axis=0)
             all_lps = jnp.concatenate([last_lps[None], lps], axis=0)
-            all_toks, all_lps = self._replicate_block(all_toks, all_lps)
+            all_toks, all_lps, *routing = self._replicate_block(
+                all_toks, all_lps, *routing)
             last, last_lp = self._pin_slot_state(last, lps[-1])
-            return all_toks, all_lps, last, last_lp, cache
+            return all_toks, all_lps, last, last_lp, cache, *routing
 
         # ordered by parallel.multihost VARIANT_* codes
         self._decode_variants = tuple(
@@ -693,7 +811,7 @@ class Engine:
 
                 def body(carry):
                     n, done, cont, lt, llp, pos, cache = carry
-                    all_toks, all_lps, lt, llp, cache = _decode(
+                    all_toks, all_lps, lt, llp, cache, *routing = _decode(
                         params, lt, llp, pos, cache, base_keys, temp,
                         topk, topp, scope=scope, use_filters=use_filters,
                         assume_greedy=assume_greedy)
@@ -706,7 +824,7 @@ class Engine:
                     cont = io_callback(
                         self._resident_emit,
                         jax.ShapeDtypeStruct((), jnp.bool_),
-                        all_toks, all_lps, n, ordered=True)
+                        all_toks, all_lps, n, *routing, ordered=True)
                     return (n + 1, done, cont, lt, llp, pos, cache)
 
                 init = (jnp.int32(0), ~live, jnp.bool_(True), last_tokens,
@@ -781,12 +899,14 @@ class Engine:
             # [Bp, V] logits at each row's final prompt position — via the
             # head-at-last forward when the model provides one (see
             # forward_last_fn above), else full logits + gather
+            # (a routed forward's routing [Bp, T, L_routed, k] rides last)
             if self._forward_last is not None:
                 return self._forward_last(params, tokens, positions, cacheB,
                                           lengths - 1)
-            logits, cacheB = self.forward_fn(params, tokens, positions,
-                                             cacheB)
-            return logits[jnp.arange(tokens.shape[0]), lengths - 1], cacheB
+            logits, cacheB, *routing = self.forward_fn(
+                params, tokens, positions, cacheB)
+            return (logits[jnp.arange(tokens.shape[0]), lengths - 1], cacheB,
+                    *routing)
 
         self._forward_last_of = _forward_last_of
 
@@ -798,8 +918,8 @@ class Engine:
                 jnp.arange(T, dtype=jnp.int32)[None], (Bp, T)
             )
             cacheB = self._prefill_cache_fn(Bp, T)
-            last, cacheB = _forward_last_of(params, tokens, positions,
-                                            cacheB, lengths)
+            last, cacheB, *routing = _forward_last_of(
+                params, tokens, positions, cacheB, lengths)
             next_tok = sample_tokens(
                 last, base_keys, lengths - 1, temp, topk, topp
             )
@@ -813,7 +933,8 @@ class Engine:
             last_lps = last_lps.at[slot_ids].set(lp, mode="drop")
             last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                          last_lps)
-            return cache, last_tokens, last_lps
+            return (cache, last_tokens, last_lps,
+                    *self._replicate_block(*routing))
 
         self._prefill_fused = jax.jit(_prefill_insert,
                                       donate_argnums=(4, 5, 6))
@@ -836,8 +957,8 @@ class Engine:
                 jnp.arange(T, dtype=jnp.int32)[None], (Bp, T)
             )
             cacheB = self._prefill_cache_fn(Bp, T)
-            last, cacheB = _forward_last_of(params, tokens, positions,
-                                            cacheB, lengths)
+            last, cacheB, *routing = _forward_last_of(
+                params, tokens, positions, cacheB, lengths)
             next_tok = sample_tokens(
                 last, base_keys, lengths - 1, temp, topk, topp
             )
@@ -867,7 +988,8 @@ class Engine:
             last_lps = last_lps.at[slot_ids].set(lp, mode="drop")
             last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                          last_lps)
-            return k_pool, v_pool, last_tokens, last_lps
+            return (k_pool, v_pool, last_tokens, last_lps,
+                    *self._replicate_block(*routing))
 
         if paged is not None:
             self._prefill_paged_fused = jax.jit(
@@ -887,13 +1009,15 @@ class Engine:
                                            scatter, k_pool, v_pool,
                                            last_tokens, last_lps, keys,
                                            temp, topk, topp):
-                    k_pool, v_pool, last_tokens, last_lps = _packed_body_fn(
+                    (k_pool, v_pool, last_tokens, last_lps,
+                     *routing) = _packed_body_fn(
                         params, tokens, lengths, target, scatter, k_pool,
                         v_pool, last_tokens, last_lps, keys, temp, topk,
                         topp)
                     last_tokens, last_lps = self._pin_slot_state(
                         last_tokens, last_lps)
-                    return k_pool, v_pool, last_tokens, last_lps
+                    return (k_pool, v_pool, last_tokens, last_lps,
+                            *self._replicate_block(*routing))
 
                 self._prefill_paged_packed = jax.jit(
                     _prefill_packed_pinned, donate_argnums=(5, 6, 7, 8)
@@ -958,7 +1082,7 @@ class Engine:
                 # prompt continues in a later wave of the same round.
                 from ..ops.paged_kv import paged_write_ragged
 
-                last, sk, sv = _ragged_body_fn(
+                last, sk, sv, *routing = _ragged_body_fn(
                     params, tokens, tok_row, tok_pos, row_tables, starts,
                     lens, plens, k_pool, v_pool)
                 # absolute-position PRNG fold == the bucketed paths'
@@ -975,7 +1099,8 @@ class Engine:
                 last_lps = last_lps.at[scatter].set(lp, mode="drop")
                 last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                              last_lps)
-                return k_pool, v_pool, last_tokens, last_lps
+                return (k_pool, v_pool, last_tokens, last_lps,
+                        *self._replicate_block(*routing))
 
             self._prefill_ragged_fused = jax.jit(
                 _prefill_ragged_insert, donate_argnums=(9, 10, 11, 12)
@@ -1029,7 +1154,7 @@ class Engine:
                 # prefix is page-granular; trash 0 for padding)
                 Bp, T = tokens.shape
                 ps = self.paged.page_size
-                logits, sk, sv = pages_fwd(
+                logits, sk, sv, *routing = pages_fwd(
                     params, tokens, prefix_table, prefix_lens, k_pool,
                     v_pool, logits_at=lengths - 1,
                 )
@@ -1060,7 +1185,8 @@ class Engine:
                 last_lps = last_lps.at[slot_ids].set(lp, mode="drop")
                 last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                              last_lps)
-                return k_pool, v_pool, last_tokens, last_lps
+                return (k_pool, v_pool, last_tokens, last_lps,
+                        *self._replicate_block(*routing))
 
             self._prefill_paged_prefix_fused = jax.jit(
                 _prefill_paged_prefix_insert, donate_argnums=(7, 8, 9, 10)
@@ -1081,7 +1207,7 @@ class Engine:
                 from ..ops.paged_kv import paged_write_chunk, pool_dtype
 
                 Bp, T = tokens.shape
-                logits, sk, sv = pages_fwd(
+                logits, sk, sv, *routing = pages_fwd(
                     params, tokens, prefix_table, resume_lens, k_pool,
                     v_pool, logits_at=lengths - 1,
                 )
@@ -1101,7 +1227,8 @@ class Engine:
                 last_lps = last_lps.at[slot_ids].set(lp, mode="drop")
                 last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                              last_lps)
-                return k_pool, v_pool, last_tokens, last_lps
+                return (k_pool, v_pool, last_tokens, last_lps,
+                        *self._replicate_block(*routing))
 
             self._prefill_paged_resume_fused = jax.jit(
                 _prefill_paged_resume_insert, donate_argnums=(7, 8, 9, 10)
@@ -1134,7 +1261,7 @@ class Engine:
                 ps = self._prefix_ps
                 PP = prefix_table.shape[1]
                 lane_pages = min(PP + -(-T // ps), self.max_seq // ps)
-                logits, lane_k, lane_v = lane_fwd(
+                logits, lane_k, lane_v, *routing = lane_fwd(
                     params, tokens, prefix_table, prefix_lens, pool_k,
                     pool_v, lane_pages, logits_at=lengths - 1,
                 )
@@ -1172,7 +1299,8 @@ class Engine:
                 last_lps = last_lps.at[slot_ids].set(lp, mode="drop")
                 last_tokens, last_lps = self._pin_slot_state(last_tokens,
                                                              last_lps)
-                return (ck, cv), last_tokens, last_lps, pool_k, pool_v
+                return ((ck, cv), last_tokens, last_lps, pool_k, pool_v,
+                        *self._replicate_block(*routing))
 
             self._prefill_prefix_fused = jax.jit(
                 _prefill_prefix_insert, donate_argnums=(8, 9, 10, 11, 12)
@@ -1356,14 +1484,14 @@ class Engine:
                 variant, positions, keys, temp, topk, topp = args
                 fn = self._decode_variants[variant]
                 (all_toks, _lps, self._last_tokens, self._last_lps,
-                 self.cache) = fn(
+                 self.cache, *_routing) = fn(
                     self.params, self._last_tokens, self._last_lps,
                     positions, self.cache, keys, temp, topk, topp,
                 )
             elif op == mh.OP_PREFILL:
                 tokens, lengths, scatter, keys, temp, topk, topp = args
-                self.cache, self._last_tokens, self._last_lps = \
-                    self._prefill_fused(
+                (self.cache, self._last_tokens, self._last_lps,
+                 *_routing) = self._prefill_fused(
                         self.params, tokens, lengths, scatter, self.cache,
                         self._last_tokens, self._last_lps, keys, temp, topk,
                         topp,
@@ -1385,8 +1513,9 @@ class Engine:
     CALL_PAGED_PREFILL_PACKED = 5
     CALL_PAGED_PREFILL_RAGGED = 6
 
-    def _replicate_block(self, all_toks, all_lps):
-        """Constrain the chunk's sampled-token block to REPLICATED when the
+    def _replicate_block(self, *blocks):
+        """Constrain the chunk's sampled-token block (and, where the
+        configuration routes, its routing) to REPLICATED when the
         engine lives on a mesh (``place_state`` sets ``_out_rep``): the
         shard_map'd paged decode leaves it data-sharded, which a pod
         coordinator cannot device_get (the shards span other processes).
@@ -1395,9 +1524,9 @@ class Engine:
         engines (no mesh) see None and compile unchanged."""
         rep = getattr(self, "_out_rep", None)
         if rep is None:
-            return all_toks, all_lps
-        return (jax.lax.with_sharding_constraint(all_toks, rep),
-                jax.lax.with_sharding_constraint(all_lps, rep))
+            return blocks
+        return tuple(jax.lax.with_sharding_constraint(b, rep)
+                     for b in blocks)
 
     def _pin_slot_state(self, *arrays):
         """Constrain per-slot [B] state outputs (fed tokens / logprobs) to
@@ -1462,6 +1591,15 @@ class Engine:
                 t_wave, "engine.admission.dispatch", cat="engine",
                 args=self._count_wave(kind))
 
+    def _take_wave(self) -> Optional[WaveRouting]:  # swarmlint: hot
+        """The routing of the prefill just dispatched, as a wave whose
+        parts the rows' records and the pages they register keep; None
+        where the configuration is dense."""
+        if not self._wave_routing:
+            return None
+        (dev,), self._wave_routing = self._wave_routing, []
+        return WaveRouting(dev)
+
     def _pack_args(self, width: int, filled: int) -> Dict[str, Any]:  # swarmlint: hot
         """Args of the ``engine.admission.pack`` phase of the wave about
         to be dispatched: its token grid and the real tokens in it."""
@@ -1479,8 +1617,8 @@ class Engine:
     # swarmlint: hot
     def _call_paged_prefill(self, tokens, lengths, target, scatter, keys,
                             temp, topk, topp) -> None:
-        k_pool, v_pool, self._last_tokens, self._last_lps = \
-            self._prefill_paged_fused(
+        (k_pool, v_pool, self._last_tokens, self._last_lps,
+         *self._wave_routing) = self._prefill_paged_fused(
                 self.params, tokens, lengths, target, scatter,
                 self.cache["k"], self.cache["v"], self._last_tokens,
                 self._last_lps, keys, temp, topk, topp,
@@ -1490,8 +1628,8 @@ class Engine:
     # swarmlint: hot
     def _call_paged_prefill_packed(self, tokens, lengths, target, scatter,
                                    keys, temp, topk, topp) -> None:
-        k_pool, v_pool, self._last_tokens, self._last_lps = \
-            self._prefill_paged_packed(
+        (k_pool, v_pool, self._last_tokens, self._last_lps,
+         *self._wave_routing) = self._prefill_paged_packed(
                 self.params, tokens, lengths, target, scatter,
                 self.cache["k"], self.cache["v"], self._last_tokens,
                 self._last_lps, keys, temp, topk, topp,
@@ -1502,8 +1640,8 @@ class Engine:
     def _call_paged_prefix_prefill(self, tokens, lengths, plens, table,
                                    target, scatter, keys, temp, topk,
                                    topp) -> None:
-        pk, pv, self._last_tokens, self._last_lps = \
-            self._prefill_paged_prefix_fused(
+        (pk, pv, self._last_tokens, self._last_lps,
+         *self._wave_routing) = self._prefill_paged_prefix_fused(
                 self.params, tokens, lengths, plens, table, target, scatter,
                 self.cache["k"], self.cache["v"], self._last_tokens,
                 self._last_lps, keys, temp, topk, topp,
@@ -1514,8 +1652,8 @@ class Engine:
     def _call_paged_resume_prefill(self, tokens, lengths, rlens, table,
                                    row_tables, scatter, keys, temp, topk,
                                    topp) -> None:
-        pk, pv, self._last_tokens, self._last_lps = \
-            self._prefill_paged_resume_fused(
+        (pk, pv, self._last_tokens, self._last_lps,
+         *self._wave_routing) = self._prefill_paged_resume_fused(
                 self.params, tokens, lengths, rlens, table, row_tables,
                 scatter, self.cache["k"], self.cache["v"],
                 self._last_tokens, self._last_lps, keys, temp, topk, topp,
@@ -1526,8 +1664,8 @@ class Engine:
     def _call_paged_ragged_prefill(self, tokens, tok_row, tok_pos, starts,
                                    lens, plens, row_tables, scatter, keys,
                                    temp, topk, topp) -> None:
-        k_pool, v_pool, self._last_tokens, self._last_lps = \
-            self._prefill_ragged_fused(
+        (k_pool, v_pool, self._last_tokens, self._last_lps,
+         *self._wave_routing) = self._prefill_ragged_fused(
                 self.params, tokens, tok_row, tok_pos, starts, lens,
                 plens, row_tables, scatter, self.cache["k"],
                 self.cache["v"], self._last_tokens, self._last_lps, keys,
@@ -1547,7 +1685,8 @@ class Engine:
                                    reg_cols, reg_pages, scatter, keys,
                                    temp, topk, topp) -> None:
         pk, pv = self._prefix_pool
-        (self.cache, self._last_tokens, self._last_lps, pk, pv) = (
+        (self.cache, self._last_tokens, self._last_lps, pk, pv,
+         *self._wave_routing) = (
             self._prefill_prefix_fused(
                 self.params, tokens, lengths, plens, table, reg_cols,
                 reg_pages, scatter, self.cache, self._last_tokens,
@@ -1890,7 +2029,7 @@ class Engine:
                                             self._base_keys_np, self._temp,
                                             self._topk, self._topp)
                 (all_toks, _lps, self._last_tokens, self._last_lps,
-                 self.cache) = decode(
+                 self.cache, *_routing) = decode(
                     self.params, self._last_tokens, self._last_lps,
                     positions, self.cache, self._base_keys_np, self._temp,
                     self._topk, self._topp,
@@ -1985,8 +2124,8 @@ class Engine:
                 if self._mh is not None:
                     self._mh.publish_prefill(tokens, lengths, drop, keys,
                                              zero_f, zero_i, ones_f)
-                self.cache, self._last_tokens, self._last_lps = \
-                    self._prefill_fused(
+                (self.cache, self._last_tokens, self._last_lps,
+                 *_routing) = self._prefill_fused(
                         self.params, tokens, lengths, drop, self.cache,
                         self._last_tokens, self._last_lps, keys, zero_f,
                         zero_i, ones_f,
@@ -2435,9 +2574,8 @@ class Engine:
 
     def _run(self) -> None:  # swarmlint: hot
         # (token block, logprob block, snapshot, dispatch stamp, decode
-        # variant) per chunk
-        in_flight: List[Tuple[Any, Any, List[Tuple[int, GenRequest, int]],
-                              int, int]] = []
+        # variant[, routing block]) per chunk
+        in_flight: List[Tuple[Any, ...]] = []
         tracer = self.tracer
         while True:
             self._in_step = False
@@ -2948,6 +3086,10 @@ class Engine:
             # part of what a plan costs
             popped: List[GenRequest] = []
             plans: Dict[int, Tuple] = {}
+            # routed configurations: slot -> the routing rows its hit
+            # pages were registered with (the head of its record)
+            hit_routing: Dict[int, List[Any]] = {}
+            routed = self._routed is not None
             t_plan = tracer.phase_begin("engine.admission.plan")
             try:
                 with self._cv:
@@ -2967,6 +3109,7 @@ class Engine:
                         popped = []
                         rows = []
                         plans = {}
+                        hit_routing = {}
                         use_pp = self._prefix is not None
                         resume_rows: Dict[int, np.ndarray] = {}
                         # candidates = ALL free slots (the wave-size cap
@@ -3050,6 +3193,7 @@ class Engine:
                             chains: List[bytes] = []
                             for attempt in range(2):
                                 hits, chains = [], []
+                                hit_rows = [] if routed else None
                                 # keep_pages (rolling) requests bypass the
                                 # hash prefix cache both ways: a hit would
                                 # reference cache-custody pages that
@@ -3059,7 +3203,8 @@ class Engine:
                                 if (use_pp and len(req.prompt) >= self._prefix_ps
                                         and not req.keep_pages):
                                     hits, chains = self._prefix_plan(
-                                        req.prompt, pin=True)
+                                        req.prompt, pin=True,
+                                        routing=hit_rows)
                                     # DP-sharded pool: a slot can only
                                     # reference pages of its own shard (the
                                     # shard_map'd decode addresses its local
@@ -3100,6 +3245,9 @@ class Engine:
                             if (use_pp and len(req.prompt) >= self._prefix_ps
                                     and not req.keep_pages):
                                 plans[slot_id] = (hits, chains)
+                                if routed:
+                                    hit_routing[slot_id] = \
+                                        hit_rows[:len(hits)]
                     else:
                         resume_rows = {}
                         popped = []
@@ -3196,6 +3344,14 @@ class Engine:
                 slot = self.slots[slot_id]
                 slot.cached_tokens = req.resume_len
                 slot.new_tokens = len(req.prompt)
+                if routed:
+                    # the record starts with what the pool really holds
+                    # for this row: its hit pages' rows. Kept pages of a
+                    # rolling resume come without theirs (their custody is
+                    # the caller's: service registry, tiers, fleet transit)
+                    slot.routing = hit_routing.get(slot_id, [])
+                    slot.cached_parts = len(slot.routing)
+                    slot.routing_complete = req.resume_pages is None
                 slot.row_pages = (
                     int(np.count_nonzero(row_by_slot[slot_id]))
                     if self.paged else 0)
@@ -3237,7 +3393,9 @@ class Engine:
                     if self.paged:
                         hits, chains = plans[slot_id]
                     else:
-                        hits, chains = self._prefix_plan(req.prompt)
+                        hits, chains = self._prefix_plan(
+                            req.prompt, routing=slot.routing)
+                        slot.cached_parts = len(hits)
                     suffix_len = len(req.prompt) - len(hits) * self._prefix_ps
                     slot.cached_tokens = len(hits) * self._prefix_ps
                     slot.new_tokens = suffix_len
@@ -3379,13 +3537,15 @@ class Engine:
                 return b
         return self._prefix_pp_buckets[-1]
 
-    def _prefix_plan(self, prompt: List[int], pin: bool = False):
+    def _prefix_plan(self, prompt: List[int], pin: bool = False,
+                     routing: Optional[List[Any]] = None):
         """Longest cached prefix for ``prompt`` -> (hit page ids, chain
         hashes for every full prompt page). Hits are capped one page short
         of the prompt so at least one suffix token remains to prefill
         (the sampled first token needs logits). ``pin=True`` (paged mode)
         pins the hits so a later admission in the same round cannot evict
-        pages this request's table row is about to reference."""
+        pages this request's table row is about to reference. ``routing``
+        (a routed configuration) receives each hit page's routing rows."""
         from ..ops.prefix_cache import page_chains
 
         ps = self._prefix_ps
@@ -3396,9 +3556,9 @@ class Engine:
         if cap <= 0:
             return [], chains
         if pin:
-            hits = self._prefix.match_and_pin(chains[:cap], prompt)
+            hits = self._prefix.match_and_pin(chains[:cap], prompt, routing)
         else:
-            hits = self._prefix.match(chains[:cap], prompt)
+            hits = self._prefix.match(chains[:cap], prompt, routing)
         return hits, chains
 
     def _paged_allocate(self, slot_id: int, hits: List[int],
@@ -3555,7 +3715,7 @@ class Engine:
                 reg_records.append(
                     (slot_id, chains[page_idx],
                      tuple(prompt[page_idx * ps:(page_idx + 1) * ps]),
-                     fresh[f]))
+                     fresh[f], (row, slice(f * ps, (f + 1) * ps))))
         self.tracer.phase_end(
             t_pack, "engine.admission.pack", cat="engine",
             args=self._pack_args(padded.size, lengths[:len(batch)].sum()))
@@ -3564,6 +3724,7 @@ class Engine:
             target, scatter, self._base_keys_np[gather],
             self._temp[gather], self._topk[gather], self._topp[gather],
         )
+        wave = self._record_wave_rows(batch, range(len(batch)), lengths)
         self.metrics.counters["prefill_padding_tokens"].inc(
             int(padded.size) - int(lengths[:len(batch)].sum()))
         self.metrics.counters["prefill_packed_tokens"].inc(
@@ -3574,8 +3735,10 @@ class Engine:
                         int(padded.size) - int(lengths[:len(batch)].sum()),
                         prof_key("prefill.paged_prefix", padded.shape, ppb))
         pins: Dict[int, List[int]] = {}
-        for slot_id, chain, toks, page_id in reg_records:
-            if self._prefix.register(chain, toks, page_id):
+        for slot_id, chain, toks, page_id, where in reg_records:
+            if self._prefix.register(
+                    chain, toks, page_id,
+                    routing=wave.part(where) if wave is not None else None):
                 # custody -> cache; pin while this slot still reads it
                 self.paged.allocator.transfer_to_cache(slot_id, [page_id])
                 self._prefix.pin([page_id])
@@ -3627,6 +3790,7 @@ class Engine:
             row_tables, scatter, self._base_keys_np[gather],
             self._temp[gather], self._topk[gather], self._topp[gather],
         )
+        self._record_wave_rows(batch, range(len(batch)), lengths)
         self.metrics.counters["prefill_padding_tokens"].inc(
             int(padded.size) - int(lengths[:len(batch)].sum()))
         self.metrics.counters["prefill_packed_tokens"].inc(
@@ -3641,7 +3805,7 @@ class Engine:
 
     # swarmlint: hot
     def _prefix_fused_dispatch(self, rows, bucket: int, ppb: int,
-                               t0: float) -> None:
+                               t0: float) -> Optional[WaveRouting]:
         """Shared array build + dispatch for the dense prefix-path
         prefills (_prefill_prefix_batch and _prefill_dense_resume_batch —
         the resume path is the registration-free special case: same
@@ -3649,7 +3813,8 @@ class Engine:
 
         ``rows``: (slot_id, req, suffix_tokens, prefix_len, table_pages,
         reg_pairs) per admission; ``reg_pairs`` = [(lane_col, pool_page)]
-        to register (empty for resume)."""
+        to register (empty for resume). Returns the wave's routing (None
+        where the configuration is dense) for the caller's registrations."""
         t_pack = self.tracer.phase_begin("engine.admission.pack")
         ps = self._prefix_ps
         Bp = self.prefill_batch
@@ -3687,6 +3852,7 @@ class Engine:
             reg_cols, reg_pages, scatter, self._base_keys_np[gather],
             self._temp[gather], self._topk[gather], self._topp[gather],
         )
+        wave = self._record_wave_rows(rows, range(len(rows)), lengths)
         self.metrics.counters["prefix_reused_tokens"].inc(int(plens.sum()))
         self.metrics.counters["prefill_padding_tokens"].inc(
             int(padded.size) - int(lengths[:len(rows)].sum()))
@@ -3698,6 +3864,7 @@ class Engine:
                         int(padded.size) - int(lengths[:len(rows)].sum()),
                         prof_key("prefill.dense_prefix", padded.shape, ppb))
         self._activate([(r[0], r[1]) for r in rows], t0)
+        return wave
 
     # swarmlint: hot
     def _prefill_dense_resume_batch(self, batch, bucket: int,
@@ -3730,7 +3897,7 @@ class Engine:
         rows = []
         reg_records = []
         acquired = []
-        for slot_id, req, hits, chains in batch:
+        for row, (slot_id, req, hits, chains) in enumerate(batch):
             prompt = req.prompt
             p0 = len(hits) * ps
             # register the prompt's fresh FULL pages (their lane content
@@ -3743,16 +3910,20 @@ class Engine:
             for page_idx, pid in reg_pairs:
                 reg_records.append(
                     (chains[page_idx],
-                     tuple(prompt[page_idx * ps:(page_idx + 1) * ps]), pid))
+                     tuple(prompt[page_idx * ps:(page_idx + 1) * ps]), pid,
+                     (row, slice(page_idx * ps - p0,
+                                 (page_idx + 1) * ps - p0))))
             rows.append((slot_id, req, prompt[p0:], p0, hits, reg_pairs))
         try:
-            self._prefix_fused_dispatch(rows, bucket, ppb, t0)
+            wave = self._prefix_fused_dispatch(rows, bucket, ppb, t0)
         except Exception:
             for pid in acquired:
                 self._prefix.release(pid)
             raise
-        for rec in reg_records:
-            self._prefix.register(*rec)
+        for chain, toks, pid, where in reg_records:
+            self._prefix.register(
+                chain, toks, pid,
+                routing=wave.part(where) if wave is not None else None)
 
     # swarmlint: hot
     def _prefill_ragged_waves(self, batch: List[Tuple]) -> None:
@@ -3788,6 +3959,8 @@ class Engine:
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
         packed_n = padding_n = 0
+        # routed: slot -> the parts of its suffix, in stream order
+        stream_parts: Dict[int, List[RoutingRows]] = {}
         tracer = self.tracer
         while pend:
             t_pack = tracer.phase_begin("engine.admission.pack")
@@ -3852,6 +4025,17 @@ class Engine:
             # wants sized show up here as named (ragged, small-width) rows
             self._prof.wave("ragged", wd, filled, wd - filled,
                             prof_key("prefill.ragged", tokens.shape))
+            wave = self._take_wave()
+            if wave is not None:
+                # a row's record takes its chunk of this wave's stream;
+                # ``page_rows`` keeps, a slot, where each suffix position's
+                # row lies, for the pages registered below
+                for j in range(r):
+                    sid = int(gather[j])
+                    part = wave.part(slice(int(starts[j]),
+                                           int(starts[j] + lens[j])))
+                    self.slots[sid].routing.append(part)
+                    stream_parts.setdefault(sid, []).append(part)
             packed_n += filled
             padding_n += wd - filled
             pend = [it for it in pend if it[3] < len(it[1])]
@@ -3876,8 +4060,11 @@ class Engine:
                     if f >= len(fresh):
                         break
                     toks = tuple(prompt[page_idx * ps:(page_idx + 1) * ps])
-                    if self._prefix.register(chains[page_idx], toks,
-                                             fresh[f]):
+                    if self._prefix.register(
+                            chains[page_idx], toks, fresh[f],
+                            routing=_SuffixRows(stream_parts[slot_id],
+                                                f * ps, (f + 1) * ps)
+                            if slot_id in stream_parts else None):
                         self.paged.allocator.transfer_to_cache(
                             slot_id, [fresh[f]])
                         self._prefix.pin([fresh[f]])
@@ -3950,8 +4137,8 @@ class Engine:
             prof = self._prof
             t_wave = self.tracer.phase_begin("engine.admission.dispatch")
             t0_ns = time.monotonic_ns() if prof.enabled else 0
-            self.cache, self._last_tokens, self._last_lps = \
-                self._prefill_fused(
+            (self.cache, self._last_tokens, self._last_lps,
+             *self._wave_routing) = self._prefill_fused(
                     self.params,
                     padded,              # raw np: transfer rides the dispatch
                     lengths,
@@ -3971,6 +4158,7 @@ class Engine:
                 key = prof_key("prefill.dense", padded.shape)
                 prof.dispatch(key, t0_ns, time.monotonic_ns() - t0_ns)
                 prof.wave("bucketed", bucket, packed_n, padding_n, key)
+            self._record_wave_rows(batch, range(n), lengths)
             self._activate(batch, t0)
             return
 
@@ -3990,10 +4178,12 @@ class Engine:
             p_scatter = np.full(R, self.max_batch, np.int32)
             p_gather = np.zeros(R, np.int64)
             fill = [0] * n_sh  # next free row within each shard block
+            packed_rows: List[int] = []  # batch order -> row of the wave
             for row, (slot_id, req) in enumerate(batch):
                 sh = self.paged.allocator.shard_of(slot_id)
                 r = sh * rows_per + fill[sh]
                 fill[sh] += 1
+                packed_rows.append(r)
                 p_tokens[r] = padded[row]
                 p_lengths[r] = lengths[row]
                 p_scatter[r] = slot_id
@@ -4010,6 +4200,7 @@ class Engine:
             self._prof.wave("packed", bucket, packed_n,
                             int(p_tokens.size) - packed_n,
                             prof_key("prefill.packed", p_tokens.shape))
+            self._record_wave_rows(batch, packed_rows, p_lengths)
             self._activate(batch, t0)
             return
         target = np.zeros((Bp, chunks), np.int32)
@@ -4026,7 +4217,24 @@ class Engine:
         )
         self._prof.wave("bucketed", bucket, packed_n, padding_n,
                         prof_key("prefill.paged", padded.shape))
+        self._record_wave_rows(batch, range(n), lengths)
         self._activate(batch, t0)
+
+    # swarmlint: hot
+    def _record_wave_rows(self, batch, wave_rows,
+                          lengths) -> Optional[WaveRouting]:
+        """A row-bucketed prefill's routing into its rows' records:
+        ``batch[j]`` (a tuple that starts with its slot id) was row
+        ``wave_rows[j]`` of the wave just dispatched and computed
+        ``lengths[row]`` positions. Returns the wave for the caller's page
+        registrations; None, and nothing done, where the configuration is
+        dense."""
+        wave = self._take_wave()
+        if wave is not None:
+            for item, row in zip(batch, wave_rows):
+                self.slots[item[0]].routing.append(
+                    wave.part((row, slice(0, int(lengths[row])))))
+        return wave
 
     def _activate(self, batch: List[Tuple[int, GenRequest]], t0: float) -> None:  # swarmlint: hot
         if self._pagecheck is not None:
@@ -4120,8 +4328,10 @@ class Engine:
         return self._resident_variants is not None and self._mh is None
 
     # swarmlint: hot
-    def _resident_emit(self, block, lps, n) -> np.bool_:
-        """Ordered io_callback target: one call per device chunk, on the
+    def _resident_emit(self, block, lps, n, routing=None) -> np.bool_:
+        """Ordered io_callback target: one call per device chunk (with a
+        fourth operand, the chunk's routing, only where the configuration
+        routes), on the
         runtime's callback thread. The engine thread is parked in the
         session drain for the whole session, and ordered callbacks are
         serialized, so this thread IS the engine thread's stand-in:
@@ -4145,8 +4355,10 @@ class Engine:
                 # no block_until_ready, the issue's design point
                 self._prof.dispatch(self._prof_resident_key, prev_ns,
                                     now_ns - prev_ns)
-            self._process_host_block(np.asarray(block), np.asarray(lps),
-                                     snapshot, self._resident_prev_ns, n)
+            self._process_host_block(
+                np.asarray(block), np.asarray(lps), snapshot,
+                self._resident_prev_ns, n,
+                None if routing is None else np.asarray(routing))
             self._resident_prev_ns = now_ns
             return np.bool_(self._resident_should_continue())
         except Exception:
@@ -4316,8 +4528,8 @@ class Engine:
         # keys ride as a raw [B, 2] numpy argument (like temp/topk/topp):
         # per-REQUEST seeds just rewrite a host row at admission, with no
         # graph change and no eager transfer
-        all_toks, all_lps, self._last_tokens, self._last_lps, self.cache = \
-            decode(
+        (all_toks, all_lps, self._last_tokens, self._last_lps, self.cache,
+         *routing) = decode(
                 self.params, self._last_tokens, self._last_lps, positions,
                 self.cache, self._base_keys_np,
                 self._temp, self._topk, self._topp,
@@ -4326,8 +4538,9 @@ class Engine:
         # "engine.decode_chunk" span against it (monotonic, so a wall
         # clock step can't produce a negative chunk); the variant index
         # rides along so the chunk's device time lands on the right
-        # swarmprof key
-        return all_toks, all_lps, snapshot, time.monotonic_ns(), variant
+        # swarmprof key; a routed configuration's chunk routing rides last
+        return (all_toks, all_lps, snapshot, time.monotonic_ns(), variant,
+                *routing)
 
     # swarmlint: hot
     def _drain_prefill_only(self) -> None:
@@ -4369,9 +4582,11 @@ class Engine:
                 self._retire(i, "length")
 
     def _process_block(self, all_toks, all_lps, snapshot,
-                       t_dispatch_ns: int = 0, variant: int = -1) -> None:
+                       t_dispatch_ns: int = 0, variant: int = -1,
+                       routing=None) -> None:
         """Fetch one dispatched chunk's [K+1, B] token block (+ matching
-        raw-model logprobs) with the one host sync and emit its tokens.
+        raw-model logprobs, + a routed configuration's [K, B, L_routed, k]
+        routing) with the one host sync and emit its tokens.
 
         Token (s+1, i) was sampled at write position ``pos0_i + s`` —
         emission stops at a slot's EOS / max_new_tokens / max_seq and the
@@ -4382,7 +4597,7 @@ class Engine:
         # the scan path's per-chunk drain (the resident emission ring
         # replaces it with one drain per SESSION — _run_resident)
         # swarmlint: sanctioned-drain
-        block, lps = jax.device_get((all_toks, all_lps))
+        block, lps, routing = jax.device_get((all_toks, all_lps, routing))
         t_sync1 = time.monotonic_ns()
         # the sanctioned sync is itself a span + counter: the flight
         # recorder and bench phase breakdown both need "how much wall
@@ -4399,17 +4614,22 @@ class Engine:
             self._prof.dispatch(PROF_DECODE_KEYS[variant], t_dispatch_ns,
                                 t_sync1 - t_dispatch_ns)
         self._process_host_block(np.asarray(block), np.asarray(lps),
-                                 snapshot, t_dispatch_ns)
+                                 snapshot, t_dispatch_ns, routing=routing)
 
     # swarmlint: hot
     def _process_host_block(self, block, lps, snapshot,
-                            t_dispatch_ns: int = 0, chunk: int = 0) -> None:
+                            t_dispatch_ns: int = 0, chunk: int = 0,
+                            routing=None) -> None:
         """Pure host-side half of block processing: emit tokens, retire
         finished slots, close the per-chunk spans. Fed numpy blocks by
         BOTH paths — the scan path after its per-chunk drain, and the
         resident emission ring's ordered callback (where the device is
         never waited on). ``chunk`` is the block's index in its resident
-        session (a scan dispatch is one chunk a loop step: 0)."""
+        session (a scan dispatch is one chunk a loop step: 0).
+        ``routing`` is the chunk's [K, B, L_routed, k] where the
+        configuration routes: row ``[s, i]`` is the routing of the token
+        slot ``i`` was FED at step ``s``, so it joins the slot's record
+        when the token that step sampled is read."""
         # the engine thread parks in the session drain for a whole
         # resident session, so the emission callback is where a live lane
         # proves progress — beat HERE, not just in the loop
@@ -4435,6 +4655,7 @@ class Engine:
                 snapshot[0][1].request_id if snapshot else None)
         now = time.time()
         K = self.decode_chunk
+        live_rows: List[np.ndarray] = []   # routed: the rows live slots read
         for i, req, pos0 in snapshot:
             if t_dispatch_ns:
                 # one decode-chunk span per live snapshot slot: these are
@@ -4463,6 +4684,14 @@ class Engine:
                 s.pending_first = False
                 self._emit_token(i, int(block[0, i]), now,
                                  logprob=float(lps[0, i]))
+            taken = 0      # steps of this chunk whose output the slot read
+            if routing is not None and s.active:
+                # the slot's K rows of the chunk, copied once (the
+                # callback's operand is not the slot's to keep): a slot
+                # that retires inside the loop below has its record cut
+                # to the outputs it read (_finish_routing)
+                col = np.array(routing[:, i])
+                s.routing.append(col)
             for step in range(K):
                 if not s.active:
                     break
@@ -4470,17 +4699,75 @@ class Engine:
                     # the cache lane is full; later writes were dropped
                     self._retire(i, "max_seq")
                     break
+                taken += 1
                 self._emit_token(i, int(block[step + 1, i]), now,
                                  logprob=float(lps[step + 1, i]))
             if s.active:
                 s.position = pos0 + K
+            if routing is not None and taken:
+                live_rows.append(col[:taken])
         c = self.metrics.counters
+        if live_rows:
+            self._observe_load(np.concatenate(live_rows))
         c["decode_slot_chunks"].inc(n_live)
         c["kv_page_chunks_reserved"].inc(pages_reserved)
         c["kv_page_chunks_written"].inc(pages_written)
         self.tracer.phase_end(t_emit, "engine.emit", cat="engine",
                               args={"step": self._loop_step, "chunk": chunk,
                                     "live": n_live})
+
+    def _observe_load(self, rows: np.ndarray) -> None:
+        """Expert balance of one decode chunk, a layer an observation:
+        the busiest expert's assignments over the mean, from the routing
+        ``[n, L_routed, k]`` of the rows live slots read (dead lanes and
+        padding, which the device's own mean load counts, are left out).
+        1.0 is even; ``E / k`` is every row on the same experts."""
+        l_routed, k, n_experts = self._routed
+        # one count over (layer, expert), whatever the depth
+        at = (np.arange(l_routed, dtype=np.int32)[None, :, None] * n_experts
+              + routing_experts(rows))
+        counts = np.bincount(at.ravel(), minlength=l_routed * n_experts)
+        busiest = counts.reshape(l_routed, n_experts).max(axis=1)
+        reservoir = self.metrics.latencies["moe_load_max_over_mean"]
+        for ratio in (busiest * (n_experts / (len(rows) * k))).tolist():
+            reservoir.observe(ratio)
+
+    def _finish_routing(self, slot: _Slot, req: GenRequest,
+                        reason: str) -> None:
+        """Hand a retiring occupant its routing record (GenRequest.routing,
+        before on_done fires) and count it. The record is what the slot
+        gathered, cut to the positions whose output was read: the prompt,
+        and a row a sampled token but the last (the slot's last chunk
+        brought all its K steps). One that holds fewer rows than that is
+        incomplete whatever its path said: forwards that report nothing,
+        a wave that never landed. ``moe_assignments`` /
+        ``moe_dropped_assignments`` are the token-choices of the rows THIS
+        request computed (its prefill and its decode steps, not the rows
+        its cached pages came with) and those of them that fell over the
+        capacity; ``routing_incomplete_requests`` the records that lack
+        positions."""
+        parts, slot.routing = slot.routing, None
+        c = self.metrics.counters
+        l_routed, k, _e = self._routed
+        try:
+            landed = [p if isinstance(p, np.ndarray) else p.get()
+                      for p in parts]
+        except Exception:
+            # the wave never landed (the dispatch that computed it failed:
+            # this retirement is the recovery's): no rows, and counted
+            logger.exception("routing of %s did not land", req.request_id)
+            landed = []
+        sampled = len(slot.generated) + (reason == "eos")
+        need = len(req.prompt) + max(sampled - 1, 0)
+        rows = (np.concatenate(landed) if landed
+                else np.zeros((0, l_routed, k), np.int16))[:need]
+        req.routing = rows
+        req.routing_complete = slot.routing_complete and len(rows) == need
+        mine = rows[sum(len(p) for p in landed[:slot.cached_parts]):]
+        c["moe_assignments"].inc(int(mine.size))
+        c["moe_dropped_assignments"].inc(int(routing_dropped(mine).sum()))
+        if not req.routing_complete:
+            c["routing_incomplete_requests"].inc()
 
     # swarmlint: hot
     def _emit_token(self, slot_id: int, token: int,
@@ -4598,6 +4885,8 @@ class Engine:
             # raw-model logprobs of the generated tokens (parallel list);
             # delivered via request metadata so on_done's signature stays
             req.metadata["logprobs"] = list(slot.logprobs)
+            if slot.routing is not None:
+                self._finish_routing(slot, req, reason)
         if req and req.on_done is not None:
             try:
                 req.on_done(req.request_id, list(slot.generated), reason)

@@ -302,6 +302,9 @@ def build_backend_engine(
         prefix_fns=prefix_fns, prefix_pages=prefix_pages,
         prefix_page_size=page_size, forward_last_fn=fwd_last,
         flight_dir=flight_dir,
+        # a configuration that routes: its forwards return their routing
+        # last and the engine carries it to GenRequest.routing
+        routed=mixtral.routing_shape(cfg),
     )
     return engine, tokenizer
 

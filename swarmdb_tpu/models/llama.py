@@ -161,19 +161,23 @@ def _ffn(cfg: ModelConfig, moe_dispatch: Optional[str] = None):
     dense one, ``mixtral.moe_block`` for one that routes. ``moe_dispatch``
     pins the routed form's realization (``parallel/serving`` pins
     ``einsum`` on an expert-sharded mesh); ``None`` keeps the module
-    default (models/mixtral.py docstring)."""
+    default (models/mixtral.py docstring). A dense FFN returns its
+    output; a routed one returns ``(output, routing [B, T, k])``."""
     if not cfg.is_moe:
         return lambda h, lp: swiglu(h, lp["w_gate"], lp["w_up"],
                                     lp["w_down"])
     from .mixtral import moe_block
 
     def routed(h, lp):
-        # the router-load aux is for direct moe_block callers (tests,
-        # balance metrics); the serving forwards carry the cache only
-        out, _load = moe_block(
+        # the forwards carry the cache and the routing. The mean load is
+        # for direct moe_block callers: it averages over every row of the
+        # call, dead lanes and padding too, so the engine reckons the
+        # served path's balance from the routing of its live rows instead
+        # (Engine._process_host_block, ``moe_load_max_over_mean``)
+        out, _load, routing = moe_block(
             h, lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
             top_k=cfg.experts_per_token, dispatch=moe_dispatch)
-        return out
+        return out, routing
 
     return routed
 
@@ -191,7 +195,9 @@ def decoder_layer(cfg: ModelConfig, cos, sin, mixer,
     buffers, a layer index), does its cache write and attention, and
     returns ``(attn [.., T, Hq, hd], out)``. The body takes
     ``(x, (layer_params, ops))`` and returns ``(x, out)``, so the scan
-    stacks ``out`` over layers."""
+    stacks ``out`` over layers. Where the configuration routes, the body
+    returns ``(x, (out, routing))`` and the scan stacks the layer's
+    routing ``[B, T, k]`` beside it: ``take_routing`` splits the two."""
     ffn = _ffn(cfg, moe_dispatch)
 
     def layer_step(x, scanned):
@@ -203,10 +209,26 @@ def decoder_layer(cfg: ModelConfig, cos, sin, mixer,
         B, T = x.shape[0], x.shape[1]
         x = x + jnp.einsum("bth,hd->btd", attn.reshape(B, T, -1), lp["wo"])
         h2 = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-        x = x + ffn(h2, lp)
-        return x, out
+        y = ffn(h2, lp)
+        if cfg.is_moe:
+            y, routing = y
+            return x + y, (out, routing)
+        return x + y, out
 
     return layer_step
+
+
+def take_routing(cfg: ModelConfig, out):
+    """Split what a scan of ``decoder_layer`` stacked into the mixer's
+    outputs and what a forward returns after its other outputs: for a
+    configuration that routes ``(routing,)``, position-major
+    (``[L_routed, B, T, k]`` as stacked becomes ``[B, T, L_routed, k]``,
+    a row a position as ``GenRequest.routing`` holds it); for a dense one
+    ``()``, so its forwards return what they always did."""
+    if not cfg.is_moe:
+        return out, ()
+    out, routing = out
+    return out, (jnp.moveaxis(routing, 0, 2),)
 
 
 def lm_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -241,7 +263,9 @@ def forward(
     logits_at: Optional[jnp.ndarray] = None,  # [B] int32 row indices into T
     moe_dispatch: Optional[str] = None,
 ) -> Tuple[jnp.ndarray, KVCache]:
-    """One forward pass; returns fp32 logits and updated cache.
+    """One forward pass; returns fp32 logits and updated cache and, where
+    the configuration routes, the routing ``[B, T, L_routed, k]`` last
+    (``take_routing``; so it is with every forward below).
 
     Works for mixed prefill/decode batches: each row's ``positions`` are its
     own absolute offsets, and attention masks by position (ops/layers.py).
@@ -263,10 +287,11 @@ def forward(
         attn = gqa_attention(q, ck, cv, positions, window=cfg.sliding_window)
         return attn, (ck, cv)
 
-    x, new_cache = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
         (params["layers"], tuple(cache)))
-    return lm_logits(params, cfg, x, logits_at), new_cache
+    new_cache, routing = take_routing(cfg, out)
+    return lm_logits(params, cfg, x, logits_at), new_cache, *routing
 
 
 def forward_prefix_pages(
@@ -287,7 +312,9 @@ def forward_prefix_pages(
     paged path (which scatters the suffix straight into fresh pages).
 
     Returns (fp32 logits [Bp, T, V] — or [Bp, V] with ``logits_at``, see
-    ``forward`` — plus sfx_k, sfx_v [L, Bp, T, Hkv, D]).
+    ``forward`` — plus sfx_k, sfx_v [L, Bp, T, Hkv, D]) and, where the
+    configuration routes, the SUFFIX tokens' routing [Bp, T, L_routed, k]:
+    the prefix was routed by whoever computed its pages.
     """
     from ..ops.paged_kv import (_dequantize_pages, is_quantized, pool_data,
                                 pools_flat)
@@ -322,10 +349,11 @@ def forward_prefix_pages(
                                     window=cfg.sliding_window)
         return attn, (k, v)
 
-    x, (sfx_k, sfx_v) = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
         (params["layers"], jnp.arange(L, dtype=jnp.int32)))
-    return lm_logits(params, cfg, x, logits_at), sfx_k, sfx_v
+    (sfx_k, sfx_v), routing = take_routing(cfg, out)
+    return lm_logits(params, cfg, x, logits_at), sfx_k, sfx_v, *routing
 
 
 def forward_ragged_prefill(
@@ -353,7 +381,8 @@ def forward_ragged_prefill(
     with a per-layer table offset, so the kernel sees a single page axis
     (a reshape, not a copy). Returns (fp32 logits [R, V] at each row's
     LAST live token, sfx_k, sfx_v [L, W, Hkv, D] — packed, stream order,
-    for ``ops.paged_kv.paged_write_ragged``).
+    for ``ops.paged_kv.paged_write_ragged``) and, where the configuration
+    routes, the stream's routing [W, L_routed, k], stream order.
     """
     from ..ops.layers import ragged_prefill_dispatch
     from ..ops.paged_kv import pool_dtype, pools_flat
@@ -381,11 +410,13 @@ def forward_ragged_prefill(
             starts, lens, plens, tok_row, window=cfg.sliding_window)
         return attn, (ks, vs)
 
-    x, (sfx_k, sfx_v) = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer), x,
         (params["layers"], jnp.arange(L, dtype=jnp.int32)))
+    (sfx_k, sfx_v), routing = take_routing(cfg, out)
     last_w = starts + jnp.maximum(lens - 1, 0)           # dead rows -> 0
-    return lm_logits(params, cfg, x, stream_at=last_w), sfx_k, sfx_v
+    return (lm_logits(params, cfg, x, stream_at=last_w), sfx_k, sfx_v,
+            *(r[0] for r in routing))
 
 
 def forward_prefix_lane(
@@ -401,16 +432,17 @@ def forward_prefix_lane(
 ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Dense-cache prefix prefill: ``forward_prefix_pages`` + per-row lane
     composition (ops/layers.compose_prefix_lane) ready for one uniform
-    slot-cache insert. Returns (fp32 logits, lane_k, lane_v).
+    slot-cache insert. Returns (fp32 logits, lane_k, lane_v) and the
+    suffix tokens' routing as ``forward_prefix_pages`` does.
     """
     from ..ops.layers import compose_prefix_lane
 
-    logits, sfx_k, sfx_v = forward_prefix_pages(
+    logits, sfx_k, sfx_v, *routing = forward_prefix_pages(
         params, cfg, tokens, prefix_table, prefix_lens, pool_k, pool_v,
         logits_at=logits_at)
     lane_k, lane_v = compose_prefix_lane(
         pool_k, pool_v, prefix_table, prefix_lens, sfx_k, sfx_v, lane_pages)
-    return logits, lane_k, lane_v
+    return logits, lane_k, lane_v, *routing
 
 
 def init_prefix_pool(
@@ -467,10 +499,11 @@ def forward_chunked(
                                      window=cfg.sliding_window)
         return attn, (hk, hv)
 
-    x, new_chunk = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
         (params["layers"], (*cache, *chunk_kv)))
-    return lm_logits(params, cfg, x), new_chunk
+    new_chunk, routing = take_routing(cfg, out)
+    return lm_logits(params, cfg, x), new_chunk, *routing
 
 
 def merge_chunk(
@@ -523,7 +556,8 @@ def forward_paged(
     the engine scatters the prefix into pages at admission
     (ops.paged_kv.paged_insert_prefill). Attention uses the ragged Pallas
     kernel on TPU (reads only live pages) with an XLA gather fallback.
-    Returns fp32 logits [B, 1, V] and the updated cache dict.
+    Returns fp32 logits [B, 1, V] and the updated cache dict and, where
+    the configuration routes, the step's routing [B, 1, L_routed, k].
     """
     from ..ops.layers import paged_attention_dispatch
     from ..ops.paged_kv import paged_write_decode
@@ -538,13 +572,14 @@ def forward_paged(
             q, kp, vp, table, positions, window=cfg.sliding_window)
         return attn, (kp, vp)
 
-    x, (new_k, new_v) = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
         (params["layers"], (cache["k"], cache["v"])))
+    (new_k, new_v), routing = take_routing(cfg, out)
     out = {"k": new_k, "v": new_v, "page_table": table}
     if "pos0" in cache:
         out["pos0"] = cache["pos0"]
-    return lm_logits(params, cfg, x), out
+    return lm_logits(params, cfg, x), out, *routing
 
 
 def forward_paged_chunked(
@@ -580,10 +615,11 @@ def forward_paged_chunked(
             step, window=cfg.sliding_window)
         return attn, (hk, hv)
 
-    x, new_chunk = jax.lax.scan(
+    x, out = jax.lax.scan(
         decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
         (params["layers"], (jnp.arange(L, dtype=jnp.int32), *chunk_kv)))
-    return lm_logits(params, cfg, x), new_chunk
+    new_chunk, routing = take_routing(cfg, out)
+    return lm_logits(params, cfg, x), new_chunk, *routing
 
 
 def merge_paged_chunk(cache, chunk_kv, start_positions: jnp.ndarray):
@@ -788,8 +824,9 @@ def forward_seq_parallel(
                                   window=cfg.sliding_window)
             return attn, (k, v)
 
-        x, (ks, vs) = jax.lax.scan(decoder_layer(cfg, cos, sin, mixer), x,
-                                   (params["layers"], None))
+        x, out = jax.lax.scan(decoder_layer(cfg, cos, sin, mixer), x,
+                              (params["layers"], None))
+        (ks, vs), _routing = take_routing(cfg, out)
         return lm_logits(params, cfg, x), ks, vs
 
     sharded = shard_map(

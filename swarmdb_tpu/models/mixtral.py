@@ -24,6 +24,16 @@ block (``llama._ffn`` calls it). Two numerically-equivalent dispatch forms:
 Tokens over capacity are dropped (contribute zero; the residual connection
 carries them) in both forms.
 
+What the router decided leaves the block: ``moe_block`` returns, beside its
+output and the mean load, the ROUTING of its call — for each token and each
+of its ``top_k`` choices the expert's index and whether that choice was
+computed or dropped (``encode_routing`` has the format). ``llama._ffn``
+hands it on, the layer scan stacks it over the layers that route, and every
+forward of ``models/llama.py`` returns it last, ``[.., T, L_routed, k]``.
+The engine carries those rows to the request that owns each position
+(``GenRequest.routing``) and the prefix cache keeps them beside a page's
+tokens, so a reference can follow the served path's choices.
+
 No reference counterpart: the reference has no model code (SURVEY §2.4).
 """
 
@@ -44,8 +54,16 @@ Params = Dict[str, Any]
 DEFAULT_CAPACITY_FACTOR = 2.0
 
 # what callers of this family import beside ``init_params``
-forward = llama.forward
 init_kv_cache = llama.init_kv_cache
+
+
+def forward(params, cfg, tokens, positions, cache, **kw):
+    """``llama.forward`` without the routing it reports: logits and cache,
+    for the callers that compare this family's logits with a reference
+    (the tests; ``tests/benchmark/test_bench_moe_reference.py``)."""
+    logits, cache, _routing = llama.forward(params, cfg, tokens, positions,
+                                            cache, **kw)
+    return logits, cache
 
 
 # ---------------------------------------------------------------------- init
@@ -112,6 +130,37 @@ def param_specs(cfg: ModelConfig, model_axis: str = "model",
     }
 
 
+# ------------------------------------------------------------------ routing
+
+
+def encode_routing(top_idx: jnp.ndarray, within_cap: jnp.ndarray) -> jnp.ndarray:
+    """One int16 a choice: the expert's index ``e``, or ``~e`` (negative)
+    where the choice fell over the capacity and its contribution was left
+    out. The sign bit is the drop, so a reader needs no mask beside it;
+    it holds for up to 32768 experts."""
+    e = top_idx.astype(jnp.int16)
+    return jnp.where(within_cap, e, ~e)
+
+
+def routing_experts(routing):
+    """Expert indices of an encoded routing (numpy or jax, any shape)."""
+    return routing ^ (routing >> 15)      # ~r where r < 0, r elsewhere
+
+
+def routing_dropped(routing):
+    """True where the encoded choice was dropped, not computed."""
+    return routing < 0
+
+
+def routing_shape(cfg: ModelConfig) -> Optional[Tuple[int, int, int]]:
+    """``(L_routed, k, E)`` of what the forwards report a position, or
+    None for a dense configuration. The layers that route are those the
+    layer scan runs ``moe_block`` in: every one today."""
+    if not cfg.is_moe:
+        return None
+    return cfg.n_layers, cfg.experts_per_token, cfg.n_experts
+
+
 # ---------------------------------------------------------------- MoE block
 
 
@@ -133,11 +182,12 @@ def moe_block(
     top_k: int,
     capacity_factor: float = DEFAULT_CAPACITY_FACTOR,
     dispatch: Optional[str] = None,
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Top-k routed expert FFN with capacity-based dispatch.
 
     Returns (output [B, T, D], router aux: mean expert load [E] for
-    balance metrics). Static shapes: capacity C = ceil(N * top_k / E *
+    balance metrics, routing [B, T, top_k] int16: ``encode_routing`` of
+    the chosen experts and of which choices were computed). Static shapes: capacity C = ceil(N * top_k / E *
     capacity_factor); overflow tokens are dropped (zero contribution).
     ``dispatch`` picks the einsum (EP-shardable) or scatter (single-device
     fast path) realization — same routing, same values (module docstring).
@@ -169,6 +219,7 @@ def moe_block(
     pos = pos.astype(jnp.int32)
     within_cap = pos < C
     load = jnp.mean(jnp.sum(assign, axis=1), axis=0)               # [E]
+    routing = encode_routing(top_idx, within_cap).reshape(B, T, top_k)
 
     if dispatch == "scatter":
         # token scatter into per-expert queues. (expert, pos) pairs are
@@ -189,7 +240,7 @@ def moe_block(
         yk = yk * (within_cap.reshape(-1)[:, None]
                    * gates.reshape(-1)[:, None]).astype(x.dtype)
         y = jnp.zeros((N, D), x.dtype).at[tok_rows].add(yk)
-        return y.reshape(B, T, D), load
+        return y.reshape(B, T, D), load, routing
 
     # dispatch [N, E, C] (0/1) and combine [N, E, C] (gate-weighted)
     pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32)             # [N, k, C]
@@ -205,4 +256,4 @@ def moe_block(
     ye = jnp.einsum("ecf,efd->ecd", g * u, w_down)                 # [E, C, D]
     y = jnp.einsum("ecd,nec->nd", ye, combine.astype(x.dtype))
 
-    return y.reshape(B, T, D), load
+    return y.reshape(B, T, D), load, routing

@@ -791,8 +791,8 @@ def _checked_prefix_lru() -> type:
             self.pagecheck.on_unpin(page_ids)
 
         def register(self, chain: bytes, tokens: Tuple[int, ...],
-                     page_id: int) -> bool:
-            accepted = super().register(chain, tokens, page_id)
+                     page_id: int, routing: Any = None) -> bool:
+            accepted = super().register(chain, tokens, page_id, routing)
             if accepted and self._own_pool:
                 # dense mode: the page moves from caller custody into
                 # the table (paged mode mirrors via transfer_to_cache)

@@ -106,8 +106,9 @@ class PrefixLRU:
         self._free: List[int] = (
             list(range(num_pages - 1, 0, -1)) if manage_free else []
         )
-        # chain -> (page_id, token window); insertion order == LRU order
-        self._entries: "OrderedDict[bytes, Tuple[int, Tuple[int, ...]]]" = (
+        # chain -> (page_id, token window, routing rows or None);
+        # insertion order == LRU order
+        self._entries: "OrderedDict[bytes, Tuple[int, Tuple[int, ...], Any]]" = (
             OrderedDict()
         )
         self._pins: dict = {}            # page_id -> pin count
@@ -139,11 +140,15 @@ class PrefixLRU:
 
     # ---------------------------------------------------------------- lookup
 
-    def match(self, chains: Sequence[bytes],
-              tokens: Sequence[int]) -> List[int]:
+    def match(self, chains: Sequence[bytes], tokens: Sequence[int],
+              routing: Optional[List[Any]] = None) -> List[int]:
         """Longest cached run of ``chains`` (from page 0); returns its page
         ids and touches them MRU. ``tokens`` re-verifies content so a hash
-        collision cannot alias two different prefixes."""
+        collision cannot alias two different prefixes. ``routing``, where
+        the caller gives a list, receives what ``register`` was given as
+        each hit page's routing, untouched: the page's keys and values
+        were computed under those choices, so a request that reads the
+        page starts its record with them."""
         pages: List[int] = []
         ps = self.page_size
         with self._lock:
@@ -151,11 +156,13 @@ class PrefixLRU:
                 entry = self._entries.get(chain)
                 if entry is None:
                     break
-                page_id, window = entry
+                page_id, window, rows = entry
                 if tuple(tokens[i * ps: (i + 1) * ps]) != window:
                     break  # collision — treat as miss
                 self._entries.move_to_end(chain)
                 pages.append(page_id)
+                if routing is not None:
+                    routing.append(rows)
             self.hits += len(pages)
             self.misses += max(0, len(chains) - len(pages))
             if chains:
@@ -179,13 +186,12 @@ class PrefixLRU:
             while len(take) < n and self._free:
                 take.append(self._free.pop())
             if len(take) < n:
-                evictable = [c for c, (p, _) in self._entries.items()
+                evictable = [c for c, (p, _, _) in self._entries.items()
                              if not self._pins.get(p)]
                 for chain in evictable:
                     if len(take) >= n:
                         break
-                    page_id, _ = self._entries.pop(chain)
-                    take.append(page_id)
+                    take.append(self._entries.pop(chain)[0])
             return take
 
     def evict_lru(self, n: int, want=None) -> List[int]:
@@ -197,21 +203,20 @@ class PrefixLRU:
         drain the whole cache without unblocking anything."""
         with self._lock:
             out: List[int] = []
-            for chain in [c for c, (p, _) in self._entries.items()
+            for chain in [c for c, (p, _, _) in self._entries.items()
                           if not self._pins.get(p)
                           and (want is None or want(p))]:
                 if len(out) >= n:
                     break
-                page_id, _ = self._entries.pop(chain)
-                out.append(page_id)
+                out.append(self._entries.pop(chain)[0])
             return out
 
-    def match_and_pin(self, chains: Sequence[bytes],
-                      tokens: Sequence[int]) -> List[int]:
+    def match_and_pin(self, chains: Sequence[bytes], tokens: Sequence[int],
+                      routing: Optional[List[Any]] = None) -> List[int]:
         """``match`` + pin the hit pages atomically (paged mode: a later
         admission in the same round must not evict pages this one is
         about to attach to a slot)."""
-        pages = self.match(chains, tokens)
+        pages = self.match(chains, tokens, routing)
         self.pin(pages)
         return pages
 
@@ -233,7 +238,7 @@ class PrefixLRU:
         headroom, since admission can always reclaim them via
         evict_lru."""
         with self._lock:
-            return sum(1 for _, (p, _t) in self._entries.items()
+            return sum(1 for p, _t, _r in self._entries.values()
                        if not self._pins.get(p))
 
     def free_count(self) -> int:
@@ -243,13 +248,23 @@ class PrefixLRU:
             return len(self._free)
 
     def register(self, chain: bytes, tokens: Tuple[int, ...],
-                 page_id: int) -> bool:
+                 page_id: int, routing: Any = None) -> bool:
         """Bind ``chain`` to ``page_id`` (whose device content a dispatched
         write is filling with exactly ``tokens``'s KV). Returns True if
         custody of ``page_id`` was accepted; False on a DUPLICATE chain
         (two slots prefilled the same new prefix in one round) — the old
         page is kept and the caller retains custody of the new one (in
-        managed-free mode it is recycled here)."""
+        managed-free mode it is recycled here).
+
+        A page's entry holds its id, its token window and, for a
+        configuration that routes, ``routing``: the caller's handle on the
+        rows [page_size, L_routed, k] (models.mixtral.encode_routing) of
+        the forward that wrote the page — host memory, page_size *
+        L_routed * k * 2 bytes once landed; this store keeps and returns
+        it and never reads it. They leave with the entry: a duplicate
+        keeps the old page WITH the old rows, an evicted and recomputed
+        page is registered with the rows of its recomputation, so ``match``
+        always hands out what the pool's page was really computed under."""
         with self._lock:
             old = self._entries.pop(chain, None)
             if old is not None:
@@ -258,7 +273,7 @@ class PrefixLRU:
                 if self._manage_free:
                     self._free.append(page_id)
                 return False
-            self._entries[chain] = (page_id, tuple(tokens))
+            self._entries[chain] = (page_id, tuple(tokens), routing)
             return True
 
     def release(self, page_id: int) -> None:

@@ -268,7 +268,7 @@ class FleetManager:
             on_pages=lambda r, pages, written, tail:
                 self._on_pages(h, r, pages, written, tail),
             on_done=lambda r, toks, reason:
-                self._stage1_done(h, r, toks, reason),
+                self._stage1_done(h, r, toks, reason, stage1),
         )
         self._note(rid, idx)
         try:
@@ -334,7 +334,7 @@ class FleetManager:
             pc.on_host_drop(rid)
 
     def _stage1_done(self, h: _Handoff, rid: str, toks: List[int],
-                     reason: str) -> None:
+                     reason: str, stage1: Any) -> None:
         """Prefill ENGINE thread, inside ``_retire``'s on_done guard —
         must NEVER raise. Builds + submits stage 2 (or a fallback)."""
         req = h.request
@@ -356,6 +356,7 @@ class FleetManager:
             # the stream is over (or the supervisor will replay it) —
             # forward the stage-1 verdict untouched
             self._drop_payload(h)
+            self._hand_routing(h, stage1)
             self._finish(h, rid, list(toks), reason)
         except Exception:
             logger.exception("fleet stage-2 build failed for %s", rid)
@@ -408,7 +409,8 @@ class FleetManager:
             promote_payload=(entry.k, entry.v),
             keep_pages=False, on_pages=None,
             on_done=lambda r, toks, reason:
-                self._stage2_done(h, eng, ids, epoch, r, toks, reason),
+                self._stage2_done(h, eng, ids, epoch, r, toks, reason,
+                                  stage2),
         )
         with self._lock:
             if h.cancelled:
@@ -434,9 +436,10 @@ class FleetManager:
 
     def _stage2_done(self, h: _Handoff, eng: Any, ids: List[int],
                      epoch: int, rid: str, toks: List[int],
-                     reason: str) -> None:
+                     reason: str, stage2: Any) -> None:
         """Decode ENGINE thread, inside ``_retire``: release transit
         custody of the resumed pages and surface the merged stream."""
+        self._hand_routing(h, stage2)
         if epoch == eng.pool_epoch():
             try:
                 eng.rolling_free(ids)
@@ -470,8 +473,9 @@ class FleetManager:
             sampling=dataclasses.replace(sp, max_new_tokens=left),
             resume_pages=None, resume_len=0, resume_epoch=None,
             promote_payload=None, keep_pages=False, on_pages=None,
-            on_done=lambda r, toks, reason:
-                self._finish(h, r, emitted + list(toks), reason),
+            on_done=lambda r, toks, reason: (
+                self._hand_routing(h, replay),
+                self._finish(h, r, emitted + list(toks), reason)),
         )
         dec_ok = self._admissible("decode")
         pool = dec_ok or self._admissible("prefill") \
@@ -492,6 +496,18 @@ class FleetManager:
             if pc is not None:
                 pc.on_host_drop(rid)
         h.has_payload = False
+
+    @staticmethod
+    def _hand_routing(h: _Handoff, staged: Any) -> None:
+        """A configuration that routes: the engine wrote its record on the
+        staged copy it served, so the caller's request takes the record
+        of the stage that ended the stream. A stage 2 resumed from the
+        transit store's pages, whose routing did not travel with them:
+        its rows are those it computed and ``routing_complete`` is False
+        (the engine counted it); a cold replay recomputed every position
+        and is complete."""
+        h.request.routing = staged.routing
+        h.request.routing_complete = staged.routing_complete
 
     def _finish(self, h: _Handoff, rid: str, tokens: List[int],
                 reason: str) -> None:

@@ -134,13 +134,13 @@ def build_sharded_model(
                 lambda c: jax.lax.with_sharding_constraint(c, cache_sharding), cache
             )
         with pallas_disabled():
-            logits, cache = llama.forward(p, cfg, tokens, positions, cache,
-                                          **moe_kw)
+            logits, cache, *routing = llama.forward(
+                p, cfg, tokens, positions, cache, **moe_kw)
         if constrain:
             cache = jax.tree.map(
                 lambda c: jax.lax.with_sharding_constraint(c, cache_sharding), cache
             )
-        return logits, cache
+        return logits, cache, *routing
 
     def init_cache_fn(batch: int, max_seq: int):
         shape_fn = partial(llama.init_kv_cache, cfg, batch, max_seq)
@@ -163,9 +163,9 @@ def build_sharded_model(
         cache = _constrain_kv(cache)
         chunk_kv = _constrain_kv(chunk_kv)
         with pallas_disabled():
-            logits, chunk_kv = llama.forward_chunked(
+            logits, chunk_kv, *routing = llama.forward_chunked(
                 p, cfg, tokens, positions, cache, chunk_kv, step, **moe_kw)
-        return logits, _constrain_kv(chunk_kv)
+        return logits, _constrain_kv(chunk_kv), *routing
 
     def init_chunk_fn(batch: int, chunk: int):
         return _constrain_kv(llama.init_chunk_kv(cfg, batch, chunk))
@@ -276,6 +276,9 @@ def build_sharded_paged(
                                             max_seq, max_batch)
 
     params_specs = jax.tree.map(lambda _: P(), sm.params)
+    # a configuration that routes: each body's forward returns its routing
+    # last ([rows, T, L_routed, k], rows on the data axis like the tokens)
+    routing_specs = (P("data", None, None, None),) if cfg.is_moe else ()
 
     def _localize(table):
         base = jax.lax.axis_index("data").astype(jnp.int32) * per_shard
@@ -284,28 +287,31 @@ def build_sharded_paged(
     def _decode_body(p, t, pos, c):
         local = dict(c, page_table=_localize(c["page_table"]))
         with pallas_disabled():
-            logits, out = llama.forward_paged(p, cfg, t, pos, local)
+            logits, out, *routing = llama.forward_paged(p, cfg, t, pos,
+                                                        local)
         out["page_table"] = c["page_table"]  # keep GLOBAL ids outside
-        return logits, out
+        return logits, out, *routing
 
     decode_forward = shard_map(
         _decode_body, mesh=mesh,
         in_specs=(params_specs, TOKEN_SPEC, TOKEN_SPEC, PAGED_CACHE_SPECS),
-        out_specs=(P("data", None, None), PAGED_CACHE_SPECS),
+        out_specs=(P("data", None, None), PAGED_CACHE_SPECS,
+                   *routing_specs),
     )
 
     def _chunk_body(p, t, pos, c, chunk_kv, step):
         local = dict(c, page_table=_localize(c["page_table"]))
         with pallas_disabled():
-            logits, out_ck = llama.forward_paged_chunked(
+            logits, out_ck, *routing = llama.forward_paged_chunked(
                 p, cfg, t, pos, local, chunk_kv, step)
-        return logits, out_ck
+        return logits, out_ck, *routing
 
     chunk_forward = shard_map(
         _chunk_body, mesh=mesh,
         in_specs=(params_specs, TOKEN_SPEC, TOKEN_SPEC, PAGED_CACHE_SPECS,
                   (CHUNK_KV_SPEC, CHUNK_KV_SPEC), P()),
-        out_specs=(P("data", None, None), (CHUNK_KV_SPEC, CHUNK_KV_SPEC)),
+        out_specs=(P("data", None, None), (CHUNK_KV_SPEC, CHUNK_KV_SPEC),
+                   *routing_specs),
     )
 
     def _merge_body(c, chunk_kv, starts):
@@ -376,8 +382,8 @@ def build_sharded_paged(
             jnp.arange(T, dtype=jnp.int32)[None], (R, T))
         cacheB = llama.init_kv_cache(cfg, R, T)
         with pallas_disabled():
-            logits, cacheB = llama.forward(p, cfg, tokens, positions, cacheB,
-                                           logits_at=lengths - 1)
+            logits, cacheB, *routing = llama.forward(
+                p, cfg, tokens, positions, cacheB, logits_at=lengths - 1)
         last = (logits if logits.ndim == 2
                 else logits[jnp.arange(R), lengths - 1])
         next_tok = sample_tokens(last, keys, lengths - 1, temp, topk, topp)
@@ -400,7 +406,7 @@ def build_sharded_paged(
         local_slots = scatter - d * slots_per  # packing makes own rows
         last_tokens = last_tokens.at[local_slots].set(next_tok, mode="drop")
         last_lps = last_lps.at[local_slots].set(lp, mode="drop")
-        return k_pool, v_pool, last_tokens, last_lps
+        return k_pool, v_pool, last_tokens, last_lps, *routing
 
     prefill_packed = shard_map(
         _packed_body, mesh=mesh,
@@ -408,7 +414,8 @@ def build_sharded_paged(
                   P("data", None), P("data"), PAGED_POOL_SPEC,
                   PAGED_POOL_SPEC, P("data"), P("data"), P("data", None),
                   P("data"), P("data"), P("data")),
-        out_specs=(PAGED_POOL_SPEC, PAGED_POOL_SPEC, P("data"), P("data")),
+        out_specs=(PAGED_POOL_SPEC, PAGED_POOL_SPEC, P("data"), P("data"),
+                   *routing_specs),
     )
 
     from ..backend.engine import PagedKV
@@ -524,6 +531,7 @@ def build_serving_engine(
         max_batch=max_batch,
         max_seq=max_seq,
         seed=seed,
+        routed=mixtral.routing_shape(sm.cfg),
         **engine_kwargs,
     )
     # replicated engine state must live ON the mesh (mandatory for

@@ -243,7 +243,9 @@ def _family_setup(family):
         np.random.default_rng(9).integers(1, cfg.vocab_size, size=(B, T)),
         jnp.int32)
     positions = jnp.broadcast_to(jnp.arange(T, dtype=jnp.int32), (B, T))
-    want, cache = llama.forward(
+    # (a routed family's forwards return their routing last, here and in
+    # every call below: tests/test_routing_record.py holds them to it)
+    want, cache, *_routing = llama.forward(
         params, cfg, tokens, positions,
         llama.init_kv_cache(cfg, B, S, dtype=jnp.float32))
     return cfg, params, tokens, want, cache
@@ -280,12 +282,12 @@ def test_every_forward_gives_forwards_logits(family, name):
         args = (params, cfg, tokens[:, P0:], table[:, :P0 // ps],
                 jnp.full((B,), P0, jnp.int32), pool_k, pool_v)
         if name == "forward_prefix_lane":
-            got, lane_k, _ = llama.forward_prefix_lane(*args, T // ps)
+            got, lane_k, *_ = llama.forward_prefix_lane(*args, T // ps)
             np.testing.assert_allclose(np.asarray(lane_k[:, :, :T]),
                                        np.asarray(cache[0][:, :, :T]),
                                        rtol=1e-4, atol=1e-4)
         else:
-            got, _, _ = llama.forward_prefix_pages(*args)
+            got, *_ = llama.forward_prefix_pages(*args)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want[:, P0:]),
                                    rtol=1e-4, atol=1e-4)
         return
@@ -301,13 +303,14 @@ def test_every_forward_gives_forwards_logits(family, name):
         pos = jnp.full((B, 1), P0 + step, jnp.int32)
         at = jnp.asarray(step, jnp.int32)
         if name == "forward_chunked":
-            got, chunk = llama.forward_chunked(params, cfg, tok, pos,
-                                               history, chunk, at)
+            got, chunk, *_ = llama.forward_chunked(params, cfg, tok, pos,
+                                                   history, chunk, at)
         elif name == "forward_paged":
-            got, paged = llama.forward_paged(params, cfg, tok, pos, paged)
+            got, paged, *_ = llama.forward_paged(params, cfg, tok, pos,
+                                                 paged)
         else:
-            got, chunk = llama.forward_paged_chunked(params, cfg, tok, pos,
-                                                     paged, chunk, at)
+            got, chunk, *_ = llama.forward_paged_chunked(
+                params, cfg, tok, pos, paged, chunk, at)
         np.testing.assert_allclose(np.asarray(got[:, 0]),
                                    np.asarray(want[:, P0 + step]),
                                    rtol=1e-4, atol=1e-4)
@@ -322,12 +325,12 @@ def test_routed_ragged_prefill_gives_forwards_logits():
     cfg, params, tokens, want, cache = _family_setup("moe")
     T, ps = tokens.shape[1], 4
     pool_k, pool_v, table = _pages_of(cache, 0, ps)
-    got, sfx_k, _ = llama.forward_ragged_prefill(
+    got, sfx_k, _, _routing = llama.forward_ragged_prefill(
         params, cfg, tokens[0], jnp.zeros((T,), jnp.int32),
         jnp.arange(T, dtype=jnp.int32), table[:1],
         jnp.asarray([0], jnp.int32), jnp.asarray([T], jnp.int32),
         jnp.asarray([0], jnp.int32), pool_k, pool_v)
-    alone, (ck, _) = llama.forward(
+    alone, (ck, _), _routing = llama.forward(
         params, cfg, tokens[:1], jnp.arange(T, dtype=jnp.int32)[None],
         llama.init_kv_cache(cfg, 1, T, dtype=jnp.float32))
     np.testing.assert_allclose(np.asarray(got[0]), np.asarray(alone[0, -1]),

@@ -59,7 +59,7 @@ def test_moe_block_top1_picks_best_expert():
     wu = jax.random.normal(ks[3], (E, D, F), jnp.float32) * 0.1
     wd = jax.random.normal(ks[4], (E, F, D), jnp.float32) * 0.1
 
-    y, load = mixtral.moe_block(x, router, wg, wu, wd, top_k=1,
+    y, load, _ = mixtral.moe_block(x, router, wg, wu, wd, top_k=1,
                                 capacity_factor=float(E))  # no drops
     # manual per-token expert apply
     xf = x[0]
@@ -87,7 +87,7 @@ def test_moe_capacity_drops_overflow():
     wu = jnp.ones((E, D, F), jnp.float32)
     wd = jnp.ones((E, F, D), jnp.float32)
     # capacity_factor chosen so C = 1: N*k*cf/E = 8*1*cf/4 = 1 -> cf = 0.5
-    y, _ = mixtral.moe_block(x, router, wg, wu, wd, top_k=1, capacity_factor=0.5)
+    y, _, _ = mixtral.moe_block(x, router, wg, wu, wd, top_k=1, capacity_factor=0.5)
     nonzero_rows = jnp.sum(jnp.any(jnp.abs(y[0]) > 1e-9, axis=-1))
     assert int(nonzero_rows) == 1
 

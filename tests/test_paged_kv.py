@@ -556,8 +556,10 @@ def test_mixtral_paged_chunked_matches_paged():
     tok = jnp.asarray([[3], [9]], jnp.int32)
     for step in range(Kc):
         pos = jnp.full((B, 1), step, jnp.int32)
-        l_ref, pool = llama.forward_paged(params, cfg, tok, pos, pool)
-        l_chk, chunk = llama.forward_paged_chunked(
+        # (a routed family's forwards return their routing last)
+        l_ref, pool, _routing = llama.forward_paged(params, cfg, tok, pos,
+                                                    pool)
+        l_chk, chunk, _routing = llama.forward_paged_chunked(
             params, cfg, tok, pos, pool2, chunk, jnp.asarray(step, jnp.int32))
         np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_chk),
                                    rtol=1e-4, atol=1e-4)
@@ -624,10 +626,11 @@ def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
     for step in range(Kc):
         pos = jnp.asarray(starts[:, None] + step, jnp.int32)
         monkeypatch.setenv("SWARMDB_PALLAS", "0")
-        l_ref, step_pool = llama.forward_paged(params, cfg, tok, pos,
-                                               step_pool)
+        # (a routed family's forwards return their routing last)
+        l_ref, step_pool, *_ = llama.forward_paged(params, cfg, tok, pos,
+                                                   step_pool)
         monkeypatch.setenv("SWARMDB_PALLAS", pallas)
-        l_chk, chunk = llama.forward_paged_chunked(
+        l_chk, chunk, *_ = llama.forward_paged_chunked(
             params, cfg, tok, pos, chunk_pool, chunk,
             jnp.asarray(step, jnp.int32))
         assert np.all(np.isfinite(np.asarray(l_chk)))

@@ -308,7 +308,8 @@ def test_mixtral_forward_prefix_lane_matches_full():
     suffix = prompt[P0:]
     sfx = np.zeros((1, T), np.int32)
     sfx[0, :len(suffix)] = suffix
-    logits_sfx, lane_k, _lane_v = llama.forward_prefix_lane(
+    # (a routed family's forwards return their routing last)
+    logits_sfx, lane_k, _lane_v, _routing = llama.forward_prefix_lane(
         params, cfg, jnp.asarray(sfx), jnp.asarray([[1, 2]], jnp.int32),
         jnp.asarray([P0], jnp.int32), pool_k, pool_v, lane_pages,
     )
