@@ -20,13 +20,16 @@ fixed-shape decode loop under ``jax.jit`` with slot management —
 - Prefill runs per-sequence at bucketed lengths (powers of two) to bound
   the number of compiled variants, then the prefix cache is inserted into
   the slot's rows of the batch KV cache. Single-shard PAGED engines
-  instead pack each admission wave into ONE ragged no-padding token
-  stream (``_prefill_ragged_waves``: per-row (start, len, prefix_len)
-  descriptors, prefix KV read in place from the page pool, widths off a
-  power-of-two ladder — ``SWARMDB_RAGGED_PREFILL=0`` restores the
-  bucketed waves). Prefill never syncs: its sampled first token is
-  scattered into the on-device ``last_tokens`` vector and reaches the
-  host as row 0 of the next chunk's token block.
+  instead pack each admission round into ragged token streams with no
+  row or length buckets (``_prefill_ragged_waves``: per-row (start, len,
+  prefix_len) descriptors, prefix KV read in place from the page pool,
+  widths off a power-of-two ladder, chosen as the cheapest cover of the
+  round where a wave under the chip's ridge costs its pass over the
+  weights: one padded wave where that beats a second pass —
+  ``SWARMDB_RAGGED_PREFILL=0`` restores the bucketed waves). Prefill
+  never syncs: its sampled first token is scattered into the on-device
+  ``last_tokens`` vector and reaches the host as row 0 of the next
+  chunk's token block.
 - Admission is priority-ordered (MessagePriority: CRITICAL first — the
   reference stores priorities but never uses them, SURVEY §2.2).
 - Tokens stream to per-request callbacks as they are sampled; the HTTP
@@ -59,7 +62,8 @@ from jax.experimental import io_callback
 from ..models.mixtral import routing_dropped, routing_experts
 from ..obs import TRACER, FlightRecorder
 from ..obs.metrics import (HIST_DECODE_CHUNK, HIST_QUEUE_WAIT, HIST_TTFT)
-from ..obs.profiler import NullLane, profiler as kernel_profiler
+from ..obs.profiler import (NullLane, platform_peaks,
+                            profiler as kernel_profiler)
 from ..utils.metrics import MetricsRegistry
 from ..utils.sync import make_condition
 from .sampling import (SamplingParams, make_slot_keys,
@@ -85,6 +89,41 @@ def is_retryable_reason(reason: str) -> bool:
     """True when a finish reason is safe to requeue (see
     :data:`RETRYABLE_REASONS`)."""
     return reason in RETRYABLE_REASONS
+
+
+def weights_ridge_tokens(params) -> float:
+    """Tokens a prefill wave carries before its matmuls cost more than
+    the pass over its weights: a weight element is ``itemsize`` bytes
+    read and 2 FLOPs a token, so the chip's ridge (peak FLOP/s over peak
+    bytes/s, ``obs/profiler.platform_peaks`` of the device that holds
+    the weights) times ``itemsize / 2``. 240 for bf16 on a v5e, 2.5-5 on
+    the CPU row. Read from the largest parameter: it is a matmul's."""
+    big = max(jax.tree_util.tree_leaves(params), key=lambda a: a.size)
+    dev = next(iter(big.devices()))
+    peaks = platform_peaks(dev.platform, dev.device_kind)
+    return peaks["ridge_flops_per_byte"] * big.dtype.itemsize / 2
+
+
+def plan_ragged_waves(n: int, ladder: Sequence[int],
+                      ridge_tokens: float) -> List[int]:
+    """Cheapest cover of ``n`` pending prefill tokens by ladder rungs,
+    in dispatch order. A wave of width ``w`` is priced
+    ``max(w, ridge_tokens)``: under the ridge it costs its pass over the
+    weights whatever it holds, over it its tokens. At each step *round
+    up to the smallest rung >= n* is compared with *the largest rung
+    <= n, then the plan of the rest*; a tie goes to the single wave.
+    With the ridge under the smallest rung a wave costs its width and
+    the plan pads no more than largest-fit does; with the ridge at 240
+    a round of 170 is one wave of 256 where largest-fit made four."""
+    up = next((w for w in ladder if w >= n), None)
+    down = next((w for w in reversed(ladder) if w <= n), None)
+    if down is None or down == up:
+        return [up]
+    rest = plan_ragged_waves(n - down, ladder, ridge_tokens)
+    if up is not None and max(up, ridge_tokens) <= sum(
+            max(w, ridge_tokens) for w in (down, *rest)):
+        return [up]
+    return [down, *rest]
 
 
 # ---- swarmprof variant naming (obs/profiler.py, ISSUE 15) ----------------
@@ -342,7 +381,7 @@ class PagedKV:
     # prefill (ISSUE 11) — (params, tokens[W], tok_row[W], tok_pos[W],
     # row_tables[R, maxp], starts[R], lens[R], prefix_lens[R], k_pool,
     # v_pool) -> ([R, V] last-token logits, sfx_k, sfx_v [L, W, Hkv, D]).
-    # One no-padding token stream per admission wave; prefix KV (cache
+    # One packed token stream per admission wave; prefix KV (cache
     # hits and earlier chunks of a split prompt) is read straight from
     # the page pool. None = the row-bucketed dense-bucket prefill.
     prefill_ragged: Optional[Callable] = None
@@ -1024,26 +1063,33 @@ class Engine:
                 )
 
         # ---- RAGGED packed prefill (ISSUE 11 tentpole) --------------------
-        # One no-padding token stream per admission wave: rows concatenate
-        # back to back, per-row (start, len, prefix_len) descriptors ride
-        # the dispatch, and attention reads each row's prefix KV straight
-        # from the page pool (ops/layers.ragged_prefill_dispatch — the
-        # Pallas ragged-paged-prefill kernel on TPU). Wave widths come off
-        # a power-of-two ladder whose smallest rung (SWARMDB_RAGGED_MIN_
-        # WIDTH, default 8) makes every admission round a near-exact
-        # binary decomposition — padding_tokens ~0 where the row-bucketed
-        # path paid 12%. The floor sits at 8 (one TPU sublane quantum)
-        # rather than 1: rungs below 8 each compile a program that the
-        # dispatcher immediately pads back up to width 8, so they add
-        # compiled variants and per-wave dispatch overhead while moving
-        # zero extra real tokens (PROFILE.md round 11 A/B). The ladder is
-        # the ONLY compiled-variant axis:
+        # One token stream per admission wave, no row or length buckets:
+        # rows concatenate back to back, per-row (start, len, prefix_len)
+        # descriptors ride the dispatch, and attention reads each row's
+        # prefix KV straight from the page pool
+        # (ops/layers.ragged_prefill_dispatch — the Pallas
+        # ragged-paged-prefill kernel on TPU). Wave widths come off a
+        # power-of-two ladder from SWARMDB_RAGGED_MIN_WIDTH (default 8)
+        # up to max_seq; which rungs a round takes is plan_ragged_waves'
+        # least-cost cover, priced by ``_ragged_ridge_tokens``: where the
+        # ridge lies under the smallest rung (a CPU) a round pads no more
+        # than its binary decomposition does, and on a chip whose ridge
+        # is some hundred tokens the tail of a round is rounded up into
+        # one wave, because a padded token under the ridge is free and a
+        # second wave is a second pass over the weights. The floor sits
+        # at 8 (one TPU sublane quantum) rather than 1: rungs below 8
+        # each compile a program that the dispatcher immediately pads
+        # back up to width 8, so they add compiled variants and per-wave
+        # dispatch overhead while moving zero extra real tokens
+        # (PROFILE.md round 11 A/B). The ladder is the ONLY
+        # compiled-variant axis:
         # |widths| programs replace |buckets| x |row buckets| (+ the whole
         # prefix-variant family, since a cache hit is just a nonzero
         # prefix_len here). SWARMDB_RAGGED_PREFILL=0 restores the
         # row-bucketed waves.
         self._prefill_ragged_fused = None
         self._ragged_widths: List[int] = []
+        self._ragged_ridge_tokens = 0.0
         self._last_wave_kind: Optional[str] = None
         # which decode-attention path serves this engine's waves (paged
         # only): stamped on flight-step records so kernel-vs-gather
@@ -1068,6 +1114,7 @@ class Engine:
             while ladder[-1] < max_seq:
                 ladder.append(min(max_seq, ladder[-1] * 2))
             self._ragged_widths = ladder
+            self._ragged_ridge_tokens = weights_ridge_tokens(params)
             _ragged_body_fn = paged.prefill_ragged
 
             def _prefill_ragged_insert(params, tokens, tok_row, tok_pos,
@@ -2225,7 +2272,7 @@ class Engine:
 
     def _ragged_active(self) -> bool:
         """Whether paged admission runs PACKED RAGGED waves (one
-        no-padding token stream per wave, prefix KV read in place)
+        packed token stream per wave, prefix KV read in place)
         instead of row-bucketed dense-bucket prefills. ONE gate shared
         by warmup(), warmup_call_plan() and _admit — the same
         agree-or-cold-compile contract as _packed_active. Off when the
@@ -2235,14 +2282,13 @@ class Engine:
                 and not self._packed_active())
 
     def _ragged_width_for(self, n: int) -> int:
-        """Largest packed-width bucket <= ``n`` — waves peel off the
-        ladder top-down, so every wave is EXACTLY full (zero padding)
-        until the remainder drops below the smallest rung; that final
-        flush pads by < min_width tokens."""
-        for w in reversed(self._ragged_widths):
-            if w <= n:
-                return w
-        return self._ragged_widths[0]
+        """Width of the next wave for ``n`` pending tokens: the first
+        rung of their cheapest cover (``plan_ragged_waves``). A wave is
+        full while splitting is cheaper, and the round's tail is rounded
+        up into one padded wave where that costs less than another pass
+        over the weights."""
+        return plan_ragged_waves(n, self._ragged_widths,
+                                 self._ragged_ridge_tokens)[0]
 
     def _role_warms_decode(self) -> bool:
         """Whether this lane's warmup covers the decode-side variants
@@ -3930,12 +3976,14 @@ class Engine:
         """Packed ragged admission waves (ISSUE 11 tentpole): the wave's
         rows concatenate into ONE token stream — no row buckets, no
         length buckets — described by per-row (start, len, prefix_len)
-        descriptors, and every wave's width comes off the power-of-two
-        ladder LARGEST-FIT, so waves are exactly full (zero padding)
-        until the remainder drops under the smallest rung. A row longer
-        than a wave's remaining budget SPLITS: its head's K/V lands in
-        its pages this wave, and the tail rides the next wave with
-        prefix_len advanced — the ragged kernel reads the
+        descriptors, and every wave's width is the next rung of the
+        round's least-cost cover (``_ragged_width_for``): full waves
+        while the tokens outweigh a pass over the weights, then one
+        wave rounded up, its padding dead (``tok_row = R``,
+        ``tok_pos = cap``) and counted in ``prefill_padding_tokens``.
+        A row longer than a wave's remaining budget SPLITS: its head's
+        K/V lands in its pages this wave, and the tail rides the next
+        wave with prefix_len advanced — the ragged kernel reads the
         already-written pages back in place, exactly like a prefix-cache
         hit. Sampling fires only on a row's FINAL chunk (scatter id
         max_batch drops the rest), with the same absolute-position PRNG

@@ -169,8 +169,10 @@ def summarize_trace(events: List[Dict[str, Any]]) -> Dict[str, Any]:
 
 def summarize_flight(dump: Dict[str, Any]) -> Dict[str, Any]:
     """Ring-only signals a trace cannot carry: per-shard occupancy
-    imbalance, padding waste, host-syncs per step, and the request-ring
-    median timeline decomposition."""
+    imbalance, prefill padding (waste on the bucketed paths, the wave
+    plan's price for fewer passes over the weights on the ragged one),
+    host-syncs per step, and the request-ring median timeline
+    decomposition."""
     steps = dump.get("steps") or []
     reqs = dump.get("requests") or []
     imbalances: List[float] = []
@@ -204,6 +206,12 @@ def summarize_flight(dump: Dict[str, Any]) -> Dict[str, Any]:
         return int(last.get(key, 0)) - int(first.get(key, 0))
 
     prompt = delta("prompt_tokens")
+    # on ragged waves padding is the plan, not waste: the engine rounds
+    # a round's tail up into one wave wherever the padded tokens cost
+    # less than a second pass over the weights (engine.plan_ragged_waves),
+    # so a ratio of 0.2-0.4 is what a chip's ridge of some hundred tokens
+    # gives chat-sized rounds. On the bucketed and packed paths it is
+    # still bucket rounding that bought nothing
     padding = delta("prefill_padding_tokens")
 
     def med(values: List[float]) -> float:

@@ -47,13 +47,16 @@ def run(tmp_path_factory):
 
     Pins SWARMDB_RAGGED_MIN_WIDTH=1: the tiny-flush detection and
     exact-packing contracts below deliberately seed width-1 waves,
-    which the default floor of 8 folds away (PROFILE.md round 11)."""
+    which the default floor of 8 folds away (PROFILE.md round 11), and
+    the wave planner's ridge to 0: at the CPU row's 5 tokens a wave of 1
+    costs a pass over the weights and a tail of 3 is rounded up to 4."""
     mp = pytest.MonkeyPatch()
     mp.setenv("SWARMDB_RAGGED_MIN_WIDTH", "1")
     prof = profiler()
     prof.reset()
     eng = build_backend_engine(CFG, max_batch=4, max_seq=96,
                                paged=True, page_size=16)[0]
+    eng._ragged_ridge_tokens = 0.0
     eng._prof.set_label("prof-test-loaded")
     eng.warmup()
     harvest_at_warmup = prof.harvest_calls
@@ -133,9 +136,9 @@ def test_duty_cycle_loaded_vs_idle_lane(run):
 
 
 def test_dispatch_profile_and_tiny_flush_detection(run):
-    """Widths come off the power-of-two ladder largest-fit, so a prompt
-    whose length is odd MUST end in a width-1 flush wave — the profile
-    names it tiny and joins the serving variant's accounting."""
+    """With no ridge the planner's cover is the binary decomposition, so
+    a prompt whose length is odd MUST end in a width-1 flush wave — the
+    profile names it tiny and joins the serving variant's accounting."""
     prof = run["prof"]
     rows = {(r["kind"], r["width"]): r for r in prof.dispatch_profile()}
     assert ("ragged", 1) in rows, rows.keys()
