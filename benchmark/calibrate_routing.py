@@ -1,8 +1,8 @@
 #!/usr/bin/env python3
-"""What a check of an architecture that routes can and cannot separate, read
-where the numbers are real: no cell, no engine, no entry of
-``BENCHMARK.json``, never run by ``run.py``, and no rule of ``check.py``
-rests on it yet (``PERF.md`` section 6 and 7, PR 29).
+"""What the largest logit gap of a check separates from a lower precision,
+and what could take its place, read where the numbers are real: no cell,
+no engine, no entry of ``BENCHMARK.json``, never run by ``run.py``, and no
+rule of ``check.py`` rests on it (``PERF.md`` section 7, last bullet).
 
     python3 benchmark/calibrate_routing.py --shape olmoe --seeds 12 --out chiprun_out/cal
 
@@ -20,10 +20,9 @@ equations several times, teacher-forced, all positions at once:
   bf16acc   bf16 with every weight matmul accumulated in bf16 over blocks
             of 256 of the contraction
   forced    float32 arithmetic with every layer's experts chosen as a
-            lower-precision run chose them: what another position's changed
-            choice does to this one, with no rounding noise beside it; and,
-            as the yardstick, what a reference would read that followed
-            the program's own choices (``followed_*``)
+            lower-precision run chose them: what a reference reads that
+            follows the program's own choices (``followed_*``), which is
+            how ``check.py`` holds a routed configuration since PR 35
 
 The equations are ``reference/moe_decoder.py``'s (rmsnorm, split-half rope,
 causal GQA softmax, a router, a SwiGLU an expert, every token reaches every
@@ -33,23 +32,19 @@ to that file at a tiny shape. The router is the shape's: ``softmax_topk``
 ``sigmoid_bias`` (the DeepSeek-V3 block: scores = sigmoid(logits), top-k of
 scores + a bias drawn from the seed, gates = the chosen scores normalised
 and scaled). The program's own forward is not used: at 64 experts its
-capacity drops tokens in most steps (``PERF.md`` section 7), which is the
-next ``model_config`` PR's to remove and not the check's to tolerate.
+capacity drops tokens in most steps (``PERF.md`` section 7).
 
 What it reads, a line of JSON a seed and a summary over the seeds: the
 share of positions whose choice differs between f32 and bf16 in a layer;
-the float32 margin (k-th chosen selection score less the best unchosen) of
-those positions; the share of positions left decided after 1..L layers at
-each of a few margins; the gap the check computes (f32 maximum less the f32
-logit of the other run's argmax) on decided and on undecided positions, for
-bf16, for the two degraded runs and for the forced one; and the same gap
-against the forced run's logits in place of the f32 run's (``followed_*``).
-And ``statistics``: what a check could compute in place of the largest gap
-(the mean gap, the share of positions over a small gap, the share whose
-first token differs), over stretches of as many positions as a check
-compares, for the sound bf16 side (its largest reading) against each
-degraded side (its smallest): a statistic separates where the second is
-three times the first or more.
+the gap the check computes (f32 maximum less the f32 logit of the other
+run's argmax) for bf16, for the two degraded runs and for the forced one,
+and the same gap against the forced run's logits in place of the f32 run's
+(``followed_*``). And ``statistics``: what a check could compute in place
+of the largest gap (the mean gap, the share of positions over a small gap,
+the share whose first token differs), over stretches of as many positions
+as a check compares, for the sound bf16 side (its largest reading) against
+each degraded side (its smallest): a statistic separates where the second
+is three times the first or more.
 """
 
 from __future__ import annotations
@@ -61,7 +56,6 @@ import os
 import sys
 import time
 
-MARGINS = (0.01, 0.02, 0.03, 0.05, 0.08, 0.12)
 # positions a reading of ``statistics``: a check compares some hundreds of
 # served tokens (4 records of at most 256), and could compare more
 STRETCHES = (256, 512, 1024)
@@ -82,10 +76,7 @@ SHAPES = {
                   V=50304, L=4, theta=1e4, router="softmax_topk", T=2048),
     "sigmoid256": dict(D=2048, Hq=16, Hkv=16, hd=128, E=256, F=256, k=8,
                        V=32000, L=4, theta=1e4, router="sigmoid_bias",
-                       scale=2.5, bias_std=0.05, T=2048,
-                       # in scores: a sigmoid's slope is 0.12 where the
-                       # 8th of 256 lies, so a tenth of MARGINS
-                       margins=tuple(m / 10 for m in MARGINS)),
+                       scale=2.5, bias_std=0.05, T=2048),
 }
 EPS = 1e-5
 
@@ -128,7 +119,7 @@ def draw(shape, seed):
 
 def make_forward(shape, mode):
     """One jitted forward of the whole stack in ``mode``. Returns logits
-    [T, V] float32, the chosen experts [L, T, k] and the margins [L, T]."""
+    [T, V] float32 and the chosen experts [L, T, k]."""
     import jax
     import jax.numpy as jnp
 
@@ -181,16 +172,14 @@ def make_forward(shape, mode):
         return (q * scale).reshape(T, Hkv, hd).astype(dt)
 
     def route(r, bias):
-        """Router logits [T, E] float32 -> chosen [T, k], gates [T, k],
-        margin [T] in the units of the score the choice is made on."""
+        """Router logits [T, E] float32 -> chosen [T, k] and the scores
+        [T, E] the gates are made from."""
         if s["router"] == "softmax_topk":
             select = score = r
         else:
             score = jax.nn.sigmoid(r)
             select = score + bias
-        top, idx = jax.lax.top_k(select, k + 1)
-        margin = top[:, k - 1] - top[:, k]
-        return idx[:, :k], margin, score
+        return jax.lax.top_k(select, k)[1], score
 
     def gates_of(score, idx):
         chosen = jnp.take_along_axis(score, idx, axis=-1)
@@ -219,7 +208,7 @@ def make_forward(shape, mode):
 
         h2 = rmsnorm(x, lp["mlp_norm"])
         r = jnp.dot(h2.astype(jnp.float32), lp["router"].astype(jnp.float32))
-        idx, margin, score = route(r, lp["bias"])
+        idx, score = route(r, lp["bias"])
         use = idx if forced_idx is None else forced_idx
         # [T, E]: a chosen expert's gate, 0 for the others; every expert is
         # computed for every token, one expert at a time
@@ -233,23 +222,22 @@ def make_forward(shape, mode):
 
         out = jax.lax.scan(expert, jnp.zeros_like(x), (
             lp["w_gate"], lp["w_up"], lp["w_down"], gate.T))[0]
-        return x + out, idx, margin
+        return x + out, idx
 
     @jax.jit
     def forward(p, tokens, forced):
         with jax.default_matmul_precision("highest" if exact else "default"):
             x = p["embed"][tokens].astype(dt)
             ones = jnp.ones((D,), dt)
-            idxs, margins = [], []
+            idxs = []
             for i, lp in enumerate(p["layers"]):
                 lp = dict(lp, attn_norm=ones, mlp_norm=ones)
-                x, idx, margin = layer(x, lp, None if forced is None
-                                       else forced[i])
+                x, idx = layer(x, lp, None if forced is None
+                               else forced[i])
                 idxs.append(idx)
-                margins.append(margin)
             logits = jnp.dot(rmsnorm(x, ones), p["lm_head"].astype(dt),
                              preferred_element_type=jnp.float32)
-            return logits, jnp.stack(idxs), jnp.stack(margins)
+            return logits, jnp.stack(idxs)
 
     return forward
 
@@ -260,8 +248,7 @@ def read_seed(shape, seed, forwards):
     import numpy as np
 
     p, tokens = draw(shape, seed)
-    ref, idx32, margin = (np.asarray(a) for a in forwards["f32"](
-        p, tokens, None))
+    ref, idx32 = (np.asarray(a) for a in forwards["f32"](p, tokens, None))
     T = ref.shape[0]
 
     def gap(logits, of=ref):
@@ -270,9 +257,9 @@ def read_seed(shape, seed, forwards):
         first = np.asarray(logits).argmax(axis=-1)
         return of.max(axis=-1) - of[np.arange(T), first]
 
-    out = {"margin": margin}
+    out = {}
     for mode in ("bf16", "int8kv", "bf16acc"):
-        lg, idx, _ = forwards[mode](p, tokens, None)
+        lg, idx = forwards[mode](p, tokens, None)
         lg = np.asarray(lg)
         out["gap_" + mode] = gap(lg)
         forced = np.asarray(forwards["forced"](p, tokens, idx)[0])
@@ -343,51 +330,17 @@ def summary(shape, reads, lo):
     left out of the gaps (a benchmark's checked positions follow a prompt)."""
     import numpy as np
 
-    margin = np.stack([r["margin"] for r in reads])        # [S, L, T]
-    flip = np.stack([r["flip"] for r in reads])
-    least = np.minimum.accumulate(margin, axis=1)          # after 1..L layers
-    # a choice that differs after the position's own earlier layer differed
-    # follows from that one, at whatever margin: the first is the near tie
-    earlier = np.concatenate([np.zeros_like(flip[:, :1]),
-                              np.logical_or.accumulate(flip, axis=1)[:, :-1]],
-                             axis=1)
-    flipped = margin[flip & ~earlier]
-    out = {
-        "seeds": len(reads), "positions": int(margin.shape[0]
-                                              * margin.shape[2]),
+    flip = np.stack([r["flip"] for r in reads])            # [S, L, T]
+    pos = np.broadcast_to(np.arange(flip.shape[2]) >= lo,
+                          (len(reads), flip.shape[2]))
+    readings = {n: np.stack([r[n] for r in reads]) for n in READINGS}
+    return {
+        "seeds": len(reads),
+        "positions": int(flip.shape[0] * flip.shape[2]),
         "flip_share_by_layer": flip.mean(axis=(0, 2)).tolist(),
         "flip_share_any_layer": float(flip.any(axis=1).mean()),
-        "flipped": int(flip.sum()), "first_flipped": int(flipped.size),
-        "first_flipped_margin_quantiles": (
-            {q: float(np.quantile(flipped, float(q))) for q in
-             ("0.5", "0.9", "0.99", "0.999", "1.0")} if flipped.size
-            else None),
-        "by_margin": {}, "gaps": {}}
-    margins = shape.get("margins", MARGINS)
-    edges = (0.0,) + tuple(margins) + (np.inf,)
-    for a, b in zip(edges[:-1], edges[1:]):
-        inside = (margin >= a) & (margin < b)
-        out["by_margin"][f"{a}-{b}"] = {
-            "share_of_layer_positions": float(inside.mean()),
-            "flip_rate": (float(flip[inside].mean()) if inside.any()
-                          else None)}
-    pos = np.broadcast_to(np.arange(margin.shape[2]) >= lo,
-                          (len(reads), margin.shape[2]))
-    readings = {n: np.stack([r[n] for r in reads]) for n in READINGS}
-    out["all_positions"] = {n: over(g, pos) for n, g in readings.items()}
-    out["statistics"] = statistics(readings, lo)
-    for m in margins:
-        decided = least > m                                # [S, L, T]
-        d = decided[:, -1] & pos
-        row = {"decided_share_after_layers":
-               decided.mean(axis=(0, 2)).tolist(),
-               "flips_at_decided": int((flip.any(axis=1)
-                                        & decided[:, -1]).sum()),
-               "decided": {n: over(g, d) for n, g in readings.items()},
-               "undecided": {n: over(g, ~d & pos) for n, g in
-                             readings.items() if n.startswith("gap_")}}
-        out["gaps"][str(m)] = row
-    return out
+        "all_positions": {n: over(g, pos) for n, g in readings.items()},
+        "statistics": statistics(readings, lo)}
 
 
 def main(argv=None) -> int:

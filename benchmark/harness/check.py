@@ -19,17 +19,31 @@ and a benchmark PR decides its tolerance and says why. A kernel that
 mis-tiles or mis-masks moves the chosen token to a typical logit, 4-5
 below the maximum.
 
-What PR 29 read against this (``PERF.md`` section 6 and 7; nothing here
-changed for it). The largest gap of a sample separates a broken kernel or
-sampler from a sound run, and hardly a lower precision: int8 pages read
-at most 0.048 at the tests' tiny widths, and int8-rounded keys and values
-0.064-0.125 against a sound 0.032-0.054 at hidden 4096, so the sentence
-above on int8 states an intent that the readings do not bear. Under an
-architecture that chooses (experts by a router) the rule is not steady
-either: at a near tie a correct bf16 program chooses otherwise than the
-float32 reference and a logit jumps by 0.1-2 with nothing wrong, so such
-a configuration is first held when the program reports its choices and
-its reference follows them."""
+What PR 29 read against this (``PERF.md`` section 6 and 7). The largest
+gap of a sample separates a broken kernel or sampler from a sound run, and
+hardly a lower precision: int8 pages read at most 0.048 at the tests' tiny
+widths, and int8-rounded keys and values 0.064-0.125 against a sound
+0.032-0.054 at hidden 4096, so the sentence above on int8 states an intent
+that the readings do not bear.
+
+An architecture that chooses (experts by a router) is held on the
+reference FORCED to the program's own choices (PR 35). Unforced the rule
+is not steady: at a near tie a correct bf16 program chooses otherwise than
+the float32 reference and a logit jumps by 0.1-2 with nothing wrong. The
+program reports what it chose (``GenRequest.routing``, kept by the
+harness's ``Recorder``), and a reference module that declares
+``FOLLOWS_ROUTING = True`` takes those rows as a fifth argument and
+computes, in float32, the experts the program computed, with its own
+gates there: the comparison then reads as a dense stack's does (at most
+0.031 on the CPU and 0.032 on the chip, where the same records read up to
+2.81 unforced and a broken sampler at least 0.50 forced, ``PERF.md``
+section 6), and LOGIT_TOL is the same 0.1. A record
+that reaches such a reference without a row for every position that went
+through the stack cannot be followed, and the check raises on it and
+never falls back to the unforced comparison. What following cannot see: a
+router whose own arithmetic is wrong and still reports what it then
+computed (``PERF.md`` section 4 says what covers that). A reference
+without the attribute is called with four arguments, as before."""
 
 from __future__ import annotations
 
@@ -53,6 +67,31 @@ def sample(records: List[Dict[str, Any]], seed: int, n: int
     return [longest] + rest[:max(0, n - 1)]
 
 
+def follows_routing(reference) -> bool:
+    """Whether the reference module asks for the program's routing."""
+    return bool(getattr(reference, "FOLLOWS_ROUTING", False))
+
+
+def routing_rows(rec: Dict[str, Any], need: int, T: int):
+    """The record's routing for a following reference: its first ``need``
+    rows (one for every position whose output was read: all of prompt +
+    compared tokens but the last), padded to ``T`` with the row that marks
+    every choice as left out (``~0``), so that a padded token takes
+    nothing and, the stack being causal, no compared position reads it."""
+    import numpy as np
+
+    routing = rec["routing"]
+    if routing is None or not rec["routing_complete"]:
+        raise ValueError("a record without complete routing reached a "
+                         "reference that follows it")
+    if len(routing) < need:
+        raise ValueError(f"{len(routing)} routing rows for a sequence "
+                         f"that needs {need}")
+    rows = np.full((T,) + tuple(routing.shape[1:]), ~0, np.int16)
+    rows[:need] = routing[:need]
+    return rows
+
+
 def logit_gaps(stack, records: List[Dict[str, Any]], reference
                ) -> List[float]:
     """For each record the largest (reference maximum - reference logit of
@@ -60,12 +99,14 @@ def logit_gaps(stack, records: List[Dict[str, Any]], reference
     module the cell's configuration names; its dimensions come from the
     configuration file through its own ``dims``, never from the program's
     configuration: a wrong mapping of the file onto the program would
-    otherwise be wrong on both sides and pass."""
+    otherwise be wrong on both sides and pass. A reference that
+    ``follows_routing`` is also handed the record's ``routing_rows``."""
     import jax.numpy as jnp
     import numpy as np
 
     dims = reference.dims(stack.cfg_file)
     params = stack.lanes[0].params
+    follows = follows_routing(reference)
     gaps = []
     for rec in records:
         if rec["resume_len"]:
@@ -78,8 +119,10 @@ def logit_gaps(stack, records: List[Dict[str, Any]], reference
         at = np.zeros((MAX_AT,), np.int32)
         # position len(p) - 1 + i predicts g[i]
         at[:len(g)] = np.arange(len(p) - 1, len(p) - 1 + len(g))
+        followed = ((jnp.asarray(routing_rows(rec, len(seq) - 1, T)),)
+                    if follows else ())
         logits = reference.logits_at(params, dims, jnp.asarray(toks),
-                                     jnp.asarray(at))
+                                     jnp.asarray(at), *followed)
         logits = np.asarray(logits)[:len(g)]
         if not np.isfinite(logits).all():
             raise ValueError("non-finite reference logits")

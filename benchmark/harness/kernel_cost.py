@@ -7,7 +7,23 @@ Shapes: ``hq`` query heads, ``hkv`` KV heads, ``hd`` head size,
 
 from __future__ import annotations
 
-from typing import Iterable, Tuple
+from typing import Any, Dict, Iterable, Tuple
+
+# entries of a published ``layer_types`` whose layer runs an attention kernel
+ATTENTION_LAYERS = ("full_attention", "sliding_attention")
+
+
+def attending_layers(cfg_file: Dict[str, Any]) -> int:
+    """How many of the configuration's layers call an attention kernel:
+    of the ``num_hidden_layers`` that run, the entries of the file's
+    published ``layer_types`` that name attention (a layer of another
+    kind, a convolution or a recurrence, calls none), and every layer
+    where the file has no such key."""
+    n = cfg_file["num_hidden_layers"]
+    kinds = cfg_file.get("layer_types")
+    if kinds is None:
+        return n
+    return sum(kind in ATTENTION_LAYERS for kind in kinds[:n])
 
 
 def ragged_prefill_attention(rows: Iterable[Tuple[int, int]], hq: int,

@@ -95,8 +95,14 @@ class Cell:
     def reference(self):
         """The configuration's plain reference, a module with ``Q_BLOCK``,
         ``dims(cfg_file)`` and ``logits_at(params, dims, tokens, at)``
-        (``benchmark/README.md``). It imports jax, so it is loaded when
-        the check needs it and not with the cell."""
+        (``benchmark/README.md``). The reference of a configuration that
+        routes declares ``FOLLOWS_ROUTING = True``, and its ``logits_at``
+        takes a fifth argument, ``routing=None``: the program's reported
+        choices, ``[T, L_routed, k]`` int16 (``e``, or ``~e`` for a
+        choice the program left out), which ``check.logit_gaps`` hands it
+        and which it computes in place of its own top-k (the README has
+        the whole contract). It imports jax, so it is loaded when the
+        check needs it and not with the cell."""
         return load_module(os.path.join(ROOT, self.config["reference"]),
                            "reference_" + self.config_entry["name"])
 
@@ -105,12 +111,29 @@ def load_cell(spec_path: str, workload: str) -> Cell:
     return Cell(_load_json(spec_path or DEFAULT_SPEC), workload)
 
 
+# a common field of the program's ``ModelConfig`` <- the key a published
+# ``config.json`` most often gives it under
+PUBLISHED = {
+    "name": "name", "vocab_size": "vocab_size", "dim": "hidden_size",
+    "n_layers": "num_hidden_layers", "n_heads": "num_attention_heads",
+    "n_kv_heads": "num_key_value_heads", "ffn_dim": "intermediate_size",
+    "norm_eps": "rms_norm_eps", "rope_theta": "rope_theta",
+    "max_seq_len": "max_position_embeddings",
+    "tie_embeddings": "tie_word_embeddings",
+    "sliding_window": "sliding_window"}
+# what a published file says by leaving the key out
+ABSENT = {"tie_embeddings": False, "sliding_window": None}
+
+
 def model_config(cfg: Dict[str, Any]):
-    """The program's ``ModelConfig`` from a configuration file: the common
-    fields from the keys of the model's published ``config.json``, and
-    over them the file's optional ``program`` group, names of
+    """The program's ``ModelConfig`` from a configuration file: each
+    common field from its ``PUBLISHED`` key where the file has that key,
+    and over them the file's optional ``program`` group, names of
     ``ModelConfig`` fields with their values, for what an architecture
-    has beyond the common ones."""
+    has beyond the common ones and for a common field that its published
+    file names otherwise (``{"norm_eps": 1e-05}`` under a file that has
+    ``norm_eps``). A common field that neither supplies is an error, never
+    the program's default."""
     from swarmdb_tpu.models.configs import ModelConfig
 
     program = cfg.get("program", {})
@@ -119,18 +142,19 @@ def model_config(cfg: Dict[str, Any]):
     if unknown:
         raise SpecError(f"{cfg['name']}: \"program\" names {unknown}, "
                         "which the program's ModelConfig does not have")
-    common = dict(
-        name=cfg["name"], vocab_size=cfg["vocab_size"],
-        dim=cfg["hidden_size"], n_layers=cfg["num_hidden_layers"],
-        n_heads=cfg["num_attention_heads"],
-        n_kv_heads=cfg["num_key_value_heads"],
-        ffn_dim=cfg["intermediate_size"], norm_eps=cfg["rms_norm_eps"],
-        rope_theta=float(cfg["rope_theta"]),
-        max_seq_len=cfg["max_position_embeddings"],
-        tie_embeddings=bool(cfg.get("tie_word_embeddings", False)),
-        sliding_window=cfg.get("sliding_window"))
-    built = ModelConfig(**{**common, **program})
-    if built.head_dim != cfg["head_dim"]:
+    fields = {**ABSENT,
+              **{f: cfg[key] for f, key in PUBLISHED.items() if key in cfg},
+              **program}
+    missing = [f for f in PUBLISHED if f not in fields]
+    if missing:
+        raise SpecError(
+            f"{cfg.get('name')}: nothing gives the program's {missing}: "
+            f"neither {[PUBLISHED[f] for f in missing]} among the file's "
+            "keys nor its \"program\" group")
+    fields["rope_theta"] = float(fields["rope_theta"])
+    fields["tie_embeddings"] = bool(fields["tie_embeddings"])
+    built = ModelConfig(**fields)
+    if "head_dim" in cfg and built.head_dim != cfg["head_dim"]:
         raise SpecError(f"{cfg['name']}: head_dim {cfg['head_dim']} in the "
                         f"file, {built.head_dim} in the program's "
                         "configuration built from it")

@@ -19,8 +19,11 @@ class Recorder:
     """Per engine request, keyed by the message id the service put in
     ``GenRequest.metadata``: the prompt as the engine got it, the time of
     ``submit``, of the first and the last token, their count, and of
-    ``on_done`` with its tokens and reason. All on ``time.time()``, the
-    clock of the program's own stage stamps."""
+    ``on_done`` with its tokens and reason, and the routing the program
+    reports for the request (``GenRequest.routing``: ``[positions,
+    L_routed, k]`` int16 where the configuration routes, else ``None``;
+    the array is the engine's own, not copied). All times on
+    ``time.time()``, the clock of the program's own stage stamps."""
 
     def __init__(self, engines) -> None:
         self.records: Dict[str, Dict[str, Any]] = {}
@@ -41,7 +44,8 @@ class Recorder:
                    "max_new": req.sampling.max_new_tokens,
                    "submit_t": time.time(), "first_t": None, "last_t": None,
                    "n_tokens": 0, "tokens": None, "reason": None,
-                   "done_t": None}
+                   "done_t": None, "routing": None,
+                   "routing_complete": False}
             tok, done = req.on_token, req.on_done
 
             def on_token(rid, token):
@@ -56,6 +60,10 @@ class Recorder:
             def on_done(rid, tokens, reason):
                 rec["done_t"] = time.time()
                 rec["tokens"], rec["reason"] = list(tokens), reason
+                # written by the engine before this call; a dense
+                # request leaves None and False
+                rec["routing"] = req.routing
+                rec["routing_complete"] = req.routing_complete
                 if done is not None:
                     done(rid, tokens, reason)
 

@@ -7,7 +7,8 @@ function in ``ops/attention_pallas.py``).
 The work comes from shapes (``harness/kernel_cost.py``) and from the
 harness's own records: a request decodes one token a step between its
 first and its last token, at a context of its prompt plus what it has
-generated so far, in every layer. The part of each request that falls
+generated so far, in every layer that attends
+(``kernel_cost.attending_layers``). The part of each request that falls
 into the span is taken in proportion to time."""
 
 from benchmark.harness import kernel_cost, peaks
@@ -22,6 +23,7 @@ def read(ctx):
         return None
     t0, t1 = ctx["trace_span"]
     m = ctx["model"]
+    layers = kernel_cost.attending_layers(ctx["config"])
     flops = moved = 0.0
     for rec in ctx["engine_records"].values():
         a, b, n = rec["first_t"], rec["last_t"], rec["n_tokens"]
@@ -34,8 +36,8 @@ def read(ctx):
         context = len(rec["prompt"]) + n * ((lo + hi) / 2 - a) / (b - a)
         f, by = kernel_cost.paged_decode_attention(
             [context], m.n_heads, m.n_kv_heads, m.head_dim)
-        flops += steps * f * m.n_layers
-        moved += steps * by * m.n_layers
+        flops += steps * f * layers
+        moved += steps * by * layers
     if not moved:
         return None
     least, bound = peaks.least_seconds(flops, moved, ctx["device_kind"])

@@ -6,12 +6,13 @@ function in ``ops/attention_pallas.py``).
 
 The work comes from shapes (``harness/kernel_cost.py``) and from the
 harness's own records: a request whose first token came inside the span
-was prefilled in it, with its prompt split into a cached prefix and new
-tokens. The engine does not say how much of a prompt it found cached, so
-the prefix is taken as the whole pages the prompt shares with the same
-sender's previous prompt: the most the cache can have served. Where it
-served less the kernel did more work than is counted here, and the share
-reads low, never high."""
+was prefilled in it, in every layer that attends
+(``kernel_cost.attending_layers``), with its prompt split into a cached
+prefix and new tokens. The engine does not say how much of a prompt it
+found cached, so the prefix is taken as the whole pages the prompt shares
+with the same sender's previous prompt: the most the cache can have
+served. Where it served less the kernel did more work than is counted
+here, and the share reads low, never high."""
 
 from benchmark.harness import kernel_cost, peaks
 
@@ -50,8 +51,8 @@ def read(ctx):
         return None
     flops, moved = kernel_cost.ragged_prefill_attention(
         rows, m.n_heads, m.n_kv_heads, m.head_dim)
-    least, bound = peaks.least_seconds(flops * m.n_layers,
-                                       moved * m.n_layers,
+    layers = kernel_cost.attending_layers(ctx["config"])
+    least, bound = peaks.least_seconds(flops * layers, moved * layers,
                                        ctx["device_kind"])
     ctx["notes"]["prefill_attn_roofline_share"] = {
         "bound": bound, "least_s": least, "kernel_s": k["seconds"],

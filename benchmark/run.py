@@ -222,8 +222,8 @@ def measure(stack, cell, args, seconds, devs, tmp, out_dir, facts) -> int:
     recs = [stack.recorder.get(r["id"]) for r in win if r["id"]]
     sample = check.sample([r for r in recs if r], args.seed, CHECK_SAMPLE)
     t_chk = time.time()
-    gaps = (check.logit_gaps(stack, sample, cell.reference())
-            if sample else [])
+    reference = cell.reference()
+    gaps = check.logit_gaps(stack, sample, reference) if sample else []
     gap_ok = bool(gaps) and max(gaps) <= check.LOGIT_TOL
     correct = bool(gap_ok and not faults and compiles == 0
                    and len(sample) >= min(CHECK_SAMPLE, len(win)))
@@ -238,6 +238,7 @@ def measure(stack, cell, args, seconds, devs, tmp, out_dir, facts) -> int:
         "compiles_in_window": compiles, "reply_faults": faults[:10],
         "logit_gaps": gaps, "logit_tol": check.LOGIT_TOL,
         "reference": cell.config["reference"],
+        "routing_followed": check.follows_routing(reference),
         "checked_lengths": [len(r["prompt"]) + len(r["tokens"])
                             for r in sample],
         "check_s": time.time() - t_chk, "setup_s": setup_s,
@@ -286,7 +287,7 @@ def measure(stack, cell, args, seconds, devs, tmp, out_dir, facts) -> int:
                                     marks["trace_start"]["counters"]),
             "decode_chunk": stack.serving["decode_chunk"],
             "max_batch": stack.max_batch, "device_kind": device["kind"],
-            "model": stack.cfg, "rows": rows,
+            "model": stack.cfg, "config": stack.cfg_file, "rows": rows,
             "page_size": stack.serving["page_size"],
             "engine_records": dict(stack.recorder.records),
             "trace_span": (marks["trace_start"]["t"],
@@ -308,8 +309,15 @@ def measure(stack, cell, args, seconds, devs, tmp, out_dir, facts) -> int:
     result["device"] = device
     if args.platform == "cpu":
         result["rehearsal"] = "cpu: no number here is a device metric"
+    # each number compared beside its limit, last in the line
+    result["compared"] = {
+        "logit_gap_max": [max(gaps, default=None), check.LOGIT_TOL],
+        "reply_faults": [len(faults), 0], "compiles_in_window": [compiles, 0],
+        "records_checked": [len(sample), min(CHECK_SAMPLE, len(win))]}
     log(f"compared: logit gaps {[round(g, 4) for g in gaps]} each <= "
-        f"{check.LOGIT_TOL}; {len(faults)} reply faults, {compiles} "
+        f"{check.LOGIT_TOL}"
+        f"{', routing followed' if facts['routing_followed'] else ''}; "
+        f"{len(faults)} reply faults, {compiles} "
         f"compiles in the window, both == 0; {len(sample)} records >= "
         f"{min(CHECK_SAMPLE, len(win))}; correct {correct}")
     print(json.dumps(facts), flush=True)

@@ -1,5 +1,6 @@
 """Operations and bytes of the attention kernels against hand counts."""
 
+import json
 import sys
 from pathlib import Path
 
@@ -43,26 +44,34 @@ def test_peaks_table_and_unknown_device():
     assert t == pytest.approx(1.0) and bound == "memory"
 
 
+MIXED = ROOT / "tests" / "benchmark" / "fixtures" / "mixed-layers.json"
+
+
+def hand_made_ctx(model, config):
+    """What the two roofline readers read of a traced run. One request:
+    100-token prompt, 11 tokens between t=10 and t=11, all inside the
+    span: 10 steps at a mean context of 100 + 5.5."""
+    records = {"m1": {"prompt": [7] * 100, "first_t": 10.0, "last_t": 11.0,
+                      "n_tokens": 11}}
+    return {"trace": {"kernels": {
+                "paged_decode_gqa_attention_chunked":
+                    {"seconds": 1e-3, "calls": 20},
+                "ragged_paged_prefill_attention":
+                    {"seconds": 1e-3, "calls": 2}}},
+            "trace_span": (9.0, 12.0), "model": model, "config": config,
+            "engine_records": records, "device_kind": "TPU v5 lite",
+            "rows": [{"id": "m1", "due": 9.5, "sender": "u"}],
+            "page_size": 16, "notes": {}}
+
+
 def test_roofline_readers_on_hand_made_records():
     from types import SimpleNamespace
 
     from benchmark.harness import spec
 
-    model = SimpleNamespace(n_heads=32, n_kv_heads=8, head_dim=128,
-                            n_layers=2)
-    # one request: 100-token prompt, 11 tokens between t=10 and t=11, all
-    # inside the span: 10 steps at a mean context of 100 + 5.5
-    records = {"m1": {"prompt": [7] * 100, "first_t": 10.0, "last_t": 11.0,
-                      "n_tokens": 11}}
-    ctx = {"trace": {"kernels": {
-               "paged_decode_gqa_attention_chunked":
-                   {"seconds": 1e-3, "calls": 20},
-               "ragged_paged_prefill_attention":
-                   {"seconds": 1e-3, "calls": 2}}},
-           "trace_span": (9.0, 12.0), "model": model,
-           "engine_records": records, "device_kind": "TPU v5 lite",
-           "rows": [{"id": "m1", "due": 9.5, "sender": "u"}],
-           "page_size": 16, "notes": {}}
+    ctx = hand_made_ctx(
+        SimpleNamespace(n_heads=32, n_kv_heads=8, head_dim=128, n_layers=2),
+        {"num_hidden_layers": 2})
     got = spec.load_reader("decode_attn_roofline_share").read(ctx)
     _, moved = kernel_cost.paged_decode_attention([105.5], 32, 8, 128)
     assert got == pytest.approx(100 * (10 * 2 * moved / 819e9) / 1e-3)
@@ -74,3 +83,35 @@ def test_roofline_readers_on_hand_made_records():
     assert got == pytest.approx(100 * least / 1e-3)
     ctx["trace"] = None
     assert spec.load_reader("decode_attn_roofline_share").read(ctx) is None
+
+
+def test_layers_of_another_kind_call_no_attention_kernel():
+    mixed = json.loads(MIXED.read_text())
+    assert kernel_cost.attending_layers(mixed) == 2
+    # a file cut in depth runs the first entries of its list
+    assert kernel_cost.attending_layers(
+        dict(mixed, num_hidden_layers=3)) == 0
+    assert kernel_cost.attending_layers(
+        dict(mixed, layer_types=["sliding_attention", "full_attention"] * 4)
+    ) == 8
+    del mixed["layer_types"]
+    assert kernel_cost.attending_layers(mixed) == 8
+
+
+@pytest.mark.parametrize("metric", ["decode_attn_roofline_share",
+                                    "prefill_attn_roofline_share"])
+def test_a_roofline_reader_counts_the_layers_that_attend(metric):
+    """Three layers of another kind to one that attends: a quarter of the
+    work of a stack of as many layers that all attend, for the same
+    kernel time, whatever depth the program's configuration has."""
+    from types import SimpleNamespace
+
+    from benchmark.harness import spec
+
+    mixed = json.loads(MIXED.read_text())
+    every = {k: v for k, v in mixed.items() if k != "layer_types"}
+    model = SimpleNamespace(n_heads=4, n_kv_heads=2, head_dim=16, n_layers=8)
+    read = spec.load_reader(metric).read
+    all_attend = read(hand_made_ctx(model, every))
+    assert all_attend > 0
+    assert read(hand_made_ctx(model, mixed)) == pytest.approx(all_attend / 4)
