@@ -21,6 +21,14 @@ Forms:
 - ``gmm``: the same sort with jax's Pallas grouped matmul (megablox), as
   a reading of what a kernel would give; not a candidate for the program
   (``--gmm``).
+- ``stream``: the served path's kernel up to 512 rows (PR 37,
+  ``ops/moe_pallas.stream_experts``): one Pallas call that walks the
+  compacted list of hit experts and streams their tiles through a double
+  buffer; float32 between the matmuls, as ``lfm2._swiglu``. One entry an
+  F tile of ``--tiles`` (``stream_tf896``).
+- ``compact``: the same compacted list walked by a ``fori_loop`` with a
+  dynamic trip count and no ``cond``, in the kernel's precisions: what
+  the conditionals alone cost.
 
 The weights are arguments of every form: as closure constants they are
 baked into each executable (1.6 GB a program, and the first call of this
@@ -43,13 +51,24 @@ def main() -> int:
     ap.add_argument("--reps", type=int, default=16)
     ap.add_argument("--gmm", action="store_true",
                     help="also time jax's Pallas grouped matmul (TPU)")
+    ap.add_argument("--waves", action="store_true",
+                    help="also a step with no live row and the other rungs "
+                         "of a ragged wave: 64, 128, 1024, 2048 and 4096 "
+                         "rows (PR 37)")
+    ap.add_argument("--tiles", default="256,896,1792",
+                    help="F tiles of the form ``stream`` (those that "
+                         "divide F are raced)")
     args = ap.parse_args()
     if args.platform == "cpu":
         os.environ["JAX_PLATFORMS"] = "cpu"
+    sys.path.insert(0, os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
     import jax
     import jax.numpy as jnp
 
-    D, F, E, K = (64, 48, 8, 2) if args.tiny else (2048, 1792, 32, 4)
+    from swarmdb_tpu.ops import moe_pallas
+
+    D, F, E, K = (128, 256, 8, 2) if args.tiny else (2048, 1792, 32, 4)
     dt = jnp.bfloat16
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 8)
@@ -68,20 +87,21 @@ def main() -> int:
         g = g / (jnp.sum(g, axis=-1, keepdims=True) + 1e-6)
         return sel, g * live[:, None]
 
-    def dense(x, live, w_gate, w_up, w_down):
+    def _gate(x, live):
         sel, g = route(x, live)
         gate = jnp.sum(jax.nn.one_hot(sel, E, dtype=jnp.float32)
                        * g[..., None], axis=1)                    # [N, E]
+        return gate, jnp.any(gate > 0, axis=0)
+
+    def dense(x, live, w_gate, w_up, w_down):
+        gate, _hit = _gate(x, live)
         h = jax.nn.silu(jnp.einsum("nd,edf->enf", x, w_gate)) * jnp.einsum(
             "nd,edf->enf", x, w_up)
         y = jnp.einsum("enf,efd->end", h, w_down)
         return jnp.einsum("end,ne->nd", y, gate.astype(x.dtype))
 
     def loop(x, live, w_gate, w_up, w_down):
-        sel, g = route(x, live)
-        gate = jnp.sum(jax.nn.one_hot(sel, E, dtype=jnp.float32)
-                       * g[..., None], axis=1)                    # [N, E]
-        hit = jnp.any(gate > 0, axis=0)
+        gate, hit = _gate(x, live)
 
         def body(e, acc):
             def run(acc):
@@ -123,9 +143,37 @@ def main() -> int:
         ys = ys * gs[:, None].astype(x.dtype)
         return jnp.zeros_like(x).at[tok].add(ys.astype(x.dtype))
 
+    def stream(tile_f):
+        def form(x, live, w_gate, w_up, w_down):
+            gate, hit = _gate(x, live)
+            return moe_pallas.stream_experts(
+                x, gate, hit, w_gate, w_up, w_down, tile_f=tile_f,
+                interpret=args.platform == "cpu")
+        return form
+
+    def compact(x, live, w_gate, w_up, w_down):
+        gate, hit = _gate(x, live)
+        n_hit, ids = moe_pallas.hit_list(hit)
+        f32 = jnp.float32
+
+        def body(i, acc):
+            e = ids[i]
+            h = jax.nn.silu(jnp.dot(x, w_gate[e], preferred_element_type=f32)
+                            ) * jnp.dot(x, w_up[e], preferred_element_type=f32)
+            ge = jax.lax.dynamic_slice_in_dim(gate, e, 1, axis=1)
+            return acc + jnp.dot(h.astype(x.dtype), w_down[e],
+                                 preferred_element_type=f32) * ge
+
+        return jax.lax.fori_loop(0, n_hit[0], body,
+                                 jnp.zeros(x.shape, f32)).astype(x.dtype)
+
     forms = {"dense": dense, "loop": loop, "ragged": ragged}
     if args.gmm:
         forms["gmm"] = gmm_form
+    forms["compact"] = compact
+    for tf in (int(t) for t in args.tiles.split(",")):
+        if F % tf == 0:
+            forms[f"stream_tf{tf}"] = stream(tf)
 
     def timed(fn, x, live):
         @jax.jit
@@ -142,6 +190,12 @@ def main() -> int:
               [("prefill", 512, 512), ("prefill", 256, 256),
                ("prefill", 512, 300), ("decode", 32, 32), ("decode", 32, 8),
                ("decode", 32, 4), ("decode", 32, 1)])
+    if args.waves and not args.tiny:
+        # no live row: what a layer costs beside its experts' bytes
+        shapes += [("decode", 32, 0),
+                   ("prefill", 64, 64), ("prefill", 128, 128),
+                   ("prefill", 1024, 1024), ("prefill", 2048, 2048),
+                   ("prefill", 4096, 4096), ("prefill", 4096, 2500)]
     print(json.dumps({"device": jax.devices()[0].device_kind, "D": D, "F": F,
                       "E": E, "k": K, "reps": args.reps}), flush=True)
     once = {name: jax.jit(fn) for name, fn in forms.items()}
@@ -157,7 +211,8 @@ def main() -> int:
         for name, fn in forms.items():
             try:
                 got = once[name](x, live, *weights).astype(jnp.float32)
-                err = float(jnp.max(jnp.abs(got - want)[:n_live]))
+                err = float(jnp.max(jnp.abs(got - want)[:n_live],
+                                    initial=0.0))
                 row[name] = {"ms": round(timed(fn, x, live), 4),
                              "max_abs_diff": round(err, 5)}
             except Exception as exc:  # a form the compiler refuses

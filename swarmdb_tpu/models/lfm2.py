@@ -25,10 +25,13 @@ family brings what a configuration with ``layer_types`` has of its own:
   scores + ``expert_bias`` (the bias chooses and never gates), gates the
   chosen scores renormalised (``/ (sum + 1e-6)``), and every chosen expert
   computed for every token: no capacity, no dropped token, so a token's
-  result does not depend on what shares its call. The dispatch is a loop
-  over experts that skips, by ``lax.cond``, an expert no live row chose:
-  a decode step reads the weights of the experts its live rows chose and
-  no others (``scripts/race_moe_dispatch.py`` has the forms it was raced
+  result does not depend on what shares its call. Either way a call reads
+  the weights of the experts its live rows chose and no others: a decode
+  step's rows and a wave's up to 512 through one Pallas kernel that
+  streams the hit experts back to back (``ops/moe_pallas``: bf16,
+  lane-multiple widths, a TPU), every other call through a loop over
+  experts that skips, by ``lax.cond``, an expert no live row chose
+  (``scripts/race_moe_dispatch.py`` has the forms they were raced
   against, ``PERF.md`` section 6 the readings). ``live`` marks the rows that
   take part; a padded token of a wave or an empty lane of a step has its
   gates zeroed and reads no expert.
@@ -45,6 +48,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import moe_pallas
 from . import llama
 from .configs import ModelConfig
 from .mixtral import encode_routing
@@ -347,11 +351,19 @@ def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
 
     # an expert in float32 between its matmuls (``_swiglu``), gated and
     # summed in float32 and rounded once: a bf16 sum over the chosen
-    # experts, a routed layer after the other, shows in the logits
-    y = jax.lax.fori_loop(0, E, expert, jnp.zeros(xf.shape, jnp.float32))
+    # experts, a routed layer after the other, shows in the logits.
+    # One algorithm, realized by the rows of the call: while the hit
+    # experts' bytes are the cost (a decode step, a wave up to 512 rows) one
+    # kernel streams them back to back; a wider wave keeps the loop
+    if moe_pallas.takes(B * T, xf.dtype, w_gate):
+        y = moe_pallas.stream_experts(
+            xf, gate, hit, w_gate, w_up, w_down, base,
+            interpret=jax.default_backend() != "tpu")
+    else:
+        y = jax.lax.fori_loop(0, E, expert, jnp.zeros(xf.shape, jnp.float32)
+                              ).astype(x.dtype)
     routing = encode_routing(chosen, jnp.ones(chosen.shape, bool))
-    return (y.astype(x.dtype).reshape(B, T, D),
-            routing.reshape(B, T, top_k))
+    return y.reshape(B, T, D), routing.reshape(B, T, top_k)
 
 
 # ------------------------------------------------------------------ the stack
@@ -392,8 +404,8 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin,
         # a routed layer's expert matrices stay out of the scan: what a
         # scan slices it copies, a layer's every expert a step (0.7 ms a
         # matrix on the chip, 25 ms a step at the published cut: PERF.md
-        # section 6, PR 36). The loop over experts indexes the flat
-        # [n * E, ...] stack itself, at repeat * E + expert
+        # section 6, PR 36). The kernel and the loop over experts index the
+        # flat [n * E, ...] stack itself, at repeat * E + expert
         routed = [ffn == "moe" for _m, ffn in pattern]
         experts = [{k: lp[k].reshape((-1,) + lp[k].shape[2:])
                     for k in EXPERT_MATRICES} if moe else {}
