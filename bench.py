@@ -431,7 +431,7 @@ def _run_window(db, seconds: float, pump, drain_grace: float = 2.0,
             jax.profiler.stop_trace()
 
 
-_PHASES = ("queue_wait", "prefill", "decode", "host_sync", "reply_emit")
+_PHASES = ("queue_wait", "prefill", "decode", "host_sync")
 
 
 def _measure_window(db, seconds, pump, drain_grace, completed, tokens,
@@ -2646,8 +2646,9 @@ def _mode_summary(r: dict) -> dict:
     for short, long in _SUMMARY_KEYS:
         if r.get(long) is not None:
             out[short] = r[long]
-    # compact phase shares (q=queue_wait p=prefill d=decode h=host_sync
-    # r=reply_emit, 2dp): scripts/bench_trend.py attributes a
+    # compact phase shares (q=queue_wait p=prefill d=decode h=host_sync,
+    # 2dp; records before PR 39 also hold r=reply_emit):
+    # scripts/bench_trend.py attributes a
     # mode-vs-mode regression from these with the analyzer's
     # contributor model, so the checked-in driver records carry enough
     # signal to NAME a regression's dominant phase

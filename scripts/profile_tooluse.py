@@ -10,7 +10,7 @@ Three measurements, mirroring the PROFILE r4 serve decomposition:
    (tiny-debug) forward as the non-MoE reference.
 2. Served-workload phase breakdown: the bench_tooluse traffic shape
    through a real ServingService, reporting the phase_us_* family
-   (queue_wait / prefill / decode / host_sync / reply_emit), prompt
+   (queue_wait / prefill / decode / host_sync), prompt
    padding share (flight counter), and prefix hit rate with the
    sink-anchored window on and off (SWARMDB_ANCHOR_HEAD).
 3. Prompt-render cost: build_prompt volume rendered vs retained at the
@@ -100,7 +100,7 @@ def section_served(anchor_head: str) -> dict:
     from swarmdb_tpu.core.runtime import SwarmDB
 
     n_users, max_batch, new_tokens = 16, 16, 16
-    phases = ("queue_wait", "prefill", "decode", "host_sync", "reply_emit")
+    phases = ("queue_wait", "prefill", "decode", "host_sync")
     with tempfile.TemporaryDirectory() as tmp:
         db = SwarmDB(broker=LocalBroker(), save_dir=tmp,
                      autosave_interval=1e9, max_messages_per_file=10**9)

@@ -34,7 +34,7 @@ gaps a line holds the window's ``out_tokens_per_s`` and ``tpot_p90_ms`` and
 the program's routing counters, so the same run says what the emission
 costs and how much the served path drops. On a program that reports no
 routing (a parent commit) the forced reading is left out and the rest
-stands. An engine cannot be freed in its process (ROADMAP Design 11): at a
+stands. An engine cannot be freed in its process (ROADMAP Design 12): at a
 size where two stacks do not fit the device together, give each seed a
 process of its own (one ``--seeds`` value a call).
 """

@@ -709,6 +709,9 @@ class Engine:
         self._cv = make_condition("backend.engine.Engine._cv")
         self._stop = False
         self._thread: Optional[threading.Thread] = None
+        # the kernel's id of the loop thread, by which obs/procwatch.py
+        # reads its scheduler account (/proc/self/task/<id>/schedstat)
+        self._native_id: Optional[int] = None
         # low-memory hook (ADVICE r4 medium #1): invoked (need_pages) from
         # the engine thread, OUTSIDE the engine lock, when paged admission
         # cannot allocate and nothing was admitted this round. The serving
@@ -1431,9 +1434,13 @@ class Engine:
         if self._thread is not None:
             return
         self._compiled_seen = self._compiled_count()
-        self._thread = threading.Thread(target=self._run, daemon=True,
-                                        name="swarmdb-engine")
+        self._thread = threading.Thread(target=self._loop_thread,
+                                        daemon=True, name="swarmdb-engine")
         self._thread.start()
+
+    def _loop_thread(self) -> None:
+        self._native_id = threading.get_native_id()
+        self._run()
 
     def stop(self) -> None:
         with self._cv:
@@ -2614,7 +2621,6 @@ class Engine:
                 (-request.priority, request.submitted_at,
                  next(self._tiebreak), request),
             )
-            self.metrics.counters["engine_requests"].inc()
             self._cv.notify_all()
         return request.request_id
 
@@ -5031,7 +5037,6 @@ class Engine:
             except Exception:
                 logger.exception("dense keep extraction failed")
         self.metrics.counters["engine_completed"].inc()
-        self.metrics.rates["requests_completed"].mark()
         if req is not None:
             # flight-recorder request timeline (ring write, engine thread)
             self.flight.record_request({

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 
 from ..core.messages import Message, MessagePriority, MessageType
 from ..core.runtime import SwarmDB
-from ..obs import TRACER
+from ..obs import TRACER, procwatch
 from ..utils.hashing import stable_partition
 from .engine import Engine, GenRequest, PagedKV
 from .sampling import SamplingParams
@@ -357,6 +357,7 @@ class ServingService:
                 self.supervisor = LaneSupervisor(
                     engine, metrics=db.metrics).start()
         self._consumer_thread: Optional[threading.Thread] = None
+        self._procwatch = None
         self._stop = threading.Event()
         # Reply emission (tokenizer decode + send_message + persistence
         # hooks) runs on its own worker, NOT the engine thread: at 32-128
@@ -540,6 +541,11 @@ class ServingService:
             except Exception:
                 logger.exception("swarmprof startup harvest failed")
         self.engine.start()
+        if self._procwatch is None:
+            # one a process, counted (obs/procwatch.py); None where
+            # SWARMDB_TRACE=0 turned every span off
+            self._procwatch = procwatch.acquire(
+                self.db.metrics, getattr(self.engine, "lanes", [self.engine]))
         if self._reply_thread is None:
             self._reply_thread = threading.Thread(
                 target=self._reply_loop, daemon=True,
@@ -566,6 +572,9 @@ class ServingService:
             # stop supervision BEFORE the engine: a lane going dead
             # during shutdown must not trigger a restart/migration race
             self.supervisor.stop()
+        if self._procwatch is not None:
+            procwatch.release(getattr(self.engine, "lanes", [self.engine]))
+            self._procwatch = None
         self.engine.stop()
         if self._reply_thread is not None:
             self._reply_queue.put(None)  # sentinel AFTER engine drained
@@ -1382,7 +1391,6 @@ class ServingService:
         the failover re-seats the partition within the detector budget,
         so the generated reply lands on the new leader instead of being
         stranded as a FAILED message awaiting an admin resend."""
-        emit_us = self.db.metrics.counters["phase_us_reply_emit"]
         retries = _env_int("SWARMDB_REPLY_RETRIES", 3)
         backoff = _env_float("SWARMDB_RETRY_BACKOFF_S", 0.05)
         while True:
@@ -1390,7 +1398,6 @@ class ServingService:
             if item is None:
                 return
             msg, rid, tokens, reason, stop, lps, alts, on_done = item
-            t0 = time.perf_counter()
             for attempt in range(retries + 1):
                 try:
                     self._emit_reply(msg, tokens, reason, stop, lps, alts)
@@ -1405,11 +1412,6 @@ class ServingService:
                         continue
                     logger.exception("failed to emit reply for %s", msg.id)
                     break
-            # reply-emit phase accumulator (same family as the engine's
-            # phase_us_*): decode + send_message + persistence hooks per
-            # completion — the tooluse decomposition needs this visible
-            # next to prefill/decode, not folded into wall-clock
-            emit_us.inc(int((time.perf_counter() - t0) * 1e6))
             if on_done is not None:
                 try:
                     on_done(rid, tokens, reason)
@@ -1483,10 +1485,6 @@ class ServingService:
         # north-star gauge: completed chat messages/sec
         self.db.metrics.rates["completed_messages"].mark()
         self.db.metrics.counters["completed_messages"].inc()
-        stages = msg.metadata.get("stages", {})
-        if "enqueued" in stages:
-            self.db.metrics.latencies["send_to_done_s"].observe(
-                time.time() - stages["enqueued"])
 
     async def stream_reply(self, msg: Message) -> AsyncIterator[str]:
         """Async token-text stream for SSE (api/app.py). Bridges engine-

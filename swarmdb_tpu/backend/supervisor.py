@@ -61,7 +61,7 @@ import threading
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
-from ..obs import TRACER, FlightRecorder
+from ..obs import TRACER, FlightRecorder, procwatch
 from ..utils.metrics import MetricsRegistry
 from .engine import Engine, GenRequest, is_retryable_reason
 from ..utils.sync import make_lock
@@ -488,15 +488,20 @@ class LaneSupervisor:
                     new: LaneState) -> None:
         old, h.state = h.state, new
         h.since = time.monotonic()
-        logger.warning("lane %d: %s -> %s (beat age %.3fs, thread %s)",
-                       idx, old.name, new.name, eng.beat_age_s(),
+        age = eng.beat_age_s()
+        # how much of that age the whole process stood still, by the
+        # watcher's record (obs/procwatch.py): the lane is then not to blame
+        late = round(procwatch.process_late_s(age), 4)
+        logger.warning("lane %d: %s -> %s (beat age %.3fs, of which the "
+                       "process stood still %.3fs, thread %s)",
+                       idx, old.name, new.name, age, late,
                        "alive" if eng.alive() else "dead")
         self.flight.record_event(
             {"kind": f"lane.{new.name.lower()}", "lane": idx,
-             "beat_age_s": round(eng.beat_age_s(), 4),
+             "beat_age_s": round(age, 4), "process_late_s": late,
              "thread_alive": eng.alive()})
         TRACER.instant(f"lane.{new.name.lower()}", cat="supervisor",
-                       args={"lane": idx})
+                       args={"lane": idx, "process_late_s": late})
         if new == LaneState.QUARANTINED:
             h.quarantines += 1
             h.clean_probes = 0

@@ -31,9 +31,13 @@ can import the package without the ML stack.
   the per-conversation hot/warm/cold temperature ledger, SHARDS-sampled
   miss-ratio curves over prefix-cache accesses, and the warm-tier /
   cold-resume what-if models ROADMAP item 3 is sized against.
+- :mod:`.procwatch` — the process's watcher thread: late wakes, the
+  kernel's scheduler accounts and every thread's frames at the wake become
+  ``process.sample`` / ``process.stall`` / ``process.engine_late`` spans
+  and ``process_*`` counters, so a process-wide stall names its cause.
 """
 
-from . import propagate
+from . import procwatch, propagate
 from .flight import FlightRecorder
 from .memprof import MemProfiler, memprof, memprof_enabled
 from .metrics import HISTOGRAMS, Histogram, HistogramRegistry
@@ -41,7 +45,8 @@ from .profiler import KernelProfiler, profile_enabled, profiler
 from .sentinel import SLOConfig, SLOSentinel
 from .tracer import TRACER, SpanTracer
 
-__all__ = ["FlightRecorder", "SpanTracer", "TRACER", "propagate",
+__all__ = ["FlightRecorder", "SpanTracer", "TRACER", "procwatch",
+           "propagate",
            "HISTOGRAMS", "Histogram", "HistogramRegistry",
            "SLOConfig", "SLOSentinel",
            "KernelProfiler", "profile_enabled", "profiler",

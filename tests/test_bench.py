@@ -265,9 +265,9 @@ def test_serve_mode_end_to_end_cpu(monkeypatch):
 
 def test_tooluse_mode_record_contract(monkeypatch):
     """The tooluse bench line's record contract (ISSUE r6 satellite): the
-    phase family (incl. the r6 reply_emit phase) explains where the time
-    went, the prefix hit/miss token counts are present, and every reply
-    to a function_call is a function_result."""
+    phase family explains where the time went, the prefix hit/miss token
+    counts are present, and every reply to a function_call is a
+    function_result."""
     monkeypatch.setenv("SWARMDB_BENCH_MODEL", "tiny-moe")
     monkeypatch.setenv("SWARMDB_BENCH_BATCH", "8")
     monkeypatch.setenv("SWARMDB_BENCH_SEQ", "128")
@@ -281,11 +281,8 @@ def test_tooluse_mode_record_contract(monkeypatch):
         result = bench.bench_tooluse(seconds=3.0)
     assert result["metric"] == "tooluse_completed_messages_per_sec"
     assert result["value"] > 0
-    # per-phase breakdown present and complete (the r6 family adds
-    # reply_emit so service-side emission is visible next to the
-    # engine-side phases)
+    # per-phase breakdown present and complete
     assert set(result["phase_seconds"]) == set(bench._PHASES)
-    assert "reply_emit" in result["phase_seconds"]
     assert abs(sum(result["phase_shares"].values()) - 1.0) < 0.01
     # prefix-cache evidence rides the record
     pc = result["prefix_cache"]
