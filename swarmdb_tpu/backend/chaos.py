@@ -24,7 +24,9 @@ Faults are applied at exactly two seams, both owned by the engine:
   swallow it and the thread dies for real — the crash the supervisor
   exists for). Wedge blocks here; slow sleeps here. The resident-session
   continue vote polls ``pending()`` so an armed fault lands at the seam
-  within one chunk even mid-session.
+  within one chunk even mid-session (one more when it is armed from a
+  request's own ``on_token``: the vote on the block being emitted has
+  been taken).
 - ``PageAllocator.reserve`` — pool squeeze withdraws free pages from
   circulation, indistinguishable from a burst of long-lived occupants.
 

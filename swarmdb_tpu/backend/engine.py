@@ -47,12 +47,14 @@ import heapq
 import itertools
 import logging
 import os
+import queue
 import threading
 import time
 import uuid
 import zlib
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, NamedTuple, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +149,95 @@ RESIDENT_PROGRAM_NAMES = ("decode_resident_full", "decode_resident_fast",
                           "decode_resident_greedy")
 # (use_filters, assume_greedy) per variant, in the order of the names
 _DECODE_VARIANT_FLAGS = ((True, False), (False, False), (False, True))
+
+
+# how long either half of a resident session waits for the other before
+# it looks for itself. The engine thread, on the FIFO, then asks whether
+# the device program is still running: the normal end of a session is the
+# block the callback marked last, so this only bounds how late a failed
+# program (or a wrong mirror of its ``cond``) is noticed. The callback, on
+# the session's credit, then votes without it: a consumer that is gone
+# cannot hang the device program.
+_RESIDENT_POLL_S = 0.25
+
+
+def _pack_resident_block(all_toks, all_lps, n, done, routing=None):
+    """What a resident chunk sends to the host, as ONE int32 buffer: on
+    the chip every operand of a callback is a transfer of its own, and an
+    operand's trip is a latency, not its bytes (some tens of KB here).
+    Layout, flat: tokens ``[K+1, B]``, logprobs ``[K+1, B]`` (their
+    float32 bits), the chunk's index, the loop's ``done`` row ``[B]``
+    and, where the configuration routes, the chunk's routing ``[K, B,
+    L_routed, k]`` int16, two to a word (padded by one where the count is
+    odd). ``_unpack_resident_block`` is its inverse on the host."""
+    parts = [all_toks.reshape(-1),
+             jax.lax.bitcast_convert_type(all_lps, jnp.int32).reshape(-1),
+             n[None], done.astype(jnp.int32)]
+    if routing is not None:
+        flat = routing.reshape(-1)
+        flat = jnp.pad(flat, (0, flat.size % 2))
+        parts.append(jax.lax.bitcast_convert_type(
+            flat.reshape(-1, 2), jnp.int32))
+    return jnp.concatenate(parts)
+
+
+def _unpack_resident_block(buf: np.ndarray, k1: int, b: int,
+                           routed: Optional[Tuple[int, int, int]]):
+    """Views of one packed block (``_pack_resident_block``), no copy:
+    tokens, logprobs, the chunk's index, ``done`` and the routing (None
+    for a dense configuration, whose buffer has no such part)."""
+    o = k1 * b
+    block = buf[:o].reshape(k1, b)
+    lps = buf[o:2 * o].view(np.float32).reshape(k1, b)
+    n = int(buf[2 * o])
+    done = buf[2 * o + 1:2 * o + 1 + b] != 0
+    routing = None
+    if routed is not None:
+        l_routed, k, _e = routed
+        rows = (k1 - 1) * b * l_routed * k
+        routing = buf[2 * o + 1 + b:].view(np.int16)[:rows].reshape(
+            k1 - 1, b, l_routed, k)
+    return block, lps, n, done, routing
+
+
+class _ResidentBlock(NamedTuple):
+    """One chunk on its way from the callback to the engine thread."""
+    block: np.ndarray            # [K+1, B] tokens
+    lps: np.ndarray              # [K+1, B] logprobs
+    routing: Optional[np.ndarray]  # [K, B, L_routed, k] or None
+    n: int                       # the chunk's index in its session
+    stamp_ns: int                # monotonic, when the callback was entered
+    vote: bool                   # what the callback answered
+    queued: int                  # the queue's length the vote saw
+    last: bool                   # the device loop ends after this chunk
+
+
+class _ResidentSession:
+    """What one resident session's votes are taken from: the snapshot and,
+    per lane ``[B]``, what the host knew when it was built."""
+    __slots__ = ("snap", "pos0", "left", "first", "alive", "max_chunks",
+                 "prev_ns", "failed", "credit", "consuming")
+
+    def __init__(self, snap, pos0, left, first, alive, max_chunks):
+        self.snap = snap            # [(slot, request, start position)]
+        self.pos0 = pos0            # start positions
+        self.left = left            # tokens each request may still emit
+        self.first = first          # 1 where the prefill sample is pending
+        self.alive = alive          # lanes the votes so far expect live
+        self.max_chunks = int(max_chunks)
+        self.prev_ns = time.monotonic_ns()  # the chunk boundary before
+        self.failed = False         # a block's processing raised
+        # one block in flight between the halves: the callback takes the
+        # credit before it votes, the engine thread gives it back when a
+        # block is processed, so the device runs ONE chunk ahead of the
+        # emission and no further (where a chunk outlasts its block's
+        # processing, as on the chip, nobody ever waits here)
+        self.credit = threading.Semaphore(1)
+        # the engine thread is back from the dispatch and takes blocks
+        # off the FIFO; until then (for good, on a backend that runs a
+        # program with a host callback on the calling thread, as the
+        # CPU's does) the callback has nobody to hand a block to
+        self.consuming = False
 
 
 def _named_partial(fn: Callable, name: str, **kwargs) -> Callable:
@@ -268,8 +359,8 @@ class WaveRouting:
         return rows
 
     def land(self) -> None:
-        # retirements land it: on the emission callback's thread, or on
-        # the engine thread (a cancellation, the scan path)
+        # retirements land it, on the engine thread (the resident
+        # session's consumer, a cancellation, the scan path)
         with self._lock:
             if self._dev is None:
                 return
@@ -833,23 +924,30 @@ class Engine:
 
         # ---- device-resident decode sessions (emission ring) -------------
         # One jitted ``lax.while_loop`` runs MANY decode chunks per host
-        # visit: each chunk's [K+1, B] token block is pushed host-ward
-        # through an ORDERED ``io_callback`` (the emission ring — the
-        # device runs one chunk ahead of host-side emission, so the
-        # stream is double-buffered by construction), and the host only
+        # visit. At each chunk's end the body sends ONE int32 buffer
+        # host-ward through an ORDERED ``io_callback`` (the chunk's
+        # [K+1, B] tokens and logprobs, its index, the loop's ``done`` row
+        # and a routed configuration's routing: ``_pack_resident_block``)
+        # and ``cond`` reads the callback's boolean before the next chunk
+        # may start: the device WAITS at every chunk boundary, for that
+        # buffer's trip to the host, a vote and the answer's trip back.
+        # It does not wait for the host's work: ``_resident_emit`` votes
+        # from what the buffer and the session's snapshot show (new
+        # admissible work, every lane done, stop) and hands the block to
+        # the engine thread over a FIFO, and that thread emits, retires
+        # and writes the chunk's spans while the device runs the next
+        # chunk, one chunk behind it (``_resident_consume``). The host
         # touches the device ONCE per session: the drain read of the
-        # chunk counter after the loop exits. The callback's boolean
-        # return is the host's continue vote (new admissible work, a
-        # cancel, stop), consumed at the next chunk boundary — stop and
-        # stream emission are serviced from the ring without ever
-        # blocking the device loop. Single-shard PAGED engines only: the
-        # shard_map'd multi-device program and the pod control plane
-        # keep the per-chunk scan+pipeline path (SWARMDB_EMIT_RING=0
-        # forces that path everywhere).
+        # chunk counter after the last block. Single-shard PAGED engines
+        # only: the shard_map'd multi-device program and the pod control
+        # plane keep the per-chunk scan+pipeline path
+        # (SWARMDB_EMIT_RING=0 forces that path everywhere).
         self._resident_variants: Optional[Tuple[Any, ...]] = None
-        self._resident_snap: Optional[List[Tuple[int, GenRequest, int]]] \
-            = None
-        self._resident_prev_ns = 0
+        self._resident: Optional[_ResidentSession] = None
+        # callback thread -> engine thread, one entry a chunk, in order
+        # (None: the callback itself failed)
+        self._resident_fifo: "queue.SimpleQueue[Optional[_ResidentBlock]]" \
+            = queue.SimpleQueue()
         self._lane_busy = False
         self._host_sync_n = 0  # engine-LOCAL sync count (registry
         # counters are shared across lanes, so per-request deltas must
@@ -888,7 +986,9 @@ class Engine:
                     cont = io_callback(
                         self._resident_emit,
                         jax.ShapeDtypeStruct((), jnp.bool_),
-                        all_toks, all_lps, n, *routing, ordered=True)
+                        _pack_resident_block(all_toks, all_lps, n, done,
+                                             *routing),
+                        ordered=True)
                     return (n + 1, done, cont, lt, llp, pos, cache)
 
                 init = (jnp.int32(0), ~live, jnp.bool_(True), last_tokens,
@@ -897,6 +997,9 @@ class Engine:
                     cond, body, init)
                 return n, lt, llp, cache
 
+            # registered at 0, so a reader tells "no vote went stale"
+            # from a program that does not count them
+            self.metrics.counters["resident_votes_stale"].inc(0)
             self._resident_variants = tuple(
                 jax.jit(_named_partial(_decode_resident, name,
                                        use_filters=uf, assume_greedy=ag),
@@ -1464,7 +1567,7 @@ class Engine:
 
     # swarmlint: heartbeat
     def _beat(self) -> None:
-        """Per-step liveness proof (engine loop / emission callback):
+        """Per-step liveness proof (engine loop / a processed block):
         one monotonic read into a single-writer float slot — the
         supervisor's verdict path reads it lock-free."""
         self._beat_mono = time.monotonic()
@@ -4484,84 +4587,196 @@ class Engine:
         return self._resident_variants is not None and self._mh is None
 
     # swarmlint: hot
-    def _resident_emit(self, block, lps, n, routing=None) -> np.bool_:
-        """Ordered io_callback target: one call per device chunk (with a
-        fourth operand, the chunk's routing, only where the configuration
-        routes), on the
-        runtime's callback thread. The engine thread is parked in the
-        session drain for the whole session, and ordered callbacks are
-        serialized, so this thread IS the engine thread's stand-in:
-        token emission, retirement, and stream callbacks all run here,
-        without the device loop ever waiting on a host round-trip (the
-        return value is consumed one chunk later — double-buffered).
-        Never raises: an exception here would poison the device program
-        mid-flight, so failures vote to stop the loop instead."""
+    def _resident_emit(self, packed) -> np.bool_:
+        """Ordered io_callback target: one call per device chunk, on the
+        runtime's callback thread, with the chunk's one packed operand
+        (``_pack_resident_block``). The device loop stands still until
+        this returns, so it does only what the answer needs: stamp the
+        time, copy the operand (the one copy: the buffer is the runtime's,
+        the block is the engine's to keep) and take views of it, vote
+        (``_resident_vote``), put the block on the FIFO for the engine
+        thread and return the vote. No token is emitted, no slot retired
+        and no span written here: ``_resident_consume`` does all of that
+        on the engine thread while the device runs the next chunk. (Where
+        the dispatch does not return before the program ends, the engine
+        thread is not there to take the block and it is processed here,
+        after the vote: the CPU backend runs a program that has a host
+        callback on the calling thread.)
+        ``last`` mirrors the device loop's ``cond`` (the chunk bound, the
+        vote, the loop's ``done`` row), so the consumer knows which block
+        ends the session without asking the device. Before it votes it
+        takes the session's credit, which the engine thread returns with
+        each processed block: the emission is one chunk behind the device
+        and no more, so what the vote reads of the slots, of a cancel and
+        of an armed chaos fault is at most one block old (a host slower
+        than the device holds the device back, as it always did). Never
+        raises: an exception here would poison the device program
+        mid-flight, so a failure votes to stop the loop and says so to
+        the consumer."""
+        stamp_ns = time.monotonic_ns()
         try:
-            snap = self._resident_snap
-            if snap is None:
+            ses = self._resident
+            if ses is None:
                 return np.bool_(False)
-            n = int(n)
-            K = self.decode_chunk
-            snapshot = [(i, req, pos0 + n * K) for i, req, pos0 in snap]
-            now_ns = time.monotonic_ns()
-            prev_ns = self._resident_prev_ns
-            if prev_ns:
-                # resident-path device time: the emission-ring chunk
-                # boundary deltas ARE the chunk wall times — no sync,
-                # no block_until_ready, the issue's design point
-                self._prof.dispatch(self._prof_resident_key, prev_ns,
-                                    now_ns - prev_ns)
-            self._process_host_block(
-                np.asarray(block), np.asarray(lps), snapshot,
-                self._resident_prev_ns, n,
-                None if routing is None else np.asarray(routing))
-            self._resident_prev_ns = now_ns
-            return np.bool_(self._resident_should_continue())
+            ses.credit.acquire(timeout=_RESIDENT_POLL_S)
+            block, lps, n, done, routing = _unpack_resident_block(
+                np.array(packed), self.decode_chunk + 1, self.max_batch,
+                self._routed)
+            vote, queued = self._resident_vote(ses, block, n)
+            last = not (vote and n + 1 < ses.max_chunks
+                        and not done.all())
+            blk = _ResidentBlock(block, lps, routing, n, stamp_ns, vote,
+                                 queued, last)
+            if ses.consuming:
+                self._resident_fifo.put(blk)
+            else:
+                self._resident_block(ses, blk)
+            return np.bool_(vote)
         except Exception:
-            logger.exception("emission-ring block processing failed; "
+            logger.exception("emission-ring callback failed; "
                              "stopping the resident session")
+            self._resident_fifo.put(None)
             return np.bool_(False)
 
     # swarmlint: hot
-    def _resident_should_continue(self) -> bool:
-        """The host's continue vote, evaluated once per emitted chunk.
-        Stop when: the engine is stopping, nothing is active anymore
-        (every lane retired host-side — the device's own done mask can
-        lag a cancel), or queued work could be admitted into a freed
-        slot (exit -> admit -> new session). Reads of _stop/slots are
-        deliberately lock-light: a stale verdict is corrected at the
-        next chunk boundary."""
-        # racy-by-design: a stale verdict costs ONE extra chunk, while
-        # taking _cv here would put lock acquisition on every emitted
-        # chunk of every lane
+    def _resident_vote(self, ses: _ResidentSession, block: np.ndarray,
+                       n: int) -> Tuple[bool, int]:
+        """The host's continue vote on chunk ``n``, taken BEFORE the
+        engine thread processes the block, so it reckons what that
+        processing will find, vectorised over the lanes ``[B]``. Returns
+        the vote and the queue's length it saw (0 where it never looked).
+
+        Stop when the engine is stopping, a chaos fault is armed, no lane
+        will be active once this block is processed, or queued work could
+        be admitted into a slot that is free now or freed by this block
+        (exit -> admit -> new session). A lane the votes so far expect
+        live retires in this block by EOS (an ``eos_id`` anywhere in its
+        column: row 0 is a pending prefill sample, or a fed token that an
+        earlier block already showed not to be one), by length (the tokens
+        its request had left when the snapshot was built, against the
+        ``(n + 1) * K`` steps through this block and the pending first
+        token) or at ``max_seq`` (the block's last step would write at or
+        past it): exactly ``_process_host_block``'s three retirements.
+        The loop's own ``done`` row is not used for this: its ``stop_pos``
+        carries a ``+ 1`` for the pending first token whether one is
+        pending or not, so by length it is up to a chunk generous; it
+        serves the mirror of ``cond`` in ``_resident_emit``, where it is
+        the device's word. What processing has already found is read from
+        the slots (a lane retired by an earlier block, a cancel flagged by
+        now); only a cancel that another thread flags between this vote
+        and the block's processing is seen a chunk late, and counted
+        (``resident_votes_stale``). Reads of _stop and the slots are
+        lock-light by design: a stale verdict costs ONE extra chunk, while
+        taking _cv for them would put the lock on every chunk's path."""
         if self._stop:  # swarmlint: disable=SWL301 -- chunk-granular race is benign
-            return False
-        cs = self.chaos_step
-        if cs is not None and getattr(cs, "pending", lambda: False)():
+            return False, 0
+        if ses.failed or self._chaos_pending():
             # an armed chaos fault must land at the loop-top seam: exit
             # the session so the next iteration runs chaos_step (a kill
             # raised inside this ordered callback would be swallowed)
-            return False
-        active = any(s.active for s in self.slots)
-        if not active:
-            return False
+            return False, 0
+        through = (n + 1) * self.decode_chunk
+        alive = ses.alive
+        alive &= ~((block == self.eos_id).any(axis=0)
+                   | (ses.left <= through + ses.first)
+                   | (ses.pos0 + through > self.max_seq))
+        for i, req, _pos0 in ses.snap:
+            if alive[i]:
+                s = self.slots[i]
+                if not s.active or s.request is not req or s.cancelled:
+                    alive[i] = False
+        if not alive.any():
+            return False, 0
         with self._cv:
             queued = len(self._queue)
-        if queued and any(not s.active for s in self.slots):
-            return False
-        return True
+        if queued and not alive.all():
+            return False, queued
+        return True, queued
+
+    # swarmlint: hot
+    def _resident_consume(self, ses: _ResidentSession, n_dev) -> None:
+        """The engine thread's half of a resident session: take the
+        session's blocks off the FIFO in order and process each
+        (``_resident_block``) while the device runs the chunk after it,
+        until the block the callback marked last. Whenever the FIFO is
+        empty it asks the device whether the program still runs, and
+        again a timeout later, so a program that failed, a ``last`` that
+        mirrored ``cond`` wrongly or a dispatch that returned only when
+        all was over cannot hang the loop: the caller's drain read raises
+        or returns, and ``_resident_flush`` takes what was left."""
+        fifo = self._resident_fifo
+        ses.consuming = True
+        while True:
+            if fifo.empty() and n_dev.is_ready():
+                return
+            try:
+                blk = fifo.get(timeout=_RESIDENT_POLL_S)
+            except queue.Empty:
+                continue
+            if self._resident_block(ses, blk):
+                return
+
+    # swarmlint: hot
+    def _resident_flush(self, ses: Optional[_ResidentSession]) -> None:
+        """Empty the FIFO: after the drain read every callback has run,
+        so what is here is the session's whole remainder (nothing, unless
+        ``_resident_consume`` left early). ``ses`` None discards it."""
+        fifo = self._resident_fifo
+        while not fifo.empty():
+            blk = fifo.get_nowait()
+            if ses is not None:
+                self._resident_block(ses, blk)
+
+    # swarmlint: hot
+    def _resident_block(self, ses: _ResidentSession,
+                        blk: Optional[_ResidentBlock]) -> bool:
+        """Process one block of a resident session: the chunk's device
+        time (boundary to boundary, by the callback's stamps: no sync, no
+        block_until_ready), ``_process_host_block``, then whether the
+        vote held. Returns whether the session ends here. An exception
+        stops the session at the next vote and is logged; later blocks
+        are still processed, so the lanes it did not touch stay in step
+        with the device."""
+        if blk is None:      # the callback itself failed, and voted stop
+            return True
+        try:
+            K = self.decode_chunk
+            snapshot = [(i, req, pos0 + blk.n * K)
+                        for i, req, pos0 in ses.snap]
+            self._prof.dispatch(self._prof_resident_key, ses.prev_ns,
+                                blk.stamp_ns - ses.prev_ns)
+            self._process_host_block(
+                blk.block, blk.lps, snapshot, ses.prev_ns, blk.n,
+                blk.routing, stamp_ns=blk.stamp_ns)
+            ses.prev_ns = blk.stamp_ns
+            if blk.vote:
+                # what the old rule, taken after processing, finds on the
+                # inputs the vote had: stop where the vote said continue
+                # is a vote that went stale (a cancel flagged after it)
+                active = [s.active for s in self.slots]
+                if not any(active) or (blk.queued and not all(active)):
+                    self.metrics.counters["resident_votes_stale"].inc()
+        except Exception:
+            ses.failed = True
+            logger.exception("emission-ring block processing failed; "
+                             "stopping the resident session")
+        finally:
+            ses.credit.release()
+        return blk.last
 
     # swarmlint: hot
     def _run_resident(self) -> None:
-        """Dispatch one device-resident decode session and drain it.
+        """Dispatch one device-resident decode session, emit its chunks
+        as they come and drain it.
 
         The session covers every currently-active slot; admission happens
         only between sessions (the continue vote exits the loop when
         queued work meets a free slot). Host<->device traffic for the
-        whole session: the dispatch (no sync) and ONE drain read of the
-        chunk counter — a request admitted and retired within a session
-        therefore spans a single sanctioned sync, vs one per chunk on
-        the scan path."""
+        whole session: the dispatch (no sync), one packed block and one
+        vote a chunk through the ordered callback, and ONE drain read of
+        the chunk counter — a request admitted and retired within a
+        session therefore spans a single sanctioned sync, vs one per
+        chunk on the scan path."""
         t_session = self.tracer.phase_begin("engine.session")
         n_chunks = 0
         variant = -1
@@ -4571,6 +4786,10 @@ class Engine:
         positions = np.zeros((B,), np.int32)
         stop_pos = np.zeros((B,), np.int32)
         live = np.zeros((B,), bool)
+        # what the votes reckon with (_resident_vote): tokens each lane's
+        # request may still emit, and whether its first is still pending
+        left = np.zeros((B,), np.int32)
+        first = np.zeros((B,), np.int32)
         snap: List[Tuple[int, GenRequest, int]] = []
         needs_filters = False
         needs_sampling = False
@@ -4581,12 +4800,12 @@ class Engine:
             pos0 = s.dispatched_position
             positions[i] = pos0
             live[i] = True
+            left[i] = s.request.sampling.max_new_tokens - len(s.generated)
+            first[i] = s.pending_first
             # +1 covers the pending first token (row 0 of the first
             # block); the device stops the LOOP here, the host still
             # owns exact retirement semantics
-            rem = (s.request.sampling.max_new_tokens
-                   - len(s.generated) + 1)
-            stop_pos[i] = min(self.max_seq, pos0 + max(1, rem))
+            stop_pos[i] = min(self.max_seq, pos0 + max(1, int(left[i]) + 1))
             snap.append((i, s.request, pos0))
             if not s.pending_first:
                 carried += 1
@@ -4601,9 +4820,12 @@ class Engine:
             max_chunks = np.int32(-(-max(1, max_rem) // K) + 1)
             variant = (0 if needs_filters else 1 if needs_sampling else 2)
             n_chunks = self._resident_session(
-                variant, positions, stop_pos, live, max_chunks, snap)
+                variant, positions, stop_pos, live, max_chunks,
+                _ResidentSession(snap, positions, left, first, live.copy(),
+                                 max_chunks))
         finally:
-            # build inputs -> dispatch -> the drain read returned
+            # build inputs -> dispatch -> every block emitted -> the
+            # drain read returned
             self.tracer.phase_end(
                 t_session, "engine.session", cat="engine",
                 args={"step": self._loop_step,
@@ -4620,13 +4842,13 @@ class Engine:
 
     # swarmlint: hot
     def _resident_session(self, variant: int, positions, stop_pos, live,
-                          max_chunks, snap) -> int:
-        """Dispatch one resident program and wait for its chunk count:
-        the device half of ``_run_resident``. Returns the chunks run."""
+                          max_chunks, ses: _ResidentSession) -> int:
+        """Dispatch one resident program, consume its blocks and read its
+        chunk count: the device half of ``_run_resident``. Returns the
+        chunks run."""
         fn = self._resident_variants[variant]
         self._prof_resident_key = PROF_RESIDENT_KEYS[variant]
-        self._resident_snap = snap
-        self._resident_prev_ns = time.monotonic_ns()
+        self._resident = ses
         self._lane_busy = True
         try:
             n_dev, lt, llp, cache = fn(
@@ -4635,12 +4857,18 @@ class Engine:
                 self._topp, stop_pos, live, max_chunks,
             )
             self._last_tokens, self._last_lps, self.cache = lt, llp, cache
+            # the drain read's transfer, queued behind the program: it is
+            # on the host by the time the last block has been emitted
+            n_dev.copy_to_host_async()
+            self._resident_consume(ses, n_dev)
             t_sync0 = time.monotonic_ns()
             # swarmlint: sanctioned-drain -- THE one sync per session:
-            # its resolution also guarantees every ordered emission
-            # callback has run, so slot state below is host-confirmed
+            # after the last block it returns at once, and its resolution
+            # guarantees every ordered emission callback has run, so the
+            # flush below leaves slot state host-confirmed
             n_chunks = int(jax.device_get(n_dev))
             t_sync1 = time.monotonic_ns()
+            self._resident_flush(ses)
             self.tracer.span_end(t_sync0, "engine.host_sync", cat="engine")
             self.metrics.counters["engine_host_syncs"].inc()
             self._host_sync_n += 1
@@ -4650,7 +4878,8 @@ class Engine:
             self.metrics.counters["engine_resident_chunks"].inc(n_chunks)
             return n_chunks
         finally:
-            self._resident_snap = None
+            self._resident = None
+            self._resident_flush(None)   # a failed program's leftovers
             self._lane_busy = False
 
     def _dispatch_decode(self):  # swarmlint: hot
@@ -4775,20 +5004,24 @@ class Engine:
     # swarmlint: hot
     def _process_host_block(self, block, lps, snapshot,
                             t_dispatch_ns: int = 0, chunk: int = 0,
-                            routing=None) -> None:
+                            routing=None, stamp_ns: int = 0) -> None:
         """Pure host-side half of block processing: emit tokens, retire
         finished slots, close the per-chunk spans. Fed numpy blocks by
-        BOTH paths — the scan path after its per-chunk drain, and the
-        resident emission ring's ordered callback (where the device is
-        never waited on). ``chunk`` is the block's index in its resident
-        session (a scan dispatch is one chunk a loop step: 0).
+        BOTH paths, on the engine thread in both: the scan path after its
+        per-chunk drain, and the resident session's consumer
+        (``_resident_block``), which runs a chunk behind the device and
+        is not waited for by it. ``chunk`` is the block's index in its
+        resident session (a scan dispatch is one chunk a loop step: 0).
         ``routing`` is the chunk's [K, B, L_routed, k] where the
         configuration routes: row ``[s, i]`` is the routing of the token
         slot ``i`` was FED at step ``s``, so it joins the slot's record
-        when the token that step sampled is read."""
-        # the engine thread parks in the session drain for a whole
-        # resident session, so the emission callback is where a live lane
-        # proves progress — beat HERE, not just in the loop
+        when the token that step sampled is read. ``stamp_ns`` is when
+        the resident callback took the block (0 on the scan path): how
+        far behind it this processing begins is ``engine.emit``'s
+        ``behind_us``."""
+        # a resident session keeps the engine thread here or on the FIFO
+        # for as long as it lasts, so a processed block is how a live
+        # lane proves progress — beat HERE, not just in the loop
         self._beat()
         t_emit = self.tracer.phase_begin("engine.emit")
         # live slots of this chunk, and over them the pages each owns
@@ -4842,10 +5075,10 @@ class Engine:
                                  logprob=float(lps[0, i]))
             taken = 0      # steps of this chunk whose output the slot read
             if routing is not None and s.active:
-                # the slot's K rows of the chunk, copied once (the
-                # callback's operand is not the slot's to keep): a slot
-                # that retires inside the loop below has its record cut
-                # to the outputs it read (_finish_routing)
+                # the slot's K rows of the chunk, copied once (a view
+                # would keep every lane's rows alive with the slot's): a
+                # slot that retires inside the loop below has its record
+                # cut to the outputs it read (_finish_routing)
                 col = np.array(routing[:, i])
                 s.routing.append(col)
             for step in range(K):
@@ -4867,6 +5100,8 @@ class Engine:
         c["kv_page_chunks_reserved"].inc(pages_reserved)
         c["kv_page_chunks_written"].inc(pages_written)
         args = {"step": self._loop_step, "chunk": chunk, "live": n_live}
+        if stamp_ns:
+            args["behind_us"] = (t_done_ns - stamp_ns) // 1000
         if live_rows:
             # what the reservoir observed of this chunk, a routed layer
             # a ratio: a reader that cannot reach the registry reads it

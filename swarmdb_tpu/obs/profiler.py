@@ -200,8 +200,8 @@ NULL_LANE = NullLane()
 class LaneProfile:
     """Per-engine (= per-lane) device-time accumulator + a bounded ring
     of recent dispatches for the Chrome-trace device tracks. Written by
-    the lane's engine thread and its emission-callback thread; the
-    races are benign (the flight-recorder stance: rings are evidence)."""
+    the lane's engine thread and read from others; the races are benign
+    (the flight-recorder stance: rings are evidence)."""
 
     __slots__ = ("label", "pool", "enabled", "busy_ns", "serving_since_ns",
                  "_reg", "_ring", "_ring_idx", "_ring_cap")

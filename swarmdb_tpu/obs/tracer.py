@@ -18,9 +18,11 @@ and the broker send path):
   (export) take benign racy snapshots — a torn read costs at most one
   event, never a crash.
 - **Bounded memory.** Rings are fixed-size (``SWARMDB_TRACE_RING``,
-  default 8192 events/thread: a minute of a saturated engine's chunk
-  and phase spans, which the benchmark's span readers need whole); old
-  events are overwritten. Rings of dead
+  default 32768 events/thread: the engine's loop thread writes every
+  phase and per-chunk span of its lane, 10,419 events in a window of
+  the busier benchmark cell, and the benchmark's span readers need a
+  window whole; a ring grows to that size as its thread writes, so a
+  thread with a few spans holds a few); old events are overwritten. Rings of dead
   threads are pruned at the next registration.
 - **Monotonic time.** Spans are stamped with ``time.monotonic_ns`` so a
   wall-clock step can never produce negative durations; one
@@ -67,14 +69,19 @@ class _Ring:
     __slots__ = ("events", "idx", "cap", "tid", "name")
 
     def __init__(self, cap: int, tid: int, name: str) -> None:
-        self.events: List[Optional[_Event]] = [None] * cap
+        # grows to ``cap`` and then wraps: a thread that writes a few
+        # spans does not pay for a busy engine's ring
+        self.events: List[_Event] = []
         self.idx = 0
         self.cap = cap
         self.tid = tid
         self.name = name
 
     def put(self, ev: _Event) -> None:
-        self.events[self.idx % self.cap] = ev
+        if self.idx < self.cap:
+            self.events.append(ev)
+        else:
+            self.events[self.idx % self.cap] = ev
         self.idx += 1
 
     def snapshot(self) -> List[_Event]:
@@ -82,11 +89,9 @@ class _Ring:
         idx = self.idx
         events = list(self.events)  # one shot; writer may lap one slot
         if idx <= self.cap:
-            out = events[:idx]
-        else:
-            cut = idx % self.cap
-            out = events[cut:] + events[:cut]
-        return [e for e in out if e is not None]
+            return events
+        cut = idx % self.cap
+        return events[cut:] + events[:cut]
 
 
 class _SpanCtx:
@@ -117,9 +122,9 @@ class SpanTracer:
         if capacity_per_thread is None:
             try:
                 capacity_per_thread = int(
-                    os.environ.get("SWARMDB_TRACE_RING", "8192"))
+                    os.environ.get("SWARMDB_TRACE_RING", "32768"))
             except ValueError:
-                capacity_per_thread = 8192
+                capacity_per_thread = 32768
         if enabled is None:
             enabled = os.environ.get("SWARMDB_TRACE", "1") != "0"
         self.enabled = bool(enabled)

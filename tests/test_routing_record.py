@@ -13,7 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from swarmdb_tpu.backend.engine import GenRequest
+from swarmdb_tpu.backend.engine import GenRequest, _unpack_resident_block
 from swarmdb_tpu.backend.sampling import SamplingParams
 from swarmdb_tpu.backend.service import build_backend_engine
 from swarmdb_tpu.models import llama, mixtral
@@ -609,17 +609,21 @@ def test_a_dense_engine_reports_nothing(dense_engine):
         eng._resident_emit = emit
     assert seen["routing"] is None and seen["complete"] is False
     # the fixture's engine traced its resident program under the spy: the
-    # callback took tokens, logprobs and the chunk's index, nothing more
-    assert emitted and set(emitted) == {3}
+    # callback took the chunk's one packed operand (tokens, logprobs, the
+    # chunk's index and the loop's done row; no routing part)
+    assert emitted and set(emitted) == {1}
     assert all(v == 0 for v in _counters(eng).values())
     assert eng.metrics.latencies["moe_load_max_over_mean"].summary()[
         "count"] == 0
     assert eng._routed is None
-    # the emission callback's signature keeps its three operands, with the
-    # routing an optional fourth that a dense program never passes
+    # the emission callback's signature is that one operand, for a dense
+    # and a routed program alike: whether the buffer has a routing part is
+    # a fact of the configuration, not an argument
     params = list(inspect.signature(emit).parameters.values())
-    assert [p.name for p in params] == ["block", "lps", "n", "routing"]
-    assert params[3].default is None
+    assert [p.name for p in params] == ["packed"]
+    K1, B = eng.decode_chunk + 1, eng.max_batch
+    assert _unpack_resident_block(
+        np.zeros(2 * K1 * B + 1 + B, np.int32), K1, B, None)[4] is None
 
 
 @pytest.mark.parametrize("routed", [False, True])

@@ -158,13 +158,17 @@ def test_kill_mid_stream_migrates_with_zero_loss(stack):
 
 def test_replay_bit_identical_at_every_chunk_boundary(stack):
     """Property-style migration-correctness satellite: interrupt the
-    stream at every chunk boundary k (emission is block-granular, so a
-    kill armed at token k lands at k's chunk boundary) and require the
-    replayed total sequence to be bit-identical with no duplicate
-    emission (greedy, seeded engine weights)."""
+    stream at every chunk boundary k (emission is block-granular; a kill
+    armed at token k, from the stream path, lands at the chunk boundary
+    after k's: the resident loop's vote on k's block was taken before
+    the block was emitted) and require the replayed total sequence to be
+    bit-identical with no duplicate emission (greedy, seeded engine
+    weights)."""
     g, sup, chaos = stack
     prompt = [2, 4, 6, 8, 10]
-    n_tokens = 16  # decode_chunk=4 -> boundaries at 4, 8, 12
+    # decode_chunk=4 -> boundaries at 4, 8, 12, and a chunk past the
+    # last of them before the stream ends, so every kill lands mid-stream
+    n_tokens = 20
     wait_until(lambda: _healthy(sup), 60.0, what="lanes healthy")
     ref, _, _ = _gen(g, prompt, n_tokens, hint=1)
     assert len(ref) == n_tokens
