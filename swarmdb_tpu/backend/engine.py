@@ -416,7 +416,11 @@ class _Slot:
     position: int = 0           # next absolute position to write
     generated: List[int] = field(default_factory=list)
     logprobs: List[float] = field(default_factory=list)  # parallel to generated
-    pending_first: bool = False  # prefill token not yet surfaced to host
+    # the fed token in ``_last_tokens[i]`` has not been surfaced to the
+    # host: a prefill's sample (the request's first token) or the token a
+    # running row sampled as a rider of a prefill wave; either way it is
+    # row 0 of the slot's next block
+    pending_token: bool = False
     cancelled: bool = False      # retire at the next processed block
     first_token_at: Optional[float] = None
     admitted_at: Optional[float] = None  # prefill start (flight timeline)
@@ -434,6 +438,10 @@ class _Slot:
     cached_tokens: int = 0
     new_tokens: int = 0
     row_pages: int = 0
+    # the occupant's page-table row as admission wrote it to the device
+    # (paged; shared prefix pages, then its own): what a later wave needs
+    # to read and extend the slot's context (_wave_riders)
+    table_row: Optional[np.ndarray] = None
     # routed configurations: the occupant's routing so far, in position
     # order — its cached pages' rows and its prefill's (arrays or
     # RoutingRows), then its [K, L_routed, k] of each decode chunk (the
@@ -1243,6 +1251,9 @@ class Engine:
                 ladder.append(min(max_seq, ladder[-1] * 2))
             self._ragged_widths = ladder
             self._ragged_ridge_tokens = weights_ridge_tokens(params)
+            # registered at 0, so a reader tells "nobody rode" from a
+            # program whose waves take no riders
+            self.metrics.counters["wave_rider_tokens"].inc(0)
             _ragged_body_fn = paged.prefill_ragged
 
             def _prefill_ragged_insert(params, tokens, tok_row, tok_pos,
@@ -1797,11 +1808,15 @@ class Engine:
         (dev,), self._wave_routing = self._wave_routing, []
         return WaveRouting(dev)
 
-    def _pack_args(self, width: int, filled: int) -> Dict[str, Any]:  # swarmlint: hot
+    # swarmlint: hot
+    def _pack_args(self, width: int, filled: int,
+                   riders: int = 0) -> Dict[str, Any]:
         """Args of the ``engine.admission.pack`` phase of the wave about
-        to be dispatched: its token grid and the real tokens in it."""
+        to be dispatched: its token grid, the admitted tokens in it and
+        the running rows that ride it (a ragged wave; a token each)."""
         return {"step": self._loop_step, "wave": self._wave_n + 1,
-                "width": int(width), "filled": int(filled)}
+                "width": int(width), "filled": int(filled),
+                "riders": riders}
 
     def _count_wave(self, kind: str) -> Dict[str, Any]:  # swarmlint: hot
         """Count one prefill dispatch (a device wave); returns the args
@@ -2452,6 +2467,50 @@ class Engine:
         over the weights."""
         return plan_ragged_waves(n, self._ragged_widths,
                                  self._ragged_ridge_tokens)[0]
+
+    # swarmlint: hot
+    def _wave_riders(self) -> List[int]:
+        """The slots that may ride this round's ragged wave as one-token
+        rows (ISSUE 43), read from what the engine can observe: a running
+        row (its first token is out and none is pending) that is not
+        cancelled, whose every dispatched chunk has been processed
+        (always so between resident sessions; with a chunk in flight on
+        the scan path nobody rides: the device is ahead of ``generated``),
+        with at least two tokens left (a row with one left would need a
+        whole chunk only to surface it) and room for the step under
+        ``max_seq``. A prefill lane has no running rows of its own. A
+        configuration whose FFN drops over a capacity never gets here: it
+        has no ragged waves (a rider would compete with the prompt tokens
+        for capacity where a decode step's rows do not)."""
+        if self._role == "prefill":
+            return []
+        return [i for i, s in enumerate(self.slots)
+                if s.active and not s.cancelled and not s.pending_token
+                and s.generated and s.position == s.dispatched_position
+                and s.request.sampling.max_new_tokens - len(s.generated) >= 2
+                and s.position + 1 < self.max_seq]
+
+    # swarmlint: hot
+    def _riders_that_fit(self, total: int, width: int, seats: int) -> int:
+        """How many of ``seats`` one-token riders the round's last wave
+        takes at the planner's own price: its ``total`` pending tokens
+        plan as one wave of ``width``, and with the riders they still
+        plan as one wave that costs no more (``max(w, ridge)``,
+        ``plan_ragged_waves``): the padding under the rung, and under the
+        ridge the wider rungs that cost the same pass over the weights.
+        No rung, program or wave the round would not have had."""
+        if seats <= 0:
+            return 0
+        ridge = self._ragged_ridge_tokens
+        price = max(width, ridge)
+        k = min(seats, max(w for w in self._ragged_widths
+                           if max(w, ridge) <= price) - total)
+        while k > 0:
+            w = self._ragged_width_for(total + k)
+            if w >= total + k and max(w, ridge) <= price:
+                break
+            k -= 1
+        return max(k, 0)
 
     def _role_warms_decode(self) -> bool:
         """Whether this lane's warmup covers the decode-side variants
@@ -3592,8 +3651,9 @@ class Engine:
                     slot.routing = hit_routing.get(slot_id, [])
                     slot.cached_parts = len(slot.routing)
                     slot.routing_complete = req.resume_pages is None
+                slot.table_row = row_by_slot.get(slot_id)
                 slot.row_pages = (
-                    int(np.count_nonzero(row_by_slot[slot_id]))
+                    int(np.count_nonzero(slot.table_row))
                     if self.paged else 0)
                 if slot_id in resume_rows:
                     resume_batch.append((slot_id, req, resume_rows[slot_id]))
@@ -4186,6 +4246,18 @@ class Engine:
         max_batch drops the rest), with the same absolute-position PRNG
         fold as the bucketed paths.
 
+        The round's last wave also carries the running rows as RIDERS
+        (ISSUE 43): a slot whose state the host has confirmed
+        (``_wave_riders``) is, to the ragged forward, a row with a cached
+        prefix of ``position`` tokens and a suffix of one, the token it
+        was fed last. It sits in a seat the plan already pays for
+        (``_riders_that_fit``), samples into its own ``_last_tokens``
+        lane with the key of its position, advances by one and has that
+        token surfaced as row 0 of its next block (``pending_token``):
+        the pass over the weights that admits a request is a decode step
+        for the rows that wait for it. Riders are not in ``batch``: they
+        count into ``wave_rider_tokens`` and into nothing of admission's.
+
         ``batch`` rows: (slot_id, req, hits, chains, table_row) — hits/
         chains from the admission-time prefix plan (chains None = row not
         prefix-planned: sub-page prompt, keep_pages, or prefix off)."""
@@ -4206,6 +4278,7 @@ class Engine:
         packed_n = padding_n = 0
         # routed: slot -> the parts of its suffix, in stream order
         stream_parts: Dict[int, List[RoutingRows]] = {}
+        riding: List[int] = []     # the slots that ride the last wave
         tracer = self.tracer
         while pend:
             t_pack = tracer.phase_begin("engine.admission.pack")
@@ -4213,6 +4286,17 @@ class Engine:
             for it in pend:
                 total += len(it[1]) - it[3]
             wd = self._ragged_width_for(total)
+            if wd >= total:
+                # the round's last wave: every pending row ends in it,
+                # and the seats and rows it has left take riders
+                riding = self._wave_riders()
+                riding = riding[:self._riders_that_fit(
+                    total, wd, min(len(riding), R - len(pend)))]
+                for sid in riding:
+                    s = self.slots[sid]
+                    pend.append([sid, s.generated[-1:], s.position, 0,
+                                 s.table_row])
+                wd = self._ragged_width_for(total + len(riding))
             tokens = np.full(wd, self.pad_id, np.int32)
             tok_row = np.full(wd, R, np.int32)   # R = dead row sentinel
             tok_pos = np.full(wd, cap, np.int32)  # >= coverage -> trash
@@ -4250,9 +4334,10 @@ class Engine:
                 gather[r] = slot_id
                 state_slot[r] = slot_id
                 # a first chunk behind a prefix hit resumes from its last
-                # hit page (the table row starts with the hit pages)
-                state_src[r] = -1 if consumed else (row[p0 // ps - 1]
-                                                    if p0 else 0)
+                # hit page (the table row starts with the hit pages); a
+                # rider, like a later chunk, from its own slot's state
+                state_src[r] = -1 if consumed or slot_id in riding else (
+                    row[p0 // ps - 1] if p0 else 0)
                 if consumed + take == len(suffix):
                     scatter[r] = slot_id     # final chunk: sample here
                 it[3] = consumed + take
@@ -4268,8 +4353,11 @@ class Engine:
                 check_wave_descriptors(
                     tok_row, tok_pos, tables,
                     self.paged.allocator.num_pages, ps)
+            # admission's accounts hold the admitted tokens alone, as
+            # before: a rider's seat is padding to them
+            admitted = filled - len(riding)
             tracer.phase_end(t_pack, "engine.admission.pack", cat="engine",
-                             args=self._pack_args(wd, filled))
+                             args=self._pack_args(wd, admitted, len(riding)))
             self._mirrored(
                 self.CALL_PAGED_PREFILL_RAGGED, tokens, tok_row, tok_pos,
                 starts, lens, plens, tables, scatter,
@@ -4283,20 +4371,31 @@ class Engine:
                             prof_key("prefill.ragged", tokens.shape))
             wave = self._take_wave()
             if wave is not None:
-                # a row's record takes its chunk of this wave's stream;
-                # ``page_rows`` keeps, a slot, where each suffix position's
+                # a row's record takes its chunk of this wave's stream (a
+                # rider's one row lies in position order: every chunk
+                # before this round has been processed); ``stream_parts``
+                # keeps, an admitted slot, where each suffix position's
                 # row lies, for the pages registered below
                 for j in range(r):
                     sid = int(gather[j])
                     part = wave.part(slice(int(starts[j]),
                                            int(starts[j] + lens[j])))
                     self.slots[sid].routing.append(part)
-                    stream_parts.setdefault(sid, []).append(part)
-            packed_n += filled
-            padding_n += wd - filled
+                    if sid not in riding:
+                        stream_parts.setdefault(sid, []).append(part)
+            for sid in riding:
+                # the slot is a token further and that token is pending,
+                # as after a prefill
+                s = self.slots[sid]
+                s.position += 1
+                s.dispatched_position = s.position
+                s.pending_token = True
+            packed_n += admitted
+            padding_n += wd - admitted
             pend = [it for it in pend if it[3] < len(it[1])]
         self.metrics.counters["prefill_packed_tokens"].inc(packed_n)
         self.metrics.counters["prefill_padding_tokens"].inc(padding_n)
+        self.metrics.counters["wave_rider_tokens"].inc(len(riding))
         self._last_wave_kind = "ragged"
         if self._prefix is not None:
             # registration mirrors _prefill_paged_prefix_batch: custody
@@ -4514,7 +4613,7 @@ class Engine:
             slot.dispatched_position = slot.position
             slot.generated = []
             slot.logprobs = []
-            slot.pending_first = True
+            slot.pending_token = True
             slot.admit_syncs = self._host_sync_n
             with self._cv:
                 self._admitting.discard(req.request_id)
@@ -4651,14 +4750,15 @@ class Engine:
         be admitted into a slot that is free now or freed by this block
         (exit -> admit -> new session). A lane the votes so far expect
         live retires in this block by EOS (an ``eos_id`` anywhere in its
-        column: row 0 is a pending prefill sample, or a fed token that an
-        earlier block already showed not to be one), by length (the tokens
-        its request had left when the snapshot was built, against the
-        ``(n + 1) * K`` steps through this block and the pending first
-        token) or at ``max_seq`` (the block's last step would write at or
-        past it): exactly ``_process_host_block``'s three retirements.
+        column: row 0 is a pending sample, a prefill's or a wave rider's,
+        or a fed token that an earlier block already showed not to be
+        one), by length (the tokens its request had left when the snapshot
+        was built, against the ``(n + 1) * K`` steps through this block
+        and the pending token) or at ``max_seq`` (the block's last step
+        would write at or past it): exactly ``_process_host_block``'s
+        three retirements.
         The loop's own ``done`` row is not used for this: its ``stop_pos``
-        carries a ``+ 1`` for the pending first token whether one is
+        carries a ``+ 1`` for the pending token whether one is
         pending or not, so by length it is up to a chunk generous; it
         serves the mirror of ``cond`` in ``_resident_emit``, where it is
         the device's word. What processing has already found is read from
@@ -4787,7 +4887,8 @@ class Engine:
         stop_pos = np.zeros((B,), np.int32)
         live = np.zeros((B,), bool)
         # what the votes reckon with (_resident_vote): tokens each lane's
-        # request may still emit, and whether its first is still pending
+        # request may still emit, and whether one is pending (a prefill's
+        # sample or a wave rider's)
         left = np.zeros((B,), np.int32)
         first = np.zeros((B,), np.int32)
         snap: List[Tuple[int, GenRequest, int]] = []
@@ -4801,13 +4902,15 @@ class Engine:
             positions[i] = pos0
             live[i] = True
             left[i] = s.request.sampling.max_new_tokens - len(s.generated)
-            first[i] = s.pending_first
-            # +1 covers the pending first token (row 0 of the first
+            first[i] = s.pending_token
+            # +1 covers a pending token (row 0 of the first
             # block); the device stops the LOOP here, the host still
             # owns exact retirement semantics
             stop_pos[i] = min(self.max_seq, pos0 + max(1, int(left[i]) + 1))
             snap.append((i, s.request, pos0))
-            if not s.pending_first:
+            if s.first_token_at is not None:
+                # decoding before this session: a rider too, whose
+                # pending token is not its first
                 carried += 1
             max_rem = max(max_rem, int(stop_pos[i]) - pos0)
             if self._topk[i] > 0 or self._topp[i] < 1.0:
@@ -4938,7 +5041,7 @@ class Engine:
         Off-role slots (max_new > 1, e.g. colocated fallback under a
         quarantined decode pool) are left for the regular decode loop."""
         rows = [i for i, s in enumerate(self.slots)
-                if s.active and s.pending_first and s.request is not None
+                if s.active and s.pending_token and s.request is not None
                 and s.request.sampling.max_new_tokens <= 1]
         if not rows:
             return
@@ -4959,7 +5062,7 @@ class Engine:
             if s.cancelled:
                 self._retire(i, "cancelled")
                 continue
-            s.pending_first = False
+            s.pending_token = False
             self._emit_token(i, int(toks[i]), now, logprob=float(lps[i]))
             if s.active:
                 # emit retires max_new<=1 on "length"/"eos"; this only
@@ -5067,10 +5170,11 @@ class Engine:
             if s.cancelled:
                 self._retire(i, "cancelled")
                 continue
-            if s.pending_first:
-                # row 0 is the fed token == this slot's prefill sample,
-                # which the host deliberately never fetched at admission
-                s.pending_first = False
+            if s.pending_token:
+                # row 0 is the fed token == what this slot sampled in a
+                # prefill wave (its first token, or the one it rode for),
+                # which the host deliberately never fetched there
+                s.pending_token = False
                 self._emit_token(i, int(block[0, i]), now,
                                  logprob=float(lps[0, i]))
             taken = 0      # steps of this chunk whose output the slot read

@@ -252,7 +252,7 @@ def _occupy(eng, lanes, left=100, first=False, pos0=16):
                 prompt=[5] * at, sampling=SamplingParams(
                     max_new_tokens=left if np.isscalar(left) else left[i]))
             s.generated, s.logprobs = [], []
-            s.pending_first = bool(first)
+            s.pending_token = bool(first)
             s.position = s.dispatched_position = at
             s.first_token_at = s.admitted_at = time.time()
             s.routing = None
