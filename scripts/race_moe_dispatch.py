@@ -1,6 +1,7 @@
 #!/usr/bin/env python3
 """Race forms of a dropless top-k expert FFN at one layer's published
-widths (PR 36): hidden 2048, expert width 1792, 32 experts, top-4, bf16.
+widths (PR 36): hidden 2048, expert width 1792, 32 experts, top-4, bf16
+(``--widths`` for another layer's).
 
     chiprun -- python3 scripts/race_moe_dispatch.py            # the chip
     python3 scripts/race_moe_dispatch.py --platform cpu --tiny # a smoke
@@ -58,6 +59,10 @@ def main() -> int:
     ap.add_argument("--tiles", default="256,896,1792",
                     help="F tiles of the form ``stream`` (those that "
                          "divide F are raced)")
+    ap.add_argument("--widths", default=None,
+                    help="D,F,E,k of another layer than lfm2's: "
+                         "5120,1536,20,1 is deepseek-v2.chat's 20 held "
+                         "experts, of which a row chooses about one (PR 44)")
     args = ap.parse_args()
     if args.platform == "cpu":
         os.environ["JAX_PLATFORMS"] = "cpu"
@@ -69,6 +74,8 @@ def main() -> int:
     from swarmdb_tpu.ops import moe_pallas
 
     D, F, E, K = (128, 256, 8, 2) if args.tiny else (2048, 1792, 32, 4)
+    if args.widths:
+        D, F, E, K = (int(v) for v in args.widths.split(","))
     dt = jnp.bfloat16
     key = jax.random.PRNGKey(0)
     ks = jax.random.split(key, 8)

@@ -521,6 +521,7 @@ class Engine:
         flight_dir: Optional[str] = None,
         aging_s: Optional[float] = None,
         routed: Optional[Tuple[int, int, int]] = None,
+        held_experts: Optional[Tuple[int, int]] = None,
     ) -> None:
         # routed = (L_routed, k, E) of a configuration that routes
         # (models.mixtral.routing_shape; None = dense). Its forwards
@@ -530,6 +531,14 @@ class Engine:
         # (GenRequest.routing). A dense engine's programs, callbacks and
         # records are as they were.
         self._routed = routed
+        # held_experts = (first, count): the weights hold that share of a
+        # routed layer's E experts (one chip of those that share a
+        # layer). A negative entry of the routing is then a choice of an
+        # expert another chip holds, nothing dropped: counted by
+        # ``moe_held_assignments`` beside ``moe_assignments``, and the
+        # reach of a step (``moe_expert_hits`` of
+        # ``moe_expert_step_slots``) is over the held experts
+        self._held_experts = held_experts
         # what the last prefill dispatch returned after its other outputs:
         # [] for a dense configuration, [routing] for one that routes
         self._wave_routing: List[Any] = []
@@ -649,28 +658,42 @@ class Engine:
         # its row from its last hit page's). The pool's shapes size both;
         # no serving key does.
         self._stateful = isinstance(self.cache, dict) and "state" in self.cache
-        if self._stateful and (
+        # latent pages (models/deepseek.py): the pool under ``"k"`` is one
+        # of rows ``[L, num_pages, ps, Wd]`` with no heads axis, and
+        # ``"v"`` is a ``NoValuePool``, the format's type, which the
+        # configuration chose where the cache was built. The programs
+        # below hand both on as they hand on keys and values (an empty
+        # pytree: nothing is donated or written for it), and page tables, the allocator, the pins and
+        # the prefix cache manage a page by its id whatever it holds. The
+        # paths that would index a heads axis are the ones a
+        # configuration with conv state is kept from, for the same
+        # reason: they are not built for it
+        from ..ops.paged_kv import NoValuePool
+
+        self._latent = (isinstance(self.cache, dict)
+                        and isinstance(self.cache.get("v"), NoValuePool))
+        if (self._stateful or self._latent) and (
                 chunked_fns is None or paged.prefill_ragged is None
                 or getattr(paged.allocator, "n_shards", 1) > 1
                 or os.environ.get("SWARMDB_RAGGED_PREFILL", "auto") == "0"):
             raise NotImplementedError(
-                "a configuration with conv state is served by the paged "
-                "engine's ragged prefill and chunked decode on one shard: "
-                "the row-bucketed prefill (SWARMDB_RAGGED_PREFILL=0), the "
-                "per-step decode (SWARMDB_CHUNKED=0) and a sharded pool do "
-                "not carry conv state")
+                "a configuration with conv state or latent pages is served "
+                "by the paged engine's ragged prefill and chunked decode on "
+                "one shard: the row-bucketed prefill "
+                "(SWARMDB_RAGGED_PREFILL=0), the per-step decode "
+                "(SWARMDB_CHUNKED=0) and a sharded pool (lanes) do not "
+                "carry conv state, nor pages without a heads axis")
+        if self._latent:
+            from ..ops.layers import latent_kernels_enabled
+
+            latent_kernels_enabled()   # a TPU with SWARMDB_PALLAS=0 refuses
         if paged is not None:
             # swarmmem (ISSUE 17): KV bytes per pool page — prices the
             # warm-tier model's re-admission device_put
             from ..obs.memprof import memprof as _memprof
-            from ..ops.paged_kv import pool_page_bytes
 
             try:
-                # pool_page_bytes folds the int8 QuantPool's scale planes
-                # into the per-page price (plain arrays: nbytes // pages)
-                _memprof().set_page_bytes(
-                    pool_page_bytes(self.cache["k"])
-                    + pool_page_bytes(self.cache["v"]))
+                _memprof().set_page_bytes(self._page_bytes())
             except Exception:  # cache layouts without nbytes (stubs)
                 pass
         self._decode_forward = paged.decode_forward if paged else forward_fn
@@ -1254,6 +1277,8 @@ class Engine:
             # registered at 0, so a reader tells "nobody rode" from a
             # program whose waves take no riders
             self.metrics.counters["wave_rider_tokens"].inc(0)
+            if self._latent:
+                self.metrics.counters["latent_prefix_tokens_reused"].inc(0)
             _ragged_body_fn = paged.prefill_ragged
 
             def _prefill_ragged_insert(params, tokens, tok_row, tok_pos,
@@ -2019,8 +2044,9 @@ class Engine:
     # pool vs the dense prefix side pool).
 
     def supports_rolling(self) -> bool:
-        if self._stateful:
-            # kept pages come back without the state at their end
+        if self._stateful or self._latent:
+            # kept pages come back without the state at their end; the
+            # resume prefill reads pages by a heads axis
             return False
         if self.paged is not None:
             return (getattr(self, "_prefill_paged_resume_fused", None)
@@ -2151,14 +2177,13 @@ class Engine:
                 # (whose dequant materializes f32 pages), not the
                 # in-kernel dequant the TPU path runs, so the roofline
                 # A/B reads KV traffic off this column instead
-                from ..ops.paged_kv import kv_dtype_name, pool_page_bytes
+                from ..ops.paged_kv import kv_dtype_name
 
                 meta["kv_dtype"] = kv_dtype_name()
                 try:
                     ps = int(self.paged.page_size)
                     meta["kv_bytes_per_token"] = (
-                        pool_page_bytes(self.cache["k"])
-                        + pool_page_bytes(self.cache["v"])) // max(1, ps)
+                        self._page_bytes() // max(1, ps))
                 except Exception:  # stub caches without nbytes
                     pass
             if (family.startswith(("decode", "resident"))
@@ -2417,6 +2442,18 @@ class Engine:
         logger.info("engine warmup compiled %d prefill buckets + decode "
                     "chunk in %.1fs", len(self.prefill_buckets), dt)
         return dt
+
+    def _page_bytes(self) -> int:
+        """HBM bytes one page id occupies across layers (swarmmem's price
+        of a page's admission). ``pool_page_bytes`` folds the int8
+        QuantPool's scale planes in; a latent cache is one pool of rows
+        ``[L, P, ps, Wd]`` and no value pool."""
+        from ..ops.paged_kv import pool_page_bytes
+
+        if self._latent:
+            return self.cache["k"].nbytes // max(1, self.cache["k"].shape[1])
+        return (pool_page_bytes(self.cache["k"])
+                + pool_page_bytes(self.cache["v"]))
 
     def _paged_cache_with(self, k_pool, v_pool):
         """Rebuild the paged cache dict around new k/v pools, carrying
@@ -2725,6 +2762,12 @@ class Engine:
                     "kept pages: a rolling resume, a tier promotion and a "
                     "fleet handoff bring pages back without the conv state "
                     "at their end")
+            if self._latent:
+                raise NotImplementedError(
+                    "a configuration with latent pages cannot resume from "
+                    "kept pages: the rolling resume prefill, swarmtier's "
+                    "host store and the fleet handoff move pages of keys "
+                    "and values a head, not rows without a heads axis")
             if not self.supports_rolling():
                 raise ValueError("resume_pages requires the rolling-KV "
                                  "machinery (paged+resume prefill, or a "
@@ -4430,6 +4473,10 @@ class Engine:
                 self._slot_prefix_pins[slot_id] = hits + pins
             if reused:
                 self.metrics.counters["prefix_reused_tokens"].inc(reused)
+                if self._latent:
+                    # cached rows this wave's attention read in place
+                    self.metrics.counters[
+                        "latent_prefix_tokens_reused"].inc(reused)
         self._activate([(b[0], b[1]) for b in batch], t0)
 
     def _prefill_batch(self, batch: List[Tuple[int, GenRequest]]) -> None:  # swarmlint: hot
@@ -5236,14 +5283,18 @@ class Engine:
         for ratio in ratios:
             reservoir.observe(ratio)
         steps = max(len(r) for r in live_rows)
-        hit = np.zeros((steps, l_routed * n_experts), bool)
+        held = getattr(self, "_held_experts", None)
+        # one column more: where the choices that are not held land
+        hit = np.zeros((steps, l_routed * n_experts + 1), bool)
         for r in live_rows:
-            np.put_along_axis(
-                hit[:len(r)],
-                (layer + routing_experts(r)).reshape(len(r), -1), True, 1)
+            at = layer + routing_experts(r)
+            if held is not None:
+                at = np.where(routing_dropped(r), l_routed * n_experts, at)
+            np.put_along_axis(hit[:len(r)], at.reshape(len(r), -1), True, 1)
         c = self.metrics.counters
-        c["moe_expert_hits"].inc(int(hit.sum()))
-        c["moe_expert_step_slots"].inc(steps * l_routed * n_experts)
+        c["moe_expert_hits"].inc(int(hit[:, :-1].sum()))
+        c["moe_expert_step_slots"].inc(
+            steps * l_routed * (held[1] if held else n_experts))
         return ratios
 
     def _finish_routing(self, slot: _Slot, req: GenRequest,
@@ -5279,7 +5330,14 @@ class Engine:
         req.routing_complete = slot.routing_complete and len(rows) == need
         mine = rows[sum(len(p) for p in landed[:slot.cached_parts]):]
         c["moe_assignments"].inc(int(mine.size))
-        c["moe_dropped_assignments"].inc(int(routing_dropped(mine).sum()))
+        left_out = int(routing_dropped(mine).sum())
+        if self._held_experts is None:
+            c["moe_dropped_assignments"].inc(left_out)
+        else:
+            # nothing falls over a capacity: what is left out here is
+            # computed where it is held
+            c["moe_dropped_assignments"].inc(0)
+            c["moe_held_assignments"].inc(int(mine.size) - left_out)
         if not req.routing_complete:
             c["routing_incomplete_requests"].inc()
 

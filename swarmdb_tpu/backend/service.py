@@ -173,7 +173,7 @@ def build_backend_engine(
     here (params, pools, slot state) lands on the caller's
     ``jax.default_device`` scope, which is how a lane pins its engine to
     one mesh device."""
-    from ..models import lfm2, llama, mixtral
+    from ..models import deepseek, lfm2, llama, mixtral
     from ..models.configs import ModelConfig, get_config
     cfg = (model_name_or_cfg
            if isinstance(model_name_or_cfg, ModelConfig)
@@ -183,12 +183,13 @@ def build_backend_engine(
     # one decoder serves every family (models/llama.py); what a family
     # brings of its own is its parameter tree, and the configuration's
     # fields say which family it is
-    family = (lfm2 if cfg.layer_types is not None
+    family = (deepseek if cfg.latent
+              else lfm2 if cfg.layer_types is not None
               else mixtral if cfg.is_moe else llama)
     params = family.init_params(cfg, key)
     if paged is None:
         paged = os.environ.get("SWARMDB_PAGED", "0") == "1"
-    if cfg.stateful and not paged:
+    if (cfg.stateful or cfg.latent) and not paged:
         llama.refuse_state(cfg, "the dense slab engine")
     fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
     fwd_last = lambda p, t, pos, c, at: llama.forward(
@@ -313,6 +314,9 @@ def build_backend_engine(
         # a configuration that routes: its forwards return their routing
         # last and the engine carries it to GenRequest.routing
         routed=family.routing_shape(cfg) if cfg.is_moe else None,
+        # ... of which this chip's weights hold a share (first, count)
+        held_experts=((cfg.first_held_expert, cfg.experts_held)
+                      if cfg.n_experts_held else None),
     )
     return engine, tokenizer
 

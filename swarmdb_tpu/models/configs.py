@@ -5,7 +5,7 @@ Llama-3-70B (TP on v5p-16), and Mixtral-8x7B (EP). The reference contains no
 model code at all (SURVEY §2.4) — these are the TPU build's first-class
 additions. Architecture constants follow the public model cards.
 
-Three families share one decoder (models/llama.py), and a configuration's
+Four families share one decoder (models/llama.py), and a configuration's
 fields decide which one it is, never a switch:
 
 - dense (Llama, Mistral): every layer attends, SwiGLU FFN;
@@ -19,6 +19,16 @@ fields decide which one it is, never a switch:
   paged engine alone. No registry entry at published widths: the
   benchmark's cell ``lfm2-8b-a1b.chat`` builds it from
   ``benchmark/configs/lfm2-8b-a1b.json``; ``tiny-lfm2`` is the tests'.
+- latent attention (models/deepseek.py, ``kv_lora_rank > 0``): every layer
+  attends through a low-rank latent (MLA): what a token leaves in the cache
+  is one row of ``kv_lora_rank + qk_rope_head_dim`` values a layer, with no
+  heads axis and no values beside it; a leading dense FFN, then shared
+  experts beside a dropless softmax-routed FFN whose top-k is limited to the
+  best groups (``router = "softmax_group_limited"``), of which a chip may
+  hold a share (``n_experts_held`` from ``first_held_expert``). Served by
+  the paged engine alone. The benchmark's cell ``deepseek-v2.chat`` builds
+  it from ``benchmark/configs/deepseek-v2.json``; ``tiny-dsv2`` is the
+  tests'.
 """
 
 from __future__ import annotations
@@ -65,6 +75,42 @@ class ModelConfig:
     # or "sigmoid_bias" (float32 sigmoid scores, a selection bias that
     # chooses and does not gate, gates renormalised, every choice computed)
     router: str = "softmax_capacity"
+    # ---- latent attention (models/deepseek.py); kv_lora_rank 0 = none ----
+    # q = rmsnorm(x W_qa) W_qb in heads of ``qk_nope_head_dim +
+    # qk_rope_head_dim``; [c_kv | k_pe] = x W_kva, ``kv_lora_rank`` and
+    # ``qk_rope_head_dim`` wide, is what the cache holds a token a layer;
+    # keys and values of ``qk_nope_head_dim`` and ``v_head_dim`` a head
+    # come from c_kv through W_kvb
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN (``yarn_factor`` 0 = plain RoPE): the blend of the plain and the
+    # interpolated frequencies between the correction dims of
+    # ``yarn_beta_fast`` and ``yarn_beta_slow`` at ``yarn_original_max_seq``
+    # positions; ``yarn_mscale_all_dim`` scales the softmax
+    yarn_factor: float = 0.0
+    yarn_original_max_seq: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_mscale: float = 1.0
+    yarn_mscale_all_dim: float = 0.0
+    # ---- "softmax_group_limited": float32 softmax scores over all
+    # ``n_experts``, the ``topk_group`` best of ``n_group`` groups by their
+    # largest score, top-k among those, gates the chosen scores times
+    # ``routed_scaling_factor``, not renormalised; ``shared_experts``
+    # SwiGLUs of the expert width that every token takes beside them
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    shared_experts: int = 0
+    # the experts whose weights this chip holds, ``n_experts_held`` from
+    # ``first_held_expert`` (0 = all): the router scores all ``n_experts``,
+    # the layer computes the held ones' part and reports the other choices
+    # as left out
+    first_held_expert: int = 0
+    n_experts_held: int = 0
 
     def __post_init__(self) -> None:
         if self.layer_types is not None:
@@ -80,12 +126,47 @@ class ModelConfig:
             if "conv" in self.layer_types and self.conv_taps < 2:
                 raise ValueError(f"{self.name!r}: conv layers need "
                                  "conv_taps >= 2")
-        if self.router not in ("softmax_capacity", "sigmoid_bias"):
+        if self.router not in ("softmax_capacity", "sigmoid_bias",
+                               "softmax_group_limited"):
             raise ValueError(f"{self.name!r}: router {self.router!r}")
+        if self.n_experts and (
+                self.n_experts % self.n_group
+                or not 0 < self.topk_group <= self.n_group
+                or self.experts_per_token
+                > self.topk_group * (self.n_experts // self.n_group)):
+            raise ValueError(
+                f"{self.name!r}: {self.n_experts} experts in {self.n_group} "
+                f"groups, top-{self.experts_per_token} of the best "
+                f"{self.topk_group}")
+        if not (0 <= self.first_held_expert
+                and self.first_held_expert + self.experts_held
+                <= max(self.n_experts, self.experts_held)):
+            raise ValueError(
+                f"{self.name!r}: experts {self.first_held_expert} to "
+                f"{self.first_held_expert + self.experts_held} of "
+                f"{self.n_experts}")
 
     @property
     def head_dim(self) -> int:
+        if self.latent:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.dim // self.n_heads
+
+    @property
+    def latent(self) -> bool:
+        """Whether attention goes through a latent (MLA): the cache then
+        holds one row a token a layer, ``latent_dim`` wide, with no heads
+        axis and no values beside it (models/deepseek.py)."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def experts_held(self) -> int:
+        """How many of a routed layer's experts the weights hold."""
+        return self.n_experts_held or self.n_experts
 
     @property
     def is_moe(self) -> bool:
@@ -138,7 +219,15 @@ class _FieldsShown:
 
     MIXER = {"layer_types": None, "conv_taps": 0, "qk_norm": False,
              "n_dense_layers": 0, "expert_ffn_dim": 0,
-             "router": "softmax_capacity"}
+             "router": "softmax_capacity",
+             # the fourth family's (models/deepseek.py)
+             "q_lora_rank": 0, "kv_lora_rank": 0, "qk_nope_head_dim": 0,
+             "qk_rope_head_dim": 0, "v_head_dim": 0, "yarn_factor": 0.0,
+             "yarn_original_max_seq": 0, "yarn_beta_fast": 32.0,
+             "yarn_beta_slow": 1.0, "yarn_mscale": 1.0,
+             "yarn_mscale_all_dim": 0.0, "n_group": 1, "topk_group": 1,
+             "routed_scaling_factor": 1.0, "shared_experts": 0,
+             "first_held_expert": 0, "n_experts_held": 0}
 
     def __init__(self, every):
         self.every = every
@@ -242,6 +331,44 @@ TINY_LFM2 = ModelConfig(
     router="sigmoid_bias",
 )
 
+# the fourth family at test widths: latent attention (4 heads of 16 + 8
+# over a latent of 32 + 8), a dense layer then 3 routed ones of 16 experts
+# in 4 groups, top-4 of the best 2 groups, 2 shared experts, every expert
+# held (``get_config("tiny-dsv2", first_held_expert=4, n_experts_held=4)``
+# holds one group), YaRN at test scale
+TINY_DSV2 = ModelConfig(
+    name="tiny-dsv2",
+    vocab_size=512,
+    dim=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=4,
+    ffn_dim=128,
+    norm_eps=1e-6,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    n_experts=16,
+    experts_per_token=4,
+    n_dense_layers=1,
+    expert_ffn_dim=32,
+    router="softmax_group_limited",
+    q_lora_rank=48,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    yarn_factor=4.0,
+    yarn_original_max_seq=64,
+    yarn_beta_fast=32.0,
+    yarn_beta_slow=1.0,
+    yarn_mscale=0.707,
+    yarn_mscale_all_dim=0.707,
+    n_group=4,
+    topk_group=2,
+    routed_scaling_factor=4.0,
+    shared_experts=2,
+)
+
 # ~1B-class config for meaningful single-chip benchmarking without 8B HBM cost.
 LLAMA_1B_BENCH = ModelConfig(
     name="llama-1b-bench",
@@ -258,7 +385,7 @@ LLAMA_1B_BENCH = ModelConfig(
 REGISTRY = {
     c.name: c
     for c in (LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, TINY_DEBUG, TINY_MOE,
-              TINY_LFM2, LLAMA_1B_BENCH)
+              TINY_LFM2, TINY_DSV2, LLAMA_1B_BENCH)
 }
 
 

@@ -321,22 +321,36 @@ def _swiglu(x, w_gate, w_up, w_down):
 
 
 def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
-              live: Optional[jnp.ndarray] = None, base=0
+              live: Optional[jnp.ndarray] = None, base=0,
+              chosen_gates=None, held: Optional[Tuple[int, int]] = None
               ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Dropless routed expert FFN. ``x`` [B, T, D]; ``live`` [B, T] bool,
     the rows that take part (None: all). ``lp``'s expert matrices are one
     layer's ``[E, ...]`` or, with ``base``, the flat ``[n * E, ...]`` stack
     of a scanned segment in which this layer's expert ``e`` is row
-    ``base + e`` (``run_layers``). Returns (output [B, T, D], routing
-    [B, T, k] int16, never negative: nothing is dropped)."""
+    ``base + e`` (``run_layers``). ``chosen_gates(xf, lp) -> (chosen
+    [N, k], gates [N, k])`` is another family's router (default: this
+    one's ``route``). ``held = (first, count)``: the weights hold experts
+    ``first .. first + count`` of those the router scores, expert ``e`` at
+    row ``base + e - first``; a choice outside them takes no part here.
+    Returns (output [B, T, D], routing [B, T, k] int16: ``e``, or ``~e``
+    for a choice that is not held; nothing is dropped)."""
     B, T, D = x.shape
-    E = lp["router"].shape[-1]
     xf = x.reshape(B * T, D)
-    chosen, gates = route(xf, lp["router"], lp["expert_bias"], top_k)
+    chosen, gates = (chosen_gates(xf, lp) if chosen_gates else route(
+        xf, lp["router"], lp["expert_bias"], top_k))
+    if held is None:
+        E, local, kept = lp["router"].shape[-1], chosen, None
+    else:
+        first, E = held
+        local = chosen - first
+        kept = (local >= 0) & (local < E)
+        gates = jnp.where(kept, gates, 0.0)
     if live is not None:
         gates = gates * live.reshape(B * T, 1)
     # [N, E]: a chosen expert's gate, 0 for the others and for dead rows
-    gate = jnp.sum(jax.nn.one_hot(chosen, E, dtype=jnp.float32)
+    # (``one_hot`` of an index outside the held ones is all zeros)
+    gate = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.float32)
                    * gates[..., None], axis=1)
     hit = jnp.any(gate > 0, axis=0)
     w_gate, w_up, w_down = lp["w_gate"], lp["w_up"], lp["w_down"]
@@ -362,7 +376,8 @@ def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
     else:
         y = jax.lax.fori_loop(0, E, expert, jnp.zeros(xf.shape, jnp.float32)
                               ).astype(x.dtype)
-    routing = encode_routing(chosen, jnp.ones(chosen.shape, bool))
+    routing = encode_routing(
+        chosen, jnp.ones(chosen.shape, bool) if kept is None else kept)
     return y.reshape(B, T, D), routing.reshape(B, T, top_k)
 
 
@@ -381,15 +396,19 @@ def _concat(trees):
 
 def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin,
                mixer, attn_ops, history, conv_ops,
-               live: Optional[jnp.ndarray] = None):
+               live: Optional[jnp.ndarray] = None, attn_tm=None,
+               routed_ffn=None):
     """``x`` through every layer. ``mixer`` and ``attn_ops`` are an
     attention layer's, as ``llama.decoder_layer`` takes them, ``attn_ops``
     with a leading axis over the layers that attend; ``history`` and
     ``conv_ops`` a conv layer's (``conv_token_mixer``), over the conv
     layers. Returns ``(x, attention outs, conv outs, routing)``: the
     mixers' outputs stacked over their layers, and the routing
-    ``[B, T, L_routed, k]`` of the layers that route."""
-    attn_tm = llama.attention_token_mixer(cfg, cos, sin, mixer)
+    ``[B, T, L_routed, k]`` of the layers that route. Another family on
+    these segments brings its own token mixer for the layers that attend
+    (``attn_tm``) and its routed layers' FFN (``routed_ffn(h, lp, repeat)
+    -> (y, routing)``): models/deepseek.py."""
+    attn_tm = attn_tm or llama.attention_token_mixer(cfg, cos, sin, mixer)
     conv_tm = conv_token_mixer(history)
 
     def dense_ffn(h, lp):
@@ -424,6 +443,8 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, cos, sin,
             lps, a_r, c_r, repeat = scanned
 
             def moe_ffn(h, lp):
+                if routed_ffn is not None:
+                    return routed_ffn(h, lp, repeat)
                 return moe_block(h, lp, cfg.experts_per_token, live,
                                  repeat * cfg.n_experts)
 

@@ -8,8 +8,10 @@ Design (idiomatic TPU, not a torch port):
   is its FFN (``_ffn``) and its parameters: a routed configuration's are
   in models/mixtral.py, and models/lfm2.py has the family whose mixer
   differs a layer (a gated short convolution or attention) with the state
-  its conv layers carry; ``run_stack`` is where a forward hands its
-  mixers to the one or the other.
+  its conv layers carry, and models/deepseek.py the family that attends
+  through a latent (its token mixer, its row a token in place of keys and
+  values a head); ``run_stack`` is where a forward hands its mixers to
+  the one or the other.
 - Parameters are a plain pytree dict; per-layer weights are STACKED along a
   leading [L, ...] axis and the forward pass is one `lax.scan` over layers —
   one compiled layer body regardless of depth (fast compiles, natural hook
@@ -189,6 +191,31 @@ def init_paged_cache(
     entries — see ops/paged_kv.py)."""
     from ..ops.paged_kv import init_paged_kv_cache
 
+    if cfg.latent:
+        # the second page format (models/deepseek.py): ONE pool of rows
+        # ``[L, P, ps, row_width]``, no heads axis and no value pool. It
+        # sits under ``"k"`` (every head's keys are read from it, and
+        # its first ``kv_lora_rank`` lanes are the values) with ``"v"``
+        # a ``NoValuePool``, so that the allocator, the pins, the prefix
+        # cache and the engine's programs manage it under the page ids
+        # and the arguments they have
+        from ..ops.paged_kv import (KV_DTYPES, NoValuePool, kv_dtype_name,
+                                    kv_quantized, pages_per_slot)
+        from . import deepseek
+
+        if dtype is None:
+            if kv_quantized():
+                deepseek.refuse_latent(
+                    cfg, "an int8 pool (SWARMDB_KV_DTYPE=int8: QuantPool "
+                         "keeps a scale a page and a head)")
+            dtype = KV_DTYPES[kv_dtype_name()]
+        return {
+            "k": jnp.zeros((cfg.n_layers, num_pages, page_size,
+                            deepseek.row_width(cfg)), dtype),
+            "v": NoValuePool(),
+            "page_table": jnp.zeros(
+                (batch, pages_per_slot(max_seq, page_size)), jnp.int32),
+            "pos0": jnp.zeros((batch,), jnp.int32)}
     cache = init_paged_kv_cache(
         cfg.n_attn_layers, num_pages, page_size, cfg.n_kv_heads,
         kv_head_dim(cfg), batch, max_seq, dtype,
@@ -323,8 +350,15 @@ def run_stack(params: Params, cfg: ModelConfig, x, cos, sin, mixer, ops,
     ``params["layers"]`` (``ops`` beside them; conv outs None); one with
     ``layer_types`` goes to ``lfm2.run_layers``, which also takes the
     forward's ``history`` and ``conv_ops`` for its conv layers and
-    ``live``, the rows that take part, for its expert FFN. ``routing`` is
-    what ``take_routing`` returns."""
+    ``live``, the rows that take part, for its expert FFN; a latent one
+    to ``deepseek.run_layers``, whose ``mixer(q, row, ops)`` attends over
+    rows (``deepseek.latent_token_mixer``). ``routing`` is what
+    ``take_routing`` returns."""
+    if cfg.latent:
+        from . import deepseek
+
+        return deepseek.run_layers(params, cfg, x, cos, sin, mixer, ops,
+                                   live)
     if cfg.layer_types is None:
         x, out = jax.lax.scan(
             decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
@@ -339,12 +373,29 @@ def run_stack(params: Params, cfg: ModelConfig, x, cos, sin, mixer, ops,
 
 def refuse_state(cfg: ModelConfig, path: str) -> None:
     """A path that cannot carry a conv layer's state refuses the
-    configuration by name; none runs it wrong."""
+    configuration by name; none runs it wrong. Those paths all assume
+    keys and values a head too, so a latent configuration is refused
+    here with them (``deepseek.refuse_latent``)."""
+    if cfg.latent:
+        from . import deepseek
+
+        deepseek.refuse_latent(cfg, path)
     if cfg.stateful:
         raise NotImplementedError(
             f"{cfg.name!r} has conv layers whose recurrent state rides "
             f"beside the KV pages; {path} does not carry conv state (the "
             "paged engine's ragged prefill and chunked decode do)")
+
+
+def rope_terms(cfg: ModelConfig, positions: jnp.ndarray):
+    """RoPE's (cos, sin) for a forward: over the head size at
+    ``rope_theta``, or a latent configuration's own (YaRN over its rope
+    dims, ``deepseek.rope_terms``)."""
+    if cfg.latent:
+        from . import deepseek
+
+        return deepseek.rope_terms(cfg, positions)
+    return rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
 
 def lm_logits(params: Params, cfg: ModelConfig, x: jnp.ndarray,
@@ -393,6 +444,12 @@ def forward(
     materialize (0.5 GB per admission wave at Bp=16, T=255, V=32k, and
     ~7% of prefill FLOPs).
     """
+    if cfg.latent:
+        from . import deepseek
+
+        deepseek.refuse_latent(
+            cfg, "the whole-sequence forward over a slab cache "
+                 "(llama.forward; deepseek.forward is this family's)")
     x = params["embed"][tokens]  # [B, T, D]; compute dtype = param dtype
     # RoPE terms depend only on positions: compute once, reuse in every
     # scanned layer (XLA can't hoist transcendentals out of the loop body)
@@ -526,9 +583,10 @@ def forward_ragged_prefill(
     from ..ops.paged_kv import pool_data, pool_dtype, pools_flat
 
     x = params["embed"][tokens][None]                    # [1, W, D]
-    cos, sin = rope_cos_sin(tok_pos[None], cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_terms(cfg, tok_pos[None])
     pool_k_flat, pool_v_flat, L, P = pools_flat(pool_k, pool_v)
-    kdt, vdt = pool_dtype(pool_k), pool_dtype(pool_v)
+    kdt = pool_dtype(pool_k)
+    vdt = None if cfg.latent else pool_dtype(pool_v)
     width = pool_data(pool_k).shape[-1]
     tables = row_tables.astype(jnp.int32)
     starts = starts.astype(jnp.int32)
@@ -549,6 +607,20 @@ def forward_ragged_prefill(
             qs, ks, vs, pool_k_flat, pool_v_flat, tables + l * P,
             starts, lens, plens, tok_row, window=cfg.sliding_window)
         return attn[..., :cfg.head_dim], (ks, vs)
+
+    if cfg.latent:
+        from ..ops.layers import latent_prefill_dispatch
+        from .deepseek import at_width
+
+        def mixer(q, row, l):
+            # the wave's rows in the pool's dtype BEFORE attention, as
+            # above: what this wave attends is what later waves and
+            # decodes read back. ``sfx_v`` is None: a row is both
+            rs = at_width(row[0], width).astype(kdt)
+            o_lat = latent_prefill_dispatch(
+                at_width(q[0], width), rs, pool_k_flat, tables + l * P,
+                starts, lens, plens, tok_row)
+            return o_lat[None], (rs, None)
 
     history = page_ids = None
     R = starts.shape[0]
@@ -619,6 +691,12 @@ def init_chunk_kv(
     [L, B, Kc, Hkv, D] over the layers that attend) and, for a
     configuration whose conv layers carry state, a third buffer for the
     chunk's gated conv inputs ``z`` ([L_conv, B, Kc, D])."""
+    if cfg.latent:
+        # a latent configuration's chunk holds rows, as its pool does
+        from . import deepseek
+
+        return (jnp.zeros((cfg.n_layers, batch, chunk,
+                           deepseek.row_width(cfg)), dtype), None)
     # the paged decode's buffers are as wide as its pool (kv_head_dim);
     # the slab engine's are the configuration's head size, and no
     # stateful configuration reaches it
@@ -708,7 +786,7 @@ def _paged_rope_terms(cfg: ModelConfig, cache, positions):
     itself stays LOGICAL (page writes + masks)."""
     pos0 = cache.get("pos0")
     rope_pos = positions if pos0 is None else positions + pos0[:, None]
-    return rope_cos_sin(rope_pos, cfg.head_dim, cfg.rope_theta)
+    return rope_terms(cfg, rope_pos)
 
 
 def forward_paged(
@@ -787,7 +865,25 @@ def forward_paged_chunked(
             step, window=cfg.sliding_window)
         return attn[..., :cfg.head_dim], (hk, hv)
 
+    if cfg.latent:
+        from ..ops.layers import latent_decode_dispatch
+        from .deepseek import at_width
+
+        def mixer(q, row, scanned):
+            l, hk, _none = scanned
+            width = hk.shape[-1]
+            hk = jax.lax.dynamic_update_slice(
+                hk, at_width(row, width).astype(hk.dtype), (0, step, 0))
+            o_lat = latent_decode_dispatch(
+                at_width(q[:, 0], width), pool_k_flat, table + l * P, hk,
+                (positions[:, 0] - step).astype(jnp.int32), step)
+            return o_lat[:, None], (hk, None)
+
     history = conv_ops = live = None
+    if cfg.latent:
+        # a lane whose table row is empty holds no sequence: it reads no
+        # expert
+        live = table[:, :1] != 0
     if cfg.stateful:
         # the slots' state stays frozen for the chunk like the pool; a
         # step reads it and the chunk's own z so far (chunk_kv[2]), and

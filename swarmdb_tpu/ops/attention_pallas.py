@@ -1299,3 +1299,351 @@ def ragged_paged_prefill_attention_quant(
     )(table, starts, lens, plens, q, sfx_k, sfx_v,
       k_pages, ks3, v_pages, vs3)
     return out[:n_tok]
+
+
+# ---------------------------------------------------------------------------
+# Latent (MLA) pages: absorbed attention over ONE pool of rows.
+#
+# A latent configuration's pool is ``[P, ps, Wd]`` (models/deepseek.py): a
+# token's row ``[c_kv | k_pe | 0..]`` a layer, no heads axis, no values. In
+# the absorbed form every query head is as wide as a row and scores against
+# the same row, and the output is the softmax-weighted sum of the rows
+# themselves: multi-query attention whose keys ARE its values. The two
+# kernels below are the walks of `_paged_chunk_attn_kernel` and
+# `_ragged_prefill_kernel` over that one pool: a page is copied once and
+# serves as key and as value, all query heads (and, in a wave, ``tile_q``
+# tokens of them) are the rows of one MXU operand, the operands stay in
+# the pool's dtype with float32 accumulation, and the softmax scale rides
+# in the queries (it carries YaRN's ``mscale^2``, so it is not
+# ``1 / sqrt(width)``).
+
+
+def _mla_fold(q, keys, valid, acc_ref, m_ref, l_ref):
+    """Fold ``keys`` [Tk, Wd], which are also the values, into the online
+    softmax of the query rows ``q`` [M, Wd]; ``valid`` [M | 1, Tk]."""
+    s = jax.lax.dot_general(q, keys, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)   # [M, Tk]
+    s = jnp.where(valid, s, -1e30)
+    m_prev = m_ref[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = l_ref[:, :1] * alpha + jnp.sum(p, -1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(keys.dtype), keys, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+    l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+
+def _mla_pages_per_block(page_size: int, maxp: int) -> int:
+    return max(1, min(_PAGE_BLOCK_TOKENS // page_size, maxp))
+
+
+def _mla_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, pool_hbm,
+                           ck_ref, o_ref, buf_ref, sem_ref, acc_ref, m_ref,
+                           l_ref, *, page_size: int, pages_per_block: int):
+    """Grid (B,): a row's frozen prefix walked in blocks of
+    ``pages_per_block`` pages through a double buffer (as
+    `_paged_chunk_attn_kernel`: trips from the prefetched ``starts``, pages
+    past the last live one never fetched), then the chunk's rows
+    (entries 0..step), one online softmax."""
+    b = pl.program_id(0)
+    start = start_ref[b]
+    step = step_ref[0]
+    ps, ppb = page_size, pages_per_block
+    tile = ppb * ps
+    maxp = table_ref.shape[1]
+    live_pages = jnp.minimum(
+        jax.lax.div(jax.lax.max(start, 0) + (ps - 1), jnp.int32(ps)), maxp)
+    n_blocks = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, -1e30)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    q = q_ref[0]                                          # [Hq, Wd]
+
+    def page_copy(blk, slot, i):
+        return pltpu.make_async_copy(
+            pool_hbm.at[table_ref[b, blk * ppb + i]],
+            buf_ref.at[slot, pl.ds(i * ps, ps)], sem_ref.at[slot])
+
+    def fetch(blk, slot):
+        for i in range(ppb):
+            live = blk * ppb + i < live_pages
+
+            @pl.when(live)
+            def _start():
+                page_copy(blk, slot, i).start()
+
+            @pl.when(jnp.logical_not(live))
+            def _blank():
+                # not fetched: masked as a key, and finite as a value
+                buf_ref[slot, pl.ds(i * ps, ps)] = jnp.zeros(
+                    (ps,) + buf_ref.shape[2:], buf_ref.dtype)
+
+    def wait(blk, slot):
+        for i in range(ppb):
+            @pl.when(blk * ppb + i < live_pages)
+            def _wait():
+                page_copy(blk, slot, i).wait()
+
+    @pl.when(n_blocks > 0)
+    def _first():
+        fetch(0, 0)
+
+    def block(blk, carry):
+        slot = jax.lax.rem(blk, 2)
+
+        @pl.when(blk + 1 < n_blocks)
+        def _next():
+            fetch(blk + 1, 1 - slot)
+
+        wait(blk, slot)
+        pos = blk * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+        _mla_fold(q, buf_ref[slot], pos < start, acc_ref, m_ref, l_ref)
+        return carry
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    Kc = ck_ref.shape[1]
+    idx = jax.lax.broadcasted_iota(jnp.int32, (1, Kc), 1)
+    _mla_fold(q, ck_ref[0], idx <= step, acc_ref, m_ref, l_ref)
+    o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+                ).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mla_paged_decode_attention_chunked(
+    q: jnp.ndarray,           # [B, Hq, Wd] absorbed, scaled queries
+    pages: jnp.ndarray,       # [P, ps, Wd] FROZEN latent pool (or flat)
+    page_table: jnp.ndarray,  # [B, maxp] int32
+    chunk: jnp.ndarray,       # [B, Kc, Wd] the chunk's rows so far
+    starts: jnp.ndarray,      # [B] int32 frozen prefix length
+    step: jnp.ndarray,        # scalar int32 index within the chunk
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Absorbed decode attention over latent pages in place; [B, Hq, Wd],
+    whose first ``kv_lora_rank`` lanes the caller takes through
+    ``W_kvb^V``. A live page is copied once a call."""
+    B, Hq, Wd = q.shape
+    _, ps, _ = pages.shape
+    maxp = page_table.shape[1]
+    Kc = chunk.shape[1]
+    ppb = _mla_pages_per_block(ps, maxp)
+    q = q.astype(pages.dtype)
+    chunk = chunk.astype(pages.dtype)
+
+    def q_map(b, table_ref, start_ref, step_ref):
+        return (b, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(B,),
+        in_specs=[
+            pl.BlockSpec((1, Hq, Wd), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec((1, Kc, Wd), q_map),
+        ],
+        out_specs=pl.BlockSpec((1, Hq, Wd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, ppb * ps, Wd), pages.dtype),   # the two halves
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((Hq, Wd), jnp.float32),            # acc
+            pltpu.VMEM((Hq, 128), jnp.float32),           # running max
+            pltpu.VMEM((Hq, 128), jnp.float32),           # running denom
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_mla_chunk_attn_kernel, page_size=ps,
+                          pages_per_block=ppb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Wd), q.dtype),
+        name="mla_paged_decode_attention_chunked",
+        interpret=interpret,
+    )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
+      jnp.reshape(step, (1,)).astype(jnp.int32), q, pages, chunk)
+
+
+def _mla_ragged_prefill_kernel(table_ref, starts_ref, lens_ref, plens_ref,
+                               q_ref, sfx_hbm, pool_hbm, o_ref, buf_ref,
+                               sem_ref, acc_ref, m_ref, l_ref, *,
+                               page_size: int, pages_per_block: int,
+                               tile_k: int):
+    """Grid (nQ, R): query block ``qb`` (``Tq`` tokens, every head: the
+    ``Tq * Hq`` rows of one operand) against wave row ``r``. A row that
+    meets the block walks its live cached pages, ``pages_per_block`` a
+    trip, then the stream's tiles of ``tile_k`` rows from the row's first
+    to the block's own, through one double buffer; a row that does not
+    meet the block does nothing (`_ragged_prefill_kernel`'s walk)."""
+    qb = pl.program_id(0)
+    r = pl.program_id(1)
+    Tq, Hq, Wd = q_ref.shape
+    ps, ppb = page_size, pages_per_block
+    blk = ppb * ps
+    T = buf_ref.shape[1]
+    maxp = table_ref.shape[1]
+    start = starts_ref[r]
+    ln = lens_ref[r]
+    plen = plens_ref[r]
+    q0 = qb * Tq
+
+    @pl.when(r == 0)
+    def _zero_out():
+        o_ref[...] = jnp.zeros_like(o_ref)
+        buf_ref[...] = jnp.zeros_like(buf_ref)
+
+    @pl.when(_ragged_row_meets_block(q0, Tq, start, ln))
+    def _row():
+        live_pages = jnp.minimum(
+            jax.lax.div(jax.lax.max(plen, 0) + (ps - 1), jnp.int32(ps)),
+            maxp)
+        n_pref = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
+        first = jax.lax.div(start, jnp.int32(tile_k))
+        last = jax.lax.div(
+            jax.lax.max(jnp.minimum(start + ln, q0 + Tq) - 1, 0),
+            jnp.int32(tile_k))
+        n_trips = n_pref + (last - first + 1)
+
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        q = q_ref[...].reshape(Tq * Hq, Wd)
+
+        def copies(t, slot, act):
+            @pl.when(t < n_pref)
+            def _pages():
+                def page(i, carry):
+                    act(pltpu.make_async_copy(
+                        pool_hbm.at[table_ref[r, t * ppb + i]],
+                        buf_ref.at[slot, pl.ds(i * ps, ps)],
+                        sem_ref.at[slot]))
+                    return carry
+
+                jax.lax.fori_loop(
+                    0, jnp.minimum(live_pages - t * ppb, ppb), page, 0)
+
+            @pl.when(t >= n_pref)
+            def _tile():
+                act(pltpu.make_async_copy(
+                    sfx_hbm.at[pl.ds((first + t - n_pref) * tile_k, tile_k)],
+                    buf_ref.at[slot, pl.ds(0, tile_k)], sem_ref.at[slot]))
+
+        # stream index of each query row (token-major, a head a row)
+        wq = q0 + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (Tq * Hq, 1), 0),
+            jnp.int32(Hq))
+        kidx = jax.lax.broadcasted_iota(jnp.int32, (1, T), 1)
+        far = 1 << 30
+
+        copies(0, 0, lambda cp: cp.start())
+
+        def trip(t, carry):
+            slot = jax.lax.rem(t, 2)
+
+            @pl.when(t + 1 < n_trips)
+            def _next():
+                copies(t + 1, 1 - slot, lambda cp: cp.start())
+
+            copies(t, slot, lambda cp: cp.wait())
+            # a cached trip's keys are positions t*blk + i of the row,
+            # valid below ``plen``; a stream trip's are stream indices,
+            # valid inside the row and not after the query
+            pre = t < n_pref
+            kx = kidx + jnp.where(pre, t * blk,
+                                  (first + t - n_pref) * tile_k)
+            valid = ((kx >= jnp.where(pre, 0, start))
+                     & (kx < jnp.where(pre, plen, start + ln))
+                     & (kx <= wq + jnp.where(pre, far, 0)))
+            if blk < T or tile_k < T:
+                valid &= kidx < jnp.where(pre, blk, tile_k)
+            _mla_fold(q, buf_ref[slot], valid, acc_ref, m_ref, l_ref)
+            return carry
+
+        jax.lax.fori_loop(0, n_trips, trip, 0)
+
+        out = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)
+               ).reshape(Tq, Hq, Wd)
+        w_iota = q0 + jax.lax.broadcasted_iota(jnp.int32, (Tq, 1, 1), 0)
+        mine = (w_iota >= start) & (w_iota < start + ln)
+        o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
+
+
+# query tokens a block of the latent prefill kernel: with every head they
+# are the rows of its MXU operands (16 x 128 heads = 2,048 at the published
+# widths; the float32 accumulator is then 5.2 MB of VMEM)
+_MLA_TILE_Q = 16
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def mla_ragged_prefill_attention(
+    q: jnp.ndarray,           # [W, Hq, Wd] absorbed, scaled query stream
+    sfx: jnp.ndarray,         # [W, Wd] the wave's own rows, stream order
+    pages: jnp.ndarray,       # [P, ps, Wd] latent pool (or flat [L*P, ..])
+    row_tables: jnp.ndarray,  # [R, maxp] int32
+    starts: jnp.ndarray,      # [R] int32 row offset in the stream
+    lens: jnp.ndarray,        # [R] int32 row token count (0 = dead)
+    prefix_lens: jnp.ndarray,  # [R] int32 tokens already in the pages
+    interpret: bool = False,
+) -> jnp.ndarray:
+    """Absorbed attention of a packed wave over its rows' cached latent
+    pages in place and over its own rows; [W, Hq, Wd] (zero where no row
+    owns the position)."""
+    n_tok, Hq, Wd = q.shape
+    _, ps, _ = pages.shape
+    R, maxp = row_tables.shape
+    # stream tiles of a lane width of keys, or the whole of a narrow wave
+    # in whole query blocks
+    tile_k = min(_PAGE_BLOCK_TOKENS, -(-n_tok // _MLA_TILE_Q) * _MLA_TILE_Q)
+    pad = (-n_tok) % tile_k
+    if pad:
+        q = jnp.pad(q, ((0, pad), (0, 0), (0, 0)))
+        sfx = jnp.pad(sfx, ((0, pad), (0, 0)))
+    W = q.shape[0]
+    Tq = _MLA_TILE_Q
+    ppb = _mla_pages_per_block(ps, maxp)
+    keys = max(ppb * ps, tile_k)
+    q = q.astype(pages.dtype)
+    sfx = sfx.astype(pages.dtype)
+
+    def q_map(qb, r, table_ref, starts_ref, lens_ref, plens_ref):
+        return (qb, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(W // Tq, R),
+        in_specs=[
+            pl.BlockSpec((Tq, Hq, Wd), q_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        # swarmlint: revisit[r] -- every row step of a query block writes
+        # into its one resident output block; the masked finalize at the
+        # end of a row's walk writes each row's lanes exactly once
+        out_specs=pl.BlockSpec((Tq, Hq, Wd), q_map),
+        scratch_shapes=[
+            pltpu.VMEM((2, keys, Wd), pages.dtype),       # the two halves
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((Tq * Hq, Wd), jnp.float32),       # acc
+            pltpu.VMEM((Tq * Hq, 128), jnp.float32),      # running max
+            pltpu.VMEM((Tq * Hq, 128), jnp.float32),      # running denom
+        ],
+    )
+    block = Tq * Hq * Wd
+    out = pl.pallas_call(
+        functools.partial(_mla_ragged_prefill_kernel, page_size=ps,
+                          pages_per_block=ppb, tile_k=tile_k),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((W, Hq, Wd), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            # the query and output blocks twice, the accumulator and the
+            # float32 temporaries of a fold of its size, the key halves
+            vmem_limit_bytes=(4 * block * q.dtype.itemsize + 4 * block * 4
+                              + 6 * Tq * Hq * 128 * 4
+                              + 2 * keys * Wd * 2 + (8 << 20))),
+        name="mla_ragged_prefill_attention",
+        interpret=interpret,
+    )(row_tables.astype(jnp.int32), starts.astype(jnp.int32),
+      lens.astype(jnp.int32), prefix_lens.astype(jnp.int32), q, sfx, pages)
+    return out[:n_tok]

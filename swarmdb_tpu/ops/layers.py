@@ -828,3 +828,131 @@ if os.environ.get("SWARMDB_KERNCHECK", "0") == "1":
         paged_attention_dispatch)
     ragged_prefill_dispatch = checked_ragged_prefill_dispatch(
         ragged_prefill_dispatch)
+
+
+# ------------------------------------------------------- latent (MLA) pages
+#
+# A latent configuration (models/deepseek.py) keeps ONE row a token a layer,
+# ``[c_kv | k_pe]`` padded to the pool's width, with no heads axis and no
+# values beside it: pages are ``[P, ps, Wd]``. Attention over them is in the
+# ABSORBED form: a query head is ``[q_nope W_kvb^K | q_pe]`` times the
+# softmax scale, as wide as a row, so every head scores against the same
+# row, and the softmax-weighted sum of the rows themselves is the output
+# (its first ``kv_lora_rank`` lanes; the caller takes them through
+# ``W_kvb^V``). The dispatchers below follow the pattern of the GQA ones:
+# the Pallas kernel on a TPU (pages read in place, once), the dense XLA
+# form elsewhere.
+
+
+def latent_kernels_enabled() -> bool:
+    """Gate for BOTH latent kernels, decode and prefill: the kernels on a
+    TPU and where SWARMDB_PALLAS=1 asks for them (interpret mode, tests),
+    the dense XLA forms elsewhere. On a TPU the dense forms are refused
+    by name: each gathers every row's pages dense a call, the gather of
+    the pool that the decode step may never run (the engine asks here
+    when it is built, so SWARMDB_PALLAS=0 fails there and not in a
+    trace)."""
+    enabled = _ragged_prefill_kernel_enabled()
+    if not enabled and jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "latent pages are attended by the Pallas kernels on a TPU "
+            "(mla_paged_decode_attention_chunked, "
+            "mla_ragged_prefill_attention): with SWARMDB_PALLAS=0 the "
+            "dense XLA forms would gather every row's pages out of the "
+            "pool each step; they are for tests and CPU drives")
+    return enabled
+
+
+
+def latent_decode_attention_reference(
+    q: jnp.ndarray,           # [B, Hq, Wd] absorbed, scaled queries
+    pages: jnp.ndarray,       # [P, ps, Wd] FROZEN latent pool (or flat)
+    page_table: jnp.ndarray,  # [B, maxp]
+    chunk: jnp.ndarray,       # [B, Kc, Wd] this chunk's rows so far
+    starts: jnp.ndarray,      # [B] frozen prefix length (chunk start)
+    step: jnp.ndarray,        # scalar: index within the chunk
+) -> jnp.ndarray:
+    """Dense XLA form of ``mla_paged_decode_attention_chunked`` and its
+    off-TPU fallback: gathers each row's pages. Returns [B, Hq, Wd]."""
+    B, maxp = page_table.shape
+    ps = pages.shape[1]
+    rows = pages[page_table].reshape(B, maxp * ps, pages.shape[-1])
+    keys = jnp.concatenate([rows, chunk.astype(rows.dtype)], axis=1)
+    s = jnp.einsum("bhw,bkw->bhk", q, keys,
+                   preferred_element_type=jnp.float32)
+    valid = jnp.concatenate(
+        [jnp.arange(maxp * ps, dtype=jnp.int32)[None] < starts[:, None],
+         jnp.broadcast_to(jnp.arange(chunk.shape[1], dtype=jnp.int32)[None]
+                          <= step, (B, chunk.shape[1]))], axis=1)
+    p = jax.nn.softmax(jnp.where(valid[:, None], s, jnp.float32(-1e30)),
+                       axis=-1)
+    out = jnp.einsum("bhk,bkw->bhw", p.astype(keys.dtype), keys,
+                     preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def latent_decode_dispatch(q, pages, page_table, chunk, starts, step):
+    """Absorbed decode attention over the latent pool in place: each live
+    page read once a call. [B, Hq, Wd]."""
+    if latent_kernels_enabled():
+        from .attention_pallas import mla_paged_decode_attention_chunked
+
+        return mla_paged_decode_attention_chunked(
+            q, pages, page_table, chunk, starts, step,
+            interpret=jax.default_backend() != "tpu")
+    return latent_decode_attention_reference(q, pages, page_table, chunk,
+                                             starts, step)
+
+
+def latent_prefill_attention_reference(
+    q: jnp.ndarray,           # [W, Hq, Wd] absorbed, scaled query stream
+    sfx: jnp.ndarray,         # [W, Wd] the wave's own rows, stream order
+    pages: jnp.ndarray,       # [P, ps, Wd]
+    row_tables: jnp.ndarray,  # [R, maxp]
+    starts: jnp.ndarray,      # [R]
+    lens: jnp.ndarray,        # [R]
+    prefix_lens: jnp.ndarray,  # [R]
+    tok_row: jnp.ndarray,     # [W]
+) -> jnp.ndarray:
+    """Dense XLA form of ``mla_ragged_prefill_attention``: every token
+    against its own row's cached rows (gathered dense) and its row's
+    earlier tokens of the stream, one softmax. Materializes [W, Pt, Wd]:
+    for tests and CPU drives. Returns [W, Hq, Wd]."""
+    W = q.shape[0]
+    R, maxp = row_tables.shape
+    ps = pages.shape[1]
+    Pt = maxp * ps
+    row = jnp.clip(tok_row, 0, R - 1)
+    cached = pages[row_tables].reshape(R, Pt, pages.shape[-1])[row]
+    sfx = sfx.astype(pages.dtype)
+    s_p = jnp.einsum("whd,wpd->whp", q, cached,
+                     preferred_element_type=jnp.float32)
+    s_s = jnp.einsum("whd,xd->whx", q, sfx,
+                     preferred_element_type=jnp.float32)
+    x = jnp.arange(W, dtype=jnp.int32)
+    valid_p = (jnp.arange(Pt, dtype=jnp.int32)[None]
+               < prefix_lens[row][:, None])
+    valid_s = (tok_row[:, None] == tok_row[None, :]) & (x[None] <= x[:, None])
+    s = jnp.concatenate(
+        [jnp.where(valid_p[:, None], s_p, jnp.float32(-1e30)),
+         jnp.where(valid_s[:, None], s_s, jnp.float32(-1e30))], axis=-1)
+    p = jax.nn.softmax(s, axis=-1)
+    out = jnp.einsum("whp,wpd->whd", p[..., :Pt].astype(pages.dtype), cached,
+                     preferred_element_type=jnp.float32)
+    out = out + jnp.einsum("whx,xd->whd", p[..., Pt:].astype(sfx.dtype), sfx,
+                           preferred_element_type=jnp.float32)
+    return out.astype(q.dtype)
+
+
+def latent_prefill_dispatch(q, sfx, pages, row_tables, starts, lens,
+                            prefix_lens, tok_row):
+    """Absorbed attention of a packed wave over the latent pool in place
+    and over the wave's own rows. [W, Hq, Wd]."""
+    if latent_kernels_enabled():
+        from .attention_pallas import mla_ragged_prefill_attention
+
+        return mla_ragged_prefill_attention(
+            q, sfx, pages, row_tables, starts, lens, prefix_lens,
+            interpret=jax.default_backend() != "tpu")
+    return latent_prefill_attention_reference(
+        q, sfx, pages, row_tables, starts, lens, prefix_lens, tok_row)

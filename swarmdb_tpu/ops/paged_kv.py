@@ -87,6 +87,17 @@ class QuantPool(NamedTuple):
     scale: jnp.ndarray
 
 
+class NoValuePool(NamedTuple):
+    """What a latent cache (``models/deepseek.py``) holds under ``"v"``:
+    nothing. Its one pool of rows ``[L, P, ps, Wd]`` sits under ``"k"``
+    (every head's keys, and its first lanes the values). A page format is
+    a type here, as ``QuantPool`` is: ``llama.init_paged_cache`` decides
+    it once from the configuration, the helpers below take the latent path
+    for this type alone, and a value pool that is None by a fault fails
+    where it is used. An empty pytree, so the engine's programs hand it on
+    as they hand on values and donate or write nothing for it."""
+
+
 def kv_dtype_name() -> str:
     """Resolve SWARMDB_KV_DTYPE (default ``bf16`` — today's pool dtype,
     bit-identical with the flag unset)."""
@@ -135,6 +146,8 @@ def pool_flat(pool: Any) -> Any:
     """Flatten the leading (L, P) axes to one L*P page axis — the view
     the ragged/prefix forwards address with per-layer table offsets. A
     reshape on both payload and scales, never a copy."""
+    if isinstance(pool, NoValuePool):
+        return pool
     if isinstance(pool, QuantPool):
         d, s = pool.data, pool.scale
         return QuantPool(d.reshape((-1,) + d.shape[2:]),
@@ -159,6 +172,8 @@ def pool_page_bytes(pool: Any) -> int:
     rows included — prices swarmmem's warm-tier H2D model (a page's
     admission moves its slot in every layer). Accepts [L, P, ...] or
     single-layer [P, ...] pools; the divisor is always the page axis."""
+    if isinstance(pool, NoValuePool):
+        return 0
     if isinstance(pool, QuantPool):
         pages = int(pool.data.shape[-4])
         return (pool.data.nbytes + pool.scale.nbytes) // max(1, pages)
@@ -290,7 +305,8 @@ def canary_fill(k_pages: Any, v_pages: Any,
         return k_pages, v_pages
     fv = value if value is not None else canary_for(k_pages.dtype)
     k_pages = k_pages.at[:, ids].set(fv)
-    v_pages = v_pages.at[:, ids].set(fv)
+    if not isinstance(v_pages, NoValuePool):
+        v_pages = v_pages.at[:, ids].set(fv)
     return k_pages, v_pages
 
 
@@ -322,7 +338,8 @@ def canary_check(k_pages: Any, v_pages: Any,
         return bad
     fv = value if value is not None else canary_for(k_pages.dtype)
     kc = np.asarray(jax.device_get(k_pages[:, ids]))
-    vc = np.asarray(jax.device_get(v_pages[:, ids]))
+    vc = kc if isinstance(v_pages, NoValuePool) else np.asarray(
+        jax.device_get(v_pages[:, ids]))
     bad = []
     for i, p in enumerate(ids):
         if not (np.all(kc[:, i] == fv) and np.all(vc[:, i] == fv)):
@@ -597,6 +614,9 @@ def paged_write_chunk(
                 pool.scale.at[:, pf].set(
                     s.reshape((L, B * npc) + s.shape[3:]))))
         return out[0], out[1]
+    if isinstance(v_pages, NoValuePool):
+        return _latent_write_chunk(k_pages, chunk_k, start_positions,
+                                   page_table), v_pages
     pos = start_positions[:, None] + jnp.arange(Kc, dtype=jnp.int32)[None, :]
     col = jnp.minimum(pos // ps, maxp - 1)
     page = jnp.take_along_axis(page_table, col, axis=1)   # [B, Kc]
@@ -631,6 +651,9 @@ def paged_write_ragged(
     if isinstance(k_pages, QuantPool):
         return _paged_write_ragged_quant(
             k_pages, v_pages, sfx_k, sfx_v, tok_row, tok_pos, row_tables)
+    if isinstance(v_pages, NoValuePool):
+        return _latent_write_ragged(k_pages, sfx_k, tok_row, tok_pos,
+                                    row_tables), v_pages
     col = jnp.clip(tok_pos // ps, 0, maxp - 1)
     row = jnp.clip(tok_row, 0, R - 1)
     page = row_tables[row, col]                          # [W]
@@ -640,6 +663,75 @@ def paged_write_ragged(
     k_pages = k_pages.at[:, page, off].set(sfx_k.astype(k_pages.dtype))
     v_pages = v_pages.at[:, page, off].set(sfx_v.astype(v_pages.dtype))
     return k_pages, v_pages
+
+
+# A latent pool (models/deepseek.py) is ``[L, P, ps, Wd]``: a token's row
+# is one sublane of a page's tile, and the chip's compiler will not scatter
+# below a tile in place: handed ``pool.at[:, page, off].set(rows)`` it
+# copies the whole pool into a layout with the layer axis beside the lanes
+# (padded 9 to 16: 3.44 GB beside a 1.93 GB pool, in every program; the AOT
+# rehearsal of PR 44), writes there and copies back. So both writes below
+# go by whole pages, as the int8 pool's requant windows do: the pages a
+# write touches are gathered, the new rows laid into them, and the pages
+# set back whole. A page untouched by the write names trash page 0.
+
+
+def _latent_write_chunk(pool, chunk, start_positions, page_table):
+    """A finished decode chunk's rows ``[L, B, Kc, Wd]`` into a latent
+    pool: each lane's chunk spans at most ``ceil((ps - 1 + Kc) / ps)``
+    page columns from ``start // ps``."""
+    L, _, ps, Wd = pool.shape
+    B, maxp = page_table.shape
+    Kc = chunk.shape[2]
+    npc = min(maxp, (Kc + 2 * ps - 2) // ps)
+    start = start_positions.astype(jnp.int32)
+    cols = (jnp.clip(start // ps, 0, maxp - 1)[:, None]
+            + jnp.arange(npc, dtype=jnp.int32))                  # [B, npc]
+    page = jnp.take_along_axis(page_table, jnp.clip(cols, 0, maxp - 1),
+                               axis=1)
+    touched = (cols < maxp) & (cols * ps < (start + Kc)[:, None])
+    page = jnp.where(touched, page, 0)                           # -> trash
+    slot_pos = cols[..., None] * ps + jnp.arange(ps, dtype=jnp.int32)
+    t = slot_pos - start[:, None, None]                          # chunk index
+    is_new = (t >= 0) & (t < Kc) & (slot_pos < maxp * ps)
+    new = chunk[:, jnp.arange(B)[:, None, None], jnp.clip(t, 0, Kc - 1)]
+    merged = jnp.where(is_new[None, ..., None], new.astype(pool.dtype),
+                       pool[:, page])                 # [L, B, npc, ps, Wd]
+    return pool.at[:, page.reshape(-1)].set(
+        merged.reshape(L, B * npc, ps, Wd))
+
+
+def _latent_write_ragged(pool, sfx, tok_row, tok_pos, row_tables):
+    """A packed wave's rows ``[L, W, Wd]`` into a latent pool. A row's
+    tokens are consecutive in the stream at consecutive positions, so the
+    stream falls into runs, one a (row, page): a run begins at a live
+    token that opens its row or a page; there are at most ``W // ps + R``
+    of them. A run's page is gathered, the run laid into it from its
+    first token's offset, and the page set back."""
+    L, _, ps, Wd = pool.shape
+    R, maxp = row_tables.shape
+    W = tok_row.shape[0]
+    live = (tok_row >= 0) & (tok_row < R) & (tok_pos < maxp * ps)
+    before = jnp.pad(tok_row, (1, 0), constant_values=-1)[:W]
+    begins = live & ((before != tok_row) | (tok_pos % ps == 0)
+                     | ~jnp.pad(live, (1, 0))[:W])
+    run_of = jnp.cumsum(begins.astype(jnp.int32)) - 1            # [W]
+    S = W // ps + R
+    (first,) = jnp.nonzero(begins, size=S, fill_value=W)         # [S]
+    at = jnp.minimum(first, W - 1)
+    page = jnp.where(
+        first < W,
+        row_tables[jnp.clip(tok_row[at], 0, R - 1),
+                   jnp.clip(tok_pos[at] // ps, 0, maxp - 1)], 0)
+    # page offset j of run s holds stream token first + j - off(first)
+    idx = (first[:, None] + jnp.arange(ps, dtype=jnp.int32)[None]
+           - (tok_pos[at] % ps)[:, None])                        # [S, ps]
+    idc = jnp.clip(idx, 0, W - 1)
+    mine = ((idx >= first[:, None]) & (idx < W) & live[idc]
+            & (run_of[idc] == jnp.arange(S, dtype=jnp.int32)[:, None]))
+    merged = jnp.where(mine[None, ..., None], sfx[:, idc].astype(pool.dtype),
+                       pool[:, page])                     # [L, S, ps, Wd]
+    return pool.at[:, page].set(merged)
 
 
 def _paged_write_ragged_quant(

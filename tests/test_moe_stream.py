@@ -223,3 +223,65 @@ def test_a_decode_chunk_reads_the_same_with_the_kernel(monkeypatch, dtype):
         # in bf16 steps of the state's largest entries
         step = 2.0 ** (np.floor(np.log2(np.abs(state[1]).max())) - 7)
         assert np.abs(state[0] - state[1]).max() <= 2 * step
+
+
+# ---- deepseek-v2's shape (PR 44): D 5120, F 1536, a held share of the
+# experts the router scores
+
+
+def _wide_layer(n_scored, n_held, seed=4):
+    d, f = 5120, 1536
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def draw(k, shape, fan_in):
+        return (jax.random.normal(k, shape, F32) / fan_in ** .5).astype(BF16)
+
+    return {"router": draw(ks[0], (d, n_scored), d),
+            "expert_bias": jnp.zeros((n_scored,), F32),
+            "w_gate": draw(ks[1], (n_held, d, f), d),
+            "w_up": draw(ks[2], (n_held, d, f), d),
+            "w_down": draw(ks[3], (n_held, f, d), f)}
+
+
+@pytest.mark.parametrize("tile_f", [None, 512],
+                         ids=["tile-of-1536-is-768", "three-tiles"])
+def test_the_kernel_equals_the_loop_at_deepseek_widths(monkeypatch, tile_f):
+    """D 5120, F 1536: ``tile_of`` gives 768 (two tiles an expert, where
+    lfm2's 1792 takes 896), and the down matmul's reduction is split as
+    at the tiny widths: within a bf16 step of the loop."""
+    assert moe_pallas.tile_of(1536) == 768 and moe_pallas.tile_of(1792) == 896
+    lp = _wide_layer(4, 4)
+    x = jax.random.normal(jax.random.PRNGKey(9), (8, 1, 5120),
+                          F32).astype(BF16)
+    (want, r_want), (got, r_got) = both(monkeypatch, x, lp, None,
+                                        tile_f=tile_f or moe_pallas.TILE_F)
+    np.testing.assert_array_equal(np.asarray(r_got), np.asarray(r_want))
+    assert bf16_steps_apart(got, want).max() <= 1
+
+
+@pytest.mark.parametrize("first", [0, 4], ids=["first-group", "second-group"])
+def test_a_held_share_streams_only_what_is_held(monkeypatch, first):
+    """The router scores 8 experts, the weights hold 4 of them from
+    ``first``: the kernel and the loop agree, a choice outside the held
+    ones is reported ``~e`` and reads no weight, and a call none of whose
+    choices is held returns zeros."""
+    lp = layer(BF16)
+    held = {**lp, **{k: lp[k][first:first + 4]
+                     for k in ("w_gate", "w_up", "w_down")}}
+    x = rows(BF16)
+
+    def run(kernel):
+        monkeypatch.setattr(moe_pallas, "takes", lambda *a: kernel)
+        return lfm2.moe_block(x, held, K, None, 0, held=(first, 4))
+
+    (want, r_want), (got, r_got) = run(False), run(True)
+    np.testing.assert_array_equal(np.asarray(r_got), np.asarray(r_want))
+    np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                  np.asarray(want, np.float32))
+    r = np.asarray(r_got)
+    experts = np.where(r < 0, ~r, r)
+    assert ((r >= 0) == ((experts >= first) & (experts < first + 4))).all()
+    assert (r < 0).any() and (r >= 0).any()
+    # the part of the uncut layer that these experts give
+    whole_gate = lfm2.moe_block(x, lp, K)[1]
+    np.testing.assert_array_equal(experts, np.asarray(whole_gate))
