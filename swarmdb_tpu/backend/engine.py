@@ -240,6 +240,65 @@ class _ResidentSession:
         self.consuming = False
 
 
+# on the resident FIFO, between a session's blocks: a request was queued
+# while the engine thread waits there, and its plan can be made now
+_PLAN_WAKE = object()
+
+
+class _AdmissionPlan:
+    """What admission has decided for the requests it took off the queue,
+    before anything is packed: each request's heap entry (a requeue puts
+    it back as it was), its slot and page-table row (paged; ``rows`` is
+    parallel to ``popped``), its prefix hits and their routing, and the
+    slots whose rows the device's table already holds. One round makes
+    one; the part of it made while a resident session still ran is held
+    on ``Engine._held_plan`` until the boundary's round takes it up."""
+    __slots__ = ("entries", "popped", "rows", "sent", "plans",
+                 "hit_routing", "resume_rows", "free")
+
+    def __init__(self) -> None:
+        self.entries: List[Tuple] = []
+        self.popped: List["GenRequest"] = []
+        self.rows: List[Tuple[int, np.ndarray]] = []
+        self.sent: set = set()
+        self.plans: Dict[int, Tuple] = {}        # slot -> (hits, chains)
+        self.hit_routing: Dict[int, List[Any]] = {}
+        self.resume_rows: Dict[int, np.ndarray] = {}
+        self.free: List[int] = []    # dense: paired with popped by position
+
+
+class _Retired:
+    """A retirement as ``_settle_retire`` left it for ``_deliver_retired``:
+    what the occupant's last callbacks and its records need, taken off the
+    slot, which admission may have filled again by then."""
+    __slots__ = ("req", "reason", "generated", "logprobs", "admitted_at",
+                 "first_token_at", "host_syncs", "routing",
+                 "routing_complete", "cached_parts")
+
+
+class _SlotEmit:
+    """What one slot takes of one block (``_settle_block``), for
+    ``_deliver_block``: the tokens to stream in order, whether the first
+    of them is its request's first (with what ``engine.first_token``
+    says of the admission), and its retirement if the block ends it."""
+    __slots__ = ("slot_id", "req", "tokens", "first", "retired")
+
+    def __init__(self, slot_id: int, req: "GenRequest") -> None:
+        self.slot_id = slot_id
+        self.req = req
+        self.tokens: List[int] = []
+        self.first: Optional[Tuple] = None
+        self.retired: Optional[_Retired] = None
+
+
+class _SettledBlock:
+    """One decode block after ``_settle_block``: the slots' state is the
+    block's, nothing of it has been told to anybody yet."""
+    __slots__ = ("snapshot", "emits", "live_rows", "n_live",
+                 "pages_reserved", "pages_written", "t_dispatch_ns",
+                 "t_begin_ns", "chunk", "stamp_ns", "settle_us")
+
+
 def _named_partial(fn: Callable, name: str, **kwargs) -> Callable:
     """``functools.partial(fn, **kwargs)`` with a ``__name__``: jax names
     a jitted partial's program ``jit__unknown``, which a trace cannot
@@ -976,9 +1035,20 @@ class Engine:
         self._resident_variants: Optional[Tuple[Any, ...]] = None
         self._resident: Optional[_ResidentSession] = None
         # callback thread -> engine thread, one entry a chunk, in order
-        # (None: the callback itself failed)
-        self._resident_fifo: "queue.SimpleQueue[Optional[_ResidentBlock]]" \
-            = queue.SimpleQueue()
+        # (None: the callback itself failed); between them ``_PLAN_WAKE``
+        # from ``submit()``, one a request queued while the engine thread
+        # waits here
+        self._resident_fifo: "queue.SimpleQueue[Any]" = queue.SimpleQueue()
+        # the boundary of two sessions (ISSUE 45). The plan for what was
+        # queued while a session still ran, made between its blocks
+        # (``_plan_ahead``): the boundary's round starts from it. Guarded
+        # by ``_cv`` (a vote counts it as queued, a cancel takes a request
+        # out of it). And the last block of a session that ended for
+        # work to admit, settled (``_settle_block``) and not yet
+        # delivered: the round's wave is dispatched first
+        # (``_deliver_pending``; the engine thread's alone).
+        self._held_plan: Optional[_AdmissionPlan] = None
+        self._undelivered: Optional[_SettledBlock] = None
         self._lane_busy = False
         self._host_sync_n = 0  # engine-LOCAL sync count (registry
         # counters are shared across lanes, so per-request deltas must
@@ -1031,6 +1101,9 @@ class Engine:
             # registered at 0, so a reader tells "no vote went stale"
             # from a program that does not count them
             self.metrics.counters["resident_votes_stale"].inc(0)
+            # requests whose plan was held when their round began; only
+            # an engine with resident sessions makes plans ahead
+            self.metrics.counters["admission_planned_ahead"].inc(0)
             self._resident_variants = tuple(
                 jax.jit(_named_partial(_decode_resident, name,
                                        use_filters=uf, assume_greedy=ag),
@@ -2827,6 +2900,13 @@ class Engine:
                  next(self._tiebreak), request),
             )
             self._cv.notify_all()
+            ses = self._resident
+            if ses is not None and ses.consuming:
+                # the engine thread waits on the FIFO between a session's
+                # blocks: wake it to plan this admission while the chunk
+                # runs (under _cv, as the session's end clears _resident:
+                # no wake is left behind for the next session)
+                self._resident_fifo.put(_PLAN_WAKE)
         return request.request_id
 
     def cancel(self, request_id: str) -> bool:
@@ -2848,6 +2928,15 @@ class Engine:
                     break
             else:
                 req = None
+            held = self._held_plan
+            if req is None and held is not None:
+                # planned ahead and still waiting for its round: it goes
+                # as a queued request goes, and gives back what the plan
+                # took for it
+                for j, r in enumerate(held.popped):
+                    if r.request_id == request_id:
+                        req = self._drop_planned(held, j)
+                        break
             if req is None:
                 if request_id in self._admitting:
                     # popped but not yet activated (prefill in flight, can
@@ -2902,6 +2991,7 @@ class Engine:
             t_wait = 0
             with self._cv:
                 while (not self._stop and not self._queue
+                       and self._held_plan is None
                        and not self._any_active() and not in_flight
                        and not self._chaos_pending()):
                     # idle engines must still beat or the supervisor
@@ -2918,7 +3008,14 @@ class Engine:
             if t_wait:
                 tracer.phase_end(t_wait, "engine.wait", cat="engine",
                                  args={"step": self._loop_step})
+            cs = self.chaos_step
+            if stopping or cs is not None:
+                # a boundary block still to be delivered: before the loop
+                # ends, and before a fault may end it
+                self._deliver_pending()
             if stopping:
+                # a plan made ahead goes back to the queue it came from
+                self._release_held_plan(requeue=True)
                 # drain dispatched chunks so their requests complete
                 # instead of hanging to their callers' timeouts — OUTSIDE
                 # the lock: processing blocks on the device and runs user
@@ -2931,7 +3028,6 @@ class Engine:
                         logger.exception("drain on stop failed")
                 in_flight.clear()
                 break
-            cs = self.chaos_step
             if cs is not None:
                 # fault-injection seam (backend/chaos.py): kill raises
                 # LaneKilled (BaseException — deliberately NOT caught by
@@ -2960,6 +3056,9 @@ class Engine:
                     self._flight_step(0)
                     if self._any_active():
                         self._run_resident()
+                    else:
+                        # a boundary block that no session follows
+                        self._deliver_pending()
                     continue
                 if self._any_active():
                     in_flight.append(self._dispatch_decode())
@@ -3197,18 +3296,24 @@ class Engine:
         now = time.time()
         expired: List[GenRequest] = []
         with self._cv:
-            if not self._queue:
-                return
-            keep = []
-            for item in self._queue:
-                req = item[3]
-                if req.deadline is not None and now > req.deadline:
-                    expired.append(req)
-                else:
-                    keep.append(item)
-            if expired:
-                self._queue[:] = keep
-                heapq.heapify(self._queue)
+            held = self._held_plan
+            if held is not None:
+                # planned ahead, not admitted yet: still a queued request
+                for j in reversed(range(len(held.popped))):
+                    req = held.popped[j]
+                    if req.deadline is not None and now > req.deadline:
+                        expired.append(self._drop_planned(held, j))
+            if self._queue:
+                keep = []
+                for item in self._queue:
+                    req = item[3]
+                    if req.deadline is not None and now > req.deadline:
+                        expired.append(req)
+                    else:
+                        keep.append(item)
+                if len(keep) < len(self._queue):
+                    self._queue[:] = keep
+                    heapq.heapify(self._queue)
         for req in expired:
             self.metrics.counters["requests_deadline_expired"].inc()
             if req.on_done is not None:
@@ -3320,6 +3425,120 @@ class Engine:
                 except Exception:
                     logger.exception("on_done callback failed")
 
+    # swarmlint: holds[self._cv]
+    def _waiting(self) -> int:
+        """Requests not admitted yet: the queue's, and those a plan made
+        ahead took off it, which still wait for their session to end."""
+        held = self._held_plan
+        return len(self._queue) + (len(held.popped) if held else 0)
+
+    # swarmlint: holds[self._cv]
+    def _drop_planned(self, plan: _AdmissionPlan, j: int) -> GenRequest:
+        """Take request ``j`` out of a held plan, under ``_cv``, and give
+        back what the plan took for it: its pinned hits, and its slot's
+        pages by way of the reclaim (as a retired slot's). Returns it;
+        what becomes of it (requeued, failed, cancelled) is the
+        caller's."""
+        req = plan.popped.pop(j)
+        plan.entries.pop(j)
+        slot_id, _row = plan.rows.pop(j)
+        hits, _chains = plan.plans.pop(slot_id, ((), None))
+        plan.hit_routing.pop(slot_id, None)
+        self._admitting.discard(req.request_id)
+        self._cancel_pending.discard(req.request_id)
+        self.paged.allocator.mark_retired(slot_id)
+        if hits:
+            self._prefix.unpin(hits)
+        return req
+
+    def _release_held_plan(self, requeue: bool) -> List[GenRequest]:
+        """Undo the plan made ahead, if one is held: every request gives
+        back its slot, pages and pins and, with ``requeue``, goes back on
+        the queue under the entry it had. Returns the requests."""
+        with self._cv:
+            plan, self._held_plan = self._held_plan, None
+            if plan is None:
+                return []
+            entries = list(plan.entries)
+            for j in reversed(range(len(plan.popped))):
+                self._drop_planned(plan, j)
+            if requeue:
+                for entry in entries:
+                    heapq.heappush(self._queue, entry)
+        return [entry[3] for entry in entries]
+
+    # swarmlint: hot
+    def _plan_ahead(self) -> None:
+        """Between two blocks of a resident session (``_PLAN_WAKE``): make
+        admission's plan for what is queued now, while the device runs a
+        chunk, so that the boundary's round finds it made. Only the
+        host's part: pops, slot choice, ``_prefix_plan`` with its pins,
+        page allocation. The table rows go with the boundary's reclaim,
+        the stamps (``admitted_at``, ``queue_wait_s``, ``engine.admit``,
+        ``engine_admitted``) where the wave is dispatched.
+
+        Only where the boundary's round would admit the same requests:
+        the gate is open and no tier drain or demotion is under way,
+        every queued request is a plain one (no kept pages to resume,
+        none past its deadline), the round still holds them all
+        (``prefill_batch``), each gets a slot that is free now and owns
+        no pages (a slot retired in this session waits for the reclaim),
+        and the pool covers all their worst-case pages. Else the queue
+        is left alone and the boundary decides, with the freed slots and
+        in priority order. A vote counts the held plan's requests as
+        queued (``_resident_vote``)."""
+        if (not self._queue  # swarmlint: disable=SWL301 -- a peek; the plan locks
+                or self.on_tier_drain is not None or self.paged is None
+                or not self._ragged_active()):
+            return
+        alloc = self.paged.allocator
+        now = time.time()
+        plan = None
+        n0 = 0
+        t_plan = self.tracer.phase_begin("engine.admission.plan")
+        try:
+            with self._cv:
+                plan = self._held_plan or _AdmissionPlan()
+                n0 = len(plan.popped)
+                queued = [item[3] for item in self._queue]
+                taken = {r[0] for r in plan.rows}
+                free = [i for i in self._free_slot_ids()
+                        if i not in taken and not alloc.owns(i)]
+                room = alloc.free_count() + (
+                    self._prefix.evictable_count()
+                    if self._prefix is not None else 0)
+                if (not queued or not self._gate_open_now()
+                        or len(queued) > len(free)
+                        or n0 + len(queued) > self.prefill_batch
+                        or any(r.resume_pages is not None
+                               or (r.deadline is not None
+                                   and now > r.deadline) for r in queued)
+                        or sum(alloc.pages_needed(
+                            len(r.prompt), r.sampling.max_new_tokens,
+                            self.decode_chunk) for r in queued) > room):
+                    return
+                self._plan_pass(plan, free, len(queued))
+                self._held_plan = plan
+        finally:
+            self.tracer.phase_end(
+                t_plan, "engine.admission.plan", cat="engine",
+                args=self._plan_phase_args(
+                    plan, early=True,
+                    planned=len(plan.popped) - n0 if plan is not None else 0))
+
+    def _gate_open_now(self) -> bool:
+        """Whether ``_backpressure_gate`` would let a round admit as the
+        pool stands, read without moving its hysteresis, firing a hook or
+        shedding: a plan made ahead may look, the boundary's round
+        decides."""
+        if self._bp_high >= 1.0:
+            return True
+        if self._bp_paused or self._tier_demoting:
+            return False
+        util = 1.0 - self._pool_headroom()
+        return util < self._bp_high and (
+            self.on_tier_pressure is None or util < self._bp_demote)
+
     def _admission_round(self) -> None:  # swarmlint: hot
         """One loop step's admission, as the phase ``engine.admission``
         (not ``engine.admit``, which is one request's wait in the queue).
@@ -3346,42 +3565,46 @@ class Engine:
         Groups are split by bucket so a short prompt co-admitted with a
         long one never pays the long bucket's O(T^2) attention (review
         finding); every popped request is still admitted this round.
+
+        A round that follows a resident session may find part of its plan
+        made (``_plan_ahead``, ``_held_plan``): it starts from that, plans
+        what arrived since into the same plan, and packs and dispatches
+        the whole as one round, so the waves are what one plan at the
+        boundary would have made.
         """
         self._age_queue()
         self._expire_deadlines()
         tracer = self.tracer
+        gate = True
         if self.paged:
             # reclaim retired slots' pages first: zero their table rows on
             # device (mirrored to pod workers), THEN return pages to the
             # pool (stale-table/reuse race)
             pending = self.paged.allocator.take_pending_frees()
+            with self._cv:
+                held = self._held_plan
             t_reclaim = (tracer.phase_begin("engine.admission.reclaim")
                          if pending or self.on_tier_drain is not None else 0)
-            if pending:
-                freed_pages: List[int] = []
-                if self._pagecheck is not None:
-                    for sid in pending:
-                        freed_pages.extend(
-                            self.paged.allocator.pages_for(sid))
-                try:
-                    self._mirrored(
-                        self.CALL_SET_PT_ROWS,
-                        np.asarray(pending, np.int32),
-                        np.zeros((len(pending),
-                                  self.paged.allocator.maxp), np.int32),
-                    )
-                except Exception:
-                    # dispatch failed before the rows were zeroed:
-                    # freeing would reopen the stale-table race,
-                    # dropping the drained batch would leak its pages
-                    # forever (swarmlint SWL801) — requeue and let the
-                    # engine's error recovery run, the next admission
-                    # round retries the reclaim
-                    self.paged.allocator.requeue_pending(pending)
-                    raise
-                self.paged.allocator.release_taken(pending)
-                if self._pagecheck is not None and freed_pages:
-                    self._pagecheck_poison(freed_pages)
+            freed_pages: List[int] = []
+            if self._pagecheck is not None:
+                for sid in pending:
+                    freed_pages.extend(self.paged.allocator.pages_for(sid))
+            try:
+                # one dispatch, if there is anything to write: a plan made
+                # ahead took slots that were free then, none of these, and
+                # its rows ride with the zeroing
+                self._send_table_rows(held, reclaim=pending)
+            except Exception:
+                # dispatch failed before the rows were zeroed: freeing
+                # would reopen the stale-table race, dropping the drained
+                # batch would leak its pages forever (swarmlint SWL801) —
+                # requeue and let the engine's error recovery run, the
+                # next admission round retries the reclaim
+                self.paged.allocator.requeue_pending(pending)
+                raise
+            self.paged.allocator.release_taken(pending)
+            if freed_pages:
+                self._pagecheck_poison(freed_pages)
             if self.on_tier_drain is not None:
                 # tiered KV (ISSUE 19): execute the tier worker's planned
                 # demotions here — the D2H gathers ride the flush wave
@@ -3394,235 +3617,43 @@ class Engine:
                              cat="engine",
                              args={"step": self._loop_step,
                                    "slots": len(pending)})
-            if not self._backpressure_gate():
+            # a held plan passed the gate when it was made: a gate that
+            # closes now holds back what arrived since, not the plan
+            gate = self._backpressure_gate()
+            if not gate and held is None:
                 return
         pressure_called = False
         while True:
             stale_resumes: List[GenRequest] = []
-            pressure_need = 0
+            pressure_need = ahead = 0
+            plan = None
             # the phase opens before the lock is taken: a wait for _cv is
             # part of what a plan costs
-            popped: List[GenRequest] = []
-            plans: Dict[int, Tuple] = {}
-            # routed configurations: slot -> the routing rows its hit
-            # pages were registered with (the head of its record)
-            hit_routing: Dict[int, List[Any]] = {}
-            routed = self._routed is not None
-            stateful = self._stateful
-            forgone = 0      # hit pages recomputed for want of their state
             t_plan = tracer.phase_begin("engine.admission.plan")
             try:
                 with self._cv:
-                    free = self._free_slot_ids()
-                    take = min(len(free), len(self._queue), self.prefill_batch)
-                    if take == 0:
-                        return
-                    if self.paged:
-                        # admit in priority order while the pool covers each
-                        # request's worst-case page footprint; stop at the first
-                        # that doesn't fit (no skip-ahead: prevents starvation
-                        # of long prompts behind a stream of short ones). With
-                        # the prefix cache, hit pages are pinned and referenced
-                        # in place; only the remainder needs fresh pages, and
-                        # LRU cache pages are evicted into the free list when
-                        # the pool runs short.
-                        popped = []
-                        rows = []
-                        plans = {}
-                        hit_routing = {}
-                        use_pp = self._prefix is not None
-                        resume_rows: Dict[int, np.ndarray] = {}
-                        # candidates = ALL free slots (the wave-size cap
-                        # bounds how many ADMIT, not which slots are
-                        # eligible — free[:take] would pre-pick slots
-                        # positionally and defeat the shard-hint search)
-                        remaining = list(free)
-                        admitted = 0
-                        n_sh = getattr(self.paged.allocator, "n_shards", 1)
-                        while remaining and self._queue and admitted < take:
-                            req = self._queue[0][3]
-                            if (req.resume_pages is not None
-                                    and req.resume_epoch is not None
-                                    and req.resume_epoch
-                                    != self.paged.allocator.generation):
-                                # re-validate the resume epoch at ADMISSION,
-                                # not just submit (ADVICE r4 #2): a pool
-                                # reset while the request sat queued makes
-                                # its page ids dangling aliases. No slot is
-                                # consumed by a stale pop.
-                                heapq.heappop(self._queue)
-                                stale_resumes.append(req)
-                                continue
-                            # slot choice: honor the request's shard hint
-                            # when its shard still has a free slot, so a
-                            # conversation's turns land where its cached
-                            # prefix pages live (same-shard-only reuse).
-                            # Unhinted prefix-eligible requests get a
-                            # CONTENT-affine default — a stable hash of the
-                            # first page of tokens — so identical prefixes
-                            # collide on one shard (cross-request reuse)
-                            # while distinct prompts still spread.
-                            slot_id = None
-                            hint = req.shard_hint
-                            if (hint is None and n_sh > 1 and use_pp
-                                    and len(req.prompt) >= self._prefix_ps
-                                    and not req.keep_pages):
-                                hint = zlib.crc32(np.asarray(
-                                    req.prompt[:self._prefix_ps],
-                                    np.int32).tobytes())
-                            if hint is not None and n_sh > 1:
-                                h = hint % n_sh
-                                for j, sid in enumerate(remaining):
-                                    if self.paged.allocator.shard_of(sid) == h:
-                                        slot_id = remaining.pop(j)
-                                        break
-                            if slot_id is None:
-                                slot_id = remaining.pop(0)
-                            if req.resume_pages is not None:
-                                # rolling-KV continuation: the kept pages are
-                                # referenced (caller custody); only the part
-                                # past resume_len needs fresh pages
-                                ps_ = self.paged.page_size
-                                worst = min(
-                                    self.paged.allocator.max_seq,
-                                    req.resume_len + len(req.prompt)
-                                    + req.sampling.max_new_tokens
-                                    + self.decode_chunk,
-                                )
-                                total = -(-worst // ps_)
-                                n_fresh = max(0,
-                                              total - len(req.resume_pages))
-                                row = self.paged.allocator.allocate_with_prefix(
-                                    slot_id, req.resume_pages, n_fresh)
-                                if row is None:
-                                    pressure_need = n_fresh
-                                    break  # pool exhausted; retry later
-                                heapq.heappop(self._queue)
-                                self._admitting.add(req.request_id)
-                                popped.append(req)
-                                rows.append((slot_id, row))
-                                resume_rows[slot_id] = row
-                                admitted += 1
-                                continue
-                            need = self.paged.allocator.pages_needed(
-                                len(req.prompt), req.sampling.max_new_tokens,
-                                self.decode_chunk,
-                            )
-                            row = None
-                            hits: List[int] = []
-                            chains: List[bytes] = []
-                            for attempt in range(2):
-                                hits, chains = [], []
-                                hit_rows = [] if routed else None
-                                hit_states = [] if stateful else None
-                                # keep_pages (rolling) requests bypass the
-                                # hash prefix cache both ways: a hit would
-                                # reference cache-custody pages that
-                                # retirement cannot hand to the caller, and
-                                # registration would steal the slot's own
-                                # pages INTO cache custody
-                                if (use_pp and len(req.prompt) >= self._prefix_ps
-                                        and not req.keep_pages):
-                                    hits, chains = self._prefix_plan(
-                                        req.prompt, pin=True,
-                                        routing=hit_rows, states=hit_states)
-                                    if stateful:
-                                        # a row can start only where the
-                                        # state it resumes from was kept:
-                                        # the match is cut back to the
-                                        # deepest page that has its state
-                                        # and the rest is computed again
-                                        keep = max((i + 1 for i, st in
-                                                    enumerate(hit_states)
-                                                    if st is not None),
-                                                   default=0)
-                                        if keep < len(hits):
-                                            forgone += len(hits) - keep
-                                            self._prefix.unpin(hits[keep:])
-                                            hits = hits[:keep]
-                                    # DP-sharded pool: a slot can only
-                                    # reference pages of its own shard (the
-                                    # shard_map'd decode addresses its local
-                                    # sub-pool); truncate foreign-shard hits
-                                    keep = self.paged.allocator.usable_prefix(
-                                        slot_id, hits)
-                                    if keep < len(hits):
-                                        self._prefix.unpin(hits[keep:])
-                                        hits = hits[:keep]
-                                row = self._paged_allocate(
-                                    slot_id, hits, max(0, need - len(hits)))
-                                if row is not None:
-                                    break
-                                if hits:
-                                    self._prefix.unpin(hits)
-                                # the hint is ADVISORY (review r5): a hinted
-                                # shard whose sub-pool cannot cover the
-                                # request must not head-of-line-block the 7
-                                # healthy shards — retry once on the
-                                # freest-pooled other free slot
-                                if (attempt == 0 and hint is not None
-                                        and n_sh > 1 and remaining):
-                                    remaining.append(slot_id)  # still free
-                                    alt = max(remaining,
-                                              key=self.paged.allocator.free_count)
-                                    remaining.remove(alt)
-                                    slot_id = alt
-                                    continue
-                                break
-                            if row is None:
-                                pressure_need = max(0, need - len(hits))
-                                break  # pool exhausted; retry after retirements
-                            heapq.heappop(self._queue)
-                            self._admitting.add(req.request_id)
-                            popped.append(req)
-                            rows.append((slot_id, row))
-                            admitted += 1
-                            if (use_pp and len(req.prompt) >= self._prefix_ps
-                                    and not req.keep_pages):
-                                plans[slot_id] = (hits, chains)
-                                if routed:
-                                    hit_routing[slot_id] = \
-                                        hit_rows[:len(hits)]
-                    else:
-                        resume_rows = {}
-                        popped = []
-                        for _ in range(take):
-                            if not self._queue:
-                                break
-                            req = self._queue[0][3]
-                            if (req.resume_pages is not None
-                                    and req.resume_epoch is not None
-                                    and req.resume_epoch != self.pool_epoch()):
-                                # dense rolling resume planned against a pool
-                                # that has since been rebuilt (same race as
-                                # the paged branch above)
-                                heapq.heappop(self._queue)
-                                stale_resumes.append(req)
-                                continue
-                            heapq.heappop(self._queue)
-                            popped.append(req)
-                        self._admitting.update(r.request_id for r in popped)
+                    plan, self._held_plan = (
+                        self._held_plan or _AdmissionPlan(), None)
+                    ahead = len(plan.popped)
+                    if gate:
+                        taken = {r[0] for r in plan.rows}
+                        free = [i for i in self._free_slot_ids()
+                                if i not in taken]
+                        take = min(len(free), len(self._queue),
+                                   self.prefill_batch - ahead)
+                        if take > 0:
+                            stale_resumes, pressure_need = (
+                                self._plan_pass(plan, free, take))
+                        elif not ahead:
+                            return
             finally:
-                # cached: prefix-cache hits (whole pages) and the kept
-                # tokens of rolling continuations, whose prompt is the
-                # new part only
-                hit = (self._prefix_ps * sum(len(plan[0])
-                                             for plan in plans.values())
-                       if plans else 0)
-                if forgone:
-                    self.metrics.counters["prefix_state_forgone_tokens"
-                                          ].inc(forgone * self._prefix_ps)
+                if ahead:
+                    self.metrics.counters["admission_planned_ahead"
+                                          ].inc(ahead)
                 tracer.phase_end(
                     t_plan, "engine.admission.plan", cat="engine",
-                    args={"step": self._loop_step, "rows": len(popped),
-                          "cached_tokens": hit + sum(r.resume_len
-                                                     for r in popped),
-                          "new_tokens": sum(len(r.prompt)
-                                            for r in popped) - hit,
-                          # rows seeded from a cached page's state
-                          "state_rows": sum(bool(plan[0])
-                                            for plan in plans.values())
-                          if stateful else 0})
+                    args=self._plan_phase_args(plan, ahead=ahead))
+            popped = plan.popped
             # outside the lock: fire callbacks / the pressure hook (either
             # may re-enter submit() or take the serving layer's locks)
             for req in stale_resumes:
@@ -3632,7 +3663,7 @@ class Engine:
                         req.on_done(req.request_id, [], "stale_resume")
                     except Exception:
                         logger.exception("on_done callback failed")
-            if self.paged and not popped:
+            if not popped:
                 if (pressure_need > 0 and not pressure_called
                         and self.on_pool_pressure is not None):
                     # ONE eviction attempt per admission round: the hook
@@ -3648,193 +3679,444 @@ class Engine:
                 if stale_resumes:
                     continue  # stale pops may have unblocked the queue head
                 return
-            if self.paged and rows and self._pagecheck is not None:
-                # sanitizer: stamp owners, then verify the canary of
-                # every re-allocated page is still intact — an
-                # overwritten canary is a write-after-free landing
-                # between free and re-allocation
-                for (sid, _row), req in zip(rows, popped):
-                    self._pagecheck_admit(sid, req)
-            if self.paged and rows:
-                self._mirrored(
-                    self.CALL_SET_PT_ROWS,
-                    np.asarray([r[0] for r in rows], np.int32),
-                    np.stack([r[1] for r in rows]).astype(np.int32),
+            self._dispatch_plan(plan)
+            if not gate:
+                return
+
+    # swarmlint: hot
+    def _plan_phase_args(self, plan: Optional[_AdmissionPlan],
+                         **more: Any) -> Dict[str, Any]:
+        """The arguments of an ``engine.admission.plan`` phase over
+        ``plan`` as it stands (the boundary's: the whole round's plan,
+        ``ahead`` of its rows taken from the held one; ``early``: the
+        held plan so far)."""
+        popped = plan.popped if plan is not None else []
+        plans = plan.plans if plan is not None else {}
+        # cached: prefix-cache hits (whole pages) and the kept tokens of
+        # rolling continuations, whose prompt is the new part only
+        hit = (self._prefix_ps * sum(len(p[0]) for p in plans.values())
+               if plans else 0)
+        return {"step": self._loop_step, "rows": len(popped),
+                "cached_tokens": hit + sum(r.resume_len for r in popped),
+                "new_tokens": sum(len(r.prompt) for r in popped) - hit,
+                # rows seeded from a cached page's state
+                "state_rows": sum(bool(p[0]) for p in plans.values())
+                if self._stateful else 0, **more}
+
+    # swarmlint: hot
+    # swarmlint: holds[self._cv]
+    def _plan_pass(self, plan: _AdmissionPlan, free: List[int],
+                   take: int) -> Tuple[List[GenRequest], int]:
+        """Admission's plan, under ``_cv``: pop up to ``take`` requests in
+        priority order into ``plan``, each with a slot of ``free`` and
+        (paged) its prefix hits pinned and its pages allocated. Returns
+        the stale resumes it popped without a slot and the pages the head
+        request lacked where the pool stopped it."""
+        entries, popped, rows = plan.entries, plan.popped, plan.rows
+        plans, hit_routing = plan.plans, plan.hit_routing
+        resume_rows = plan.resume_rows
+        stale_resumes: List[GenRequest] = []
+        pressure_need = forgone = 0
+        # routed configurations: slot -> the routing rows its hit pages
+        # were registered with (the head of its record)
+        routed = self._routed is not None
+        stateful = self._stateful
+        if self.paged:
+            # admit in priority order while the pool covers each
+            # request's worst-case page footprint; stop at the first
+            # that doesn't fit (no skip-ahead: prevents starvation
+            # of long prompts behind a stream of short ones). With
+            # the prefix cache, hit pages are pinned and referenced
+            # in place; only the remainder needs fresh pages, and
+            # LRU cache pages are evicted into the free list when
+            # the pool runs short.
+            use_pp = self._prefix is not None
+            # candidates = ALL free slots (the wave-size cap
+            # bounds how many ADMIT, not which slots are
+            # eligible — free[:take] would pre-pick slots
+            # positionally and defeat the shard-hint search)
+            remaining = list(free)
+            admitted = 0
+            n_sh = getattr(self.paged.allocator, "n_shards", 1)
+            while remaining and self._queue and admitted < take:
+                req = self._queue[0][3]
+                if (req.resume_pages is not None
+                        and req.resume_epoch is not None
+                        and req.resume_epoch
+                        != self.paged.allocator.generation):
+                    # re-validate the resume epoch at ADMISSION,
+                    # not just submit (ADVICE r4 #2): a pool
+                    # reset while the request sat queued makes
+                    # its page ids dangling aliases. No slot is
+                    # consumed by a stale pop.
+                    heapq.heappop(self._queue)
+                    stale_resumes.append(req)
+                    continue
+                # slot choice: honor the request's shard hint
+                # when its shard still has a free slot, so a
+                # conversation's turns land where its cached
+                # prefix pages live (same-shard-only reuse).
+                # Unhinted prefix-eligible requests get a
+                # CONTENT-affine default — a stable hash of the
+                # first page of tokens — so identical prefixes
+                # collide on one shard (cross-request reuse)
+                # while distinct prompts still spread.
+                slot_id = None
+                hint = req.shard_hint
+                if (hint is None and n_sh > 1 and use_pp
+                        and len(req.prompt) >= self._prefix_ps
+                        and not req.keep_pages):
+                    hint = zlib.crc32(np.asarray(
+                        req.prompt[:self._prefix_ps],
+                        np.int32).tobytes())
+                if hint is not None and n_sh > 1:
+                    h = hint % n_sh
+                    for j, sid in enumerate(remaining):
+                        if self.paged.allocator.shard_of(sid) == h:
+                            slot_id = remaining.pop(j)
+                            break
+                if slot_id is None:
+                    slot_id = remaining.pop(0)
+                if req.resume_pages is not None:
+                    # rolling-KV continuation: the kept pages are
+                    # referenced (caller custody); only the part
+                    # past resume_len needs fresh pages
+                    ps_ = self.paged.page_size
+                    worst = min(
+                        self.paged.allocator.max_seq,
+                        req.resume_len + len(req.prompt)
+                        + req.sampling.max_new_tokens
+                        + self.decode_chunk,
+                    )
+                    total = -(-worst // ps_)
+                    n_fresh = max(0,
+                                  total - len(req.resume_pages))
+                    row = self.paged.allocator.allocate_with_prefix(
+                        slot_id, req.resume_pages, n_fresh)
+                    if row is None:
+                        pressure_need = n_fresh
+                        break  # pool exhausted; retry later
+                    entries.append(heapq.heappop(self._queue))
+                    self._admitting.add(req.request_id)
+                    popped.append(req)
+                    rows.append((slot_id, row))
+                    resume_rows[slot_id] = row
+                    admitted += 1
+                    continue
+                need = self.paged.allocator.pages_needed(
+                    len(req.prompt), req.sampling.max_new_tokens,
+                    self.decode_chunk,
                 )
-            if self.paged:
-                # warm-tier promotions (ISSUE 19): bulk-insert the host
-                # payload into the freshly reserved resume pages BEFORE
-                # the resume prefill reads them. Engine thread only —
-                # the pools are donated by the prefill jits below.
-                for req in popped:
-                    if req.promote_payload is not None:
-                        self._promote_insert(req)
-            use_prefix = self._prefix is not None
-            ragged = self.paged is not None and self._ragged_active()
-            row_by_slot = dict(rows) if self.paged else {}
-            groups: Dict[Tuple[Any, int], List[Tuple]] = {}
-            ragged_batch: List[Tuple] = []
-            prefix_batch: List[Tuple] = []
-            resume_batch: List[Tuple] = []
-            max_suffix = max_hits = 0
-            # paged pops can SKIP a slot (stale resume popped without
-            # consuming it), so pair each request with the slot recorded
-            # at its allocation, not positionally with `free`
-            slot_ids = ([r[0] for r in rows] if self.paged
-                        else free[:len(popped)])
-            for slot_id, req in zip(slot_ids, popped):
-                slot = self.slots[slot_id]
-                slot.cached_tokens = req.resume_len
-                slot.new_tokens = len(req.prompt)
-                if routed:
-                    # the record starts with what the pool really holds
-                    # for this row: its hit pages' rows. Kept pages of a
-                    # rolling resume come without theirs (their custody is
-                    # the caller's: service registry, tiers, fleet transit)
-                    slot.routing = hit_routing.get(slot_id, [])
-                    slot.cached_parts = len(slot.routing)
-                    slot.routing_complete = req.resume_pages is None
-                slot.table_row = row_by_slot.get(slot_id)
-                slot.row_pages = (
-                    int(np.count_nonzero(slot.table_row))
-                    if self.paged else 0)
-                if slot_id in resume_rows:
-                    resume_batch.append((slot_id, req, resume_rows[slot_id]))
-                    continue
-                if ragged:
-                    # packed ragged waves absorb BOTH the plain and the
-                    # prefix-planned rows (a cache hit is just a nonzero
-                    # prefix_len descriptor); resume rows keep the
-                    # bucketed path (mid-page custody bookkeeping)
-                    if use_prefix and slot_id in plans:
-                        hits, chains = plans[slot_id]
-                    else:
-                        hits, chains = [], None
-                    slot.cached_tokens = len(hits) * self.paged.page_size
-                    slot.new_tokens -= slot.cached_tokens
-                    ragged_batch.append((slot_id, req, hits, chains,
-                                         row_by_slot[slot_id]))
-                    continue
-                if not self.paged and req.resume_pages is not None:
-                    # dense rolling resume: kept prefix-pool pages compose
-                    # into the lane (no row-table — the lane IS the slot)
-                    resume_batch.append((slot_id, req, None))
-                    continue
-                # sub-page prompts (no hit possible, nothing to register)
-                # stay on the plain path; everything else goes through the
-                # prefix path even on a full miss so its pages get
-                # REGISTERED for the next turn. Paged requests were
-                # matched (and pinned) during the pop loop above —
-                # matching again would double-pin — so route on the plan's
-                # existence there.
-                if self.paged and self._prefix is not None:
-                    planned = slot_id in plans
-                else:
-                    planned = (use_prefix
-                               and len(req.prompt) >= self._prefix_ps)
-                if planned:
-                    if self.paged:
-                        hits, chains = plans[slot_id]
-                    else:
+                row = None
+                hits: List[int] = []
+                chains: List[bytes] = []
+                for attempt in range(2):
+                    hits, chains = [], []
+                    hit_rows = [] if routed else None
+                    hit_states = [] if stateful else None
+                    # keep_pages (rolling) requests bypass the
+                    # hash prefix cache both ways: a hit would
+                    # reference cache-custody pages that
+                    # retirement cannot hand to the caller, and
+                    # registration would steal the slot's own
+                    # pages INTO cache custody
+                    if (use_pp and len(req.prompt) >= self._prefix_ps
+                            and not req.keep_pages):
                         hits, chains = self._prefix_plan(
-                            req.prompt, routing=slot.routing)
-                        slot.cached_parts = len(hits)
-                    suffix_len = len(req.prompt) - len(hits) * self._prefix_ps
-                    slot.cached_tokens = len(hits) * self._prefix_ps
-                    slot.new_tokens = suffix_len
-                    prefix_batch.append((slot_id, req, hits, chains))
-                    max_suffix = max(max_suffix, suffix_len)
-                    max_hits = max(max_hits, len(hits))
+                            req.prompt, pin=True,
+                            routing=hit_rows, states=hit_states)
+                        if stateful:
+                            # a row can start only where the
+                            # state it resumes from was kept:
+                            # the match is cut back to the
+                            # deepest page that has its state
+                            # and the rest is computed again
+                            keep = max((i + 1 for i, st in
+                                        enumerate(hit_states)
+                                        if st is not None),
+                                       default=0)
+                            if keep < len(hits):
+                                forgone += len(hits) - keep
+                                self._prefix.unpin(hits[keep:])
+                                hits = hits[:keep]
+                        # DP-sharded pool: a slot can only
+                        # reference pages of its own shard (the
+                        # shard_map'd decode addresses its local
+                        # sub-pool); truncate foreign-shard hits
+                        keep = self.paged.allocator.usable_prefix(
+                            slot_id, hits)
+                        if keep < len(hits):
+                            self._prefix.unpin(hits[keep:])
+                            hits = hits[:keep]
+                    row = self._paged_allocate(
+                        slot_id, hits, max(0, need - len(hits)))
+                    if row is not None:
+                        break
+                    if hits:
+                        self._prefix.unpin(hits)
+                    # the hint is ADVISORY (review r5): a hinted
+                    # shard whose sub-pool cannot cover the
+                    # request must not head-of-line-block the 7
+                    # healthy shards — retry once on the
+                    # freest-pooled other free slot
+                    if (attempt == 0 and hint is not None
+                            and n_sh > 1 and remaining):
+                        remaining.append(slot_id)  # still free
+                        alt = max(remaining,
+                                  key=self.paged.allocator.free_count)
+                        remaining.remove(alt)
+                        slot_id = alt
+                        continue
+                    break
+                if row is None:
+                    pressure_need = max(0, need - len(hits))
+                    break  # pool exhausted; retry after retirements
+                entries.append(heapq.heappop(self._queue))
+                self._admitting.add(req.request_id)
+                popped.append(req)
+                rows.append((slot_id, row))
+                admitted += 1
+                if (use_pp and len(req.prompt) >= self._prefix_ps
+                        and not req.keep_pages):
+                    plans[slot_id] = (hits, chains)
+                    if routed:
+                        hit_routing[slot_id] = \
+                            hit_rows[:len(hits)]
+        else:
+            for _ in range(take):
+                if not self._queue:
+                    break
+                req = self._queue[0][3]
+                if (req.resume_pages is not None
+                        and req.resume_epoch is not None
+                        and req.resume_epoch != self.pool_epoch()):
+                    # dense rolling resume planned against a pool
+                    # that has since been rebuilt (same race as
+                    # the paged branch above)
+                    heapq.heappop(self._queue)
+                    stale_resumes.append(req)
+                    continue
+                entries.append(heapq.heappop(self._queue))
+                popped.append(req)
+            self._admitting.update(r.request_id for r in popped)
+            plan.free = free
+        if forgone:
+            self.metrics.counters["prefix_state_forgone_tokens"
+                                  ].inc(forgone * self._prefix_ps)
+        return stale_resumes, pressure_need
+
+    # swarmlint: hot
+    def _send_table_rows(self, plan: Optional[_AdmissionPlan],
+                         reclaim: Sequence[int] = ()) -> None:
+        """One dispatch that writes to the device's page table the rows of
+        ``plan`` it does not hold yet and zeroes the rows of the retired
+        slots in ``reclaim`` (never a planned slot: a plan takes slots
+        that own no pages)."""
+        with self._cv:
+            new = ([(r, q) for r, q in zip(plan.rows, plan.popped)
+                    if r[0] not in plan.sent] if plan is not None else [])
+        if self._pagecheck is not None:
+            # sanitizer: stamp owners, then verify the canary of every
+            # re-allocated page is still intact — an overwritten canary
+            # is a write-after-free landing between free and re-allocation
+            for (sid, _row), req in new:
+                self._pagecheck_admit(sid, req)
+        if not new and not len(reclaim):
+            return
+        vals = np.zeros((len(reclaim) + len(new),
+                         self.paged.allocator.maxp), np.int32)
+        for j, ((_sid, row), _req) in enumerate(new, len(reclaim)):
+            vals[j] = row
+        self._mirrored(
+            self.CALL_SET_PT_ROWS,
+            np.asarray(list(reclaim) + [r[0] for r, _q in new], np.int32),
+            vals)
+        if new:
+            plan.sent.update(r[0] for r, _q in new)
+
+    # swarmlint: hot
+    def _dispatch_plan(self, plan: _AdmissionPlan) -> None:
+        """Hand a round's plan to the prefill paths: the table rows the
+        device lacks, then the groups' packs and dispatches."""
+        popped, rows, plans = plan.popped, plan.rows, plan.plans
+        hit_routing, resume_rows = plan.hit_routing, plan.resume_rows
+        routed = self._routed is not None
+        if self.paged:
+            self._send_table_rows(plan)
+            # warm-tier promotions (ISSUE 19): bulk-insert the host
+            # payload into the freshly reserved resume pages BEFORE
+            # the resume prefill reads them. Engine thread only —
+            # the pools are donated by the prefill jits below.
+            for req in popped:
+                if req.promote_payload is not None:
+                    self._promote_insert(req)
+        use_prefix = self._prefix is not None
+        ragged = self.paged is not None and self._ragged_active()
+        row_by_slot = dict(rows) if self.paged else {}
+        groups: Dict[Tuple[Any, int], List[Tuple]] = {}
+        ragged_batch: List[Tuple] = []
+        prefix_batch: List[Tuple] = []
+        resume_batch: List[Tuple] = []
+        max_suffix = max_hits = 0
+        # paged pops can SKIP a slot (stale resume popped without
+        # consuming it), so pair each request with the slot recorded
+        # at its allocation, not positionally with `free`
+        slot_ids = ([r[0] for r in rows] if self.paged
+                    else plan.free[:len(popped)])
+        for slot_id, req in zip(slot_ids, popped):
+            slot = self.slots[slot_id]
+            slot.cached_tokens = req.resume_len
+            slot.new_tokens = len(req.prompt)
+            if routed:
+                # the record starts with what the pool really holds
+                # for this row: its hit pages' rows. Kept pages of a
+                # rolling resume come without theirs (their custody is
+                # the caller's: service registry, tiers, fleet transit)
+                slot.routing = hit_routing.get(slot_id, [])
+                slot.cached_parts = len(slot.routing)
+                slot.routing_complete = req.resume_pages is None
+            slot.table_row = row_by_slot.get(slot_id)
+            slot.row_pages = (
+                int(np.count_nonzero(slot.table_row))
+                if self.paged else 0)
+            if slot_id in resume_rows:
+                resume_batch.append((slot_id, req, resume_rows[slot_id]))
+                continue
+            if ragged:
+                # packed ragged waves absorb BOTH the plain and the
+                # prefix-planned rows (a cache hit is just a nonzero
+                # prefix_len descriptor); resume rows keep the
+                # bucketed path (mid-page custody bookkeeping)
+                if use_prefix and slot_id in plans:
+                    hits, chains = plans[slot_id]
                 else:
-                    key = (self._bucket_for(len(req.prompt)), 0)
-                    groups.setdefault(key, []).append((slot_id, req))
-            if prefix_batch:
-                # ONE group per admission wave, padded to the wave's max
-                # (suffix bucket, prefix width): prefill cost is dominated
-                # by the weight read, so co-dispatching short-suffix rows
-                # with long ones is nearly free while per-(bucket, width)
-                # splitting multiplies whole-model HBM passes (measured:
-                # fragmentation cost more than prefix reuse saved)
-                key = (self._bucket_for(max(1, max_suffix)),
-                       self._pp_bucket_for(max(1, max_hits)))
-                groups[key] = prefix_batch
-            if resume_batch:
-                # rolling-KV continuations, grouped PER suffix bucket
-                # (sentinel -ppb keys route to the resume prefill). The
-                # prefix wave's one-group rule does not transfer here:
-                # resume deltas are bimodal — a one-turn continuation is
-                # a few tokens while a conversation that chatted plain
-                # during an in-flight stretch returns with hundreds — and
-                # padding the short rows to the deep straggler's bucket
-                # multiplies their whole-model pass (measured 290ms vs
-                # 10ms at S=512), landing squarely on resume TTFT. The
-                # warmup grid already covers every (bucket, width) pair.
-                per_bucket: Dict[int, List[Tuple]] = {}
-                for item in resume_batch:
-                    b = self._bucket_for(max(1, len(item[1].prompt)))
-                    per_bucket.setdefault(b, []).append(item)
-                for b, items in per_bucket.items():
-                    maxp = max(
-                        max(1, len(it[1].resume_pages)) for it in items)
-                    key = (b, -self._pp_bucket_for(maxp))
-                    groups.setdefault(key, []).extend(items)
-            if ragged_batch:
-                groups[("ragged", 0)] = ragged_batch
-            for (bucket, ppb), batch in groups.items():
-                try:
-                    if bucket == "ragged":
-                        self._prefill_ragged_waves(batch)
-                    elif ppb < 0 and not self.paged:
-                        self._prefill_dense_resume_batch(batch, bucket, -ppb)
-                    elif ppb < 0:
-                        self._prefill_paged_resume_batch(batch, bucket, -ppb)
-                    elif ppb > 0 and self.paged:
-                        self._prefill_paged_prefix_batch(batch, bucket, ppb)
-                    elif ppb > 0:
-                        self._prefill_prefix_batch(batch, bucket, ppb)
-                    else:
-                        self._prefill_batch(batch)
-                except Exception:
-                    # the requests are already off the queue and not yet in
-                    # slots: fail them here or their on_done would never fire
-                    # (generate_sync / SSE streams would hang to the timeout)
-                    logger.exception("prefill failed for %s",
-                                     [item[1].request_id for item in batch])
-                    if self._mh is not None:
-                        # pod mode: the op may already be published (workers
-                        # applied a prefill this coordinator didn't) —
-                        # swallowing here would silently desynchronize the
-                        # SPMD state; escalate to _run's pod-fatal handler
-                        for item in batch:
-                            req = item[1]
-                            if req.on_done is not None:
-                                try:
-                                    req.on_done(req.request_id, [],
-                                                "engine_error")
-                                except Exception:
-                                    pass
-                        raise
+                    hits, chains = [], None
+                slot.cached_tokens = len(hits) * self.paged.page_size
+                slot.new_tokens -= slot.cached_tokens
+                ragged_batch.append((slot_id, req, hits, chains,
+                                     row_by_slot[slot_id]))
+                continue
+            if not self.paged and req.resume_pages is not None:
+                # dense rolling resume: kept prefix-pool pages compose
+                # into the lane (no row-table — the lane IS the slot)
+                resume_batch.append((slot_id, req, None))
+                continue
+            # sub-page prompts (no hit possible, nothing to register)
+            # stay on the plain path; everything else goes through the
+            # prefix path even on a full miss so its pages get
+            # REGISTERED for the next turn. Paged requests were
+            # matched (and pinned) during the pop loop above —
+            # matching again would double-pin — so route on the plan's
+            # existence there.
+            if self.paged and self._prefix is not None:
+                planned = slot_id in plans
+            else:
+                planned = (use_prefix
+                           and len(req.prompt) >= self._prefix_ps)
+            if planned:
+                if self.paged:
+                    hits, chains = plans[slot_id]
+                else:
+                    hits, chains = self._prefix_plan(
+                        req.prompt, routing=slot.routing)
+                    slot.cached_parts = len(hits)
+                suffix_len = len(req.prompt) - len(hits) * self._prefix_ps
+                slot.cached_tokens = len(hits) * self._prefix_ps
+                slot.new_tokens = suffix_len
+                prefix_batch.append((slot_id, req, hits, chains))
+                max_suffix = max(max_suffix, suffix_len)
+                max_hits = max(max_hits, len(hits))
+            else:
+                key = (self._bucket_for(len(req.prompt)), 0)
+                groups.setdefault(key, []).append((slot_id, req))
+        if prefix_batch:
+            # ONE group per admission wave, padded to the wave's max
+            # (suffix bucket, prefix width): prefill cost is dominated
+            # by the weight read, so co-dispatching short-suffix rows
+            # with long ones is nearly free while per-(bucket, width)
+            # splitting multiplies whole-model HBM passes (measured:
+            # fragmentation cost more than prefix reuse saved)
+            key = (self._bucket_for(max(1, max_suffix)),
+                   self._pp_bucket_for(max(1, max_hits)))
+            groups[key] = prefix_batch
+        if resume_batch:
+            # rolling-KV continuations, grouped PER suffix bucket
+            # (sentinel -ppb keys route to the resume prefill). The
+            # prefix wave's one-group rule does not transfer here:
+            # resume deltas are bimodal — a one-turn continuation is
+            # a few tokens while a conversation that chatted plain
+            # during an in-flight stretch returns with hundreds — and
+            # padding the short rows to the deep straggler's bucket
+            # multiplies their whole-model pass (measured 290ms vs
+            # 10ms at S=512), landing squarely on resume TTFT. The
+            # warmup grid already covers every (bucket, width) pair.
+            per_bucket: Dict[int, List[Tuple]] = {}
+            for item in resume_batch:
+                b = self._bucket_for(max(1, len(item[1].prompt)))
+                per_bucket.setdefault(b, []).append(item)
+            for b, items in per_bucket.items():
+                maxp = max(
+                    max(1, len(it[1].resume_pages)) for it in items)
+                key = (b, -self._pp_bucket_for(maxp))
+                groups.setdefault(key, []).extend(items)
+        if ragged_batch:
+            groups[("ragged", 0)] = ragged_batch
+        for (bucket, ppb), batch in groups.items():
+            try:
+                if bucket == "ragged":
+                    self._prefill_ragged_waves(batch)
+                elif ppb < 0 and not self.paged:
+                    self._prefill_dense_resume_batch(batch, bucket, -ppb)
+                elif ppb < 0:
+                    self._prefill_paged_resume_batch(batch, bucket, -ppb)
+                elif ppb > 0 and self.paged:
+                    self._prefill_paged_prefix_batch(batch, bucket, ppb)
+                elif ppb > 0:
+                    self._prefill_prefix_batch(batch, bucket, ppb)
+                else:
+                    self._prefill_batch(batch)
+            except Exception:
+                # the requests are already off the queue and not yet in
+                # slots: fail them here or their on_done would never fire
+                # (generate_sync / SSE streams would hang to the timeout)
+                logger.exception("prefill failed for %s",
+                                 [item[1].request_id for item in batch])
+                if self._mh is not None:
+                    # pod mode: the op may already be published (workers
+                    # applied a prefill this coordinator didn't) —
+                    # swallowing here would silently desynchronize the
+                    # SPMD state; escalate to _run's pod-fatal handler
                     for item in batch:
-                        slot_id, req = item[0], item[1]
-                        with self._cv:
-                            self._admitting.discard(req.request_id)
-                            self._cancel_pending.discard(req.request_id)
-                        if self.paged:
-                            # release the slot's pages or the next occupant's
-                            # allocate() raises "already holds pages" and the
-                            # whole engine fails over (review finding)
-                            self.paged.allocator.mark_retired(slot_id)
-                            # prefix items carry (slot, req, hits, chains);
-                            # resume items carry (slot, req, row ndarray) —
-                            # only matched-hit LISTS are pinned
-                            if (len(item) > 2 and isinstance(item[2], list)
-                                    and item[2]):
-                                self._prefix.unpin(item[2])  # matched hits
+                        req = item[1]
                         if req.on_done is not None:
                             try:
-                                req.on_done(req.request_id, [], "engine_error")
+                                req.on_done(req.request_id, [],
+                                            "engine_error")
                             except Exception:
                                 pass
+                    raise
+                for item in batch:
+                    slot_id, req = item[0], item[1]
+                    with self._cv:
+                        self._admitting.discard(req.request_id)
+                        self._cancel_pending.discard(req.request_id)
+                    if self.paged:
+                        # release the slot's pages or the next occupant's
+                        # allocate() raises "already holds pages" and the
+                        # whole engine fails over (review finding)
+                        self.paged.allocator.mark_retired(slot_id)
+                        # prefix items carry (slot, req, hits, chains);
+                        # resume items carry (slot, req, row ndarray) —
+                        # only matched-hit LISTS are pinned
+                        if (len(item) > 2 and isinstance(item[2], list)
+                                and item[2]):
+                            self._prefix.unpin(item[2])  # matched hits
+                    if req.on_done is not None:
+                        try:
+                            req.on_done(req.request_id, [], "engine_error")
+                        except Exception:
+                            pass
+
 
     def _pp_widths(self, maxp: int) -> List[int]:
         """Prefix-PP gather-width buckets (both prefix engines): each
@@ -4793,9 +5075,10 @@ class Engine:
         the vote and the queue's length it saw (0 where it never looked).
 
         Stop when the engine is stopping, a chaos fault is armed, no lane
-        will be active once this block is processed, or queued work could
-        be admitted into a slot that is free now or freed by this block
-        (exit -> admit -> new session). A lane the votes so far expect
+        will be active once this block is processed, or queued work (a
+        request on the queue, or one whose plan ``_plan_ahead`` holds)
+        could be admitted into a slot that is free now or freed by this
+        block (exit -> admit -> new session). A lane the votes so far expect
         live retires in this block by EOS (an ``eos_id`` anywhere in its
         column: row 0 is a pending sample, a prefill's or a wave rider's,
         or a fed token that an earlier block already showed not to be
@@ -4832,11 +5115,9 @@ class Engine:
                 s = self.slots[i]
                 if not s.active or s.request is not req or s.cancelled:
                     alive[i] = False
-        if not alive.any():
-            return False, 0
         with self._cv:
-            queued = len(self._queue)
-        if queued and not alive.all():
+            queued = self._waiting()
+        if not alive.any() or (queued and not alive.all()):
             return False, queued
         return True, queued
 
@@ -4853,6 +5134,8 @@ class Engine:
         or returns, and ``_resident_flush`` takes what was left."""
         fifo = self._resident_fifo
         ses.consuming = True
+        # what was queued between the dispatch and here woke nobody
+        self._plan_wake(ses)
         while True:
             if fifo.empty() and n_dev.is_ready():
                 return
@@ -4860,8 +5143,21 @@ class Engine:
                 blk = fifo.get(timeout=_RESIDENT_POLL_S)
             except queue.Empty:
                 continue
-            if self._resident_block(ses, blk):
+            if blk is _PLAN_WAKE:
+                self._plan_wake(ses)
+            elif self._resident_block(ses, blk):
                 return
+
+    # swarmlint: hot
+    def _plan_wake(self, ses: _ResidentSession) -> None:
+        """A request was queued while the session runs: plan its admission
+        now, while the device runs the chunk, not at the boundary."""
+        try:
+            self._plan_ahead()
+        except Exception:
+            ses.failed = True    # the boundary's round decides
+            logger.exception("planning ahead failed; stopping the "
+                             "resident session")
 
     # swarmlint: hot
     def _resident_flush(self, ses: Optional[_ResidentSession]) -> None:
@@ -4871,7 +5167,7 @@ class Engine:
         fifo = self._resident_fifo
         while not fifo.empty():
             blk = fifo.get_nowait()
-            if ses is not None:
+            if ses is not None and blk is not _PLAN_WAKE:
                 self._resident_block(ses, blk)
 
     # swarmlint: hot
@@ -4892,9 +5188,14 @@ class Engine:
                         for i, req, pos0 in ses.snap]
             self._prof.dispatch(self._prof_resident_key, ses.prev_ns,
                                 blk.stamp_ns - ses.prev_ns)
+            # the block that ends a session for work to admit (the vote
+            # saw it queued or planned) is settled here and delivered
+            # behind the round's wave (``_deliver_pending``): the device
+            # waits for the wave's dispatch, not for the callbacks
             self._process_host_block(
                 blk.block, blk.lps, snapshot, ses.prev_ns, blk.n,
-                blk.routing, stamp_ns=blk.stamp_ns)
+                blk.routing, stamp_ns=blk.stamp_ns,
+                defer=bool(blk.last and blk.queued and ses.consuming))
             ses.prev_ns = blk.stamp_ns
             if blk.vote:
                 # what the old rule, taken after processing, finds on the
@@ -4925,6 +5226,10 @@ class Engine:
         session therefore spans a single sanctioned sync, vs one per
         chunk on the scan path."""
         t_session = self.tracer.phase_begin("engine.session")
+        # the last session's last block, settled before the round's wave
+        # went: delivered now, behind that wave and before this session's
+        # inputs are read off the slots
+        self._deliver_pending()
         n_chunks = 0
         variant = -1
         carried = 0  # slots already decoding before this session
@@ -5028,7 +5333,8 @@ class Engine:
             self.metrics.counters["engine_resident_chunks"].inc(n_chunks)
             return n_chunks
         finally:
-            self._resident = None
+            with self._cv:     # submit() wakes a live session under it
+                self._resident = None
             self._resident_flush(None)   # a failed program's leftovers
             self._lane_busy = False
 
@@ -5087,6 +5393,7 @@ class Engine:
         host sync per admission round, accounted like _process_block's.
         Off-role slots (max_new > 1, e.g. colocated fallback under a
         quarantined decode pool) are left for the regular decode loop."""
+        self._deliver_pending()    # a request's callbacks keep their order
         rows = [i for i, s in enumerate(self.slots)
                 if s.active and s.pending_token and s.request is not None
                 and s.request.sampling.max_new_tokens <= 1]
@@ -5154,53 +5461,86 @@ class Engine:
     # swarmlint: hot
     def _process_host_block(self, block, lps, snapshot,
                             t_dispatch_ns: int = 0, chunk: int = 0,
-                            routing=None, stamp_ns: int = 0) -> None:
-        """Pure host-side half of block processing: emit tokens, retire
-        finished slots, close the per-chunk spans. Fed numpy blocks by
-        BOTH paths, on the engine thread in both: the scan path after its
-        per-chunk drain, and the resident session's consumer
-        (``_resident_block``), which runs a chunk behind the device and
-        is not waited for by it. ``chunk`` is the block's index in its
-        resident session (a scan dispatch is one chunk a loop step: 0).
-        ``routing`` is the chunk's [K, B, L_routed, k] where the
-        configuration routes: row ``[s, i]`` is the routing of the token
-        slot ``i`` was FED at step ``s``, so it joins the slot's record
-        when the token that step sampled is read. ``stamp_ns`` is when
-        the resident callback took the block (0 on the scan path): how
-        far behind it this processing begins is ``engine.emit``'s
-        ``behind_us``."""
+                            routing=None, stamp_ns: int = 0,
+                            defer: bool = False) -> None:
+        """Pure host-side half of block processing, in two passes over
+        the block: ``_settle_block`` (the slots' state: tokens taken,
+        positions, retirements, pages marked for the reclaim) and
+        ``_deliver_block`` (everything anybody is told: ``on_token`` and
+        ``on_done``, the per-chunk spans, counters, the flight record,
+        the ``engine.emit`` phase). Fed numpy blocks by BOTH paths, on
+        the engine thread in both: the scan path after its per-chunk
+        drain, and the resident session's consumer (``_resident_block``),
+        which runs a chunk behind the device and is not waited for by it.
+        The passes run back to back but for one block: the one that ends
+        a resident session for work to admit (``defer``) is settled here,
+        where the device stands still, and delivered behind the
+        admission round's wave (``_deliver_pending``).
+
+        ``chunk`` is the block's index in its resident session (a scan
+        dispatch is one chunk a loop step: 0). ``routing`` is the chunk's
+        [K, B, L_routed, k] where the configuration routes: row
+        ``[s, i]`` is the routing of the token slot ``i`` was FED at step
+        ``s``, so it joins the slot's record when the token that step
+        sampled is read. ``stamp_ns`` is when the resident callback took
+        the block (0 on the scan path): how far behind it the delivery
+        begins is ``engine.emit``'s ``behind_us``."""
+        # blocks are delivered in order, whatever was deferred
+        self._deliver_pending()
         # a resident session keeps the engine thread here or on the FIFO
         # for as long as it lasts, so a processed block is how a live
         # lane proves progress — beat HERE, not just in the loop
         self._beat()
+        if defer:
+            self._undelivered = self._settle_block(
+                block, lps, snapshot, t_dispatch_ns, chunk, routing,
+                stamp_ns)
+            return
         t_emit = self.tracer.phase_begin("engine.emit")
+        done = self._settle_block(block, lps, snapshot, t_dispatch_ns,
+                                  chunk, routing, stamp_ns)
+        self.tracer.phase_end(t_emit, "engine.emit", cat="engine",
+                              args=self._deliver_block(done, False))
+
+    # swarmlint: hot
+    def _deliver_pending(self) -> None:
+        """Deliver the block a session's end left settled, if any: behind
+        the admission round's wave, as its ``engine.emit`` says."""
+        done, self._undelivered = self._undelivered, None
+        if done is None:
+            return
+        t_emit = self.tracer.phase_begin("engine.emit")
+        self.tracer.phase_end(t_emit, "engine.emit", cat="engine",
+                              args=self._deliver_block(done, True))
+
+    # swarmlint: hot
+    def _settle_block(self, block, lps, snapshot, t_dispatch_ns: int = 0,
+                      chunk: int = 0, routing=None,
+                      stamp_ns: int = 0) -> _SettledBlock:
+        """First pass over a block: bring every live slot of ``snapshot``
+        to the state the block leaves it in, and tell nobody. A slot takes
+        its tokens (``generated``, ``logprobs``, ``pending_token``,
+        ``position``, its routing rows) up to where it retires, by EOS,
+        length, ``max_seq`` or a flagged cancel: the three retirements a
+        vote reckons (``_resident_vote``) and the cancel it reads, decided
+        here and nowhere else; a retired slot's pages are marked for the
+        reclaim. What ``_deliver_block`` owes each request is returned,
+        with nothing in it that reads a slot again: admission may fill a
+        retired slot before the delivery runs."""
+        done = _SettledBlock()
+        done.t_begin_ns = time.monotonic_ns()
+        done.snapshot = snapshot
+        done.t_dispatch_ns, done.chunk = t_dispatch_ns, chunk
+        done.stamp_ns = stamp_ns
+        done.emits = emits = []
+        # routed: the rows live slots read
+        done.live_rows = live_rows = []
         # live slots of this chunk, and over them the pages each owns
         # and how many of those its written extent covers
         n_live = pages_reserved = pages_written = 0
         alloc = self.paged.allocator if self.paged else None
-        t_done_ns = time.monotonic_ns()
-        if t_dispatch_ns:
-            # per-chunk latency, dispatch -> processed (pipelined chunks
-            # overlap, so sums can exceed wall clock — documented); on
-            # the resident path the stamp is the previous emission, so
-            # this is the chunk's device wall time
-            self.metrics.counters["phase_us_decode"].inc(
-                (t_done_ns - t_dispatch_ns) // 1000)
-            # exemplar rid: the chunk covers every snapshot slot; tag it
-            # with the first one so a tail decode-chunk bucket opens a
-            # representative trace (tuple indexing, no allocation)
-            HIST_DECODE_CHUNK.observe(
-                (t_done_ns - t_dispatch_ns) / 1e9,
-                snapshot[0][1].request_id if snapshot else None)
-        now = time.time()
         K = self.decode_chunk
-        live_rows: List[np.ndarray] = []   # routed: the rows live slots read
         for i, req, pos0 in snapshot:
-            if t_dispatch_ns:
-                # one decode-chunk span per live snapshot slot: these are
-                # the leaves of a request's exported timeline
-                self.tracer.span_end(t_dispatch_ns, "engine.decode_chunk",
-                                     cat="engine", rid=req.request_id)
             s = self.slots[i]
             if not s.active or s.request is not req:
                 continue  # retired mid-flight (possibly re-admitted)
@@ -5214,16 +5554,17 @@ class Engine:
                 pages_reserved += owned
                 pages_written += min(owned, max(
                     0, -(-s.position // alloc.page_size) - shared))
+            em = _SlotEmit(i, req)
+            emits.append(em)
             if s.cancelled:
-                self._retire(i, "cancelled")
+                em.retired = self._settle_retire(i, "cancelled")
                 continue
             if s.pending_token:
                 # row 0 is the fed token == what this slot sampled in a
                 # prefill wave (its first token, or the one it rode for),
                 # which the host deliberately never fetched there
                 s.pending_token = False
-                self._emit_token(i, int(block[0, i]), now,
-                                 logprob=float(lps[0, i]))
+                self._settle_token(em, int(block[0, i]), float(lps[0, i]))
             taken = 0      # steps of this chunk whose output the slot read
             if routing is not None and s.active:
                 # the slot's K rows of the chunk, copied once (a view
@@ -5237,28 +5578,73 @@ class Engine:
                     break
                 if pos0 + step >= self.max_seq:
                     # the cache lane is full; later writes were dropped
-                    self._retire(i, "max_seq")
+                    em.retired = self._settle_retire(i, "max_seq")
                     break
                 taken += 1
-                self._emit_token(i, int(block[step + 1, i]), now,
-                                 logprob=float(lps[step + 1, i]))
+                self._settle_token(em, int(block[step + 1, i]),
+                                   float(lps[step + 1, i]))
             if s.active:
                 s.position = pos0 + K
             if routing is not None and taken:
                 live_rows.append(col[:taken])
+        done.n_live = n_live
+        done.pages_reserved, done.pages_written = (pages_reserved,
+                                                   pages_written)
+        done.settle_us = (time.monotonic_ns() - done.t_begin_ns) // 1000
+        return done
+
+    # swarmlint: hot
+    def _deliver_block(self, done: _SettledBlock,
+                       behind_wave: bool) -> Dict[str, Any]:
+        """Second pass: tell everybody what ``_settle_block`` decided. A
+        request's ``on_token`` calls in order and its ``on_done`` after
+        its last token, the slots in the snapshot's order; the per-chunk
+        spans and counters; the expert load. Returns the arguments of the
+        pass's ``engine.emit`` phase, which its caller opened: over both
+        passes where they run back to back, over this one alone where an
+        admission round's dispatch went between them (``behind_wave``;
+        the settling then stood in ``engine.session``, ``settle_us``
+        long)."""
+        t_begin_ns = (time.monotonic_ns() if behind_wave
+                      else done.t_begin_ns)
+        t_dispatch_ns, snapshot = done.t_dispatch_ns, done.snapshot
+        if t_dispatch_ns:
+            # per-chunk latency, dispatch -> processed (pipelined chunks
+            # overlap, so sums can exceed wall clock — documented); on
+            # the resident path the stamp is the previous emission, so
+            # this is the chunk's device wall time
+            self.metrics.counters["phase_us_decode"].inc(
+                (done.t_begin_ns - t_dispatch_ns) // 1000)
+            # exemplar rid: the chunk covers every snapshot slot; tag it
+            # with the first one so a tail decode-chunk bucket opens a
+            # representative trace (tuple indexing, no allocation)
+            HIST_DECODE_CHUNK.observe(
+                (done.t_begin_ns - t_dispatch_ns) / 1e9,
+                snapshot[0][1].request_id if snapshot else None)
+            for _i, req, _pos0 in snapshot:
+                # one decode-chunk span per live snapshot slot: these are
+                # the leaves of a request's exported timeline
+                self.tracer.span_end(t_dispatch_ns, "engine.decode_chunk",
+                                     cat="engine", rid=req.request_id)
+        now = time.time()
+        for em in done.emits:
+            self._deliver_emit(em, now)
         c = self.metrics.counters
-        c["decode_slot_chunks"].inc(n_live)
-        c["kv_page_chunks_reserved"].inc(pages_reserved)
-        c["kv_page_chunks_written"].inc(pages_written)
-        args = {"step": self._loop_step, "chunk": chunk, "live": n_live}
-        if stamp_ns:
-            args["behind_us"] = (t_done_ns - stamp_ns) // 1000
-        if live_rows:
+        c["decode_slot_chunks"].inc(done.n_live)
+        c["kv_page_chunks_reserved"].inc(done.pages_reserved)
+        c["kv_page_chunks_written"].inc(done.pages_written)
+        args = {"step": self._loop_step, "chunk": done.chunk,
+                "live": done.n_live}
+        if done.stamp_ns:
+            args["behind_us"] = (t_begin_ns - done.stamp_ns) // 1000
+            args["behind_wave"] = behind_wave
+            args["settle_us"] = done.settle_us
+        if done.live_rows:
             # what the reservoir observed of this chunk, a routed layer
             # a ratio: a reader that cannot reach the registry reads it
             # here (benchmark/layer_metrics/moe_load_max_over_mean_p50.py)
-            args["moe_load"] = self._observe_load(live_rows)
-        self.tracer.phase_end(t_emit, "engine.emit", cat="engine", args=args)
+            args["moe_load"] = self._observe_load(done.live_rows)
+        return args
 
     def _observe_load(self, live_rows: List[np.ndarray]) -> List[float]:
         """Expert load of one decode chunk, from the routing of the rows
@@ -5297,9 +5683,8 @@ class Engine:
             steps * l_routed * (held[1] if held else n_experts))
         return ratios
 
-    def _finish_routing(self, slot: _Slot, req: GenRequest,
-                        reason: str) -> None:
-        """Hand a retiring occupant its routing record (GenRequest.routing,
+    def _finish_routing(self, ret: _Retired) -> None:
+        """Hand a retired occupant its routing record (GenRequest.routing,
         before on_done fires) and count it. The record is what the slot
         gathered, cut to the positions whose output was read: the prompt,
         and a row a sampled token but the last (the slot's last chunk
@@ -5311,7 +5696,7 @@ class Engine:
         its cached pages came with) and those of them that fell over the
         capacity; ``routing_incomplete_requests`` the records that lack
         positions."""
-        parts, slot.routing = slot.routing, None
+        req, parts = ret.req, ret.routing
         c = self.metrics.counters
         l_routed, k, _e = self._routed
         try:
@@ -5322,13 +5707,13 @@ class Engine:
             # this retirement is the recovery's): no rows, and counted
             logger.exception("routing of %s did not land", req.request_id)
             landed = []
-        sampled = len(slot.generated) + (reason == "eos")
+        sampled = len(ret.generated) + (ret.reason == "eos")
         need = len(req.prompt) + max(sampled - 1, 0)
         rows = (np.concatenate(landed) if landed
                 else np.zeros((0, l_routed, k), np.int16))[:need]
         req.routing = rows
-        req.routing_complete = slot.routing_complete and len(rows) == need
-        mine = rows[sum(len(p) for p in landed[:slot.cached_parts]):]
+        req.routing_complete = ret.routing_complete and len(rows) == need
+        mine = rows[sum(len(p) for p in landed[:ret.cached_parts]):]
         c["moe_assignments"].inc(int(mine.size))
         left_out = int(routing_dropped(mine).sum())
         if self._held_experts is None:
@@ -5345,31 +5730,60 @@ class Engine:
     def _emit_token(self, slot_id: int, token: int,
                     now: Optional[float] = None,
                     logprob: Optional[float] = None) -> None:
-        """Record a sampled token for a slot, stream it, retire if finished."""
-        slot = self.slots[slot_id]
-        req = slot.request
-        now = now or time.time()
-        if slot.first_token_at is None:
-            slot.first_token_at = now
+        """Record a sampled token for a slot, stream it, retire if
+        finished: the two passes of ``_process_host_block`` on one token,
+        for a caller that has a token and no block
+        (``_drain_prefill_only``)."""
+        em = _SlotEmit(slot_id, self.slots[slot_id].request)
+        self._settle_token(em, token, logprob)
+        self._deliver_emit(em, now or time.time())
+
+    # swarmlint: hot
+    def _settle_token(self, em: _SlotEmit, token: int,
+                      logprob: Optional[float] = None) -> None:
+        """A slot takes a sampled token: its state moves (``generated``,
+        ``logprobs``; the retirement where the token is EOS or the
+        request's last) and ``em`` notes what is owed for it."""
+        slot = self.slots[em.slot_id]
+        if slot.first_token_at is None and em.first is None:
+            # its request's first, EOS or not: ``_deliver_emit`` stamps it
+            em.first = (slot.admitted_at, slot.cached_tokens,
+                        slot.new_tokens)
+        if token == self.eos_id:
+            em.retired = self._settle_retire(em.slot_id, "eos")
+            return
+        slot.generated.append(token)
+        if logprob is not None:
+            slot.logprobs.append(logprob)
+        em.tokens.append(token)
+        if len(slot.generated) >= em.req.sampling.max_new_tokens:
+            em.retired = self._settle_retire(em.slot_id, "length")
+
+    # swarmlint: hot
+    def _deliver_emit(self, em: _SlotEmit, now: float) -> None:
+        """Tell a request what ``_settle_token`` noted for it: its first
+        token's stamps, its tokens to ``on_token`` in order, and its
+        retirement after them."""
+        req, ret = em.req, em.retired
+        if em.first is not None:
+            admitted_at, cached_tokens, new_tokens = em.first
+            slot = self.slots[em.slot_id]
+            if ret is not None:
+                ret.first_token_at = now
+            elif slot.request is req:
+                slot.first_token_at = now
             self._lat_first_token.observe(now - req.submitted_at)
             HIST_TTFT.observe(now - req.submitted_at, req.request_id)
             # admission (prefill start) -> first token out: the prefill
             # waves and the decode chunk the token rode out with
             self.tracer.span_at(
-                "engine.first_token", slot.admitted_at or now, now,
+                "engine.first_token", admitted_at or now, now,
                 cat="engine", rid=req.request_id,
                 args={"step": self._loop_step,
                       "mid": req.metadata.get("message_id"),
-                      "cached_tokens": slot.cached_tokens,
-                      "new_tokens": slot.new_tokens})
-
-        finished_reason = None
-        if token == self.eos_id:
-            finished_reason = "eos"
-        else:
-            slot.generated.append(token)
-            if logprob is not None:
-                slot.logprobs.append(logprob)
+                      "cached_tokens": cached_tokens,
+                      "new_tokens": new_tokens})
+        for token in em.tokens:
             self.total_generated += 1
             self.metrics.rates["tokens_generated"].mark(now)
             self.metrics.counters["tokens_generated"].inc()
@@ -5378,17 +5792,39 @@ class Engine:
                     req.on_token(req.request_id, token)
                 except Exception:
                     logger.exception("on_token callback failed")
-            if len(slot.generated) >= req.sampling.max_new_tokens:
-                finished_reason = "length"
-
-        if finished_reason is not None:
-            self._retire(slot_id, finished_reason)
+        if ret is not None:
+            self._deliver_retired(ret)
 
     def _retire(self, slot_id: int, reason: str) -> None:  # swarmlint: hot
+        """Retire a slot's occupant and tell it so at once (a failure, a
+        cancel, a prefill lane's drain): both passes, as a block's
+        retirements go through."""
+        self._deliver_retired(self._settle_retire(slot_id, reason))
+
+    # swarmlint: hot
+    def _settle_retire(self, slot_id: int, reason: str) -> _Retired:
+        """The slot's half of a retirement: it is free from here on (the
+        votes and the next round read that), its pages go to their next
+        owner (the caller of a rolling conversation, else the reclaim),
+        its pins are dropped. Returns what ``_deliver_retired`` needs of
+        the occupant, which the slot forgets."""
         slot = self.slots[slot_id]
         req = slot.request
         slot.active = False
         slot.request = None
+        ret = _Retired()
+        ret.req, ret.reason = req, reason
+        ret.generated, ret.logprobs = slot.generated, slot.logprobs
+        ret.admitted_at = slot.admitted_at
+        ret.first_token_at = slot.first_token_at
+        # sanctioned host syncs this request's lifetime spanned, +1 for
+        # the drain its retirement rides in (the resident session's
+        # drain lands AFTER its last block). Scan path: ~one per chunk;
+        # resident path: admit + drain (+ final)
+        ret.host_syncs = self._host_sync_n - slot.admit_syncs + 1
+        ret.routing, slot.routing = slot.routing, None
+        ret.routing_complete = slot.routing_complete
+        ret.cached_parts = slot.cached_parts
         if self.paged:
             if req is not None and req.keep_pages:
                 # rolling KV: hand the conversation's pages to the caller
@@ -5433,34 +5869,37 @@ class Engine:
                 self._dense_keep_extract(slot_id, slot, req)
             except Exception:
                 logger.exception("dense keep extraction failed")
+        return ret
+
+    # swarmlint: hot
+    def _deliver_retired(self, ret: _Retired) -> None:
+        """The occupant's half of a retirement: the completion counter,
+        the flight record, its log-probs and routing record, ``on_done``."""
+        req = ret.req
         self.metrics.counters["engine_completed"].inc()
-        if req is not None:
-            # flight-recorder request timeline (ring write, engine thread)
-            self.flight.record_request({
-                "rid": req.request_id,
-                "priority": req.priority,
-                "prompt_len": len(req.prompt) + req.resume_len,
-                "generated": len(slot.generated),
-                "reason": reason,
-                "submitted_at": req.submitted_at,
-                "admitted_at": slot.admitted_at,
-                "first_token_at": slot.first_token_at,
-                "retired_at": time.time(),
-                # sanctioned host syncs this request's lifetime spanned,
-                # +1 for the drain its retirement rides in (the resident
-                # session's drain lands AFTER this record). Scan path:
-                # ~one per chunk; resident path: admit + drain (+ final)
-                "host_syncs": self._host_sync_n - slot.admit_syncs + 1,
-            })
-        if req is not None:
-            # raw-model logprobs of the generated tokens (parallel list);
-            # delivered via request metadata so on_done's signature stays
-            req.metadata["logprobs"] = list(slot.logprobs)
-            if slot.routing is not None:
-                self._finish_routing(slot, req, reason)
-        if req and req.on_done is not None:
+        if req is None:
+            return
+        # flight-recorder request timeline (ring write, engine thread)
+        self.flight.record_request({
+            "rid": req.request_id,
+            "priority": req.priority,
+            "prompt_len": len(req.prompt) + req.resume_len,
+            "generated": len(ret.generated),
+            "reason": ret.reason,
+            "submitted_at": req.submitted_at,
+            "admitted_at": ret.admitted_at,
+            "first_token_at": ret.first_token_at,
+            "retired_at": time.time(),
+            "host_syncs": ret.host_syncs,
+        })
+        # raw-model logprobs of the generated tokens (parallel list);
+        # delivered via request metadata so on_done's signature stays
+        req.metadata["logprobs"] = list(ret.logprobs)
+        if ret.routing is not None:
+            self._finish_routing(ret)
+        if req.on_done is not None:
             try:
-                req.on_done(req.request_id, list(slot.generated), reason)
+                req.on_done(req.request_id, list(ret.generated), ret.reason)
             except Exception:
                 logger.exception("on_done callback failed")
 
@@ -5557,11 +5996,14 @@ class Engine:
                 logger.exception("on_pages callback failed")
 
     def _fail_all(self, reason: str) -> None:
+        # what a boundary block still owes its requests comes first
+        self._deliver_pending()
         for i, s in enumerate(self.slots):
             if s.active:
                 self._retire(i, reason)
+        planned = self._release_held_plan(requeue=False)
         with self._cv:
-            pending = [item[3] for item in self._queue]
+            pending = planned + [item[3] for item in self._queue]
             self._queue.clear()
             self._admitting.clear()
             self._cancel_pending.clear()
@@ -5578,7 +6020,7 @@ class Engine:
         # caught by swarmlint SWL301 on landing the guard declarations:
         # len() of a mutating heap from outside the engine lock
         with self._cv:
-            queued = len(self._queue)
+            queued = self._waiting()
         out = {
             "active_slots": sum(1 for s in self.slots if s.active),
             "max_batch": self.max_batch,

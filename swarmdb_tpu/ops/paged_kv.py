@@ -992,6 +992,13 @@ class PageAllocator:
             sp = self._by_slot.get(slot_id)
             return list(sp.pages) if sp else []
 
+    def owns(self, slot_id: int) -> bool:
+        """Whether the slot holds pages: an occupant's, or a retired
+        occupant's that wait for the reclaim. ``allocate`` refuses such a
+        slot."""
+        with self._lock:
+            return slot_id in self._by_slot
+
     # -- retirement ----------------------------------------------------------
 
     def mark_retired(self, slot_id: int) -> None:

@@ -115,6 +115,9 @@ class PrefixLRU:
         # page's end, for the entries registered with one (``register``)
         self._states: dict = {}
         self._pins: dict = {}            # page_id -> pin count
+        # the pages the entries hold: what ``evictable_count`` needs to
+        # tell a pin on a cached page without walking every entry
+        self._entry_pages: set = set()
         self._lock = make_lock("ops.prefix_cache.PrefixLRU._lock")
         self.hits = 0
         self.misses = 0
@@ -196,14 +199,29 @@ class PrefixLRU:
             while len(take) < n and self._free:
                 take.append(self._free.pop())
             if len(take) < n:
-                evictable = [c for c, (p, _, _) in self._entries.items()
-                             if not self._pins.get(p)]
-                for chain in evictable:
-                    if len(take) >= n:
-                        break
-                    take.append(self._entries.pop(chain)[0])
-                    self._states.pop(chain, None)
+                take.extend(self._evict(n - len(take)))
             return take
+
+    # swarmlint: holds[self._lock]
+    def _evict(self, n: int, want=None) -> List[int]:
+        """Drop up to ``n`` unpinned entries, oldest first, and return
+        their pages. The walk stops at the ``n``-th victim: the entries
+        are in LRU order, so it passes the pinned ones at the old end and
+        no more (the gate and every allocation of a full pool come
+        through here, at a session boundary)."""
+        victims: List[bytes] = []
+        for chain, (p, _, _) in self._entries.items():
+            if len(victims) >= n:
+                break
+            if not self._pins.get(p) and (want is None or want(p)):
+                victims.append(chain)
+        out: List[int] = []
+        for chain in victims:
+            page = self._entries.pop(chain)[0]
+            self._entry_pages.discard(page)
+            self._states.pop(chain, None)
+            out.append(page)
+        return out
 
     def evict_lru(self, n: int, want=None) -> List[int]:
         """Evict up to ``n`` LRU unpinned entries, returning their page
@@ -213,15 +231,7 @@ class PrefixLRU:
         a slot's shortfall, and evicting foreign-shard entries would
         drain the whole cache without unblocking anything."""
         with self._lock:
-            out: List[int] = []
-            for chain in [c for c, (p, _, _) in self._entries.items()
-                          if not self._pins.get(p)
-                          and (want is None or want(p))]:
-                if len(out) >= n:
-                    break
-                out.append(self._entries.pop(chain)[0])
-                self._states.pop(chain, None)
-            return out
+            return self._evict(n, want)
 
     def match_and_pin(self, chains: Sequence[bytes], tokens: Sequence[int],
                       routing: Optional[List[Any]] = None,
@@ -243,6 +253,7 @@ class PrefixLRU:
             self._free = (list(range(self.num_pages - 1, 0, -1))
                           if self._manage_free else [])
             self._entries.clear()
+            self._entry_pages.clear()
             self._states.clear()
             self._pins.clear()
 
@@ -252,8 +263,10 @@ class PrefixLRU:
         headroom, since admission can always reclaim them via
         evict_lru."""
         with self._lock:
-            return sum(1 for p, _t, _r in self._entries.values()
-                       if not self._pins.get(p))
+            # the entries less those whose page is pinned: a walk over
+            # the pins (the live rows' pages), not over the cache
+            return len(self._entries) - sum(
+                1 for p in self._pins if p in self._entry_pages)
 
     def free_count(self) -> int:
         """Managed-free mode: pages immediately takeable without eviction
@@ -298,6 +311,7 @@ class PrefixLRU:
                     self._free.append(page_id)
                 return False
             self._entries[chain] = (page_id, tuple(tokens), routing)
+            self._entry_pages.add(page_id)
             if state is not None:
                 self._states[chain] = state
             return True

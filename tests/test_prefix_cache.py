@@ -324,6 +324,59 @@ def test_mixtral_forward_prefix_lane_matches_full():
     )
 
 
+@pytest.mark.parametrize("manage_free", [False, True])
+def test_evictable_count_and_lru_victims_without_walking_the_cache(
+        manage_free):
+    """``evictable_count`` walks the pins and ``evict_lru`` stops at its
+    last victim (both stand at a session boundary, PR 45): over a random
+    run of registrations, matches, pins, unpins and evictions the count
+    is the brute-force one and the victims are the oldest unpinned
+    entries, in LRU order."""
+    rng = np.random.default_rng(45)
+    ps, pages = 4, 64
+    lru = PrefixLRU(pages, ps, manage_free=manage_free)
+    free = list(range(pages - 1, 0, -1))
+    pinned = []
+
+    def brute():
+        return sum(1 for p, _t, _r in lru._entries.values()
+                   if not lru._pins.get(p))
+
+    for step in range(600):
+        op = rng.integers(0, 5)
+        if op == 0 and free:
+            toks = tuple(rng.integers(3, 9, size=ps).tolist())
+            page = free.pop()
+            if not lru.register(page_chains(list(toks), ps)[0], toks, page):
+                free.append(page)
+        elif op == 1 and lru._entries:
+            chain = list(lru._entries)[rng.integers(len(lru._entries))]
+            page, toks, _ = lru._entries[chain]
+            assert lru.match_and_pin([chain], list(toks)) == [page]
+            pinned.append(page)
+        elif op == 2 and pinned:
+            lru.unpin([pinned.pop(rng.integers(len(pinned)))])
+        elif op == 3:
+            n = int(rng.integers(1, 4))
+            want = [p for p, _t, _r in lru._entries.values()
+                    if not lru._pins.get(p)][:n]
+            got = lru.evict_lru(n)
+            assert got == want
+            free.extend(got)
+        elif op == 4:
+            odd = lambda p: p % 2 == 1      # noqa: E731
+            want = [p for p, _t, _r in lru._entries.values()
+                    if not lru._pins.get(p) and odd(p)][:2]
+            got = lru.evict_lru(2, want=odd)
+            assert got == want
+            free.extend(got)
+        assert lru.evictable_count() == brute(), step
+        assert lru._entry_pages == {p for p, _t, _r in lru._entries.values()}
+    assert lru.evictable_count() < len(lru._entries)    # pins were live
+    lru.reset()
+    assert lru.evictable_count() == 0 and not lru._entry_pages
+
+
 def test_prefix_lru_duplicate_registration_recycles():
     lru = PrefixLRU(4, 4)
     toks = list(range(1, 5))
