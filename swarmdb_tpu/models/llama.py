@@ -849,12 +849,15 @@ def forward_paged_chunked(
     ``forward_ragged_prefill`` the layer scan reads the pool in place,
     through its flat view and a per-layer table offset."""
     from ..ops.layers import paged_attention_dispatch_chunked
-    from ..ops.paged_kv import pools_flat
+    from ..ops.paged_kv import live_row_list, pools_flat
 
     x = params["embed"][tokens]
     table = cache["page_table"]
     pool_k_flat, pool_v_flat, L, P = pools_flat(cache["k"], cache["v"])
     cos, sin = _paged_rope_terms(cfg, cache, positions)
+    # once a step, from the table as it is: inside the scan a layer's
+    # table is offset and its trash page is no longer page 0
+    live_rows = live_row_list(table)
 
     def mixer(q, k, v, scanned):
         l, hk, hv = scanned
@@ -862,7 +865,7 @@ def forward_paged_chunked(
         hk, hv = _write_chunk_step(hk, hv, k, v, step)
         attn = paged_attention_dispatch_chunked(
             q, pool_k_flat, pool_v_flat, table + l * P, hk, hv, positions,
-            step, window=cfg.sliding_window)
+            step, window=cfg.sliding_window, live_rows=live_rows)
         return attn[..., :cfg.head_dim], (hk, hv)
 
     if cfg.latent:

@@ -9,11 +9,13 @@ dot against V — the only HBM traffic is the cache itself, which is the
 unavoidable read.
 
 The paged engine's default decode kernel is
-`paged_decode_gqa_attention_chunked`: one grid step a row, the pools left
-in HBM, and a loop inside the step that copies the row's LIVE pages, a
-block of pages a trip, into a double buffer — so a call costs what the
-contexts hold, not what the page table could hold. Its per-step-write twin
-and the int8 variants still walk a `(B, maxp)` grid, one page a step.
+`paged_decode_gqa_attention_chunked`: one grid step a group of 32 slots,
+the pools left in HBM, and inside the step a loop over the slots that hold
+a sequence (a list the forward makes once a step) and, a row, a loop that
+copies its LIVE pages, a block of pages a trip, into a double buffer — so
+a call costs what the live rows' contexts hold, not what the batch or the
+page table could hold. Its per-step-write twin and the int8 variants still
+walk a `(B, maxp)` grid, one page a step.
 `ragged_paged_prefill_attention` walks the same way: one grid step a
 (query block, wave row), and inside it the row's live prefix pages and
 its suffix tiles, a 128-token block a trip; its int8 twin keeps the grid
@@ -143,18 +145,29 @@ def decode_gqa_attention(
 # walking a row's pages live here:
 #
 #   * `_paged_chunk_attn_kernel` (the chunked decode path, what the engine
-#     runs): grid (B,), one step a row. The pools are operands in ANY
+#     runs): grid (ceil(B / 32),), one step a group of `_ROW_GROUP` slots,
+#     whose queries, chunk buffers and outputs are whole VMEM blocks
+#     indexed by a row read from SMEM. The step walks the LIVE rows: the
+#     list ``rows[:n_live]`` rides as scalar prefetch beside the table
+#     (`ops.paged_kv.live_row_list` makes it once a decode step from the
+#     un-offset table: a slot whose table row is all trash holds no
+#     sequence; the kernel cannot tell from its own table, which the
+#     layer scan hands over offset by l * P). A slot that is not walked
+#     costs nothing and reads exact zeros. The pools are operands in ANY
 #     space (HBM, exactly as `pools_flat` hands them over: [L*P, ps, Hkv,
-#     D] with the table already offset by l * P — no copy, no layout
-#     change) and the step loops over the row's live pages in blocks of
-#     `_pages_per_block` pages: ceil(live pages / block) trips, read from
-#     the prefetched ``starts``. Each trip's pages are copied by the
-#     kernel itself (`make_async_copy`, one contiguous page a DMA, DMA
-#     semaphores) into one half of a double buffer while the other half
-#     is computed on, and folded into the softmax as ONE [block * ps]-
-#     token tile. A row with an empty prefix makes no trip and starts no
-#     DMA; pages past a row's last live one are never fetched. The cost
-#     of a call follows the contexts.
+#     D] — no copy, no layout change) and a row loops over its live
+#     pages in blocks of `_pages_per_block` pages: ceil(live pages /
+#     block) trips, read from the prefetched ``starts``. Each trip's
+#     pages are copied by the kernel itself (`make_async_copy`, one
+#     contiguous page a DMA, DMA semaphores) into one half of a double
+#     buffer while the other half is computed on, and folded into the
+#     softmax as ONE [block * ps]-token tile; the double buffer runs
+#     across rows (a row's last trip starts the next row's first block).
+#     A row with an empty prefix makes no trip and starts no DMA; pages
+#     past a row's last live one are never fetched. The cost of a call
+#     follows the live rows and their contexts (v5e, PERF.md section 6,
+#     PR 47: 4-6 us a call whatever it holds, 1.2 us a live row, 1.7-1.8
+#     us a block of 128 tokens).
 #   * `_paged_attn_kernel` (per-step-write path, SWARMDB_CHUNKED=0) and
 #     the `_quant` twins: grid (B, maxp) with the page axis innermost, one
 #     page a grid step through a BlockSpec whose index_map picks the
@@ -267,42 +280,72 @@ def _pages_per_block(page_size: int, n_kv_heads: int, head_dim: int,
     return max(1, min(tokens // page_size, maxp))
 
 
-def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, k_hbm,
-                             v_hbm, ck_ref, cv_ref, o_ref, kbuf_ref,
-                             vbuf_ref, sem_ref, acc_ref, m_ref, l_ref, *,
-                             page_size: int, n_kv_heads: int,
-                             pages_per_block: int, window):
+# slots one grid step of the chunked decode kernel holds: ``q``, the chunk
+# buffers and the output come in as blocks of this many rows (at 32/8
+# heads of 128 in bf16: 256 KB + 2 x 512 KB + 256 KB, beside the 1 MB page
+# double buffer), so a batch up to it is ONE grid step
+_ROW_GROUP = 32
+
+
+def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, rows_ref,
+                             nlive_ref, q_ref, k_hbm, v_hbm, ck_ref, cv_ref,
+                             o_ref, kbuf_ref, vbuf_ref, sem_ref, acc_ref,
+                             m_ref, l_ref, *, page_size: int,
+                             n_kv_heads: int, pages_per_block: int,
+                             n_groups: int, window):
     """Ragged paged attention + in-chunk segment under ONE online softmax.
 
-    Grid (B,): one step a row. The row's FROZEN prefix (valid strictly
-    below the chunk start) is walked in blocks of ``pages_per_block``
-    pages, ``ceil(live pages / pages_per_block)`` trips read from the
-    scalar-prefetched ``starts``: an empty row makes none. A trip waits
-    for its block's page DMAs (pool -> one half of the double buffer,
-    one contiguous page a copy, ids from the scalar-prefetched table),
-    starts the next block's into the other half, and folds its
-    ``pages_per_block * page_size`` tokens into the online softmax.
-    Pages past the row's last live one are never fetched. Then the [Kc]
-    chunk buffer (entries 0..step) and the finalize.
+    Grid (groups,): one step a group of ``_ROW_GROUP`` slots, whose
+    ``q``, chunk buffers and output are whole VMEM blocks. The step
+    zeroes its output block and walks the LIVE rows of its group: entries
+    ``rows[lo:hi]`` of the scalar-prefetched list (live slots first, in
+    slot order; ``n_live`` of them), so a slot that holds no sequence
+    costs nothing and reads exact zeros. A row's FROZEN prefix (valid
+    strictly below the chunk start) is walked in blocks of
+    ``pages_per_block`` pages, ``ceil(live pages / pages_per_block)``
+    trips read from the prefetched ``starts``. A trip waits for its
+    block's page DMAs (pool -> one half of the double buffer, one
+    contiguous page a copy, ids from the prefetched table), starts the
+    next block's into the other half and folds its
+    ``pages_per_block * page_size`` tokens into the online softmax; a
+    row's last trip starts the NEXT row's first block instead, so only
+    the group's first row waits for a copy nothing hides. Pages past a
+    row's last live one are never fetched. Then the row's [Kc] chunk
+    buffer (entries 0..step) and its finalize.
     """
-    b = pl.program_id(0)
-    start = start_ref[b]              # frozen prefix length = chunk start
+    g = pl.program_id(0)
     step = step_ref[0]
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
+    n_live = nlive_ref[0]
+    Bg, Hq, D = q_ref.shape
     Hkv = n_kv_heads
     ps, ppb = page_size, pages_per_block
     tile = ppb * ps
     maxp = table_ref.shape[1]
-    # truncating lax.div on non-negative numerators, as `_last_live_page`
-    live_pages = jnp.minimum(
-        jax.lax.div(jax.lax.max(start, 0) + (ps - 1), jnp.int32(ps)), maxp)
-    n_blocks = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
 
-    acc_ref[...] = jnp.zeros_like(acc_ref)
-    m_ref[...] = jnp.full_like(m_ref, -1e30)
-    l_ref[...] = jnp.zeros_like(l_ref)
+    if n_groups == 1:
+        lo, hi = 0, n_live
+    else:
+        # the list is in slot order: the group's rows are one run of it
+        def below(bound):
+            return jax.lax.fori_loop(
+                0, n_live,
+                lambda i, c: c + jnp.where(rows_ref[i] < bound, 1, 0),
+                jnp.int32(0))
 
-    def page_copies(blk, slot, i):
+        lo, hi = below(g * Bg), below((g + 1) * Bg)
+
+    def walk_of(i):
+        """(slot, frozen prefix length, live pages) of list entry ``i``;
+        no pages past the group's last row."""
+        b = rows_ref[jnp.minimum(i, rows_ref.shape[0] - 1)]
+        start = start_ref[b]
+        # truncating lax.div on non-negative numerators (`_last_live_page`)
+        pages = jnp.minimum(
+            jax.lax.div(jax.lax.max(start, 0) + (ps - 1), jnp.int32(ps)),
+            maxp)
+        return b, start, jnp.where(i < hi, pages, 0)
+
+    def page_copies(b, blk, slot, i):
         dst = pl.ds(i * ps, ps)
         pid = table_ref[b, blk * ppb + i]
         return (pltpu.make_async_copy(k_hbm.at[pid], kbuf_ref.at[slot, dst],
@@ -310,13 +353,13 @@ def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, k_hbm,
                 pltpu.make_async_copy(v_hbm.at[pid], vbuf_ref.at[slot, dst],
                                       sem_ref.at[1, slot]))
 
-    def fetch(blk, slot):
+    def fetch(b, live_pages, blk, slot):
         for i in range(ppb):
             live = blk * ppb + i < live_pages
 
             @pl.when(live)
             def _start():
-                for cp in page_copies(blk, slot, i):
+                for cp in page_copies(b, blk, slot, i):
                     cp.start()
 
             @pl.when(jnp.logical_not(live))
@@ -326,45 +369,72 @@ def _paged_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, k_hbm,
                 vbuf_ref[slot, pl.ds(i * ps, ps)] = jnp.zeros(
                     (ps,) + vbuf_ref.shape[2:], vbuf_ref.dtype)
 
-    def wait(blk, slot):
+    def wait(b, live_pages, blk, slot):
         for i in range(ppb):
             @pl.when(blk * ppb + i < live_pages)
             def _wait():
-                for cp in page_copies(blk, slot, i):
+                for cp in page_copies(b, blk, slot, i):
                     cp.wait()
 
-    @pl.when(n_blocks > 0)
+    o_ref[...] = jnp.zeros_like(o_ref)
+    b0, _, pages0 = walk_of(lo)
+
+    @pl.when(pages0 > 0)
     def _first():
-        fetch(0, 0)
+        fetch(b0, pages0, 0, 0)
 
-    def block(blk, carry):
-        slot = jax.lax.rem(blk, 2)
+    def row(i, slot0):
+        # ``slot0``: the half this row's first block is in (or would be)
+        b, start, live_pages = walk_of(i)
+        nb, _, nxt_pages = walk_of(i + 1)
+        n_blocks = jax.lax.div(live_pages + (ppb - 1), jnp.int32(ppb))
+        r = b - g * Bg
+        q_row = q_ref.at[pl.ds(r, 1)]
 
-        @pl.when(blk + 1 < n_blocks)
-        def _next():
-            fetch(blk + 1, 1 - slot)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, -1e30)
+        l_ref[...] = jnp.zeros_like(l_ref)
 
-        wait(blk, slot)
-        pos = blk * tile + jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
-        valid = pos < start
+        @pl.when((n_blocks == 0) & (nxt_pages > 0))
+        def _next_row():
+            fetch(nb, nxt_pages, 0, slot0)
+
+        def block(blk, carry):
+            slot = jax.lax.rem(slot0 + blk, 2)
+            mine = blk + 1 < n_blocks
+
+            @pl.when(mine | (nxt_pages > 0))
+            def _next():
+                fetch(jnp.where(mine, b, nb),
+                      jnp.where(mine, live_pages, nxt_pages),
+                      jnp.where(mine, blk + 1, 0), 1 - slot)
+
+            wait(b, live_pages, blk, slot)
+            pos = blk * tile + jax.lax.broadcasted_iota(
+                jnp.int32, (1, tile), 1)
+            valid = pos < start
+            if window is not None:
+                valid &= pos > (start + step - window)
+            _attend_tile(q_row, kbuf_ref.at[pl.ds(slot, 1)],
+                         vbuf_ref.at[pl.ds(slot, 1)], valid, Hkv, acc_ref,
+                         m_ref, l_ref)
+            return carry
+
+        jax.lax.fori_loop(0, n_blocks, block, 0)
+
+        Kc = ck_ref.shape[1]
+        idx = jax.lax.broadcasted_iota(jnp.int32, (1, Kc), 1)
+        valid = idx <= step
         if window is not None:
-            valid &= pos > (start + step - window)
-        _attend_tile(q_ref, kbuf_ref.at[pl.ds(slot, 1)],
-                     vbuf_ref.at[pl.ds(slot, 1)], valid, Hkv, acc_ref,
-                     m_ref, l_ref)
-        return carry
+            valid &= (start + idx) > (start + step - window)
+        _attend_tile(q_row, ck_ref.at[pl.ds(r, 1)], cv_ref.at[pl.ds(r, 1)],
+                     valid, Hkv, acc_ref, m_ref, l_ref)
 
-    jax.lax.fori_loop(0, n_blocks, block, 0)
+        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
+        o_ref[r] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
+        return jax.lax.rem(slot0 + n_blocks, 2)
 
-    Kc = ck_ref.shape[1]
-    idx = jax.lax.broadcasted_iota(jnp.int32, (1, Kc), 1)
-    valid = idx <= step
-    if window is not None:
-        valid &= (start + idx) > (start + step - window)
-    _attend_tile(q_ref, ck_ref, cv_ref, valid, Hkv, acc_ref, m_ref, l_ref)
-
-    denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
-    o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
+    jax.lax.fori_loop(lo, hi, row, jnp.int32(0))
 
 
 def _last_live_page(n, ps):
@@ -384,40 +454,42 @@ def paged_decode_gqa_attention_chunked(
     chunk_v: jnp.ndarray,
     starts: jnp.ndarray,      # [B] int32 frozen prefix length (chunk start)
     step: jnp.ndarray,        # scalar int32 current step within the chunk
+    rows: jnp.ndarray,        # [B] int32 the slots to walk, in slot order
+    n_live: jnp.ndarray,      # scalar int32: how many of ``rows`` are walked
     window=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """Two-segment ragged paged decode attention; returns [B, Hq, D].
-    The pools stay in HBM as they are handed over; the kernel copies each
-    row's live pages itself, so its cost follows ``starts``, not the
-    table's width."""
+    """Two-segment ragged paged decode attention; returns [B, Hq, D],
+    exact zeros for a slot that is not among the first ``n_live`` of
+    ``rows``. The pools stay in HBM as they are handed over; the kernel
+    copies each walked row's live pages itself, so its cost follows
+    ``n_live`` and ``starts``, not the batch or the table's width."""
     B, Hq, D = q.shape
     _, ps, Hkv, _ = k_pages.shape
     maxp = page_table.shape[1]
     G = Hq // Hkv
     Kc = chunk_k.shape[1]
     ppb = _pages_per_block(ps, Hkv, D, k_pages.dtype.itemsize, maxp)
-    table = page_table.astype(jnp.int32)
-    starts = starts.astype(jnp.int32)
-    step_arr = jnp.reshape(step, (1,)).astype(jnp.int32)
+    Bg = min(B, _ROW_GROUP)
+    n_groups = -(-B // Bg)
 
-    def q_map(b, table_ref, start_ref, step_ref):
-        return (b, 0, 0)
+    def q_map(g, *prefetched):
+        return (g, 0, 0)
 
-    def chunk_map(b, table_ref, start_ref, step_ref):
-        return (b, 0, 0, 0)
+    def chunk_map(g, *prefetched):
+        return (g, 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(B,),
+        num_scalar_prefetch=5,
+        grid=(n_groups,),
         in_specs=[
-            pl.BlockSpec((1, Hq, D), q_map),
+            pl.BlockSpec((Bg, Hq, D), q_map),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
-            pl.BlockSpec((1, Kc, Hkv, D), chunk_map),
-            pl.BlockSpec((1, Kc, Hkv, D), chunk_map),
+            pl.BlockSpec((Bg, Kc, Hkv, D), chunk_map),
+            pl.BlockSpec((Bg, Kc, Hkv, D), chunk_map),
         ],
-        out_specs=pl.BlockSpec((1, Hq, D), q_map),
+        out_specs=pl.BlockSpec((Bg, Hq, D), q_map),
         scratch_shapes=[
             pltpu.VMEM((2, ppb * ps, Hkv, D), k_pages.dtype),  # K halves
             pltpu.VMEM((2, ppb * ps, Hkv, D), v_pages.dtype),  # V halves
@@ -427,15 +499,17 @@ def paged_decode_gqa_attention_chunked(
             pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running denom (bcast)
         ],
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_paged_chunk_attn_kernel, page_size=ps,
                           n_kv_heads=Hkv, pages_per_block=ppb,
-                          window=window),
+                          n_groups=n_groups, window=window),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
         interpret=interpret,
-    )(table, starts, step_arr, q, k_pages, v_pages, chunk_k, chunk_v)
-    return out
+    )(page_table.astype(jnp.int32), starts.astype(jnp.int32),
+      jnp.reshape(step, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      jnp.reshape(n_live, (1,)).astype(jnp.int32), q, k_pages, v_pages,
+      chunk_k, chunk_v)
 
 
 # ---------------------------------------------------------------------------
@@ -1343,11 +1417,11 @@ def _mla_pages_per_block(page_size: int, maxp: int) -> int:
 def _mla_chunk_attn_kernel(table_ref, start_ref, step_ref, q_ref, pool_hbm,
                            ck_ref, o_ref, buf_ref, sem_ref, acc_ref, m_ref,
                            l_ref, *, page_size: int, pages_per_block: int):
-    """Grid (B,): a row's frozen prefix walked in blocks of
-    ``pages_per_block`` pages through a double buffer (as
-    `_paged_chunk_attn_kernel`: trips from the prefetched ``starts``, pages
-    past the last live one never fetched), then the chunk's rows
-    (entries 0..step), one online softmax."""
+    """Grid (B,), a slot a step, live or not: a row's frozen prefix
+    walked in blocks of ``pages_per_block`` pages through a double buffer
+    (a row's walk in `_paged_chunk_attn_kernel`: trips from the
+    prefetched ``starts``, pages past the last live one never fetched),
+    then the chunk's rows (entries 0..step), one online softmax."""
     b = pl.program_id(0)
     start = start_ref[b]
     step = step_ref[0]

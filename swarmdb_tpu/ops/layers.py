@@ -208,17 +208,21 @@ def paged_attention_dispatch_chunked(
     step: jnp.ndarray,        # scalar int32
     *,
     window: Optional[int] = None,
+    live_rows=None,           # (rows [B], n_live) int32
 ) -> jnp.ndarray:
     """Two-segment decode attention for the PAGED cache: frozen page pool
     + in-chunk buffer under one softmax (the paged counterpart of
     ``gqa_attention_chunked``; the pool is only written once per chunk via
     ``ops.paged_kv.paged_write_chunk``).
 
-    Ragged Pallas kernel on TPU (reads only live pages + the chunk
-    buffer); XLA page-gather fallback elsewhere — the fallback reuses
-    ``gqa_attention_chunked`` directly on the gathered dense view, whose
-    frozen-segment mask (kv_pos < chunk start) already expresses "pool
-    holds strictly the prefix".
+    Ragged Pallas kernel on TPU (walks the slots of ``live_rows``, as
+    ``ops.paged_kv.live_row_list`` makes them from the un-offset table,
+    and reads only their live pages + chunk buffer; every other slot's
+    output is exact zeros; without the list every slot is walked); XLA
+    page-gather fallback elsewhere, which computes every slot — the
+    fallback reuses ``gqa_attention_chunked`` directly on the gathered
+    dense view, whose frozen-segment mask (kv_pos < chunk start) already
+    expresses "pool holds strictly the prefix".
     """
     from .paged_kv import is_quantized, paged_gather_kv, pool_data
 
@@ -239,14 +243,17 @@ def paged_attention_dispatch_chunked(
             return out[:, None]
         from .attention_pallas import paged_decode_gqa_attention_chunked
 
+        B = q.shape[0]
         _record_static_vmem(
             "_paged_chunk_attn_kernel", "kernel:pallas",
-            {"Hq": q.shape[2], "Hkv": kd.shape[2], "D": q.shape[3],
+            {"B": B, "Hq": q.shape[2], "Hkv": kd.shape[2], "D": q.shape[3],
              "ps": kd.shape[1], "Kc": chunk_k.shape[1],
              "maxp": page_table.shape[1], "itemsize": kd.dtype.itemsize})
+        if live_rows is None:
+            live_rows = (jnp.arange(B, dtype=jnp.int32), jnp.int32(B))
         out = paged_decode_gqa_attention_chunked(
             q[:, 0], k_pages, v_pages, page_table, chunk_k, chunk_v,
-            starts, step.astype(jnp.int32),
+            starts, step.astype(jnp.int32), *live_rows,
             window=window, interpret=interp,
         )
         return out[:, None]

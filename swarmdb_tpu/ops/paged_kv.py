@@ -832,6 +832,26 @@ def set_page_table_rows(
     return _set_page_table_rows(page_table, rows, values)
 
 
+def live_row_list(page_table: jnp.ndarray):
+    """``(rows [B], n_live)``, both int32, of an UN-OFFSET page table:
+    the slots that hold a sequence (a row that is not all trash: its first
+    page is not page 0) first and in slot order, then the others. What
+    the chunked decode kernel walks. Compares and sums, no sort: it runs
+    once a decode step."""
+    live = page_table[:, 0] != 0
+    slot = jnp.arange(page_table.shape[0], dtype=jnp.int32)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    # a slot's place: the slots of its kind before it, the dead behind
+    # the live
+    same_before = ((slot[None, :] < slot[:, None])
+                   & (live[None, :] == live[:, None]))
+    place = (jnp.sum(same_before, axis=1, dtype=jnp.int32)
+             + jnp.where(live, 0, n_live))
+    rows = jnp.sum(jnp.where(place[None, :] == slot[:, None],
+                             slot[None, :], 0), axis=1, dtype=jnp.int32)
+    return rows, n_live
+
+
 @dataclass
 class _SlotPages:
     pages: List[int]

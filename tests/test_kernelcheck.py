@@ -80,20 +80,24 @@ def test_static_vmem_table_covers_in_tree_kernels():
 
 def test_static_vmem_of_the_chunked_decode_page_loop():
     """The chunked paged decode kernel leaves its pools in HBM (ANY
-    space, no block) and copies pages into scratch of the pool's dtype:
-    its row is in the table, and under the dims its dispatcher binds
-    the page buffers are what `_pages_per_block` makes them."""
-    from swarmdb_tpu.ops.attention_pallas import _pages_per_block
+    space, no block) and copies pages into scratch of the pool's dtype,
+    and holds a whole group of slots' queries, chunk buffers and outputs
+    as blocks: its row is in the table, and under the dims its
+    dispatcher binds the page buffers are what `_pages_per_block` makes
+    them and the blocks a batch's, up to `_ROW_GROUP` slots."""
+    from swarmdb_tpu.ops.attention_pallas import (_ROW_GROUP,
+                                                  _pages_per_block)
 
     rows = {r["kernel"]: r for r in static_vmem_table()}
     assert "_paged_chunk_attn_kernel" in rows
     hq, hkv, d, ps, kc, maxp = 32, 8, 128, 16, 8, 256
-    for itemsize in (2, 4):
-        dims = {"Hq": hq, "Hkv": hkv, "D": d, "ps": ps, "Kc": kc,
+    for b, itemsize in ((16, 2), (32, 2), (40, 2), (16, 4)):
+        dims = {"B": b, "Hq": hq, "Hkv": hkv, "D": d, "ps": ps, "Kc": kc,
                 "maxp": maxp, "itemsize": itemsize}
         ppb = _pages_per_block(ps, hkv, d, itemsize, maxp)
         buffers = 2 * 2 * ppb * ps * hkv * d * itemsize   # K, V halves
-        blocks = 2 * 4 * (2 * hq * d + 2 * kc * hkv * d)  # q, out, chunks
+        blocks = (2 * 4 * min(b, _ROW_GROUP)              # q, out, chunks
+                  * (2 * hq * d + 2 * kc * hkv * d))
         state = 4 * hq * (d + 2 * 128)                    # acc, max, denom
         assert estimate_vmem("_paged_chunk_attn_kernel",
                              dims) == buffers + blocks + state

@@ -396,14 +396,21 @@ _CFULL = _CPS * _CMAXP
 
 
 def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
-                  dtype=jnp.float32, shuffle=False, layer=None, seed=0):
+                  dtype=jnp.float32, shuffle=False, layer=None, seed=0,
+                  keeps=()):
     """Kernel (interpret mode) against the gather path on one batch:
-    row b holds ``starts[b]`` frozen tokens in its own pages. ``shuffle``
-    deals the page ids out of order; ``layer`` = (l, L) puts the pool at
-    slice l of a flat [L*P, ...] pool, the table offset by l * P."""
+    row b holds ``starts[b]`` frozen tokens in its own pages; a row that
+    owns no page is a dead slot (its table row all trash), as the forward
+    reads it (`live_row_list`), and its output must be exact zeros. Rows
+    in ``keeps`` own a page whatever they hold: a live row with nothing
+    frozen yet, or a finished one that keeps its table until the reclaim.
+    ``shuffle`` deals the page ids out of order; ``layer`` = (l, L) puts
+    the pool at slice l of a flat [L*P, ...] pool, the table offset by
+    l * P (the list still comes from the table before the offset)."""
     from swarmdb_tpu.ops.attention_pallas import (
         _pages_per_block, paged_decode_gqa_attention_chunked)
     from swarmdb_tpu.ops.layers import gqa_attention_chunked
+    from swarmdb_tpu.ops.paged_kv import live_row_list
 
     rng = np.random.default_rng(seed)
     ps, maxp, Kc = _CPS, _CMAXP, 8
@@ -417,7 +424,7 @@ def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
     table = np.zeros((B, maxp), np.int32)
     nxt = 0
     for b, n in enumerate(starts):
-        live = -(-max(int(n), 0) // ps)
+        live = max(-(-max(int(n), 0) // ps), int(b in keeps))
         table[b, :live] = ids[nxt:nxt + live]
         nxt += live
     kp = jnp.asarray(rng.normal(size=(P, ps, Hkv, D)), dtype)
@@ -430,6 +437,11 @@ def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
     kg, vg = paged_gather_kv(kp, vp, jnp.asarray(table))
     ref = gqa_attention_chunked(q, kg, vg, ck, cv, (starts + step)[:, None],
                                 step, window=window)[:, 0]
+    rows, n_live = live_row_list(jnp.asarray(table))
+    held = table[:, 0] != 0
+    assert int(n_live) == held.sum()
+    assert sorted(np.asarray(rows)) == list(range(B))
+    assert list(np.asarray(rows)[:held.sum()]) == list(np.flatnonzero(held))
     pool_k, pool_v, tbl = kp, vp, table
     if layer is not None:
         l, L = layer
@@ -443,12 +455,18 @@ def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
         pool_k, pool_v, tbl = flat(kp), flat(vp), table + l * P
     out = paged_decode_gqa_attention_chunked(
         q[:, 0], pool_k, pool_v, jnp.asarray(tbl), ck, cv, starts, step,
-        window=window, interpret=True)
+        rows, n_live, window=window, interpret=True)
     assert out.dtype == q.dtype
+    out = np.asarray(out, np.float32)
     tol = 2e-5 if dtype == jnp.float32 else 3e-2
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
+    np.testing.assert_allclose(out[held], np.asarray(ref, np.float32)[held],
                                rtol=tol, atol=tol)
+    assert not out[~held].any()
+
+
+def _spread(B, live, n):
+    """``B`` starts: ``n`` tokens in the slots of ``live``, 0 elsewhere."""
+    return tuple(n if b in live else 0 for b in range(B))
 
 
 @pytest.mark.parametrize("kw", [
@@ -473,13 +491,68 @@ def _chunked_case(starts, *, Hq=4, Hkv=2, D=16, step=2, window=None,
     *[pytest.param(dict(starts=(300, 0, 129), shuffle=True, layer=(l, 3)),
                    id=f"flat-pool-layer-{l}")
       for l in (0, 2)],
+    # the walk over rows: who is in the list, and whose pages are in
+    # which half of the double buffer when a row ends
+    pytest.param(dict(starts=(200, 9, 131, 300, 1, 128, 77, 256, 40, 129,
+                              8, 320, 5, 17, 255, 64)),
+                 id="every-slot-live-B16"),
+    pytest.param(dict(starts=_spread(32, {31}, 150)),
+                 id="one-live-row-in-the-last-slot-of-32"),
+    pytest.param(dict(starts=(0, 300, 0, 0, 129, 0, 17, 0), shuffle=True,
+                      layer=(1, 3)),
+                 id="live-and-dead-interleaved-flat-pool"),
+    pytest.param(dict(starts=_spread(40, {0, 7, 30, 31, 32, 33, 39}, 140)),
+                 id="two-row-groups-B40"),
+    pytest.param(dict(starts=_spread(40, {33, 38}, 260)),
+                 id="first-row-group-all-dead-B40"),
+    pytest.param(dict(starts=(0, 200, 0, 0, 77), keeps=(0, 3)),
+                 id="live-rows-with-nothing-frozen"),
+    pytest.param(dict(starts=(-3, 140, 0), keeps=(0,)),
+                 id="finished-row-keeps-its-table"),
+    # a row's first block rides the row before it: rows of no, one, two
+    # and three blocks next to each other, in both orders
+    pytest.param(dict(starts=(0, 260, 5, 0, 130, 300, 1, 129), keeps=(0, 3)),
+                 id="block-counts-mixed-ascending"),
+    pytest.param(dict(starts=(300, 129, 0, 260, 128, 0, 257, 3),
+                      keeps=(2, 5), window=100, step=5),
+                 id="block-counts-mixed-with-window"),
 ])
 def test_paged_chunked_kernel_walks_live_pages(kw):
-    """The chunked decode kernel's page loop (one grid step a row,
-    blocks of pages copied by the kernel itself) agrees with the gather
-    path wherever a row's prefix ends: before, at and after a block's
-    edge, for an empty row, and at the table's full width."""
+    """The chunked decode kernel's walk (one grid step a group of slots,
+    the live rows of the prefetched list inside it, blocks of pages
+    copied by the kernel itself, a row's first block started under the
+    row before it) agrees with the gather path wherever a row's prefix
+    ends: before, at and after a block's edge, for a live row with
+    nothing frozen, and at the table's full width; a slot that holds no
+    sequence reads exact zeros."""
     _chunked_case(**kw)
+
+
+def test_paged_chunked_dispatch_walks_every_slot_without_a_list(
+        monkeypatch):
+    """Called without ``live_rows`` the dispatch hands the kernel every
+    slot, the all-trash ones too, and every row is the gather path's: what
+    a caller that has no un-offset table gets."""
+    from swarmdb_tpu.ops.layers import paged_attention_dispatch_chunked
+
+    rng = np.random.default_rng(4)
+    B, ps, Hq, Hkv, D, Kc = 3, 8, 4, 2, 16, 8
+    table = jnp.asarray([[1, 2, 0, 0], [0, 0, 0, 0], [3, 0, 0, 0]],
+                        jnp.int32)
+    step = jnp.asarray(1, jnp.int32)
+    q_pos = jnp.asarray([[12], [0], [8]], jnp.int32) + step
+    kp, vp = (jnp.asarray(rng.normal(size=(4, ps, Hkv, D)), jnp.float32)
+              for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(B, 1, Hq, D)), jnp.float32)
+    ck, cv = (jnp.asarray(rng.normal(size=(B, Kc, Hkv, D)), jnp.float32)
+              for _ in range(2))
+    outs = {}
+    for flag in ("0", "1"):     # the gather path, the kernel (interpret)
+        monkeypatch.setenv("SWARMDB_PALLAS", flag)
+        outs[flag] = np.asarray(paged_attention_dispatch_chunked(
+            q, kp, vp, table, ck, cv, q_pos, step))
+    assert outs["1"][1].any()
+    np.testing.assert_allclose(outs["1"], outs["0"], rtol=2e-5, atol=2e-5)
 
 
 @pytest.fixture(scope="module")

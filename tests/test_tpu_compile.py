@@ -80,21 +80,26 @@ def test_paged_decode_compiles(one_chip):
     pytest.param(B, HKV, MAXP, PAGES, None, id="llama3-8b-span1024"),
     # mistral7b.chat: 16 rows, a 256-page table, the flat 16-layer pool
     pytest.param(16, 8, 256, 73728, None, id="chat-cell-flat-pool"),
+    # lfm2-8b-a1b.chat: 32 rows, 8 KV heads of 64 in 128 lanes, the flat
+    # pool of its 4 attention layers (11,265 pages each)
+    pytest.param(32, 8, 256, 45060, None, id="chat-lfm2-cell"),
+    pytest.param(40, 8, 256, 4096, None, id="two-row-groups"),
     pytest.param(16, 4, 256, 4096, None, id="yi-G8"),
     pytest.param(16, 8, 256, 4096, 1024, id="sliding-window"),
 ])
 def test_paged_chunked_decode_compiles(one_chip, b, hkv, maxp, pages,
                                        window):
-    """The in-kernel page loop (pools left in HBM, pages copied by the
-    kernel into a double buffer, a loop whose trip count is read from
-    SMEM) is what the chip's compiler has to take; and the custom call
-    keeps the name the benchmark's trace reader looks for."""
+    """The in-kernel walk (whole-batch blocks indexed by a row read from
+    SMEM, pools left in HBM, pages copied by the kernel into a double
+    buffer, loops over the live rows and their blocks whose trip counts
+    are read from SMEM) is what the chip's compiler has to take; and the
+    custom call keeps the name the benchmark's trace reader looks for."""
     pool = ((pages, PS, hkv, D), BF)
     chunk = ((b, KC, hkv, D), BF)
     compiled = _compile(
         one_chip, ap.paged_decode_gqa_attention_chunked,
         ((b, HQ, D), BF), pool, pool, ((b, maxp), I32), chunk, chunk,
-        ((b,), I32), STEP, window=window)
+        ((b,), I32), STEP, ((b,), I32), STEP, window=window)
     assert "%paged_decode_gqa_attention_chunked" in compiled.as_text()
     assert compiled.memory_analysis().temp_size_in_bytes == 0
 
