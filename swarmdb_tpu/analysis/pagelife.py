@@ -70,8 +70,8 @@ _XFER_TAILS = {"register", "transfer_to_cache", "requeue_pending",
                "on_demote", "on_promote"}
 #: page-table write / dispatch-descriptor sinks (SWL802/SWL805 anchors)
 _TABLE_TAILS = {"set_page_table_rows", "paged_write_ragged",
-                "paged_write_decode", "paged_write_chunk",
-                "paged_insert_prefill", "paged_gather_kv"}
+                "paged_write_chunk", "paged_insert_prefill",
+                "paged_gather_kv"}
 #: builtins that observe a handle without taking custody
 _PURE_OBSERVERS = {"len", "min", "max", "sum", "any", "all", "bool",
                    "int", "float", "str", "repr", "print", "isinstance",

@@ -519,14 +519,20 @@ class PagedKV:
     The engine's main cache becomes a shared page pool + page table
     (ops/paged_kv.py): HBM ∝ num_pages*page_size instead of
     max_batch*max_seq. Prefill still runs on dense bucket-sized temp caches
-    (`Engine.forward_fn`); ``decode_forward`` is the paged-cache model
-    forward (e.g. ``llama.forward_paged``) and ``init_pool`` builds the
-    {"k","v","page_table"} cache dict. Admission allocates pages via the
+    (`Engine.forward_fn`); ``init_pool`` builds the {"k","v","page_table"}
+    cache dict and ``chunked_fns`` is the chunk triple that pool is
+    decoded with (e.g. ``llama.forward_paged_chunked``,
+    ``llama.init_chunk_kv``, ``llama.merge_paged_chunk``): the pool stays
+    frozen for a chunk's K steps and is written once at its end
+    (``Engine._decode``). The two travel together because the forward
+    must match the pool's layout. Admission allocates pages via the
     host-side allocator and stalls (keeps requests queued) when the pool
     cannot cover a request's worst-case footprint.
     """
 
-    decode_forward: Callable    # (params, tokens, positions, cache) -> ...
+    # (forward(params, tokens, positions, cache, chunk_kv, step),
+    #  init_chunk(batch, K), merge(cache, chunk_kv, start_positions))
+    chunked_fns: Tuple[Callable, Callable, Callable]
     init_pool: Callable         # () -> {"k", "v", "page_table"}
     page_size: int
     num_pages: int
@@ -732,16 +738,15 @@ class Engine:
         self._latent = (isinstance(self.cache, dict)
                         and isinstance(self.cache.get("v"), NoValuePool))
         if (self._stateful or self._latent) and (
-                chunked_fns is None or paged.prefill_ragged is None
+                paged.prefill_ragged is None
                 or getattr(paged.allocator, "n_shards", 1) > 1
                 or os.environ.get("SWARMDB_RAGGED_PREFILL", "auto") == "0"):
             raise NotImplementedError(
                 "a configuration with conv state or latent pages is served "
                 "by the paged engine's ragged prefill and chunked decode on "
                 "one shard: the row-bucketed prefill "
-                "(SWARMDB_RAGGED_PREFILL=0), the per-step decode "
-                "(SWARMDB_CHUNKED=0) and a sharded pool (lanes) do not "
-                "carry conv state, nor pages without a heads axis")
+                "(SWARMDB_RAGGED_PREFILL=0) and a sharded pool (lanes) do "
+                "not carry conv state, nor pages without a heads axis")
         if self._latent:
             from ..ops.layers import latent_kernels_enabled
 
@@ -755,7 +760,6 @@ class Engine:
                 _memprof().set_page_bytes(self._page_bytes())
             except Exception:  # cache layouts without nbytes (stubs)
                 pass
-        self._decode_forward = paged.decode_forward if paged else forward_fn
         self._prefill_cache_fn = init_cache_fn
         self._seed = seed
         self.base_keys = make_slot_keys(seed, max_batch)
@@ -923,15 +927,23 @@ class Engine:
         # the [B, V] sort is the most expensive op in a large-batch decode
         # step). _dispatch_decode picks per chunk from host-side slot state.
         # Two chunk-loop shapes:
-        # - chunked_fns (dense AND paged; the caller supplies the matching
-        #   triple): the main cache stays FROZEN across the K steps; each
-        #   step's K/V lands in a small [B, K, ...] buffer (uniform
-        #   dynamic_update_slice) and is folded into the cache ONCE per
-        #   chunk — a full-cache rewrite (dense) or bulk page scatter
-        #   (paged) per chunk instead of per step. Profiling on the v5e
-        #   showed the per-step rewrite cost ~2x the model matmuls.
-        # - fallback (chunked_fns=None): per-step cache threading.
-        self._chunked_fns = chunked_fns
+        # - a chunk triple (a page pool brings its own, PagedKV.chunked_fns;
+        #   the dense slab's comes as ``chunked_fns``): the main cache
+        #   stays FROZEN across the K steps; each step's K/V lands in a
+        #   small [B, K, ...] buffer (uniform dynamic_update_slice) and is
+        #   folded into the cache ONCE per chunk — a full-cache rewrite
+        #   (dense) or bulk page scatter (paged) per chunk instead of per
+        #   step. Profiling on the v5e showed the per-step rewrite cost
+        #   ~2x the model matmuls.
+        # - fallback (a dense slab built without a triple, nothing else):
+        #   ``forward_fn`` threads the slab through every step.
+        if paged is not None and chunked_fns is not None:
+            raise ValueError(
+                "Engine(paged=..., chunked_fns=...): a page pool is decoded "
+                "with the chunk triple its PagedKV carries "
+                "(PagedKV.chunked_fns); the chunked_fns argument is the "
+                "dense slab's")
+        self._chunked_fns = paged.chunked_fns if paged else chunked_fns
 
         def _decode(params, last_tokens, last_lps, positions, cache,
                     base_keys, temp, topk, topp, *, scope, use_filters,
@@ -982,7 +994,7 @@ class Engine:
             def body(carry, _):
                 tok, pos, cache = carry
                 with jax.named_scope(scope):
-                    logits, cache, *routing = self._decode_forward(
+                    logits, cache, *routing = self.forward_fn(
                         params, tok[:, None], pos[:, None], cache
                     )
                 nxt = sample_tokens(logits[:, -1], base_keys, pos, temp,

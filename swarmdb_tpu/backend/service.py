@@ -195,37 +195,32 @@ def build_backend_engine(
     fwd_last = lambda p, t, pos, c, at: llama.forward(
         p, cfg, t, pos, c, logits_at=at)
     init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
-    paged_fwd = lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c)
     # two-segment chunked decode — the cache (dense slot buffer OR
     # paged pool) stays frozen per chunk; see Engine._decode /
-    # ops.layers. SWARMDB_CHUNKED=0 falls back to per-step cache
-    # threading (escape hatch if a backend's compiler mishandles the
-    # chunked graph).
+    # ops.layers. A page pool's triple rides its PagedKV, the dense
+    # slab's goes to the engine as ``chunked_fns``.
     # ONE prefix-cache enablement flag shared by paged pool sizing and
     # prefix_fns wiring (review finding: duplicated conditions drift)
     prefix_enabled = (
         os.environ.get("SWARMDB_PREFIX", "1") != "0"
         and seq % page_size == 0
     )
-    chunked_fns = None
-    if os.environ.get("SWARMDB_CHUNKED", "1") != "0":
-        chunk_fwd = (llama.forward_paged_chunked if paged
-                     else llama.forward_chunked)
-        if paged:
-            merge = llama.merge_paged_chunk
-        elif os.environ.get("SWARMDB_MERGE", "einsum") == "scatter":
-            # scatter-form chunk merge: numerically identical
-            # (ops/layers.merge_chunk_kv_scatter); raced against the
-            # einsum form on silicon by scripts/profile_merge.py
-            merge = llama.merge_chunk_scatter
-        else:
-            merge = llama.merge_chunk
-        chunked_fns = (
-            lambda p, t, pos, c, hkv, s: chunk_fwd(p, cfg, t, pos, c,
-                                                   hkv, s),
-            lambda b, k: llama.init_chunk_kv(cfg, b, k),
-            merge,
-        )
+    chunk_fwd = (llama.forward_paged_chunked if paged
+                 else llama.forward_chunked)
+    if paged:
+        merge = llama.merge_paged_chunk
+    elif os.environ.get("SWARMDB_MERGE", "einsum") == "scatter":
+        # scatter-form chunk merge: numerically identical
+        # (ops/layers.merge_chunk_kv_scatter); raced against the
+        # einsum form on silicon by scripts/profile_merge.py
+        merge = llama.merge_chunk_scatter
+    else:
+        merge = llama.merge_chunk
+    chunked_fns = (
+        lambda p, t, pos, c, hkv, s: chunk_fwd(p, cfg, t, pos, c, hkv, s),
+        lambda b, k: llama.init_chunk_kv(cfg, b, k),
+        merge,
+    )
 
     paged_spec = None
     if paged:
@@ -243,7 +238,7 @@ def build_backend_engine(
                 "SWARMDB_PREFIX_TOKENS", max_batch * seq // 2))
         num_pages = 1 + -(-pool_tokens // page_size)  # +1 trash page
         paged_spec = PagedKV(
-            decode_forward=paged_fwd,
+            chunked_fns=chunked_fns,
             init_pool=lambda: llama.init_paged_cache(
                 cfg, max_batch, seq, num_pages, page_size),
             page_size=page_size,
@@ -306,7 +301,8 @@ def build_backend_engine(
         max_batch=max_batch, max_seq=seq,
         eos_id=tokenizer.eos_id, pad_id=tokenizer.pad_id, seed=seed,
         metrics=metrics, decode_chunk=decode_chunk, paged=paged_spec,
-        prefill_batch=prefill_batch, chunked_fns=chunked_fns,
+        prefill_batch=prefill_batch,
+        chunked_fns=None if paged else chunked_fns,
         pipeline_depth=int(os.environ.get("SWARMDB_PIPELINE", "2")),
         prefix_fns=prefix_fns, prefix_pages=prefix_pages,
         prefix_page_size=page_size, forward_last_fn=fwd_last,

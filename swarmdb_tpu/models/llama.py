@@ -789,48 +789,6 @@ def _paged_rope_terms(cfg: ModelConfig, cache, positions):
     return rope_terms(cfg, rope_pos)
 
 
-def forward_paged(
-    params: Params,
-    cfg: ModelConfig,
-    tokens: jnp.ndarray,       # [B, 1] int32 — DECODE steps only
-    positions: jnp.ndarray,    # [B, 1] int32 absolute positions per row
-    cache,                     # {"k": [L,P,ps,Hkv,D], "v": ..., "page_table"}
-    moe_dispatch: Optional[str] = None,
-):
-    """Decode forward over the block-paged KV pool (ops/paged_kv.py).
-
-    Prefill stays on the dense bucket path (`forward` with a temp cache);
-    the engine scatters the prefix into pages at admission
-    (ops.paged_kv.paged_insert_prefill). Attention uses the ragged Pallas
-    kernel on TPU (reads only live pages) with an XLA gather fallback.
-    Returns fp32 logits [B, 1, V] and the updated cache dict and, where
-    the configuration routes, the step's routing [B, 1, L_routed, k].
-    """
-    from ..ops.layers import paged_attention_dispatch
-    from ..ops.paged_kv import paged_write_decode
-
-    refuse_state(cfg, "the per-step paged decode (forward_paged, "
-                      "SWARMDB_CHUNKED=0)")
-    x = params["embed"][tokens]  # [B, 1, D]
-    table = cache["page_table"]
-    cos, sin = _paged_rope_terms(cfg, cache, positions)
-
-    def mixer(q, k, v, pages):
-        kp, vp = paged_write_decode(*pages, k, v, positions, table)
-        attn = paged_attention_dispatch(
-            q, kp, vp, table, positions, window=cfg.sliding_window)
-        return attn, (kp, vp)
-
-    x, out = jax.lax.scan(
-        decoder_layer(cfg, cos, sin, mixer, moe_dispatch), x,
-        (params["layers"], (cache["k"], cache["v"])))
-    (new_k, new_v), routing = take_routing(cfg, out)
-    out = {"k": new_k, "v": new_v, "page_table": table}
-    if "pos0" in cache:
-        out["pos0"] = cache["pos0"]
-    return lm_logits(params, cfg, x), out, *routing
-
-
 def forward_paged_chunked(
     params: Params,
     cfg: ModelConfig,

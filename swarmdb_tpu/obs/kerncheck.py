@@ -30,10 +30,22 @@ over the real kernel function:
   that land at ``wait()``, its buffers starting as NaN,
 - the shadow result is compared against the dispatched result — a
   free differential check of kernel-vs-dispatch parity on the live
-  descriptors; :func:`differential_ragged_prefill` /
-  :func:`differential_paged_decode` run the same comparison over
-  randomized descriptor soups (mixed lens, page-boundary crossings,
-  empty rows, split rows) for the CI harness.
+  descriptors; :func:`differential_ragged_prefill` runs the same
+  comparison over randomized descriptor soups (mixed lens,
+  page-boundary crossings, empty rows, split rows) for the CI harness,
+- the chunked paged DECODE kernel, the one the engine runs
+  (``paged_decode_gqa_attention_chunked`` and its int8 twin), has no
+  numpy replay of its walk (rows and page blocks from SMEM, a double
+  buffer that runs across rows): it is held to the XLA gather form
+  instead. :func:`differential_paged_decode` runs the kernels
+  interpreted against ``gqa_attention_chunked`` on the gathered view
+  (mixed lengths, empty slots, a live-row list that leaves slots out,
+  a chunk buffer part full), and the checked dispatcher
+  (:func:`checked_paged_attention_dispatch_chunked`) compares the
+  kernel's live rows with that form on every concrete call and holds
+  the kernel to its contract that a slot not on the list is exact
+  zeros; where the dispatch itself gathered it runs the kernel
+  interpreted on the same operands.
 
 Violations are recorded once, written to attached flight recorders as
 ``kerncheck.violation`` instants, dumped immediately to
@@ -76,11 +88,10 @@ logger = logging.getLogger("swarmdb_tpu.obs")
 __all__ = ["enabled", "registry", "KernCheckRegistry", "ShadowRef",
            "CANARY", "parity_tol",
            "ragged_prefill_body", "shadow_ragged_prefill",
-           "shadow_paged_decode",
            "shadow_paged_write_ragged", "check_wave_descriptors",
            "differential_ragged_prefill", "differential_paged_decode",
            "checked_ragged_prefill_dispatch",
-           "checked_paged_attention_dispatch",
+           "checked_paged_attention_dispatch_chunked",
            "checked_paged_write_ragged"]
 
 # float canary pre-poisoning shadow outputs: exactly representable in
@@ -652,53 +663,6 @@ def shadow_ragged_prefill(q, sfx_k, sfx_v, k_pages, v_pages, row_tables,
     return out
 
 
-def shadow_paged_decode(q, k_pages, v_pages, page_table, lengths, *,
-                        window=None,
-                        kernel: Optional[Callable] = None) -> np.ndarray:
-    """Shadow the ragged paged DECODE kernel (grid (B, maxp)); canary
-    check: every slot's [Hq, D] output row must be overwritten."""
-    from ..ops import attention_pallas as ap
-
-    q = np.asarray(q)
-    B, Hq, D = q.shape
-    k_pages = np.asarray(k_pages)
-    _, ps, Hkv, _ = k_pages.shape
-    table = np.asarray(page_table, np.int32)
-    maxp = table.shape[1]
-    lengths = np.asarray(lengths, np.int32)
-    name = "paged_decode_gqa_attention"
-    if kernel is None:
-        kernel = functools.partial(
-            ap._paged_attn_kernel, page_size=ps, n_kv_heads=Hkv,
-            window=window)
-
-    def q_map(b, j, table_ref, len_ref):
-        return (b, 0, 0)
-
-    def kv_map(b, j, table_ref, len_ref):
-        import jax.numpy as jnp
-
-        last_live = ap._last_live_page(len_ref[b], ps)
-        return (table_ref[b, jnp.minimum(j, last_live)], 0, 0, 0)
-
-    out = np.full((B, Hq, D), CANARY, q.dtype)
-    G = Hq // Hkv
-    out, writers = _run_grid(
-        kernel, name, (B, maxp),
-        [("table", table), ("lengths", lengths)],
-        [("q", q, (1, Hq, D), q_map),
-         ("k_pages", k_pages, (1, ps, Hkv, D), kv_map),
-         ("v_pages", np.asarray(v_pages), (1, ps, Hkv, D), kv_map)],
-        ("o", out, (1, Hq, D), q_map),
-        [np.zeros((Hkv, G, D), np.float32),
-         np.full((Hkv, G, 128), -1e30, np.float32),
-         np.zeros((Hkv, G, 128), np.float32)])
-    _coverage_rows(name, out, writers,
-                   np.arange(B, dtype=np.int32),
-                   (lengths > 0).astype(np.int32))
-    return out
-
-
 def _coverage_rows(kernel: str, out: np.ndarray, writers: np.ndarray,
                    starts: np.ndarray, lens: np.ndarray) -> None:
     """Output-coverage check (the runtime face of SWL905). A
@@ -898,66 +862,134 @@ def differential_ragged_prefill(seed: int = 0, rounds: int = 4,
     return bad
 
 
+def _gather_form_chunked(q, k_pages, v_pages, page_table, chunk_k,
+                         chunk_v, q_positions, step, window=None):
+    """The XLA form of two-segment paged decode attention, what the
+    chunked kernels are held to: ``gqa_attention_chunked`` on the
+    gathered dense view (a QuantPool dequantizes in the gather), with
+    the Pallas dense-slab kernel kept out of it. [B, 1, Hq, D]."""
+    from ..ops.layers import gqa_attention_chunked, pallas_disabled
+    from ..ops.paged_kv import paged_gather_kv
+
+    kg, vg = paged_gather_kv(k_pages, v_pages, page_table)
+    with pallas_disabled():
+        return gqa_attention_chunked(q, kg, vg, chunk_k, chunk_v,
+                                     q_positions, step, window=window)
+
+
+def _chunked_kernel_interpreted(q, k_pages, v_pages, page_table, chunk_k,
+                                chunk_v, starts, step, live_rows,
+                                window=None):
+    """The chunked paged decode kernel of this pool's dtype, interpreted,
+    and its name. ``q`` [B, Hq, D]; the int8 twin walks every slot and
+    takes no list, the plain one walks ``live_rows`` (every slot where
+    there is none). [B, Hq, D]."""
+    import jax.numpy as jnp
+
+    from ..ops.attention_pallas import (
+        paged_decode_gqa_attention_chunked,
+        paged_decode_gqa_attention_chunked_quant)
+    from ..ops.paged_kv import is_quantized
+
+    starts = jnp.asarray(starts, jnp.int32)
+    step = jnp.asarray(step, jnp.int32)
+    if is_quantized(k_pages):
+        return paged_decode_gqa_attention_chunked_quant(
+            q, k_pages.data, k_pages.scale, v_pages.data, v_pages.scale,
+            page_table, chunk_k, chunk_v, starts, step, window=window,
+            interpret=True), "paged_decode_gqa_attention_chunked_quant"
+    if live_rows is None:
+        B = q.shape[0]
+        live_rows = (jnp.arange(B, dtype=jnp.int32), jnp.int32(B))
+    return paged_decode_gqa_attention_chunked(
+        q, k_pages, v_pages, page_table, chunk_k, chunk_v, starts, step,
+        *live_rows, window=window,
+        interpret=True), "paged_decode_gqa_attention_chunked"
+
+
+def _hold_to_gather_form(kernel: str, got, want, walked: np.ndarray,
+                         zeros_elsewhere: bool, tol: float) -> bool:
+    """``got`` [B, Hq, D] of a chunked decode kernel against ``want`` of
+    the gather form: within ``tol`` on the ``walked`` slots (``parity``)
+    and, where the kernel walks a live-row list, exact zeros on every
+    other slot (``dead-row``). True when it held."""
+    got = np.asarray(got, np.float32)
+    slots = np.nonzero(walked)[0]
+    ok = _parity(kernel, np.asarray(want), got, slots,
+                 np.ones_like(slots), tol=tol)
+    dirty = [int(b) for b in np.nonzero(
+        (got != 0).any(axis=(1, 2)) & ~walked)[0][:4]]
+    if zeros_elsewhere and dirty:
+        ok = False
+        registry().record(
+            "dead-row", kernel,
+            f"slot(s) {dirty} are not on the live-row list and do not "
+            f"read exact zeros — the kernel wrote a row it was not "
+            f"handed (or left its block unfilled)",
+            {"slots": dirty})
+    return ok
+
+
 def differential_paged_decode(seed: int = 0, rounds: int = 4,
                               tol: float = _PARITY_TOL,
                               quantized: bool = False) -> int:
-    """Randomized parity of the paged decode kernel against the XLA
-    page-gather path (mixed lengths incl. empty slots);
-    ``quantized=True`` runs the int8 kernel against the quantized
-    gather path."""
+    """Randomized parity of the chunked paged decode kernel (the one the
+    engine runs, interpreted) against the XLA gather form: mixed prefix
+    lengths (none, mid-page, page-aligned), empty slots, the live-row
+    list ``live_row_list`` makes of such a table (it leaves the empty
+    slots out, and they must read exact zeros), a chunk buffer filled
+    up to a random step with garbage behind it. ``quantized=True`` runs
+    the int8 twin, which walks every slot, against the quantized gather
+    path. Returns the number of mismatching rounds."""
     import jax.numpy as jnp
 
-    from ..ops.attention_pallas import (paged_decode_gqa_attention,
-                                        paged_decode_gqa_attention_quant)
-    from ..ops.layers import gqa_attention
-    from ..ops.paged_kv import (QuantPool, _quantize_pages,
-                                paged_gather_kv)
+    from ..ops.paged_kv import QuantPool, _quantize_pages, live_row_list
 
     rng = np.random.default_rng(seed)
     bad = 0
-    for i in range(rounds):
-        B, Hkv, G, D, ps, maxp = 4, 2, 2, 8, 4, 3
+    for _ in range(rounds):
+        B, Hkv, G, D, ps, maxp, Kc = 5, 2, 2, 8, 4, 3, 4
         Hq = Hkv * G
         P = 1 + B * maxp
-        lengths = rng.integers(0, maxp * ps + 1, B).astype(np.int32)
+        step = int(rng.integers(0, Kc))
+        # a slot in three holds no sequence (an all-trash table row);
+        # slot 0 always does, slot 1 never: every round has both
+        held = rng.random(B) < 0.67
+        held[0], held[1] = True, False
+        starts = np.where(
+            held, rng.choice([0, 1, ps - 1, ps, ps + 1, 2 * ps], B),
+            0).astype(np.int32)
         table = np.zeros((B, maxp), np.int32)
         free = list(range(1, P))
         rng.shuffle(free)
-        for b in range(B):
-            for c in range(max(1, -(-int(lengths[b]) // ps))):
+        for b in np.nonzero(held)[0]:
+            for c in range(-(-int(starts[b] + Kc) // ps)):
                 table[b, c] = free.pop()
         q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
         kp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)),
                          jnp.float32)
         vp = jnp.asarray(rng.standard_normal((P, ps, Hkv, D)),
                          jnp.float32)
+        ck = jnp.asarray(rng.standard_normal((B, Kc, Hkv, D)),
+                         jnp.float32)
+        cv = jnp.asarray(rng.standard_normal((B, Kc, Hkv, D)),
+                         jnp.float32)
+        tbl = jnp.asarray(table)
         if quantized:
             registry().note_check("differential.paged-decode.int8")
-            kq, ks = _quantize_pages(kp)
-            vq, vs = _quantize_pages(vp)
-            got = np.asarray(paged_decode_gqa_attention_quant(
-                q, kq, ks, vq, vs, jnp.asarray(table),
-                jnp.asarray(lengths), interpret=True))
-            kp, vp = QuantPool(kq, ks), QuantPool(vq, vs)
+            kp = QuantPool(*_quantize_pages(kp))
+            vp = QuantPool(*_quantize_pages(vp))
         else:
             registry().note_check("differential.paged-decode")
-            got = np.asarray(paged_decode_gqa_attention(
-                q, kp, vp, jnp.asarray(table), jnp.asarray(lengths),
-                interpret=True))
-        kg, vg = paged_gather_kv(kp, vp, jnp.asarray(table))
-        want = np.asarray(gqa_attention(
-            q[:, None], kg, vg,
-            jnp.asarray(lengths - 1)[:, None])[:, 0])
-        liveb = lengths > 0
-        err = float(np.max(np.abs(got[liveb] - want[liveb]))) \
-            if liveb.any() else 0.0
-        if err > tol:
+        got, name = _chunked_kernel_interpreted(
+            q, kp, vp, tbl, ck, cv, starts, step, live_row_list(tbl))
+        want = _gather_form_chunked(
+            q[:, None], kp, vp, tbl, ck, cv,
+            jnp.asarray(starts + step)[:, None], jnp.int32(step))[:, 0]
+        if not _hold_to_gather_form(
+                name, got, want, np.ones(B, bool) if quantized else held,
+                not quantized, tol):
             bad += 1
-            registry().record(
-                "parity", "paged_decode_gqa_attention",
-                f"differential round {i} (seed {seed}): kernel vs "
-                f"gather path disagree by {err:.3e} (> {tol})",
-                {"round": i, "seed": seed, "max_err": err})
     return bad
 
 
@@ -1001,36 +1033,57 @@ def checked_ragged_prefill_dispatch(fn: Callable) -> Callable:
     return wrapper
 
 
-def checked_paged_attention_dispatch(fn: Callable) -> Callable:
-    """Wrap ``ops.layers.paged_attention_dispatch``; flag off returns
-    ``fn`` itself."""
+def checked_paged_attention_dispatch_chunked(fn: Callable) -> Callable:
+    """Wrap ``ops.layers.paged_attention_dispatch_chunked``; flag off
+    returns ``fn`` itself. On concrete operands the chunked decode
+    kernel's result is held to the XLA gather form
+    (:func:`_hold_to_gather_form`): the slots of ``live_rows`` within
+    :func:`parity_tol`, and exact zeros on every other slot from the
+    kernel that walks that list. Where the dispatch gathered (off the
+    chip, or under the kv-span threshold) the kernel is run here,
+    interpreted, on the same operands."""
     if not enabled():
         return fn
 
     @functools.wraps(fn)
-    def wrapper(q, k_pages, v_pages, page_table, q_positions, *,
-                window=None):
-        from ..ops.paged_kv import pool_data
+    def wrapper(q, k_pages, v_pages, page_table, chunk_k, chunk_v,
+                q_positions, step, *, window=None, live_rows=None):
+        from ..ops.layers import decode_kernel_choice
+        from ..ops.paged_kv import is_quantized, pool_data
 
-        out = fn(q, k_pages, v_pages, page_table, q_positions,
-                 window=window)
-        if (_any_tracer(q, pool_data(k_pages), page_table)
+        out = fn(q, k_pages, v_pages, page_table, chunk_k, chunk_v,
+                 q_positions, step, window=window, live_rows=live_rows)
+        kd = pool_data(k_pages)
+        if (_any_tracer(q, kd, page_table, chunk_k, q_positions, step,
+                        *(live_rows or ()))
                 or q.shape[0] > _max_shadow_width()):
             return out
         try:
-            registry().note_check("shadow.paged-decode")
-            lengths = (np.asarray(q_positions)[:, 0] + 1).astype(np.int32)
-            kp, vp = _dequant_pools(k_pages, v_pages)
-            shadow = shadow_paged_decode(
-                np.asarray(q)[:, 0], kp, vp, page_table,
-                lengths, window=window)
-            B = shadow.shape[0]
-            _parity("paged_decode_gqa_attention", shadow,
-                    np.asarray(out)[:, 0],
-                    np.arange(B, dtype=np.int32), np.ones(B, np.int32),
-                    tol=parity_tol())
+            registry().note_check("dispatch.paged-decode-chunked")
+            B = q.shape[0]
+            walked = np.ones(B, bool)
+            quant = is_quantized(k_pages)
+            if live_rows is not None:
+                rows, n_live = live_rows
+                walked = np.zeros(B, bool)
+                walked[np.asarray(rows)[:int(n_live)]] = True
+            got, name = out[:, 0], ("paged_decode_gqa_attention_chunked"
+                                    + ("_quant" if quant else ""))
+            if decode_kernel_choice(
+                    page_table.shape[1] * kd.shape[1]) != "pallas":
+                got, name = _chunked_kernel_interpreted(
+                    q[:, 0], k_pages, v_pages, page_table, chunk_k,
+                    chunk_v, q_positions[:, 0] - step, step, live_rows,
+                    window=window)
+            want = _gather_form_chunked(
+                q, k_pages, v_pages, page_table, chunk_k, chunk_v,
+                q_positions, step, window=window)
+            # the int8 twin takes no list and computes every slot
+            _hold_to_gather_form(name, got, want[:, 0], walked,
+                                 not quant and live_rows is not None,
+                                 parity_tol())
         except Exception:
-            logger.exception("kerncheck paged-decode shadow failed")
+            logger.exception("kerncheck paged-decode check failed")
         return out
 
     return wrapper
@@ -1145,8 +1198,9 @@ def _replay_write_parity_quant(sfx_k, tok_row, tok_pos, row_tables,
 
 def _parity(kernel: str, shadow: np.ndarray, dispatched: np.ndarray,
             starts: np.ndarray, lens: np.ndarray,
-            tol: float = _PARITY_TOL) -> None:
-    """Compare shadow vs dispatched output on descriptor-live rows."""
+            tol: float = _PARITY_TOL) -> bool:
+    """Compare shadow (or reference) vs dispatched output on
+    descriptor-live rows; a NaN is a mismatch. True when they agree."""
     worst = 0.0
     for r in range(len(lens)):
         if lens[r] <= 0:
@@ -1154,11 +1208,13 @@ def _parity(kernel: str, shadow: np.ndarray, dispatched: np.ndarray,
         s, e = int(starts[r]), int(starts[r]) + int(lens[r])
         a = np.asarray(shadow[s:e], np.float32)
         b = np.asarray(dispatched[s:e], np.float32)
-        worst = max(worst, float(np.max(np.abs(a - b))))
-    if worst > tol:
-        registry().record(
-            "parity", kernel,
-            f"shadow interpreter vs dispatched output disagree by "
-            f"{worst:.3e} (> {tol}) on live rows — the dispatched path "
-            f"and the kernel math diverged",
-            {"max_err": worst})
+        worst = float(np.maximum(worst, np.max(np.abs(a - b))))
+    if worst <= tol:
+        return True
+    registry().record(
+        "parity", kernel,
+        f"shadow interpreter (or reference form) vs dispatched output "
+        f"disagree by {worst:.3e} (> {tol}) on live rows — the "
+        f"dispatched path and the kernel math diverged",
+        {"max_err": worst})
+    return False

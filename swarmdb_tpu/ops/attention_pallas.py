@@ -14,8 +14,8 @@ the pools left in HBM, and inside the step a loop over the slots that hold
 a sequence (a list the forward makes once a step) and, a row, a loop that
 copies its LIVE pages, a block of pages a trip, into a double buffer — so
 a call costs what the live rows' contexts hold, not what the batch or the
-page table could hold. Its per-step-write twin and the int8 variants still
-walk a `(B, maxp)` grid, one page a step.
+page table could hold. Its int8 twin still walks a `(B, maxp + 1)` grid,
+one page a step.
 `ragged_paged_prefill_attention` walks the same way: one grid step a
 (query block, wave row), and inside it the row's live prefix pages and
 its suffix tiles, a 128-token block a trip; its int8 twin keeps the grid
@@ -112,8 +112,8 @@ def decode_gqa_attention(
         raise ValueError(
             f"decode_gqa_attention keeps whole KV lanes in VMEM: S={S} x "
             f"{Hkv} kv heads x {D} needs {lanes} bytes, the limit is "
-            f"{vmem_budget()}; use the chunked or paged kernel "
-            f"(SWARMDB_CHUNKED unset) or unset SWARMDB_PALLAS")
+            f"{vmem_budget()}; use the chunked or paged kernel or unset "
+            f"SWARMDB_PALLAS")
 
     grid = (B,)
     return pl.pallas_call(
@@ -168,15 +168,15 @@ def decode_gqa_attention(
 #     follows the live rows and their contexts (v5e, PERF.md section 6,
 #     PR 47: 4-6 us a call whatever it holds, 1.2 us a live row, 1.7-1.8
 #     us a block of 128 tokens).
-#   * `_paged_attn_kernel` (per-step-write path, SWARMDB_CHUNKED=0) and
-#     the `_quant` twins: grid (B, maxp) with the page axis innermost, one
-#     page a grid step through a BlockSpec whose index_map picks the
-#     physical page. Dead iterations (j beyond the slot's live pages)
-#     remap to the SAME page as the last live step, and Pallas skips the
-#     DMA for a block whose indices didn't change — HBM traffic is ~live
-#     pages, but every dead step is still a grid step (about 0.18 us on
-#     v5e: 0.7 ms a call at a 256-page table whatever the rows hold,
-#     PERF.md section 6, PR 30).
+#   * the int8 twin `_paged_chunk_attn_kernel_quant` (further down): grid
+#     (B, maxp + 1) with the page axis innermost, one page a grid step
+#     through a BlockSpec whose index_map picks the physical page, the
+#     chunk buffer the last step. Dead iterations (j beyond the slot's
+#     live pages) remap to the SAME page as the last live step, and
+#     Pallas skips the DMA for a block whose indices didn't change — HBM
+#     traffic is ~live pages, but every dead step is still a grid step
+#     (about 0.18 us on v5e: 0.7 ms a call at a 256-page table whatever
+#     the rows hold, PERF.md section 6, PR 30).
 
 
 def _online_update(h, s, v, acc_ref, m_ref, l_ref):
@@ -224,37 +224,6 @@ def _attend_tile(q_ref, k_tile_ref, v_tile_ref, valid, n_kv_heads,
         ) * scale                                      # [G, Tk]
         _online_update(h, jnp.where(valid, s, -1e30), v[:, h, :],
                        acc_ref, m_ref, l_ref)
-
-
-def _paged_attn_kernel(table_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                       acc_ref, m_ref, l_ref, *, page_size: int,
-                       n_kv_heads: int, window):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    maxp = pl.num_programs(1)
-    length = len_ref[b]
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = n_kv_heads
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j * page_size < length)
-    def _compute():
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)              # [1, ps] global pos
-        valid = pos < length
-        if window is not None:
-            valid &= pos > (length - 1 - window)
-        _attend_tile(q_ref, k_ref, v_ref, valid, Hkv, acc_ref, m_ref, l_ref)
-
-    @pl.when(j == maxp - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)    # inactive slot: 0/eps
-        o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
 
 
 # VMEM the chunked decode kernel's page loop may hold for one block of
@@ -926,70 +895,13 @@ def decode_gqa_attention_chunked(
     return out
 
 
-@functools.partial(
-    jax.jit, static_argnames=("window", "interpret")
-)
-def paged_decode_gqa_attention(
-    q: jnp.ndarray,           # [B, Hq, D] one decode query per slot
-    k_pages: jnp.ndarray,     # [P, ps, Hkv, D] single-layer page pool
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, maxp] int32
-    lengths: jnp.ndarray,     # [B] int32 valid prefix (q position + 1)
-    window=None,              # sliding-window size (None = full causal)
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Ragged paged decode attention; returns [B, Hq, D] in q.dtype."""
-    B, Hq, D = q.shape
-    _, ps, Hkv, _ = k_pages.shape
-    maxp = page_table.shape[1]
-    G = Hq // Hkv
-    table = page_table.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-
-    def q_map(b, j, table_ref, len_ref):
-        return (b, 0, 0)
-
-    def kv_map(b, j, table_ref, len_ref):
-        # dead iterations re-point at the last live page so their DMA is
-        # skipped (same indices as the previous step); length 0 -> trash 0
-        last_live = _last_live_page(len_ref[b], ps)
-        return (table_ref[b, jnp.minimum(j, last_live)], 0, 0, 0)
-
-    def o_map(b, j, table_ref, len_ref):
-        return (b, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, maxp),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), q_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), o_map),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, G, D), jnp.float32),    # acc
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running max (bcast)
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running denom (bcast)
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel, page_size=ps, n_kv_heads=Hkv,
-                          window=window),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(table, lengths, q, k_pages, v_pages)
-    return out
-
-
 # ---------------------------------------------------------------------------
 # Quantized-pool kernel variants (SWARMDB_KV_DTYPE=int8, ISSUE 18).
 #
 # The grid form of the paged kernels (page axis in the grid, DMA-skip
-# index maps: `(B, maxp)`, and `(B, maxp + 1)` for the chunked twin with
-# the chunk segment as its last step), the same online softmax as the
-# kernels above — the ONLY difference is the KV operands: int8 page
+# index maps: `(B, maxp + 1)` for the chunked decode twin with the chunk
+# segment as its last step), the same online softmax as the kernels
+# above — the ONLY difference is the KV operands: int8 page
 # payloads plus a per-page-per-head f32 scale operand shaped [P, 1, Hkv]
 # (block (1, 1, Hkv), whole in its last two dims — Mosaic-legal — and
 # indexed by the SAME page map as the payload, so a page's scale row
@@ -997,101 +909,6 @@ def paged_decode_gqa_attention(
 # `_attend_tile` in VMEM: HBM sees half the bytes, the MXU still runs
 # f32. Suffix streams and in-chunk buffers stay full precision — only
 # what lives in the POOL is quantized.
-
-
-def _paged_attn_kernel_quant(table_ref, len_ref, q_ref, k_ref, ks_ref,
-                             v_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref,
-                             *, page_size: int, n_kv_heads: int, window):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-    maxp = pl.num_programs(1)
-    length = len_ref[b]
-    Hq, D = q_ref.shape[1], q_ref.shape[2]
-    Hkv = n_kv_heads
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, -1e30)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    @pl.when(j * page_size < length)
-    def _compute():
-        pos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        valid = pos < length
-        if window is not None:
-            valid &= pos > (length - 1 - window)
-        _attend_tile(q_ref, k_ref, v_ref, valid, Hkv, acc_ref, m_ref,
-                     l_ref, k_scale=ks_ref[...], v_scale=vs_ref[...])
-
-    @pl.when(j == maxp - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
-        o_ref[0] = (acc_ref[...] / denom).reshape(Hq, D).astype(o_ref.dtype)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "interpret"))
-def paged_decode_gqa_attention_quant(
-    q: jnp.ndarray,           # [B, Hq, D] one decode query per slot
-    k_pages: jnp.ndarray,     # [P, ps, Hkv, D] int8 single-layer pool
-    k_scale: jnp.ndarray,     # [P, Hkv] f32 per-page-per-head scales
-    v_pages: jnp.ndarray,
-    v_scale: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, maxp] int32
-    lengths: jnp.ndarray,     # [B] int32 valid prefix (q position + 1)
-    window=None,
-    interpret: bool = False,
-) -> jnp.ndarray:
-    """Quantized ragged paged decode attention; returns [B, Hq, D]."""
-    B, Hq, D = q.shape
-    P, ps, Hkv, _ = k_pages.shape
-    maxp = page_table.shape[1]
-    G = Hq // Hkv
-    table = page_table.astype(jnp.int32)
-    lengths = lengths.astype(jnp.int32)
-    ks3 = k_scale.reshape(P, 1, Hkv)
-    vs3 = v_scale.reshape(P, 1, Hkv)
-
-    def q_map(b, j, table_ref, len_ref):
-        return (b, 0, 0)
-
-    def kv_map(b, j, table_ref, len_ref):
-        last_live = _last_live_page(len_ref[b], ps)
-        return (table_ref[b, jnp.minimum(j, last_live)], 0, 0, 0)
-
-    def sc_map(b, j, table_ref, len_ref):
-        last_live = _last_live_page(len_ref[b], ps)
-        return (table_ref[b, jnp.minimum(j, last_live)], 0, 0)
-
-    def o_map(b, j, table_ref, len_ref):
-        return (b, 0, 0)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(B, maxp),
-        in_specs=[
-            pl.BlockSpec((1, Hq, D), q_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, 1, Hkv), sc_map),
-            pl.BlockSpec((1, ps, Hkv, D), kv_map),
-            pl.BlockSpec((1, 1, Hkv), sc_map),
-        ],
-        out_specs=pl.BlockSpec((1, Hq, D), o_map),
-        scratch_shapes=[
-            pltpu.VMEM((Hkv, G, D), jnp.float32),    # acc
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running max (bcast)
-            pltpu.VMEM((Hkv, G, 128), jnp.float32),  # running denom (bcast)
-        ],
-    )
-    out = pl.pallas_call(
-        functools.partial(_paged_attn_kernel_quant, page_size=ps,
-                          n_kv_heads=Hkv, window=window),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hq, D), q.dtype),
-        interpret=interpret,
-    )(table, lengths, q, k_pages, ks3, v_pages, vs3)
-    return out
 
 
 def _paged_chunk_attn_kernel_quant(table_ref, start_ref, step_ref, q_ref,

@@ -147,56 +147,6 @@ def _record_static_vmem(kernel: str, key: str, dims) -> None:
         pass
 
 
-def paged_attention_dispatch(
-    q: jnp.ndarray,          # [B, 1, Hq, D] (decode only)
-    k_pages: jnp.ndarray,    # [P, ps, Hkv, D]
-    v_pages: jnp.ndarray,
-    page_table: jnp.ndarray,  # [B, maxp]
-    q_positions: jnp.ndarray,  # [B, 1]
-    *,
-    window: Optional[int] = None,
-) -> jnp.ndarray:
-    """Decode attention over the paged pool: ragged Pallas kernel on TPU,
-    XLA page-gather fallback elsewhere. Returns [B, 1, Hq, D].
-
-    Accepts a plain pool OR an int8 :class:`~..ops.paged_kv.QuantPool`
-    (SWARMDB_KV_DTYPE=int8): the quantized pool routes to the in-kernel
-    dequant kernel variant; the gather fallback dequantizes to a dense
-    f32 view inside ``paged_gather_kv``."""
-    from .paged_kv import is_quantized, paged_gather_kv, pool_data
-
-    kd = pool_data(k_pages)
-    if _paged_pallas_enabled(page_table.shape[1] * kd.shape[1]):
-        lengths = (q_positions[:, 0] + 1).astype(jnp.int32)
-        interp = jax.default_backend() != "tpu"
-        if is_quantized(k_pages):
-            from .attention_pallas import paged_decode_gqa_attention_quant
-
-            _record_static_vmem(
-                "_paged_attn_kernel_quant", "kernel:pallas-int8",
-                {"Hq": q.shape[2], "Hkv": kd.shape[2],
-                 "D": q.shape[3], "ps": kd.shape[1]})
-            out = paged_decode_gqa_attention_quant(
-                q[:, 0], k_pages.data, k_pages.scale,
-                v_pages.data, v_pages.scale, page_table, lengths,
-                window=window, interpret=interp,
-            )
-            return out[:, None]
-        from .attention_pallas import paged_decode_gqa_attention
-
-        _record_static_vmem(
-            "_paged_attn_kernel", "kernel:pallas",
-            {"Hq": q.shape[2], "Hkv": kd.shape[2],
-             "D": q.shape[3], "ps": kd.shape[1]})
-        out = paged_decode_gqa_attention(
-            q[:, 0], k_pages, v_pages, page_table, lengths,
-            window=window, interpret=interp,
-        )
-        return out[:, None]
-    kg, vg = paged_gather_kv(k_pages, v_pages, page_table)
-    return gqa_attention(q, kg, vg, q_positions, window=window)
-
-
 def paged_attention_dispatch_chunked(
     q: jnp.ndarray,           # [B, 1, Hq, D] decode query
     k_pages: jnp.ndarray,     # [P, ps, Hkv, D] single-layer pool (FROZEN)
@@ -821,18 +771,21 @@ def gqa_attention_prefix(
 
 
 # --- kerncheck: interpreter-mode kernel sanitizer (obs/kerncheck.py) ----
-# SWARMDB_KERNCHECK=1 swaps the TPU-gated dispatchers for shadow-checked
-# wrappers: every concrete (non-traced) call re-runs the kernel through
-# the numpy grid interpreter with canary-poisoned outputs and bounds-
-# checked Refs, then asserts parity against the dispatched result. Flag
+# SWARMDB_KERNCHECK=1 swaps the TPU-gated dispatchers for checked
+# wrappers. Every concrete (non-traced) ragged prefill call re-runs the
+# kernel through the numpy grid interpreter with canary-poisoned outputs
+# and bounds-checked Refs, then asserts parity against the dispatched
+# result; every concrete chunked decode call is held to the XLA gather
+# form (live rows in parity, exact zeros off the live-row list). Flag
 # off, this block never runs and the module exports the plain function
 # objects — type identity is pinned by tests/test_kernelcheck.py.
 if os.environ.get("SWARMDB_KERNCHECK", "0") == "1":
-    from ..obs.kerncheck import (checked_paged_attention_dispatch,
+    from ..obs.kerncheck import (checked_paged_attention_dispatch_chunked,
                                  checked_ragged_prefill_dispatch)
 
-    paged_attention_dispatch = checked_paged_attention_dispatch(
-        paged_attention_dispatch)
+    paged_attention_dispatch_chunked = (
+        checked_paged_attention_dispatch_chunked(
+            paged_attention_dispatch_chunked))
     ragged_prefill_dispatch = checked_ragged_prefill_dispatch(
         ragged_prefill_dispatch)
 

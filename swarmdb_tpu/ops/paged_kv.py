@@ -401,47 +401,6 @@ def init_paged_kv_cache(
     }
 
 
-def paged_write_decode(
-    k_pages: jnp.ndarray,   # [P, ps, Hkv, D] (single layer)
-    v_pages: jnp.ndarray,
-    k: jnp.ndarray,         # [B, 1, Hkv, D]
-    v: jnp.ndarray,
-    positions: jnp.ndarray,  # [B, 1] absolute write positions
-    page_table: jnp.ndarray,  # [B, maxp]
-) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Scatter one decode token per slot into its page.
-
-    Writes at positions >= maxp*ps (chunk overshoot on full lanes; the
-    engine keeps max_seq a page multiple so this cap == max_seq) and
-    writes from inactive slots (zeroed table rows) both land in trash
-    page 0 — see module invariants.
-    """
-    ps = pool_data(k_pages).shape[1]
-    maxp = page_table.shape[1]
-    pos = positions[:, 0]                                # [B]
-    col = jnp.minimum(pos // ps, maxp - 1)
-    page = jnp.take_along_axis(page_table, col[:, None], axis=1)[:, 0]
-    page = jnp.where(pos < maxp * ps, page, 0)           # overshoot -> trash
-    off = pos % ps
-    if isinstance(k_pages, QuantPool):
-        # one-column requant window: slots before pos survive, the new
-        # token lands at off, later slots are stale garbage -> zeroed
-        slots = jnp.arange(ps, dtype=jnp.int32)[None, :]         # [1, ps]
-        slot_pos = (col * ps)[:, None] + slots                   # [B, ps]
-        is_new = slots == off[:, None]
-        is_keep = slot_pos < pos[:, None]
-        out = []
-        for pool, tok in ((k_pages, k), (v_pages, v)):
-            q, s = _requant_window(pool.data[page], pool.scale[page],
-                                   tok[:, 0][:, None], is_new, is_keep)
-            out.append(QuantPool(pool.data.at[page].set(q),
-                                 pool.scale.at[page].set(s)))
-        return out[0], out[1]
-    k_pages = k_pages.at[page, off].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[page, off].set(v[:, 0].astype(v_pages.dtype))
-    return k_pages, v_pages
-
-
 def paged_gather_kv(
     k_pages: jnp.ndarray,   # [P, ps, Hkv, D] (single layer)
     v_pages: jnp.ndarray,
@@ -574,9 +533,11 @@ def paged_write_chunk(
     scatter per chunk instead of one per step (the paged counterpart of
     ops/layers.merge_chunk_kv).
 
-    Same trash-page invariants as :func:`paged_write_decode`: positions
-    past the table's coverage and rows with zeroed (retired/inactive)
-    table entries land in trash page 0 and are never read.
+    Trash-page invariants (see the module's): positions past the table's
+    coverage (chunk overshoot on full lanes; the engine keeps max_seq a
+    page multiple so this cap == max_seq) and rows with zeroed
+    (retired/inactive) table entries land in trash page 0 and are never
+    read.
     """
     L = pool_data(k_pages).shape[0]
     ps = pool_data(k_pages).shape[2]
@@ -645,7 +606,7 @@ def paged_write_ragged(
     ``row_tables[tok_row[t], tok_pos[t] // ps]`` offset ``tok_pos[t] %
     ps``. Padding tokens (row id out of range, or positions past the
     table's coverage) land in trash page 0 — the same invariants as
-    :func:`paged_write_decode` / :func:`paged_write_chunk`."""
+    :func:`paged_write_chunk`."""
     ps = pool_data(k_pages).shape[2]
     R, maxp = row_tables.shape
     if isinstance(k_pages, QuantPool):

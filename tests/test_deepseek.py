@@ -555,8 +555,6 @@ def test_every_path_that_cannot_carry_latent_pages_refuses_by_name(
     refused("forward_chunked", lambda: llama.forward_chunked(
         params, cfg, tok[:, :1], tok[:, :1], (pool, pool), (pool, pool),
         jnp.int32(0)))
-    refused("forward_paged", lambda: llama.forward_paged(
-        params, cfg, tok[:, :1], tok[:, :1], {}))
     refused("forward_pipelined", lambda: llama.forward_pipelined(
         params, cfg, tok, tok, None))
     refused("forward_seq_parallel", lambda: llama.forward_seq_parallel(
@@ -571,7 +569,7 @@ def test_every_path_that_cannot_carry_latent_pages_refuses_by_name(
             lambda: serving.build_sharded_model(cfg, None))
 
 
-@pytest.mark.parametrize("env", ["SWARMDB_RAGGED_PREFILL", "SWARMDB_CHUNKED"])
+@pytest.mark.parametrize("env", ["SWARMDB_RAGGED_PREFILL"])
 def test_the_engine_refuses_the_paths_without_latent_pages(monkeypatch, env):
     from swarmdb_tpu.backend.service import build_backend_engine
 

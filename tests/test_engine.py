@@ -457,8 +457,7 @@ def test_precompile_cache_covers_warmup(compile_cache_dir):
     entries — any spec/shape/dtype/arg-order/donation mismatch between
     the plan and warmup's real calls shows up as a fresh compile here.
     Covers the paged branches the inline-lowering test cannot."""
-    from swarmdb_tpu.backend.engine import PagedKV
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
+    from paged_engine import paged_engine
     import swarmdb_tpu.utils.xla_cache as xla_cache
 
     cfg = TINY_DEBUG
@@ -479,24 +478,9 @@ def test_precompile_cache_covers_warmup(compile_cache_dir):
         prefix_pages=4, prefix_page_size=8,
     )
     ps, num_pages = 8, 17  # 2 rows x 8 pages/row + trash
-    paged = Engine(
-        fwd, init_cache, params, max_batch=2, max_seq=64, eos_id=2,
-        prefill_buckets=[8],
-        paged=PagedKV(
-            decode_forward=lambda p, t, pos, c:
-                llama.forward_paged(p, cfg, t, pos, c),
-            init_pool=lambda: llama.init_paged_cache(
-                cfg, 2, 64, num_pages, ps),
-            page_size=ps, num_pages=num_pages,
-            allocator=PageAllocator(num_pages, ps, 64, 2),
-        ),
-        prefix_fns=(
-            lambda p, t, tab, pl, pk, pv, logits_at=None:
-                llama.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
-                                           logits_at=logits_at),
-            None,
-        ),
-    )
+    paged = paged_engine(
+        cfg, params, max_batch=2, max_seq=64, page_size=ps,
+        num_pages=num_pages, prefix=True, eos_id=2, prefill_buckets=[8])
     for eng in (dense, paged):
         eng.precompile(parallel=2)
     before = xla_cache.persistent_cache_programs(str(cache_dir))
@@ -507,6 +491,28 @@ def test_precompile_cache_covers_warmup(compile_cache_dir):
     assert after == before, (
         f"warmup compiled {len(after - before)} programs precompile "
         f"missed — warmup_call_plan() drifted from warmup()")
+
+
+def test_a_page_pool_brings_its_chunk_triple():
+    """The pairing of a pool with the forward it is decoded with is
+    PagedKV's: a spec cannot be built without the triple, the engine
+    decodes with it, and an engine handed a second triple beside a page
+    pool refuses by name (``chunked_fns`` is the dense slab's)."""
+    from paged_engine import paged_chunk_fns, paged_engine
+    from swarmdb_tpu.backend.engine import PagedKV
+    from swarmdb_tpu.ops.paged_kv import PageAllocator
+
+    cfg = TINY_DEBUG
+    with pytest.raises(TypeError, match="chunked_fns"):
+        PagedKV(init_pool=lambda: None, page_size=8, num_pages=17,
+                allocator=PageAllocator(17, 8, 64, 2))
+    params = llama.init_params(cfg, jax.random.PRNGKey(0))
+    kw = dict(max_batch=2, max_seq=64, page_size=8, num_pages=17, eos_id=2,
+              prefill_buckets=[8])
+    eng = paged_engine(cfg, params, **kw)
+    assert eng._chunked_fns is eng.paged.chunked_fns
+    with pytest.raises(ValueError, match=r"PagedKV\.chunked_fns"):
+        paged_engine(cfg, params, chunked_fns=paged_chunk_fns(cfg), **kw)
 
 
 def test_warmup_parallel_env_is_forgiving(monkeypatch):
@@ -539,25 +545,13 @@ def _paged_tiny_engine(**kw):
     """Single-chip paged engine (the device-resident session path is the
     DEFAULT for single-shard paged engines; SWARMDB_EMIT_RING=0 pins the
     per-chunk scan+pipeline path)."""
-    from swarmdb_tpu.backend.engine import PagedKV
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
+    from paged_engine import paged_engine
 
     cfg = TINY_DEBUG
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    ps, num_pages = 8, 41
-    return Engine(
-        lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c),
-        lambda b, s: llama.init_kv_cache(cfg, b, s),
-        params, max_batch=2, max_seq=96, eos_id=2, seed=0,
-        prefill_buckets=[16, 32],
-        paged=PagedKV(
-            decode_forward=lambda p, t, pos, c:
-                llama.forward_paged(p, cfg, t, pos, c),
-            init_pool=lambda: llama.init_paged_cache(
-                cfg, 2, 96, num_pages, ps),
-            page_size=ps, num_pages=num_pages,
-            allocator=PageAllocator(num_pages, ps, 96, 2)),
-        **kw)
+    return paged_engine(
+        cfg, params, max_batch=2, max_seq=96, page_size=8, num_pages=41,
+        eos_id=2, seed=0, prefill_buckets=[16, 32], **kw)
 
 
 def test_resident_matches_scan_path_tokens(monkeypatch):

@@ -322,6 +322,18 @@ def test_consumer_group_continuity_across_partition_move():
                     assert time.monotonic() < deadline
                     time.sleep(0.02)
         assert client.wait_durable("t", part, off, 5.0)
+        # durable is a quorum: the third node may still hold nothing, and
+        # a follower whose end is behind the trim point cannot catch up
+        # by replication any more ("partition needs re-seeding": it would
+        # never converge below). The trim waits for every peer's log.
+        def holds_all(nid):
+            try:
+                return harness.nodes[nid].broker.end_offset("t", part) == 40
+            except Exception:       # the topic has not reached it yet
+                return False
+
+        wait_until(lambda: all(holds_all(n) for n in ("n0", "n1", "n2")),
+                   10.0, what="the 40 records on every peer")
         client.commit_offset("workers", "t", part, 30)
         client.trim_older_than("t", 1500.0)
         old_leader = cluster.read()["assignments"][tp_key("t", part)]

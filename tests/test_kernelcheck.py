@@ -71,7 +71,6 @@ def test_static_vmem_table_covers_in_tree_kernels():
     rows = static_vmem_table()
     kernels = {r["kernel"] for r in rows}
     assert "_ragged_prefill_kernel" in kernels
-    assert "_paged_attn_kernel" in kernels
     assert "_paged_chunk_attn_kernel" in kernels
     for r in rows:
         assert r["formula"]
@@ -172,6 +171,14 @@ def kerncheck_on(monkeypatch, tmp_path):
     kerncheck.registry().reset()
 
 
+def _unwrapped(fn):
+    """The plain dispatcher under whatever the CI kerncheck job wrapped
+    it in at import."""
+    while hasattr(fn, "__wrapped__"):
+        fn = fn.__wrapped__
+    return fn
+
+
 def test_factories_return_plain_functions_when_off(monkeypatch):
     """The zero-overhead contract: flag off = the checked factories hand
     back the exact function objects they were given (type identity, not
@@ -183,7 +190,7 @@ def test_factories_return_plain_functions_when_off(monkeypatch):
         return None
 
     assert kerncheck.checked_ragged_prefill_dispatch(fn) is fn
-    assert kerncheck.checked_paged_attention_dispatch(fn) is fn
+    assert kerncheck.checked_paged_attention_dispatch_chunked(fn) is fn
     assert kerncheck.checked_paged_write_ragged(fn) is fn
 
 
@@ -197,8 +204,8 @@ def test_dispatch_module_binding_matches_flag():
     wrapped = os.environ.get("SWARMDB_KERNCHECK", "0") == "1"
     assert hasattr(layers.ragged_prefill_dispatch, "__wrapped__") \
         == wrapped
-    assert hasattr(layers.paged_attention_dispatch, "__wrapped__") \
-        == wrapped
+    assert hasattr(layers.paged_attention_dispatch_chunked,
+                   "__wrapped__") == wrapped
     assert hasattr(paged_kv.paged_write_ragged, "__wrapped__") == wrapped
 
 
@@ -356,9 +363,7 @@ def test_checked_write_replay_parity_clean(kerncheck_on):
         np.array([[3, 4, 0], [5, 0, 0], [6, 7, 0]], np.int32))
     tok_row = jnp.asarray(np.array([0, 0, 1, 1, 1, 2, 5, 5], np.int32))
     tok_pos = jnp.asarray(np.array([3, 4, 0, 1, 2, 7, 0, 0], np.int32))
-    base = paged_write_ragged
-    while hasattr(base, "__wrapped__"):      # unwrap under the CI job
-        base = base.__wrapped__
+    base = _unwrapped(paged_write_ragged)
     f = kerncheck_on.checked_paged_write_ragged(base)
     assert f is not base                     # flag on: wrapped
     f(kp, vp, sk, sv, tok_row, tok_pos, tables)
@@ -369,14 +374,154 @@ def test_checked_write_replay_parity_clean(kerncheck_on):
 
 def test_differential_parity_in_tree(kerncheck_on):
     """Randomized kernel-vs-reference differentials (mixed lens, page
-    crossings, empty rows, splits): zero mismatching rounds, zero
-    violations."""
+    crossings, empty rows, splits; for the chunked decode kernel empty
+    slots off the live-row list and a chunk buffer part full): zero
+    mismatching rounds, zero violations."""
     assert kerncheck_on.differential_ragged_prefill(seed=0, rounds=2) == 0
     assert kerncheck_on.differential_paged_decode(seed=0, rounds=2) == 0
     assert kerncheck_on.registry().violations() == []
     checks = kerncheck_on.registry().report()["checks"]
     assert checks["differential.ragged-prefill"] == 2
     assert checks["differential.paged-decode"] == 2
+
+
+def test_differential_paged_decode_int8_in_tree(kerncheck_on):
+    """The int8 twin of the chunked decode kernel, which walks every
+    slot, against the quantized gather path: zero mismatching rounds."""
+    assert kerncheck_on.differential_paged_decode(
+        seed=3, rounds=3, quantized=True) == 0
+    assert kerncheck_on.registry().violations() == []
+    assert kerncheck_on.registry().report()["checks"][
+        "differential.paged-decode.int8"] == 3
+
+
+def test_differential_paged_decode_catches_a_row_left_off_the_walk(
+        kerncheck_on, monkeypatch):
+    """The differential can fail: a kernel handed one live row fewer
+    than the table holds leaves that row zero, and every round is a
+    parity mismatch naming the chunked kernel."""
+    from swarmdb_tpu.ops import paged_kv
+
+    real = paged_kv.live_row_list
+
+    def one_short(table):
+        rows, n_live = real(table)
+        return rows, n_live - 1
+
+    monkeypatch.setattr(paged_kv, "live_row_list", one_short)
+    assert kerncheck_on.differential_paged_decode(seed=0, rounds=2) == 2
+    (v, *_rest) = kerncheck_on.registry().violations()
+    assert v["kind"] == "parity"
+    assert v["kernel"] == "paged_decode_gqa_attention_chunked"
+
+
+def _chunked_dispatch_case(seed=5, quantized=False):
+    """Concrete operands of ``paged_attention_dispatch_chunked``: four
+    slots, slot 2 holds no sequence (all-trash table row) and is off the
+    live-row list; prefixes mid-page, page-aligned and empty; step 1 of a
+    chunk of 4."""
+    from swarmdb_tpu.ops.paged_kv import (QuantPool, _quantize_pages,
+                                          live_row_list)
+
+    rng = np.random.default_rng(seed)
+    B, Hkv, G, D, ps, maxp, Kc = 4, 2, 2, 8, 4, 3, 4
+    P = 1 + B * maxp
+    table = np.arange(1, 1 + B * maxp, dtype=np.int32).reshape(B, maxp)
+    table[2] = 0
+    starts = np.asarray([ps + 1, 2 * ps, 0, 0], np.int32)
+    step = jnp.int32(1)
+    f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape),
+                                     jnp.float32)
+    kp, vp = f32(P, ps, Hkv, D), f32(P, ps, Hkv, D)
+    if quantized:
+        kp, vp = (QuantPool(*_quantize_pages(p)) for p in (kp, vp))
+    table = jnp.asarray(table)
+    return dict(
+        args=(f32(B, 1, Hkv * G, D), kp, vp, table, f32(B, Kc, Hkv, D),
+              f32(B, Kc, Hkv, D), jnp.asarray(starts + 1)[:, None], step),
+        live_rows=live_row_list(table), dead=2)
+
+
+def _plain_chunked_dispatch():
+    from swarmdb_tpu.ops import layers
+
+    return _unwrapped(layers.paged_attention_dispatch_chunked)
+
+
+@pytest.mark.parametrize("quantized", [False, True], ids=["bf16", "int8"])
+def test_checked_chunked_dispatch_clean_on_the_kernel(kerncheck_on,
+                                                      monkeypatch,
+                                                      quantized):
+    """The checker on the dispatch the cells run (SWARMDB_PALLAS=1: the
+    kernel, interpreted off the chip): live rows agree with the gather
+    form, the slot off the list reads exact zeros from the kernel that
+    walks the list, no violation; one check is tallied a call."""
+    monkeypatch.setenv("SWARMDB_PALLAS", "1")
+    if quantized:
+        monkeypatch.setenv("SWARMDB_KV_DTYPE", "int8")
+    case = _chunked_dispatch_case(quantized=quantized)
+    f = kerncheck_on.checked_paged_attention_dispatch_chunked(
+        _plain_chunked_dispatch())
+    assert f is not _plain_chunked_dispatch()    # flag on: wrapped
+    out = np.asarray(f(*case["args"], live_rows=case["live_rows"]))
+    assert kerncheck_on.registry().violations() == []
+    assert kerncheck_on.registry().report()["checks"][
+        "dispatch.paged-decode-chunked"] == 1
+    assert out[case["dead"]].any() == quantized   # int8: every slot walked
+    # where the dispatch gathers, the check runs the kernel itself on the
+    # same operands and holds that to the gather form
+    monkeypatch.setenv("SWARMDB_PALLAS", "0")
+    f(*case["args"], live_rows=case["live_rows"])
+    assert kerncheck_on.registry().violations() == []
+    assert kerncheck_on.registry().report()["checks"][
+        "dispatch.paged-decode-chunked"] == 2
+
+
+@pytest.mark.parametrize("planted,kind", [
+    ("dead-row", "dead-row"), ("live-row", "parity")])
+def test_checked_chunked_dispatch_catches_planted_faults(kerncheck_on,
+                                                         monkeypatch,
+                                                         planted, kind):
+    """A dispatch that writes a slot it was not handed breaks the
+    kernel's contract (exact zeros off the live-row list); one that
+    returns a wrong live row is a parity violation. Each names the
+    chunked kernel."""
+    monkeypatch.setenv("SWARMDB_PALLAS", "1")
+    case = _chunked_dispatch_case()
+    base = _plain_chunked_dispatch()
+    row = case["dead"] if planted == "dead-row" else 0
+
+    def rogue(*args, **kw):
+        return base(*args, **kw).at[row, 0, 0, 0].add(0.5)
+
+    f = kerncheck_on.checked_paged_attention_dispatch_chunked(rogue)
+    f(*case["args"], live_rows=case["live_rows"])
+    (v,) = kerncheck_on.registry().violations()
+    assert v["kind"] == kind
+    assert v["kernel"] == "paged_decode_gqa_attention_chunked"
+
+
+def test_checked_chunked_dispatch_checks_the_kernel_where_it_gathered(
+        kerncheck_on, monkeypatch):
+    """SWARMDB_PALLAS=0 (as on the CPU without the flag, or under the
+    kv-span threshold): the dispatch returns the gather form, and the
+    check is still of the kernel, never of the gather form against
+    itself: a kernel that leaves a live row wrong is a parity violation
+    there too."""
+    from swarmdb_tpu.ops import attention_pallas
+
+    monkeypatch.setenv("SWARMDB_PALLAS", "0")
+    real = attention_pallas.paged_decode_gqa_attention_chunked
+    monkeypatch.setattr(
+        attention_pallas, "paged_decode_gqa_attention_chunked",
+        lambda *a, **kw: real(*a, **kw).at[0, 0, 0].add(0.5))
+    case = _chunked_dispatch_case()
+    f = kerncheck_on.checked_paged_attention_dispatch_chunked(
+        _plain_chunked_dispatch())
+    f(*case["args"], live_rows=case["live_rows"])
+    (v,) = kerncheck_on.registry().violations()
+    assert v["kind"] == "parity"
+    assert v["kernel"] == "paged_decode_gqa_attention_chunked"
 
 
 def test_checked_dispatch_catches_wrong_output(kerncheck_on):

@@ -35,7 +35,6 @@ from swarmdb_tpu.ops.paged_kv import (
     kv_quantized,
     paged_gather_kv,
     paged_write_chunk,
-    paged_write_decode,
     paged_write_ragged,
     pages_per_slot,
     pool_dtype,
@@ -72,21 +71,24 @@ def test_plain_dtypes_bit_identical_to_explicit(monkeypatch, name, dt):
     unquantized configs cannot drift)."""
     rng = np.random.default_rng(0)
     L, P, ps, Hkv, D = 2, 5, 4, 2, 8
-    k = jnp.asarray(rng.standard_normal((1, 1, Hkv, D)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 1, Hkv, D)), jnp.float32)
+    k = jnp.asarray(rng.standard_normal((L, 1, 1, Hkv, D)), jnp.float32)
+    v = jnp.asarray(rng.standard_normal((L, 1, 1, Hkv, D)), jnp.float32)
     table = jnp.asarray([[1, 2, 3]], jnp.int32)
 
     def run(dtype_arg):
         cache = init_paged_kv_cache(L, P, ps, Hkv, D, 1, 12, dtype_arg)
-        kl, vl = pool_layer(cache["k"], 0), pool_layer(cache["v"], 0)
-        return paged_write_decode(
-            kl, vl, k.astype(kl.dtype), v.astype(vl.dtype),
-            jnp.asarray([[5]], jnp.int32), table)
+        # a one-step chunk written at position 5
+        return paged_write_chunk(
+            cache["k"], cache["v"], k.astype(cache["k"].dtype),
+            v.astype(cache["v"].dtype), jnp.asarray([5], jnp.int32), table)
 
     monkeypatch.setenv("SWARMDB_KV_DTYPE", name)
     got_k, got_v = run(None)
     want_k, want_v = run(dt)
     assert got_k.dtype == dt
+    # ... which put the token where a numpy scatter puts it
+    assert np.array_equal(np.asarray(got_k, np.float32)[:, 2, 1],
+                          np.asarray(k.astype(dt), np.float32)[:, 0, 0])
     assert np.array_equal(np.asarray(got_k, np.float32),
                           np.asarray(want_k, np.float32))
     assert np.array_equal(np.asarray(got_v, np.float32),
@@ -216,34 +218,6 @@ def _quant_pool_fixture(seed, B, Hkv, D, ps, maxp, lengths):
     return kq, ks, vq, vs, table, rng
 
 
-@pytest.mark.parametrize("G", [1, 2, 4])
-def test_decode_quant_kernel_parity(G):
-    """In-kernel dequant == boundary dequant: the quant decode kernel
-    must match the quantized XLA gather path to fp rounding, across
-    GQA ratios and page-crossing lengths (incl. an empty slot)."""
-    from swarmdb_tpu.ops.attention_pallas import (
-        paged_decode_gqa_attention_quant)
-    from swarmdb_tpu.ops.layers import gqa_attention
-
-    B, Hkv, D, ps, maxp = 4, 2, 16, 8, 3
-    Hq = Hkv * G
-    lengths = np.asarray([5, ps, 2 * ps + 3, 0], np.int32)
-    kq, ks, vq, vs, table, rng = _quant_pool_fixture(
-        10 + G, B, Hkv, D, ps, maxp, lengths)
-    q = jnp.asarray(rng.standard_normal((B, Hq, D)), jnp.float32)
-
-    got = np.asarray(paged_decode_gqa_attention_quant(
-        q, kq, ks, vq, vs, jnp.asarray(table), jnp.asarray(lengths),
-        interpret=True))
-    kg, vg = paged_gather_kv(QuantPool(kq, ks), QuantPool(vq, vs),
-                             jnp.asarray(table))
-    want = np.asarray(gqa_attention(
-        q[:, None], kg, vg,
-        jnp.asarray(np.maximum(lengths - 1, 0))[:, None])[:, 0])
-    live = lengths > 0
-    assert np.max(np.abs(got[live] - want[live])) < 2e-5
-
-
 @pytest.mark.parametrize("G", [1, 4])
 def test_ragged_quant_kernel_parity_prefix_suffix(G):
     """Ragged prefill with BOTH pool-resident (quantized) prefix pages
@@ -306,18 +280,22 @@ def test_ragged_quant_kernel_parity_prefix_suffix(G):
     assert np.max(np.abs(got[live] - want_f[live])) < 5e-2
 
 
-def test_chunked_decode_quant_kernel_parity():
-    """Quant chunked decode kernel (pool pages quantized, chunk buffer
-    full precision) vs its XLA fallback."""
+@pytest.mark.parametrize("G", [1, 2, 4])
+def test_chunked_decode_quant_kernel_parity(G):
+    """In-kernel dequant == boundary dequant: the quant chunked decode
+    kernel (pool pages quantized, chunk buffer full precision) must match
+    its XLA fallback, the quantized gather path, to fp rounding, across
+    GQA ratios and page-crossing prefix lengths (incl. an empty slot,
+    which attends its chunk buffer alone)."""
     from swarmdb_tpu.ops.layers import (paged_attention_dispatch_chunked,
                                         pallas_disabled)
 
     rng = np.random.default_rng(7)
-    B, Hkv, G, D, ps, maxp = 2, 2, 2, 16, 4, 3
+    B, Hkv, D, ps, maxp = 4, 2, 16, 8, 3
     Hq = Hkv * G
-    lengths = np.asarray([ps + 2, 2 * ps], np.int32)
+    lengths = np.asarray([5, ps, 2 * ps + 3, 0], np.int32)
     kq, ks, vq, vs, table, _ = _quant_pool_fixture(
-        40, B, Hkv, D, ps, maxp, lengths)
+        40 + G, B, Hkv, D, ps, maxp, lengths)
     pool_k, pool_v = QuantPool(kq, ks), QuantPool(vq, vs)
     Kc = 4
     step = 2
@@ -344,29 +322,29 @@ def test_chunked_decode_quant_kernel_parity():
 
 
 def test_int8_decode_write_survivors_bounded(monkeypatch):
-    """paged_write_decode on a QuantPool: the new token lands within
-    the rounding budget and survivors drift at most one requant step."""
+    """One-step chunk writes on a QuantPool, a token at a time: the new
+    token lands within the rounding budget and survivors drift at most
+    one requant step."""
     monkeypatch.setenv("SWARMDB_KV_DTYPE", "int8")
     rng = np.random.default_rng(11)
     ps, Hkv, D, maxp, B = 4, 2, 8, 3, 1
     P = 1 + maxp
     cache = init_paged_kv_cache(1, P, ps, Hkv, D, B, maxp * ps)
     table = jnp.asarray([[1, 2, 3]], jnp.int32)
-    pk = pool_layer(cache["k"], 0)
-    pv = pool_layer(cache["v"], 0)
+    pk, pv = cache["k"], cache["v"]
     history = []
     for pos in range(6):
-        k = rng.standard_normal((B, 1, Hkv, D)).astype(np.float32)
-        v = rng.standard_normal((B, 1, Hkv, D)).astype(np.float32)
-        history.append(k)
-        pk, pv = paged_write_decode(
+        k = rng.standard_normal((1, B, 1, Hkv, D)).astype(np.float32)
+        v = rng.standard_normal((1, B, 1, Hkv, D)).astype(np.float32)
+        history.append(k[0])
+        pk, pv = paged_write_chunk(
             pk, pv, jnp.asarray(k), jnp.asarray(v),
-            jnp.asarray([[pos]], jnp.int32), table)
+            jnp.asarray([pos], jnp.int32), table)
     want = np.concatenate([h[:, 0] for h in history], axis=0)  # [6,Hkv,D]
-    scl = np.asarray(pk.scale)  # [P, Hkv]
+    scl = np.asarray(pk.scale)[0]  # [P, Hkv]
     for pos in range(6):
         page = int(np.asarray(table)[0, pos // ps])
-        got = np.asarray(pk.data)[page, pos % ps].astype(np.float32) \
+        got = np.asarray(pk.data)[0, page, pos % ps].astype(np.float32) \
             * scl[page][:, None]
         assert np.max(np.abs(got - want[pos])) < \
             np.max(scl[page]) * 0.75 + 1e-5
@@ -434,8 +412,8 @@ def int8_engines():
     """Dense engine + int8-paged engine over identical params."""
     import os
 
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
+    from paged_engine import paged_engine
+    from swarmdb_tpu.backend.engine import Engine
 
     prev = os.environ.get("SWARMDB_KV_DTYPE")
     os.environ["SWARMDB_KV_DTYPE"] = "int8"
@@ -452,18 +430,10 @@ def int8_engines():
                        max_seq=max_seq, eos_id=2, seed=0,
                        prefill_buckets=[16, 32])
         dense.start()
-        paged_spec = PagedKV(
-            decode_forward=lambda p, t, pos, c: llama.forward_paged(
-                p, cfg, t, pos, c),
-            init_pool=lambda: llama.init_paged_cache(
-                cfg, max_batch, max_seq, num_pages, ps),
-            page_size=ps,
-            num_pages=num_pages,
-            allocator=PageAllocator(num_pages, ps, max_seq, max_batch),
-        )
-        paged = Engine(fwd, init_cache, params, max_batch=max_batch,
-                       max_seq=max_seq, eos_id=2, seed=0,
-                       prefill_buckets=[16, 32], paged=paged_spec)
+        paged = paged_engine(cfg, params, max_batch=max_batch,
+                             max_seq=max_seq, page_size=ps,
+                             num_pages=num_pages, eos_id=2, seed=0,
+                             prefill_buckets=[16, 32])
         paged.start()
         yield dense, paged
         dense.stop()
@@ -501,8 +471,8 @@ def test_engine_int8_greedy_drift_floor(int8_engines):
 
 
 def test_forward_paged_int8_logit_divergence(monkeypatch):
-    """Per-step logit divergence bound: paged int8 decode vs the dense
-    forward, same prefix. The bound is the parity contract obs/analyze
+    """Per-step logit divergence bound: paged int8 decode (the chunked
+    forward over the quantized pool) vs the dense forward, same prefix. The bound is the parity contract obs/analyze
     roofline A/Bs rely on (quantization is the only error source)."""
     monkeypatch.setenv("SWARMDB_KV_DTYPE", "int8")
     cfg = TINY_DEBUG
@@ -535,12 +505,14 @@ def test_forward_paged_int8_logit_divergence(monkeypatch):
 
     tok = jnp.asarray([[7], [11]], jnp.int32)
     worst = 0.0
+    chunk = llama.init_chunk_kv(cfg, B, 3)
     for step in range(3):
         dpos = jnp.asarray([[int(plen[0]) + step], [int(plen[1]) + step]],
                            jnp.int32)
         ld, dense_cache = llama.forward(params, cfg, tok, dpos, dense_cache)
-        lp, cache_paged = llama.forward_paged(params, cfg, tok, dpos,
-                                              cache_paged)
+        lp, chunk = llama.forward_paged_chunked(
+            params, cfg, tok, dpos, cache_paged, chunk,
+            jnp.asarray(step, jnp.int32))
         worst = max(worst, float(np.max(np.abs(
             np.asarray(ld) - np.asarray(lp)))))
         tok = jnp.argmax(ld[:, -1], axis=-1).astype(jnp.int32)[:, None]

@@ -407,14 +407,10 @@ def test_paths_that_cannot_carry_the_state_refuse_by_name(monkeypatch):
     monkeypatch.setenv("SWARMDB_RAGGED_PREFILL", "0")
     with pytest.raises(NotImplementedError, match="conv state"):
         _engine()
-    monkeypatch.delenv("SWARMDB_RAGGED_PREFILL")
-    monkeypatch.setenv("SWARMDB_CHUNKED", "0")
-    with pytest.raises(NotImplementedError, match="conv state"):
-        _engine()
 
 
 @pytest.mark.parametrize("path", [
-    "forward_paged", "forward_chunked", "forward_prefix_pages",
+    "forward_chunked", "forward_prefix_pages",
     "forward_pipelined", "forward_seq_parallel", "build_sharded_model",
     "rolling resume"])
 def test_a_forward_without_state_refuses(path, engine):

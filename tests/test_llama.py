@@ -269,7 +269,7 @@ def _pages_of(cache, n_tokens, ps):
 
 
 @pytest.mark.parametrize("name", [
-    "forward_chunked", "forward_paged", "forward_paged_chunked",
+    "forward_chunked", "forward_paged_chunked",
     "forward_prefix_pages", "forward_prefix_lane"])
 @pytest.mark.parametrize("family", ["dense", "moe"])
 def test_every_forward_gives_forwards_logits(family, name):
@@ -305,9 +305,6 @@ def test_every_forward_gives_forwards_logits(family, name):
         if name == "forward_chunked":
             got, chunk, *_ = llama.forward_chunked(params, cfg, tok, pos,
                                                    history, chunk, at)
-        elif name == "forward_paged":
-            got, paged, *_ = llama.forward_paged(params, cfg, tok, pos,
-                                                 paged)
         else:
             got, chunk, *_ = llama.forward_paged_chunked(
                 params, cfg, tok, pos, paged, chunk, at)

@@ -223,27 +223,19 @@ def test_churn_counters_are_flag_independent(monkeypatch):
 
 
 def _tiny_paged_engine(label):
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
+    from paged_engine import paged_engine
     from swarmdb_tpu.models import llama
     from swarmdb_tpu.models.configs import TINY_DEBUG
 
     cfg = TINY_DEBUG
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
-    init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
     max_batch, max_seq, ps = 4, 96, 16
     num_pages = 1 + 4 * pages_per_slot(max_seq, ps)
     alloc = make_page_allocator(num_pages, ps, max_seq, max_batch,
                                 label=label)
-    spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(
-            p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(
-            cfg, max_batch, max_seq, num_pages, ps),
-        page_size=ps, num_pages=num_pages, allocator=alloc)
-    eng = Engine(fwd, init_cache, params, max_batch=max_batch,
-                 max_seq=max_seq, eos_id=2, seed=0,
-                 prefill_buckets=[16, 32, 64], paged=spec)
+    eng = paged_engine(cfg, params, max_batch=max_batch, max_seq=max_seq,
+                       page_size=ps, num_pages=num_pages, allocator=alloc,
+                       eos_id=2, seed=0, prefill_buckets=[16, 32, 64])
     eng.start()
     return eng, alloc, num_pages
 

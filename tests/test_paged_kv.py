@@ -9,14 +9,16 @@ import jax.numpy as jnp
 
 from swarmdb_tpu.models import llama
 from swarmdb_tpu.models.configs import TINY_DEBUG, TINY_MOE
-from swarmdb_tpu.ops.attention_pallas import paged_decode_gqa_attention
+from swarmdb_tpu.ops.attention_pallas import (
+    paged_decode_gqa_attention_chunked)
 from swarmdb_tpu.ops.layers import gqa_attention
 from swarmdb_tpu.ops.paged_kv import (
     PageAllocator,
     init_paged_kv_cache,
+    live_row_list,
     paged_gather_kv,
     paged_insert_prefill,
-    paged_write_decode,
+    paged_write_chunk,
     pages_per_slot,
 )
 
@@ -52,6 +54,21 @@ def _ragged_fixture(seed=0, B=4, Hq=8, Hkv=2, D=32, ps=16, maxp=4,
     return q, kp, vp, table, lengths, dense_k, dense_v
 
 
+def _scatter_chunk(pool, chunk, starts, table):
+    """What ``paged_write_chunk`` must leave in one pool, token by token
+    in numpy: chunk token t of slot b lands at position ``starts[b] + t``
+    of the slot's pages, past the table's coverage in trash page 0."""
+    out = np.array(pool)
+    chunk, starts, table = (np.asarray(a) for a in (chunk, starts, table))
+    ps, maxp = out.shape[2], table.shape[1]
+    for b in range(chunk.shape[1]):
+        for t in range(chunk.shape[2]):
+            pos = int(starts[b]) + t
+            page = table[b, pos // ps] if pos < maxp * ps else 0
+            out[:, page, pos % ps] = chunk[:, b, t]
+    return out
+
+
 @pytest.mark.parametrize("window", [None, 8])
 def test_paged_kernel_matches_dense_attention(window):
     q, kp, vp, table, lengths, dk, dv = _ragged_fixture()
@@ -59,12 +76,23 @@ def test_paged_kernel_matches_dense_attention(window):
     ref = gqa_attention(jnp.asarray(q)[:, None], jnp.asarray(dk),
                         jnp.asarray(dv), jnp.asarray(qpos)[:, None],
                         window=window)[:, 0]
-    out = paged_decode_gqa_attention(
+    # the chunked kernel at step 0: the pool holds strictly the prefix
+    # (what it keeps at the query's own position is masked) and the
+    # query's own K/V is the chunk buffer's first entry, garbage behind it
+    rows = np.arange(len(lengths))
+    Kc = 4
+    ck = np.full((len(lengths), Kc) + dk.shape[2:], 7.0, np.float32)
+    cv = np.full((len(lengths), Kc) + dv.shape[2:], -7.0, np.float32)
+    ck[:, 0], cv[:, 0] = dk[rows, qpos], dv[rows, qpos]
+    out = paged_decode_gqa_attention_chunked(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-        jnp.asarray(table), jnp.asarray(lengths),
+        jnp.asarray(table), jnp.asarray(ck), jnp.asarray(cv),
+        jnp.asarray(qpos), jnp.int32(0),
+        *live_row_list(jnp.asarray(table)),
         window=window, interpret=True,
     )
     active = lengths > 0
+    assert not np.asarray(out)[~active].any()   # not walked: exact zeros
     np.testing.assert_allclose(np.asarray(out)[active],
                                np.asarray(ref)[active], atol=2e-5)
 
@@ -84,19 +112,22 @@ def test_paged_gather_matches_dense():
 
 
 def test_paged_write_routes_overshoot_and_inactive_to_trash():
-    B, ps, maxp, Hkv, D = 2, 4, 2, 1, 4
+    L, B, ps, maxp, Hkv, D = 1, 2, 4, 2, 1, 4
     P = 4
-    kp = jnp.zeros((P, ps, Hkv, D))
-    vp = jnp.zeros((P, ps, Hkv, D))
+    kp = jnp.zeros((L, P, ps, Hkv, D))
+    vp = jnp.zeros((L, P, ps, Hkv, D))
     table = jnp.asarray([[1, 2], [0, 0]], jnp.int32)  # slot1 inactive
-    k = jnp.ones((B, 1, Hkv, D))
-    v = jnp.ones((B, 1, Hkv, D))
-    # slot0 writes at position >= maxp*ps (overshoot), slot1 at 0 (inactive)
-    pos = jnp.asarray([[maxp * ps + 1], [0]], jnp.int32)
-    kp2, _ = paged_write_decode(kp, vp, k, v, pos, table)
-    assert np.asarray(kp2[1]).sum() == 0  # live pages untouched
-    assert np.asarray(kp2[2]).sum() == 0
-    assert np.asarray(kp2[0]).sum() > 0   # both landed in trash page 0
+    k = jnp.ones((L, B, 1, Hkv, D))
+    v = jnp.ones((L, B, 1, Hkv, D))
+    # a one-step chunk: slot0 writes at position >= maxp*ps (overshoot),
+    # slot1 at 0 (inactive)
+    starts = jnp.asarray([maxp * ps + 1, 0], jnp.int32)
+    kp2, _ = paged_write_chunk(kp, vp, k, v, starts, table)
+    want = _scatter_chunk(kp, k, starts, table)   # the numpy scatter
+    np.testing.assert_array_equal(np.asarray(kp2[:, 1:]), want[:, 1:])
+    assert np.asarray(kp2[0, 1]).sum() == 0  # live pages untouched
+    assert np.asarray(kp2[0, 2]).sum() == 0
+    assert np.asarray(kp2[0, 0]).sum() > 0   # both landed in trash page 0
 
 
 def test_paged_insert_prefill_scatters_chunks():
@@ -154,6 +185,16 @@ def test_pages_needed_caps_at_maxp():
 # model forward parity (dense vs paged cache, decode steps)
 
 
+def _slab_of(pool, table):
+    """The dense slab ``[L, B, maxp * ps, Hkv, D]`` a pool and a page
+    table describe: row b's pages in its table's order (the trash page's
+    content where the table holds none). What the plain ``llama.forward``
+    decodes over, as the reference of the paged forwards."""
+    pages = jnp.asarray(pool)[:, jnp.asarray(table)]  # [L, B, maxp, ps, ..]
+    L, B, maxp, ps = pages.shape[:4]
+    return pages.reshape((L, B, maxp * ps) + pages.shape[4:])
+
+
 def test_llama_forward_paged_matches_dense():
     cfg = TINY_DEBUG
     key = jax.random.PRNGKey(0)
@@ -183,17 +224,28 @@ def test_llama_forward_paged_matches_dense():
     )
     cache_paged = {"k": pk, "v": pv, "page_table": jnp.asarray(table)}
 
-    # run a few decode steps through both paths; logits must match
+    # run a few decode steps through both paths (the paged one over the
+    # frozen pool and a chunk buffer); logits must match
     tok = jnp.asarray([[7], [11]], jnp.int32)
+    chunk = llama.init_chunk_kv(cfg, B, 3)
     for step in range(3):
         dpos = jnp.asarray([[int(plen[0]) + step], [int(plen[1]) + step]],
                            jnp.int32)
         ld, dense_cache = llama.forward(params, cfg, tok, dpos, dense_cache)
-        lp, cache_paged = llama.forward_paged(params, cfg, tok, dpos,
-                                              cache_paged)
+        lp, chunk = llama.forward_paged_chunked(
+            params, cfg, tok, dpos, cache_paged, chunk,
+            jnp.asarray(step, jnp.int32))
         np.testing.assert_allclose(np.asarray(ld), np.asarray(lp),
                                    rtol=1e-4, atol=1e-4)
         tok = jnp.argmax(ld[:, -1], axis=-1).astype(jnp.int32)[:, None]
+    # and the chunk's one write leaves the pages the slab's rows
+    merged = llama.merge_paged_chunk(cache_paged, chunk, jnp.asarray(plen))
+    for name, slab in zip(("k", "v"), dense_cache):
+        for b in range(B):
+            n = int(plen[b]) + 3
+            np.testing.assert_array_equal(
+                np.asarray(_slab_of(merged[name], table))[:, b, :n],
+                np.asarray(slab)[:, b, :n])
 
 
 # ---------------------------------------------------------------------------
@@ -202,7 +254,8 @@ def test_llama_forward_paged_matches_dense():
 
 @pytest.fixture(scope="module")
 def engines():
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
+    from paged_engine import paged_engine
+    from swarmdb_tpu.backend.engine import Engine
 
     cfg = TINY_DEBUG
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
@@ -216,21 +269,11 @@ def engines():
                    prefill_buckets=[16, 32, 64])
     dense.start()
 
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
     # pool HALF of full coverage: 2 slots' worth -> exercises admission
     # stalls + page reuse
-    num_pages = 1 + 2 * maxp
-    paged_spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(
-            cfg, max_batch, max_seq, num_pages, ps),
-        page_size=ps,
-        num_pages=num_pages,
-        allocator=PageAllocator(num_pages, ps, max_seq, max_batch),
-    )
-    paged = Engine(fwd, init_cache, params, max_batch=max_batch,
-                   max_seq=max_seq, eos_id=2, seed=0,
-                   prefill_buckets=[16, 32, 64], paged=paged_spec)
+    paged = paged_engine(cfg, params, max_batch=max_batch, max_seq=max_seq,
+                         page_size=ps, num_pages=1 + 2 * maxp, eos_id=2,
+                         seed=0, prefill_buckets=[16, 32, 64])
     paged.start()
     yield dense, paged
     dense.stop()
@@ -282,24 +325,16 @@ def test_engine_paged_pool_contention(engines):
 def test_engine_paged_oversized_request_rejected():
     """A request whose worst-case footprint exceeds the ENTIRE pool must be
     rejected at submit, not deadlock admission forever."""
-    from swarmdb_tpu.backend.engine import Engine, GenRequest, PagedKV
+    from paged_engine import paged_engine
+    from swarmdb_tpu.backend.engine import GenRequest
     from swarmdb_tpu.backend.sampling import SamplingParams
 
     cfg = TINY_DEBUG
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
-    init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
-    ps, max_seq = 16, 96
-    num_pages = 3  # 2 usable pages = 32 tokens, far below maxp=6
-    spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(cfg, 2, max_seq, num_pages, ps),
-        page_size=ps,
-        num_pages=num_pages,
-        allocator=PageAllocator(num_pages, ps, max_seq, 2),
-    )
-    eng = Engine(fwd, init_cache, params, max_batch=2, max_seq=max_seq,
-                 eos_id=2, seed=0, prefill_buckets=[16, 32, 64], paged=spec)
+    # 2 usable pages = 32 tokens, far below maxp=6
+    eng = paged_engine(cfg, params, max_batch=2, max_seq=96, page_size=16,
+                       num_pages=3, eos_id=2, seed=0,
+                       prefill_buckets=[16, 32, 64])
     with pytest.raises(ValueError):
         eng.submit(GenRequest(prompt=list(range(1, 60)),
                               sampling=SamplingParams(max_new_tokens=32)))
@@ -314,9 +349,7 @@ def test_engine_paged_oversized_request_rejected():
 
 def test_paged_write_chunk_matches_per_step():
     """One bulk chunk write must land tokens exactly where K sequential
-    paged_write_decode calls would (incl. trash routing for overshoot)."""
-    from swarmdb_tpu.ops.paged_kv import paged_write_chunk
-
+    per-token scatters would (incl. trash routing for overshoot)."""
     rng = np.random.default_rng(0)
     L, P, ps, H, D = 2, 6, 4, 2, 8
     B, Kc = 3, 4
@@ -331,18 +364,8 @@ def test_paged_write_chunk_matches_per_step():
     bk, bv = paged_write_chunk(pool_k, pool_v, chunk_k, chunk_v, starts,
                                table)
 
-    sk, sv = pool_k, pool_v
-    for step in range(Kc):
-        pos = (starts + step)[:, None]
-        for layer in range(L):
-            lk, lv = paged_write_decode(
-                sk[layer], sv[layer],
-                chunk_k[layer, :, step][:, None],
-                chunk_v[layer, :, step][:, None],
-                pos, table,
-            )
-            sk = sk.at[layer].set(lk)
-            sv = sv.at[layer].set(lv)
+    sk = _scatter_chunk(pool_k, chunk_k, starts, table)
+    sv = _scatter_chunk(pool_v, chunk_v, starts, table)
     # live pages must match exactly; trash page 0 is garbage on both sides
     np.testing.assert_allclose(np.asarray(bk[:, 1:]), np.asarray(sk[:, 1:]))
     np.testing.assert_allclose(np.asarray(bv[:, 1:]), np.asarray(sv[:, 1:]))
@@ -559,34 +582,16 @@ def test_paged_chunked_dispatch_walks_every_slot_without_a_list(
 def chunked_paged_engine():
     """Engine over the paged pool WITH the two-segment chunked decode
     (the ServingService default for paged mode)."""
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
+    from paged_engine import paged_engine
 
     cfg = TINY_DEBUG
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
-    init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
     max_batch, max_seq, ps = 4, 96, 16
-    maxp = pages_per_slot(max_seq, ps)
-    num_pages = 1 + 2 * maxp
-    paged_spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(
-            cfg, max_batch, max_seq, num_pages, ps),
-        page_size=ps,
-        num_pages=num_pages,
-        allocator=PageAllocator(num_pages, ps, max_seq, max_batch),
-    )
-    chunked = (
-        lambda p, t, pos, c, hkv, s: llama.forward_paged_chunked(
-            p, cfg, t, pos, c, hkv, s),
-        lambda b, k: llama.init_chunk_kv(cfg, b, k),
-        llama.merge_paged_chunk,
-    )
-    eng = Engine(fwd, init_cache, params, max_batch=max_batch,
-                 max_seq=max_seq, eos_id=2, seed=0,
-                 prefill_buckets=[16, 32, 64], paged=paged_spec,
-                 chunked_fns=chunked, decode_chunk=4)
+    eng = paged_engine(cfg, params, max_batch=max_batch, max_seq=max_seq,
+                       page_size=ps,
+                       num_pages=1 + 2 * pages_per_slot(max_seq, ps),
+                       eos_id=2, seed=0, prefill_buckets=[16, 32, 64],
+                       decode_chunk=4)
     eng.start()
     yield eng
     eng.stop()
@@ -607,7 +612,7 @@ def test_engine_paged_chunked_matches_dense(engines, chunked_paged_engine):
 
 def test_mixtral_paged_chunked_matches_paged():
     """MoE paged chunked decode (the SWARMDB_PAGED=1 ServingService
-    default) must match the per-step paged forward step-for-step."""
+    default) must match the plain forward over a slab step-for-step."""
     from swarmdb_tpu.models import mixtral
 
     cfg = TINY_MOE
@@ -621,6 +626,7 @@ def test_mixtral_paged_chunked_matches_paged():
     table = np.arange(1, 1 + B * maxp, dtype=np.int32).reshape(B, maxp)
     pool["page_table"] = jnp.asarray(table)
     pool2 = {k: v for k, v in pool.items()}
+    slab = llama.init_kv_cache(cfg, B, S, dtype=jnp.float32)
 
     Kc = 4
     starts = jnp.asarray([0, 0], jnp.int32)
@@ -630,16 +636,15 @@ def test_mixtral_paged_chunked_matches_paged():
     for step in range(Kc):
         pos = jnp.full((B, 1), step, jnp.int32)
         # (a routed family's forwards return their routing last)
-        l_ref, pool, _routing = llama.forward_paged(params, cfg, tok, pos,
-                                                    pool)
+        l_ref, slab, _routing = llama.forward(params, cfg, tok, pos, slab)
         l_chk, chunk, _routing = llama.forward_paged_chunked(
             params, cfg, tok, pos, pool2, chunk, jnp.asarray(step, jnp.int32))
         np.testing.assert_allclose(np.asarray(l_ref), np.asarray(l_chk),
                                    rtol=1e-4, atol=1e-4)
         tok = jnp.argmax(l_ref[:, -1], axis=-1).astype(jnp.int32)[:, None]
     pool2 = llama.merge_paged_chunk(pool2, chunk, starts)
-    np.testing.assert_allclose(np.asarray(pool["k"][:, 1:]),
-                               np.asarray(pool2["k"][:, 1:]),
+    np.testing.assert_allclose(np.asarray(slab[0]),
+                               np.asarray(_slab_of(pool2["k"], table)),
                                rtol=1e-5, atol=1e-5)
 
 
@@ -658,10 +663,11 @@ def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
     """Three layers, a pool whose every page differs, rows with their own
     tables and prefix lengths: the chunked forward (flat pool view +
     ``table + l * P``; gather path and interpreted kernel) must give the
-    logits of ``forward_paged`` stepped on the same pool. An inactive row
-    (zeroed table) sends layer ``l`` to its own trash page ``l * P``.
-    int8: the per-step reference runs on the dequantized pool, so the
-    two sides read the same values."""
+    logits of the plain ``forward`` stepped on the slab that pool and
+    those tables describe. An inactive row (zeroed table) sends layer
+    ``l`` to its own trash page ``l * P``. int8: the reference's slab is
+    made of the dequantized pool, so the two sides read the same
+    values."""
     import dataclasses
 
     from swarmdb_tpu.models import mixtral
@@ -690,7 +696,7 @@ def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
         table[1] = 0
         live[1] = False
     table = jnp.asarray(table)
-    step_pool = {"k": k, "v": v, "page_table": table}
+    slab = (_slab_of(k, table), _slab_of(v, table))
     chunk_pool["page_table"] = table
     chunk = (jnp.zeros((cfg.n_layers, B, Kc, cfg.n_kv_heads, cfg.head_dim),
                        jnp.float32),) * 2
@@ -700,8 +706,7 @@ def test_paged_chunked_reads_every_layers_own_pages(monkeypatch, family, kv,
         pos = jnp.asarray(starts[:, None] + step, jnp.int32)
         monkeypatch.setenv("SWARMDB_PALLAS", "0")
         # (a routed family's forwards return their routing last)
-        l_ref, step_pool, *_ = llama.forward_paged(params, cfg, tok, pos,
-                                                   step_pool)
+        l_ref, slab, *_ = llama.forward(params, cfg, tok, pos, slab)
         monkeypatch.setenv("SWARMDB_PALLAS", pallas)
         l_chk, chunk, *_ = llama.forward_paged_chunked(
             params, cfg, tok, pos, chunk_pool, chunk,
@@ -733,13 +738,22 @@ def test_paged_pos0_rope_offset():
     toks = jnp.asarray(np.array([[7], [9]], np.int32))
     pos = jnp.asarray(np.array([[0], [0]], np.int32))
 
+    def step(cache, positions):
+        """One decode step the served way: the chunked forward over the
+        frozen pool, then the chunk's one write. (logits, written pool)"""
+        logits, chunk = llama.forward_paged_chunked(
+            params, cfg, toks, positions, cache,
+            llama.init_chunk_kv(cfg, B, 1), jnp.asarray(0, jnp.int32))
+        return logits, llama.merge_paged_chunk(cache, chunk,
+                                               positions[:, 0])
+
     base = mk_cache()
-    logits0, out0 = llama.forward_paged(params, cfg, toks, pos, base)
+    logits0, out0 = step(base, pos)
     assert "pos0" in out0 and np.all(np.asarray(out0["pos0"]) == 0)
 
     # explicit zero offset == default zeros
     z = {**mk_cache(), "pos0": jnp.zeros((B,), jnp.int32)}
-    logits_z, _ = llama.forward_paged(params, cfg, toks, pos, z)
+    logits_z, _ = step(z, pos)
     np.testing.assert_array_equal(np.asarray(logits0), np.asarray(logits_z))
 
     # RoPE phases: K written at logical position 0 under pos0=4 must
@@ -748,11 +762,11 @@ def test_paged_pos0_rope_offset():
     # themselves are offset-invariant (RoPE is relative), so the test
     # asserts on the written pages, not the outputs.
     off = {**mk_cache(), "pos0": jnp.asarray(np.array([4, 0], np.int32))}
-    _, out_o = llama.forward_paged(params, cfg, toks, pos, off)
+    _, out_o = step(off, pos)
     np.testing.assert_array_equal(np.asarray(out_o["pos0"]), [4, 0])
     shifted = mk_cache()
     pos4 = jnp.asarray(np.array([[4], [0]], np.int32))
-    _, out_s = llama.forward_paged(params, cfg, toks, pos4, shifted)
+    _, out_s = step(shifted, pos4)
     # row 0: page 1 holds the write — offset-0 write under pos0=4 vs
     # offset-4 write under pos0=0, same absolute phase, same K values.
     # LAYER 0 only: deeper layers see different attention context (the
@@ -761,6 +775,10 @@ def test_paged_pos0_rope_offset():
     k_o = np.asarray(out_o["k"])[0, 1, 0]   # [Hkv, D] at page off 0
     k_s = np.asarray(out_s["k"])[0, 1, 4]   # [Hkv, D] at page off 4
     np.testing.assert_array_equal(k_o, k_s)
+    # ... which is the K the plain forward writes at position 4 of a slab
+    _, (slab_k, _v) = llama.forward(params, cfg, toks, pos4,
+                                    llama.init_kv_cache(cfg, B, max_seq))
+    np.testing.assert_array_equal(k_s, np.asarray(slab_k)[0, 0, 4])
     # and a mismatched absolute phase differs (rope really rotated)
     k_s0 = np.asarray(np.asarray(out0["k"]))[0, 1, 0]
     assert not np.array_equal(k_o, k_s0)

@@ -394,40 +394,14 @@ def test_prefix_lru_duplicate_registration_recycles():
 
 def _mk_paged_prefix_engine(pool_pages: int = 64):
     """Paged engine with IN-PLACE prefix caching over the main pool."""
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
-    from swarmdb_tpu.ops.paged_kv import PageAllocator, pages_per_slot
+    from paged_engine import paged_engine
 
     cfg = TINY
-    ps = 8
-    max_batch, max_seq = 4, 64
     params = llama.init_params(cfg, jax.random.PRNGKey(0))
-    fwd = lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c)
-    init_cache = lambda b, s: llama.init_kv_cache(cfg, b, s)
-    num_pages = 1 + pool_pages
-    paged_spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(
-            cfg, max_batch, max_seq, num_pages, ps),
-        page_size=ps,
-        num_pages=num_pages,
-        allocator=PageAllocator(num_pages, ps, max_seq, max_batch),
-    )
-    chunked = (
-        lambda p, t, pos, c, hkv, s: llama.forward_paged_chunked(
-            p, cfg, t, pos, c, hkv, s),
-        lambda b, k: llama.init_chunk_kv(cfg, b, k),
-        llama.merge_paged_chunk,
-    )
-    eng = Engine(fwd, init_cache, params, max_batch=max_batch,
-                 max_seq=max_seq, eos_id=2, seed=0,
-                 prefill_buckets=[8, 16, 32, 63], decode_chunk=4,
-                 paged=paged_spec, chunked_fns=chunked,
-                 prefix_fns=(
-                     lambda p, t, tab, pl, pk, pv, logits_at=None:
-                         llama.forward_prefix_pages(p, cfg, t, tab, pl, pk,
-                                                    pv, logits_at=logits_at),
-                     None,
-                 ))
+    eng = paged_engine(cfg, params, max_batch=4, max_seq=64, page_size=8,
+                       num_pages=1 + pool_pages, prefix=True, eos_id=2,
+                       seed=0, prefill_buckets=[8, 16, 32, 63],
+                       decode_chunk=4)
     eng.start()
     return eng
 

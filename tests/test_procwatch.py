@@ -198,35 +198,59 @@ def backtracking(seconds):
 
 def test_a_thread_that_keeps_the_interpreter_is_a_stall_with_its_frame(
         watch, caplog):
+    """What is asserted is order and accounts, none of it a window of
+    the wall clock: on a loaded machine the call is stretched by the
+    neighbours' turns and every thread wakes late."""
     text = backtracking(0.4)
-    time.sleep(0.3)
     held = {}
+    seen = threading.Event()
 
     def hold():
-        held["t0"] = time.time()
+        # begin as the watcher goes to sleep on a new deadline: awake, it
+        # would take the call into its own turn and see no late wake
+        due = watch.due_ns
+        while watch.due_ns == due:
+            time.sleep(0.001)
+        held["t0"], cpu = time.time(), time.thread_time()
         re.match(r"(a+)+$", text)
+        held["cpu_ms"] = (time.thread_time() - cpu) * 1e3
         held["t1"] = time.time()
-        time.sleep(0.2)     # alive while the watcher names the dump's threads
+        while not seen.is_set():    # alive, and here, while the watcher
+            time.sleep(0.01)        # names the dump's threads
+
+    def settled():
+        """The stalls that meet the call, once the watcher is through
+        with them (the counters are the last thing a stall writes)."""
+        stalls = spans("process.stall")
+        c = watch.metrics.counters
+        if ("t1" not in held or c["process_stalls"].value != len(stalls)
+                or c["process_stall_us"].value
+                < sum(e["args"]["ms"] for e in stalls) * 1e3 - len(stalls)):
+            return []
+        return [e for e in stalls if e["start_s"] < held["t1"]
+                and e["start_s"] + e["dur_us"] * 1e-6 > held["t0"]]
 
     th = threading.Thread(target=hold, name="holder")
     th.start()
+    deadline = time.monotonic() + 10
+    while not (stalls := settled()) and time.monotonic() < deadline:
+        time.sleep(0.01)
+    seen.set()
     th.join(timeout=10)
     assert not th.is_alive()
-    time.sleep(0.15)
-    stalls = [e for e in spans("process.stall")
-              if e["start_s"] < held["t1"]
-              and e["start_s"] + e["dur_us"] * 1e-6 > held["t0"]]
     assert stalls, spans("process.stall")
     stall = max(stalls, key=lambda e: e["dur_us"])
-    length = held["t1"] - held["t0"]
-    # it covers the call: the watcher was due within a tick of its start
-    # and woke when it ended
-    assert stall["start_s"] <= held["t0"] + 0.05
-    assert stall["start_s"] + stall["dur_us"] * 1e-6 >= held["t1"] - 0.02
     args = stall["args"]
-    assert args["ms"] == pytest.approx(length * 1e3, abs=60)
-    # the accounts, not the verdict: a loaded machine may read starved
-    assert args["proc_cpu_ms"] >= args["ms"] / 2
+    # it covers the call: the watcher slept to a deadline a tick or less
+    # after the call began (whatever the machine does meanwhile, the
+    # deadline was set before), and woke when the call had ended, so the
+    # stall is no shorter than the CPU the call took, less that tick
+    assert stall["start_s"] <= held["t0"] + procwatch.TICK_S + 0.005
+    assert args["ms"] >= held["cpu_ms"] - procwatch.TICK_S * 1e3 - 5
+    assert args["ms"] >= procwatch.STALL_S * 1e3
+    # the accounts, not the verdict: a loaded machine may read starved.
+    # The process burned at least what the call did
+    assert args["proc_cpu_ms"] >= held["cpu_ms"] * 0.9
     assert args["verdict"] in ("interpreter_held", "starved", "unknown")
     # the watcher holds the interpreter when it looks: the holder stands
     # where it gave it up, in the function that made the call
@@ -234,14 +258,18 @@ def test_a_thread_that_keeps_the_interpreter_is_a_stall_with_its_frame(
               if ln.startswith("holder: ")]
     assert holder and " hold" in holder[0]
     c = watch.metrics.counters
-    assert c["process_stalls"].value == len(spans("process.stall")) >= 1
     assert c["process_stall_us"].value >= args["ms"] * 1e3 - 1
     # the samples hold the stall as it fell
     assert max(e["args"]["late_ms_max"] for e in spans("process.sample")) \
         >= args["ms"] - 1
     assert c["process_wake_late_us"].value >= args["ms"] * 1e3 - 1e3
-    assert procwatch.process_late_s(time.time() - held["t0"]) == \
-        pytest.approx(args["ms"] / 1e3, abs=0.01)
+    # and the reader answers from the newest stall: all of it, asked for
+    # a stretch that holds it
+    t0, t1 = watch.last_stall
+    assert t1 - t0 >= procwatch.STALL_S * 1e9
+    age = (time.monotonic_ns() - t0) / 1e9 + 0.01
+    assert procwatch.process_late_s(age) == \
+        pytest.approx((t1 - t0) / 1e9, abs=1e-3)
 
 
 def test_a_long_stall_goes_to_the_logger(watch, caplog, monkeypatch):
@@ -270,16 +298,26 @@ def test_without_the_kernels_accounts_the_clocks_and_the_stacks_are_left(
     eng = FakeEngine()
     procwatch.acquire(metrics, [eng])
     try:
-        time.sleep(0.3)
-        re.match(r"(a+)+$", text)
-        time.sleep(0.3)
+        # a call that the neighbours' turns stretch to twice its CPU reads
+        # `unknown`, rightly (the process burned under half of the
+        # stall): such a call told nothing, and another is made
+        for _attempt in range(4):
+            TRACER.reset()
+            time.sleep(0.3)
+            wall, cpu = time.perf_counter(), time.thread_time()
+            re.match(r"(a+)+$", text)
+            cpu = time.thread_time() - cpu
+            wall = time.perf_counter() - wall
+            time.sleep(0.3)
+            if cpu >= 0.7 * wall:
+                break
     finally:
         procwatch.release([eng])
         eng.hold.set()
     stall = max(spans("process.stall"), key=lambda e: e["dur_us"])
     args = stall["args"]
     assert "runq_ms" not in args and "majflt" not in args
-    assert args["run_ms"] < 50 and args["proc_cpu_ms"] >= args["ms"] / 2
+    assert args["run_ms"] < 50 and args["proc_cpu_ms"] >= cpu * 1e3 * 0.9
     assert args["verdict"] == "interpreter_held"
     assert "MainThread: " in args["stacks"]     # it made the call
     sample = spans("process.sample")[1]["args"]

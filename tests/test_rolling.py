@@ -10,38 +10,21 @@ import pytest
 
 import jax
 
-from swarmdb_tpu.backend.engine import Engine, GenRequest, PagedKV
+from paged_engine import paged_engine
+from swarmdb_tpu.backend.engine import Engine, GenRequest
 from swarmdb_tpu.backend.sampling import SamplingParams
 from swarmdb_tpu.models import llama
 from swarmdb_tpu.models.configs import TINY_DEBUG
-from swarmdb_tpu.ops.paged_kv import PageAllocator
 
 PS, MAX_SEQ, BATCH = 8, 96, 2
 
 
 def _mk_engine(params, start=True):
     cfg = TINY_DEBUG
-    num_pages = 1 + 2 * BATCH * (MAX_SEQ // PS)
-    spec = PagedKV(
-        decode_forward=lambda p, t, pos, c: llama.forward_paged(
-            p, cfg, t, pos, c),
-        init_pool=lambda: llama.init_paged_cache(
-            cfg, BATCH, MAX_SEQ, num_pages, PS),
-        page_size=PS, num_pages=num_pages,
-        allocator=PageAllocator(num_pages, PS, MAX_SEQ, BATCH),
-    )
-    eng = Engine(
-        lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c),
-        lambda b, s: llama.init_kv_cache(cfg, b, s),
-        params, max_batch=BATCH, max_seq=MAX_SEQ, eos_id=-1, seed=0,
-        prefill_buckets=[16, 32, 64], decode_chunk=4, paged=spec,
-        prefix_fns=(
-            lambda p, t, tab, pl, pk, pv, logits_at=None:
-                llama.forward_prefix_pages(p, cfg, t, tab, pl, pk, pv,
-                                           logits_at=logits_at),
-            None,
-        ),
-    )
+    eng = paged_engine(
+        cfg, params, max_batch=BATCH, max_seq=MAX_SEQ, page_size=PS,
+        num_pages=1 + 2 * BATCH * (MAX_SEQ // PS), prefix=True, eos_id=-1,
+        seed=0, prefill_buckets=[16, 32, 64], decode_chunk=4)
     if start:
         eng.start()
     return eng

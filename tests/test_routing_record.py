@@ -130,7 +130,7 @@ def _pages_of(cache, n_tokens, ps):
 
 @pytest.mark.parametrize("name", [
     "forward_prefix_pages", "forward_prefix_lane", "forward_ragged_prefill",
-    "forward_chunked", "forward_paged", "forward_paged_chunked"])
+    "forward_chunked", "forward_paged_chunked"])
 def test_every_routed_forward_reports_forwards_routing(name):
     """The same tokens, the same numerics (float32, no call drops at
     these sizes): each forward reports, for the positions it computes, the
@@ -176,8 +176,6 @@ def test_every_routed_forward_reports_forwards_routing(name):
         if name == "forward_chunked":
             _, chunk, got = llama.forward_chunked(
                 params, cfg, tok, pos, history, chunk, at)
-        elif name == "forward_paged":
-            _, paged, got = llama.forward_paged(params, cfg, tok, pos, paged)
         else:
             _, chunk, got = llama.forward_paged_chunked(
                 params, cfg, tok, pos, paged, chunk, at)

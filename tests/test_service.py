@@ -175,6 +175,35 @@ def test_merge_env_selects_scatter(monkeypatch):
             db.close()
 
 
+def test_chunked_env_is_read_nowhere(monkeypatch):
+    """Nothing reads SWARMDB_CHUNKED: with it set to 0 a paged engine is
+    the engine it is without it, the same programs in its warm-up plan,
+    the chunk triple of its page pool, the same greedy tokens."""
+    from swarmdb_tpu.backend.sampling import SamplingParams
+    from swarmdb_tpu.backend.service import build_backend_engine
+
+    def build():
+        eng, _tok = build_backend_engine(
+            "tiny-debug", paged=True, max_batch=2, max_seq=64, page_size=8,
+            decode_chunk=4)
+        names = [getattr(fn, "__name__", repr(fn))
+                 for fn, _args in eng.warmup_call_plan()]
+        assert eng._chunked_fns is eng.paged.chunked_fns
+        eng.start()
+        try:
+            toks = [eng.generate_sync(p, SamplingParams(max_new_tokens=9))
+                    for p in ([1, 5, 9], list(range(3, 20)))]
+        finally:
+            eng.stop()
+        return names, toks
+
+    monkeypatch.delenv("SWARMDB_CHUNKED", raising=False)
+    want = build()
+    monkeypatch.setenv("SWARMDB_CHUNKED", "0")
+    got = build()
+    assert want[0] and got == want
+
+
 def test_build_prompt_window_is_anchor_stable(monkeypatch):
     """Prompts must stay prefix-stable (each turn extends the previous
     prompt) even after the conversation exceeds SWARMDB_HISTORY_LIMIT:

@@ -96,28 +96,12 @@ def test_demote_gate_hysteresis_no_thrash():
 def test_demote_watermark_env_parsing(monkeypatch):
     """SWARMDB_TIER_DEMOTE >= 1.0 disables; otherwise clamped into the
     [low, high] band (a demote mark above shed would never fire)."""
-    import jax
-
-    from swarmdb_tpu.backend.engine import Engine, PagedKV
-    from swarmdb_tpu.models import llama
-    from swarmdb_tpu.models.configs import TINY_DEBUG
-    from swarmdb_tpu.ops.paged_kv import PageAllocator
+    from swarmdb_tpu.backend.service import build_backend_engine
 
     def mk():
-        cfg = TINY_DEBUG
-        spec = PagedKV(
-            decode_forward=lambda p, t, pos, c: llama.forward_paged(
-                p, cfg, t, pos, c),
-            init_pool=lambda: llama.init_paged_cache(cfg, 2, 64, 17, 8),
-            page_size=8, num_pages=17,
-            allocator=PageAllocator(17, 8, 64, 2),
-        )
-        params = llama.init_params(cfg, jax.random.PRNGKey(0))
-        return Engine(
-            lambda p, t, pos, c: llama.forward(p, cfg, t, pos, c),
-            lambda b, s: llama.init_kv_cache(cfg, b, s),
-            params, max_batch=2, max_seq=64, eos_id=-1, seed=0,
-            prefill_buckets=[16, 32], decode_chunk=4, paged=spec)
+        return build_backend_engine(
+            "tiny-debug", paged=True, max_batch=2, max_seq=64, page_size=8,
+            kv_pool_tokens=128, decode_chunk=4)[0]
 
     monkeypatch.setenv("SWARMDB_TIER_DEMOTE", "1.0")
     assert mk()._bp_demote >= 1.0          # disabled, not clamped

@@ -62,18 +62,12 @@ def _compile(chip, fn, *shapes, **static):
 
 BF, I8, I32, F32 = jnp.bfloat16, jnp.int8, jnp.int32, jnp.float32
 Q = ((B, HQ, D), BF)
-POOL = ((PAGES, PS, HKV, D), BF)
 QPOOL = ((PAGES, PS, HKV, D), I8)
 SCALE = ((PAGES, HKV), F32)
 TABLE = ((B, MAXP), I32)
 ROW = ((B,), I32)
 CHUNK = ((B, KC, HKV, D), BF)
 STEP = ((), I32)
-
-
-def test_paged_decode_compiles(one_chip):
-    _compile(one_chip, ap.paged_decode_gqa_attention,
-             Q, POOL, POOL, TABLE, ROW)
 
 
 @pytest.mark.parametrize("b,hkv,maxp,pages,window", [
@@ -159,14 +153,18 @@ def test_expert_stream_kernel_compiles(one_chip, rows):
     assert compiled.memory_analysis().temp_size_in_bytes < d * f * 2
 
 
-def test_paged_decode_quant_compiles(one_chip):
-    _compile(one_chip, ap.paged_decode_gqa_attention_quant,
-             Q, QPOOL, SCALE, QPOOL, SCALE, TABLE, ROW)
-
-
-def test_paged_chunked_decode_quant_compiles(one_chip):
+@pytest.mark.parametrize("b,maxp,pages", [
+    pytest.param(B, MAXP, PAGES, id="llama3-8b-span1024"),
+    # the chat cells' geometry under SWARMDB_KV_DTYPE=int8: 16 rows and a
+    # 256-page table, which this twin still walks as a grid (16, 257)
+    pytest.param(16, 256, 4096, id="chat-cell-table"),
+])
+def test_paged_chunked_decode_quant_compiles(one_chip, b, maxp, pages):
+    qpool, scale = ((pages, PS, HKV, D), I8), ((pages, HKV), F32)
+    chunk = ((b, KC, HKV, D), BF)
     _compile(one_chip, ap.paged_decode_gqa_attention_chunked_quant,
-             Q, QPOOL, SCALE, QPOOL, SCALE, TABLE, CHUNK, CHUNK, ROW, STEP)
+             ((b, HQ, D), BF), qpool, scale, qpool, scale,
+             ((b, maxp), I32), chunk, chunk, ((b,), I32), STEP)
 
 
 @pytest.mark.parametrize("width", (128, 1024))
