@@ -550,6 +550,9 @@ class PagedKV:
     # hits and earlier chunks of a split prompt) is read straight from
     # the page pool. None = the row-bucketed dense-bucket prefill.
     prefill_ragged: Optional[Callable] = None
+    # the widest wave ``prefill_ragged`` is compiled for (None: up to
+    # ``max_seq``); a round with more tokens takes more waves
+    ragged_max_width: Optional[int] = None
 
 
 class Engine:
@@ -723,6 +726,26 @@ class Engine:
         # its row from its last hit page's). The pool's shapes size both;
         # no serving key does.
         self._stateful = isinstance(self.cache, dict) and "state" in self.cache
+        # snapshots rarer than a page (models/nemotron_h.py): a Mamba-2
+        # layer's state is hundreds of times a page's keys and values, so
+        # ``state`` and ``page_state`` are ``{"ssm", "conv"}`` and the rows
+        # of ``page_state`` are not pages but SNAPSHOT slots (row 0 the
+        # bin), far fewer than pages. The prefix cache says which cached
+        # page owns which (``PrefixLRU.keep_state_slots``); a wave is told
+        # where each row resumes from and where the state at its last
+        # page end goes; a prefix hit resumes behind the deepest hit page
+        # that still owns one and the rest is forgone. Such rows do not
+        # ride waves: a one-token row would cost a segment of the wave's
+        # scan a layer, a decode step's worth
+        self._snapshots = 0
+        # slot -> the snapshot slot its admission resumes from (plan time)
+        self._snap_src: Dict[int, int] = {}
+        if self._stateful and isinstance(self.cache["state"], dict):
+            self._snapshots = self.cache["page_state"]["ssm"].shape[1] - 1
+            for name in ("ssm_snapshots_taken", "ssm_snapshots_evicted",
+                         "ssm_snapshot_slots", "ssm_snapshot_slots_live",
+                         "ssm_state_tokens_resumed"):
+                self.metrics.counters[name].inc(0)
         # latent pages (models/deepseek.py): the pool under ``"k"`` is one
         # of rows ``[L, num_pages, ps, Wd]`` with no heads axis, and
         # ``"v"`` is a ``NoValuePool``, the format's type, which the
@@ -1354,9 +1377,10 @@ class Engine:
                                "using 8",
                                os.environ.get("SWARMDB_RAGGED_MIN_WIDTH"))
                 min_w = 8
-            ladder = [max(1, min(min_w, max_seq))]
-            while ladder[-1] < max_seq:
-                ladder.append(min(max_seq, ladder[-1] * 2))
+            widest = min(max_seq, paged.ragged_max_width or max_seq)
+            ladder = [max(1, min(min_w, widest))]
+            while ladder[-1] < widest:
+                ladder.append(min(widest, ladder[-1] * 2))
             self._ragged_widths = ladder
             self._ragged_ridge_tokens = weights_ridge_tokens(params)
             # registered at 0, so a reader tells "nobody rode" from a
@@ -1383,21 +1407,40 @@ class Engine:
                 # slot's (src < 0: a later chunk of a split prompt); its
                 # state after this wave lands in slot ``slots`` (max_batch
                 # drops a padding row) and the state at each page end it
-                # computed under that page's id.
+                # computed under that page's id. With snapshots
+                # (``self._snapshots``) ``src`` names a snapshot slot, a
+                # third vector ``dst`` [R] the slot that takes the state
+                # at the row's last page end (0: the bin), and the body
+                # says which rows crossed a page end at all.
+                # The large part of such a state (``"ssm"``) the body
+                # reads from and writes to the two pools in place; only
+                # the conv rows are seeded and scattered here.
                 from ..ops.paged_kv import paged_write_ragged
 
-                seed = ()
+                seed, big = (), None
                 if state:
-                    src, slots, slot_state, page_state = state
-                    seed = (seed_state(src, slots, slot_state, page_state),)
+                    src, slots, *dst, slot_state, page_state = state
+                    if dst:
+                        big = (slot_state["ssm"], page_state["ssm"])
+                        slot_state = slot_state["conv"]
+                        page_state = page_state["conv"]
+                    seed = seed_state(src, slots, slot_state, page_state)
+                    seed = ({"conv": seed, "ssm": (src, slots, *dst, *big)}
+                            if dst else seed,)
                 last, sk, sv, *routing = _ragged_body_fn(
                     params, tokens, tok_row, tok_pos, row_tables, starts,
                     lens, plens, k_pool, v_pool, *seed)
                 if state:
                     row_state, ends_state, end_pages, *routing = routing
+                    if dst:
+                        end_pages = jnp.where(end_pages > 0, dst[0], 0)
+                        big, routing = routing[:2], routing[2:]
                     state = (
                         slot_state.at[:, slots].set(row_state, mode="drop"),
                         page_state.at[:, end_pages].set(ends_state))
+                    if dst:
+                        state = tuple({"ssm": b, "conv": c}
+                                      for b, c in zip(big, state))
                 # absolute-position PRNG fold == the bucketed paths'
                 # (prefix_lens + lengths - 1): identical sampling for an
                 # identical prompt whichever path admitted it
@@ -1418,7 +1461,8 @@ class Engine:
             self._prefill_ragged_fused = jax.jit(
                 _prefill_ragged_insert,
                 donate_argnums=(9, 10, 11, 12)
-                + ((19, 20) if self._stateful else ()))
+                + (((20, 21) if self._snapshots else (19, 20))
+                   if self._stateful else ()))
 
         # ---- automatic prefix caching --------------------------------------
         # Chat serving re-prefills each conversation's WHOLE history every
@@ -1453,6 +1497,8 @@ class Engine:
                                            paged.page_size,
                                            manage_free=False,
                                            pool=paged.allocator)
+            if self._snapshots:
+                self._prefix.keep_state_slots(self._snapshots)
             pages_fwd = prefix_fns[0]
             maxp_row = paged.allocator.maxp
             self._prefix_pp_buckets = self._pp_widths(maxp_row)
@@ -2412,6 +2458,7 @@ class Engine:
                     *((np.zeros(R, np.int32),
                        np.full(R, self.max_batch, np.int32))
                       if self._stateful else ()),
+                    *((np.zeros(R, np.int32),) if self._snapshots else ()),
                 )
         for bucket in self.prefill_buckets:
             if not self._role_warms_prefill():
@@ -2604,7 +2651,7 @@ class Engine:
         configuration whose FFN drops over a capacity never gets here: it
         has no ragged waves (a rider would compete with the prompt tokens
         for capacity where a decode step's rows do not)."""
-        if self._role == "prefill":
+        if self._role == "prefill" or self._snapshots:
             return []
         return [i for i, s in enumerate(self.slots)
                 if s.active and not s.cancelled and not s.pending_token
@@ -2723,7 +2770,9 @@ class Engine:
                     sds((B, maxp), np.int32), i32_B, cache_s["k"],
                     cache_s["v"], lt_s, llp_s, keys_R, f32_B, i32_B,
                     f32_B,
-                    *((i32_B, i32_B, cache_s["state"],
+                    *((i32_B, i32_B,
+                       *((i32_B,) if self._snapshots else ()),
+                       cache_s["state"],
                        cache_s["page_state"]) if self._stateful else ()))))
         for bucket in self.prefill_buckets:
             if not self._role_warms_prefill():
@@ -3851,6 +3900,10 @@ class Engine:
                                 forgone += len(hits) - keep
                                 self._prefix.unpin(hits[keep:])
                                 hits = hits[:keep]
+                            if self._snapshots:
+                                # the snapshot slot the row resumes from
+                                self._snap_src[slot_id] = (
+                                    hit_states[keep - 1] if keep else 0)
                         # DP-sharded pool: a slot can only
                         # reference pages of its own shard (the
                         # shard_map'd decode addresses its local
@@ -4566,6 +4619,45 @@ class Engine:
                 routing=wave.part(where) if wave is not None else None)
 
     # swarmlint: hot
+    def _take_snapshots(self, batch: List[Tuple]
+                        ) -> Optional[Dict[int, Tuple[int, int]]]:
+        """With snapshots: slot -> ``(snapshot slot, end)`` for each row
+        of ``batch`` whose prompt has a whole page behind its hits: the
+        snapshot slot taken for the state at the prompt's last page end,
+        ``end`` tokens in. Taken before the round's first wave, by
+        ``PrefixLRU.take_state_slot``'s rule, never one a row of this
+        round resumes from; a row without one writes that state to the
+        bin. A row's older snapshot is superseded by its newer one, and
+        only a snapshot that was still its sequence's deepest counts as
+        evicted. None for an engine without snapshots."""
+        if not self._snapshots:
+            return None
+        ps = self.paged.page_size
+        busy = {self._snap_src.get(b[0], 0) for b in batch if b[2]}
+        out: Dict[int, Tuple[int, int]] = {}
+        c = self.metrics.counters
+        for slot_id, req, hits, chains, _row in batch:
+            src = self._snap_src.get(slot_id, 0) if hits else 0
+            if src:
+                c["ssm_state_tokens_resumed"].inc(len(hits) * ps)
+            n_full = len(req.prompt) // ps
+            if chains is None or n_full <= len(hits):
+                continue
+            dst, lost = self._prefix.take_state_slot(
+                chains[n_full - 1], n_full, busy)
+            c["ssm_snapshots_evicted"].inc(int(lost))
+            if dst:
+                c["ssm_snapshots_taken"].inc()
+                busy.add(dst)
+                out[slot_id] = (dst, n_full * ps)
+                if src:
+                    self._prefix.supersede(src)
+        if self._prefix is not None:
+            c["ssm_snapshot_slots"].inc(self._snapshots)
+            c["ssm_snapshot_slots_live"].inc(self._prefix.state_slots_live())
+        return out
+
+    # swarmlint: hot
     def _prefill_ragged_waves(self, batch: List[Tuple]) -> None:
         """Packed ragged admission waves (ISSUE 11 tentpole): the wave's
         rows concatenate into ONE token stream — no row buckets, no
@@ -4612,6 +4704,7 @@ class Engine:
             self._topk[slot_id] = s.top_k
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
+        snap_dst = self._take_snapshots(batch)
         packed_n = padding_n = 0
         # routed: slot -> the parts of its suffix, in stream order
         stream_parts: Dict[int, List[RoutingRows]] = {}
@@ -4648,6 +4741,7 @@ class Engine:
             # after this wave goes to (max_batch: a padding row, dropped)
             state_src = np.zeros(R, np.int32)
             state_slot = np.full(R, self.max_batch, np.int32)
+            state_dst = np.zeros(R, np.int32)
             filled = 0
             r = 0
             for it in pend:
@@ -4675,6 +4769,16 @@ class Engine:
                 # rider, like a later chunk, from its own slot's state
                 state_src[r] = -1 if consumed or slot_id in riding else (
                     row[p0 // ps - 1] if p0 else 0)
+                if snap_dst is not None:
+                    if not consumed:
+                        state_src[r] = (self._snap_src.get(slot_id, 0)
+                                        if p0 else 0)
+                    # the chunk that holds the prompt's last page end
+                    # writes the snapshot; an earlier chunk's goes to the
+                    # bin
+                    dst, end = snap_dst.get(slot_id, (0, 0))
+                    if abs0 < end <= abs0 + take:
+                        state_dst[r] = dst
                 if consumed + take == len(suffix):
                     scatter[r] = slot_id     # final chunk: sample here
                 it[3] = consumed + take
@@ -4701,6 +4805,7 @@ class Engine:
                 self._base_keys_np[gather], self._temp[gather],
                 self._topk[gather], self._topp[gather],
                 *((state_src, state_slot) if self._stateful else ()),
+                *((state_dst,) if self._snapshots else ()),
             )
             # dispatch-shape profile: the tiny flush waves ROADMAP item 2
             # wants sized show up here as named (ragged, small-width) rows
@@ -4758,8 +4863,12 @@ class Engine:
                                                 f * ps, (f + 1) * ps)
                             if slot_id in stream_parts else None,
                             # the wave wrote the state at this page's end
-                            # into page_state under the page's id
-                            state=True if self._stateful else None):
+                            # into page_state under the page's id (with
+                            # snapshots: the state at the prompt's last
+                            # page end into the slot ``_take_snapshots``
+                            # bound to that page's chain)
+                            state=True if self._stateful
+                            and snap_dst is None else None):
                         self.paged.allocator.transfer_to_cache(
                             slot_id, [fresh[f]])
                         self._prefix.pin([fresh[f]])

@@ -173,7 +173,7 @@ def build_backend_engine(
     here (params, pools, slot state) lands on the caller's
     ``jax.default_device`` scope, which is how a lane pins its engine to
     one mesh device."""
-    from ..models import deepseek, lfm2, llama, mixtral
+    from ..models import deepseek, lfm2, llama, mixtral, nemotron_h
     from ..models.configs import ModelConfig, get_config
     cfg = (model_name_or_cfg
            if isinstance(model_name_or_cfg, ModelConfig)
@@ -184,6 +184,7 @@ def build_backend_engine(
     # brings of its own is its parameter tree, and the configuration's
     # fields say which family it is
     family = (deepseek if cfg.latent
+              else nemotron_h if cfg.sublayers
               else lfm2 if cfg.layer_types is not None
               else mixtral if cfg.is_moe else llama)
     params = family.init_params(cfg, key)
@@ -262,6 +263,10 @@ def build_backend_engine(
                 *seed: llama.forward_ragged_prefill(
                     p, cfg, toks, trow, tpos, tables, st, ln, pl, pk, pv,
                     *seed))
+            from ..ops.layers import ragged_wave_max_width
+
+            paged_spec.ragged_max_width = ragged_wave_max_width(
+                cfg.n_heads, cfg.n_kv_heads)
 
     # Automatic prefix caching: chat serving re-prefills each
     # conversation's history every turn, so reuse of page-aligned

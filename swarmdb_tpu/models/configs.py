@@ -29,6 +29,17 @@ fields decide which one it is, never a switch:
   the paged engine alone. The benchmark's cell ``deepseek-v2.chat`` builds
   it from ``benchmark/configs/deepseek-v2.json``; ``tiny-dsv2`` is the
   tests'.
+- a sublayer a layer (models/nemotron_h.py, ``"mamba"`` or ``"moe"`` among
+  ``layer_types``): a layer is ONE of a Mamba-2 mixer, GQA attention
+  without RoPE over heads wider than ``dim // n_heads``, or a routed FFN of
+  un-gated relu² experts beside a shared one, each behind one norm and one
+  residual. A sequence carries each Mamba-2 layer's state (hundreds of
+  times a page's keys and values) beside its pages, and a prefix hit
+  resumes from a SNAPSHOT of it, of which the pool holds far fewer than
+  pages. Served by the paged engine alone. The benchmark's cell
+  ``nemotron3-nano.chat`` builds it from
+  ``benchmark/configs/nemotron-3-nano-30b-a3b.json``; ``tiny-nemotron`` is
+  the tests'.
 """
 
 from __future__ import annotations
@@ -111,6 +122,30 @@ class ModelConfig:
     # as left out
     first_held_expert: int = 0
     n_experts_held: int = 0
+    # ---- a sublayer a layer (models/nemotron_h.py): ``layer_types`` also
+    # takes "mamba" (a Mamba-2 mixer and no FFN) and "moe" (a routed FFN
+    # and no mixer), and "full_attention" is then attention alone ----
+    # an attention head's size where it is not ``dim // n_heads``
+    attn_head_dim: int = 0
+    # whether attention rotates q and k by position
+    rope: bool = True
+    # Mamba-2: ``ssm_heads`` heads of ``ssm_head_dim``, a state of
+    # ``ssm_state`` a head value, B and C in ``ssm_groups`` groups, a
+    # causal depthwise conv of ``conv_taps`` taps (with ``conv_bias``)
+    # over x, B and C before them
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_state: int = 0
+    ssm_groups: int = 1
+    conv_bias: bool = False
+    # one shared expert of this width beside the routed ones (0 = none;
+    # models/deepseek.py counts its own in ``shared_experts``)
+    shared_ffn_dim: int = 0
+    # how many snapshots of a sequence's recurrent state the paged pool
+    # keeps for prefix hits to resume from, where that state is too large
+    # to keep one a page (a pool for Mamba-2 layers needs it: no rule
+    # stands in for what is left of the chip)
+    state_snapshots: int = 0
 
     def __post_init__(self) -> None:
         if self.layer_types is not None:
@@ -120,12 +155,24 @@ class ModelConfig:
                 raise ValueError(
                     f"{self.name!r}: {len(self.layer_types)} layer_types "
                     f"for {self.n_layers} layers")
-            unknown = set(self.layer_types) - {"conv", "full_attention"}
+            unknown = set(self.layer_types) - {"conv", "full_attention",
+                                               "mamba", "moe"}
             if unknown:
                 raise ValueError(f"{self.name!r}: layer_types {unknown}")
-            if "conv" in self.layer_types and self.conv_taps < 2:
+            if ({"conv", "mamba"} & set(self.layer_types)
+                    and self.conv_taps < 2):
                 raise ValueError(f"{self.name!r}: conv layers need "
                                  "conv_taps >= 2")
+            if self.sublayers and "conv" in self.layer_types:
+                raise ValueError(f"{self.name!r}: a gated short conv pairs "
+                                 "with an FFN; \"mamba\" and \"moe\" "
+                                 "layers stand alone")
+            if "mamba" in self.layer_types and not (
+                    self.ssm_heads and self.ssm_head_dim and self.ssm_state
+                    and self.ssm_heads % self.ssm_groups == 0):
+                raise ValueError(
+                    f"{self.name!r}: mamba layers need ssm_heads (a "
+                    "multiple of ssm_groups), ssm_head_dim and ssm_state")
         if self.router not in ("softmax_capacity", "sigmoid_bias",
                                "softmax_group_limited"):
             raise ValueError(f"{self.name!r}: router {self.router!r}")
@@ -150,7 +197,7 @@ class ModelConfig:
     def head_dim(self) -> int:
         if self.latent:
             return self.qk_nope_head_dim + self.qk_rope_head_dim
-        return self.dim // self.n_heads
+        return self.attn_head_dim or self.dim // self.n_heads
 
     @property
     def latent(self) -> bool:
@@ -173,12 +220,20 @@ class ModelConfig:
         return self.n_experts > 0
 
     @property
-    def mixers(self) -> Tuple[str, ...]:
-        """Each layer's mixer, "attention" or "conv"."""
+    def sublayers(self) -> bool:
+        """Whether a layer is ONE sublayer, a mixer or an FFN alone
+        (models/nemotron_h.py), and not a mixer and an FFN."""
+        return self.layer_types is not None and bool(
+            {"mamba", "moe"} & set(self.layer_types))
+
+    @property
+    def mixers(self) -> Tuple[Optional[str], ...]:
+        """Each layer's mixer: "attention", "conv", "mamba", or None for
+        a layer that is an FFN alone."""
         if self.layer_types is None:
             return ("attention",) * self.n_layers
-        return tuple("conv" if t == "conv" else "attention"
-                     for t in self.layer_types)
+        return tuple({"conv": "conv", "mamba": "mamba", "moe": None}.get(
+            t, "attention") for t in self.layer_types)
 
     @property
     def n_attn_layers(self) -> int:
@@ -189,15 +244,33 @@ class ModelConfig:
         return self.mixers.count("conv")
 
     @property
+    def n_ssm_layers(self) -> int:
+        return self.mixers.count("mamba")
+
+    @property
+    def ssm_inner(self) -> int:
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """Width of what a Mamba-2 layer convolves: x, B and C."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
     def stateful(self) -> bool:
         """Whether a sequence carries recurrent state beside its keys and
-        values (a conv layer's last inputs): only the paged engine's
-        ragged prefill and chunked decode carry it."""
-        return self.n_conv_layers > 0
+        values (a conv layer's last inputs, a Mamba-2 layer's state and
+        last inputs): only the paged engine's ragged prefill and chunked
+        decode carry it."""
+        return self.n_conv_layers > 0 or self.n_ssm_layers > 0
 
     @property
     def n_routed_layers(self) -> int:
-        return self.n_layers - self.n_dense_layers if self.is_moe else 0
+        if not self.is_moe:
+            return 0
+        if self.sublayers:
+            return self.layer_types.count("moe")
+        return self.n_layers - self.n_dense_layers
 
     @property
     def ffn_drops(self) -> bool:
@@ -227,7 +300,12 @@ class _FieldsShown:
              "yarn_beta_slow": 1.0, "yarn_mscale": 1.0,
              "yarn_mscale_all_dim": 0.0, "n_group": 1, "topk_group": 1,
              "routed_scaling_factor": 1.0, "shared_experts": 0,
-             "first_held_expert": 0, "n_experts_held": 0}
+             "first_held_expert": 0, "n_experts_held": 0,
+             # the fifth family's (models/nemotron_h.py)
+             "attn_head_dim": 0, "rope": True, "ssm_heads": 0,
+             "ssm_head_dim": 0, "ssm_state": 0, "ssm_groups": 1,
+             "conv_bias": False, "shared_ffn_dim": 0,
+             "state_snapshots": 0}
 
     def __init__(self, every):
         self.every = every
@@ -369,6 +447,40 @@ TINY_DSV2 = ModelConfig(
     shared_experts=2,
 )
 
+# the fifth family at test widths: the published pattern's first period and
+# its tail (M E M E M * E M E), Mamba-2 of 8 heads of 16 over a state of 16 in
+# 2 groups with a conv of 4 taps and a bias, attention of 4 query heads over
+# 1 KV head of 32 (wider than 64 / 4) without RoPE, 8 un-gated relu² experts
+# of 32, top-2, gates renormalised and times 2.5, a shared expert of 64
+TINY_NEMOTRON = ModelConfig(
+    name="tiny-nemotron",
+    vocab_size=512,
+    dim=64,
+    n_layers=9,
+    n_heads=4,
+    n_kv_heads=1,
+    ffn_dim=32,
+    max_seq_len=256,
+    rope_theta=10_000.0,
+    n_experts=8,
+    experts_per_token=2,
+    layer_types=("mamba", "moe", "mamba", "moe", "mamba", "full_attention",
+                 "moe", "mamba", "moe"),
+    conv_taps=4,
+    expert_ffn_dim=32,
+    router="sigmoid_bias",
+    routed_scaling_factor=2.5,
+    attn_head_dim=32,
+    rope=False,
+    ssm_heads=8,
+    ssm_head_dim=16,
+    ssm_state=16,
+    ssm_groups=2,
+    conv_bias=True,
+    shared_ffn_dim=64,
+    state_snapshots=8,
+)
+
 # ~1B-class config for meaningful single-chip benchmarking without 8B HBM cost.
 LLAMA_1B_BENCH = ModelConfig(
     name="llama-1b-bench",
@@ -385,7 +497,7 @@ LLAMA_1B_BENCH = ModelConfig(
 REGISTRY = {
     c.name: c
     for c in (LLAMA3_8B, LLAMA3_70B, MIXTRAL_8X7B, TINY_DEBUG, TINY_MOE,
-              TINY_LFM2, TINY_DSV2, LLAMA_1B_BENCH)
+              TINY_LFM2, TINY_DSV2, TINY_NEMOTRON, LLAMA_1B_BENCH)
 }
 
 

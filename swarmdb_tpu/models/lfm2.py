@@ -70,8 +70,13 @@ def layer_plan(cfg: ModelConfig) -> List[Tuple[Tuple[Signature, ...], int]]:
     leaves the fewest layer bodies (sum of pattern lengths): 4 for
     ``conv conv full_attention conv``, whose first group differs by its
     dense FFNs and stands alone."""
-    sigs = [(mixer, "moe" if cfg.is_moe and l >= cfg.n_dense_layers
-             else "dense") for l, mixer in enumerate(cfg.mixers)]
+    return plan_periods([
+        (mixer, "moe" if cfg.is_moe and l >= cfg.n_dense_layers else "dense")
+        for l, mixer in enumerate(cfg.mixers)])
+
+
+def plan_periods(sigs: List[Any]) -> List[Tuple[Tuple[Any, ...], int]]:
+    """``layer_plan``'s rule over any list of layer signatures."""
     best = None
     for period in range(1, len(sigs) + 1):
         segs: List[List[Any]] = []
@@ -320,6 +325,21 @@ def _swiglu(x, w_gate, w_up, w_down):
     return jnp.dot(h.astype(x.dtype), w_down, preferred_element_type=f32)
 
 
+def _relu2(x, w_up, w_down):
+    """The un-gated expert ``W_2 relu(W_1 x)^2``, float32 between its two
+    matmuls as ``_swiglu`` is. Returns float32."""
+    f32 = jnp.float32
+    h = jnp.square(jax.nn.relu(jnp.dot(x, w_up, preferred_element_type=f32)))
+    return jnp.dot(h.astype(x.dtype), w_down, preferred_element_type=f32)
+
+
+def expert_ffn(x, w_gate, w_up, w_down):
+    """One expert by what its layer holds: ``_swiglu``, or ``_relu2`` for a
+    layer without gate matrices (``w_gate`` None)."""
+    return (_relu2(x, w_up, w_down) if w_gate is None
+            else _swiglu(x, w_gate, w_up, w_down))
+
+
 def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
               live: Optional[jnp.ndarray] = None, base=0,
               chosen_gates=None, held: Optional[Tuple[int, int]] = None
@@ -353,13 +373,14 @@ def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
     gate = jnp.sum(jax.nn.one_hot(local, E, dtype=jnp.float32)
                    * gates[..., None], axis=1)
     hit = jnp.any(gate > 0, axis=0)
-    w_gate, w_up, w_down = lp["w_gate"], lp["w_up"], lp["w_down"]
+    w_gate, w_up, w_down = lp.get("w_gate"), lp["w_up"], lp["w_down"]
 
     def expert(e, acc):
         def run(acc):
             ge = jax.lax.dynamic_slice_in_dim(gate, e, 1, axis=1)
-            return acc + _swiglu(xf, w_gate[base + e], w_up[base + e],
-                                 w_down[base + e]) * ge
+            return acc + expert_ffn(
+                xf, None if w_gate is None else w_gate[base + e],
+                w_up[base + e], w_down[base + e]) * ge
 
         return jax.lax.cond(hit[e], run, lambda a: a, acc)
 
@@ -369,7 +390,7 @@ def moe_block(x: jnp.ndarray, lp: Params, top_k: int,
     # One algorithm, realized by the rows of the call: while the hit
     # experts' bytes are the cost (a decode step, a wave up to 512 rows) one
     # kernel streams them back to back; a wider wave keeps the loop
-    if moe_pallas.takes(B * T, xf.dtype, w_gate):
+    if moe_pallas.takes(B * T, xf.dtype, w_up):
         y = moe_pallas.stream_experts(
             xf, gate, hit, w_gate, w_up, w_down, base,
             interpret=jax.default_backend() != "tpu")

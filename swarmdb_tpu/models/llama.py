@@ -10,8 +10,10 @@ Design (idiomatic TPU, not a torch port):
   differs a layer (a gated short convolution or attention) with the state
   its conv layers carry, and models/deepseek.py the family that attends
   through a latent (its token mixer, its row a token in place of keys and
-  values a head); ``run_stack`` is where a forward hands its mixers to
-  the one or the other.
+  values a head), and models/nemotron_h.py the family whose layer is ONE
+  sublayer (a Mamba-2 mixer, attention or a routed FFN alone) with the
+  Mamba-2 state a sequence carries; ``run_stack`` is where a forward hands
+  its mixers to the one or the other.
 - Parameters are a plain pytree dict; per-layer weights are STACKED along a
   leading [L, ...] axis and the forward pass is one `lax.scan` over layers —
   one compiled layer body regardless of depth (fast compiles, natural hook
@@ -226,12 +228,31 @@ def init_paged_cache(
         # that wrote a page carried at that page's end, under the page's
         # own id: the allocator, the pins and the prefix cache's LRU that
         # manage a page's keys and values manage its state with them
-        from ..ops.paged_kv import pool_dtype
-        from . import lfm2
+        from ..ops.paged_kv import is_quantized, pool_dtype
+        from . import lfm2, nemotron_h
 
         dt = pool_dtype(cache["k"])
-        cache["state"] = lfm2.init_state(cfg, batch, dt)
-        cache["page_state"] = lfm2.init_state(cfg, num_pages, dt)
+        if cfg.n_ssm_layers and is_quantized(cache["k"]):
+            refuse_state(cfg, "an int8 pool (SWARMDB_KV_DTYPE=int8: no "
+                              "test and no chip run holds this family's "
+                              "waves to quantized pages)")
+        if cfg.n_ssm_layers:
+            # a Mamba-2 layer's state is hundreds of times a page's keys
+            # and values: no state a page but a pool of SNAPSHOTS, far
+            # fewer than pages, each owned by a page while it lives
+            # (``PrefixLRU.keep_state_slots``); row 0 is the bin
+            if cfg.state_snapshots < 1:
+                raise ValueError(
+                    f"{cfg.name!r}: a paged pool for Mamba-2 layers needs "
+                    "state_snapshots, how many snapshots it keeps (each "
+                    "as large as a slot's state: what is left of the "
+                    "chip decides)")
+            cache["state"] = nemotron_h.init_state(cfg, batch, dt)
+            cache["page_state"] = nemotron_h.init_state(
+                cfg, 1 + cfg.state_snapshots, dt)
+        else:
+            cache["state"] = lfm2.init_state(cfg, batch, dt)
+            cache["page_state"] = lfm2.init_state(cfg, num_pages, dt)
     return cache
 
 
@@ -343,7 +364,7 @@ def take_routing(cfg: ModelConfig, out):
 
 def run_stack(params: Params, cfg: ModelConfig, x, cos, sin, mixer, ops,
               moe_dispatch: Optional[str] = None, history=None,
-              conv_ops=None, live=None):
+              conv_ops=None, live=None, mamba=None):
     """``x`` through every layer, for a forward that brings its mixers:
     ``(x, attention outs, conv outs, routing)``. A configuration whose
     layers all attend is one ``lax.scan`` of ``decoder_layer`` over
@@ -352,8 +373,18 @@ def run_stack(params: Params, cfg: ModelConfig, x, cos, sin, mixer, ops,
     forward's ``history`` and ``conv_ops`` for its conv layers and
     ``live``, the rows that take part, for its expert FFN; a latent one
     to ``deepseek.run_layers``, whose ``mixer(q, row, ops)`` attends over
-    rows (``deepseek.latent_token_mixer``). ``routing`` is what
-    ``take_routing`` returns."""
+    rows (``deepseek.latent_token_mixer``); one whose layer is a
+    sublayer to ``nemotron_h.run_layers``, with ``mamba``, the forward's
+    ``(Mamba-2 token mixer, its ops a layer, aux)`` (the three
+    ``nemotron_h.*_mixers``), and its third result is ``(mamba outs,
+    aux)``. ``routing`` is what ``take_routing`` returns."""
+    if cfg.sublayers:
+        from . import nemotron_h
+
+        mamba_tm, mamba_ops, aux = mamba or (None, None, None)
+        return nemotron_h.run_layers(
+            params, cfg, x, attention_token_mixer(cfg, cos, sin, mixer),
+            ops, mamba_tm, mamba_ops, live, aux)
     if cfg.latent:
         from . import deepseek
 
@@ -380,6 +411,12 @@ def refuse_state(cfg: ModelConfig, path: str) -> None:
         from . import deepseek
 
         deepseek.refuse_latent(cfg, path)
+    if cfg.n_ssm_layers:
+        raise NotImplementedError(
+            f"{cfg.name!r} has Mamba-2 layers whose recurrent state rides "
+            f"beside the KV pages, a snapshot for a prefix hit; {path} "
+            "does not carry Mamba-2 state (the paged engine's ragged "
+            "prefill and chunked decode do)")
     if cfg.stateful:
         raise NotImplementedError(
             f"{cfg.name!r} has conv layers whose recurrent state rides "
@@ -395,6 +432,10 @@ def rope_terms(cfg: ModelConfig, positions: jnp.ndarray):
         from . import deepseek
 
         return deepseek.rope_terms(cfg, positions)
+    if not cfg.rope:
+        # attention that does not rotate: the identity's terms
+        shape = positions.shape + (1, cfg.head_dim // 2)
+        return jnp.ones(shape, jnp.float32), jnp.zeros(shape, jnp.float32)
     return rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
 
 
@@ -453,15 +494,20 @@ def forward(
     x = params["embed"][tokens]  # [B, T, D]; compute dtype = param dtype
     # RoPE terms depend only on positions: compute once, reuse in every
     # scanned layer (XLA can't hoist transcendentals out of the loop body)
-    cos, sin = rope_cos_sin(positions, cfg.head_dim, cfg.rope_theta)
+    cos, sin = rope_terms(cfg, positions)
 
     def mixer(q, k, v, kv):
         ck, cv = write_kv_cache(*kv, k, v, positions)
         attn = gqa_attention(q, ck, cv, positions, window=cfg.sliding_window)
         return attn, (ck, cv)
 
-    history = None
-    if cfg.stateful:
+    history = mamba = None
+    if cfg.n_ssm_layers:
+        # likewise for Mamba-2 layers: the plain recurrence from zeros
+        from . import nemotron_h
+
+        mamba = nemotron_h.whole_mixers(cfg)
+    elif cfg.stateful:
         # the plain whole-sequence forward of a configuration with conv
         # layers: rows start at position 0 with nothing before them, and
         # the cache is the attending layers' alone. What the served paths
@@ -471,7 +517,7 @@ def forward(
         history = lfm2.history_whole(cfg.conv_taps - 1)
     x, new_cache, _state, routing = run_stack(
         params, cfg, x, cos, sin, mixer, tuple(cache), moe_dispatch,
-        history=history)
+        history=history, mamba=mamba)
     return lm_logits(params, cfg, x, logits_at), new_cache, *routing
 
 
@@ -578,6 +624,18 @@ def forward_ragged_prefill(
     [L_conv, W // ps + R, taps-1, D] with ``page_ids`` [W // ps + R], the
     state after every token that ends a page and that page's id (0, the
     trash page, for the unused entries).
+
+    A configuration with Mamba-2 layers (models/nemotron_h.py) takes as
+    ``state_seed`` ``{"conv": the rows' conv rows [L_m, R, taps - 1, conv
+    dim], "ssm": (src [R], slots [R], dst [R], the slots' pool, the
+    snapshots' pool)}`` (``nemotron_h.ssm_segments`` has what each means:
+    the large part of the state is read from and written to its pools in
+    place, never stacked over a wave's rows), and returns after ``sfx_v``:
+    the conv rows after each row's last token of this call and after its
+    LAST page end of this call (what a snapshot holds: one a row, not one
+    a page), both over R rows; ``end_lens`` [R], how many of the row's
+    tokens lie up to that page end (0: it crossed none); and the two
+    pools as written.
     """
     from ..ops.layers import ragged_prefill_dispatch
     from ..ops.paged_kv import pool_data, pool_dtype, pools_flat
@@ -622,10 +680,16 @@ def forward_ragged_prefill(
                 starts, lens, plens, tok_row)
             return o_lat[None], (rs, None)
 
-    history = page_ids = None
+    history = page_ids = mamba = None
     R = starts.shape[0]
     live = (tok_row >= 0) & (tok_row < R)
-    if cfg.stateful:
+    if cfg.n_ssm_layers:
+        from . import nemotron_h
+
+        mamba, page_ids = nemotron_h.stream_mixers(
+            cfg, state_seed, tok_row, tok_pos, starts, lens, live,
+            pool_data(pool_k).shape[2])
+    elif cfg.stateful:
         from . import lfm2
 
         ps = pool_data(pool_k).shape[2]
@@ -642,10 +706,13 @@ def forward_ragged_prefill(
                                       lens, at)
     x, (sfx_k, sfx_v), state, routing = run_stack(
         params, cfg, x, cos, sin, mixer, jnp.arange(L, dtype=jnp.int32),
-        history=history, conv_ops=state_seed, live=live[None])
+        history=history, conv_ops=state_seed, live=live[None], mamba=mamba)
+    pools = ()
+    if cfg.n_ssm_layers:
+        (state, _none), pools = state
     last_w = starts + jnp.maximum(lens - 1, 0)           # dead rows -> 0
     return (lm_logits(params, cfg, x, stream_at=last_w), sfx_k, sfx_v,
-            *(() if state is None else (*state, page_ids)),
+            *(() if state is None else (*state, page_ids)), *pools,
             *(r[0] for r in routing))
 
 
@@ -703,7 +770,11 @@ def init_chunk_kv(
     shape = (cfg.n_attn_layers, batch, chunk, cfg.n_kv_heads,
              kv_head_dim(cfg))
     kv = jnp.zeros(shape, dtype), jnp.zeros(shape, dtype)
-    if cfg.stateful:
+    if cfg.n_ssm_layers:
+        from . import nemotron_h
+
+        kv += (nemotron_h.init_chunk_state(cfg, batch, chunk, dtype),)
+    elif cfg.stateful:
         kv += (jnp.zeros((cfg.n_conv_layers, batch, chunk, cfg.dim), dtype),)
     return kv
 
@@ -840,12 +911,20 @@ def forward_paged_chunked(
                 (positions[:, 0] - step).astype(jnp.int32), step)
             return o_lat[:, None], (hk, None)
 
-    history = conv_ops = live = None
+    history = conv_ops = live = mamba = None
     if cfg.latent:
         # a lane whose table row is empty holds no sequence: it reads no
         # expert
         live = table[:, :1] != 0
-    if cfg.stateful:
+    if cfg.n_ssm_layers:
+        # as below, for the Mamba-2 layers: the step reads the slots'
+        # frozen state once and the chunk's own buffers (chunk_kv[2])
+        from . import nemotron_h
+
+        mamba = nemotron_h.chunk_mixers(
+            cfg, cache["state"], chunk_kv[2], step)
+        live = table[:, :1] != 0
+    elif cfg.stateful:
         # the slots' state stays frozen for the chunk like the pool; a
         # step reads it and the chunk's own z so far (chunk_kv[2]), and
         # ``merge_paged_chunk`` folds the chunk in. A lane whose table
@@ -858,7 +937,10 @@ def forward_paged_chunked(
     x, new_chunk, hz, routing = run_stack(
         params, cfg, x, cos, sin, mixer,
         (jnp.arange(L, dtype=jnp.int32), *chunk_kv[:2]), moe_dispatch,
-        history=history, conv_ops=conv_ops, live=live)
+        history=history, conv_ops=conv_ops, live=live, mamba=mamba)
+    if cfg.n_ssm_layers:
+        (hz, ssm_bufs), _aux = hz
+        hz = (hz, *ssm_bufs)
     if hz is not None:
         new_chunk = (*new_chunk, hz)
     return lm_logits(params, cfg, x), new_chunk, *routing
@@ -875,11 +957,16 @@ def merge_paged_chunk(cache, chunk_kv, start_positions: jnp.ndarray):
         cache["page_table"],
     )
     out = {**cache, "k": new_k, "v": new_v}
-    if hz:
+    if hz and isinstance(cache["state"], dict):
+        from . import nemotron_h
+
+        out["state"] = nemotron_h.merge_state(cache["state"], *hz[0])
+    elif hz:
         from . import lfm2
 
         out["state"] = lfm2.merge_state(cache["state"], hz[0])
     return out
+
 
 
 # ----------------------------------------------------- pipeline parallelism

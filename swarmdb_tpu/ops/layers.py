@@ -293,6 +293,17 @@ def ragged_prefill_attention_reference(
     return out.reshape(W, Hq, D).astype(q.dtype)
 
 
+def ragged_wave_max_width(n_heads: int, n_kv_heads: int) -> Optional[int]:
+    """The widest wave ``ragged_prefill_dispatch``'s kernel is built into
+    (None: any width up to ``max_seq``). At 16 query heads a KV head the
+    chip's compiler refused the wave programs of 2,048 tokens (the
+    kernel's score tile is ``tile x G`` rows a KV head: 17.4 MB of VMEM
+    where a call is given 16) and of 4,096 (it copied the 2-KV-head pool),
+    and passed 1,024 (``benchmark/aot_rehearsal.py``, PR 50); at 4 a KV
+    head it takes every width. A longer round takes more waves."""
+    return 1024 if n_heads // n_kv_heads >= 16 else None
+
+
 def ragged_prefill_dispatch(
     q: jnp.ndarray,           # [W, Hq, D] packed query stream
     sfx_k: jnp.ndarray,       # [W, Hkv, D]

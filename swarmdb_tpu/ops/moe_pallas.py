@@ -26,6 +26,12 @@ operation: float32 out of every matmul, ``silu(g) * u`` in float32 and
 rounded once where the down matmul reads it, the expert's output gated and
 summed in float32 in ascending expert order, rounded once at the end. Only
 the down matmul's reduction is split where F is tiled.
+
+A layer of UN-GATED experts (``w_gate`` None: ``W_2 relu(W_1 x)^2``,
+``lfm2._relu2``) streams two matrices an expert and not three: the same
+walk, grid and sum, one fetch fewer a tile. (Such a family keeps an F that
+is no lane multiple, 1856, at the next one, 1920, the upper columns zero:
+``models/nemotron_h.py``.)
 """
 
 from __future__ import annotations
@@ -66,14 +72,14 @@ def hit_list(hit: jnp.ndarray):
     return n_hit.reshape(1), jnp.where(e < n_hit, ids, last)
 
 
-def takes(n_rows: int, x_dtype, w_gate: jnp.ndarray) -> bool:
+def takes(n_rows: int, x_dtype, w_up: jnp.ndarray) -> bool:
     """Whether the kernel takes a call of ``n_rows`` rows against these
-    expert matrices, by what the call itself shows: rows few enough that
-    the hit experts' bytes are the cost, bf16 rows and weights, widths
-    that are lane multiples, and a TPU to run on."""
-    _, D, F = w_gate.shape
+    expert matrices (``w_up`` [.., D, F]), by what the call itself shows:
+    rows few enough that the hit experts' bytes are the cost, bf16 rows
+    and weights, widths that are lane multiples, and a TPU to run on."""
+    _, D, F = w_up.shape
     return (n_rows <= MAX_ROWS and x_dtype == jnp.bfloat16
-            and w_gate.dtype == jnp.bfloat16
+            and w_up.dtype == jnp.bfloat16
             and D % LANES == 0 and F % LANES == 0 and _on_tpu())
 
 
@@ -92,8 +98,11 @@ def tile_of(F: int) -> int:
     return t
 
 
-def _stream_kernel(base_ref, nhit_ref, ids_ref, x_ref, gate_ref, wg_ref,
-                   wu_ref, wd_ref, o_ref, acc_ref, part_ref, *, n_f):
+def _stream_kernel(base_ref, nhit_ref, ids_ref, x_ref, gate_ref, *refs,
+                   n_f, gated):
+    # refs: (w_gate,) w_up, w_down, out, the two float32 scratches
+    wg_ref = refs[0] if gated else None
+    wu_ref, wd_ref, o_ref, acc_ref, part_ref = refs[gated:]
     i, f = pl.program_id(0), pl.program_id(1)
     f32 = jnp.float32
 
@@ -104,8 +113,13 @@ def _stream_kernel(base_ref, nhit_ref, ids_ref, x_ref, gate_ref, wg_ref,
     @pl.when(i < nhit_ref[0])
     def _expert():
         x = x_ref[...]
-        h = jax.nn.silu(jnp.dot(x, wg_ref[...], preferred_element_type=f32)) \
-            * jnp.dot(x, wu_ref[...], preferred_element_type=f32)
+        if gated:
+            h = jax.nn.silu(jnp.dot(x, wg_ref[...],
+                                    preferred_element_type=f32)) \
+                * jnp.dot(x, wu_ref[...], preferred_element_type=f32)
+        else:
+            h = jnp.square(jnp.maximum(
+                jnp.dot(x, wu_ref[...], preferred_element_type=f32), 0.0))
         y = jnp.dot(h.astype(x.dtype), wd_ref[...],
                     preferred_element_type=f32)
         gate = gate_ref[...]
@@ -138,18 +152,21 @@ def stream_experts(
     x: jnp.ndarray,        # [N, D] the rows
     gate: jnp.ndarray,     # [N, E] float32, 0 where not chosen or dead
     hit: jnp.ndarray,      # [E] bool, the experts some live row chose
-    w_gate: jnp.ndarray,   # [E | n * E, D, F]
+    w_gate,                # [E | n * E, D, F]; None: un-gated relu² experts
     w_up: jnp.ndarray,
     w_down: jnp.ndarray,   # [E | n * E, F, D]
     base=0,                # this layer's first row of a flat stack
     tile_f=None,
     interpret: bool = False,
 ) -> jnp.ndarray:
-    """The gated sum over the hit experts of ``_swiglu(x, expert)``,
-    ``[N, D]`` in ``x.dtype``; zeros where no expert is hit."""
+    """The gated sum over the hit experts of ``_swiglu(x, expert)``
+    (``_relu2`` where ``w_gate`` is None), ``[N, D]`` in ``x.dtype``; zeros
+    where no expert is hit."""
     N, D = x.shape
     E = gate.shape[1]
-    F = w_gate.shape[2]
+    F = w_up.shape[2]
+    gated = w_gate is not None
+    ins = 2 + gated                # the matrices an expert streams
     tf = tile_of(F) if tile_f is None else tile_f
     if F % tf:
         raise ValueError(f"tile_f {tf} does not divide F {F}")
@@ -174,7 +191,7 @@ def stream_experts(
     def down_map(i, f, base_ref, nhit_ref, ids_ref):
         return (base_ref[0] + ids_ref[i], tile(i, f, nhit_ref), 0)
 
-    buffers = 2 * 3 * D * tf * w_gate.dtype.itemsize
+    buffers = 2 * ins * D * tf * w_up.dtype.itemsize
     resident = rows * D * (4 * x.dtype.itemsize + 8) + 3 * rows * tf * 4
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
@@ -182,8 +199,7 @@ def stream_experts(
         in_specs=[
             pl.BlockSpec((rows, D), rows_map),
             pl.BlockSpec((rows, E), rows_map),
-            pl.BlockSpec((None, D, tf), in_map),
-            pl.BlockSpec((None, D, tf), in_map),
+            *[pl.BlockSpec((None, D, tf), in_map)] * (ins - 1),
             pl.BlockSpec((None, tf, D), down_map),
         ],
         # swarmlint: revisit[i] -- every step sums into the float32
@@ -195,7 +211,7 @@ def stream_experts(
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_stream_kernel, n_f=n_f),
+        functools.partial(_stream_kernel, n_f=n_f, gated=gated),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((rows, D), x.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -203,5 +219,6 @@ def stream_experts(
             vmem_limit_bytes=buffers + resident + (8 << 20)),
         name="moe_stream_experts",
         interpret=interpret,
-    )(base, n_hit, ids, x, gate.astype(jnp.float32), w_gate, w_up, w_down)
+    )(base, n_hit, ids, x, gate.astype(jnp.float32),
+      *((w_gate,) if gated else ()), w_up, w_down)
     return out[:N]

@@ -113,7 +113,15 @@ class PrefixLRU:
         )
         # chain -> the caller's handle on the recurrent state at that
         # page's end, for the entries registered with one (``register``)
+        # or given a snapshot slot (``take_state_slot``)
         self._states: dict = {}
+        # snapshot slots (``keep_state_slots``; 0 = none kept): the free
+        # ones, slot -> (the chain that owns it, its depth in pages), and
+        # the slots whose sequence has a deeper one since
+        self.state_slots = 0
+        self._state_free: List[int] = []
+        self._state_owner: dict = {}
+        self._state_superseded: set = set()
         self._pins: dict = {}            # page_id -> pin count
         # the pages the entries hold: what ``evictable_count`` needs to
         # tell a pin on a cached page without walking every entry
@@ -219,9 +227,81 @@ class PrefixLRU:
         for chain in victims:
             page = self._entries.pop(chain)[0]
             self._entry_pages.discard(page)
-            self._states.pop(chain, None)
+            self._drop_state(chain)
             out.append(page)
         return out
+
+    # swarmlint: holds[self._lock]
+    def _drop_state(self, chain: bytes) -> None:
+        state = self._states.pop(chain, None)
+        if self._state_owner.pop(state, None) is not None:
+            self._state_superseded.discard(state)
+            self._state_free.append(state)
+
+    # -------------------------------------------------------- snapshot slots
+
+    def keep_state_slots(self, n: int) -> None:
+        """From here on a state handle is a slot ``1..n`` of a pool of
+        SNAPSHOTS the caller keeps on the device (0 is its bin), for
+        sequences whose recurrent state is hundreds of times a page's keys
+        and values (models/nemotron_h.py): far fewer snapshots than pages,
+        each the state as it stood at ONE cached page's end. A snapshot
+        leaves with its page (an eviction, ``reset``) or apart from it,
+        when ``take_state_slot`` finds no free slot: the page then stays,
+        and a hit on it has nothing to resume behind and is forgone."""
+        with self._lock:
+            self.state_slots = n
+            self._state_free = list(range(n, 0, -1))
+
+    def take_state_slot(self, chain: bytes, depth: int, busy=()
+                        ) -> Tuple[int, bool]:
+        """A slot for the state at ``chain``'s end, ``depth`` pages deep,
+        bound to the chain from here on (``match`` hands it out once the
+        chain's page is registered): a free one; else one whose sequence
+        has a deeper one since (``supersede``: no loss); else the
+        SHALLOWEST's, if that is shallower than this one. A forgone hit
+        computes again exactly the tokens its snapshot stood behind, so
+        the shallowest is the one whose loss costs least, and a newcomer
+        shallower than all that are kept takes none. (Age is the pages'
+        matter: a sequence nobody comes back to loses its pages to their
+        LRU, and its snapshot with them.) Never a slot in ``busy``, the
+        ones this round's rows resume from: a wave reads them. Returns
+        ``(slot, whether a snapshot that was still its sequence's deepest
+        left for it)``; ``(0, False)`` where the chain has one already
+        (the same prompt twice) or none is to be had."""
+        with self._lock:
+            if chain in self._states:
+                return 0, False
+            lost = False
+            if self._state_free:
+                slot = self._state_free.pop()
+            else:
+                slot = next((s for s in self._state_superseded
+                             if s not in busy), 0)
+                if not slot:
+                    slot, shallowest = min(
+                        ((s, d) for s, (_, d) in self._state_owner.items()
+                         if s not in busy),
+                        key=lambda sd: sd[1], default=(0, 0))
+                    if not slot or shallowest >= depth:
+                        return 0, False
+                    lost = True
+                self._drop_state(self._state_owner[slot][0])
+                self._state_free.remove(slot)
+            self._states[chain] = slot
+            self._state_owner[slot] = (chain, depth)
+            return slot, lost
+
+    def supersede(self, slot: int) -> None:
+        """The sequence that resumed from ``slot`` has a deeper snapshot
+        since: ``slot`` is the first to leave."""
+        with self._lock:
+            if slot in self._state_owner:
+                self._state_superseded.add(slot)
+
+    def state_slots_live(self) -> int:
+        with self._lock:
+            return len(self._state_owner)
 
     def evict_lru(self, n: int, want=None) -> List[int]:
         """Evict up to ``n`` LRU unpinned entries, returning their page
@@ -255,6 +335,9 @@ class PrefixLRU:
             self._entries.clear()
             self._entry_pages.clear()
             self._states.clear()
+            self._state_owner.clear()
+            self._state_superseded.clear()
+            self._state_free = list(range(self.state_slots, 0, -1))
             self._pins.clear()
 
     def evictable_count(self) -> int:
@@ -301,7 +384,9 @@ class PrefixLRU:
         under the page's own id (``cache["page_state"]``, models/lfm2.py),
         so its handle is ``True``; a page registered with ``None`` has
         keys and values a request can attend to and no state to resume
-        behind."""
+        behind. A snapshot slot (``take_state_slot``) is bound to the chain
+        where it is taken, not here: the state is the chain's, whichever
+        page holds the keys and values."""
         with self._lock:
             old = self._entries.pop(chain, None)
             if old is not None:

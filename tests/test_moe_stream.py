@@ -285,3 +285,63 @@ def test_a_held_share_streams_only_what_is_held(monkeypatch, first):
     # the part of the uncut layer that these experts give
     whole_gate = lfm2.moe_block(x, lp, K)[1]
     np.testing.assert_array_equal(experts, np.asarray(whole_gate))
+
+
+# ------------------------------------------------- un-gated relu² experts
+
+
+def _relu2_layer(d, f, e, dtype, seed=9):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+
+    def draw(k, shape, fan_in):
+        return (jax.random.normal(k, shape, F32) / fan_in ** .5).astype(dtype)
+
+    return {"router": draw(ks[0], (d, e), d),
+            "expert_bias": 0.1 * jax.random.normal(ks[1], (e,), F32),
+            "w_up": draw(ks[2], (e, d, f), d),
+            "w_down": draw(ks[3], (e, f, d), f)}
+
+
+@pytest.mark.parametrize("d,f,tile_f,dtype", [
+    (256, 256, 128, F32), (256, 256, 128, BF16), (256, 256, None, BF16),
+    (2688, 1920, None, BF16)],
+    ids=["f32-two-tiles", "bf16-two-tiles", "bf16-one-tile",
+         "nemotron-2688-1856-as-laid-out"])
+def test_the_kernel_equals_the_loop_for_ungated_relu2_experts(
+        monkeypatch, d, f, tile_f, dtype):
+    """A layer without ``w_gate`` streams two matrices an expert. The
+    published 2688 / 1856 is kept at 1920 columns, the upper 64 zero
+    (``nemotron_h.lanes_up``): three tiles of 640."""
+    from swarmdb_tpu.models import nemotron_h
+
+    e = 4
+    lp = _relu2_layer(d, f, e, dtype)
+    if f == 1920:
+        assert nemotron_h.lanes_up(1856) == f and moe_pallas.tile_of(f) == 640
+        lp["w_up"] = lp["w_up"].at[..., 1856:].set(0)
+        lp["w_down"] = lp["w_down"].at[:, 1856:].set(0)
+    x = jax.random.normal(jax.random.PRNGKey(2), (16, 1, d),
+                          F32).astype(dtype)
+    monkeypatch.setattr(moe_pallas, "_on_tpu", lambda: True)
+    assert moe_pallas.takes(16, BF16, lp["w_up"]) == (dtype == BF16)
+    (want, r_want), (got, r_got) = both(monkeypatch, x, lp, None, 0, tile_f)
+    np.testing.assert_array_equal(np.asarray(r_got), np.asarray(r_want))
+    if dtype == F32:
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=1e-5, rtol=0)
+    else:
+        # three tiles split the down matmul's sum three ways: where the
+        # sum cancels to 1e-5 of its terms one element in 43,008 lands
+        # two bf16 steps off
+        steps = bf16_steps_apart(got, want)
+        assert steps.max() <= (2 if f == 1920 else 1)
+        assert (steps > 1).mean() < 1e-3
+    # and the loop's expert is the un-gated form by hand
+    xf = x.reshape(-1, d)
+    one = lfm2.expert_ffn(xf, None, lp["w_up"][0], lp["w_down"][0])
+    np.testing.assert_allclose(
+        np.asarray(one, np.float32),
+        np.asarray(jnp.square(jax.nn.relu(
+            xf.astype(F32) @ lp["w_up"][0].astype(F32))).astype(dtype)
+            .astype(F32) @ lp["w_down"][0].astype(F32)),
+        atol=2e-2 if dtype == BF16 else 1e-4, rtol=2e-2)

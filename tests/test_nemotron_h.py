@@ -1,0 +1,616 @@
+"""The family with a sublayer a layer (models/nemotron_h.py) at tiny widths:
+the three forms of the Mamba-2 recurrence against each other; every served
+forward against the plain whole-sequence ``forward``; snapshots rarer than
+a page through the engine (a prefix hit that resumes from a snapshot gives
+what the cold request gives, and so does one whose snapshot was evicted,
+forgone and counted); a layer with no FFN and one with no mixer; the
+shares of an expert layer add up to the whole; the paths that cannot carry
+the state refuse by name."""
+
+import threading
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from swarmdb_tpu.backend.engine import GenRequest
+from swarmdb_tpu.backend.sampling import SamplingParams
+from swarmdb_tpu.backend.service import build_backend_engine
+from swarmdb_tpu.models import lfm2, llama, nemotron_h
+from swarmdb_tpu.models.configs import TINY_NEMOTRON as CFG
+from swarmdb_tpu.models.configs import ModelConfig, get_config
+from swarmdb_tpu.ops.paged_kv import paged_write_ragged
+from swarmdb_tpu.ops.prefix_cache import PrefixLRU
+
+PS, SLOTS, PAGES, MAX_SEQ, SNAPS = 16, 4, 64, 256, 5
+F32 = jnp.float32
+H, P, G, N = CFG.ssm_heads, CFG.ssm_head_dim, CFG.ssm_groups, CFG.ssm_state
+
+
+@pytest.fixture(autouse=True)
+def short_segments(monkeypatch):
+    # rows of tens of tokens must span several segments of the wave's scan
+    monkeypatch.setattr(nemotron_h, "SCAN_CHUNK", 8)
+
+
+@pytest.fixture(scope="module")
+def params():
+    return nemotron_h.init_params(CFG, jax.random.PRNGKey(11), F32)
+
+
+@pytest.fixture(scope="module")
+def tokens():
+    return np.asarray(jax.random.randint(jax.random.PRNGKey(5), (3, 96), 3,
+                                         CFG.vocab_size))
+
+
+def _highest(fn):
+    def run(params, *args):
+        with jax.default_matmul_precision("highest"):
+            return fn(params, CFG, *args)
+    return jax.jit(run)
+
+
+_FORWARD = _highest(llama.forward)
+_RAGGED = _highest(llama.forward_ragged_prefill)
+_CHUNKED = _highest(llama.forward_paged_chunked)
+TOL = dict(atol=3e-4, rtol=0)
+
+
+def whole(params, toks):
+    T = len(toks)
+    logits, _cache, _routing = _FORWARD(
+        params, jnp.asarray(toks)[None], jnp.arange(T)[None],
+        llama.init_kv_cache(CFG, 1, T, F32))
+    return np.asarray(logits[0])
+
+
+# ------------------------------------------------- the recurrence's forms
+
+
+def _draw(key, *shape):
+    return jax.random.normal(jax.random.PRNGKey(key), shape, F32)
+
+
+def test_the_scan_over_a_ragged_wave_equals_the_plain_recurrence():
+    """Seeded from a non-zero state, rows that end inside a segment, a
+    one-token row, a dead row, a row that ends AT its last page end."""
+    W, R, B_, S_ = 64, 5, 6, 4
+    lens = np.array([19, 1, 0, 30, 7])
+    starts = np.array([0, 19, 0, 20, 50])
+    end_lens = np.array([16, 0, 0, 30, 5])
+    src = np.array([2, -1, 0, 0, 3])          # a snapshot, the slot's own
+    slots = np.array([0, 4, B_, 2, 5])        # (B_: nowhere)
+    dst = np.array([1, 0, 0, 4, 0])           # (0: the bin)
+    xd, la = _draw(0, W, H, P), -jnp.abs(_draw(1, W, H))
+    Bm, Cm = _draw(2, W, G, N), _draw(3, W, G, N)
+    slot0, snap0 = _draw(4, 2, B_, H * P, N), _draw(15, 2, 1 + S_, H * P, N)
+    y, (slot, snap) = jax.jit(lambda *a: nemotron_h.ssm_segments(
+        CFG, *a[:4], *(jnp.asarray(v) for v in (starts, lens, end_lens)),
+        jnp.int32(1), *(jnp.asarray(v) for v in (src, slots, dst)),
+        a[4:]))(xd, la, Bm, Cm, slot0, snap0)
+    # the other layer of the pools is untouched, and so is what no row names
+    np.testing.assert_array_equal(slot[0], slot0[0])
+    np.testing.assert_array_equal(snap[0], snap0[0])
+    np.testing.assert_array_equal(slot[1, [1, 3]], slot0[1, [1, 3]])
+    np.testing.assert_array_equal(snap[1, [2, 3]], snap0[1, [2, 3]])
+    for r in range(R):
+        s, n, e = starts[r], lens[r], end_lens[r]
+        if not n:
+            continue
+        seed = (snap0[1, src[r]] if src[r] > 0 else slot0[1, slots[r]]
+                if src[r] < 0 else jnp.zeros((H * P, N)))
+        S0 = seed.reshape(1, H, P, N)
+        cut = lambda a, m: a[None, s:s + m]
+        want, S = nemotron_h.ssm_recurrence(
+            CFG, cut(xd, n), cut(la, n), cut(Bm, n), cut(Cm, n), S0)
+        np.testing.assert_allclose(y[s:s + n], want[0], atol=2e-5)
+        np.testing.assert_allclose(slot[1, slots[r]], S.reshape(H * P, N),
+                                   atol=2e-5)
+        if e and dst[r]:
+            _, Se = nemotron_h.ssm_recurrence(
+                CFG, cut(xd, e), cut(la, e), cut(Bm, e), cut(Cm, e), S0)
+            np.testing.assert_allclose(snap[1, dst[r]],
+                                       Se.reshape(H * P, N), atol=2e-5)
+
+
+def test_the_one_token_step_equals_the_recurrence_and_merges_its_state():
+    B_, K = 3, 4
+    S0 = _draw(5, B_, H * P, N)
+    xs, las = _draw(6, B_, K, H, P), -jnp.abs(_draw(7, B_, K, H))
+    Bs, Cs = _draw(8, B_, K, G, N), _draw(9, B_, K, G, N)
+    want, SK = nemotron_h.ssm_recurrence(CFG, xs, las, Bs, Cs,
+                                         S0.reshape(B_, H, P, N))
+    bufs = (jnp.zeros((B_, K, H, P)), jnp.zeros((B_, K, G, N)),
+            jnp.zeros((B_, K, H)))
+    for j in range(K):
+        y, bufs = nemotron_h.ssm_chunk_step(
+            CFG, xs[:, j], las[:, j], Bs[:, j], Cs[:, j], S0, bufs,
+            jnp.int32(j))
+        np.testing.assert_allclose(y, want[:, j], atol=2e-5)
+    state = {"ssm": S0[None], "conv": jnp.zeros((1, B_, 3, CFG.ssm_conv_dim))}
+    merged = nemotron_h.merge_state(
+        state, jnp.zeros((1, B_, K, CFG.ssm_conv_dim)),
+        *(b[None] for b in bufs))
+    np.testing.assert_allclose(merged["ssm"][0], SK.reshape(B_, H * P, N),
+                               atol=2e-5)
+
+
+def test_a_steps_decay_lies_strictly_inside_zero_and_one(params):
+    """As the weights are drawn: over a thousand tokens no step's
+    ``exp(dt a)`` is 0 or 1 in float32."""
+    lp = params["segments"][0][0]
+    dt = jax.nn.softplus(_draw(10, 1000, 1, H) * 3 + lp["dt_bias"][0])
+    decay = np.asarray(jnp.exp(-jnp.exp(lp["A_log"][0]) * dt))
+    assert 0.0 < decay.min() and decay.max() < 1.0
+
+
+# ------------------------------------------- the served forwards, float32
+
+
+class Served:
+    """The served path's model calls around a float32 pool, with the seed
+    and scatter rules of ``Engine._prefill_ragged_insert`` under
+    snapshots: a wave of rows ``(slot, tokens, first position, table row,
+    src, dst)``, ``src`` a snapshot slot to resume from, 0 for a cold row,
+    -1 for the slot's own state; ``dst`` the snapshot slot that takes the
+    state at the row's last page end."""
+
+    def __init__(self, params):
+        self.params = params
+        self.cache = llama.init_paged_cache(
+            get_config("tiny-nemotron", state_snapshots=SNAPS), SLOTS,
+            MAX_SEQ, PAGES, PS, F32)
+        assert self.cache["page_state"]["ssm"].shape[:2] == (4, 1 + SNAPS)
+
+    def wave(self, rows, width):
+        R, maxp = SLOTS, MAX_SEQ // PS
+        toks = np.zeros(width, np.int32)
+        tok_row = np.full(width, R, np.int32)
+        tok_pos = np.full(width, maxp * PS, np.int32)
+        starts, lens, plens = (np.zeros(R, np.int32) for _ in range(3))
+        tables = np.zeros((R, maxp), np.int32)
+        src, dst = np.zeros(R, np.int32), np.zeros(R, np.int32)
+        slots = np.full(R, SLOTS, np.int32)
+        at = 0
+        for r, (slot, row_toks, p0, table, s, d) in enumerate(rows):
+            n = len(row_toks)
+            toks[at:at + n] = row_toks
+            tok_row[at:at + n] = r
+            tok_pos[at:at + n] = np.arange(p0, p0 + n)
+            starts[r], lens[r], plens[r] = at, n, p0
+            tables[r, :len(table)] = table
+            src[r], dst[r], slots[r] = s, d, slot
+            at += n
+        c = self.cache
+        src, slots, dst = (jnp.asarray(a) for a in (src, slots, dst))
+        seed = {"conv": lfm2.seed_state(src, slots, c["state"]["conv"],
+                                        c["page_state"]["conv"]),
+                "ssm": (src, slots, dst, c["state"]["ssm"],
+                        c["page_state"]["ssm"])}
+        (logits, sk, sv, row_conv, end_conv, end_lens, slot_ssm, snap_ssm,
+         routing) = _RAGGED(
+            self.params, jnp.asarray(toks), jnp.asarray(tok_row),
+            jnp.asarray(tok_pos), jnp.asarray(tables),
+            jnp.asarray(starts), jnp.asarray(lens), jnp.asarray(plens),
+            c["k"], c["v"], seed)
+        c["k"], c["v"] = paged_write_ragged(
+            c["k"], c["v"], sk, sv, jnp.asarray(tok_row),
+            jnp.asarray(tok_pos), jnp.asarray(tables))
+        to = jnp.where(end_lens > 0, dst, 0)
+        c["state"] = {"ssm": slot_ssm, "conv": c["state"]["conv"].at[
+            :, slots].set(row_conv, mode="drop")}
+        c["page_state"] = {"ssm": snap_ssm, "conv": c["page_state"][
+            "conv"].at[:, to].set(end_conv)}
+        for r, (slot, _t, _p, table, _s, _d) in enumerate(rows):
+            c["page_table"] = c["page_table"].at[slot, :len(table)].set(
+                jnp.asarray(table, jnp.int32))
+        assert routing.shape == (width, CFG.n_routed_layers,
+                                 CFG.experts_per_token)
+        return np.asarray(logits), np.asarray(end_lens)
+
+    def chunk(self, feed, pos0, K=8):
+        chunk_kv = llama.init_chunk_kv(CFG, SLOTS, K, F32)
+        out = []
+        for s in range(K):
+            logits, chunk_kv, _routing = _CHUNKED(
+                self.params, jnp.asarray(feed[s])[:, None],
+                jnp.asarray(pos0 + s)[:, None], self.cache, chunk_kv,
+                jnp.int32(s))
+            out.append(np.asarray(logits[:, 0]))
+        self.cache = llama.merge_paged_chunk(self.cache, chunk_kv,
+                                             jnp.asarray(pos0))
+        return np.stack(out)
+
+
+def test_layer_plan_of_the_published_pattern_is_a_period_and_a_tail():
+    pattern = "MEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEM*EMEMEMEM*EMEMEMEME"
+    kinds = {"M": "mamba", "E": "moe", "*": "full_attention"}
+    cfg = get_config("tiny-nemotron", n_layers=52,
+                     layer_types=tuple(kinds[c] for c in pattern))
+    plan = nemotron_h.layer_plan(cfg)
+    assert plan[0] == (tuple(kinds[c] for c in "MEMEM*E"), 5)
+    assert sum(len(p) * n for p, n in plan) == 52
+    assert (cfg.n_ssm_layers, cfg.n_routed_layers, cfg.n_attn_layers) == (
+        23, 23, 6)
+
+
+def test_cold_rows_packed_in_one_wave_match_the_whole_forward(params,
+                                                              tokens):
+    s = Served(params)
+    a, b = tokens[0][:37], tokens[1][:21]
+    got, end_lens = s.wave([(0, a, 0, [1, 2, 3], 0, 1),
+                            (1, b, 0, [4, 5], 0, 2)], 64)
+    np.testing.assert_allclose(got[0], whole(params, a)[-1], **TOL)
+    np.testing.assert_allclose(got[1], whole(params, b)[-1], **TOL)
+    assert list(end_lens[:2]) == [32, 16]
+
+
+@pytest.mark.parametrize("boundary", [16, 32, 48])
+def test_a_row_that_resumes_from_a_snapshot_matches_cold(params, tokens,
+                                                         boundary):
+    """The snapshot is the state at the row's LAST page end of the wave
+    that took it; another sequence uses the slot in between."""
+    s = Served(params)
+    toks = tokens[0][:70]
+    pages = list(range(1, 6))
+    s.wave([(0, toks[:boundary + 5], 0, pages, 0, 3)], 64)
+    s.wave([(0, tokens[2][:30], 0, [9, 10], 0, 0)], 32)
+    got, _ = s.wave([(2, toks[boundary:], boundary, pages, 3, 0)], 64)
+    np.testing.assert_allclose(got[0], whole(params, toks)[-1], **TOL)
+
+
+def test_a_split_prompt_and_chunked_decode_carry_the_state(params, tokens):
+    s = Served(params)
+    toks = tokens[1][:60]
+    pages = [7, 8, 9, 10, 11]
+    s.wave([(1, toks[:23], 0, pages, 0, 0)], 32)
+    got, _ = s.wave([(1, toks[23:44], 23, pages, -1, 0)], 32)
+    want = whole(params, toks)
+    np.testing.assert_allclose(got[0], want[43], **TOL)
+    feed = np.zeros((16, SLOTS), np.int32)
+    feed[:, 1] = toks[44:60]
+    pos0 = np.zeros(SLOTS, np.int32)
+    pos0[1] = 44
+    out = np.concatenate([s.chunk(feed[:8], pos0),
+                          s.chunk(feed[8:], pos0 + 8)])
+    np.testing.assert_allclose(out[:, 1], want[44:60], **TOL)
+
+
+def test_prefill_then_decode_match_the_benchmarks_reference(params, tokens):
+    """Through pages and state, against the plain reference the benchmark
+    holds the cell to: once with the reference choosing for itself and
+    once following the routing the served path reports, in float32."""
+    import json
+    import sys
+    from pathlib import Path
+
+    root = Path(__file__).resolve().parents[1]
+    sys.path.insert(0, str(root))
+    from benchmark.reference import nemotron_h_decoder as ref
+
+    f = json.loads((root / "tests" / "benchmark" / "tiny"
+                    / "tiny-nemotron.json").read_text())
+    dims = dict(ref.dims(f), n_held=CFG.n_experts, first_held=0)
+    s = Served(params)
+    toks = tokens[2][:56]
+    first, _ = s.wave([(3, toks[:40], 0, [20, 21, 22, 23], 0, 2)], 64)
+    feed = np.zeros((16, SLOTS), np.int32)
+    feed[:, 3] = toks[40:56]
+    pos0 = np.zeros(SLOTS, np.int32)
+    pos0[3] = 40
+    out = np.concatenate([s.chunk(feed[:8], pos0),
+                          s.chunk(feed[8:], pos0 + 8)])[:, 3]
+    served = np.concatenate([first[:1], out])           # positions 39..55
+    T = ref.Q_BLOCK
+    padded = jnp.asarray(np.concatenate([toks, np.zeros(T - 56, np.int32)]))
+    at = jnp.arange(39, 56)
+    alone = np.asarray(ref.logits_at(params, dims, padded, at))
+    np.testing.assert_allclose(served, alone, atol=5e-4)
+    _l, _c, report = _FORWARD(params, padded[None], jnp.arange(T)[None],
+                              llama.init_kv_cache(CFG, 1, T, F32))
+    forced = np.asarray(ref.logits_at(params, dims, padded, at, report[0]))
+    np.testing.assert_allclose(served, forced, atol=5e-4)
+
+
+# ------------------------------------------- a mixer alone, an FFN alone
+
+
+def _only(kind):
+    return get_config("tiny-nemotron", n_layers=2, layer_types=(kind, kind))
+
+
+def test_a_layer_with_no_ffn_and_one_with_no_mixer():
+    toks = jnp.asarray(np.arange(3, 27))[None]
+    pos = jnp.arange(24)[None]
+    for kind, keys, absent in (
+            ("mamba", {"in_proj", "out_proj", "A_log"}, {"w_up", "router"}),
+            ("moe", {"router", "w_up", "w_down", "ws_up"},
+             {"in_proj", "wq", "w_gate"})):
+        cfg = _only(kind)
+        p = nemotron_h.init_params(cfg, jax.random.PRNGKey(0), F32)
+        (layer,), = p["segments"]
+        assert keys <= set(layer) and not absent & set(layer)
+        out = llama.forward(p, cfg, toks, pos,
+                            llama.init_kv_cache(cfg, 1, 24, F32))
+        assert np.isfinite(np.asarray(out[0])).all()
+        # an FFN alone mixes nothing: a token's logits do not depend on
+        # what came before it; a mixer alone does
+        other = llama.forward(p, cfg, toks.at[0, 0].set(99), pos,
+                              llama.init_kv_cache(cfg, 1, 24, F32))
+        moved = np.abs(np.asarray(out[0] - other[0])[0, 5:]).max()
+        assert (moved == 0) == (kind == "moe")
+        assert cfg.n_attn_layers == 0 and cfg.stateful == (kind == "mamba")
+
+
+def test_the_eight_shares_of_an_expert_layer_sum_to_the_uncut_layer():
+    """The shared expert counted once: eight chips that each hold one
+    expert of eight, and the whole layer on one."""
+    cfg = _only("moe")
+    p = nemotron_h.init_params(cfg, jax.random.PRNGKey(3), F32)
+    lp = jax.tree.map(lambda a: a[0], p["segments"][0][0])
+    h = _draw(12, 1, 10, cfg.dim)
+    with jax.default_matmul_precision("highest"):
+        whole_y, routing = nemotron_h.moe_ffn(cfg, None)(h, lp, 0)
+        shared = lfm2.expert_ffn(h, None, lp["ws_up"], lp["ws_down"])
+        parts = 0
+        for e in range(cfg.n_experts):
+            share = get_config("tiny-nemotron", n_layers=2,
+                               layer_types=("moe", "moe"),
+                               first_held_expert=e, n_experts_held=1)
+            mine = {**lp, "w_up": lp["w_up"][e:e + 1],
+                    "w_down": lp["w_down"][e:e + 1]}
+            y, r = nemotron_h.moe_ffn(share, None)(h, mine, 0)
+            parts = parts + (y - shared)
+            held = np.asarray(r) >= 0
+            assert (np.asarray(r)[held] == e).all()
+    assert int(np.asarray(routing).min()) >= 0
+    np.testing.assert_allclose(parts + shared, whole_y, atol=1e-5)
+
+
+def test_the_gates_are_renormalised_scaled_and_the_bias_never_gates():
+    h = _draw(13, 6, CFG.dim)
+    w = _draw(14, CFG.dim, CFG.n_experts)
+    bias = jnp.zeros((CFG.n_experts,)).at[3].set(100.0)
+    chosen, gates = nemotron_h.route(CFG, h, w, bias)
+    assert (np.asarray(chosen)[:, 0] == 3).all()
+    np.testing.assert_allclose(np.asarray(gates).sum(-1),
+                               CFG.routed_scaling_factor, rtol=1e-5)
+    s = jax.nn.sigmoid(h @ w)
+    np.testing.assert_allclose(
+        gates[:, 0], 2.5 * s[:, 3] / jnp.take_along_axis(
+            s, chosen, axis=-1).sum(-1), rtol=1e-4)
+
+
+def test_the_fitted_bias_takes_every_expert_equally_often(params):
+    """``fit_bias`` on scores that favour a few experts for every row, and
+    ``init_params``' own: under it every routed layer's busiest expert
+    over the rows it was fitted on is near the mean."""
+    k, E = CFG.experts_per_token, CFG.n_experts
+    s = jax.nn.sigmoid(_draw(21, 256, E) + 3.0 * _draw(22, 1, E))
+
+    def busiest(chosen):
+        load = np.bincount(np.asarray(chosen).ravel(), minlength=E)
+        return load.max() / load.mean()
+
+    bias = nemotron_h.fit_bias(CFG, s)
+    assert busiest(jax.lax.top_k(s, k)[1]) > 2.0
+    assert busiest(jax.lax.top_k(s + bias, k)[1]) < 1.2
+    toks = jax.random.randint(
+        jax.random.fold_in(jax.random.PRNGKey(11), 1),
+        (nemotron_h.BALANCE_ROWS, nemotron_h.BALANCE_LEN), 0, CFG.vocab_size)
+    pos = jnp.broadcast_to(jnp.arange(toks.shape[1]), toks.shape)
+    flat = jax.tree.map(
+        lambda a: jnp.zeros_like(a), [
+            [lp.get("expert_bias") for lp in seg]
+            for seg in params["segments"]])
+    unfitted = {**params, "segments": [
+        [{**lp, "expert_bias": z} if z is not None else lp
+         for lp, z in zip(seg, zs)]
+        for seg, zs in zip(params["segments"], flat)]}
+    worst = []
+    for p in (params, unfitted):
+        routing = _highest(llama.forward)(
+            p, toks, pos, llama.init_kv_cache(CFG, *toks.shape, F32))[2]
+        worst.append(max(busiest(routing[:, :, layer])
+                         for layer in range(CFG.n_routed_layers)))
+    assert worst[0] < 1.1 and worst[1] > 1.3, worst
+
+
+# ------------------------------------------------------- the snapshot table
+
+
+def test_a_snapshot_leaves_with_its_page_or_apart_from_it():
+    lru = PrefixLRU(8, 4, manage_free=False)
+    lru.keep_state_slots(2)
+    a, b, c = b"a", b"b", b"c"
+    (sa, _), (sb, _) = lru.take_state_slot(a, 2), lru.take_state_slot(b, 3)
+    assert sa and sb and lru.state_slots_live() == 2
+    # the same prompt twice: the chain has its snapshot
+    assert lru.take_state_slot(a, 2) == (0, False)
+    lru.register(a, (1, 2, 3, 4), 1)
+    lru.register(b, (5, 6, 7, 8), 2)
+    states = []
+    lru.match([a, b], (1, 2, 3, 4, 5, 6, 7, 8), states=states)
+    assert states == [sa, sb]
+    # no free slot: the SHALLOWEST goes (its loss costs the fewest tokens),
+    # never a busy one, and never for a newcomer that is no deeper
+    assert lru.take_state_slot(c, 4, busy={sa, sb}) == (0, False)
+    assert lru.take_state_slot(c, 2) == (0, False)
+    assert lru.take_state_slot(c, 4) == (sa, True)
+    states = []
+    lru.match([a, b], (1, 2, 3, 4, 5, 6, 7, 8), states=states)
+    assert states == [None, sb]                   # a's page stays
+    # a superseded snapshot goes first, whatever its depth, and is no loss
+    lru.supersede(sa)
+    assert lru.take_state_slot(b"e", 1) == (sa, False)
+    # a page that leaves frees its snapshot's slot
+    assert lru.evict_lru(2) == [1, 2] and lru.state_slots_live() == 1
+    assert lru.take_state_slot(b"f", 1)[0] == sb
+    # a page computed again finds the new snapshot of its chain
+    lru.register(a, (1, 2, 3, 4), 1)
+    (sf, _), states = lru.take_state_slot(a, 2), []
+    assert sf and not lru.register(a, (1, 2, 3, 4), 4)
+    lru.match([a], (1, 2, 3, 4), states=states)
+    assert states == [sf]
+    lru.reset()
+    assert lru.state_slots_live() == 0
+    assert {lru.take_state_slot(bytes([i]), 1)[0] for i in range(3)} == {
+        0, 1, 2}
+
+
+# ------------------------------------------------------------- the engine
+
+
+def _engine(snapshots=SNAPS, **kw):
+    eng, _tok = build_backend_engine(
+        get_config("tiny-nemotron", state_snapshots=snapshots),
+        max_batch=SLOTS, max_seq=MAX_SEQ, seed=3, decode_chunk=8,
+        paged=True, page_size=PS, kv_pool_tokens=2048, **kw)
+    return eng
+
+
+@pytest.fixture(scope="module", params=["resident", "scan"])
+def engine(request):
+    mp = pytest.MonkeyPatch()
+    mp.setattr(nemotron_h, "SCAN_CHUNK", 8)
+    if request.param == "scan":
+        mp.setenv("SWARMDB_EMIT_RING", "0")
+    eng = _engine()
+    assert eng._stateful and eng._snapshots == SNAPS
+    assert eng._ragged_active() and eng._wave_riders() == []
+    eng.start()
+    yield eng
+    eng.stop()
+    mp.undo()
+
+
+def _run(eng, prompt, max_new=20):
+    done, seen = threading.Event(), {}
+    req = GenRequest(prompt=[int(t) for t in prompt],
+                     sampling=SamplingParams(temperature=0.0,
+                                             max_new_tokens=max_new))
+
+    def on_done(_rid, toks, reason):
+        seen.update(tokens=list(toks), reason=reason, req=req)
+        done.set()
+
+    req.on_done = on_done
+    eng.submit(req)
+    assert done.wait(300), "request did not finish"
+    return seen
+
+
+def _counter(eng, name):
+    return eng.metrics.snapshot()["counters"].get(name, 0)
+
+
+def test_a_hit_resumes_from_a_snapshot_and_an_evicted_one_is_forgone(
+        engine, tokens):
+    prompt = tokens[0][:70]
+    base = {n: _counter(engine, n) for n in (
+        "prefix_reused_tokens", "prefix_state_forgone_tokens",
+        "ssm_state_tokens_resumed", "ssm_snapshots_taken",
+        "ssm_snapshots_evicted")}
+    since = lambda n: _counter(engine, n) - base[n]
+    cold = _run(engine, prompt)
+    assert since("ssm_snapshots_taken") == 1
+    _run(engine, tokens[1][:40], 9)            # someone else's state
+    hit = _run(engine, prompt)
+    assert since("prefix_reused_tokens") == 64
+    assert since("ssm_state_tokens_resumed") == 64
+    assert since("prefix_state_forgone_tokens") == 0
+    assert hit["tokens"] == cold["tokens"]
+    np.testing.assert_allclose(hit["req"].metadata["logprobs"],
+                               cold["req"].metadata["logprobs"], atol=0.05)
+    for seen in (cold, hit):
+        rows = seen["req"].routing
+        assert seen["req"].routing_complete
+        assert rows.shape[1:] == (4, 2) and rows.min() >= 0
+    # a longer prompt of the same conversation resumes behind page 4 and
+    # its deeper snapshot takes a slot of its own
+    longer = list(prompt) + list(tokens[2][:30])
+    _run(engine, longer, 4)
+    assert since("ssm_state_tokens_resumed") == 128
+    # more conversations than the pool holds snapshots: the superseded
+    # one and then the shallowest leave apart from their pages
+    for i in range(SNAPS + 1):
+        _run(engine, tokens[1][i + 1:i + 57], 4)
+    assert since("ssm_snapshots_evicted") > 0
+    again = _run(engine, prompt)
+    assert since("prefix_state_forgone_tokens") == 64
+    assert again["tokens"] == cold["tokens"]
+    assert engine._prefix.state_slots_live() <= SNAPS
+    assert _counter(engine, "ssm_snapshot_slots_live") <= _counter(
+        engine, "ssm_snapshot_slots")
+    assert _counter(engine, "wave_rider_tokens") == 0
+
+
+def test_the_widest_wave_follows_the_query_heads_a_kv_head():
+    """What the chip's compiler refused at 16 query heads a KV head is the
+    attention kernel's, whatever else the layers are; a pool for Mamba-2
+    layers says how many snapshots it keeps."""
+    from swarmdb_tpu.ops.layers import ragged_wave_max_width
+
+    assert ragged_wave_max_width(32, 2) == 1024
+    assert ragged_wave_max_width(32, 8) is None
+    assert ragged_wave_max_width(CFG.n_heads, CFG.n_kv_heads) is None
+    wide, _tok = build_backend_engine(
+        get_config("tiny-nemotron", n_heads=16, n_kv_heads=1), max_batch=2,
+        max_seq=MAX_SEQ, paged=True, page_size=PS, kv_pool_tokens=1024)
+    assert wide.paged.ragged_max_width == 1024
+    with pytest.raises(ValueError, match="state_snapshots"):
+        _engine(snapshots=0)
+
+
+# --------------------------------------------------------------- refusals
+
+
+def test_paths_that_cannot_carry_the_state_refuse_by_name(monkeypatch):
+    with pytest.raises(NotImplementedError, match="Mamba-2 state"):
+        build_backend_engine(CFG, max_batch=2, max_seq=64, paged=False)
+    monkeypatch.setenv("SWARMDB_RAGGED_PREFILL", "0")
+    with pytest.raises(NotImplementedError, match="conv state"):
+        _engine()
+    monkeypatch.delenv("SWARMDB_RAGGED_PREFILL")
+    monkeypatch.setenv("SWARMDB_KV_DTYPE", "int8")
+    with pytest.raises(NotImplementedError, match="int8"):
+        _engine()
+
+
+@pytest.mark.parametrize("path", [
+    "forward_chunked", "forward_prefix_pages", "forward_pipelined",
+    "forward_seq_parallel", "build_sharded_model", "rolling resume"])
+def test_a_forward_without_state_refuses(path, engine):
+    if path == "rolling resume":
+        # what swarmtier's promotion, the supervisor's replay and the
+        # fleet's handoff all come in by
+        assert not engine.supports_rolling()
+        with pytest.raises(NotImplementedError, match="kept pages"):
+            engine.submit(GenRequest(prompt=[5, 6], resume_pages=[1],
+                                     resume_len=16))
+        return
+    if path == "build_sharded_model":
+        from swarmdb_tpu.parallel.serving import build_sharded_model
+
+        with pytest.raises(NotImplementedError, match="Mamba-2 state"):
+            build_sharded_model(CFG)
+        return
+    import inspect
+
+    fn = getattr(llama, path)
+    need = [p for p in inspect.signature(fn).parameters.values()
+            if p.default is p.empty and p.kind is p.POSITIONAL_OR_KEYWORD]
+    with pytest.raises(NotImplementedError, match="Mamba-2 state"):
+        fn(None, CFG, *([None] * (len(need) - 2)))
+
+
+def test_the_configuration_refuses_what_it_cannot_be():
+    with pytest.raises(ValueError, match="stand alone"):
+        get_config("tiny-nemotron", layer_types=("conv", "moe") * 4
+                   + ("mamba",))
+    with pytest.raises(ValueError, match="ssm_heads"):
+        get_config("tiny-nemotron", ssm_heads=0)
+    assert isinstance(CFG, ModelConfig) and CFG.head_dim == 32 != 64 // 4
