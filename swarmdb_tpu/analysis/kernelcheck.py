@@ -1338,6 +1338,21 @@ def _check_coverage(src: SourceFile, site: _Site,
     # symbolic assignments; collect (ref name, guard stack) per store
     stores: Dict[str, List[List[ast.expr]]] = {nm: [] for nm in out_names}
 
+    def dma_stores(stmt: ast.stmt, guards: List[ast.expr]) -> None:
+        # an output left in HBM (``ANY``) is written by the kernel's own
+        # copies: ``make_async_copy(src, out.at[...], sem)`` is its store
+        for call in ast.walk(stmt):
+            if (isinstance(call, ast.Call) and len(call.args) >= 2
+                    and getattr(call.func, "attr", None)
+                    == "make_async_copy"):
+                dst = call.args[1]
+                while isinstance(dst, (ast.Subscript, ast.Attribute,
+                                       ast.Call)):
+                    dst = dst.func if isinstance(dst, ast.Call) \
+                        else dst.value
+                if isinstance(dst, ast.Name) and dst.id in stores:
+                    stores[dst.id].append(list(guards))
+
     def walk(stmts: List[ast.stmt], guards: List[ast.expr]) -> None:
         for stmt in stmts:
             if isinstance(stmt, ast.FunctionDef):
@@ -1345,6 +1360,8 @@ def _check_coverage(src: SourceFile, site: _Site,
                 inner = guards + ([cond] if cond is not None else [])
                 walk(stmt.body, inner)
                 continue
+            if isinstance(stmt, (ast.Return, ast.Expr, ast.Assign)):
+                dma_stores(stmt, guards)
             if isinstance(stmt, (ast.Assign, ast.AugAssign, ast.AnnAssign)):
                 tgt = (stmt.targets[0] if isinstance(stmt, ast.Assign)
                        else stmt.target)

@@ -742,9 +742,14 @@ class Engine:
         self._snap_src: Dict[int, int] = {}
         if self._stateful and isinstance(self.cache["state"], dict):
             self._snapshots = self.cache["page_state"]["ssm"].shape[1] - 1
+            # ``ssm_state_rows_walked`` / ``ssm_state_rows_held``: of the
+            # slots' state, the share a decode chunk reads a step and
+            # rewrites at its end (the live slots', ``nemotron_h.
+            # chunk_mixers`` and ``merge_state``)
             for name in ("ssm_snapshots_taken", "ssm_snapshots_evicted",
                          "ssm_snapshot_slots", "ssm_snapshot_slots_live",
-                         "ssm_state_tokens_resumed"):
+                         "ssm_state_tokens_resumed", "ssm_state_rows_walked",
+                         "ssm_state_rows_held"):
                 self.metrics.counters[name].inc(0)
         # latent pages (models/deepseek.py): the pool under ``"k"`` is one
         # of rows ``[L, num_pages, ps, Wd]`` with no heads axis, and
@@ -5752,6 +5757,9 @@ class Engine:
             self._deliver_emit(em, now)
         c = self.metrics.counters
         c["decode_slot_chunks"].inc(done.n_live)
+        if self._snapshots:
+            c["ssm_state_rows_walked"].inc(done.n_live)
+            c["ssm_state_rows_held"].inc(self.max_batch)
         c["kv_page_chunks_reserved"].inc(done.pages_reserved)
         c["kv_page_chunks_written"].inc(done.pages_written)
         args = {"step": self._loop_step, "chunk": done.chunk,

@@ -375,16 +375,17 @@ def run_stack(params: Params, cfg: ModelConfig, x, cos, sin, mixer, ops,
     to ``deepseek.run_layers``, whose ``mixer(q, row, ops)`` attends over
     rows (``deepseek.latent_token_mixer``); one whose layer is a
     sublayer to ``nemotron_h.run_layers``, with ``mamba``, the forward's
-    ``(Mamba-2 token mixer, its ops a layer, aux)`` (the three
-    ``nemotron_h.*_mixers``), and its third result is ``(mamba outs,
-    aux)``. ``routing`` is what ``take_routing`` returns."""
+    ``(Mamba-2 token mixer, aux)`` (the three ``nemotron_h.*_mixers``:
+    ``aux`` is carried whole through the layers and a layer is handed its
+    index), and its third result is ``(mamba outs, aux)``. ``routing`` is
+    what ``take_routing`` returns."""
     if cfg.sublayers:
         from . import nemotron_h
 
-        mamba_tm, mamba_ops, aux = mamba or (None, None, None)
+        mamba_tm, aux = mamba or (None, None)
         return nemotron_h.run_layers(
             params, cfg, x, attention_token_mixer(cfg, cos, sin, mixer),
-            ops, mamba_tm, mamba_ops, live, aux)
+            ops, mamba_tm, live, aux)
     if cfg.latent:
         from . import deepseek
 
@@ -757,7 +758,12 @@ def init_chunk_kv(
     """Per-chunk K/V accumulator for the two-segment decode (zeros; shape
     [L, B, Kc, Hkv, D] over the layers that attend) and, for a
     configuration whose conv layers carry state, a third buffer for the
-    chunk's gated conv inputs ``z`` ([L_conv, B, Kc, D])."""
+    chunk's gated conv inputs ``z`` ([L_conv, B, Kc, D]). For one with
+    Mamba-2 layers the third entry is ``nemotron_h``'s tuple of the
+    chunk's buffers (``init_chunk_state``), and that module owns them:
+    ``forward_paged_chunked`` hands the tuple to ``chunk_mixers`` as the
+    stack's carry and takes it back as it comes out, ``merge_paged_chunk``
+    hands it to ``merge_state``; nothing here slices or stacks it."""
     if cfg.latent:
         # a latent configuration's chunk holds rows, as its pool does
         from . import deepseek
@@ -917,12 +923,13 @@ def forward_paged_chunked(
         # expert
         live = table[:, :1] != 0
     if cfg.n_ssm_layers:
-        # as below, for the Mamba-2 layers: the step reads the slots'
-        # frozen state once and the chunk's own buffers (chunk_kv[2])
+        # as below, for the Mamba-2 layers: the step reads the LIVE
+        # slots' frozen state once and carries the chunk's own buffers
+        # (chunk_kv[2]) whole through the layers
         from . import nemotron_h
 
         mamba = nemotron_h.chunk_mixers(
-            cfg, cache["state"], chunk_kv[2], step)
+            cfg, cache["state"], chunk_kv[2], step, live_rows)
         live = table[:, :1] != 0
     elif cfg.stateful:
         # the slots' state stays frozen for the chunk like the pool; a
@@ -939,8 +946,7 @@ def forward_paged_chunked(
         (jnp.arange(L, dtype=jnp.int32), *chunk_kv[:2]), moe_dispatch,
         history=history, conv_ops=conv_ops, live=live, mamba=mamba)
     if cfg.n_ssm_layers:
-        (hz, ssm_bufs), _aux = hz
-        hz = (hz, *ssm_bufs)
+        _kept, hz = hz
     if hz is not None:
         new_chunk = (*new_chunk, hz)
     return lm_logits(params, cfg, x), new_chunk, *routing
@@ -948,8 +954,10 @@ def forward_paged_chunked(
 
 def merge_paged_chunk(cache, chunk_kv, start_positions: jnp.ndarray):
     """Fold a finished chunk's K/V into the page pool — one bulk write
-    (ops/paged_kv.paged_write_chunk)."""
-    from ..ops.paged_kv import paged_write_chunk
+    (ops/paged_kv.paged_write_chunk) — and, where the slots carry state,
+    the chunk into it (a Mamba-2 stack's for the slots the table holds a
+    sequence in, and no others: ``nemotron_h.merge_state``)."""
+    from ..ops.paged_kv import live_row_list, paged_write_chunk
 
     hk, hv, *hz = chunk_kv
     new_k, new_v = paged_write_chunk(
@@ -960,7 +968,8 @@ def merge_paged_chunk(cache, chunk_kv, start_positions: jnp.ndarray):
     if hz and isinstance(cache["state"], dict):
         from . import nemotron_h
 
-        out["state"] = nemotron_h.merge_state(cache["state"], *hz[0])
+        out["state"] = nemotron_h.merge_state(
+            cache["state"], hz[0], *live_row_list(cache["page_table"]))
     elif hz:
         from . import lfm2
 

@@ -26,9 +26,13 @@ says which:
   state handed from a row's segment to its next, starting from the row's
   seed and kept at the row's last page end and last token) and
   ``ssm_chunk_step`` (a decode step against the slots' state FROZEN for the
-  chunk, as the pool is: the step reads ``S_0`` once, adds what the chunk's
-  own earlier steps put on top from small buffers, and ``merge_state``
-  writes ``S_K`` once a chunk).
+  chunk, as the pool is: ``ssm_state_read`` reads ``S_0`` of the slots
+  that hold a sequence, a block ``(layer, slot)`` of the pool each, once a
+  step, and of no other slot; the step adds what the chunk's own earlier
+  steps put on top from buffers that the stack carries whole over the
+  layers; and ``merge_state`` reads and writes ``S_K`` of the same slots
+  once a chunk, in place. A dead slot's state is neither read nor
+  written: 23 of 32 slots in ``nemotron3-nano.chat``'s mean step).
 - ``"full_attention"``: GQA attention alone, heads of ``attn_head_dim``
   (wider than ``dim / n_heads``), no RoPE (``cfg.rope`` False:
   ``llama.rope_terms`` gives the identity), through the paged kernels every
@@ -71,6 +75,7 @@ this state.
 
 from __future__ import annotations
 
+import os
 from typing import Any, Dict, List, Tuple
 
 import jax
@@ -327,81 +332,144 @@ def ssm_segments(cfg: ModelConfig, xd, la, Bm, Cm, starts, lens, end_lens,
     return y[:W], (slot, snap)
 
 
-def ssm_chunk_step(cfg: ModelConfig, xd, la, Bm, Cm, S0, bufs, step):
+def ssm_state_read(cfg: ModelConfig, pool, layer, Cm, rows, n_live):
+    """The frozen state's part of a decode step: ``y0`` [B, H, P] float32,
+    ``y0[b] = S_0[layer, b] C[b]`` for the slots ``rows[:n_live]``
+    (``paged_kv.live_row_list``: the slots that hold a sequence) and ZEROS
+    for every other slot, whose row of the step is masked downstream as
+    every dead row's is. ``pool`` [L_m, B, H P, N] is the slots' state
+    where it lies, ``Cm`` [B, G, N] float32. The live rows' blocks of
+    layer ``layer`` are read and nothing else of the pool: by the kernel
+    ``ops/ssm_pallas.state_read`` where it takes the call (bf16 state,
+    lane-multiple widths, a TPU), else by a loop of one block a live row
+    with the same contract. No form gathers the rows into a new array."""
+    from ..ops import ssm_pallas
+
+    H, P = cfg.ssm_heads, cfg.ssm_head_dim
+    B_, N = pool.shape[1], pool.shape[3]
+    if ssm_pallas.takes(pool, cfg.ssm_groups):
+        return ssm_pallas.state_read(
+            pool, layer, Cm, rows, n_live,
+            interpret=jax.default_backend() != "tpu").reshape(B_, H, P)
+    Ch = _heads_of_groups(cfg, Cm)                         # [B, H, N]
+
+    def row(i, y0):
+        b = rows[i]
+        S = jax.lax.dynamic_slice(pool, (layer, b, 0, 0), (1, 1, H * P, N))
+        y = jnp.sum(S.reshape(H, P, N).astype(f32) * Ch[b][:, None, :],
+                    axis=-1)
+        return jax.lax.dynamic_update_slice(y0, y[None], (b, 0, 0))
+
+    return jax.lax.fori_loop(0, n_live, row, jnp.zeros((B_, H, P), f32))
+
+
+# SWARMDB_KERNCHECK=1 (obs/kerncheck.py; off in every cell): every concrete
+# call holds the state-read kernel to the batch-wide form, as ``ops/layers``
+# has the paged decode kernel held. Flag off, the plain function.
+if os.environ.get("SWARMDB_KERNCHECK", "0") == "1":
+    from ..obs.kerncheck import checked_ssm_state_read
+
+    ssm_state_read = checked_ssm_state_read(ssm_state_read)
+
+
+def ssm_chunk_step(cfg: ModelConfig, xd, la, Bm, Cm, y0, bufs, layer, step):
     """One decode step of a chunk against the slots' FROZEN state. ``xd``
     [B, H, P], ``la`` [B, H], ``Bm``, ``Cm`` [B, G, N] float32, this
-    step's; ``S0`` [B, H P, N] the state as the chunk began (stored
-    dtype); ``bufs = (hxd [B, K, H, P], hB [B, K, G, N], hcs [B, K, H])``
-    the chunk's own ``dt x``, ``B`` and running sum of ``dt a`` so far.
-    Returns ``(y [B, H, P], bufs)`` with this step written at ``step``:
-    ``y_j = exp(cs_j) S_0 C_j + sum_{i <= j} exp(cs_j - cs_i) (C_j . B_i)
-    dt_i x_i``. The state is read once, as one fused pass, and not
-    written."""
+    step's; ``y0`` [B, H, P] the state's part ``S_0 C_j`` (``ssm_state_
+    read``); ``bufs = (hxd [L_m, B, K, H, P], hB [L_m, B, K, G, N], hcs
+    [L_m, B, K, H])`` the chunk's own ``dt x``, ``B`` and running sum of
+    ``dt a`` so far, WHOLE over the Mamba-2 layers, of which this is layer
+    ``layer``. Returns ``(y [B, H, P], bufs)`` with this step written at
+    ``(layer, :, step)`` and nothing else of the buffers touched: ``y_j =
+    exp(cs_j) S_0 C_j + sum_{i <= j} exp(cs_j - cs_i) (C_j . B_i) dt_i
+    x_i``. The layer's ``[B, K, ...]`` is read through an index."""
     hxd, hB, hcs = bufs
-    B_, K, H, P = hxd.shape
-    prev = jnp.where(step > 0, jax.lax.dynamic_index_in_dim(
-        hcs, jnp.maximum(step - 1, 0), axis=1, keepdims=False), 0.0)
+    K, H = hcs.shape[2:]
+    prev = jnp.where(step > 0, jax.lax.dynamic_slice(
+        hcs, (layer, 0, jnp.maximum(step - 1, 0), 0),
+        (1, la.shape[0], 1, H))[0, :, 0], 0.0)
     cs = prev + la                                         # [B, H]
-    put = lambda h, v: jax.lax.dynamic_update_slice_in_dim(
-        h, v[:, None].astype(h.dtype), step, axis=1)
+    put = lambda h, v: jax.lax.dynamic_update_slice(
+        h, v[None, :, None].astype(h.dtype),
+        (layer, 0, step) + (0,) * (v.ndim - 1))
     hxd, hB, hcs = put(hxd, xd), put(hB, Bm), put(hcs, cs)
-    Ch = _heads_of_groups(cfg, Cm)                         # [B, H, N]
-    S = S0.reshape(B_, H, P, -1)
-    y0 = jnp.sum(S.astype(f32) * Ch[:, :, None, :], axis=-1)
+    mine = lambda h: jax.lax.dynamic_index_in_dim(h, layer, keepdims=False)
     seen = jnp.arange(K) <= step
-    w = jnp.exp(jnp.where(seen[None, :, None], cs[:, None] - hcs, -jnp.inf))
-    cb = jnp.einsum("bgn,bkgn->bkg", Cm, hB, precision=HI)
+    w = jnp.exp(jnp.where(seen[None, :, None], cs[:, None] - mine(hcs),
+                          -jnp.inf))
+    cb = jnp.einsum("bgn,bkgn->bkg", Cm, mine(hB), precision=HI)
     w = w * jnp.repeat(cb, H // cfg.ssm_groups, axis=-1)   # [B, K, H]
     y = jnp.exp(cs)[..., None] * y0 + jnp.einsum(
-        "bkh,bkhp->bhp", w, hxd, precision=HI)
+        "bkh,bkhp->bhp", w, mine(hxd), precision=HI)
     return y, (hxd, hB, hcs)
 
 
-def merge_state(state, hz, *bufs):
+def merge_state(state, bufs, rows, n_live):
     """The slots' state after a chunk, ``{"ssm", "conv"}`` over the
-    Mamba-2 layers: ``S_K = exp(cs_K) S_0 + sum_i exp(cs_K - cs_i) dt_i
-    x_i (x) B_i`` from the chunk's buffers (``bufs`` with a leading layer
-    axis), one read and one write of the state a chunk, a layer at a time
-    in place (all layers at once in float32 is 1.4 GB as published), and
-    the last ``taps - 1`` rows of the un-convolved ``xBC``
-    (``lfm2.merge_state``)."""
-    hxd, hB, hcs = bufs                                    # [L, B, K, ...]
+    Mamba-2 layers, from the chunk's buffers ``bufs = (hz, hxd, hB, hcs)``
+    (``init_chunk_state``). Only the slots ``rows[:n_live]``
+    (``paged_kv.live_row_list`` of the chunk's table) are read and
+    written, IN PLACE, every layer of them: ``S_K = exp(cs_K) S_0 + sum_i
+    exp(cs_K - cs_i) dt_i x_i (x) B_i``, float32, rounded once, and the
+    last ``taps - 1`` rows of the un-convolved ``xBC``
+    (``lfm2.merge_state``). Every other slot's state stays bit for bit
+    what it was: nobody reads it before an admission's wave writes it
+    (``ssm_segments``). The kernel ``ops/ssm_pallas.state_merge`` where it
+    takes the pool (as ``ssm_state_read``), else a loop of one live row's
+    layers a trip."""
+    from ..ops import ssm_pallas
+
+    hz, hxd, hB, hcs = bufs                                # [L, B, K, ...]
     L, B_, K, H, P = hxd.shape
     G, N = hB.shape[-2:]
+    ssm, conv = state["ssm"], state["conv"]
+    last = hcs[:, :, -1]                                   # [L, B, H]
+    # what the chunk's steps put on top, each carried to the chunk's end
+    w = hxd.reshape(L, B_, K, H * P) * jnp.repeat(
+        jnp.exp(last[:, :, None] - hcs), P, axis=-1)
+    if ssm_pallas.takes(ssm, G, K):
+        ssm = ssm_pallas.state_merge(
+            ssm, jnp.exp(last), w, hB, rows, n_live,
+            interpret=jax.default_backend() != "tpu")
+    else:
+        def row(i, ssm):
+            b = rows[i]
+            of = lambda a: jax.lax.dynamic_index_in_dim(a, b, 1,
+                                                        keepdims=False)
+            built = jnp.einsum(
+                "lkgm,lkgn->lgmn", of(w).reshape(L, K, G, (H // G) * P),
+                of(hB), precision=HI).reshape(L, H * P, N)
+            new = (jnp.repeat(jnp.exp(of(last)), P, axis=-1)[..., None]
+                   * of(ssm).astype(f32) + built)
+            return jax.lax.dynamic_update_slice(
+                ssm, new[:, None].astype(ssm.dtype), (0, b, 0, 0))
 
-    def layer(l, ssm):
-        S = jax.lax.dynamic_index_in_dim(ssm, l, keepdims=False)
-        last = hcs[l, :, -1]                               # [B, H]
-        w = jnp.exp(last[:, None] - hcs[l])[..., None] * hxd[l]
-        built = jnp.einsum("bkgm,bkgn->bgmn",
-                           w.reshape(B_, K, G, (H // G) * P), hB[l],
-                           precision=HI).reshape(B_, H * P, N)
-        new = (jnp.repeat(jnp.exp(last), P, axis=-1)[..., None]
-               * S.astype(f32) + built)
-        return jax.lax.dynamic_update_index_in_dim(
-            ssm, new.astype(ssm.dtype), l, 0)
-
-    return {"ssm": jax.lax.fori_loop(0, L, layer, state["ssm"]),
-            "conv": lfm2.merge_state(state["conv"], hz)}
+        ssm = jax.lax.fori_loop(0, n_live, row, ssm)
+    live = jnp.zeros((B_,), bool).at[rows].set(jnp.arange(B_) < n_live)
+    return {"ssm": ssm,
+            "conv": jnp.where(live[None, :, None, None],
+                              lfm2.merge_state(conv, hz), conv)}
 
 
 def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
     """The Mamba-2 mixer as a token mixer: ``token_mixer(h [B, T, D], lp,
-    ops) -> (out [B, T, D], kept)``. ``history(xBC, conv_ops) -> (earlier,
-    conv_out)`` is the forward's, as ``lfm2.conv_token_mixer`` takes it
-    (where a token's earlier ``xBC`` come from); ``recurrence(xd, la, Bm,
-    Cm, ssm_ops) -> (y [B, T, H, P], ssm_out)`` its form of the scan.
-    ``ops = (conv_ops, ssm_ops)``; ``kept = (conv_out, ssm_out)``.
-    ``aux`` is what the stack carries from layer to layer beside ``x``
-    for a recurrence that writes in place (``ssm_segments``' pools): the
-    recurrence takes and returns it, ``token_mixer(h, lp, ops, aux) ->
-    (out, kept, aux)``."""
+    layer, aux) -> (out [B, T, D], kept, aux)``. ``layer`` is the layer's
+    index among the Mamba-2 layers and all a layer is handed: what a
+    forward keeps a layer (a wave's seed rows, the slots' state, a
+    chunk's buffers) it keeps WHOLE over the layers and reads at
+    ``layer``. ``aux`` is what the stack carries from layer to layer
+    beside ``x`` for the parts that are written in place
+    (``ssm_segments``' pools, a chunk's buffers). ``history(xBC, layer,
+    aux) -> (earlier, conv_out, aux)`` is the forward's, where a token's
+    earlier ``xBC`` come from (``lfm2.conv_token_mixer``'s, with the
+    carry); ``recurrence(xd, la, Bm, Cm, layer, aux) -> (y [B, T, H, P],
+    ssm_out, aux)`` its form of the scan. ``kept = (conv_out,
+    ssm_out)``, stacked over the layers by the stack."""
     H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
                   cfg.ssm_state)
     Di, Cd = cfg.ssm_inner, cfg.ssm_conv_dim
 
-    def token_mixer(h, lp, ops, aux):
-        conv_ops, ssm_ops = ops
+    def token_mixer(h, lp, layer, aux):
         B_, T = h.shape[0], h.shape[1]
         zxd = jnp.einsum("btd,de->bte", h, lp["in_proj"],
                          preferred_element_type=f32)
@@ -410,7 +478,7 @@ def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
         # state holds, and a token reads the same rows from its call and
         # from a state
         xbc = zxd[..., Di:Di + Cd].astype(h.dtype)
-        earlier, conv_out = history(xbc, conv_ops)
+        earlier, conv_out, aux = history(xbc, layer, aux)
         w = lp["conv_w"].astype(f32)          # [taps, Cd]; w[-1] takes t
         c = w[-1] * xbc
         for i, e in enumerate(earlier):
@@ -423,7 +491,7 @@ def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
         Cm = c[..., Di + G * N:].reshape(B_, T, G, N)
         dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))   # [B, T, H]
         la = -jnp.exp(lp["A_log"].astype(f32)) * dt
-        y, ssm_out, aux = recurrence(x * dt[..., None], la, Bm, Cm, ssm_ops,
+        y, ssm_out, aux = recurrence(x * dt[..., None], la, Bm, Cm, layer,
                                      aux)
         y = y + lp["D"].astype(f32)[:, None] * x
         y = (y.reshape(B_, T, Di) * jax.nn.silu(z)).reshape(
@@ -528,11 +596,11 @@ def fitted_bias(params: Params, cfg: ModelConfig,
 
     x = params["embed"][tokens]
     cos, sin = llama.rope_terms(cfg, positions)
-    mamba_tm, mamba_ops, aux = whole_mixers(cfg)
+    mamba_tm, aux = whole_mixers(cfg)
     out = run_layers(
         params, cfg, x, llama.attention_token_mixer(cfg, cos, sin, mixer),
         llama.init_kv_cache(cfg, *tokens.shape, x.dtype), mamba_tm,
-        mamba_ops, aux=aux, ffn=fitting)
+        aux=aux, ffn=fitting)
     return out[3][0][0, 0]
 
 
@@ -558,16 +626,20 @@ def balance_expert_bias(params: Params, cfg: ModelConfig,
 
 
 def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, attn_tm,
-               attn_ops, mamba_tm, mamba_ops, live=None, aux=None, ffn=None):
+               attn_ops, mamba_tm, live=None, aux=None, ffn=None):
     """``x`` through every layer. ``attn_tm`` and ``mamba_tm`` are the
     forward's token mixers (``llama.attention_token_mixer``,
-    ``mamba_token_mixer``), ``attn_ops`` and ``mamba_ops`` what each takes
-    a layer, with a leading axis over the layers of its kind. Returns
+    ``mamba_token_mixer``). ``attn_ops`` is what attention takes a layer,
+    with a leading axis over the layers that attend: a segment's share is
+    sliced out and scanned. A Mamba-2 layer is handed its INDEX among the
+    Mamba-2 layers and nothing sliced: ``aux`` is carried through every
+    layer, scanned or not, beside ``x``, and the mixer reads and writes
+    its layer's part of it in place (``mamba_token_mixer``), so nothing
+    of a Mamba-2 layer's is sliced in, stacked or concatenated a call
+    but what the mixer itself keeps (a wave's few conv rows). Returns
     ``(x, attention outs, (mamba outs, aux), (routing [B, T, L_routed,
-    k],))``, the outs stacked over their layers; ``aux`` is carried
-    through the layers beside ``x`` for the Mamba-2 mixer
-    (``mamba_token_mixer``); ``ffn`` takes ``moe_ffn``'s place
-    (``balance_expert_bias``)."""
+    k],))``, the outs stacked over their layers; ``ffn`` takes
+    ``moe_ffn``'s place (``balance_expert_bias``)."""
     ffn = ffn or moe_ffn(cfg, live)
     a0 = m0 = 0
     outs_a, outs_m, outs_r = [], [], []
@@ -587,9 +659,10 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, attn_tm,
                 lambda a: a[lo:lo + n * per].reshape((n, per) + a.shape[1:]),
                 ops)
 
-        def body(carry, scanned, pattern=pattern, experts=experts):
+        def body(carry, scanned, pattern=pattern, experts=experts, m0=m0,
+                 nm=nm):
             x, aux = carry
-            lps, a_r, m_r, repeat = scanned
+            lps, a_r, repeat = scanned
             ja = jm = 0
             a_out, m_out, r_out = [], [], []
             for kind, lp, big in zip(pattern, lps, experts):
@@ -598,8 +671,7 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, attn_tm,
                     y, routing = ffn(h, {**lp, **big}, repeat)
                     r_out.append(routing)
                 elif kind == "mamba":
-                    y, out, aux = mamba_tm(
-                        h, lp, jax.tree.map(lambda a, j=jm: a[j], m_r), aux)
+                    y, out, aux = mamba_tm(h, lp, m0 + repeat * nm + jm, aux)
                     m_out.append(out)
                     jm += 1
                 else:
@@ -612,7 +684,6 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, attn_tm,
                               lfm2._stack(r_out))
 
         scanned = (seg, of_segment(attn_ops, a0, na),
-                   of_segment(mamba_ops, m0, nm),
                    jnp.arange(n, dtype=jnp.int32))
         if n == 1:
             (x, aux), outs = body((x, aux),
@@ -635,32 +706,39 @@ def run_layers(params: Params, cfg: ModelConfig, x: jnp.ndarray, attn_tm,
 # ------------------------------------------------- what each forward brings
 
 
+def _carried(history, ops_at):
+    """An ``lfm2`` history, which keeps nothing in the stack's carry, as
+    ``mamba_token_mixer`` calls one; ``ops_at(layer)`` is what it takes a
+    layer."""
+    return lambda z, layer, aux: (*history(z, ops_at(layer)), aux)
+
+
 def whole_mixers(cfg: ModelConfig):
-    """``llama.forward``'s ``(Mamba-2 token mixer, its ops a layer, aux)``
+    """``llama.forward``'s ``(Mamba-2 token mixer, aux)``
     (``llama.run_stack``'s ``mamba``): a whole sequence from position 0,
     nothing before it, the plain recurrence."""
-    def recurrence(xd, la, Bm, Cm, _ops, aux):
+    def recurrence(xd, la, Bm, Cm, _layer, aux):
         S0 = jnp.zeros((xd.shape[0], cfg.ssm_heads, cfg.ssm_head_dim,
                         cfg.ssm_state), f32)
         return (*ssm_recurrence(cfg, xd, la, Bm, Cm, S0), aux)
 
-    return (mamba_token_mixer(cfg, lfm2.history_whole(cfg.conv_taps - 1),
-                              recurrence),
-            (jnp.zeros((cfg.n_ssm_layers,), jnp.int32),) * 2, None)
+    history = _carried(lfm2.history_whole(cfg.conv_taps - 1),
+                       lambda _layer: None)
+    return mamba_token_mixer(cfg, history, recurrence), None
 
 
 def stream_mixers(cfg: ModelConfig, seed, tok_row, tok_pos, starts, lens,
                   live, page_size: int):
-    """The Mamba-2 mixer of a ragged wave, its ops and what the stack
-    carries for it. ``seed = {"conv": [L_m, R, taps - 1, conv dim], "ssm":
-    (src, slots, dst, slot pool, snapshot pool)}``: each row's conv rows
-    before its first token of the call, and for the large part of the
-    state where to read it from and write it to (``ssm_segments``; the
-    pools are carried through the layers and written in place). Keeps, a
-    layer, the conv rows ``(row, end)`` after each row's last token and
-    after its last page end of this call. Returns ``((token mixer, ops,
-    pools), end_lens [R])``: ``llama.run_stack``'s ``mamba`` and what the
-    forward reports."""
+    """The Mamba-2 mixer of a ragged wave and what the stack carries for
+    it. ``seed = {"conv": [L_m, R, taps - 1, conv dim], "ssm": (src,
+    slots, dst, slot pool, snapshot pool)}``: each row's conv rows before
+    its first token of the call (read at the layer's index), and for the
+    large part of the state where to read it from and write it to
+    (``ssm_segments``; the pools are carried through the layers and
+    written in place). Keeps, a layer, the conv rows ``(row, end)`` after
+    each row's last token and after its last page end of this call.
+    Returns ``((token mixer, pools), end_lens [R])``:
+    ``llama.run_stack``'s ``mamba`` and what the forward reports."""
     R = starts.shape[0]
     row = jnp.clip(tok_row, 0, R - 1)
     ends = live & ((tok_pos + 1) % page_size == 0)
@@ -669,8 +747,10 @@ def stream_mixers(cfg: ModelConfig, seed, tok_row, tok_pos, starts, lens,
     end_lens = jnp.zeros((R + 1,), jnp.int32).at[
         jnp.where(ends, row, R)].max(o + 1)[:R]
     at = starts + jnp.maximum(end_lens - 1, 0)
-    history = lfm2.history_stream(cfg.conv_taps - 1, tok_row, starts, lens,
-                                  at)
+    history = _carried(
+        lfm2.history_stream(cfg.conv_taps - 1, tok_row, starts, lens, at),
+        lambda layer: jax.lax.dynamic_index_in_dim(seed["conv"], layer,
+                                                   keepdims=False))
     src, slots, dst, *pools = seed["ssm"]
 
     def recurrence(xd, la, Bm, Cm, layer, pools):
@@ -679,32 +759,55 @@ def stream_mixers(cfg: ModelConfig, seed, tok_row, tok_pos, starts, lens,
                                 pools)
         return y[None], None, pools
 
-    return ((mamba_token_mixer(cfg, history, recurrence),
-             (seed["conv"], jnp.arange(cfg.n_ssm_layers, dtype=jnp.int32)),
-             tuple(pools)), end_lens)
+    return ((mamba_token_mixer(cfg, history, recurrence), tuple(pools)),
+            end_lens)
 
 
-def chunk_mixers(cfg: ModelConfig, state, bufs, step):
+def chunk_mixers(cfg: ModelConfig, state, bufs, step, live_rows):
     """One decode step of a chunk as ``llama.run_stack``'s ``mamba``:
-    ``state`` the slots' (frozen; a layer's ``S_0`` is read where the step
-    needs it, never sliced out over the layers), ``bufs = (hz, hxd, hB,
-    hcs)`` the chunk's own so far, each with a leading layer axis. Keeps
-    the buffers with this step written."""
-    hz, *ssm_bufs = bufs
-    history = lfm2.history_chunk(cfg.conv_taps - 1, step)
+    ``(token mixer, bufs)``. ``state`` is the slots' ``{"ssm", "conv"}``,
+    FROZEN for the chunk and left where it lies: of ``state["ssm"]`` a
+    layer reads the blocks ``(layer, b)`` of the live slots ``b`` alone
+    (``live_rows = (rows, n_live)``, ``paged_kv.live_row_list`` of the
+    step's table; ``ssm_state_read``), of ``state["conv"]`` its own three
+    rows a slot. ``bufs = (hz, hxd, hB, hcs)`` are the chunk's own so far
+    (``init_chunk_state``), each WHOLE over the Mamba-2 layers: they are
+    the stack's carry, a layer writes this step's row at ``(layer, :,
+    step)`` and reads its ``[B, K, ...]`` through an index, and nothing
+    of them is sliced in or stacked out a step. A dead slot's row of the
+    step is computed from zeros in the state's place."""
+    n_state = cfg.conv_taps - 1
+    rows, n_live = live_rows
 
-    def recurrence(xd, la, Bm, Cm, ops, aux):
-        layer, layer_bufs = ops
-        S0 = jax.lax.dynamic_index_in_dim(state["ssm"], layer,
-                                          keepdims=False)
-        y, layer_bufs = ssm_chunk_step(cfg, xd[:, 0], la[:, 0], Bm[:, 0],
-                                       Cm[:, 0], S0, layer_bufs, step)
-        return y[:, None], layer_bufs, aux
+    def history(z, layer, bufs):
+        hz = bufs[0]
+        B_, _, Cd = z.shape
 
-    return (mamba_token_mixer(cfg, history, recurrence),
-            ((state["conv"], hz),
-             (jnp.arange(cfg.n_ssm_layers, dtype=jnp.int32),
-              tuple(ssm_bufs))), None)
+        def tap(i):
+            # the un-convolved row ``i + 1`` positions back: the chunk's
+            # own where it reaches that far, else the slot's state's
+            p = step - 1 - i
+            own = jax.lax.dynamic_slice(
+                hz, (layer, 0, jnp.maximum(p, 0), 0), (1, B_, 1, Cd))[0]
+            old = jax.lax.dynamic_slice(
+                state["conv"], (layer, 0, jnp.clip(n_state + p, 0,
+                                                   n_state - 1), 0),
+                (1, B_, 1, Cd))[0]
+            return jnp.where(p >= 0, own.astype(z.dtype),
+                             old.astype(z.dtype))
+
+        earlier = [tap(i) for i in range(n_state)]
+        hz = jax.lax.dynamic_update_slice(hz, z[None].astype(hz.dtype),
+                                          (layer, 0, step, 0))
+        return earlier, None, (hz, *bufs[1:])
+
+    def recurrence(xd, la, Bm, Cm, layer, bufs):
+        y0 = ssm_state_read(cfg, state["ssm"], layer, Cm[:, 0], rows, n_live)
+        y, ssm_bufs = ssm_chunk_step(cfg, xd[:, 0], la[:, 0], Bm[:, 0],
+                                     Cm[:, 0], y0, bufs[1:], layer, step)
+        return y[:, None], None, (bufs[0], *ssm_bufs)
+
+    return mamba_token_mixer(cfg, history, recurrence), tuple(bufs)
 
 
 def init_chunk_state(cfg: ModelConfig, batch: int, chunk: int, dtype):

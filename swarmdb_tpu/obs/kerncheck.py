@@ -92,7 +92,7 @@ __all__ = ["enabled", "registry", "KernCheckRegistry", "ShadowRef",
            "differential_ragged_prefill", "differential_paged_decode",
            "checked_ragged_prefill_dispatch",
            "checked_paged_attention_dispatch_chunked",
-           "checked_paged_write_ragged"]
+           "checked_ssm_state_read", "checked_paged_write_ragged"]
 
 # float canary pre-poisoning shadow outputs: exactly representable in
 # bf16/f32 and far outside attention's output range (softmax-weighted
@@ -1084,6 +1084,50 @@ def checked_paged_attention_dispatch_chunked(fn: Callable) -> Callable:
                                  parity_tol())
         except Exception:
             logger.exception("kerncheck paged-decode check failed")
+        return out
+
+    return wrapper
+
+
+def checked_ssm_state_read(fn: Callable) -> Callable:
+    """Wrap ``models.nemotron_h.ssm_state_read``; flag off returns ``fn``
+    itself. On concrete operands the kernel ``ops.ssm_pallas.state_read``
+    (the dispatched result where it took the call, else run here,
+    interpreted, on a bf16 copy of the pool) is held to the batch-wide
+    form ``sum(S C)``: the slots ``rows[:n_live]`` within
+    :func:`parity_tol`, exact zeros on every other slot."""
+    if not enabled():
+        return fn
+
+    @functools.wraps(fn)
+    def wrapper(cfg, pool, layer, Cm, rows, n_live):
+        import jax.numpy as jnp
+
+        from ..ops import ssm_pallas
+
+        out = fn(cfg, pool, layer, Cm, rows, n_live)
+        if (_any_tracer(pool, layer, Cm, rows, n_live)
+                or pool.shape[1] > _max_shadow_width()):
+            return out
+        try:
+            registry().note_check("dispatch.ssm-state-read")
+            B, H = pool.shape[1], cfg.ssm_heads
+            S = pool.astype(jnp.bfloat16)
+            got = out
+            if not ssm_pallas.takes(pool, cfg.ssm_groups):
+                got = ssm_pallas.state_read(S, layer, Cm, rows, n_live,
+                                            interpret=True)
+            per = H // cfg.ssm_groups
+            want = jnp.sum(S[layer].astype(jnp.float32).reshape(
+                B, H, -1, S.shape[-1]) * jnp.repeat(Cm, per, axis=1)[
+                    :, :, None, :], axis=-1)
+            walked = np.zeros(B, bool)
+            walked[np.asarray(rows)[:int(n_live)]] = True
+            _hold_to_gather_form("ssm_state_read",
+                                 np.asarray(got).reshape(want.shape), want,
+                                 walked, True, parity_tol("bfloat16"))
+        except Exception:
+            logger.exception("kerncheck ssm-state-read check failed")
         return out
 
     return wrapper

@@ -192,6 +192,7 @@ def test_factories_return_plain_functions_when_off(monkeypatch):
     assert kerncheck.checked_ragged_prefill_dispatch(fn) is fn
     assert kerncheck.checked_paged_attention_dispatch_chunked(fn) is fn
     assert kerncheck.checked_paged_write_ragged(fn) is fn
+    assert kerncheck.checked_ssm_state_read(fn) is fn
 
 
 def test_dispatch_module_binding_matches_flag():
@@ -199,9 +200,11 @@ def test_dispatch_module_binding_matches_flag():
     checked factories exactly when the env flag was set at import: the
     tier-1 run sees the plain functions, the CI kerncheck job sees the
     wrappers."""
+    from swarmdb_tpu.models import nemotron_h
     from swarmdb_tpu.ops import layers, paged_kv
 
     wrapped = os.environ.get("SWARMDB_KERNCHECK", "0") == "1"
+    assert hasattr(nemotron_h.ssm_state_read, "__wrapped__") == wrapped
     assert hasattr(layers.ragged_prefill_dispatch, "__wrapped__") \
         == wrapped
     assert hasattr(layers.paged_attention_dispatch_chunked,
@@ -522,6 +525,39 @@ def test_checked_chunked_dispatch_checks_the_kernel_where_it_gathered(
     (v,) = kerncheck_on.registry().violations()
     assert v["kind"] == "parity"
     assert v["kernel"] == "paged_decode_gqa_attention_chunked"
+
+
+@pytest.mark.parametrize("planted,kind", [
+    (None, None), ("dead-row", "dead-row"), ("live-row", "parity")])
+def test_checked_ssm_state_read(kerncheck_on, monkeypatch, planted, kind):
+    """The Mamba-2 state-read kernel (interpreted here, on a bf16 copy of
+    the pool) held to the batch-wide form on every concrete call: clean
+    as it is; a kernel that writes a slot off the list, or a live one
+    wrong, is named."""
+    from swarmdb_tpu.models import nemotron_h
+    from swarmdb_tpu.models.configs import get_config
+    from swarmdb_tpu.ops import ssm_pallas
+
+    cfg = get_config("tiny-nemotron", ssm_heads=4, ssm_head_dim=64,
+                     ssm_groups=2, ssm_state=128)
+    rng = np.random.default_rng(5)
+    pool = jnp.asarray(rng.standard_normal((2, 4, 256, 128)), jnp.float32)
+    Cm = jnp.asarray(rng.standard_normal((4, 2, 128)), jnp.float32)
+    rows, n_live = jnp.asarray([3, 1, 0, 2], jnp.int32), jnp.int32(2)
+    if planted:
+        real = ssm_pallas.state_read
+        row = 0 if planted == "dead-row" else 3
+        monkeypatch.setattr(
+            ssm_pallas, "state_read",
+            lambda *a, **kw: real(*a, **kw).at[row, 0].add(0.5))
+    f = kerncheck_on.checked_ssm_state_read(
+        _unwrapped(nemotron_h.ssm_state_read))
+    f(cfg, pool, jnp.int32(1), Cm, rows, n_live)
+    assert kerncheck_on.registry().report()["checks"][
+        "dispatch.ssm-state-read"] == 1
+    found = kerncheck_on.registry().violations()
+    assert [v["kind"] for v in found] == ([kind] if kind else [])
+    assert all(v["kernel"] == "ssm_state_read" for v in found)
 
 
 def test_checked_dispatch_catches_wrong_output(kerncheck_on):

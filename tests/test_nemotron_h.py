@@ -123,19 +123,27 @@ def test_the_one_token_step_equals_the_recurrence_and_merges_its_state():
     Bs, Cs = _draw(8, B_, K, G, N), _draw(9, B_, K, G, N)
     want, SK = nemotron_h.ssm_recurrence(CFG, xs, las, Bs, Cs,
                                          S0.reshape(B_, H, P, N))
-    bufs = (jnp.zeros((B_, K, H, P)), jnp.zeros((B_, K, G, N)),
-            jnp.zeros((B_, K, H)))
+    # the second of two layers: the other's buffers and state stay put
+    pool = jnp.stack([_draw(16, B_, H * P, N), S0])
+    bufs = (jnp.zeros((2, B_, K, H, P)), jnp.zeros((2, B_, K, G, N)),
+            jnp.zeros((2, B_, K, H)))
+    every = (jnp.arange(B_, dtype=jnp.int32), jnp.int32(B_))
     for j in range(K):
+        y0 = nemotron_h.ssm_state_read(CFG, pool, jnp.int32(1), Cs[:, j],
+                                       *every)
         y, bufs = nemotron_h.ssm_chunk_step(
-            CFG, xs[:, j], las[:, j], Bs[:, j], Cs[:, j], S0, bufs,
-            jnp.int32(j))
+            CFG, xs[:, j], las[:, j], Bs[:, j], Cs[:, j], y0, bufs,
+            jnp.int32(1), jnp.int32(j))
         np.testing.assert_allclose(y, want[:, j], atol=2e-5)
-    state = {"ssm": S0[None], "conv": jnp.zeros((1, B_, 3, CFG.ssm_conv_dim))}
+    assert not any(np.asarray(b[0]).any() for b in bufs)
+    state = {"ssm": pool,
+             "conv": jnp.zeros((2, B_, 3, CFG.ssm_conv_dim))}
+    # layer 0's buffers are zeros, its ``cs_K`` 0: its state stands
     merged = nemotron_h.merge_state(
-        state, jnp.zeros((1, B_, K, CFG.ssm_conv_dim)),
-        *(b[None] for b in bufs))
-    np.testing.assert_allclose(merged["ssm"][0], SK.reshape(B_, H * P, N),
+        state, (jnp.zeros((2, B_, K, CFG.ssm_conv_dim)), *bufs), *every)
+    np.testing.assert_allclose(merged["ssm"][1], SK.reshape(B_, H * P, N),
                                atol=2e-5)
+    np.testing.assert_array_equal(merged["ssm"][0], pool[0])
 
 
 def test_a_steps_decay_lies_strictly_inside_zero_and_one(params):
@@ -614,3 +622,166 @@ def test_the_configuration_refuses_what_it_cannot_be():
     with pytest.raises(ValueError, match="ssm_heads"):
         get_config("tiny-nemotron", ssm_heads=0)
     assert isinstance(CFG, ModelConfig) and CFG.head_dim == 32 != 64 // 4
+
+
+# ------------------- a decode chunk moves its live rows' state and no other
+
+
+LANE = get_config("tiny-nemotron", ssm_heads=4, ssm_head_dim=64,
+                  ssm_groups=2, ssm_state=128)        # H P = 256, N = 128
+
+
+def _scattered(n_live, B_=32):
+    """A page table of ``B_`` slots of which ``n_live`` hold a sequence,
+    scattered, and the list ``live_row_list`` makes of it."""
+    from swarmdb_tpu.ops.paged_kv import live_row_list
+
+    live = np.zeros(B_, bool)
+    live[np.random.default_rng(n_live).permutation(B_)[:n_live]] = True
+    table = jnp.asarray(np.where(live[:, None], 1 + np.arange(B_)[:, None],
+                                 0) * np.ones((1, 3), int), jnp.int32)
+    return live, live_row_list(table)
+
+
+@pytest.mark.parametrize("n_live", [0, 1, 9, 32])
+def test_the_live_row_read_is_the_steps_read_on_the_live_rows(monkeypatch,
+                                                              n_live):
+    """The kernel (interpreted) and the loop against the batch-wide form
+    ``sum(S * C)``: equal on the live slots, zeros on the others."""
+    from swarmdb_tpu.ops import ssm_pallas
+
+    Hh, Pp, Gg, Nn = (LANE.ssm_heads, LANE.ssm_head_dim, LANE.ssm_groups,
+                      LANE.ssm_state)
+    B_ = 32
+    pool = _draw(20, 3, B_, Hh * Pp, Nn).astype(jnp.bfloat16)
+    Cm = _draw(21, B_, Gg, Nn)
+    live, rows = _scattered(n_live)
+    Ch = jnp.repeat(Cm, Hh // Gg, axis=1)
+    want = np.asarray(jnp.sum(
+        pool[2].reshape(B_, Hh, Pp, Nn).astype(F32) * Ch[:, :, None, :],
+        axis=-1))
+    read = jax.jit(lambda *a: nemotron_h.ssm_state_read(LANE, *a))
+    assert not ssm_pallas.takes(pool, Gg)             # the CPU: the loop
+    loop = np.asarray(read(pool, jnp.int32(2), Cm, *rows))
+    monkeypatch.setattr(ssm_pallas, "_on_tpu", lambda: True)
+    assert ssm_pallas.takes(pool, Gg) and not ssm_pallas.takes(
+        pool.astype(F32), Gg) and not ssm_pallas.takes(pool, 4)
+    jax.clear_caches()
+    kernel = np.asarray(jax.jit(lambda *a: nemotron_h.ssm_state_read(
+        LANE, *a))(pool, jnp.int32(2), Cm, *rows))
+    for got in (loop, kernel):
+        np.testing.assert_allclose(got[live], want[live], rtol=2e-6,
+                                   atol=2e-5)
+        assert not got[~live].any()
+
+
+@pytest.mark.parametrize("form", ["loop", "kernel"])
+@pytest.mark.parametrize("n_live", [0, 1, 3, 6])
+def test_a_chunks_merge_touches_the_live_slots_alone(monkeypatch, n_live,
+                                                     form):
+    """Every dead slot's state bit for bit what it was, the live ones
+    ``exp(cs_K) S_0 + built`` as the plain recurrence leaves them: by the
+    loop in float32 at the tiny widths, by the kernel (interpreted) in
+    bfloat16 at lane-multiple ones, to a bf16 rounding."""
+    from swarmdb_tpu.ops import ssm_pallas
+
+    cfg, dt, tol = CFG, F32, dict(atol=2e-5)
+    if form == "kernel":
+        cfg, dt, tol = LANE, jnp.bfloat16, dict(rtol=2 ** -7, atol=1e-6)
+        monkeypatch.setattr(ssm_pallas, "_on_tpu", lambda: True)
+    Hh, Pp, Gg, Nn = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state)
+    B_, K, L = 6, 4, 2
+    live, rows = _scattered(n_live, B_)
+    ssm = _draw(30, L, B_, Hh * Pp, Nn).astype(dt)
+    conv = _draw(31, L, B_, 3, cfg.ssm_conv_dim).astype(dt)
+    hz = _draw(32, L, B_, K, cfg.ssm_conv_dim).astype(dt)
+    xd, la = _draw(33, L, B_, K, Hh, Pp), -jnp.abs(_draw(34, L, B_, K, Hh))
+    hB = _draw(35, L, B_, K, Gg, Nn)
+    assert ssm_pallas.takes(ssm, Gg, K) == (form == "kernel")
+    merged = jax.jit(nemotron_h.merge_state)(
+        {"ssm": ssm, "conv": conv}, (hz, xd, hB, jnp.cumsum(la, axis=2)),
+        *rows)
+    dead = ~live
+    np.testing.assert_array_equal(merged["ssm"][:, dead], ssm[:, dead])
+    np.testing.assert_array_equal(merged["conv"][:, dead], conv[:, dead])
+    np.testing.assert_array_equal(merged["conv"][:, live], hz[:, live, -3:])
+    for l in range(L):
+        _y, SK = nemotron_h.ssm_recurrence(
+            cfg, xd[l], la[l], hB[l], jnp.zeros_like(hB[l]),
+            ssm[l].astype(F32).reshape(B_, Hh, Pp, Nn))
+        np.testing.assert_allclose(
+            merged["ssm"][l][live].astype(F32),
+            SK.reshape(B_, Hh * Pp, Nn)[live], **tol)
+
+
+def test_a_chunk_with_dead_slots_beside_it_matches_the_whole_forward(
+        params, tokens):
+    """``test_a_split_prompt_and_chunked_decode_carry_the_state``'s decode
+    with a second sequence two slots on and two slots empty: each live
+    slot's logits are its own whole forward's, the empty slots' state is
+    untouched by two chunks."""
+    s = Served(params)
+    a, b = tokens[1][:60], tokens[2][:52]
+    s.wave([(1, a[:44], 0, [7, 8, 9, 10, 11], 0, 0),
+            (3, b[:36], 0, [12, 13, 14, 15], 0, 0)], 128)
+    before = jax.tree.map(np.asarray, s.cache["state"])
+    feed = np.zeros((16, SLOTS), np.int32)
+    feed[:, 1], feed[:, 3] = a[44:60], b[36:52]
+    pos0 = np.array([0, 44, 0, 36], np.int32)
+    out = np.concatenate([s.chunk(feed[:8], pos0),
+                          s.chunk(feed[8:], pos0 + 8 * (pos0 > 0))])
+    np.testing.assert_allclose(out[:, 1], whole(params, a)[44:60], **TOL)
+    np.testing.assert_allclose(out[:, 3], whole(params, b)[36:52], **TOL)
+    after = jax.tree.map(np.asarray, s.cache["state"])
+    for part in ("ssm", "conv"):
+        np.testing.assert_array_equal(after[part][:, [0, 2]],
+                                      before[part][:, [0, 2]])
+        assert (after[part][:, [1, 3]] != before[part][:, [1, 3]]).any()
+
+
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _eqns(inner)
+
+
+def test_the_decode_step_carries_the_chunks_buffers_whole(params):
+    """Every scanned segment of the decode step has the four buffers in
+    its CARRY, whole over the Mamba-2 layers, none among what it slices a
+    layer or stacks out, and nothing concatenates or slices them."""
+    cfg = get_config("tiny-nemotron", state_snapshots=SNAPS)
+    cache = llama.init_paged_cache(cfg, SLOTS, MAX_SEQ, PAGES, PS, F32)
+    chunk_kv = llama.init_chunk_kv(CFG, SLOTS, 8, F32)
+    whole_shapes = {b.shape for b in chunk_kv[2]}
+    assert len(whole_shapes) == 4
+
+    def of_a_buffer(shape):
+        # a buffer or any run of its layers: its [B, K, ...] behind
+        return any(tuple(shape[-len(s) + 1:]) == s[1:] for s in whole_shapes)
+
+    jaxpr = jax.make_jaxpr(
+        lambda *a: llama.forward_paged_chunked(params, CFG, *a))(
+        jnp.zeros((SLOTS, 1), jnp.int32), jnp.zeros((SLOTS, 1), jnp.int32),
+        cache, chunk_kv, jnp.int32(0)).jaxpr
+    # the layer scans: the ones that carry ``x``
+    scans = [e for e in _eqns(jaxpr) if e.primitive.name == "scan"
+             and (SLOTS, 1, CFG.dim) in {v.aval.shape for v in e.invars}]
+    assert scans, "the tiny stack has a repeated segment"
+    for e in scans:
+        nc, ncar = e.params["num_consts"], e.params["num_carry"]
+        shapes = [v.aval.shape for v in e.invars]
+        carry, xs = shapes[nc:nc + ncar], shapes[nc + ncar:]
+        assert whole_shapes <= set(carry)
+        ys = [v.aval.shape for v in e.outvars[ncar:]]
+        assert not any(map(of_a_buffer, (*xs, *ys)))
+    for e in _eqns(jaxpr):
+        if e.primitive.name in ("concatenate", "slice"):
+            assert not any(of_a_buffer(v.aval.shape)
+                           for v in (*e.invars, *e.outvars)
+                           if hasattr(v.aval, "shape")), e

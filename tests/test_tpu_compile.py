@@ -23,7 +23,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from swarmdb_tpu.ops import attention_pallas as ap
-from swarmdb_tpu.ops import moe_pallas
+from swarmdb_tpu.ops import moe_pallas, ssm_pallas
 
 HQ, HKV, D, PS, B, SPAN = 32, 8, 128, 16, 8, 1024
 MAXP = SPAN // PS
@@ -178,6 +178,26 @@ def test_dense_chunked_decode_compiles(one_chip):
     lane = ((B, SPAN, HKV, D), BF)
     _compile(one_chip, ap.decode_gqa_attention_chunked,
              Q, lane, lane, CHUNK, CHUNK, ROW, STEP, tile=256)
+
+
+# nemotron3-nano.chat: 23 Mamba-2 layers, 32 slots, 64 heads of 64 in 8
+# groups, a state of 128, a chunk of 8
+SSM_POOL = ((23, 32, 4096, 128), BF)
+
+
+def test_the_ssm_state_read_compiles_at_the_published_widths(one_chip):
+    _compile(one_chip, ssm_pallas.state_read, SSM_POOL, STEP,
+             ((32, 8, 128), F32), ((32,), I32), STEP)
+
+
+def test_the_ssm_state_merge_compiles_in_place_at_the_published_widths(
+        one_chip):
+    """And plans no copy of the pool: its output is its operand."""
+    compiled = _compile(
+        one_chip, ssm_pallas.state_merge, SSM_POOL, ((23, 32, 64), F32),
+        ((23, 32, 8, 4096), F32), ((23, 32, 8, 8, 128), F32), ((32,), I32),
+        STEP)
+    assert compiled.memory_analysis().temp_size_in_bytes < 23 * 2 ** 20
 
 
 def test_dense_decode_compiles_to_its_span_limit(one_chip):
