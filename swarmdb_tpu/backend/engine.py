@@ -63,6 +63,7 @@ from jax.experimental import io_callback
 
 from ..models.lfm2 import seed_state
 from ..models.mixtral import routing_dropped, routing_experts
+from ..models.nemotron_h import wave_segments
 from ..obs import TRACER, FlightRecorder
 from ..obs.metrics import (HIST_DECODE_CHUNK, HIST_QUEUE_WAIT, HIST_TTFT)
 from ..obs.profiler import (NullLane, platform_peaks,
@@ -735,8 +736,10 @@ class Engine:
         # where each row resumes from and where the state at its last
         # page end goes; a prefix hit resumes behind the deepest hit page
         # that still owns one and the rest is forgone. Such rows do not
-        # ride waves: a one-token row would cost a segment of the wave's
-        # scan a layer, a decode step's worth
+        # ride waves yet: a one-token row cost a whole segment of the
+        # wave's scan a layer while that was XLA's loop (73 us, 1.7 ms a
+        # rider a wave); under ``ssm_pallas.ssm_wave_scan`` it is 13 us,
+        # 0.3 ms a wave (scripts/race_ssm_wave.py; ROADMAP Reach B4)
         self._snapshots = 0
         # slot -> the snapshot slot its admission resumes from (plan time)
         self._snap_src: Dict[int, int] = {}
@@ -745,11 +748,16 @@ class Engine:
             # ``ssm_state_rows_walked`` / ``ssm_state_rows_held``: of the
             # slots' state, the share a decode chunk reads a step and
             # rewrites at its end (the live slots', ``nemotron_h.
-            # chunk_mixers`` and ``merge_state``)
+            # chunk_mixers`` and ``merge_state``). ``ssm_wave_segments`` /
+            # ``ssm_wave_segment_tokens``: the live segments a wave's scan
+            # walks a layer and the tokens in them, from the wave's plan
+            # (``nemotron_h.wave_segments``): segments a wave, and the
+            # live share of a segment's ``SCAN_CHUNK``
             for name in ("ssm_snapshots_taken", "ssm_snapshots_evicted",
                          "ssm_snapshot_slots", "ssm_snapshot_slots_live",
                          "ssm_state_tokens_resumed", "ssm_state_rows_walked",
-                         "ssm_state_rows_held"):
+                         "ssm_state_rows_held", "ssm_wave_segments",
+                         "ssm_wave_segment_tokens"):
                 self.metrics.counters[name].inc(0)
         # latent pages (models/deepseek.py): the pool under ``"k"`` is one
         # of rows ``[L, num_pages, ps, Wd]`` with no heads axis, and
@@ -4710,7 +4718,7 @@ class Engine:
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
         snap_dst = self._take_snapshots(batch)
-        packed_n = padding_n = 0
+        packed_n = padding_n = scan_segments = 0
         # routed: slot -> the parts of its suffix, in stream order
         stream_parts: Dict[int, List[RoutingRows]] = {}
         riding: List[int] = []     # the slots that ride the last wave
@@ -4784,6 +4792,7 @@ class Engine:
                     dst, end = snap_dst.get(slot_id, (0, 0))
                     if abs0 < end <= abs0 + take:
                         state_dst[r] = dst
+                    scan_segments += wave_segments(abs0, take, ps)
                 if consumed + take == len(suffix):
                     scatter[r] = slot_id     # final chunk: sample here
                 it[3] = consumed + take
@@ -4843,6 +4852,10 @@ class Engine:
         self.metrics.counters["prefill_packed_tokens"].inc(packed_n)
         self.metrics.counters["prefill_padding_tokens"].inc(padding_n)
         self.metrics.counters["wave_rider_tokens"].inc(len(riding))
+        if snap_dst is not None:
+            # such an engine takes no riders: every token is an admitted one
+            self.metrics.counters["ssm_wave_segments"].inc(scan_segments)
+            self.metrics.counters["ssm_wave_segment_tokens"].inc(packed_n)
         self._last_wave_kind = "ragged"
         if self._prefix is not None:
             # registration mirrors _prefill_paged_prefix_batch: custody

@@ -24,7 +24,9 @@ says which:
   ``ssm_segments`` (a ragged wave: each row cut into segments of up to
   ``SCAN_CHUNK`` tokens, a segment in its attention-like dual form, the
   state handed from a row's segment to its next, starting from the row's
-  seed and kept at the row's last page end and last token) and
+  seed and kept at the row's last page end and last token; the same walk
+  is ONE kernel a layer, ``ops/ssm_pallas.ssm_wave_scan``, where the call
+  shows bf16 pools, lane-multiple widths and a TPU: ``stream_mixers``) and
   ``ssm_chunk_step`` (a decode step against the slots' state FROZEN for the
   chunk, as the pool is: ``ssm_state_read`` reads ``S_0`` of the slots
   that hold a sequence, a block ``(layer, slot)`` of the pool each, once a
@@ -81,6 +83,7 @@ from typing import Any, Dict, List, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..ops import ssm_pallas
 from ..ops.layers import gqa_attention, rms_norm, write_kv_cache
 from . import lfm2, llama
 from .configs import ModelConfig
@@ -332,6 +335,16 @@ def ssm_segments(cfg: ModelConfig, xd, la, Bm, Cm, starts, lens, end_lens,
     return y[:W], (slot, snap)
 
 
+def wave_segments(first: int, tokens: int, page_size: int) -> int:
+    """How many live segments a wave's scan walks, a layer, for a row of
+    ``tokens`` new tokens from position ``first``: the row cut at its last
+    page end and each part into segments of ``SCAN_CHUNK``
+    (``ssm_segments``' rule, on the host's integers: the engine counts a
+    wave's walk from its plan, ``ssm_wave_segments``)."""
+    to_end = max((first + tokens) // page_size * page_size - first, 0)
+    return -(-to_end // SCAN_CHUNK) + -(-(tokens - to_end) // SCAN_CHUNK)
+
+
 def ssm_state_read(cfg: ModelConfig, pool, layer, Cm, rows, n_live):
     """The frozen state's part of a decode step: ``y0`` [B, H, P] float32,
     ``y0[b] = S_0[layer, b] C[b]`` for the slots ``rows[:n_live]``
@@ -343,8 +356,6 @@ def ssm_state_read(cfg: ModelConfig, pool, layer, Cm, rows, n_live):
     ``ops/ssm_pallas.state_read`` where it takes the call (bf16 state,
     lane-multiple widths, a TPU), else by a loop of one block a live row
     with the same contract. No form gathers the rows into a new array."""
-    from ..ops import ssm_pallas
-
     H, P = cfg.ssm_heads, cfg.ssm_head_dim
     B_, N = pool.shape[1], pool.shape[3]
     if ssm_pallas.takes(pool, cfg.ssm_groups):
@@ -417,8 +428,6 @@ def merge_state(state, bufs, rows, n_live):
     (``ssm_segments``). The kernel ``ops/ssm_pallas.state_merge`` where it
     takes the pool (as ``ssm_state_read``), else a loop of one live row's
     layers a trip."""
-    from ..ops import ssm_pallas
-
     hz, hxd, hB, hcs = bufs                                # [L, B, K, ...]
     L, B_, K, H, P = hxd.shape
     G, N = hB.shape[-2:]
@@ -451,7 +460,7 @@ def merge_state(state, bufs, rows, n_live):
                               lfm2.merge_state(conv, hz), conv)}
 
 
-def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
+def mamba_token_mixer(cfg: ModelConfig, history, recurrence, flat=False):
     """The Mamba-2 mixer as a token mixer: ``token_mixer(h [B, T, D], lp,
     layer, aux) -> (out [B, T, D], kept, aux)``. ``layer`` is the layer's
     index among the Mamba-2 layers and all a layer is handed: what a
@@ -463,8 +472,14 @@ def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
     aux) -> (earlier, conv_out, aux)`` is the forward's, where a token's
     earlier ``xBC`` come from (``lfm2.conv_token_mixer``'s, with the
     carry); ``recurrence(xd, la, Bm, Cm, layer, aux) -> (y [B, T, H, P],
-    ssm_out, aux)`` its form of the scan. ``kept = (conv_out,
-    ssm_out)``, stacked over the layers by the stack."""
+    ssm_out, aux)`` its form of the scan; a ``flat`` one takes ``(the
+    conv's output [B, T, x | B | C], dt [B, T, H])`` in ``xd``'s place
+    and gives ``y`` as ``[B, T, H P]``, the heads side by side in the
+    lanes as the projections have them (``ssm_pallas.ssm_wave_scan``,
+    which cuts its windows out of the conv's rows and makes ``dt x``
+    itself: nothing is sliced or laid out anew for it or after it).
+    ``kept = (conv_out, ssm_out)``, stacked over the layers by the
+    stack."""
     H, P, G, N = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
                   cfg.ssm_state)
     Di, Cd = cfg.ssm_inner, cfg.ssm_conv_dim
@@ -473,6 +488,11 @@ def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
         B_, T = h.shape[0], h.shape[1]
         zxd = jnp.einsum("btd,de->bte", h, lp["in_proj"],
                          preferred_element_type=f32)
+        if flat:
+            # made once: with no loop between its readers the chip's
+            # compiler kept it in fast memory and made the whole matmul
+            # again for each of its four readers (PERF.md section 6, PR 53)
+            zxd = jax.lax.optimization_barrier(zxd)
         z, dt = zxd[..., :Di], zxd[..., Di + Cd:Di + Cd + H]
         # rounded to the stream's dtype where it is made: it is what the
         # state holds, and a token reads the same rows from its call and
@@ -491,9 +511,13 @@ def mamba_token_mixer(cfg: ModelConfig, history, recurrence):
         Cm = c[..., Di + G * N:].reshape(B_, T, G, N)
         dt = jax.nn.softplus(dt + lp["dt_bias"].astype(f32))   # [B, T, H]
         la = -jnp.exp(lp["A_log"].astype(f32)) * dt
-        y, ssm_out, aux = recurrence(x * dt[..., None], la, Bm, Cm, layer,
-                                     aux)
-        y = y + lp["D"].astype(f32)[:, None] * x
+        if flat:
+            y, ssm_out, aux = recurrence((c, dt), la, Bm, Cm, layer, aux)
+            y = y + jnp.repeat(lp["D"].astype(f32), P) * c[..., :Di]
+        else:
+            y, ssm_out, aux = recurrence(x * dt[..., None], la, Bm, Cm,
+                                         layer, aux)
+            y = y + lp["D"].astype(f32)[:, None] * x
         y = (y.reshape(B_, T, Di) * jax.nn.silu(z)).reshape(
             B_, T, G, Di // G)
         y = y * jax.lax.rsqrt(jnp.mean(y * y, axis=-1, keepdims=True)
@@ -752,15 +776,34 @@ def stream_mixers(cfg: ModelConfig, seed, tok_row, tok_pos, starts, lens,
         lambda layer: jax.lax.dynamic_index_in_dim(seed["conv"], layer,
                                                    keepdims=False))
     src, slots, dst, *pools = seed["ssm"]
+    W = tok_row.shape[0]
+    # which form scans the wave is read from the call, as the decode's
+    # state passes are (``ssm_state_read``): the kernel ``ops/ssm_pallas.
+    # ssm_wave_scan`` where it takes the pools and the stream (bf16 state,
+    # lane-multiple widths, a TPU), over a table of the live segments made
+    # HERE, once a wave, for all the layers; else ``ssm_segments``
+    by_kernel = ssm_pallas.takes_wave(
+        pools, (W, cfg.ssm_heads, cfg.ssm_head_dim), cfg.ssm_groups,
+        SCAN_CHUNK)
+    if by_kernel:
+        table, n_live = ssm_pallas.wave_segment_table(
+            starts, lens, end_lens, src, slots, dst, pools[0].shape[1], W)
 
-    def recurrence(xd, la, Bm, Cm, layer, pools):
-        y, pools = ssm_segments(cfg, xd[0], la[0], Bm[0], Cm[0], starts,
-                                lens, end_lens, layer, src, slots, dst,
-                                pools)
-        return y[None], None, pools
+        def recurrence(xbc_dt, la, _Bm, _Cm, layer, pools):
+            xbc, dt = xbc_dt
+            y, *pools = ssm_pallas.ssm_wave_scan(
+                xbc[0], dt[0], la[0], table, n_live, layer, *pools,
+                interpret=jax.default_backend() != "tpu")
+            return y[None], None, tuple(pools)
+    else:
+        def recurrence(xd, la, Bm, Cm, layer, pools):
+            y, pools = ssm_segments(cfg, xd[0], la[0], Bm[0], Cm[0], starts,
+                                    lens, end_lens, layer, src, slots, dst,
+                                    pools)
+            return y[None], None, pools
 
-    return ((mamba_token_mixer(cfg, history, recurrence), tuple(pools)),
-            end_lens)
+    return ((mamba_token_mixer(cfg, history, recurrence, flat=by_kernel),
+             tuple(pools)), end_lens)
 
 
 def chunk_mixers(cfg: ModelConfig, state, bufs, step, live_rows):

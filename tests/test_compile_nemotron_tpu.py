@@ -1,7 +1,8 @@
 """AOT compiles for a described TPU v5e of what PR 50 brought to the
 kernels the cells share: the expert stream kernel's un-gated form at
 ``nemotron3-nano.chat``'s widths as laid out, and both paged attention
-kernels at 16 query heads a KV head over its 2-KV-head pool. As
+kernels at 16 query heads a KV head over its 2-KV-head pool; and of PR
+53's ``ssm_wave_scan`` at the cell's widths. As
 ``tests/test_tpu_compile.py`` (whose fixture and helper these are): nothing
 runs, and a compile that passes says nothing about results or times. A
 file of its own so that it runs beside that one, not after it."""
@@ -15,7 +16,7 @@ from jax.sharding import SingleDeviceSharding
 
 from swarmdb_tpu.models import nemotron_h
 from swarmdb_tpu.ops import attention_pallas as ap
-from swarmdb_tpu.ops import moe_pallas
+from swarmdb_tpu.ops import moe_pallas, ssm_pallas
 
 PS, KC = 16, 8
 BF, I32, F32 = jnp.bfloat16, jnp.int32, jnp.float32
@@ -91,3 +92,22 @@ def test_ragged_prefill_compiles_at_16_query_heads_a_kv_head(one_chip):
                         qs, kv, kv, POOL, POOL, ((ROWS, MAXP), I32), row, row,
                         row)
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize("width", [8, 1024], ids=["the-narrowest-rung",
+                                                  "the-widest-rung"])
+def test_the_wave_scan_compiles_at_the_cells_widths(one_chip, width):
+    """64 heads of 64 in 8 groups, state 128, the 23 layers' pools of 32
+    slots and 100 snapshots left in HBM where they are (aliased in and
+    out: no copy beside the kernel), a table for 32 rows."""
+    H, P, G, N, L = 64, 64, 8, 128, 23
+    slot, snap = ((L, ROWS, H * P, N), BF), ((L, 101, H * P, N), BF)
+    assert ssm_pallas.WAVE_SEGMENT == nemotron_h.SCAN_CHUNK
+    columns = -(-width // ssm_pallas.WAVE_SEGMENT) + 2 * ROWS
+    compiled = _compile(
+        one_chip, ssm_pallas.ssm_wave_scan,
+        ((width, H * P + 2 * G * N), F32), ((width, H), F32),
+        ((width, H), F32),
+        ((6, columns), I32), ((), I32), ((), I32), slot, snap)
+    assert "%ssm_wave_scan" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < 32 << 20

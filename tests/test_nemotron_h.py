@@ -521,10 +521,14 @@ def test_a_hit_resumes_from_a_snapshot_and_an_evicted_one_is_forgone(
     base = {n: _counter(engine, n) for n in (
         "prefix_reused_tokens", "prefix_state_forgone_tokens",
         "ssm_state_tokens_resumed", "ssm_snapshots_taken",
-        "ssm_snapshots_evicted")}
+        "ssm_snapshots_evicted", "ssm_wave_segments",
+        "ssm_wave_segment_tokens")}
     since = lambda n: _counter(engine, n) - base[n]
     cold = _run(engine, prompt)
     assert since("ssm_snapshots_taken") == 1
+    # 64 tokens to the last page end in segments of 8, and the 6 behind it
+    assert (since("ssm_wave_segments"),
+            since("ssm_wave_segment_tokens")) == (9, 70)
     _run(engine, tokens[1][:40], 9)            # someone else's state
     hit = _run(engine, prompt)
     assert since("prefix_reused_tokens") == 64
@@ -785,3 +789,227 @@ def test_the_decode_step_carries_the_chunks_buffers_whole(params):
             assert not any(of_a_buffer(v.aval.shape)
                            for v in (*e.invars, *e.outvars)
                            if hasattr(v.aval, "shape")), e
+
+
+# ------------------- a wave's scan as one kernel over its live segments
+
+
+def _wave_case(rows, width, n_slots=4, n_snaps=3):
+    """A made wave at the lane-multiple widths: ``rows`` of ``(tokens,
+    tokens up to the last page end, src, slot, dst)`` packed in stream
+    order from token 3 on (no row starts at a tile's first row), bf16
+    pools of two layers drawn non-zero."""
+    R = len(rows)
+    lens = np.array([r[0] for r in rows])
+    starts = np.where(lens > 0, 3 + np.cumsum(lens) - lens, 0)
+    plan = [starts, lens] + [np.array([r[i] for r in rows])
+                             for i in (1, 2, 3, 4)]
+    Hh, Pp, Gg, Nn = (LANE.ssm_heads, LANE.ssm_head_dim, LANE.ssm_groups,
+                      LANE.ssm_state)
+    xd, la = _draw(40, width, Hh, Pp), -jnp.abs(_draw(41, width, Hh)) * 0.3
+    Bm, Cm = _draw(42, width, Gg, Nn), _draw(43, width, Gg, Nn)
+    slot0 = _draw(44, 2, n_slots, Hh * Pp, Nn).astype(jnp.bfloat16)
+    snap0 = _draw(45, 2, 1 + n_snaps, Hh * Pp, Nn).astype(jnp.bfloat16)
+    assert starts[-1] + lens[-1] <= width and R <= 4
+    return (xd, la, Bm, Cm), plan, (slot0, snap0)
+
+
+# (tokens, of them up to the last page end, src, slot, dst); slot 4: nowhere
+WAVES = {
+    "a-row-from-zeros": ([(40, 32, 0, 1, 2)], 64),
+    "a-row-from-a-snapshot": ([(40, 32, 3, 0, 1)], 64),
+    "a-row-from-its-slots-own-state": ([(40, 32, -1, 2, 0)], 64),
+    "a-row-that-ends-at-its-last-page-end": ([(48, 48, 2, 3, 1)], 64),
+    "a-dead-row-between-two-live-ones": (
+        [(21, 16, 1, 0, 2), (0, 0, 0, 4, 0), (30, 16, -1, 3, 3)], 64),
+    "a-one-token-row": ([(1, 0, -1, 2, 0)], 8),
+    "1-token-at-a-page-end": ([(1, 1, 2, 1, 3)], 8),
+    "127-tokens": ([(127, 112, 0, 0, 1)], 256),
+    "128-tokens": ([(128, 128, 1, 1, 2)], 256),
+    "129-tokens": ([(129, 128, -1, 2, 3)], 256),
+    "300-tokens": ([(300, 288, 3, 3, 1)], 512),
+    "rows-of-129-1-and-300-with-nowhere-to-go": (
+        [(129, 128, 0, 4, 0), (1, 0, -1, 0, 0), (300, 160, 2, 1, 3)], 512),
+}
+
+
+@pytest.mark.parametrize("case", list(WAVES))
+def test_the_wave_kernel_is_the_loop_over_the_live_segments(monkeypatch,
+                                                            case):
+    """``ssm_pallas.ssm_wave_scan`` (interpreted) against ``ssm_segments``
+    at the published segment and against ``ssm_recurrence``: ``y`` of the
+    live tokens, exact zeros elsewhere, the states a bf16 rounding from
+    the recurrence's, and every slot row and snapshot row the table does
+    not name, the bin and the other layer among them, bit for bit what
+    it was."""
+    from swarmdb_tpu.ops import ssm_pallas
+
+    monkeypatch.setattr(nemotron_h, "SCAN_CHUNK", 128)
+    rows, width = WAVES[case]
+    streams, plan, (slot0, snap0) = _wave_case(rows, width)
+    starts, lens, end_lens, src, slots, dst = plan
+    Hh, Pp, Nn = LANE.ssm_heads, LANE.ssm_head_dim, LANE.ssm_state
+    n_slots = slot0.shape[1]
+    assert not ssm_pallas.takes_wave((slot0, snap0), (width, Hh, Pp),
+                                     LANE.ssm_groups)        # the CPU
+    monkeypatch.setattr(ssm_pallas, "_on_tpu", lambda: True)
+    assert ssm_pallas.takes_wave((slot0, snap0), (width, Hh, Pp),
+                                 LANE.ssm_groups)
+    for pools, stream, seg in (
+            ((slot0.astype(F32), snap0.astype(F32)), (width, Hh, Pp), 128),
+            ((slot0, snap0), (width, Hh, 48), 128),
+            ((slot0, snap0), (width + 4, Hh, Pp), 128),
+            ((slot0, snap0), (width, Hh, Pp), 8)):
+        assert not ssm_pallas.takes_wave(pools, stream, LANE.ssm_groups, seg)
+    as_i32 = [jnp.asarray(a, jnp.int32) for a in plan]
+    y_loop, (slot_l, snap_l) = jax.jit(lambda *a: nemotron_h.ssm_segments(
+        LANE, *a[:4], *as_i32[:3], jnp.int32(1), *as_i32[3:], a[4:]))(
+            *streams, slot0, snap0)
+    table, n_live = ssm_pallas.wave_segment_table(*as_i32, n_slots, width)
+    assert int(n_live) == sum(-(-e // 128) + -(-(n - e) // 128)
+                              for n, e in zip(lens, end_lens))
+    # as the kernel takes them: ``x`` and a head's ``dt`` apart, ``x | B |
+    # C`` a token's one row
+    dt = 0.5 + jnp.abs(_draw(46, width, Hh))
+    xd, la, Bm, Cm = streams
+    xbc = jnp.concatenate([a.reshape(width, -1) for a in (
+        xd / dt[..., None], Bm, Cm)], axis=1)       # the conv's row
+    y, slot, snap = ssm_pallas.ssm_wave_scan(
+        xbc, dt, la, table, n_live, jnp.int32(1), slot0, snap0,
+        interpret=True)
+    y = y.reshape(width, Hh, Pp)
+    live = np.zeros(width, bool)
+    named_slots, named_snaps = set(), set()
+    for r in range(len(rows)):
+        s, n, e = int(starts[r]), int(lens[r]), int(end_lens[r])
+        if not n:
+            continue
+        live[s:s + n] = True
+        seed = (snap0[1, src[r]] if src[r] > 0 else slot0[1, slots[r]]
+                if src[r] < 0 else jnp.zeros((Hh * Pp, Nn)))
+        S0 = seed.astype(F32).reshape(1, Hh, Pp, Nn)
+        cut = lambda a, m: a[None, s:s + m]
+        want, S = nemotron_h.ssm_recurrence(
+            LANE, *(cut(a, n) for a in streams), S0)
+        scale = float(jnp.max(jnp.abs(want)))
+        np.testing.assert_allclose(y[s:s + n], want[0], atol=2e-6 * scale,
+                                   rtol=1e-5)
+        np.testing.assert_allclose(y[s:s + n], y_loop[s:s + n],
+                                   atol=2e-6 * scale, rtol=1e-5)
+        bf16 = dict(rtol=2 ** -7, atol=1e-4)
+        if slots[r] < n_slots:
+            named_slots.add(int(slots[r]))
+            np.testing.assert_allclose(slot[1, slots[r]].astype(F32),
+                                       S.reshape(Hh * Pp, Nn), **bf16)
+        if e and dst[r]:
+            named_snaps.add(int(dst[r]))
+            _, Se = nemotron_h.ssm_recurrence(
+                LANE, *(cut(a, e) for a in streams), S0)
+            np.testing.assert_allclose(snap[1, dst[r]].astype(F32),
+                                       Se.reshape(Hh * Pp, Nn), **bf16)
+    assert not np.asarray(y)[~live].any()
+    same = lambda a, b: np.testing.assert_array_equal(
+        np.asarray(a.astype(F32)), np.asarray(b.astype(F32)))
+    same(slot[0], slot0[0])
+    same(snap[0], snap0[0])
+    for b in set(range(n_slots)) - named_slots:
+        same(slot[1, b], slot0[1, b])
+    for b in set(range(snap0.shape[1])) - named_snaps:     # 0: the bin
+        same(snap[1, b], snap0[1, b])
+    # the loop's own: one rounding of float32 sums made in another order
+    for got, loops, named in ((slot, slot_l, named_slots),
+                              (snap, snap_l, named_snaps)):
+        for b in named:
+            np.testing.assert_allclose(got[1, b].astype(F32),
+                                       loops[1, b].astype(F32), **bf16)
+
+
+@pytest.mark.parametrize("first,tokens,want", [
+    (32, 215, 3), (0, 1, 1), (15, 1, 1), (0, 128, 1), (0, 129, 2),
+    (0, 300, 4), (7, 0, 0), (40, 7, 1), (250, 6, 1), (250, 7, 2)])
+def test_the_hosts_count_of_a_rows_segments_is_the_scans(monkeypatch, first,
+                                                         tokens, want):
+    """``wave_segments`` on the host's integers against the table the
+    kernel walks, with the page ends ``stream_mixers`` finds."""
+    from swarmdb_tpu.ops import ssm_pallas
+
+    monkeypatch.setattr(nemotron_h, "SCAN_CHUNK", 128)
+    assert nemotron_h.wave_segments(first, tokens, PS) == want
+    pos = first + np.arange(tokens)
+    ends = np.nonzero((pos + 1) % PS == 0)[0]
+    one = lambda v: jnp.asarray([v], jnp.int32)
+    _table, n_live = ssm_pallas.wave_segment_table(
+        one(0), one(tokens), one(ends[-1] + 1 if len(ends) else 0), one(0),
+        one(0), one(0), 4, 512)
+    assert int(n_live) == want
+
+
+def test_a_wave_through_the_stack_takes_the_kernel_the_call_shows(
+        monkeypatch, tokens):
+    """``forward_ragged_prefill`` over bf16 pools at the lane-multiple
+    widths: XLA's loop on the CPU, the kernel (interpreted) once
+    ``_on_tpu`` says so, a table made once for all the layers; the same
+    logits and conv rows, and the states to a bf16 rounding."""
+    from swarmdb_tpu.ops import ssm_pallas
+
+    monkeypatch.setattr(nemotron_h, "SCAN_CHUNK", 128)
+    bf = jnp.bfloat16
+    cfg = get_config("tiny-nemotron", ssm_heads=4, ssm_head_dim=64,
+                     ssm_groups=2, ssm_state=128, state_snapshots=SNAPS)
+    # a float32 stream: a bf16 one rounds the forms' last float32 bits into
+    # another expert here and there
+    params = nemotron_h.init_params(cfg, jax.random.PRNGKey(3), F32)
+    cache = llama.init_paged_cache(cfg, SLOTS, MAX_SEQ, PAGES, PS, bf)
+    R, maxp, W = SLOTS, MAX_SEQ // PS, 64
+    rows = [(0, tokens[0][:37], [1, 2, 3], 1), (2, tokens[1][:21], [4, 5], 2)]
+    toks, tok_row = np.zeros(W, np.int32), np.full(W, R, np.int32)
+    tok_pos = np.full(W, maxp * PS, np.int32)
+    starts, lens = np.zeros(R, np.int32), np.zeros(R, np.int32)
+    tables = np.zeros((R, maxp), np.int32)
+    slots, dst = np.full(R, SLOTS, np.int32), np.zeros(R, np.int32)
+    at = 0
+    for r, (slot, t, table, d) in enumerate(rows):
+        n = len(t)
+        toks[at:at + n], tok_row[at:at + n] = t, r
+        tok_pos[at:at + n] = np.arange(n)
+        starts[r], lens[r], slots[r], dst[r] = at, n, slot, d
+        tables[r, :len(table)] = table
+        at += n
+    src = jnp.zeros(R, jnp.int32)
+    seed = {"conv": lfm2.seed_state(src, jnp.asarray(slots),
+                                    cache["state"]["conv"],
+                                    cache["page_state"]["conv"]),
+            "ssm": (src, jnp.asarray(slots), jnp.asarray(dst),
+                    cache["state"]["ssm"], cache["page_state"]["ssm"])}
+    args = [jnp.asarray(a) for a in (toks, tok_row, tok_pos, tables, starts,
+                                     lens, np.zeros(R, np.int32))]
+    run = lambda: jax.jit(
+        lambda p, *a: llama.forward_ragged_prefill(p, cfg, *a))(
+            params, *args, cache["k"], cache["v"], seed)
+    calls = []
+    scan = ssm_pallas.ssm_wave_scan
+    monkeypatch.setattr(ssm_pallas, "ssm_wave_scan",
+                        lambda *a, **k: calls.append(1) or scan(*a, **k))
+    by_loop = run()
+    assert not calls
+    monkeypatch.setattr(ssm_pallas, "_on_tpu", lambda: True)
+    jax.clear_caches()
+    by_kernel = run()
+    # traced once a layer body: a repeated segment's once for its repeats
+    assert 0 < len(calls) <= cfg.n_ssm_layers
+    for got, want in zip(jax.tree.leaves(by_kernel),
+                         jax.tree.leaves(by_loop)):
+        if W in want.shape:
+            # the stream's live tokens: behind them the loop leaves what
+            # its masked segments made, the kernel zeros
+            live = (slice(None),) * want.shape.index(W) + (slice(0, at),)
+            got, want = got[live], want[live]
+        if want.shape == cache["page_state"]["ssm"].shape:
+            assert not np.asarray(got[:, 0].astype(F32)).any()
+            got, want = got[:, 1:], want[:, 1:]      # the bin: the loop's
+        if jnp.issubdtype(want.dtype, jnp.floating):
+            np.testing.assert_allclose(
+                np.asarray(got.astype(F32)), np.asarray(want.astype(F32)),
+                rtol=2 ** -7, atol=2e-3)
+        else:
+            np.testing.assert_array_equal(got, want)
