@@ -735,11 +735,12 @@ class Engine:
         # page owns which (``PrefixLRU.keep_state_slots``); a wave is told
         # where each row resumes from and where the state at its last
         # page end goes; a prefix hit resumes behind the deepest hit page
-        # that still owns one and the rest is forgone. Such rows do not
-        # ride waves yet: a one-token row cost a whole segment of the
-        # wave's scan a layer while that was XLA's loop (73 us, 1.7 ms a
-        # rider a wave); under ``ssm_pallas.ssm_wave_scan`` it is 13 us,
-        # 0.3 ms a wave (scripts/race_ssm_wave.py; ROADMAP Reach B4)
+        # that still owns one and the rest is forgone. A running row that
+        # rides a wave (``_wave_riders``) resumes from its own slot and
+        # takes no snapshot: a one-token row is 13 us a layer under
+        # ``ssm_pallas.ssm_wave_scan``, 0.3 ms a rider a wave (scripts/
+        # race_ssm_wave.py), and 73 us, 1.7 ms, where ``ssm_segments``'
+        # loop runs instead (no bf16 state, odd widths, the CPU)
         self._snapshots = 0
         # slot -> the snapshot slot its admission resumes from (plan time)
         self._snap_src: Dict[int, int] = {}
@@ -1397,8 +1398,10 @@ class Engine:
             self._ragged_widths = ladder
             self._ragged_ridge_tokens = weights_ridge_tokens(params)
             # registered at 0, so a reader tells "nobody rode" from a
-            # program whose waves take no riders
+            # program whose waves take no riders; beside it the running
+            # rows a round's plan had no seat for (``_riders_that_fit``)
             self.metrics.counters["wave_rider_tokens"].inc(0)
+            self.metrics.counters["wave_riders_unseated"].inc(0)
             if self._latent:
                 self.metrics.counters["latent_prefix_tokens_reused"].inc(0)
             _ragged_body_fn = paged.prefill_ragged
@@ -2660,11 +2663,16 @@ class Engine:
         the scan path nobody rides: the device is ahead of ``generated``),
         with at least two tokens left (a row with one left would need a
         whole chunk only to surface it) and room for the step under
-        ``max_seq``. A prefill lane has no running rows of its own. A
-        configuration whose FFN drops over a capacity never gets here: it
-        has no ragged waves (a rider would compete with the prompt tokens
-        for capacity where a decode step's rows do not)."""
-        if self._role == "prefill" or self._snapshots:
+        ``max_seq``. What holds for the pages holds for a slot's state
+        (conv rows, a Mamba-2 layer's ``ssm``): every chunk merges its
+        steps into the slot pool at its end, so with every dispatched
+        chunk processed the pool holds the row's state at ``position``,
+        and the wave reads it from there and writes it back a token on
+        (``state_src`` -1). A prefill lane has no running rows of its own.
+        A configuration whose FFN drops over a capacity never gets here:
+        it has no ragged waves (a rider would compete with the prompt
+        tokens for capacity where a decode step's rows do not)."""
+        if self._role == "prefill":
             return []
         return [i for i, s in enumerate(self.slots)
                 if s.active and not s.cancelled and not s.pending_token
@@ -4698,7 +4706,10 @@ class Engine:
         token surfaced as row 0 of its next block (``pending_token``):
         the pass over the weights that admits a request is a decode step
         for the rows that wait for it. Riders are not in ``batch``: they
-        count into ``wave_rider_tokens`` and into nothing of admission's.
+        count into ``wave_rider_tokens`` and into nothing of admission's,
+        and the running rows the plan had no seat for into
+        ``wave_riders_unseated``. Under snapshots a rider's state goes
+        from its slot back to its slot, a token on; it takes no snapshot.
 
         ``batch`` rows: (slot_id, req, hits, chains, table_row) — hits/
         chains from the admission-time prefix plan (chains None = row not
@@ -4718,7 +4729,7 @@ class Engine:
             self._topp[slot_id] = s.top_p
             self._set_slot_key(slot_id, s.seed)
         snap_dst = self._take_snapshots(batch)
-        packed_n = padding_n = scan_segments = 0
+        packed_n = padding_n = scan_segments = unseated = 0
         # routed: slot -> the parts of its suffix, in stream order
         stream_parts: Dict[int, List[RoutingRows]] = {}
         riding: List[int] = []     # the slots that ride the last wave
@@ -4732,9 +4743,10 @@ class Engine:
             if wd >= total:
                 # the round's last wave: every pending row ends in it,
                 # and the seats and rows it has left take riders
-                riding = self._wave_riders()
-                riding = riding[:self._riders_that_fit(
-                    total, wd, min(len(riding), R - len(pend)))]
+                running = self._wave_riders()
+                riding = running[:self._riders_that_fit(
+                    total, wd, min(len(running), R - len(pend)))]
+                unseated = len(running) - len(riding)
                 for sid in riding:
                     s = self.slots[sid]
                     pend.append([sid, s.generated[-1:], s.position, 0,
@@ -4778,18 +4790,21 @@ class Engine:
                 gather[r] = slot_id
                 state_slot[r] = slot_id
                 # a first chunk behind a prefix hit resumes from its last
-                # hit page (the table row starts with the hit pages); a
-                # rider, like a later chunk, from its own slot's state
-                state_src[r] = -1 if consumed or slot_id in riding else (
-                    row[p0 // ps - 1] if p0 else 0)
+                # hit page (the table row starts with the hit pages; with
+                # snapshots: from the snapshot its hits led to); a rider,
+                # like a later chunk, from its own slot's state
+                rides = slot_id in riding
+                if consumed or rides:
+                    state_src[r] = -1
+                elif p0:
+                    state_src[r] = (row[p0 // ps - 1] if snap_dst is None
+                                    else self._snap_src.get(slot_id, 0))
                 if snap_dst is not None:
-                    if not consumed:
-                        state_src[r] = (self._snap_src.get(slot_id, 0)
-                                        if p0 else 0)
                     # the chunk that holds the prompt's last page end
                     # writes the snapshot; an earlier chunk's goes to the
-                    # bin
-                    dst, end = snap_dst.get(slot_id, (0, 0))
+                    # bin, and so does a rider's whose token ends a page
+                    dst, end = (0, 0) if rides else snap_dst.get(
+                        slot_id, (0, 0))
                     if abs0 < end <= abs0 + take:
                         state_dst[r] = dst
                     scan_segments += wave_segments(abs0, take, ps)
@@ -4852,10 +4867,12 @@ class Engine:
         self.metrics.counters["prefill_packed_tokens"].inc(packed_n)
         self.metrics.counters["prefill_padding_tokens"].inc(padding_n)
         self.metrics.counters["wave_rider_tokens"].inc(len(riding))
+        self.metrics.counters["wave_riders_unseated"].inc(unseated)
         if snap_dst is not None:
-            # such an engine takes no riders: every token is an admitted one
+            # what the scan walks: a rider's one-token segment is one too
             self.metrics.counters["ssm_wave_segments"].inc(scan_segments)
-            self.metrics.counters["ssm_wave_segment_tokens"].inc(packed_n)
+            self.metrics.counters["ssm_wave_segment_tokens"].inc(
+                packed_n + len(riding))
         self._last_wave_kind = "ragged"
         if self._prefix is not None:
             # registration mirrors _prefill_paged_prefix_batch: custody

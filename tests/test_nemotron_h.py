@@ -287,6 +287,53 @@ def test_a_split_prompt_and_chunked_decode_carry_the_state(params, tokens):
     np.testing.assert_allclose(out[:, 1], want[44:60], **TOL)
 
 
+@pytest.mark.parametrize("first", [44, 47],
+                         ids=["inside_a_page", "ends_a_page"])
+def test_a_riders_state_is_the_decode_steps(params, tokens, first):
+    """A running row's one token taken by a round's wave, beside an
+    admitted row (``src`` -1: from its slot's own state; ``dst`` 0: no
+    snapshot, also where the token ends a page), against the same token
+    taken by a decode step (a chunk of one, merged): the logits, the
+    slot's state and conv rows, and the snapshots the wave was not given
+    bit for bit what they were."""
+    toks = tokens[1][:first + 1]
+    pages = [7, 8, 9, 10, 11]
+    rode, stepped = Served(params), Served(params)
+    for s in (rode, stepped):
+        s.wave([(1, toks[:first], 0, pages, 0, 2)], 64)
+    snaps = jax.tree.map(np.asarray, rode.cache["page_state"])
+    assert snaps["ssm"][:, 2].any()
+    got, end_lens = rode.wave([(3, tokens[2][:20], 0, [20, 21], 0, 4),
+                               (1, toks[first:], first, pages, -1, 0)], 32)
+    assert list(end_lens[:2]) == [16, int((first + 1) % PS == 0)]
+    feed = np.zeros((1, SLOTS), np.int32)
+    feed[0, 1] = toks[first]
+    pos0 = np.zeros(SLOTS, np.int32)
+    pos0[1] = first
+    out = stepped.chunk(feed, pos0, K=1)
+    np.testing.assert_allclose(got[1], out[0, 1], **TOL)
+    np.testing.assert_allclose(got[1], whole(params, toks)[-1], **TOL)
+    for part in ("ssm", "conv"):
+        np.testing.assert_allclose(rode.cache["state"][part][:, 1],
+                                   stepped.cache["state"][part][:, 1],
+                                   atol=2e-5)
+        # the admitted row's snapshot is the wave's, every other the bin's
+        kept = [1, 2, 3, 5]
+        now = np.asarray(rode.cache["page_state"][part])
+        np.testing.assert_array_equal(now[:, kept], snaps[part][:, kept])
+        assert (now[:, 4] != snaps[part][:, 4]).any()
+    for pool in ("k", "v"):
+        np.testing.assert_allclose(rode.cache[pool][:, pages],
+                                   stepped.cache[pool][:, pages], atol=2e-5)
+    # and the row decodes on from there as if it had never left the chunk
+    more = tokens[1][first + 1:first + 9]
+    feed = np.zeros((8, SLOTS), np.int32)
+    feed[:, 1] = more
+    after = rode.chunk(feed, pos0 + (pos0 > 0))
+    np.testing.assert_allclose(
+        after[:, 1], whole(params, tokens[1][:first + 9])[first + 1:], **TOL)
+
+
 def test_prefill_then_decode_match_the_benchmarks_reference(params, tokens):
     """Through pages and state, against the plain reference the benchmark
     holds the cell to: once with the reference choosing for itself and
@@ -488,7 +535,14 @@ def engine(request):
         mp.setenv("SWARMDB_EMIT_RING", "0")
     eng = _engine()
     assert eng._stateful and eng._snapshots == SNAPS
+    # nothing runs yet; a row that does rides this engine's waves as any
+    # paged engine's (ISSUE 56: tests/test_wave_riders.py has the cases)
     assert eng._ragged_active() and eng._wave_riders() == []
+    s = eng.slots[0]
+    s.active, s.generated, s.request = True, [7], GenRequest(
+        prompt=[5], sampling=SamplingParams(max_new_tokens=4))
+    assert eng._wave_riders() == [0]
+    s.active, s.generated, s.request = False, [], None
     eng.start()
     yield eng
     eng.stop()
@@ -522,7 +576,8 @@ def test_a_hit_resumes_from_a_snapshot_and_an_evicted_one_is_forgone(
         "prefix_reused_tokens", "prefix_state_forgone_tokens",
         "ssm_state_tokens_resumed", "ssm_snapshots_taken",
         "ssm_snapshots_evicted", "ssm_wave_segments",
-        "ssm_wave_segment_tokens")}
+        "ssm_wave_segment_tokens", "wave_rider_tokens",
+        "wave_riders_unseated")}
     since = lambda n: _counter(engine, n) - base[n]
     cold = _run(engine, prompt)
     assert since("ssm_snapshots_taken") == 1
@@ -557,7 +612,11 @@ def test_a_hit_resumes_from_a_snapshot_and_an_evicted_one_is_forgone(
     assert engine._prefix.state_slots_live() <= SNAPS
     assert _counter(engine, "ssm_snapshot_slots_live") <= _counter(
         engine, "ssm_snapshot_slots")
-    assert _counter(engine, "wave_rider_tokens") == 0
+    # one request at a time: no row was running when another was
+    # admitted, so none rode and none was left without a seat (both
+    # counters are this engine's since ISSUE 56, registered at 0)
+    assert since("wave_rider_tokens") == since("wave_riders_unseated") == 0
+    assert "wave_riders_unseated" in engine.metrics.snapshot()["counters"]
 
 
 def test_the_widest_wave_follows_the_query_heads_a_kv_head():

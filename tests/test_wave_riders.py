@@ -1,9 +1,12 @@
 """Running requests ride the round's prefill wave (ISSUE 43): when an
 admission round dispatches its ragged wave, the slots whose state the host
 has confirmed join it as one-token rows, advance by one position and have
-the token they sampled surfaced with their next block. CPU, float32, tiny
-paged engines: the path a token took does not change it."""
+the token they sampled surfaced with their next block. CPU, tiny paged
+engines: the path a token took does not change it. Since ISSUE 56 an
+engine with snapshots (``tiny-nemotron``: a Mamba-2 state a slot) rides
+too: a rider's state goes from its slot back to its slot."""
 
+import importlib
 import json
 import os
 import sys
@@ -11,12 +14,14 @@ import threading
 from pathlib import Path
 from types import SimpleNamespace
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from swarmdb_tpu.backend.engine import GenRequest
 from swarmdb_tpu.backend.sampling import SamplingParams
 from swarmdb_tpu.backend.service import build_backend_engine
+from swarmdb_tpu.models import nemotron_h
 from swarmdb_tpu.models.configs import get_config
 from swarmdb_tpu.obs import TRACER
 
@@ -29,14 +34,14 @@ LENGTHS = (19, 5, 11, 3, 13)
 BUDGETS = (30, 25, 17, 22, 9)
 
 
-def _build(name="tiny-debug", scan=False, **kw):
+def _build(name="tiny-debug", scan=False, **cfg):
     was = os.environ.get("SWARMDB_EMIT_RING")
     if scan:
         os.environ["SWARMDB_EMIT_RING"] = "0"
     try:
         eng, _tok = build_backend_engine(
-            get_config(name), max_seq=MAX_SEQ, paged=True, page_size=PS,
-            decode_chunk=K, max_batch=B, **kw)
+            get_config(name, **cfg), max_seq=MAX_SEQ, paged=True,
+            page_size=PS, decode_chunk=K, max_batch=B)
     finally:
         if scan:
             if was is None:
@@ -44,7 +49,33 @@ def _build(name="tiny-debug", scan=False, **kw):
             else:
                 os.environ["SWARMDB_EMIT_RING"] = was
     assert eng._use_resident() == (not scan)
+    if scan:
+        # every chunk processed before the next round, as between two
+        # resident sessions: with one in flight nobody rides
+        eng.pipeline_depth = 1
     return eng
+
+
+@pytest.fixture
+def float32(request, monkeypatch):
+    """``tiny-nemotron`` built in float32, weights and pools. In bfloat16
+    a state that took one more rounding (a rider's once a wave, a resumed
+    snapshot's) turns the near-ties of a random tiny model, with or
+    without riders. A case on another family is built as it was."""
+    if request.node.callspec.params.get("name") != "tiny-nemotron":
+        return
+    monkeypatch.setenv("SWARMDB_KV_DTYPE", "f32")
+    init = nemotron_h.init_params
+    monkeypatch.setattr(
+        nemotron_h, "init_params",
+        lambda cfg, key, dtype=jnp.float32: init(cfg, key, dtype))
+
+
+# the engines a case runs on: the dense stack, and the one with snapshots
+# on both decode paths
+ENGINES = pytest.mark.parametrize("name,scan", [
+    ("tiny-debug", False), ("tiny-nemotron", False), ("tiny-nemotron", True)],
+    ids=["dense", "snapshots", "snapshots-scan"])
 
 
 def _prompts(name="tiny-debug", lengths=LENGTHS):
@@ -111,9 +142,14 @@ def _riders(eng):
     return eng.metrics.counters["wave_rider_tokens"].value
 
 
-def _watch_rides(eng):
+def _unseated(eng):
+    return eng.metrics.counters["wave_riders_unseated"].value
+
+
+def _watch_rides(eng, at=None):
     """Which requests rode, and how often: a slot that holds the same
-    request a position further after a wave than before it."""
+    request a position further after a wave than before it. ``at`` takes
+    ``(request id, the position its token was written at)`` a ride."""
     rides = {}
     waves = eng._prefill_ragged_waves
 
@@ -127,6 +163,8 @@ def _watch_rides(eng):
                     and s.position == pos + 1:
                 assert s.pending_token
                 rides[rid] = rides.get(rid, 0) + 1
+                if at is not None:
+                    at.append((rid, pos))
 
     eng._prefill_ragged_waves = watched
     return rides
@@ -135,11 +173,13 @@ def _watch_rides(eng):
 # ------------------------------------------------- (a) the dense stack
 
 
+@ENGINES
 @pytest.mark.parametrize("sampling", [
     {}, {"temperature": 0.8, "seed": 7}], ids=["greedy", "seeded"])
-def test_a_token_is_the_same_whichever_pass_sampled_it(sampling):
-    eng = _build()
-    prompts = _prompts()
+def test_a_token_is_the_same_whichever_pass_sampled_it(float32, name, scan,
+                                                       sampling):
+    eng = _build(name, scan)
+    prompts = _prompts(name)
     eng.start()
     try:
         alone = _alone(eng, prompts, **sampling)
@@ -147,11 +187,16 @@ def test_a_token_is_the_same_whichever_pass_sampled_it(sampling):
         rides = _watch_rides(eng)
         together = _staggered(eng, prompts, **sampling)
         assert _riders(eng) > 0 and sum(rides.values()) == _riders(eng)
+        assert _unseated(eng) == 0     # every round had a seat for each
         for a, t in zip(alone, together):
-            # float32 on one backend: no tie between two logits decided
-            # otherwise by the wave's attention than by the decode step's
+            # one backend: no tie between two logits decided otherwise by
+            # the wave's attention (and scan) than by the decode step's
             assert t["tokens"] == a["tokens"] and t["reason"] == a["reason"]
             assert t["streamed"] == t["tokens"]
+        if eng._snapshots:
+            # the second pass found the first's pages and resumed from
+            # their snapshots, with rows riding beside it
+            assert eng.metrics.counters["ssm_state_tokens_resumed"].value
     finally:
         eng.stop()
 
@@ -179,15 +224,24 @@ def test_the_scan_path_rides_where_nothing_is_in_flight_and_only_there():
 # ------------------------------------- (b) conv state, routed experts
 
 
+@pytest.mark.parametrize("name,held", [
+    ("tiny-lfm2", {}),
+    # as the tiny file has it: half of the experts on this chip
+    ("tiny-nemotron", dict(first_held_expert=2, n_experts_held=4))],
+    ids=["conv", "snapshots"])
 @pytest.mark.parametrize("sampling", [
     {}, {"temperature": 0.8, "seed": 7}], ids=["greedy", "seeded"])
-def test_a_rider_carries_its_conv_state_and_its_routing_row(sampling):
+def test_a_rider_carries_its_conv_state_and_its_routing_row(float32, name,
+                                                            held, sampling):
     sys.path.insert(0, str(ROOT))
     from benchmark.harness import check
-    from benchmark.reference import lfm2_moe_decoder as reference
 
-    eng = _build("tiny-lfm2")
-    prompts = _prompts("tiny-lfm2")
+    cfg_file = json.loads((ROOT / "tests" / "benchmark" / "tiny"
+                           / f"{name}.json").read_text())
+    reference = importlib.import_module(
+        "benchmark.reference." + Path(cfg_file["reference"]).stem)
+    eng = _build(name, **held)
+    prompts = _prompts(name)
     eng.start()
     try:
         alone = _alone(eng, prompts, **sampling)
@@ -205,9 +259,7 @@ def test_a_rider_carries_its_conv_state_and_its_routing_row(sampling):
         twice = [t for t in together if rides.get(t["rid"], 0) >= 2]
         assert twice
         stack = SimpleNamespace(
-            cfg_file=json.loads((ROOT / "tests" / "benchmark" / "tiny"
-                                 / "tiny-lfm2.json").read_text()),
-            lanes=[SimpleNamespace(params=eng.params)])
+            cfg_file=cfg_file, lanes=[SimpleNamespace(params=eng.params)])
         gaps = check.logit_gaps(stack, twice, reference)
         if sampling:
             # a sampled token is not the maximum: the follower runs, on a
@@ -219,6 +271,77 @@ def test_a_rider_carries_its_conv_state_and_its_routing_row(sampling):
         eng.stop()
 
 
+@pytest.mark.parametrize("name,scan", [
+    ("tiny-nemotron", False), ("tiny-nemotron", True)],
+    ids=["resident", "scan"])
+def test_a_rider_at_a_page_end_takes_no_snapshot_and_costs_none(float32,
+                                                                 name, scan):
+    """A rider's token that ends a page crosses a page end like a prompt's
+    last whole page, and its state there goes to the bin: the snapshot
+    pool's rows are bit for bit what they were but the ones the round's
+    admitted rows took, the host's table counts the admitted rows' alone,
+    and a later turn of the conversation that rode resumes from the
+    snapshot its own prompt left."""
+    lengths = (23, 5, 11, 3, 13)
+    eng = _build(name, scan)
+    prompts = _prompts(name, lengths)
+    at, rounds, faults = [], [], []
+    _watch_rides(eng, at)
+    waves, take = eng._prefill_ragged_waves, eng._take_snapshots
+    pools = lambda: [np.asarray(eng.cache["page_state"][part])
+                     for part in ("ssm", "conv")]
+
+    def taking(batch):
+        out = take(batch)
+        rounds.append(sorted(dst for dst, _end in out.values()))
+        return out
+
+    def watched(batch):
+        before, live = pools(), eng._prefix.state_slots_live()
+        waves(batch)
+        taken = rounds[-1]
+        kept = [i for i in range(1, eng._snapshots + 1) if i not in taken]
+        try:        # on the engine's thread: kept for the test's
+            for was, now in zip(before, pools()):
+                np.testing.assert_array_equal(now[:, kept], was[:, kept])
+            assert eng._prefix.state_slots_live() == live + len(taken)
+        except AssertionError as e:
+            faults.append(e)
+
+    eng._take_snapshots, eng._prefill_ragged_waves = taking, watched
+    eng.start()
+    try:
+        together = _staggered(eng, prompts)
+        assert not faults, faults
+        c = eng.metrics.counters
+        # every prompt of a page or more took one snapshot, riders none
+        assert c["ssm_snapshots_taken"].value == sum(
+            n >= PS for n in lengths) == sum(len(t) for t in rounds)
+        assert c["ssm_snapshots_evicted"].value == 0
+        assert eng._prefix.state_slots_live() == c[
+            "ssm_snapshots_taken"].value
+        # someone whose prompt left a snapshot rode at a page end
+        ended = {rid for rid, pos in at if (pos + 1) % PS == 0}
+        turns = [t for t in together
+                 if t["rid"] in ended and len(t["prompt"]) >= PS]
+        assert turns, at
+        turn = turns[0]
+        resumed = c["ssm_state_tokens_resumed"].value
+        more = turn["prompt"] + turn["tokens"] + prompts[1]
+        done, later = _submit(eng, more, 12)
+        assert done.wait(180)
+        full = len(turn["prompt"]) // PS * PS
+        assert c["ssm_state_tokens_resumed"].value - resumed == full
+    finally:
+        eng.stop()
+    cold = _build(name, scan)
+    cold.start()
+    try:
+        assert _alone(cold, [more], (12,))[0]["tokens"] == later["tokens"]
+    finally:
+        cold.stop()
+
+
 # -------------------------------------------------------- (c) the rule
 
 
@@ -226,6 +349,17 @@ def test_a_rider_carries_its_conv_state_and_its_routing_row(sampling):
 def idle():
     """An engine that never runs: its slots are set by hand."""
     return _build()
+
+
+@pytest.fixture(scope="module", params=["dense", "snapshots"])
+def either(request, idle):
+    """The same, of either kind: who rides is read from the slots alone,
+    whatever state a slot carries beside its pages."""
+    if request.param == "dense":
+        return idle
+    eng = _build("tiny-nemotron")
+    assert eng._snapshots
+    return eng
 
 
 def _running(eng, **over):
@@ -245,9 +379,9 @@ def _running(eng, **over):
     return s
 
 
-def test_a_running_row_whose_state_is_confirmed_rides(idle):
-    _running(idle)
-    assert idle._wave_riders() == [0]
+def test_a_running_row_whose_state_is_confirmed_rides(either):
+    _running(either)
+    assert either._wave_riders() == [0]
 
 
 @pytest.mark.parametrize("over", [
@@ -259,25 +393,25 @@ def test_a_running_row_whose_state_is_confirmed_rides(idle):
     {"pending_token": True, "generated": []},  # admitted, first not out
 ], ids=["one_left", "cancelled", "at_max_seq", "chunk_in_flight",
         "pending", "first_pending"])
-def test_who_does_not_ride(idle, over):
-    _running(idle, **over)
-    assert idle._wave_riders() == []
+def test_who_does_not_ride(either, over):
+    _running(either, **over)
+    assert either._wave_riders() == []
 
 
-def test_two_left_and_the_last_position_under_max_seq_still_ride(idle):
-    _running(idle, generated=list(range(8)))
-    assert idle._wave_riders() == [0]
-    _running(idle, position=MAX_SEQ - 2, dispatched_position=MAX_SEQ - 2)
-    assert idle._wave_riders() == [0]
+def test_two_left_and_the_last_position_under_max_seq_still_ride(either):
+    _running(either, generated=list(range(8)))
+    assert either._wave_riders() == [0]
+    _running(either, position=MAX_SEQ - 2, dispatched_position=MAX_SEQ - 2)
+    assert either._wave_riders() == [0]
 
 
-def test_a_prefill_lane_takes_no_rider(idle):
-    _running(idle)
-    idle._role = "prefill"
+def test_a_prefill_lane_takes_no_rider(either):
+    _running(either)
+    either._role = "prefill"
     try:
-        assert idle._wave_riders() == []
+        assert either._wave_riders() == []
     finally:
-        idle._role = None
+        either._role = None
 
 
 @pytest.mark.parametrize("ridge,total,width,seats,fit", [
@@ -308,16 +442,20 @@ def test_riders_sit_in_seats_the_plan_pays_for(idle, ridge, total, width,
         idle._ragged_ridge_tokens = was
 
 
-def test_a_full_wave_leaves_the_running_rows_to_their_decode_step():
+@ENGINES
+def test_a_full_wave_leaves_the_running_rows_to_their_decode_step(
+        float32, name, scan):
     """The second request's 8 tokens fill its rung: the first, which is
-    decoding, does not ride, and loses nothing by it."""
-    eng = _build()
-    prompts = _prompts(lengths=(19, 8))
+    decoding, does not ride, loses nothing by it, and is counted as the
+    one running row that round had no seat for."""
+    eng = _build(name, scan)
+    prompts = _prompts(name, lengths=(19, 8))
     eng.start()
     try:
         alone = _alone(eng, prompts, (30, 12))
+        assert _unseated(eng) == 0     # nobody ran beside an admission
         together = _staggered(eng, prompts, (30, 12))
-        assert _riders(eng) == 0
+        assert _riders(eng) == 0 and _unseated(eng) == 1
         assert [t["tokens"] for t in together] == [a["tokens"]
                                                    for a in alone]
     finally:
@@ -396,14 +534,16 @@ def test_a_rider_is_no_admission_and_its_token_is_no_first_token():
 # ------------------------------------------------- (e) nothing compiles
 
 
-def test_riders_compile_nothing_after_warm_up():
-    eng = _build()
+@pytest.mark.parametrize("name", ["tiny-debug", "tiny-nemotron"],
+                         ids=["dense", "snapshots"])
+def test_riders_compile_nothing_after_warm_up(name):
+    eng = _build(name)
     eng.warmup()
     n0 = eng._compiled_count()
     eng.start()
     try:
-        _staggered(eng, _prompts())
-        _staggered(eng, _prompts(lengths=(7, 21, 4, 9, 16)))
+        _staggered(eng, _prompts(name))
+        _staggered(eng, _prompts(name, lengths=(7, 21, 4, 9, 16)))
         assert _riders(eng) > 0
         assert eng._compiled_count() == n0
     finally:
