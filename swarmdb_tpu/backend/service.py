@@ -546,6 +546,11 @@ class ServingService:
             except Exception:
                 logger.exception("swarmprof startup harvest failed")
         self.engine.start()
+        # the consumer's duty, at 0 from the start: 0 is then a reading
+        # and absence an older program
+        for name in ("serve_poll_rounds", "serve_poll_rounds_idle",
+                     "serve_poll_sleep_us"):
+            self.db.metrics.counters[name].inc(0)
         if self._procwatch is None:
             # one a process, counted (obs/procwatch.py); None where
             # SWARMDB_TRACE=0 turned every span off
@@ -596,6 +601,8 @@ class ServingService:
         ordering, offsets, and visibility; one consumer per backend drains
         all of its assigned agents.
         """
+        counters = self.db.metrics.counters
+        slept = (0, 0)      # the last idle sleep, on time.monotonic_ns
         while not self._stop.is_set():
             # watchdog (SURVEY §5.3): a dead decode loop strands every
             # in-flight and queued request — restart it, failing them fast
@@ -613,6 +620,7 @@ class ServingService:
                     self._stop.wait(1.0)
                     continue
             agents = self.db.agents_for_backend(self.backend_id)
+            counters["serve_poll_rounds"].inc()
             served = 0
             for agent in agents:
                 if self._stop.is_set():
@@ -627,6 +635,8 @@ class ServingService:
                     if msg.type in (MessageType.CHAT, MessageType.FUNCTION_CALL):
                         # one bad message must not kill the consumer thread
                         try:
+                            self._trace_pickup(msg, slept, served,
+                                               len(agents))
                             self.serve_message(msg)
                         except Exception:
                             logger.exception("serve_message failed for %s", msg.id)
@@ -641,7 +651,34 @@ class ServingService:
                                      msg.type.value, msg.id, agent)
                         self.db.metrics.counters["backend_skipped_messages"].inc()
             if served == 0:
+                # counted, not traced: an empty round every poll_interval
+                # would fill the ring the window's spans have to stay in
+                t_sleep = time.monotonic_ns()
                 self._stop.wait(self.poll_interval)
+                slept = (t_sleep, time.monotonic_ns())
+                counters["serve_poll_rounds_idle"].inc()
+                counters["serve_poll_sleep_us"].inc(
+                    (slept[1] - t_sleep) // 1000)
+
+    def _trace_pickup(self, msg: Message, slept: Tuple[int, int],
+                      behind: int, agents: int) -> None:
+        """``serve.pickup``: from the message's ``enqueued`` stamp (wall
+        clock, put onto the rings' clock) to now, when the consumer hands
+        it to ``serve_message``. ``slept_us`` is the part of it that the
+        consumer's last idle sleep ``slept`` covers, ``behind`` the
+        messages this round served before it, ``agents`` the inboxes a
+        round walks."""
+        if not TRACER.enabled:
+            return
+        enqueued = msg.metadata.get("stages", {}).get("enqueued")
+        if enqueued is None:
+            return
+        now = time.monotonic_ns()
+        t0 = min(TRACER.mono_of_epoch(enqueued), now)
+        overlap = min(now, slept[1]) - max(t0, slept[0])
+        TRACER.span_end(t0, "serve.pickup", cat="serving", rid=msg.id,
+                        args={"slept_us": max(0, overlap) // 1000,
+                              "behind": behind, "agents": agents})
 
     # ------------------------------------------------------ rolling KV
 
@@ -1165,7 +1202,8 @@ class ServingService:
                 lps = (list(req.metadata.get("logprobs", []))
                        if want_logprobs else None)
                 self._reply_queue.put((msg, rid, tokens, reason, sampling.stop,
-                                       lps, None, on_done))
+                                       lps, None, on_done,
+                                       time.monotonic_ns()))
 
             # stop-sequence watch (host-side): keep a bounded tail of decoded
             # text and CANCEL the engine request at the first match — the
@@ -1250,6 +1288,7 @@ class ServingService:
                     # bytes admission bulk-inserts into the reserved
                     # pages before the resume prefill reads them
                     req.promote_payload = resume[3]
+            t_submit = TRACER.span_begin()
             if n > 1:
                 rid = self._serve_n(msg, req, prompt, sampling, priority, n,
                                     want_logprobs, on_done)
@@ -1258,9 +1297,15 @@ class ServingService:
             # the span covers prompt build + trim + submit; args link the
             # message id to the ENGINE request id so one export joins the
             # runtime/broker spans (rid = msg.id) to the engine spans
-            # (rid = engine request id)
-            TRACER.span_end(t_serve, "serve.request", cat="serving",
-                            rid=msg.id, args={"engine_rid": rid})
+            # (rid = engine request id), and split the span at the submit:
+            # the history read before it, the engine's lock after
+            if t_serve:
+                TRACER.span_end(
+                    t_serve, "serve.request", cat="serving", rid=msg.id,
+                    args={"engine_rid": rid, "prompt_tokens": len(prompt),
+                          "build_us": (t_submit - t_serve) // 1000,
+                          "submit_us": (time.monotonic_ns() - t_submit)
+                          // 1000})
             return rid
         except Exception:
             # the in-flight claim taken by _rolling_plan must not leak on
@@ -1303,7 +1348,7 @@ class ServingService:
                 alts = [results[i] for i in range(1, n)]
                 self._reply_queue.put(
                     (msg, reqs[0].request_id, toks0, reason0, sampling.stop,
-                     lps0, alts, on_done))
+                     lps0, alts, on_done, time.monotonic_ns()))
             return _done_i
 
         reqs: List[GenRequest] = []
@@ -1402,10 +1447,13 @@ class ServingService:
             item = self._reply_queue.get()
             if item is None:
                 return
-            msg, rid, tokens, reason, stop, lps, alts, on_done = item
+            msg, rid, tokens, reason, stop, lps, alts, on_done, t_done = item
+            t_reply = TRACER.span_begin()
+            decode_us = send_us = 0
             for attempt in range(retries + 1):
                 try:
-                    self._emit_reply(msg, tokens, reason, stop, lps, alts)
+                    decode_us, send_us = self._emit_reply(
+                        msg, tokens, reason, stop, lps, alts)
                     break
                 except Exception as exc:
                     if (getattr(exc, "retryable", False)
@@ -1417,6 +1465,13 @@ class ServingService:
                         continue
                     logger.exception("failed to emit reply for %s", msg.id)
                     break
+            # one a message whatever n: the wait in _reply_queue since the
+            # engine's on_done, then the emit (its last attempt's parts)
+            TRACER.span_end(
+                t_reply, "serve.reply", cat="serving", rid=msg.id,
+                args={"queued_us": (t_reply - t_done) // 1000,
+                      "decode_us": decode_us, "send_us": send_us,
+                      "tokens": len(tokens), "attempts": attempt + 1})
             if on_done is not None:
                 try:
                     on_done(rid, tokens, reason)
@@ -1450,7 +1505,11 @@ class ServingService:
 
     def _emit_reply(self, msg: Message, tokens: List[int], reason: str,
                     stop: tuple = (), logprobs: Optional[List[float]] = None,
-                    alts: Optional[List[Tuple]] = None) -> None:
+                    alts: Optional[List[Tuple]] = None) -> Tuple[int, int]:
+        """Send the reply and mark ``msg`` processed. Returns the
+        microseconds the completions' decode took and those
+        ``db.send_message`` took, for ``serve.reply``."""
+        t_decode = time.monotonic_ns()
         text, reason, logprobs = self._finish_completion(
             tokens, reason, stop, logprobs)
         reply_type = (
@@ -1477,6 +1536,7 @@ class ServingService:
                     entry["logprobs"] = [round(x, 6) for x in lps_i]
                 rendered.append(entry)
             reply_meta["alternatives"] = rendered
+        t_send = time.monotonic_ns()
         reply_id = self.db.send_message(
             msg.receiver_id or self.backend_id,
             msg.sender_id,
@@ -1485,11 +1545,13 @@ class ServingService:
             priority=msg.priority,
             metadata=reply_meta,
         )
+        t_sent = time.monotonic_ns()
         msg.metadata["reply_id"] = reply_id
         self.db.mark_message_as_processed(msg.id)
         # north-star gauge: completed chat messages/sec
         self.db.metrics.rates["completed_messages"].mark()
         self.db.metrics.counters["completed_messages"].inc()
+        return (t_send - t_decode) // 1000, (t_sent - t_send) // 1000
 
     async def stream_reply(self, msg: Message) -> AsyncIterator[str]:
         """Async token-text stream for SSE (api/app.py). Bridges engine-
